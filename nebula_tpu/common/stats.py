@@ -152,6 +152,10 @@ METRIC_NAMES = (
     "tpu.compile.count",
     "tpu.prewarm.hits",
     "tpu.prewarm.misses",
+    "tpu.prewarm.failed",            # a prewarm compile the compiler refused
+    # where the runtime landed: device count labelled platform /
+    # device_kind (what storaged's /status also publishes)
+    "tpu.device.count",
     "tpu.dispatch.latency_us",
     # roofline accounting (tpu/runtime.py collector, docs/roofline.md):
     # sampled device-compute latency distinct from link RTT, achieved

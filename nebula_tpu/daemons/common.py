@@ -59,6 +59,19 @@ def write_pidfile(path: Optional[str]) -> None:
             f.write(str(os.getpid()))
 
 
+def native_built(daemon: str) -> bool:
+    """Build + load the native library before serving, not during.  A
+    failed build (ensure_built printed the compiler output) is fatal
+    to a daemon: the Python engines are a library fallback, not a
+    deployment mode."""
+    from ..native import ensure_built
+    if ensure_built():
+        return True
+    print(f"{daemon}: native library build failed (compiler output "
+          f"above)", file=sys.stderr)
+    return False
+
+
 def parse_meta_addrs(s: str) -> List[HostAddr]:
     return [HostAddr.parse(a.strip()) for a in s.split(",") if a.strip()]
 

@@ -18,7 +18,8 @@ from ..kvstore.store import KVOptions, NebulaStore
 from ..meta.service import META_PART, META_SPACE, MetaService
 from ..webservice import WebService
 from .common import (apply_flag_overrides, base_parser, load_flagfile,
-                     parse_meta_addrs, serve_forever, write_pidfile)
+                     native_built, parse_meta_addrs, serve_forever,
+                     write_pidfile)
 
 
 def build(args, cm=None):
@@ -75,8 +76,8 @@ def main(argv=None) -> int:
     apply_flag_overrides(args.flag)
     write_pidfile(args.pid_file)
 
-    from ..native import ensure_built
-    ensure_built()      # compile the C++ engine before serving, not during
+    if not native_built("nebula-metad"):
+        return 1
 
     service, cm, handler, raft_service = build(args)
     rpc = RpcServer(handler, host=args.local_ip, port=args.port).start()

@@ -18,7 +18,8 @@ from ..common.flags import flags
 from ..interface.rpc import ClientManager, RpcServer
 from ..webservice import WebService
 from .common import (apply_flag_overrides, base_parser, load_flagfile,
-                     parse_meta_addrs, serve_forever, write_pidfile)
+                     native_built, parse_meta_addrs, serve_forever,
+                     write_pidfile)
 
 
 def resolve_store_type(cli_value):
@@ -65,8 +66,8 @@ def main(argv=None) -> int:
         return 1
     write_pidfile(args.pid_file)
 
-    from ..native import ensure_built
-    ensure_built()      # compile the C++ engine before serving, not during
+    if not native_built("nebula-storaged"):
+        return 1
 
     cm = ClientManager()
     local = f"{args.local_ip}:{args.port}"

@@ -12,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 from typing import Optional
 
 _LIB: Optional[ctypes.CDLL] = None
@@ -32,32 +33,38 @@ def _sig(fn, restype, argtypes):
 
 
 def ensure_built() -> bool:
-    """Compile the native library if missing, then load it. Call this
-    from process STARTUP paths only (daemon mains, test session setup,
-    CLI tools) — never from a serving thread: the compile can take tens
-    of seconds and lib() itself deliberately never builds."""
+    """Build the native library from the tracked ``native/*.cc``
+    sources (``make`` — a no-op when the artifact is newer than them),
+    then load it.  Call this from process STARTUP paths only (daemon
+    mains, test session setup, CLI tools) — never from a serving
+    thread: the compile takes seconds and lib() itself deliberately
+    never builds.
+
+    A failed build is reported on stderr WITH the compiler output and
+    returns False: daemon mains treat that as fatal, library callers
+    keep the pure-Python paths."""
     global _TRIED
-    stale = False
-    if os.path.exists(_SO_PATH):
-        try:
-            ctypes.CDLL(_SO_PATH)
-        except OSError:
-            # the artifact exists but won't load here — typically a
-            # checked-in build from a newer toolchain (glibc symbol
-            # versions); force a local rebuild instead of silently
-            # dropping every native-served path to the Python fallback
-            stale = True
-    if stale or not os.path.exists(_SO_PATH):
-        makefile = os.path.join(_REPO_ROOT, "native", "Makefile")
-        if os.path.exists(makefile):
-            cmd = ["make", "-C", os.path.dirname(makefile)]
-            if stale:
-                cmd.insert(1, "-B")      # mtime says up-to-date; it isn't
+    makefile = os.path.join(_REPO_ROOT, "native", "Makefile")
+    if "NEBULA_NATIVE_SO" not in os.environ and os.path.exists(makefile):
+        cmd = ["make", "-C", os.path.dirname(makefile)]
+        if os.path.exists(_SO_PATH):
             try:
-                subprocess.run(cmd, capture_output=True, timeout=120,
-                               check=True)
-            except Exception:            # noqa: BLE001 — fall back to Python
-                return False
+                ctypes.CDLL(_SO_PATH)
+            except OSError:
+                # the artifact exists but won't load here (built by
+                # another toolchain — glibc symbol versions): mtime
+                # says up-to-date, it isn't
+                cmd.insert(1, "-B")
+        try:
+            subprocess.run(cmd, capture_output=True, timeout=120,
+                           check=True, text=True)
+        except (OSError, subprocess.SubprocessError) as e:
+            sys.stderr.write(
+                f"[native] `{' '.join(cmd)}` failed — the C++ engine, "
+                f"codec and ELL builder fall back to Python: {e}\n"
+                f"{getattr(e, 'stdout', '') or ''}"
+                f"{getattr(e, 'stderr', '') or ''}")
+            return False
         _TRIED = False                   # allow lib() to retry the load
     return lib() is not None
 

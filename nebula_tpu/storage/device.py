@@ -597,7 +597,7 @@ class RemoteDeviceRuntime:
         # WITHOUT mesh sharding re-heartbeats, metad's catalog clock
         # moves, graphd's next load_data bumps the generation, and the
         # space probes UPTO again without waiting out the TTL or
-        # restarting graphd (ADVICE.md round 5)
+        # restarting graphd
         self._upto_declined: Dict[int, Tuple[float, str, int]] = {}
         # failover-ladder decline cache, the UPTO style made per
         # (space, host): a replica that answered degraded (or was

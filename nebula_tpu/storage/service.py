@@ -767,20 +767,33 @@ class StorageService:
                 out.extend(b.cells_snapshot())
         return out
 
-    def device_ready(self) -> bool:
-        """Healthz probe: the device runtime either isn't wanted
-        (storage_backend=cpu) or its jax substrate imports/configures."""
-        if flags.get("storage_backend") == "cpu":
-            return True
+    def device_info(self) -> Optional[dict]:
+        """{platform, device_kind, device_count} of the runtime that
+        serves deviceGo here (the bulk-read backend's when that is the
+        only one built), or None before the first device request —
+        what /status publishes so a harness learns WHERE storaged's
+        kernels ran from storaged itself."""
         with self._device_rt_lock:
-            if self._device_rt is not None or self._backend_rt is not None:
-                return True
+            rt = self._device_rt or self._backend_rt
+        return dict(rt.device_info) if rt is not None else None
+
+    def device_ready(self):
+        """Healthz probe -> (ok, detail): the device runtime either
+        isn't wanted (storage_backend=cpu) or jax imports.  The detail
+        names the platform once a runtime exists — ready says nothing
+        about WHICH device, /status does."""
+        if flags.get("storage_backend") == "cpu":
+            return True, "storage_backend=cpu"
+        info = self.device_info()
+        if info is not None:
+            return True, ("device runtime on platform={platform} "
+                          "device_kind={device_kind!r} "
+                          "devices={device_count}".format(**info))
         try:
-            from ..tpu.jax_setup import ensure_jax_configured
-            ensure_jax_configured()
-            return True
-        except Exception:       # noqa: BLE001
-            return False
+            import jax  # noqa: F401 — importable is all boot can prove
+        except ImportError as e:
+            return False, f"jax unavailable: {e}"
+        return True, "jax importable; no device runtime built yet"
 
     def rpc_addLearner(self, req: dict) -> dict:
         part = self._raft(req)

@@ -1,5 +1,6 @@
-"""storaged web handlers — /status is WebService-builtin; this module
-adds the bulk-load pair the reference serves from storaged's proxygen
+"""storaged web handlers — /status is WebService-builtin (extended
+here with the device runtime's platform / device_kind / count); this
+module adds the bulk-load pair the reference serves from storaged's proxygen
 server (StorageHttpDownloadHandler / StorageHttpIngestHandler,
 StorageServer.cpp:60-89):
 
@@ -180,9 +181,10 @@ def register_web_handlers(ws, node) -> None:
     # runtime importable (docs/observability.md "Metrics & events")
     ws.register_health_check("meta", lambda: _meta_reachable(node))
     ws.register_health_check("parts", lambda: _parts_serving(node))
-    ws.register_health_check(
-        "device", lambda: (node.service.device_ready(),
-                           "device runtime ready"))
+    ws.register_health_check("device", node.service.device_ready)
+    # /status additionally says WHERE this storaged's device runtime
+    # landed (null until the first device request builds it)
+    ws.register_status_field("device", node.service.device_info)
     # degradation signal: 503 while a device circuit breaker is OPEN
     # (queries keep answering via the CPU fallback — docs/durability.md)
     ws.register_health_check("device_breaker",

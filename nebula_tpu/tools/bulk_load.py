@@ -30,6 +30,12 @@ import numpy as np
 
 from ..common.clock import inverted_version
 from ..common.keys import id_hash
+from ..common.status import Status
+
+# the largest staging file bulk_load writes (and the engine reads back
+# whole): big enough that a 2^24-edge load is one file per part, far
+# enough under 1 GiB that a file-size limit never cuts a load short
+STAGE_BYTES = 256 << 20
 
 _S32 = np.uint64(1 << 31)
 _S64 = np.uint64(1 << 63)
@@ -104,12 +110,21 @@ def _split_by_part(parts: np.ndarray, nparts: int, buf: np.ndarray,
                    off: np.ndarray) -> Dict[int, List[np.ndarray]]:
     """Slice a part-major frame buffer into per-part byte views
     (``parts`` must be sorted ascending — both frame builders sort
-    part-major)."""
+    part-major).  A part whose frames exceed ``STAGE_BYTES`` is cut at
+    row boundaries into consecutive views of at most that size (one
+    row at least), so no staging file grows past it."""
     out: Dict[int, List[np.ndarray]] = {}
     bounds = np.searchsorted(parts, np.arange(nparts + 2))
     for p in np.unique(parts).tolist():
-        lo, hi = int(off[bounds[p]]), int(off[bounds[p + 1]])
-        out[int(p)] = [buf[lo:hi]]
+        row, end = int(bounds[p]), int(bounds[p + 1])
+        views = []
+        while row < end:
+            nxt = int(np.searchsorted(off, off[row] + STAGE_BYTES,
+                                      side="right")) - 1
+            nxt = min(max(nxt, row + 1), end)
+            views.append(buf[int(off[row]):int(off[nxt])])
+            row = nxt
+        out[int(p)] = views
     return out
 
 
@@ -217,44 +232,38 @@ def _assert_be(c: np.ndarray) -> np.ndarray:
     return c
 
 
-def write_ingest_files(store, space_id: int, staging_dir: str,
-                       frame_groups: Sequence[Dict[int, List[np.ndarray]]],
-                       name: str = "bulk") -> List[str]:
-    """Write per-engine snapshot-format files (one per engine that owns
-    any of the touched parts, named *.engineN.snap so NebulaStore.ingest
-    routes them) and return the paths."""
+def bulk_load(store, space_id: int, staging_dir: str,
+              frame_groups: Sequence[Dict[int, List[np.ndarray]]],
+              name: str = "bulk"):
+    """Stage and ingest the frames ONE BUFFER AT A TIME: each per-part
+    buffer is written to a snapshot-format file (named *.engineN.snap
+    so NebulaStore.ingest routes it to the engine whose parts read
+    it), ingested, and removed before the next one is staged.  Staging
+    — the file on disk and the engine's read of it — therefore never
+    holds more than ``STAGE_BYTES``: a 2^24-edge load staged as ONE
+    file is 1.7 GB, past the 1 GiB file-size limit some machines run
+    under.  Buffers go in ascending part order, so the engine still
+    sees ascending runs (hinted inserts).  Returns the first failing
+    ingest Status, else OK."""
     os.makedirs(staging_dir, exist_ok=True)
-    by_engine: Dict[int, List[np.ndarray]] = {}
+    seq = 0
     for group in frame_groups:
-        for part, chunks in group.items():
+        for part, chunks in sorted(group.items()):
             ei = store.engine_index_of_part(space_id, part)
             if ei is None:
                 raise ValueError(f"part {part} not on this store")
-            by_engine.setdefault(ei, []).extend(chunks)
-    paths = []
-    for ei, chunks in sorted(by_engine.items()):
-        path = os.path.join(staging_dir,
-                            f"{name}_{space_id}.engine{ei}.snap")
-        with open(path, "wb") as f:
             for c in chunks:
-                _assert_be(c).tofile(f)
-        paths.append(path)
-    return paths
-
-
-def bulk_load(store, space_id: int, staging_dir: str,
-              frame_groups: Sequence[Dict[int, List[np.ndarray]]],
-              name: str = "bulk", keep_files: bool = False):
-    """write_ingest_files + NebulaStore.ingest in one step.  Returns
-    the ingest Status; staging files are removed on success unless
-    ``keep_files``."""
-    paths = write_ingest_files(store, space_id, staging_dir,
-                               frame_groups, name)
-    st = store.ingest(space_id, paths)
-    if st.ok() and not keep_files:
-        for p in paths:
-            try:
-                os.remove(p)
-            except OSError:
-                pass
-    return st
+                path = os.path.join(
+                    staging_dir,
+                    f"{name}_{space_id}.{seq}.engine{ei}.snap")
+                seq += 1
+                try:
+                    with open(path, "wb") as f:
+                        _assert_be(c).tofile(f)
+                    st = store.ingest(space_id, [path])
+                finally:
+                    if os.path.exists(path):
+                        os.remove(path)
+                if not st.ok():
+                    return st
+    return Status.OK()

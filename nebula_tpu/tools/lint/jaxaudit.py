@@ -95,8 +95,10 @@ def _sig_of(avals) -> Tuple:
 
 
 def _find_pjit(jaxpr):
+    """The traced jit call (the equation that carries
+    ``donated_invars``) — jax 0.9.0 names the primitive ``jit``."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             return eqn
     return None
 
@@ -287,7 +289,6 @@ def audit_specs(specs, fx, phases_table: Dict[str, dict],
     (rel_path, line) places each violation.  ``hbm`` (the runtime's
     HBM_MODEL) arms the per-rung resident-bytes budget gate."""
     import jax
-    from jax.experimental import enable_x64
 
     out: List[Violation] = []
 
@@ -330,7 +331,7 @@ def audit_specs(specs, fx, phases_table: Dict[str, dict],
                 continue
             traced.add(tkey)
             try:
-                with enable_x64():
+                with jax.enable_x64(True):
                     closed = jax.make_jaxpr(fn)(*avals)
             except Exception as e:  # noqa: BLE001 — untraceable =
                 emit(f"kernel '{spec.name}': trace failed for bucket "

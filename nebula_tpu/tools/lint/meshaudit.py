@@ -224,7 +224,6 @@ def mesh_audit_specs(specs, fx, anchor, hbm: Optional[dict] = None,
     and run the five IR checks.  ``anchor(spec) -> (rel, line)`` places
     violations; ``hbm`` (runtime.HBM_MODEL) arms the residency gate."""
     import jax
-    from jax.experimental import enable_x64
     from . import jaxaudit
 
     out: List[Violation] = []
@@ -266,7 +265,7 @@ def mesh_audit_specs(specs, fx, anchor, hbm: Optional[dict] = None,
                 continue
             for key, fn, avals in buckets:
                 try:
-                    with enable_x64():
+                    with jax.enable_x64(True):
                         closed = jax.make_jaxpr(fn)(*avals)
                 except Exception as e:  # noqa: BLE001
                     emit(f"kernel '{spec.name}': mesh trace failed for "

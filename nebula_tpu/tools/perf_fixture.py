@@ -52,13 +52,12 @@ def edge(src: int, etype: int, dst: int, w: int) -> dict:
             "props": encode_row(REL, {"w": w})}
 
 
-def probe_link_rtt_ms(reps: int = 5) -> float:
-    """Measured device-link round trip (one jitted execute + fetch of
-    a tiny array, averaged over ``reps``).  The serving path's
-    per-batch floor is one execute + one fetch over this link, so
-    bench outputs record it for cross-environment attribution — the
-    ONE probe bench.py and bench_suite share, so their numbers stay
-    comparable."""
+def probe_device_roundtrip_ms(reps: int = 5) -> float:
+    """Measured host->device->host round trip (one jitted execute +
+    fetch of a tiny array, averaged over ``reps``).  The serving path's
+    per-batch floor is one execute + one fetch, so bench outputs record
+    it beside the rows it bounds — the ONE probe bench.py and
+    bench_suite share, so their numbers stay comparable."""
     import time
 
     import jax

@@ -151,6 +151,12 @@ class ProcDaemon:
     def metrics(self, timeout: float = 5.0) -> str:
         return self._http("/metrics", timeout)
 
+    def status(self, timeout: float = 5.0) -> dict:
+        """Parsed /status — on a storaged its ``device`` field is the
+        platform / device_kind / device_count of the runtime that
+        served (None before the first device request)."""
+        return json.loads(self._http("/status", timeout))
+
     def events(self, timeout: float = 5.0) -> List[dict]:
         return json.loads(self._http("/events", timeout)).get("events", [])
 
@@ -170,14 +176,25 @@ class ProcCluster:
     name=value`` to every daemon (chaos suites shrink heartbeat /
     election timers there).  ``storage_backend="cpu"`` by default keeps
     subprocess boot lean (no jax import on the storaged); pass "tpu"
-    to exercise device serving across the process boundary."""
+    to exercise device serving across the process boundary.
+
+    ``device_env`` is laid over the inherited environment of the
+    STORAGED processes — the only daemons that touch jax.  The default
+    forces CPU jax (tier-1, chaos and the test-driven bench legs run
+    from parents that already hold whatever accelerator exists).  To
+    put storaged on the chip pass ``{"JAX_PLATFORMS": "tpu"}`` from a
+    parent that has NOT initialised jax: a chip belongs to one process,
+    so it is one storaged per chip.  metad and graphd are jax-free and
+    always get ``JAX_PLATFORMS=cpu``."""
 
     BOOT_TIMEOUT_S = 60.0
+    CPU_DEVICE_ENV = {"JAX_PLATFORMS": "cpu"}
 
     def __init__(self, run_dir: str, num_storage: int = 1,
                  storage_backend: str = "cpu",
                  extra_flags: Optional[Dict[str, object]] = None,
-                 start: bool = True):
+                 start: bool = True,
+                 device_env: Optional[Dict[str, str]] = None):
         self.run_dir = os.path.abspath(run_dir)
         os.makedirs(self.run_dir, exist_ok=True)
         self.daemons: Dict[str, ProcDaemon] = {}
@@ -192,10 +209,12 @@ class ProcCluster:
             flag_args += ["--flag", f"{k}={v}"]
 
         env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = _repo_root() + os.pathsep + \
             env.get("PYTHONPATH", "")
         env.setdefault("PYTHONUNBUFFERED", "1")
+        storaged_env = {**env, **(self.CPU_DEVICE_ENV if device_env is None
+                                  else device_env)}
+        env.update(self.CPU_DEVICE_ENV)
         # kept for add_graphd: extra front ends inherit the cluster's
         # flag set (overridable per instance)
         self._flag_args = list(flag_args)
@@ -222,7 +241,7 @@ class ProcCluster:
                 "--ws_http_port", str(ws),
                 "--meta_server_addrs", self.meta_addr,
                 "--data_path", os.path.join(self.run_dir, name),
-            ] + flag_args, port, ws, env)
+            ] + flag_args, port, ws, storaged_env)
 
         graph_port, graph_ws = _free_port(), _free_port()
         self.graph_addr = f"127.0.0.1:{graph_port}"

@@ -145,7 +145,7 @@ def main():
 
     # scale-tuned serving shape: sparse pair kernels with a deep final
     # cap; the dense bitmap path is a last resort at this graph size
-    # (its fetch is tens of MB over a 15 MB/s link)
+    # (its fetch is tens of MB)
     flags.set("tpu_sparse_cap", 1 << 18)
     flags.set("tpu_ell_cap", 256)
     flags.set("go_batch_widths", "128")
@@ -297,9 +297,8 @@ def main():
                 / out["tpu" + tag]["p50_ms"], 2)
             # auto-routed leg: the backend router measures both paths
             # and serves each family from the cheaper one — the light
-            # shapes where the flat CPU fallback beat the device
-            # (SCALE_r05 0.58x/0.9x) must recover to >= the max of
-            # both curves here
+            # shapes where the flat CPU fallback beats the device
+            # must recover to >= the max of both curves here
             flags.set("storage_backend", "tpu")
             flags.set("go_backend_router", True)
             try:

@@ -372,7 +372,7 @@ def make_sharded_go_kernel(mesh: Mesh, axis: str, n: int, steps: int,
     edge_etype) + replicated start bitmap -> (final_mask sharded bool[m],
     frontier bool[n]).
     """
-    from .compat import shard_map
+    from jax import shard_map
 
     def per_shard(edge_src, edge_dst, edge_etype, frontier0):
         ok = etype_mask(edge_etype, etypes)

@@ -5,7 +5,8 @@ Capability parity with the reference's proxygen webservice
 17-40, GetFlagsHandler.cpp, SetFlagsHandler.cpp): each daemon runs one
 HTTP server exposing
 
-  GET /status                       liveness + daemon role
+  GET /status                       liveness + daemon role (+ any
+                                    register_status_field extras)
   GET /flags[?names=a,b]            runtime gflag read (JSON)
   PUT /flags?name=<n>&value=<v>     runtime gflag write (MUTABLE only)
   GET /get_stats[?stats=expr,...]   StatsManager counters; expr syntax
@@ -54,6 +55,8 @@ class WebService:
         self._handlers: Dict[str, Callable] = {}
         # name -> fn() -> (ok: bool, detail: str); all must pass for 200
         self._health_checks: Dict[str, Callable] = {}
+        # name -> fn() -> JSON-able; extra daemon-specific /status fields
+        self._status_fields: Dict[str, Callable] = {}
         self.register_handler("/status", self._status)
         self.register_handler("/flags", self._flags)
         self.register_handler("/faults", self._faults)
@@ -131,10 +134,17 @@ class WebService:
         failed (its exception becomes the detail)."""
         self._health_checks[name] = fn
 
+    def register_status_field(self, name: str, fn: Callable) -> None:
+        """``fn() -> JSON-able`` evaluated per /status request and
+        published under ``name`` (storaged: the device runtime's
+        platform)."""
+        self._status_fields[name] = fn
+
     # ------------------------------------------------------- built-ins
     def _status(self, q: dict, body: bytes):
         return 200, {"status": "running", "name": self.daemon_name,
-                     "git_info_sha": "nebula-tpu"}
+                     "git_info_sha": "nebula-tpu",
+                     **{k: fn() for k, fn in self._status_fields.items()}}
 
     def _flags(self, q: dict, body: bytes):
         if q.get("__method__") in ("PUT", "POST"):

@@ -119,3 +119,45 @@ def test_bulk_load_bumps_version_and_serves_device(cluster, tmp_path):
     assert store.mutation_version(sid) > v0
     r = g.execute("GO 3 STEPS FROM 1 OVER knows")
     assert r.ok() and sorted(map(tuple, r.rows)) == [(4,)]
+
+
+def test_bulk_load_stages_bounded_files(cluster, tmp_path, monkeypatch):
+    """No staging file grows past STAGE_BYTES (a part larger than that
+    is cut at row boundaries), each file is gone once ingested, and the
+    load is still complete."""
+    import os
+    c = cluster
+    g = c.client()
+    ok, sid, tag, et = _mk_space(c, g, "blk3")
+    store = c.storage_nodes[0].kv
+    monkeypatch.setattr(BL, "STAGE_BYTES", 1024)
+    rng = np.random.default_rng(5)
+    m = 400
+    src = rng.integers(1, 40, m)
+    dst = rng.integers(1, 40, m)
+    schema_e = c.schema_man.get_edge_schema(sid, et)
+    blobs = [encode_row(schema_e, {"w": int(i)}) for i in range(3)]
+    frames = BL.edge_frames(len(store.part_ids(sid)), et, src, dst,
+                            blobs, np.arange(m) % 3)
+    assert max(len(v) for v in frames.values()) > 1     # parts were cut
+    staged = []
+    ingest = store.ingest
+
+    def spy(space_id, paths):
+        staged.extend(os.path.getsize(p) for p in paths)
+        assert os.listdir(tmp_path) == [os.path.basename(p)
+                                        for p in paths]
+        return ingest(space_id, paths)
+
+    monkeypatch.setattr(store, "ingest", spy)
+    assert BL.bulk_load(store, sid, str(tmp_path), [frames]).ok()
+    assert staged and max(staged) <= 1024
+    assert sum(staged) == sum(v.nbytes for vs in frames.values()
+                              for v in vs)
+    assert os.listdir(tmp_path) == []
+    pairs = set(zip(src.tolist(), dst.tolist()))
+    for v in (int(src[0]), int(src[1])):
+        r = g.execute(f"GO FROM {v} OVER knows")
+        assert r.ok()
+        assert sorted(x[0] for x in r.rows) == \
+            sorted(d for s, d in pairs if s == v)

@@ -410,7 +410,7 @@ class TestUptoDeclineCacheHealing:
         assert 7 not in rt._upto_declined
 
     def test_decline_dropped_on_meta_refresh(self):
-        """ADVICE.md round 5: a storaged restarted WITHOUT mesh
+        """A storaged restarted WITHOUT mesh
         sharding (same host, same placement) must resume UPTO traffic
         as soon as graphd's meta cache refreshes — not only after the
         TTL or a graphd restart.  load_data bumps

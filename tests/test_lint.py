@@ -1644,14 +1644,13 @@ def test_hbm_residency_rows_positive():
     """The docs budget table's source: every registered kernel bucket
     reports a positive peak with mirror+dispatch+output parts."""
     import jax
-    from jax.experimental import enable_x64
     from nebula_tpu.tools.lint.jaxaudit import hbm_residency
     from nebula_tpu.tpu.kernels import AuditFixture, kernel_registry
 
     fx = AuditFixture()
     spec = kernel_registry()["ell_go"]
     key, fn, avals = spec.instantiate(fx)[0]
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(fn)(*avals)
     mirror_b, dispatch_b, out_b, peak = hbm_residency(spec, closed, avals)
     assert mirror_b > 0 and dispatch_b > 0 and out_b > 0
@@ -1915,7 +1914,7 @@ def _psum_kernel(fx, mesh):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from nebula_tpu.tpu.compat import shard_map
+    from jax import shard_map
 
     def per_shard(x):
         return jax.lax.psum(x, "parts")
