@@ -465,7 +465,8 @@ class PhaseA:
     def tick_summary(recs: List[dict]) -> dict:
         """Continuous-pump ticks: ``dur_us`` is a tick's wall (it ends
         with the blocking extract, so it contains the device's hop);
-        ``hop_us`` is only the asynchronous enqueue."""
+        ``hop_us`` is only the asynchronous enqueue, ``fetch_wait_us``
+        the pump blocked on the device."""
         ticks = [r for r in recs if r.get("kind") == "tick"]
         if not ticks:
             return {"ticks": 0}
@@ -475,7 +476,9 @@ class PhaseA:
                 "tick_wall_ms_median": round(statistics.median(wall), 3),
                 "tick_wall_ms_max": round(max(wall), 3),
                 "hop_enqueue_ms_median": round(statistics.median(
-                    r["hop_us"] / 1e3 for r in ticks), 3)}
+                    r["hop_us"] / 1e3 for r in ticks), 3),
+                "fetch_wait_ms_median": round(statistics.median(
+                    r["fetch_wait_us"] / 1e3 for r in ticks), 3)}
 
     # ----------------------------------------------------------- steps
     def cache_probe(self, stmt: str) -> None:

@@ -1,14 +1,18 @@
-"""nebulaprof — the device flight recorder (docs/observability.md
+"""nebulaprof — the flight recorder (docs/observability.md
 "The device timeline").
 
 The metrics plane's fourth leg: counters/gauges say HOW MUCH, traces
 say WHERE in one statement, events say WHAT happened — the flight
-recorder says WHEN on the device.  A lock-cheap ring buffer holds one
-structured record per continuous-pump tick (seat churn, per-phase op
-micros, idle gap, mirror generation — graph/batch_dispatch.py), one
-per windowed/mesh kernel dispatch (kernel class, shape rung,
+recorder says WHEN on the pump that feeds the device.  Every number
+in it is a HOST clock reading: a lock-cheap ring buffer holds one
+structured record per continuous-pump tick (seat churn, per-phase
+micros of the pump thread, idle gap, mirror generation —
+graph/batch_dispatch.py; ``fetch_wait_us`` is where that thread
+blocked on the device, the device as the pump sees it), one per
+windowed/mesh kernel dispatch (kernel class, shape rung,
 per-collective ICI bytes — tpu/runtime.py), and one per sampled
-device-timing probe (the ``tpu_device_timing_every`` gate).  Records
+device-timing probe (the ``tpu_device_timing_every`` gate: windowed
+dispatch only, the continuous stream is never blocked by it).  Records
 are stamped with clock.now_micros() so ``clock.advance_for_tests``
 ages the timeline deterministically, exactly like the event journal.
 
@@ -120,9 +124,11 @@ class FlightRecorder:
 
     def note_tick(self, stream: int, **fields) -> int:
         """One continuous-pump tick of the per-(space, OVER set)
-        stream keyed ``stream``: seat churn counts, per-phase op
-        micros (join/hop/extract/clear/assemble), idle gap since the
-        previous tick, mirror generation, total busy micros."""
+        stream keyed ``stream``: seat churn counts, per-phase micros
+        in pump order (seat, then the join/hop/extract/clear ENQUEUES,
+        then the leave cohort's fetch_wait/d2h/unpack/rows/handover,
+        whose sum is assemble_us), leaver_rows, idle gap since the
+        previous tick, mirror generation, tick wall micros."""
         rec = {"kind": "tick", "stream": int(stream)}
         rec.update(fields)
         return self._note(rec)
@@ -138,7 +144,7 @@ class FlightRecorder:
                     gbps: float) -> int:
         """One sampled device-timing probe — the rows the
         ``tpu_device_timing_every`` flag gates (tpu/runtime.py
-        _maybe_time_device)."""
+        _maybe_time_device; windowed dispatch sites only)."""
         return self._note({"kind": "timing", "op": str(op),
                            "wall_us": round(float(wall_us), 1),
                            "bytes": int(nbytes),
@@ -242,7 +248,7 @@ stats.register_collector(recorder._collect)
 
 # ------------------------------------------------------- trace export
 _HOST_PID = 1          # the span-tree rows
-_DEVICE_PID = 2        # the flight-recorder rows
+_PUMP_PID = 2          # the flight-recorder rows (host clock too)
 _DISPATCH_TID = 1
 _TIMING_TID = 2
 _STREAM_TID_BASE = 10  # continuous stream S renders as tid 10+S
@@ -259,11 +265,13 @@ def _span_events(node: dict, tid: int, out: List[dict]) -> None:
         _span_events(child, tid, out)
 
 
-# per-tick op phases, in pump execution order — rendered as nested
+# per-tick phases, in pump execution order — rendered as nested
 # slices inside the tick so the "where do the busy-ms go" question is
-# answered visually (batch_dispatch._tick records the micros)
-_TICK_PHASES = ("join_us", "hop_us", "extract_us", "clear_us",
-                "assemble_us")
+# answered visually (batch_dispatch._tick records the micros; the last
+# five are the parts of assemble_us)
+_TICK_PHASES = ("seat_us", "join_us", "hop_us", "extract_us",
+                "clear_us", "fetch_wait_us", "d2h_us", "unpack_us",
+                "rows_us", "handover_us")
 
 
 def chrome_trace(tree: Optional[dict] = None,
@@ -276,12 +284,12 @@ def chrome_trace(tree: Optional[dict] = None,
     ev: List[dict] = [
         {"ph": "M", "pid": _HOST_PID, "tid": 0, "name": "process_name",
          "args": {"name": "host spans"}},
-        {"ph": "M", "pid": _DEVICE_PID, "tid": 0,
+        {"ph": "M", "pid": _PUMP_PID, "tid": 0,
          "name": "process_name",
-         "args": {"name": "nebulaprof device flight recorder"}},
-        {"ph": "M", "pid": _DEVICE_PID, "tid": _DISPATCH_TID,
+         "args": {"name": "pump flight recorder (host)"}},
+        {"ph": "M", "pid": _PUMP_PID, "tid": _DISPATCH_TID,
          "name": "thread_name", "args": {"name": "dispatch"}},
-        {"ph": "M", "pid": _DEVICE_PID, "tid": _TIMING_TID,
+        {"ph": "M", "pid": _PUMP_PID, "tid": _TIMING_TID,
          "name": "thread_name", "args": {"name": "device timing"}},
     ]
     if tree:
@@ -302,7 +310,7 @@ def chrome_trace(tree: Optional[dict] = None,
             tid = _STREAM_TID_BASE + int(rec.get("stream", 0))
             if tid not in streams_named:
                 streams_named.add(tid)
-                ev.append({"ph": "M", "pid": _DEVICE_PID, "tid": tid,
+                ev.append({"ph": "M", "pid": _PUMP_PID, "tid": tid,
                            "name": "thread_name",
                            "args": {"name":
                                     f"stream {rec.get('stream', 0)}"}})
@@ -310,7 +318,7 @@ def chrome_trace(tree: Optional[dict] = None,
             start = ts - dur
             args = {k: v for k, v in sorted(rec.items())
                     if k not in ("kind", "time_us")}
-            ev.append({"ph": "X", "pid": _DEVICE_PID, "tid": tid,
+            ev.append({"ph": "X", "pid": _PUMP_PID, "tid": tid,
                        "cat": "tick", "name": "tick", "ts": start,
                        "dur": dur, "args": args})
             cursor = start
@@ -318,13 +326,13 @@ def chrome_trace(tree: Optional[dict] = None,
                 us = int(rec.get(phase) or 0)
                 if us <= 0:
                     continue
-                ev.append({"ph": "X", "pid": _DEVICE_PID, "tid": tid,
+                ev.append({"ph": "X", "pid": _PUMP_PID, "tid": tid,
                            "cat": "phase", "name": phase[:-3],
                            "ts": cursor, "dur": us, "args": {}})
                 cursor += us
         elif kind == "timing":
             dur = int(rec.get("wall_us") or 0)
-            ev.append({"ph": "X", "pid": _DEVICE_PID,
+            ev.append({"ph": "X", "pid": _PUMP_PID,
                        "tid": _TIMING_TID, "cat": "timing",
                        "name": str(rec.get("op", "?")),
                        "ts": ts - dur, "dur": dur,
@@ -333,7 +341,7 @@ def chrome_trace(tree: Optional[dict] = None,
         else:                      # dispatch rows render as markers
             args = {k: v for k, v in sorted(rec.items())
                     if k not in ("kind", "time_us")}
-            ev.append({"ph": "i", "s": "p", "pid": _DEVICE_PID,
+            ev.append({"ph": "i", "s": "p", "pid": _PUMP_PID,
                        "tid": _DISPATCH_TID,
                        "name": str(rec.get("kernel", "dispatch")),
                        "ts": ts, "args": args})

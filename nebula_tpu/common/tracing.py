@@ -30,7 +30,9 @@ Design constraints, in order:
 
 Timing: spans use clock.Duration (monotonic) plus the fake-clock test
 offset (clock.advance_for_tests), so tracing tests are deterministic
-without sleeping.
+without sleeping.  ``emit`` records a span that was timed elsewhere
+(the continuous pump stamps perf_counter and reports after the tick),
+on the same now_micros() clock.
 """
 from __future__ import annotations
 
@@ -101,7 +103,30 @@ SPAN_NAMES = (
                               # /timeline endpoint — common/flight.py
                               # chrome_trace, docs/observability.md
                               # "The device timeline")
+    # the continuous pump's own trace, emitted post hoc from one set
+    # of perf_counter stamps per tick (batch_dispatch _emit_pump_trace,
+    # docs/observability.md "The pump trace"): a root per traced tick,
+    # children that tile it in pump order, and the idle stretch since
+    # the previous tick ended
+    "pump.tick",              # root: one traced tick (tags: stream,
+                              # tick, seats, joins, leaves, riders)
+    "pump.seat",              # anchor + seat-map bookkeeping
+    "pump.enqueue",           # join + hop + extract + clear enqueues
+    "pump.fetch_wait",        # host blocked on the leave cohort's
+                              # extract buffer (the device, seen from
+                              # the pump)
+    "pump.d2h",               # the copy after that wait
+    "pump.unpack",            # per-leaver bit extraction + perm
+                              # gather + nonzero
+    "pump.rows",              # COUNT folds + grouped row assembly
+    "pump.handover",          # results published, waiters notified
+    "pump.idle",              # root: no tick in flight (tag: why)
 )
+
+# the waits a continuous rider's time in submit() is made of, in
+# order (batch_dispatch _ContinuousStream._waits): tags of its
+# graph.continuous marker, keys of its seat markers and slow-log entry
+RIDER_WAITS = ("seat_wait_us", "ride_us", "result_wait_us", "wake_us")
 
 _tls = threading.local()          # .ctx = (trace_id, span_id, True)
 _rng = random.Random()            # ids; independent of seeded test RNGs
@@ -197,7 +222,7 @@ def start_trace(name: str, forced: bool = False, **tags):
         rate = flags.get("trace_sample_rate", 0.0)
         if not rate or _rng.random() >= float(rate):
             return _NOOP
-    return Span(name, _rng.getrandbits(63), None, tags)
+    return Span(name, new_trace_id(), None, tags)
 
 
 class _Attach:
@@ -386,9 +411,11 @@ class SlowQueryLog:
                trace_id: Optional[int],
                seat: Optional[dict] = None) -> None:
         """``seat`` carries the continuous-dispatch markers of a slow
-        statement that rode a lane batch — lane, joined_tick, hops,
-        the typed ``ending`` (common/protocol.py continuous-ending
-        vocabulary) and the ``timeline`` anchor (first/last flight-
+        statement that rode a lane batch — lane, joined_tick,
+        left_tick, hops, the typed ``ending`` (common/protocol.py
+        continuous-ending vocabulary), the four waits its submit() was
+        made of (seat_wait_us / ride_us / result_wait_us / wake_us:
+        which wait was slow) and the ``timeline`` anchor (first/last flight-
         recorder tick ids for the rider's stream, common/flight.py) —
         so the slow log attributes a slow rider to its seat trajectory
         and its `/timeline` window, not just its wall time (windowed
@@ -405,8 +432,8 @@ class SlowQueryLog:
                  "trace_id": (f"{trace_id:016x}"
                               if trace_id is not None else None)}
         if seat:
-            for k in ("lane", "joined_tick", "hops", "ending",
-                      "timeline"):
+            for k in ("lane", "joined_tick", "left_tick", "hops",
+                      "ending", "timeline") + RIDER_WAITS:
                 if seat.get(k) is not None:
                     entry[k] = seat[k]
         with self._lock:
@@ -434,6 +461,30 @@ def _record(wire: Dict[str, Any]) -> None:
         sink.append(wire)
 
 
+def new_trace_id() -> int:
+    """A fresh trace id for a trace that has no live root Span (the
+    pump's post-hoc trees)."""
+    return _rng.getrandbits(63)
+
+
+def emit(name: str, trace_id: int, parent_id: Optional[int],
+         start_us: int, duration_us: int, **tags) -> int:
+    """Record a FINISHED span with explicit timing into ``trace_id``
+    and return its span id (so the caller can parent children to it).
+    For code that stamps its own clock and reports afterwards — the
+    continuous pump — and the primitive under ``annotate``.
+    ``start_us`` is on the now_micros() clock, so a caller converting
+    from perf_counter takes ONE ``now_micros() - perf_counter`` offset
+    per batch of spans and ``advance_for_tests`` ages them like any
+    other span.  ``name`` must be a SPAN_NAMES literal (lint:
+    span-registry)."""
+    s = Span(name, trace_id, parent_id, tags)
+    s.start_us = int(start_us)
+    s.duration_us = int(duration_us)
+    _record(s.to_wire())
+    return s.span_id
+
+
 def annotate(name: str, **tags) -> None:
     """Best-effort tag drop on the thread's ACTIVE span context — used
     by layers that don't own a span object (fault injection).  The tags
@@ -443,9 +494,7 @@ def annotate(name: str, **tags) -> None:
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
         return
-    s = Span(name, ctx[0], ctx[1], tags)
-    s.start_us = now_micros()
-    _record(s.to_wire())
+    emit(name, ctx[0], ctx[1], now_micros(), 0, **tags)
 
 
 # ------------------------------------------- critical-path analyzer

@@ -81,6 +81,7 @@ from ..common import flight
 from ..common import mc_hooks
 from ..common import protocol
 from ..common import tracing
+from ..common.clock import now_micros
 from ..common.deadline import DeadlineExceeded
 from ..common.events import journal
 from ..common.flags import flags
@@ -415,6 +416,13 @@ class _LaneLedger:
 # servers that touch many spaces.
 CONTINUOUS_IDLE_RELEASE_S = 30.0
 
+# the pump's own trace (docs/observability.md "The pump trace"): a
+# stretch between two ticks shorter than this is loop overhead, not an
+# idle gap, and gets no pump.idle span; a pump.tick names at most this
+# many of the trace ids it touched
+PUMP_IDLE_SPAN_MIN_US = 1000
+PUMP_TICK_RIDER_TAGS = 8
+
 
 class ContinuousUnavailable(Exception):
     """The stream could not anchor a device session for this space
@@ -439,7 +447,8 @@ class _Rider:
     reads result/error after ``done`` flips."""
 
     __slots__ = ("payload", "steps", "upto", "reduce", "deadline",
-                 "tctx", "enq_t", "lane", "remaining", "joined_tick",
+                 "tctx", "enq_t", "seated_t", "left_t", "done_t",
+                 "lane", "remaining", "joined_tick", "left_tick",
                  "midflight", "done", "result", "mirror", "error",
                  "qid")
 
@@ -456,9 +465,14 @@ class _Rider:
         # a windowed batch leader's would
         self.tctx = tracing.capture()
         self.enq_t = time.perf_counter()
+        # perf_counter stamps the PUMP writes as the rider moves on:
+        # seated, left the seat map, result (or error) published.
+        # submit() turns them into the marker's four waits
+        self.seated_t = self.left_t = self.done_t = 0.0
         self.lane = -1
         self.remaining = 0
         self.joined_tick = -1
+        self.left_tick = -1
         self.midflight = False
         self.done = False
         self.result = None
@@ -517,6 +531,9 @@ class _ContinuousStream:
         # pump-only: perf_counter stamp of the previous tick's end —
         # the flight recorder's idle-gap column (common/flight.py)
         self._last_tick_end = 0.0       # nebulint: guarded-by=none
+        # pump-only: the pump slept for want of work since the last
+        # tick — the ``why`` of that tick's pump.idle span
+        self._saw_no_work = False       # nebulint: guarded-by=none
         self._pump_thread = threading.Thread(
             target=self._pump, daemon=True,
             name=f"continuous-go-{space_id}")
@@ -555,6 +572,8 @@ class _ContinuousStream:
                             self.retired = True
                             self.stopping = True
                     continue
+                # pump-thread-only state (see __init__)
+                self._saw_no_work = True  # nebulint: disable=lock-discipline
                 with self.cond:
                     if not self.queue and not self.seated \
                             and not self.stopping:
@@ -703,7 +722,11 @@ class _ContinuousStream:
         # — one column of the flight-recorder tick record
         idle_us = (t0 - self._last_tick_end) * 1e6 \
             if self._last_tick_end else 0.0
+        saw_no_work = self._saw_no_work
+        # pump-thread-only state (see __init__)
+        self._saw_no_work = False  # nebulint: disable=lock-discipline
         with self.cond:
+            was_draining = self.draining
             # riders present BEFORE this tick's generation check are
             # seatable this tick; later arrivals wait for the next
             # tick's _anchor so a query admitted after generation g
@@ -741,6 +764,7 @@ class _ContinuousStream:
                 # previously seated riders — co-arrivals pooling into
                 # a fresh batch this same tick are the windowed case
                 was_running = bool(self.seated)
+                t_seated = time.perf_counter()
                 while self.queue and n_eligible > 0 \
                         and self.ledger.free_count() > 0:
                     n_eligible -= 1
@@ -762,6 +786,7 @@ class _ContinuousStream:
                     r.lane = self.ledger.alloc()
                     r.remaining = r.steps - 1
                     r.joined_tick = self.tick_no
+                    r.seated_t = t_seated
                     r.midflight = was_running
                     self.seated[r.lane] = r
                     joiners.append(r)
@@ -774,6 +799,8 @@ class _ContinuousStream:
                 if (r.deadline is not None and r.deadline.expired()) \
                         or query_registry.is_killed(r.qid):
                     del self.seated[lane]
+                    r.left_t = time.perf_counter()
+                    r.left_tick = self.tick_no
                     evicted.append(r)
             # a KILLed rider still waiting for a lane must not sit out
             # a full seat map it will never use — end it this tick too
@@ -807,6 +834,9 @@ class _ContinuousStream:
                 # pump-thread-only state (see __init__)
                 self._widen = True  # nebulint: disable=lock-discipline
                 self._widen_min = width + 1  # nebulint: disable=lock-discipline
+        # end of the seating block (anchor + seat-map bookkeeping):
+        # the tick record's seat_us, the trace's pump.seat
+        t_seat = time.perf_counter()
 
         new_pending = None
         leavers: List[_Rider] = []
@@ -848,7 +878,8 @@ class _ContinuousStream:
                         if has_work:
                             th = time.perf_counter()
                             sess.hop()
-                            hop_us = (time.perf_counter() - th) * 1e6
+                            t_left = time.perf_counter()
+                            hop_us = (t_left - th) * 1e6
                             with self.cond:
                                 self.tick_no += 1
                                 for lane, r in \
@@ -859,6 +890,8 @@ class _ContinuousStream:
                                         r.steps - 1 - r.remaining)
                                     if r.remaining <= 0:
                                         del self.seated[lane]
+                                        r.left_t = t_left
+                                        r.left_tick = self.tick_no
                                         leavers.append(r)
                     if leavers:
                         tx = time.perf_counter()
@@ -910,6 +943,7 @@ class _ContinuousStream:
             stats.add_value("graph.continuous.evictions",
                             len(evicted))
             with self.cond:
+                t_done = time.perf_counter()
                 for r in evicted:
                     if query_registry.is_killed(r.qid):
                         r.error = KilledError(
@@ -919,16 +953,18 @@ class _ContinuousStream:
                         r.error = DeadlineExceeded(
                             "go: deadline expired mid-flight (evicted "
                             "at a hop boundary)")
+                    r.done_t = t_done
                     r.done = True
                 self.cond.notify_all()
 
         # hop k's work is on the device; assemble hop k-1's leavers
-        # NOW — host post-processing overlaps device compute
-        assemble_us = 0.0
+        # NOW — host post-processing overlaps device compute.  Each
+        # _finish returns its stamps; the tick record's assemble_us is
+        # the sum of their parts
+        finishes = []
+        pending_leavers = pending[1] if pending is not None else []
         if pending is not None:
-            ta = time.perf_counter()
-            self._finish(pending)
-            assemble_us = (time.perf_counter() - ta) * 1e6
+            finishes.append(self._finish(pending))
         # nothing left in flight: the cohort just produced has no hop
         # to hide behind — flush it immediately rather than letting it
         # age one idle-poll interval
@@ -936,11 +972,17 @@ class _ContinuousStream:
             with self.cond:
                 empty = not self.seated and not self.queue
             if empty:
-                ta = time.perf_counter()
-                self._finish(new_pending)
-                assemble_us += (time.perf_counter() - ta) * 1e6
+                finishes.append(self._finish(new_pending))
                 new_pending = None
-        dur = time.perf_counter() - t0
+        t_end = time.perf_counter()
+        dur = t_end - t0
+        # a handover runs to where the pump stamps next (the flush's
+        # start, or the tick's end): the parts then tile the tick's
+        # tail, and a pump that lost the interpreter between two stamps
+        # is charged to a part instead of to nothing
+        handed = [stamps[0] for stamps, _n in finishes[1:]] + [t_end]
+        finishes = [(stamps + (t_hand,), n)
+                    for (stamps, n), t_hand in zip(finishes, handed)]
         with self.cond:
             self.hop_ema_s = dur if self.hop_ema_s == 0.0 \
                 else 0.7 * self.hop_ema_s + 0.3 * dur
@@ -949,52 +991,142 @@ class _ContinuousStream:
         # pump-thread-only state (see __init__)
         self._last_tick_end = time.perf_counter()  # nebulint: disable=lock-discipline
         if busy:
+            parts = [0] * 5     # fetch_wait, d2h, unpack, rows, handover
+            for stamps, _n in finishes:
+                for i in range(5):
+                    parts[i] += int((stamps[i + 1] - stamps[i]) * 1e6)
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
                 leaves=len(leavers), evictions=len(evicted),
+                seat_us=int((t_seat - t0) * 1e6),
                 join_us=int(join_us), hop_us=int(hop_us),
                 extract_us=int(extract_us), clear_us=int(clear_us),
-                assemble_us=int(assemble_us), idle_us=int(idle_us),
+                fetch_wait_us=parts[0], d2h_us=parts[1],
+                unpack_us=parts[2], rows_us=parts[3],
+                handover_us=parts[4], assemble_us=sum(parts),
+                leaver_rows=sum(n for _stamps, n in finishes),
+                idle_us=int(idle_us),
                 dur_us=int(dur * 1e6),
                 generation=int(getattr(getattr(sess, "m", None),
                                        "generation", -1)))
             # advance every touched rider's slow-log timeline anchor
             # (first note pins the window start —
-            # query_registry.note_timeline)
-            for r in joiners + leavers + evicted:
+            # query_registry.note_timeline), and collect the trace ids
+            # of those that are traced: the tick is traced iff it
+            # touched one
+            touched = joiners + leavers + evicted + seated_riders
+            for r in touched:
                 query_registry.note_timeline(r.qid, rec_id)
-            for r in seated_riders:
-                query_registry.note_timeline(r.qid, rec_id)
+            riders = list(dict.fromkeys(
+                r.tctx[0][0] for r in touched + pending_leavers
+                if r.tctx is not None))
+            if riders:
+                why = ("no_work" if saw_no_work else
+                       "drain" if was_draining else
+                       "tick_delay" if self.tick_delay_s > 0 else
+                       "loop")
+                self._emit_pump_trace(
+                    riders, (t0, t_seat, t_end), finishes, idle_us, why,
+                    tick=tick_done, rec=rec_id, seats=occupancy,
+                    joins=len(joiners), leaves=len(leavers))
         return new_pending
 
-    def _finish(self, pending) -> None:
+    def _emit_pump_trace(self, riders: List[int], tick_stamps,
+                         finishes, idle_us: float, why: str,
+                         **tags) -> None:
+        """The tick just ended, as a trace of its own, post hoc from
+        the perf_counter stamps the tick took anyway: root pump.tick,
+        children that tile it in pump order (they lie inside it and do
+        not overlap, by construction: consecutive stamps on one
+        clock), and a root pump.idle over the stretch since the
+        previous tick ended when that is >= PUMP_IDLE_SPAN_MIN_US
+        (``why``: no_work — the pump slept for want of riders; drain /
+        tick_delay — a generation change / the test hook held it; loop
+        — it was between two ticks: recording, the condition, the
+        interpreter lock).  ``finishes`` holds, per finished cohort,
+        _finish's stamps plus the one its handover ran to, and its
+        rows.  Only called for a tick that touched a traced rider."""
+        t0, t_seat, t_end = tick_stamps
+        # ONE wall-minus-perf offset for the whole tick: the spans land
+        # on the now_micros() clock every other span uses
+        off = now_micros() - time.perf_counter() * 1e6
+
+        def us(t: float) -> int:
+            return int(t * 1e6 + off)
+
+        tid = tracing.new_trace_id()
+        root = tracing.emit(
+            "pump.tick", tid, None, us(t0), us(t_end) - us(t0),
+            stream=self.space_id,
+            riders=[f"{r:016x}" for r in riders[:PUMP_TICK_RIDER_TAGS]],
+            **tags)
+        tracing.emit("pump.seat", tid, root, us(t0),
+                     us(t_seat) - us(t0))
+        t_enq = finishes[0][0][0] if finishes else t_end   # first ta
+        tracing.emit("pump.enqueue", tid, root, us(t_seat),
+                     us(t_enq) - us(t_seat))
+        for (ta, t_wait, t_d2h, t_unpack, t_rows, t_hand), n in finishes:
+            tracing.emit("pump.fetch_wait", tid, root, us(ta),
+                         us(t_wait) - us(ta))
+            tracing.emit("pump.d2h", tid, root, us(t_wait),
+                         us(t_d2h) - us(t_wait))
+            tracing.emit("pump.unpack", tid, root, us(t_d2h),
+                         us(t_unpack) - us(t_d2h))
+            tracing.emit("pump.rows", tid, root, us(t_unpack),
+                         us(t_rows) - us(t_unpack), rows=n)
+            tracing.emit("pump.handover", tid, root, us(t_rows),
+                         us(t_hand) - us(t_rows))
+        if idle_us >= PUMP_IDLE_SPAN_MIN_US:
+            tracing.emit("pump.idle", tid, None, us(t0) - int(idle_us),
+                         int(idle_us), stream=self.space_id, why=why)
+
+    def _finish(self, pending) -> Tuple:
         """Force the leave cohort's extraction fetch, run the same
         grouped assembly the windowed leader uses, wake the waiters.
         Per-query failures stay per-query (Exception entries); a
-        cohort-level failure wakes every cohort member with it."""
+        cohort-level failure wakes every cohort member with it.
+
+        Returns (the stamps that split this stretch of the pump's
+        time: start, fetch_wait end, d2h end, unpack end, rows end;
+        the result rows handed over).  The handover ends where the
+        caller stamps next."""
         resolver, leavers, m = pending
         rt = self.sched.runtime
+        ta = time.perf_counter()
+        t_unpack = 0.0
         try:
             # fetch + assembly spans land on the first leaver's trace
             with tracing.attach_captured(leavers[0].tctx):
                 vs_lists = resolver()
+                t_unpack = time.perf_counter()
                 results = rt.continuous_results(
                     self.space_id, m, [r.payload for r in leavers],
                     [r.reduce for r in leavers], vs_lists,
                     self.et_tuple)
         except Exception as ex:         # noqa: BLE001 — cohort-level
             results = [ex] * len(leavers)
+        t_rows = time.perf_counter()
+        # a resolver that failed (or a test's stand-in) has no stamps:
+        # its whole stretch reads as the part it died in
+        t_unpack = t_unpack or t_rows
+        t_wait = getattr(resolver, "t_wait", 0.0) or t_unpack
+        t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
         stats.add_value("graph.continuous.leaves", len(leavers))
+        n_rows = 0
         with self.cond:
+            t_done = time.perf_counter()
             for r, out in zip(leavers, results):
                 if isinstance(out, Exception):
                     r.error = out
                 else:
                     r.result = out
                     r.mirror = m
+                    n_rows += len(out[1])
+                r.done_t = t_done
                 r.done = True
             self.cond.notify_all()
+        return (ta, t_wait, t_d2h, t_unpack, t_rows), n_rows
 
     # ------------------------------------------------------- submit
     def submit(self, key: Tuple, payload, steps: int, upto: bool,
@@ -1074,10 +1206,13 @@ class _ContinuousStream:
                         disp._note_deadline_drop(key)
                         break
         # the seat trajectory lands on the WAITER's own trace: a
-        # PROFILE of the query shows its lane, join tick, whether it
-        # merged into an already-running batch, and HOW its wait ended
-        # — one of protocol's closed "continuous-ending" kinds, the
+        # PROFILE of the query shows its lane, join and leave tick,
+        # whether it merged into an already-running batch, the four
+        # waits its time here was made of, and HOW its wait ended —
+        # one of protocol's closed "continuous-ending" kinds, the
         # vocabulary the eviction dashboards key on
+        waits = self._waits(rider, time.perf_counter())
+        query_registry.note_waits(rider.qid, rider.left_tick, waits)
         if rider.error is not None:
             if isinstance(rider.error, ContinuousUnavailable):
                 ending = protocol.END_BOUNCED
@@ -1091,19 +1226,40 @@ class _ContinuousStream:
             query_registry.note_ending(rider.qid, ending)
             tracing.annotate("graph.continuous", lane=rider.lane,
                              joined_tick=rider.joined_tick,
-                             ending=ending)
+                             left_tick=rider.left_tick,
+                             ending=ending, **waits)
             raise rider.error
         query_registry.note_ending(rider.qid, protocol.END_LEFT)
         tracing.annotate("graph.continuous", lane=rider.lane,
                          joined_tick=rider.joined_tick,
+                         left_tick=rider.left_tick,
                          hops=rider.steps - 1,
                          midflight=rider.midflight,
-                         ending=protocol.END_LEFT)
+                         ending=protocol.END_LEFT, **waits)
         with self.sched.dispatcher._lock:
             self.sched.dispatcher.stats["continuous_queries"] = \
                 self.sched.dispatcher.stats.get("continuous_queries",
                                                 0) + 1
         return rider.result, rider.mirror
+
+    @staticmethod
+    def _waits(rider: _Rider, t_wake: float) -> Dict[str, int]:
+        """The rider's time in submit(), split where the pump stamped
+        it: queued until seated, riding until it left the seat map,
+        waiting for its cohort's fetch + assembly, and from the result
+        published to this thread running again.  A rider that left
+        normally has all four and they sum to enq_t -> t_wake; one
+        that ended early has the waits it got as far as."""
+        out: Dict[str, int] = {}
+        t = rider.enq_t
+        for key, stamp in zip(tracing.RIDER_WAITS,
+                              (rider.seated_t, rider.left_t,
+                               rider.done_t, t_wake)):
+            if not stamp:
+                break
+            out[key] = int((stamp - t) * 1e6)
+            t = stamp
+        return out
 
     # ------------------------------------------------------ control
     def stop(self, timeout_s: float = 10.0) -> None:

@@ -99,8 +99,8 @@ def current() -> Optional[int]:
 class _Entry:
     __slots__ = ("qid", "session", "user", "stmt", "cls", "space",
                  "mode", "phase", "hop", "lane", "joined_tick",
-                 "ending", "tl_first", "tl_last", "start_us",
-                 "deadline", "kill_flag")
+                 "left_tick", "waits", "ending", "tl_first", "tl_last",
+                 "start_us", "deadline", "kill_flag")
 
     def __init__(self, qid, session, user, stmt, cls, space, mode,
                  dl):
@@ -115,6 +115,8 @@ class _Entry:
         self.hop = -1
         self.lane = -1
         self.joined_tick = -1
+        self.left_tick = -1
+        self.waits = None         # seat_wait/ride/result_wait/wake us
         self.ending = None        # protocol continuous-ending, once done
         self.tl_first = -1        # first/last flight-recorder tick id
         self.tl_last = -1         # for the rider's stream (flight.py)
@@ -200,6 +202,15 @@ class QueryRegistry:
         if e is not None:
             e.ending = ending
 
+    def note_waits(self, qid: Optional[int], left_tick: int,
+                   waits: dict) -> None:
+        """The waits a rider's submit() was made of (batch_dispatch
+        _ContinuousStream._waits) and the tick it left on."""
+        e = self._entries.get(qid) if qid is not None else None
+        if e is not None:
+            e.left_tick = left_tick
+            e.waits = waits
+
     def note_timeline(self, qid: Optional[int], rec_id: int) -> None:
         """Anchor the rider's stream to a flight-recorder tick id
         (common/flight.py): the first note pins tl_first, every note
@@ -213,8 +224,10 @@ class QueryRegistry:
 
     def seat_markers(self, qid: Optional[int]) -> Optional[dict]:
         """The continuous-tier seat trajectory of a still-registered
-        statement — lane, joined_tick, hop count, typed ending, and
-        the [first, last] recorder tick-id window — or None when it
+        statement — lane, joined_tick, left_tick, hop count, typed
+        ending, the four waits (seat_wait_us / ride_us /
+        result_wait_us / wake_us) and the [first, last] recorder
+        tick-id window — or None when it
         never rode a lane batch.  The engine folds this into
         slow-query-log entries before unregistering."""
         e = self._entries.get(qid) if qid is not None else None
@@ -222,6 +235,9 @@ class QueryRegistry:
             return None
         out = {"lane": e.lane, "joined_tick": e.joined_tick,
                "hops": e.hop, "ending": e.ending}
+        if e.waits is not None:     # submit() returned: note_waits
+            out["left_tick"] = e.left_tick
+            out.update(e.waits)
         if e.tl_first >= 0:
             out["timeline"] = [e.tl_first, e.tl_last]
         return out

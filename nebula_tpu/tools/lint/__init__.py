@@ -17,7 +17,8 @@ whole-package checks gated as a tier-1 test (tests/test_lint.py):
                     frontier loops (tpu/runtime.py, tpu/kernels.py,
                     graph/executors/)
   flag-registry     flags.get("x") without a define(), and dead defines
-  span-registry     tracing.span()/start_trace() names must be literal
+  span-registry     tracing.span()/start_trace()/annotate()/emit() names
+                    must be literal
                     dotted strings from the single SPAN_NAMES registry
                     (common/tracing.py), with dead entries flagged
   metric-registry   StatsManager names (add_value/observe/set_gauge/

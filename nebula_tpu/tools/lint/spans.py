@@ -1,5 +1,5 @@
 """span-registry — every ``tracing.span("...")`` / ``start_trace("...")``
-/ ``annotate("...")`` uses a LITERAL dotted name from the single
+/ ``annotate("...")`` / ``emit("...")`` uses a LITERAL dotted name from the single
 ``SPAN_NAMES`` registry (common/tracing.py), and no dead registry
 entries remain.
 
@@ -16,7 +16,7 @@ from typing import List, Optional, Tuple
 from .core import PackageContext, Violation, dotted, enclosing_symbol, \
     qualname_map
 
-_CALLS = ("span", "start_trace", "annotate")
+_CALLS = ("span", "start_trace", "annotate", "emit")
 
 
 def _literal(node: ast.AST) -> Optional[str]:

@@ -555,15 +555,22 @@ def _bucket_expand_packed(jnp, jax, fp, nbr, et, etypes):
 def _hop_body_packed(jnp, jax, n: int, n_extras: int,
                      etypes: Tuple[int, ...], nbr_dev, et_dev,
                      eslot, hrows, fp):
-    """One packed frontier advance: fp [n_rows+1, W] uint8 -> same."""
-    outs = [_bucket_expand_packed(jnp, jax, fp, nbr, et, etypes)
-            for nbr, et in zip(nbr_dev, et_dev)]
+    """One packed frontier advance: fp [n_rows+1, W] uint8 -> same.
+    Each bucket's expansion and the hub merge sit in a named scope, so
+    a device trace's op names say which bucket a loop or fusion is
+    (``hop/bucket_w<D>``, ``hop/hub_merge`` in the HLO op_name)."""
+    outs = []
+    for nbr, et in zip(nbr_dev, et_dev):
+        with jax.named_scope(f"hop/bucket_w{nbr.shape[1]}"):
+            outs.append(_bucket_expand_packed(jnp, jax, fp, nbr, et,
+                                              etypes))
     if not outs:
         return jnp.zeros_like(fp)
     nxt = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
     if n_extras:
         extras = nxt[n:]
-        nxt = _scatter_or_rows(jnp, nxt, extras, eslot, hrows)
+        with jax.named_scope("hop/hub_merge"):
+            nxt = _scatter_or_rows(jnp, nxt, extras, eslot, hrows)
     pad = jnp.zeros((1, fp.shape[1]), dtype=jnp.uint8)
     return jnp.concatenate([nxt, pad], axis=0)
 
@@ -700,10 +707,11 @@ def make_lane_join_kernel(ell: EllIndex, donate: bool = True):
     pad_row = ell.n_rows
 
     def join(fp, accp, rows, words, vals):
-        fp = fp.at[rows, words].add(vals)
-        fp = fp.at[pad_row, :].set(0)
-        accp = accp.at[rows, words].add(vals)
-        accp = accp.at[pad_row, :].set(0)
+        with jax.named_scope("lane/join"):
+            fp = fp.at[rows, words].add(vals)
+            fp = fp.at[pad_row, :].set(0)
+            accp = accp.at[rows, words].add(vals)
+            accp = accp.at[pad_row, :].set(0)
         return fp, accp
 
     return jax.jit(join, donate_argnums=(0, 1) if donate else ())
@@ -717,7 +725,8 @@ def make_lane_clear_kernel(donate: bool = True):
     import jax
 
     def clear(fp, accp, keep):
-        return fp & keep[None, :], accp & keep[None, :]
+        with jax.named_scope("lane/clear"):
+            return fp & keep[None, :], accp & keep[None, :]
 
     return jax.jit(clear, donate_argnums=(0, 1) if donate else ())
 
@@ -735,9 +744,10 @@ def make_lane_extract_kernel():
     import jax.numpy as jnp
 
     def extract(fp, accp, words, sel):
-        fg = jnp.take(fp, words, axis=1)         # [R1, P]
-        ag = jnp.take(accp, words, axis=1)
-        return jnp.where(sel[None, :] != 0, ag, fg)
+        with jax.named_scope("lane/extract"):
+            fg = jnp.take(fp, words, axis=1)     # [R1, P]
+            ag = jnp.take(accp, words, axis=1)
+            return jnp.where(sel[None, :] != 0, ag, fg)
 
     return jax.jit(extract)
 

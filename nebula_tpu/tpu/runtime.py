@@ -201,8 +201,11 @@ flags.define(
     "achieved-GB/s gauge, BASELINE.md roofline columns) AND the "
     "flight-recorder kernel-timing rows that feed the live-vs-"
     "declared HBM drift fold (common/flight.py, docs/observability.md "
-    "'The device timeline').  0 disables sampling (no serialization "
-    "of the dispatch pipeline at all, and no timing rows)")
+    "'The device timeline').  Windowed dispatch only: the continuous "
+    "stream is never blocked by this probe, its device wait is the "
+    "tick record's fetch_wait_us, read on every leaving tick.  0 "
+    "disables sampling (no serialization of the dispatch pipeline at "
+    "all, and no timing rows)")
 flags.define(
     "tpu_adaptive_single", True,
     "single-query GO runs the adaptive sparse-frontier kernel "
@@ -3735,7 +3738,7 @@ class _ContinuousGoSession:
     def hop(self) -> None:
         """Advance every seated lane one hop; the UPTO accumulator
         unions the new frontier (exact-depth lanes never read it)."""
-        from .ell import dense_hop_bytes, make_continuous_hop_kernel
+        from .ell import make_continuous_hop_kernel
         kern = self.rt._kernel(
             ("ell_go_hop", self.ix.shape_sig(), self.et_tuple),
             lambda: make_continuous_hop_kernel(self.ix, self.et_tuple,
@@ -3745,15 +3748,18 @@ class _ContinuousGoSession:
             self.fp, self.accp = kern(self.fp, self.accp, self.eslot,
                                       self.hrows, *self._tables)
         self.hops += 1
-        self.rt._maybe_time_device(
-            self.fp, dense_hop_bytes(self.ix, self.W, 2),
-            kind="ell_go_hop")
+        # no tpu_device_timing_every probe here: blocking on the hop
+        # before the pending cohort is assembled gives up the overlap
+        # the stream exists for.  The stream's device wait is read
+        # where the host blocks anyway — _LaneFetch.t_wait, the tick
+        # record's fetch_wait_us (graph/batch_dispatch.py)
 
     def extract(self, leavers):
         """Slice the leaving lanes' word columns (UPTO lanes read the
-        accumulator) and return a zero-arg resolver -> per-leaver
-        ascending old-dense-id frontier arrays.  The resolver is where
-        the d2h fetch forces — call it AFTER enqueueing the next hop
+        accumulator) and return a zero-arg resolver (_LaneFetch) ->
+        per-leaver ascending old-dense-id frontier arrays.  The
+        resolver is where the d2h fetch forces — call it AFTER
+        enqueueing the next hop
         so the host assembly overlaps the device compute."""
         from .ell import make_lane_extract_kernel
         pair_ix: Dict[Tuple[int, bool], int] = {}
@@ -3774,19 +3780,8 @@ class _ContinuousGoSession:
             out_dev = kern(self.fp, self.accp, words_p, sel_p)
         cols_of = [pair_ix[(lane >> 3, bool(upto))]
                    for lane, upto in leavers]
-
-        def resolve():
-            with tracing.span("tpu.fetch"):
-                cols = np.asarray(out_dev)          # [R1, P] uint8
-            self.rt._note_fetch(cols[:, :np_pairs])
-            outs = []
-            for (lane, _upto), j in zip(leavers, cols_of):
-                bit = (cols[:, j] >> (lane & 7)) & np.uint8(1)
-                old = bit[self.ix.perm]             # old dense order
-                outs.append(np.nonzero(old)[0].astype(np.int64))
-            return outs
-
-        return resolve
+        return _LaneFetch(self.rt, self.ix.perm, out_dev, leavers,
+                          cols_of, np_pairs)
 
     def clear(self, lanes) -> None:
         """Zero the freed lanes' bits in both carriers — the seat-map
@@ -3803,6 +3798,43 @@ class _ContinuousGoSession:
         with tracing.span("tpu.kernel", kind="ell_lane_clear",
                           width=self.B):
             self.fp, self.accp = kern(self.fp, self.accp, keep)
+
+
+class _LaneFetch:
+    """Zero-arg resolver of one leave cohort's lane extraction ->
+    per-leaver ascending old-dense-id frontier arrays.  It stamps
+    perf_counter where the device wait ends (``t_wait``) and where the
+    copy ends (``t_d2h``) — the pump reads both into the tick record
+    and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
+    still wraps wait + copy, as the windowed resolvers' does."""
+
+    __slots__ = ("rt", "perm", "out_dev", "leavers", "cols_of",
+                 "np_pairs", "t_wait", "t_d2h")
+
+    def __init__(self, rt, perm, out_dev, leavers, cols_of, np_pairs):
+        self.rt = rt
+        self.perm = perm
+        self.out_dev = out_dev
+        self.leavers = leavers
+        self.cols_of = cols_of
+        self.np_pairs = np_pairs
+        self.t_wait = self.t_d2h = 0.0
+
+    def __call__(self):
+        import time
+        with tracing.span("tpu.fetch"):
+            # the only point the pump waits on the device
+            self.out_dev.block_until_ready()
+            self.t_wait = time.perf_counter()
+            cols = np.asarray(self.out_dev)         # [R1, P] uint8
+            self.t_d2h = time.perf_counter()
+        self.rt._note_fetch(cols[:, :self.np_pairs])
+        outs = []
+        for (lane, _upto), j in zip(self.leavers, self.cols_of):
+            bit = (cols[:, j] >> (lane & 7)) & np.uint8(1)
+            old = bit[self.perm]                    # old dense order
+            outs.append(np.nonzero(old)[0].astype(np.int64))
+        return outs
 
 
 # ================================================== path reconstruction

@@ -229,7 +229,8 @@ class TestDriftFold:
 # ============================================== chrome_trace + golden
 def _golden_inputs():
     """Fixed inputs for the byte-stable pin: one host span tree with a
-    seat marker, one tick with all five pump phases, one sharded
+    seat marker (with the four waits), one tick with all ten pump
+    phases (assemble_us is the sum of its five parts), one sharded
     dispatch, one timing probe, one second-stream tick."""
     tree = {
         "trace_id": "00000000deadbeef",
@@ -245,13 +246,17 @@ def _golden_inputs():
             ],
         }],
     }
-    seat = {"lane": 3, "joined_tick": 17, "hops": 2,
-            "ending": "left-batch", "timeline": [41, 44]}
+    seat = {"lane": 3, "joined_tick": 17, "left_tick": 19, "hops": 2,
+            "ending": "left-batch", "timeline": [41, 44],
+            "seat_wait_us": 120, "ride_us": 410, "result_wait_us": 90,
+            "wake_us": 30}
     ticks = [
         {"kind": "tick", "stream": 0, "id": 41, "time_us": 1400,
-         "dur_us": 260, "join_us": 20, "hop_us": 180, "extract_us": 30,
-         "clear_us": 10, "assemble_us": 20, "seats": 2, "joins": 1,
-         "leaves": 0, "evictions": 0, "generation": 5},
+         "dur_us": 360, "seat_us": 15, "join_us": 20, "hop_us": 180,
+         "extract_us": 30, "clear_us": 10, "fetch_wait_us": 60,
+         "d2h_us": 5, "unpack_us": 12, "rows_us": 18, "handover_us": 5,
+         "assemble_us": 100, "leaver_rows": 7, "seats": 2, "joins": 1,
+         "leaves": 1, "evictions": 0, "generation": 5},
         {"kind": "dispatch", "kernel": "ell_go_sharded", "id": 42,
          "time_us": 1500, "k": 8, "rung": 1024, "steps": 3,
          "ici_bytes": 917504, "ici_declared": 1048576,
@@ -259,8 +264,10 @@ def _golden_inputs():
         {"kind": "timing", "op": "ell_go", "id": 43, "time_us": 1700,
          "wall_us": 120.0, "bytes": 4096, "gbps": 0.034},
         {"kind": "tick", "stream": 1, "id": 44, "time_us": 1900,
-         "dur_us": 150, "join_us": 0, "hop_us": 120, "extract_us": 20,
-         "clear_us": 0, "assemble_us": 10, "seats": 1},
+         "dur_us": 150, "seat_us": 4, "join_us": 0, "hop_us": 120,
+         "extract_us": 20, "clear_us": 0, "fetch_wait_us": 0,
+         "d2h_us": 0, "unpack_us": 0, "rows_us": 0, "handover_us": 0,
+         "assemble_us": 0, "leaver_rows": 0, "seats": 1},
     ]
     return tree, ticks, seat
 
@@ -269,7 +276,7 @@ class TestChromeTrace:
     def test_golden_is_byte_stable(self):
         """chrome_trace is a PURE function — same inputs, byte-identical
         JSON.  A diff here is a trace-schema change: regenerate with
-        `python tests/test_flight.py` and eyeball the golden in
+        `PYTHONPATH=. python tests/test_flight.py` and eyeball the golden in
         chrome://tracing before committing (ci.sh ships it as an
         artifact beside the SARIF files)."""
         tree, ticks, seat = _golden_inputs()
@@ -288,7 +295,8 @@ class TestChromeTrace:
         meta = {(e["pid"], e["args"]["name"]) for e in ev
                 if e["ph"] == "M" and e["name"] == "process_name"}
         assert (1, "host spans") in meta
-        assert (2, "nebulaprof device flight recorder") in meta
+        # process 2 is the PUMP's host timeline, not a device's
+        assert (2, "pump flight recorder (host)") in meta
         # every span in the tree renders as a host "X" slice
         host = {e["name"] for e in ev
                 if e["ph"] == "X" and e["pid"] == 1}
@@ -301,11 +309,15 @@ class TestChromeTrace:
         tick_ev = [e for e in ev if e.get("cat") == "tick"]
         assert len(tick_ev) == 2
         t0 = tick_ev[0]
-        assert t0["ts"] == 1400 - 260 and t0["dur"] == 260
+        assert t0["ts"] == 1400 - 360 and t0["dur"] == 360
         phases = [e for e in ev if e.get("cat") == "phase"
                   and e["tid"] == t0["tid"]]
         assert [p["name"] for p in phases] == \
-            ["join", "hop", "extract", "clear", "assemble"]
+            ["seat", "join", "hop", "extract", "clear", "fetch_wait",
+             "d2h", "unpack", "rows", "handover"]
+        # the five parts of assemble_us render in its place
+        assert sum(p["dur"] for p in phases[5:]) == \
+            t0["args"]["assemble_us"]
         # phases tile the tick start-to-busy, in pump order
         assert phases[0]["ts"] == t0["ts"]
         for a, b in zip(phases, phases[1:]):
@@ -476,7 +488,7 @@ class TestSlowRiderTimelineAnchor:
 
 if __name__ == "__main__":
     # regenerate the golden after a DELIBERATE trace-schema change:
-    #   python tests/test_flight.py
+    #   PYTHONPATH=. python tests/test_flight.py
     tree, ticks, seat = _golden_inputs()
     GOLDEN.write_text(json.dumps(
         flight.chrome_trace(tree=tree, ticks=ticks, seat=seat),

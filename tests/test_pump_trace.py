@@ -1,0 +1,516 @@
+"""The continuous pump timed from inside (docs/observability.md "The
+pump trace"): one set of perf_counter stamps per tick feeds
+
+  * the flight recorder's tick record (always on): ``seat_us`` and the
+    five parts of ``assemble_us``;
+  * a ``pump.tick`` trace of its own (only for ticks that touched a
+    traced rider), emitted post hoc from those stamps, with the idle
+    stretch before it as ``pump.idle``;
+  * each rider's ``graph.continuous`` marker: the four waits its
+    ``submit()`` was made of.
+
+And the instrument that measured by stopping the pump is off it: the
+continuous hop never blocks on the device and writes no ``timing`` row.
+"""
+import json
+import re
+import threading
+import time
+import tracemalloc
+import urllib.request
+
+import numpy as np
+import pytest
+
+from nebula_tpu.common import clock, flight, tracing
+from nebula_tpu.common.flags import flags
+from nebula_tpu.common.tracing import slow_log, trace_store
+from nebula_tpu.graph import batch_dispatch as bd
+
+from test_continuous import _boot_graph
+
+PARTS = ("fetch_wait_us", "d2h_us", "unpack_us", "rows_us",
+         "handover_us")
+WAITS = tracing.RIDER_WAITS
+CHILDREN = {"pump.seat", "pump.enqueue", "pump.fetch_wait", "pump.d2h",
+            "pump.unpack", "pump.rows", "pump.handover"}
+
+
+@pytest.fixture(scope="module")
+def graph():
+    flags.set("go_dispatch_mode", "continuous")
+    c, g, ok = _boot_graph(seed=24)
+    ok("GO 2 STEPS FROM 1 OVER e")          # stream anchored, compiled
+    ok("GO 3 STEPS FROM 1 OVER e YIELD e._dst")
+    yield c, g, ok
+    c.stop()
+
+
+@pytest.fixture(autouse=True)
+def _clean(graph):
+    _settle(graph[0])
+    saved = flags.get("trace_sample_rate")
+    trace_store.clear_for_tests()
+    flight.recorder.clear_for_tests()
+    yield
+    flags.set("trace_sample_rate", saved)
+    clock.reset_for_tests()
+    trace_store.clear_for_tests()
+
+
+def _settle(c, timeout_s=5.0):
+    """Wait until nothing is queued or seated on any stream."""
+    d = c.tpu_runtime.dispatcher
+    end = time.monotonic() + timeout_s
+    while time.monotonic() < end \
+            and d.continuous.seat_counts() != (0, 0):
+        time.sleep(0.01)
+    time.sleep(0.05)        # the last tick's record and trace land
+
+
+def _burst(c, statements):
+    """Run the statements concurrently; returns their responses."""
+    out, errors = {}, []
+    barrier = threading.Barrier(len(statements))
+
+    def worker(i):
+        try:
+            g2 = c.client()
+            g2.execute("USE s")
+            barrier.wait()
+            r = g2.execute(statements[i])
+            assert r.ok(), r.error_msg
+            out[i] = r
+        except Exception as ex:     # noqa: BLE001 — reported below
+            errors.append(ex)
+
+    ts = [threading.Thread(target=worker, args=(i,))
+          for i in range(len(statements))]
+    [t.start() for t in ts]
+    [t.join() for t in ts]
+    assert not errors, errors
+    _settle(c)
+    return [out[i] for i in range(len(statements))]
+
+
+def _mixed(n):
+    return [f"GO {2 + i % 3} STEPS FROM {1 + i} OVER e YIELD e._dst"
+            for i in range(n)]
+
+
+def _walk(node):
+    yield node
+    for ch in node.get("children", ()):
+        yield from _walk(ch)
+
+
+def _trees():
+    return [trace_store.tree(int(s["id"], 16))
+            for s in trace_store.summaries()]
+
+
+def _pump_roots(name):
+    return [r for t in _trees() for r in t["roots"] if r["name"] == name]
+
+
+def _ticks():
+    return [r for r in flight.recorder.dump(limit=4096)
+            if r["kind"] == "tick"]
+
+
+# ===================================================== (a) the tick
+class TestTickTrace:
+    def test_every_flight_tick_has_one_tiled_tree(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(10))
+        ticks = _ticks()
+        assert len(ticks) >= 3
+        roots = _pump_roots("pump.tick")
+        assert len(roots) == len(ticks)
+        by_rec = {r["tags"]["rec"]: r for r in roots}
+        for rec in ticks:
+            root = by_rec[rec["id"]]
+            for k in ("stream", "tick", "seats", "joins", "leaves"):
+                assert root["tags"][k] == rec[k]
+            assert abs(root["duration_us"] - rec["dur_us"]) <= 2
+            kids = root["children"]
+            assert {k["name"] for k in kids} <= CHILDREN
+            lo = root["start_us"]
+            hi = lo + root["duration_us"]
+            at = lo
+            for k in kids:                  # sorted by start
+                assert k["start_us"] >= at, (k, at)     # disjoint
+                at = k["start_us"] + k["duration_us"]
+                assert at <= hi                         # inside
+            covered = sum(k["duration_us"] for k in kids)
+            assert covered >= 0.95 * root["duration_us"], (root, rec)
+            # the record's seat_us and parts are the same stamps
+            seat = [k for k in kids if k["name"] == "pump.seat"]
+            assert len(seat) == 1
+            assert abs(seat[0]["duration_us"] - rec["seat_us"]) <= 2
+            for part in PARTS:
+                name = "pump." + part[:-3]
+                got = sum(k["duration_us"] for k in kids
+                          if k["name"] == name)
+                assert abs(got - rec[part]) <= 4, (part, got, rec)
+
+    def test_assemble_us_is_the_sum_of_its_parts(self, graph):
+        c, g, ok = graph                    # untraced: always on
+        flags.set("trace_sample_rate", 0.0)
+        _burst(c, _mixed(8))
+        ticks = _ticks()
+        assert any(t["assemble_us"] > 0 for t in ticks)
+        for t in ticks:
+            assert t["assemble_us"] == sum(t[p] for p in PARTS)
+            assert t["seat_us"] >= 0
+            assert t["seat_us"] + t["assemble_us"] <= t["dur_us"]
+        # rows handed over are counted where leavers were finished
+        assert sum(t["leaver_rows"] for t in ticks) > 0
+        assert all(t["leaver_rows"] == 0 for t in ticks
+                   if t["assemble_us"] == 0)
+
+
+# =================================================== (b) the rider
+class TestRiderWaits:
+    def test_four_waits_sum_to_the_submit_wall(self, graph, monkeypatch):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        walls = {}
+        real = bd._ContinuousStream.submit
+
+        def timed(self, key, payload, steps, upto, reduce):
+            t = time.perf_counter()
+            try:
+                return real(self, key, payload, steps, upto, reduce)
+            finally:
+                ctx = tracing.current_context()
+                walls[ctx[0]] = (time.perf_counter() - t) * 1e6
+
+        monkeypatch.setattr(bd._ContinuousStream, "submit", timed)
+        assert bd.PUMP_TICK_RIDER_TAGS >= 8
+        _burst(c, _mixed(8))
+        ticks_of = {}
+        for r in _pump_roots("pump.tick"):
+            for rider in r["tags"]["riders"]:
+                ticks_of.setdefault(rider, []).append(r["tags"]["tick"])
+        seen, outside = 0, []
+        for tree in _trees():
+            marks = [n["tags"] for r in tree["roots"] for n in _walk(r)
+                     if n["name"] == "graph.continuous"]
+            if not marks:
+                continue
+            m = marks[0]
+            seen += 1
+            assert m["ending"] == "left-batch"
+            assert all(m[w] >= 0 for w in WAITS), m
+            wall = walls[int(tree["trace_id"], 16)]
+            total = sum(m[w] for w in WAITS)
+            # the stamps tile enq_t -> wake; submit() adds admission
+            # before and the marker after: a few us each, unless this
+            # thread loses the interpreter there (eight run at once)
+            assert total <= wall
+            outside.append(wall - total)
+            assert m["joined_tick"] < m["left_tick"]
+            assert m["left_tick"] - m["joined_tick"] >= m["hops"]
+            # joined to its ticks by trace id: every tick it rode
+            # names it (a pump.tick names up to 8 riders, and this
+            # burst has no more)
+            mine = ticks_of.get(tree["trace_id"], [])
+            assert mine, (tree["trace_id"], ticks_of)
+            assert set(range(m["joined_tick"] + 1, m["left_tick"] + 1)) \
+                <= set(mine), (m, mine)
+        assert seen == 8
+        assert sorted(outside)[len(outside) // 2] <= 400, outside
+
+    def test_slow_log_entry_says_which_wait_was_slow(self, graph):
+        c, g, ok = graph
+        saved = flags.get("slow_query_threshold_ms")
+        flags.set("slow_query_threshold_ms", 1)
+        slow_log.clear_for_tests()
+        st = next(iter(c.tpu_runtime.dispatcher.continuous.streams()))
+        st.tick_delay_s = 0.03
+        try:
+            ok("GO 4 STEPS FROM 5 OVER e YIELD e._dst")
+        finally:
+            st.tick_delay_s = 0.0
+            flags.set("slow_query_threshold_ms", saved)
+        e = [e for e in slow_log.dump() if "4 STEPS FROM 5" in e["stmt"]]
+        assert e, slow_log.dump()
+        e = e[0]
+        assert all(k in e for k in WAITS + ("left_tick",)), e
+        # three hops at >= 30 ms of tick delay each: the ride was slow
+        assert e["ride_us"] >= 55_000 and e["ride_us"] == max(
+            e[w] for w in WAITS)
+        assert sum(e[w] for w in WAITS) <= e["latency_us"]
+
+
+# ============================================== (c) sampling 0 is free
+class TestUntraced:
+    def test_no_pump_span_no_trace_no_span_allocated(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 0.0)
+        _burst(c, _mixed(6))                # warm code paths
+        trace_store.clear_for_tests()
+        flight.recorder.clear_for_tests()
+        tracemalloc.start()
+        try:
+            snap1 = tracemalloc.take_snapshot()
+            _burst(c, _mixed(6))
+            snap2 = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        assert _ticks(), "the burst never ticked"
+        assert trace_store.summaries() == []
+        # nothing allocated by Span or emit (a new thread's first read
+        # of the thread-local context allocates its dict in
+        # current_context()/span(): not a span, not the pump's)
+        import inspect
+        span_lines = set()
+        for obj in (tracing.Span, tracing.emit):
+            src, first = inspect.getsourcelines(obj)
+            span_lines |= set(range(first, first + len(src)))
+        filt = [tracemalloc.Filter(True, "*/common/tracing.py")]
+        grew = [s for s in snap2.filter_traces(filt).compare_to(
+                    snap1.filter_traces(filt), "lineno")
+                if (s.size_diff > 0 or s.count_diff > 0)
+                and s.traceback[0].lineno in span_lines]
+        assert grew == [], f"a Span was built on an untraced tick: {grew}"
+
+
+# ======================================================= (d) PROFILE
+class TestProfile:
+    def test_marker_has_the_waits_and_traces_lists_its_ticks(self, graph):
+        from nebula_tpu.webservice import WebService
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 0.0)     # PROFILE alone traces
+        r = ok("PROFILE GO 2 STEPS FROM 3 OVER e YIELD e._dst")
+        _settle(c)
+        prof = r.raw["profile"]
+        marks = [n["tags"] for root in prof["roots"] for n in _walk(root)
+                 if n["name"] == "graph.continuous"]
+        assert len(marks) == 1
+        m = marks[0]
+        assert all(m[w] >= 0 for w in WAITS), m
+        assert m["left_tick"] == m["joined_tick"] + 1   # one hop
+        # no span was added to the rider's own tree for this
+        names = {n["name"] for root in prof["roots"] for n in _walk(root)}
+        assert not any(n.startswith("pump.") for n in names), names
+        ws = WebService("test").start()
+        base = f"http://127.0.0.1:{ws.port}"
+        try:
+            listing = json.load(urllib.request.urlopen(
+                f"{base}/traces"))["traces"]
+            pumps = [t for t in listing if t["name"] == "pump.tick"]
+            assert pumps, listing
+            rode = []
+            for t in pumps:
+                tree = json.load(urllib.request.urlopen(
+                    f"{base}/traces?id={t['id']}"))
+                root = [x for x in tree["roots"]
+                        if x["name"] == "pump.tick"][0]
+                if prof["trace_id"] in root["tags"]["riders"]:
+                    rode.append(root["tags"]["tick"])
+        finally:
+            ws.stop()
+        # only the ticks the PROFILEd statement rode were traced
+        assert len(rode) == len(pumps)
+        assert m["left_tick"] in rode
+
+
+# ==================================================== (e) fake clock
+class TestFakeClock:
+    def test_advance_for_tests_ages_the_post_hoc_spans(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        ok("GO 2 STEPS FROM 2 OVER e YIELD e._dst")
+        _settle(c)
+        before = max(r["start_us"] for r in _pump_roots("pump.tick"))
+        live0 = max(t["roots"][0]["start_us"] for t in _trees()
+                    if t["roots"][0]["name"] == "graph.query")
+        clock.advance_for_tests(3600.0)
+        ok("GO 2 STEPS FROM 2 OVER e YIELD e._dst")
+        _settle(c)
+        after = max(r["start_us"] for r in _pump_roots("pump.tick"))
+        live1 = max(t["roots"][0]["start_us"] for t in _trees()
+                    if t["roots"][0]["name"] == "graph.query")
+        assert 3600e6 <= after - before < 3660e6
+        # ...exactly like the spans that time themselves
+        assert abs((after - before) - (live1 - live0)) < 1e6
+        # and the emitted tick still sits where its rider's query does
+        q = [t["roots"][0] for t in _trees()
+             if t["roots"][0]["name"] == "graph.query"
+             and t["roots"][0]["start_us"] == live1][0]
+        assert q["start_us"] <= after <= q["start_us"] + q["duration_us"]
+
+    def test_emit_takes_explicit_timing(self):
+        tid = tracing.new_trace_id()
+        root = tracing.emit("pump.tick", tid, None, 1_000, 500, tick=7)
+        tracing.emit("pump.seat", tid, root, 1_000, 20)
+        tree = trace_store.tree(tid)
+        assert tree["roots"][0]["start_us"] == 1_000
+        assert tree["roots"][0]["duration_us"] == 500
+        assert tree["roots"][0]["tags"] == {"tick": 7}
+        assert tree["roots"][0]["children"][0]["name"] == "pump.seat"
+        assert tracing.current_context() is None
+
+
+# ========================================================= (f) idle
+class TestIdle:
+    def test_idle_stretch_between_bursts_is_one_pump_idle(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(4))
+        n0 = len(_pump_roots("pump.idle"))
+        t_gap = time.perf_counter()
+        time.sleep(0.4)
+        gap_us = (time.perf_counter() - t_gap) * 1e6
+        _burst(c, _mixed(4))
+        idles = sorted(_pump_roots("pump.idle"),
+                       key=lambda r: -r["duration_us"])
+        assert len(idles) > n0
+        long = [r for r in idles if r["duration_us"] >= 0.9 * gap_us]
+        assert len(long) == 1, idles
+        assert long[0]["tags"]["why"] == "no_work"
+        # it ends where a tick starts, and that tick's record has it
+        ticks = _pump_roots("pump.tick")
+        end = long[0]["start_us"] + long[0]["duration_us"]
+        assert any(abs(t["start_us"] - end) <= 2 for t in ticks)
+        assert any(r["idle_us"] == long[0]["duration_us"]
+                   for r in _ticks())
+        # a stretch under the floor is loop overhead, not a span
+        assert all(r["duration_us"] >= bd.PUMP_IDLE_SPAN_MIN_US
+                   for r in idles)
+
+    def test_a_delayed_tick_is_not_no_work(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        st = next(iter(c.tpu_runtime.dispatcher.continuous.streams()))
+        st.tick_delay_s = 0.02
+        try:
+            ok("GO 4 STEPS FROM 6 OVER e YIELD e._dst")
+        finally:
+            st.tick_delay_s = 0.0
+        _settle(c)
+        whys = [r["tags"]["why"] for r in _pump_roots("pump.idle")]
+        assert "tick_delay" in whys, whys
+
+
+# ================================== (g) the probe is off the pump
+class TestNoStoppingProbe:
+    def test_hop_never_blocks_and_writes_no_timing_row(self, graph,
+                                                       monkeypatch):
+        import jax
+        c, g, ok = graph
+        rt = c.tpu_runtime
+        saved = flags.get("tpu_device_timing_every")
+        flags.set("tpu_device_timing_every", 1)      # every dispatch
+        in_fetch = threading.local()
+        outside = []
+        real_call = type(rt)._maybe_time_device
+        probed = []
+        monkeypatch.setattr(
+            type(rt), "_maybe_time_device",
+            lambda self, *a, **k: (probed.append(k.get("kind")),
+                                   real_call(self, *a, **k))[1])
+        real_bur = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: (outside.append("jax.block_until_ready"),
+                       real_bur(x))[1])
+        from nebula_tpu.tpu.runtime import _LaneFetch
+        real_fetch = _LaneFetch.__call__
+
+        def fetch(self):
+            in_fetch.on = True
+            try:
+                return real_fetch(self)
+            finally:
+                in_fetch.on = False
+
+        monkeypatch.setattr(_LaneFetch, "__call__", fetch)
+        try:
+            _burst(c, _mixed(8))
+        finally:
+            flags.set("tpu_device_timing_every", saved)
+        assert _ticks()
+        assert probed == [], probed
+        assert outside == [], outside
+        rows = [r for r in flight.recorder.dump(limit=4096)
+                if r["kind"] == "timing"]
+        assert rows == [], rows
+        # the device wait is read where the pump blocks anyway
+        assert any(t["fetch_wait_us"] > 0 for t in _ticks())
+
+    def test_no_probe_call_in_the_session_source(self):
+        import inspect
+        from nebula_tpu.tpu.runtime import _ContinuousGoSession
+        src = inspect.getsource(_ContinuousGoSession)
+        assert not re.search(r"_maybe_time_device\(", src)
+
+
+# ============================= SHOW TIMELINE / timeline detail
+class TestTimelineDetail:
+    def test_show_timeline_detail_has_the_new_fields(self, graph):
+        c, g, ok = graph
+        _burst(c, _mixed(4))
+        r = ok("SHOW TIMELINE 64")
+        detail = [row[5] for row in r.rows if row[3] == "tick"]
+        assert detail
+        for field in ("seat_us=", "leaver_rows=") + tuple(
+                p + "=" for p in PARTS):
+            assert all(field in d for d in detail), (field, detail[0])
+
+
+# ================================================ kernel named scopes
+class TestKernelScopes:
+    def _index(self):
+        from nebula_tpu.tpu import ell as E
+        rng = np.random.default_rng(3)
+        n, m = 400, 6000
+        # a skewed in-degree so several buckets and hub rows exist
+        ed = (rng.zipf(1.6, m) % n).astype(np.int32)
+        es = rng.integers(0, n, m).astype(np.int32)
+        ee = np.ones(m, np.int32)
+        return E, E.EllIndex.build(es, ed, ee, n, cap=32, min_d=2)
+
+    def test_hop_has_one_scope_per_bucket_width(self):
+        import jax.numpy as jnp
+        E, ix = self._index()
+        widths = [int(nbr.shape[1]) for nbr in ix.bucket_nbr]
+        assert len(widths) >= 2 and len(ix.extra_owner) > 0
+        kern = E.make_continuous_hop_kernel(ix, (1,), donate=False)
+        fp = jnp.zeros((ix.n_rows + 1, E.lanes_width(128)), jnp.uint8)
+        eslot, hrows = ix.hub_merge()
+        lowered = kern.lower(fp, fp, jnp.asarray(eslot),
+                             jnp.asarray(hrows), *ix.kernel_args()[1:])
+        assert lowered.compile() is not None
+        text = lowered.as_text(debug_info=True)
+        for d in widths:
+            assert f"hop/bucket_w{d}" in text, d
+        assert "hop/hub_merge" in text
+        # the jitted function keeps its name: the benchmark's
+        # hop_kernel_ms matches ^jit_hop$
+        assert "jit_hop" in text
+
+    @pytest.mark.parametrize("make,scope,name", [
+        ("make_lane_join_kernel", "lane/join", "jit_join"),
+        ("make_lane_extract_kernel", "lane/extract", "jit_extract"),
+        ("make_lane_clear_kernel", "lane/clear", "jit_clear")])
+    def test_lane_kernels_have_a_scope_each(self, make, scope, name):
+        import jax.numpy as jnp
+        E, ix = self._index()
+        W = E.lanes_width(128)
+        fp = jnp.zeros((ix.n_rows + 1, W), jnp.uint8)
+        i32 = jnp.zeros(8, jnp.int32)
+        u8 = jnp.zeros(8, jnp.uint8)
+        if make == "make_lane_join_kernel":
+            low = E.make_lane_join_kernel(ix, donate=False).lower(
+                fp, fp, i32, i32, u8)
+        elif make == "make_lane_extract_kernel":
+            low = E.make_lane_extract_kernel().lower(fp, fp, i32, u8)
+        else:
+            low = E.make_lane_clear_kernel(donate=False).lower(
+                fp, fp, jnp.zeros(W, jnp.uint8))
+        text = low.as_text(debug_info=True)
+        assert scope in text and name in text

@@ -110,6 +110,14 @@ class TestQueryRegistry:
             m = registry.seat_markers(qid)
             assert m == {"lane": 5, "joined_tick": 17, "hops": 2,
                          "ending": None}
+            # once submit() returns, the tick it left on and the waits
+            # its time there was made of ride along (the slow log
+            # copies them)
+            waits = {"seat_wait_us": 40, "ride_us": 900,
+                     "result_wait_us": 120, "wake_us": 15}
+            registry.note_waits(qid, 19, waits)
+            assert registry.seat_markers(qid) == {
+                **m, "left_tick": 19, **waits}
         finally:
             registry.unregister(qid)
 
