@@ -127,8 +127,11 @@ class FlightRecorder:
         stream keyed ``stream``: seat churn counts, per-phase micros
         in pump order (seat, then the join/hop/extract/clear ENQUEUES,
         then the leave cohort's fetch_wait/d2h/unpack/rows/handover,
-        whose sum is assemble_us), leaver_rows, idle gap since the
-        previous tick, mirror generation, tick wall micros."""
+        whose sum is assemble_us), leaver_rows, the hops whose branch
+        the tick learned (hop_reads; of them hop_sparse pushed out of
+        the live slot rows; hop_slots the ELL slots they visited),
+        idle gap since the previous tick, mirror generation, tick wall
+        micros."""
         rec = {"kind": "tick", "stream": int(stream)}
         rec.update(fields)
         return self._note(rec)

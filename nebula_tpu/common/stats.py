@@ -163,6 +163,11 @@ METRIC_NAMES = (
     "tpu.device_compute.latency_us",
     "tpu.roofline.achieved_gbps",
     "tpu.fetch.bytes",
+    # continuous hops by the branch the hop program took on the device
+    # (tpu/ell.py make_continuous_hop_kernel): push out of the live
+    # slot rows / pull over every slot of the table
+    "tpu.hop.sparse",
+    "tpu.hop.dense",
     # device idle share since the previous scrape, both dispatch modes
     # (graph/batch_dispatch.py _DeviceBusyMeter): windowed mode idles
     # between windows, the continuous pipeline's double-buffered hop

@@ -991,6 +991,11 @@ class _ContinuousStream:
         # pump-thread-only state (see __init__)
         self._last_tick_end = time.perf_counter()  # nebulint: disable=lock-discipline
         if busy:
+            # the branch the device took, for the hops whose info the
+            # session has read since the last record: the fetch reads
+            # it where it has just waited (tpu/runtime.py _LaneFetch),
+            # this call takes in what else is ready.  Never a wait
+            hop_reads, hop_sparse, hop_slots = sess.hop_reads()
             parts = [0] * 5     # fetch_wait, d2h, unpack, rows, handover
             for stamps, _n in finishes:
                 for i in range(5):
@@ -1006,6 +1011,8 @@ class _ContinuousStream:
                 unpack_us=parts[2], rows_us=parts[3],
                 handover_us=parts[4], assemble_us=sum(parts),
                 leaver_rows=sum(n for _stamps, n in finishes),
+                hop_reads=hop_reads, hop_sparse=hop_sparse,
+                hop_slots=hop_slots,
                 idle_us=int(idle_us),
                 dur_us=int(dur * 1e6),
                 generation=int(getattr(getattr(sess, "m", None),

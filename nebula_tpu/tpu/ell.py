@@ -647,10 +647,24 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 # OCCUPANCY changes — and occupancy is data, not shape.
 #
 #   make_continuous_hop_kernel   one frontier advance + UPTO union:
-#                                (fp, accp) -> (hop(fp), accp|hop(fp));
-#                                both carriers donated (the stream owns
-#                                them, nothing else ever reads the old
-#                                generation of the pair)
+#                                (fp, accp) -> (hop(fp), accp|hop(fp),
+#                                info); both carriers donated (the
+#                                stream owns them, nothing else ever
+#                                reads the old generation of the pair).
+#                                The program FOLLOWS THE FRONTIER: it
+#                                counts the live slot rows it was
+#                                handed and, on the device, takes one
+#                                of two exact branches —
+#                                  push  (<= HOP_PUSH_ROWS live slot
+#                                        rows) each live row ORs its
+#                                        words into its out-neighbours'
+#                                        rows of a zeroed frontier:
+#                                        work ~ the live rows' slots;
+#                                  pull  (over the budget) the full
+#                                        _hop_body_packed sweep of every
+#                                        slot of the table, unchanged.
+#                                ``info`` says which ran and what it
+#                                visited; nothing on the host chooses
 #   make_lane_join_kernel        scatter-ADD of single lane bits into
 #                                FREE lanes.  Exact by the clear
 #                                contract: a freed lane's bit is zero
@@ -668,28 +682,187 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #                                the d2h fetch is R1 bytes per leaving
 #                                word, never the whole matrix
 # ====================================================================
+# Push budget of the continuous hop, in live SLOT ROWS (a live vertex's
+# main row plus its hub extra rows).  The push pays XLA's row scatter,
+# which the TPU runs one index after the other: 47 ns a slot on the
+# v5e (24 us a 512-wide row, 1.2 us an 8-wide one) beside 3.5 ms
+# fixed, against the pull's 2.7 ns a slot over EVERY slot of the table
+# (114 ms at 42.2 M).  4,096 rows of the widest bucket are 2.1 M
+# slots, ~100 ms: the budget is where the worst push still undercuts
+# the sweep at that table size (PERF.md §5-§6, PR 25's chip runs).  A
+# speed choice only: both branches are exact.
+HOP_PUSH_ROWS = 4096
+
+# info vector of the continuous hop program (int32[3])
+HOP_INFO_SPARSE, HOP_INFO_ROWS, HOP_INFO_SLOTS = 0, 1, 2
+
+
+def table_slots(ell: EllIndex) -> int:
+    """Slots of the whole table — what one pull hop visits."""
+    return int(sum(nbr.shape[0] * nbr.shape[1] for nbr in ell.bucket_nbr))
+
+
+def _set_positions(jnp, mask, cap: int, group: int = 128):
+    """Ascending indices of the first ``cap`` set entries of ``mask``
+    (bool[R]), padded with R — jnp.nonzero(size=cap) in two levels,
+    because a running sum over all R entries costs the TPU compiler
+    half a minute at R = 670 k (PERF.md §6, PR 25) and a group of 128
+    costs it nothing: count per group, find each wanted entry's group
+    by its rank, then its place inside the group from the group's own
+    running count (a 0/1 product with a triangle, exact in any matmul
+    precision: the sums stay under 2^8)."""
+    R = mask.shape[0]
+    G = -(-R // group)
+    m2 = jnp.pad(mask, (0, G * group - R)).reshape(G, group)
+    per = jnp.sum(m2, axis=1, dtype=jnp.int32)
+    upto = jnp.cumsum(per)
+    j = jnp.arange(cap, dtype=jnp.int32)
+    g = jnp.searchsorted(upto, j + 1, side="left").astype(jnp.int32)
+    gc = jnp.minimum(g, G - 1)
+    k = j - (upto[gc] - per[gc])            # rank inside the group
+    tri = jnp.asarray(np.triu(np.ones((group, group), np.float32)))
+    run = m2[gc].astype(jnp.float32) @ tri  # [cap, group] running count
+    # entries whose running count is still <= k precede the wanted one
+    pos = jnp.sum(run <= k[:, None].astype(jnp.float32), axis=1,
+                  dtype=jnp.int32)
+    return jnp.where(g < G, gc * group + pos, jnp.int32(R))
+
+
+def _hop_push_packed(jnp, jax, n: int, n_rows: int,
+                     etypes: Tuple[int, ...], nbrs, ets, owner, fp,
+                     row_live, counts, cap: int):
+    """One packed frontier advance by PUSH: every live slot row ORs its
+    source's word row into the rows of its out-neighbours.
+
+    ``row_live`` bool[n_rows] marks the live slot rows (a live vertex's
+    main row and its hub extra rows — ``owner`` int32[n_extras] names
+    an extra row's vertex), ``counts`` their number per bucket, at most
+    ``cap`` in all.  A row's out-neighbours over t are its -t slots
+    (csr.py stores the reverse direction under -etype; the windowed
+    sparse kernels push the same way).  Targets inside one slot row
+    take one value (old | source), so a plain set is exact there even
+    where a row names a neighbour twice; rows run one after the other,
+    so a target shared by two rows ORs both.  Rows >= n of the result
+    stay zero (no slot points there) and the pad row is never written:
+    masked and sentinel slots scatter out of range and drop."""
+    neg = tuple(-t for t in etypes)
+    R1 = n_rows + 1
+    # bucket b's live rows are the run [sum(counts[:b]),
+    # sum(counts[:b+1])) of the ascending list
+    rows = _set_positions(jnp, row_live, cap)
+    src = rows
+    if owner is not None:
+        src = jnp.where(rows < n, rows,
+                        owner[jnp.clip(rows - n, 0, owner.shape[0] - 1)])
+    # every live row's word row, read once here, so the loops below
+    # touch ONE frontier-sized array, the carrier they write
+    vals = fp[jnp.minimum(src, R1 - 1)]                # [cap, W]
+    nxt = jnp.zeros_like(fp)
+    rows = jnp.concatenate([rows, jnp.full((cap,), n_rows, jnp.int32)])
+    lo = jnp.int32(0)
+    bstart = 0
+    for nbr, et, cnt in zip(nbrs, ets, counts):
+        with jax.named_scope(f"hop/push_w{nbr.shape[1]}"):
+            # the bucket's live slot rows in one gather (entries past
+            # ``cnt`` belong to later buckets and are never looped
+            # over).  One gather, not a row read per loop turn: the TPU
+            # keeps a table narrower than its 128 lanes column-major,
+            # and a row read inside a loop makes the compiler lay the
+            # WHOLE table out row-major first, 2 x 197 MB of copies a
+            # hop for the 8-wide bucket of a 670 k-row table (PR 25,
+            # the TPU compiler's memory analysis); a gather reads it
+            # where it lies
+            loc = jnp.clip(jax.lax.dynamic_slice(rows, (lo,), (cap,))
+                           - bstart, 0, nbr.shape[0] - 1)
+            tgts = jnp.where(_etype_ok(jnp, et[loc], neg), nbr[loc], R1)
+
+            def body(i, nxt, tgts=tgts, lo=lo):
+                tgt = tgts[i]                              # [D]
+                cur = nxt[jnp.minimum(tgt, R1 - 1)]        # [D, W]
+                return nxt.at[tgt].set(cur | vals[lo + i][None, :],
+                                       mode="drop")
+
+            nxt = jax.lax.fori_loop(0, cnt, body, nxt)
+        lo = lo + cnt
+        bstart += nbr.shape[0]
+    return nxt
+
+
 def make_continuous_hop_kernel(ell: EllIndex,
                                etypes: Tuple[int, ...],
-                               donate: bool = True):
+                               donate: bool = True,
+                               push_rows: Optional[int] = None):
     """One continuous-mode frontier advance.
 
     fn(fp uint8 [n_rows+1, W], accp uint8 [n_rows+1, W],
        eslot int32[n_extras], hrows int32[n_hubs], *tables)
-    -> (fp', accp'): fp' is one packed hop of fp, accp' accumulates
-    the union (the per-lane UPTO carrier — exact-depth lanes simply
-    never read it).  Unlike the windowed kernels the hop count is NOT
-    baked in: one jitted program serves every mix of per-query depths,
-    so the cache key space per (mirror, OVER) family is ONE entry per
-    lane-width rung."""
+    -> (fp', accp', info int32[3]): fp' is one packed hop of fp, accp'
+    accumulates the union (the per-lane UPTO carrier — exact-depth
+    lanes simply never read it).  Unlike the windowed kernels the hop
+    count is NOT baked in: one jitted program serves every mix of
+    per-query depths, so the cache key space per (mirror, OVER) family
+    is ONE entry per lane-width rung.
+
+    The program measures the frontier it is handed — the live slot
+    rows: a real row v < n with any lane bit set, plus the hub extra
+    rows of such a v (rows >= n of ``fp`` hold a previous pull's
+    partial ORs and are never read as sources) — and takes the push
+    (_hop_push_packed) when they number at most ``push_rows``
+    (HOP_PUSH_ROWS unless a test passes its own), else the pull over
+    every slot (_hop_body_packed, as the windowed kernels run it).
+    Both are exact on rows < n and the pad row; they differ only in
+    what they leave in the extra rows, which nothing reads.
+    ``info`` = [1 if the push ran else 0, live slot rows, ELL slots
+    the hop visited (the live rows' widths, or the whole table)]; the
+    session reads it without waiting on the hop."""
     import jax
     import jax.numpy as jnp
-    n, n_extras, nb = ell.n, len(ell.extra_owner), len(ell.bucket_nbr)
+    n, n_rows = ell.n, ell.n_rows
+    n_extras, nb = len(ell.extra_owner), len(ell.bucket_nbr)
+    all_slots = table_slots(ell)
+    if push_rows is None:
+        push_rows = HOP_PUSH_ROWS
 
     def hop(fp, accp, eslot, hrows, *tables):
         nbrs, ets = tables[:nb], tables[nb:]
-        nxt = _hop_body_packed(jnp, jax, n, n_extras, etypes,
-                               nbrs, ets, eslot, hrows, fp)
-        return nxt, accp | nxt
+
+        def pull(fp):
+            return _hop_body_packed(jnp, jax, n, n_extras, etypes,
+                                    nbrs, ets, eslot, hrows, fp)
+
+        if not nb:                     # empty graph: nothing moves
+            nxt = pull(fp)
+            return nxt, accp | nxt, jnp.zeros((3,), jnp.int32)
+        with jax.named_scope("hop/frontier"):
+            row_live = jnp.any(fp[:n] != 0, axis=1)
+            owner = None
+            if n_extras:
+                # an unclaimed growth spare's owner is the spare
+                # sentinel (>= n): it belongs to nobody
+                owner = hrows[eslot]
+                row_live = jnp.concatenate(
+                    [row_live, (owner < n)
+                     & row_live[jnp.minimum(owner, n - 1)]])
+            counts, slots, b0 = [], jnp.int32(0), 0
+            for nbr in nbrs:
+                c = jnp.sum(row_live[b0:b0 + nbr.shape[0]],
+                            dtype=jnp.int32)
+                counts.append(c)
+                slots = slots + c * nbr.shape[1]
+                b0 += nbr.shape[0]
+            live_rows = sum(counts)
+            sparse = live_rows <= push_rows
+
+        def push(fp):
+            return _hop_push_packed(jnp, jax, n, n_rows, etypes, nbrs,
+                                    ets, owner, fp, row_live, counts,
+                                    push_rows)
+
+        nxt = jax.lax.cond(sparse, push, pull, fp)
+        info = jnp.stack([sparse.astype(jnp.int32), live_rows,
+                          jnp.where(sparse, slots,
+                                    jnp.int32(all_slots))])
+        return nxt, accp | nxt, info
 
     return jax.jit(hop, donate_argnums=(0, 1) if donate else ())
 
@@ -2378,7 +2551,9 @@ def _ell_go_count_buckets(fx):
 def _ell_go_hop_buckets(fx):
     """Continuous-mode hop: ONE cache key per (mirror, OVER) family —
     the per-steps key dimension is gone (the host loop owns the hop
-    count), so the retrace space is just the lane-width rung ladder."""
+    count), and the push / pull choice is a conditional inside the
+    program, so the retrace space is just the lane-width rung
+    ladder."""
     kern = make_continuous_hop_kernel(fx.ell, fx.etypes, donate=True)
     out = []
     for B in fx.widths:
@@ -2567,7 +2742,10 @@ register_kernel(KernelSpec(
 register_kernel(KernelSpec(
     "ell_go_hop", make_continuous_hop_kernel, phase_kind="ell_go_hop",
     # continuous dispatch: one retrace per lane-width rung, steps
-    # folded out of the key entirely (the host tick loop owns depth)
+    # folded out of the key entirely (the host tick loop owns depth);
+    # push and pull are two branches of the ONE program, so the
+    # frontier's size is data too.  Outputs: the resident pair's next
+    # generation and the int32[3] info vector
     budget=2, instantiate=_ell_go_hop_buckets, donate=(0, 1),
     frontier=(0, 1), packed=(0, 1)))
 register_kernel(KernelSpec(

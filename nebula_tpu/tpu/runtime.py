@@ -43,6 +43,7 @@ return identical result sets (tests/test_tpu_backend.py asserts this).
 """
 from __future__ import annotations
 
+import collections
 import sys
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -451,8 +452,11 @@ DEVICE_PHASES = {
     # docs/admission.md "Continuous dispatch"): the resident frontier
     # pair never crosses the link — hop/join/clear "fetches" are the
     # next resident (fp, accp) generation (donated in, stays on
-    # device); only the leave-extract's word columns actually move d2h
-    "ell_go_hop": {"phases": ("tpu.kernel",), "h2d": 0, "d2h": 2},
+    # device); only the leave-extract's word columns actually move d2h.
+    # The hop's third output is its 12-byte info vector (which branch
+    # ran, live slot rows, slots visited): read once it is ready,
+    # never waited for (_ContinuousGoSession.hop_reads)
+    "ell_go_hop": {"phases": ("tpu.kernel",), "h2d": 0, "d2h": 3},
     "ell_lane_join": {"phases": ("tpu.kernel",), "h2d": 3, "d2h": 2},
     "ell_lane_clear": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 2},
     "ell_lane_extract": {"phases": ("tpu.kernel", "tpu.fetch"),
@@ -529,6 +533,11 @@ class TpuQueryRuntime:
                       "mirror_slot_grows": 0,
                       "go_sparse": 0, "go_dense": 0,
                       "go_adaptive": 0, "sparse_overflows": 0,
+                      # continuous hops by the branch the program took
+                      # on the device (ell.make_continuous_hop_kernel):
+                      # push out of the live slot rows / pull over the
+                      # whole table
+                      "hop_sparse": 0, "hop_dense": 0,
                       "prewarm_compiled": 0, "prewarm_hits": 0,
                       "prewarm_misses": 0, "prewarm_failed": 0,
                       "t_launch_s": 0.0, "t_fetch_s": 0.0,
@@ -662,6 +671,10 @@ class TpuQueryRuntime:
                 round(snap.get("device_bytes_moved", 0) / t_dev / 1e9,
                       3), runtime=role)
         _stats.set_gauge("tpu.fetch.bytes", snap.get("fetch_bytes", 0),
+                         runtime=role)
+        _stats.set_gauge("tpu.hop.sparse", snap.get("hop_sparse", 0),
+                         runtime=role)
+        _stats.set_gauge("tpu.hop.dense", snap.get("hop_dense", 0),
                          runtime=role)
         for key, state, _reason in self.breaker.cells_snapshot():
             _stats.set_gauge("tpu.breaker.state",
@@ -3696,6 +3709,11 @@ class _ContinuousGoSession:
         # argument slots must never alias one device buffer
         self.fp, self.accp = fp, fp.copy()
         self.hops = 0
+        # info vectors of hops whose branch is not read yet, oldest
+        # first (hop_reads); bounded, so a caller that never reads
+        # them forgets the oldest
+        self._hop_info: collections.deque = collections.deque(maxlen=64)
+        self._hop_read = [0, 0, 0]      # read, not yet in a tick record
 
     def join(self, joiners) -> None:
         """Scatter the arrivals' start frontiers into their assigned
@@ -3745,14 +3763,43 @@ class _ContinuousGoSession:
                                                donate=True))
         with tracing.span("tpu.kernel", kind="ell_go_hop",
                           width=self.B, packed=True):
-            self.fp, self.accp = kern(self.fp, self.accp, self.eslot,
-                                      self.hrows, *self._tables)
+            self.fp, self.accp, info = kern(self.fp, self.accp,
+                                            self.eslot, self.hrows,
+                                            *self._tables)
+        self._hop_info.append(info)
         self.hops += 1
         # no tpu_device_timing_every probe here: blocking on the hop
         # before the pending cohort is assembled gives up the overlap
         # the stream exists for.  The stream's device wait is read
         # where the host blocks anyway — _LaneFetch.t_wait, the tick
         # record's fetch_wait_us (graph/batch_dispatch.py)
+
+    def read_hop_info(self) -> None:
+        """Take in the info vectors of the hops the device has finished
+        (12 bytes each); a hop still in flight stays for a later call.
+        Never waits.  _LaneFetch calls this where the pump has just
+        waited on an extract queued behind those hops."""
+        from .ell import HOP_INFO_SLOTS, HOP_INFO_SPARSE
+        reads = sparse = 0
+        while self._hop_info and self._hop_info[0].is_ready():
+            info = np.asarray(self._hop_info.popleft())
+            reads += 1
+            sparse += int(info[HOP_INFO_SPARSE])
+            self._hop_read[2] += int(info[HOP_INFO_SLOTS])
+        if reads:
+            self._hop_read[0] += reads
+            self._hop_read[1] += sparse
+            self.rt._bump("hop_sparse", sparse)
+            self.rt._bump("hop_dense", reads - sparse)
+
+    def hop_reads(self) -> Tuple[int, int, int]:
+        """(hops read, of them pushed, ELL slots they visited) since
+        the last call — the tick record's hop_reads / hop_sparse /
+        hop_slots."""
+        self.read_hop_info()
+        out = tuple(self._hop_read)
+        self._hop_read = [0, 0, 0]
+        return out
 
     def extract(self, leavers):
         """Slice the leaving lanes' word columns (UPTO lanes read the
@@ -3780,8 +3827,7 @@ class _ContinuousGoSession:
             out_dev = kern(self.fp, self.accp, words_p, sel_p)
         cols_of = [pair_ix[(lane >> 3, bool(upto))]
                    for lane, upto in leavers]
-        return _LaneFetch(self.rt, self.ix.perm, out_dev, leavers,
-                          cols_of, np_pairs)
+        return _LaneFetch(self, out_dev, leavers, cols_of, np_pairs)
 
     def clear(self, lanes) -> None:
         """Zero the freed lanes' bits in both carriers — the seat-map
@@ -3808,12 +3854,11 @@ class _LaneFetch:
     and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
     still wraps wait + copy, as the windowed resolvers' does."""
 
-    __slots__ = ("rt", "perm", "out_dev", "leavers", "cols_of",
+    __slots__ = ("session", "out_dev", "leavers", "cols_of",
                  "np_pairs", "t_wait", "t_d2h")
 
-    def __init__(self, rt, perm, out_dev, leavers, cols_of, np_pairs):
-        self.rt = rt
-        self.perm = perm
+    def __init__(self, session, out_dev, leavers, cols_of, np_pairs):
+        self.session = session
         self.out_dev = out_dev
         self.leavers = leavers
         self.cols_of = cols_of
@@ -3826,13 +3871,18 @@ class _LaneFetch:
             # the only point the pump waits on the device
             self.out_dev.block_until_ready()
             self.t_wait = time.perf_counter()
+            # every hop queued before this extract is done: their info
+            # vectors are read here, before the results are handed
+            # over, so the tick's record is not held up after it
+            self.session.read_hop_info()
             cols = np.asarray(self.out_dev)         # [R1, P] uint8
             self.t_d2h = time.perf_counter()
-        self.rt._note_fetch(cols[:, :self.np_pairs])
+        self.session.rt._note_fetch(cols[:, :self.np_pairs])
+        perm = self.session.ix.perm
         outs = []
         for (lane, _upto), j in zip(self.leavers, self.cols_of):
             bit = (cols[:, j] >> (lane & 7)) & np.uint8(1)
-            old = bit[self.perm]                    # old dense order
+            old = bit[perm]                         # old dense order
             outs.append(np.nonzero(old)[0].astype(np.int64))
         return outs
 

@@ -364,6 +364,49 @@ class TestServingSemantics:
         assert "nebula_tpu_device_idle_frac" in text
         assert "nebula_graph_autoscale_recommended_replicas" in text
 
+    def test_served_go_counts_the_branch_its_hops_took(self,
+                                                       monkeypatch):
+        """A lone 2-step and 3-step GO push (hop_sparse moves); a
+        many-start GO whose frontier is over the budget pulls
+        (hop_dense moves) — same answers either way."""
+        from nebula_tpu.tpu import ell as E
+        # the budget is read when the program is built: a cluster of
+        # its own, so the nba stream's compiled program is not reused
+        monkeypatch.setattr(E, "HOP_PUSH_ROWS", 6)
+        flags.set("go_dispatch_mode", "continuous")
+        c, g, ok = _boot_graph(seed=25)
+        try:
+            rt = c.tpu_runtime
+            s0 = dict(rt.stats)
+            r2 = ok("GO 2 STEPS FROM 3 OVER e YIELD e._dst")
+            s1 = dict(rt.stats)
+            assert s1["hop_sparse"] - s0["hop_sparse"] == 1
+            assert s1["hop_dense"] == s0["hop_dense"]
+            starts = ", ".join(str(v) for v in range(1, 31))
+            many = ok(f"GO 2 STEPS FROM {starts} OVER e YIELD e._dst")
+            s2 = dict(rt.stats)
+            assert s2["hop_dense"] - s1["hop_dense"] == 1
+            assert s2["hop_sparse"] == s1["hop_sparse"]
+            ok("GO 3 STEPS FROM 3 OVER e YIELD e._dst")
+            s3 = dict(rt.stats)
+            # two hops; the second frontier (a vertex's out-neighbours)
+            # may or may not fit six rows, the first always does
+            assert s3["hop_sparse"] - s2["hop_sparse"] >= 1
+            assert (s3["hop_sparse"] + s3["hop_dense"]
+                    - s2["hop_sparse"] - s2["hop_dense"]) == 2
+            # the windowed tier is the oracle for the rows
+            flags.set("go_dispatch_mode", "windowed")
+            w2 = ok("GO 2 STEPS FROM 3 OVER e YIELD e._dst")
+            wm = ok(f"GO 2 STEPS FROM {starts} OVER e YIELD e._dst")
+            assert sorted(map(tuple, r2.rows)) == \
+                sorted(map(tuple, w2.rows))
+            assert sorted(map(tuple, many.rows)) == \
+                sorted(map(tuple, wm.rows))
+            assert rt.stats["go_device"] > s0["go_device"]
+        finally:
+            flags.set("go_dispatch_mode", "continuous")
+            c.stop()
+
     def test_extract_failure_wakes_leavers_typed(self, nba):
         """Review regression: leavers leave the seat map BEFORE the
         extract/clear ops run, so a device failure there must wake

@@ -664,3 +664,170 @@ def test_frontier_sharded_sparse_bfs_bitmatch():
         np.testing.assert_array_equal(
             ix.to_old(got)[:, :nq], ix.to_old(ref)[:, :nq],
             err_msg=f"trial {trial} shortest={shortest}")
+
+
+# ============================================================
+# The continuous hop follows the frontier (PR 25): the program that
+# chooses, on the device, between a push out of the live slot rows and
+# the pull over every slot must agree with the pull alone, bit for
+# bit, on every row a reader looks at: rows < n, the pad row, and the
+# UPTO accumulator over the same rows.  Extra rows (>= n) are scratch:
+# the pull leaves partial ORs there, the push zeros.
+# ============================================================
+def _hop_graph(seed, n=300, m=5000, cap=16, min_d=2, etypes=(1, 2),
+               multi_edges=False):
+    """A skewed two-edge-type graph in the mirror's form (both
+    directions, reverse under -etype) with hubs of several extra
+    rows."""
+    rng = np.random.default_rng(seed)
+    dst = (rng.zipf(1.5, m) % n).astype(np.int32)
+    src = rng.integers(0, n, m).astype(np.int32)
+    et = rng.choice(np.asarray(etypes, np.int32), m)
+    if not multi_edges:
+        _, first = np.unique(
+            (src.astype(np.int64) * n + dst) * 4 + et, return_index=True)
+        src, dst, et = src[first], dst[first], et[first]
+    ix = E.EllIndex.build(np.concatenate([src, dst]),
+                        np.concatenate([dst, src]),
+                        np.concatenate([et, -et]), n, cap=cap, min_d=min_d,
+                        growth_slack=3)
+    return ix
+
+
+def _hop_frontier(ix, rng, n_live, W, words=None, junk=True):
+    """fp with ``n_live`` live real rows (random lane bytes in
+    ``words``), random junk in the hub extra rows, a zero pad row."""
+    fp = np.zeros((ix.n_rows + 1, W), np.uint8)
+    rows = rng.choice(ix.n, n_live, replace=False)
+    cols = list(range(W)) if words is None else list(words)
+    vals = rng.integers(1, 256, (n_live, len(cols)), dtype=np.uint8)
+    fp[np.asarray(rows)[:, None], np.asarray(cols)[None, :]] = vals
+    if junk:
+        fp[ix.n:ix.n_rows] = rng.integers(
+            0, 256, (ix.n_rows - ix.n, W), dtype=np.uint8)
+    return fp, rows
+
+
+def _live_slot_rows(ix, rows):
+    """Slot rows of a set of live vertices (new ids): one main row
+    each plus their hub extra rows."""
+    ecnt, _e0 = ix.hub_expansion()
+    return int(len(rows) + ecnt[np.asarray(rows, np.int64)].sum())
+
+
+def _pull_reference(ix, etypes, fp, accp):
+    nb = len(ix.bucket_nbr)
+    tables = ix.kernel_args()[1:]
+    eslot, hrows = ix.hub_merge()
+    nxt = E._hop_body_packed(jnp, jax, ix.n, len(ix.extra_owner),
+                             tuple(etypes), tables[:nb], tables[nb:],
+                             jnp.asarray(eslot), jnp.asarray(hrows),
+                             jnp.asarray(fp))
+    return np.asarray(nxt), np.asarray(jnp.asarray(accp) | nxt)
+
+
+def _run_hop(ix, etypes, fp, accp, push_rows):
+    kern = E.make_continuous_hop_kernel(ix, tuple(etypes), donate=False,
+                                        push_rows=push_rows)
+    eslot, hrows = ix.hub_merge()
+    out = kern(jnp.asarray(fp), jnp.asarray(accp), jnp.asarray(eslot),
+               jnp.asarray(hrows), *ix.kernel_args()[1:])
+    return [np.asarray(o) for o in out]
+
+
+HOP_CASES = [
+    # name, etypes, live rows, lane words, budget rule
+    ("one_row", (1,), 1, None, "fits"),
+    ("few_rows_over_a", (1,), 7, None, "fits"),
+    ("over_a_b", (1, 2), 9, None, "fits"),
+    ("reversely", (-1,), 9, None, "fits"),
+    ("reversely_both", (-1, -2), 5, None, "fits"),
+    ("mixed_directions", (2, -1), 6, None, "fits"),
+    ("empty_frontier", (1,), 0, None, "fits"),
+    ("lanes_in_one_word", (1, 2), 12, (3,), "fits"),
+    ("lanes_in_several_words", (1, 2), 12, (0, 5, 15), "fits"),
+    ("no_junk_in_extras", (1,), 8, None, "fits_clean"),
+    ("exactly_at_the_budget", (1, 2), 25, None, "exact"),
+    ("one_row_over_the_budget", (1, 2), 25, None, "over"),
+    ("far_over_the_budget", (1,), 200, None, "tiny"),
+    ("every_row_live", (1, 2), 300, None, "tiny"),
+    ("multi_edges_between_a_pair", (1,), 10, None, "fits_multi"),
+]
+
+
+@pytest.mark.parametrize("name,etypes,n_live,words,rule", HOP_CASES,
+                         ids=[c[0] for c in HOP_CASES])
+def test_continuous_hop_agrees_with_the_pull(name, etypes, n_live, words,
+                                             rule):
+    ix = _hop_graph(11, multi_edges=(rule == "fits_multi"))
+    assert len(ix.bucket_nbr) >= 2
+    ecnt, _ = ix.hub_expansion()
+    assert ecnt.max() >= 3, "the graph must have hubs of several extra rows"
+    rng = np.random.default_rng(5)
+    W = 16
+    fp, rows = _hop_frontier(ix, rng, n_live, W, words,
+                             junk=(rule != "fits_clean"))
+    if n_live:
+        # a hub among the live rows, so the push walks its extra rows
+        hub = int(np.argmax(ecnt[:ix.n]))
+        fp[hub] = fp[rows[0]]
+        rows = np.unique(np.append(rows, hub))
+    # UPTO lanes: the accumulator holds earlier depths, and must take
+    # the new frontier in whichever branch produced it
+    accp = rng.integers(0, 256, fp.shape, dtype=np.uint8)
+    accp[ix.n_rows] = 0
+    live = _live_slot_rows(ix, rows)
+    budget = {"fits": 4096, "fits_clean": 4096, "fits_multi": 4096,
+              "exact": live, "over": live - 1, "tiny": 8}[rule]
+    nxt, acc, info = _run_hop(ix, etypes, fp, accp, budget)
+    want_nxt, want_acc = _pull_reference(ix, etypes, fp, accp)
+    sel = np.r_[0:ix.n, ix.n_rows]
+    assert np.array_equal(nxt[sel], want_nxt[sel])
+    assert np.array_equal(acc[sel], want_acc[sel])
+    assert not nxt[ix.n_rows].any()            # the pad row stays zero
+    pushed = live <= budget
+    assert info[E.HOP_INFO_SPARSE] == int(pushed)
+    assert info[E.HOP_INFO_ROWS] == live
+    if pushed:
+        widths = np.concatenate([np.full(nbr.shape[0], nbr.shape[1])
+                                 for nbr in ix.bucket_nbr])
+        _e, e0 = ix.hub_expansion()
+        visited = sum(int(widths[r]) + int(widths[e0[r]:e0[r] + ecnt[r]]
+                                           .sum()) for r in rows)
+        assert info[E.HOP_INFO_SLOTS] == visited
+    else:
+        assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix)
+
+
+def test_continuous_hop_chain_push_after_pull_after_push():
+    """Three hops in a row under a budget the middle frontier
+    overflows: the push reads a frontier whose extra rows a pull left
+    full of partial ORs, and the chain still agrees with three
+    pulls."""
+    ix = _hop_graph(12)
+    rng = np.random.default_rng(9)
+    fp, rows = _hop_frontier(ix, rng, 2, 16, junk=False)
+    accp = fp.copy()
+    ref_fp, ref_acc = fp.copy(), fp.copy()
+    branches = []
+    sel = np.r_[0:ix.n, ix.n_rows]
+    for _hop in range(3):
+        fp, accp, info = _run_hop(ix, (1, 2), fp, accp, push_rows=20)
+        ref_fp, ref_acc = _pull_reference(ix, (1, 2), ref_fp, ref_acc)
+        branches.append(int(info[0]))
+        assert np.array_equal(fp[sel], ref_fp[sel])
+        assert np.array_equal(accp[sel], ref_acc[sel])
+    assert 1 in branches and 0 in branches, branches
+
+
+def test_set_positions_is_nonzero_with_a_size():
+    rng = np.random.default_rng(2)
+    for R, k, cap in ((1, 1, 4), (127, 0, 8), (128, 128, 128),
+                      (1000, 37, 64), (1000, 300, 64), (4097, 4097, 16)):
+        mask = np.zeros(R, bool)
+        mask[rng.choice(R, k, replace=False)] = True
+        got = np.asarray(E._set_positions(jnp, jnp.asarray(mask), cap))
+        want = np.full(cap, R, np.int64)
+        idx = np.nonzero(mask)[0][:cap]
+        want[:len(idx)] = idx
+        assert np.array_equal(got, want), (R, k, cap)

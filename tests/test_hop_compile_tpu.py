@@ -1,0 +1,92 @@
+"""The continuous hop program compiled for the v5e at the benchmark
+cell's real table shapes — no chip needed: the TPU's compiler is
+installed here and compiles for a described, unattached chip
+(PERF.md §6, PR 25).
+
+What it guards is what no CPU test can see: the push branch once made
+the compiler lay whole slot tables out row-major for a single row read
+(2 x 197 MB of copies and temporaries a hop for the 8-wide bucket) and
+a flat running sum over 670 k rows cost it 33 s of compile.  Both read
+as a scratch size and a compile time here.  Nothing runs: no time, no
+result.  One file, one module-scoped topology (one process may hold
+the TPU library; see the on-chip-measurement guide)."""
+import time
+
+import numpy as np
+import pytest
+
+# graph500-s20's buckets (rows, width) as the cell builds them, its
+# vertices, slot rows and hub extra rows (PERF.md §5)
+S20_BUCKETS = [(385823, 8), (94937, 16), (30842, 32), (73934, 64),
+               (9372, 128), (29473, 256), (45260, 512)]
+S20_N, S20_ROWS, S20_EXTRAS, S20_HUBS = 646081, 669641, 23560, 6198
+LANES = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure means: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+class _Shapes:
+    """What a kernel builder reads of an EllIndex: sizes only (the
+    zero tables are never touched)."""
+
+    def __init__(self):
+        self.n, self.n_rows = S20_N, S20_ROWS
+        self.extra_owner = np.zeros(S20_EXTRAS, np.int32)
+        self.bucket_nbr = [np.zeros(s, np.int32) for s in S20_BUCKETS]
+        self.bucket_et = self.bucket_nbr
+
+
+def _compile(fn, one_chip):
+    import jax
+    from nebula_tpu.tpu import ell as E
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fp = sd((S20_ROWS + 1, E.lanes_width(LANES)), np.uint8)
+    args = (fp, fp, sd((S20_EXTRAS,), np.int32), sd((S20_HUBS,), np.int32)) \
+        + tuple(sd(s, np.int32) for s in S20_BUCKETS) * 2
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args).compile()
+    return compiled, time.perf_counter() - t0
+
+
+def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip):
+    import jax
+    import jax.numpy as jnp
+    from nebula_tpu.tpu import ell as E
+    ix = _Shapes()
+    nb = len(S20_BUCKETS)
+    hop, hop_s = _compile(
+        E.make_continuous_hop_kernel(ix, (1,), donate=True), one_chip)
+
+    def pull_only(fp, accp, eslot, hrows, *tables):
+        nxt = E._hop_body_packed(jnp, jax, ix.n, S20_EXTRAS, (1,),
+                                 tables[:nb], tables[nb:], eslot, hrows, fp)
+        return nxt, accp | nxt
+
+    pull, _s = _compile(jax.jit(pull_only, donate_argnums=(0, 1)),
+                        one_chip)
+    text = hop.as_text()
+    assert "conditional" in text                  # both branches, one program
+    scratch = hop.memory_analysis().temp_size_in_bytes
+    scratch_pull = pull.memory_analysis().temp_size_in_bytes
+    # the two branches share their scratch; what the program adds over
+    # the pull alone is the conditional's own frontier-sized result
+    # (measured 142 MB against 118 MB).  A table laid out anew for a
+    # row read shows as +395 MB
+    assert scratch <= scratch_pull + 48 * 2**20, (scratch, scratch_pull)
+    # measured 5-6 s; the flat running sum alone was 33 s
+    assert hop_s < 25.0, hop_s

@@ -449,6 +449,75 @@ class TestNoStoppingProbe:
         assert not re.search(r"_maybe_time_device\(", src)
 
 
+# ================================== the hop's branch in the tick record
+class TestHopBranchFields:
+    """PR 25: the hop program says which branch it took; the session
+    reads that without waiting on the hop, the tick record carries
+    it."""
+
+    def test_tick_record_says_which_branch_ran(self, graph):
+        c, g, ok = graph
+        rt = c.tpu_runtime
+        before = dict(rt.stats)
+        _burst(c, _mixed(6))
+        ticks = _ticks()
+        for field in ("hop_reads", "hop_sparse", "hop_slots"):
+            assert all(field in t for t in ticks), field
+        reads = sum(t["hop_reads"] for t in ticks)
+        # every hop of the burst was read into exactly one record
+        assert reads == sum(1 for t in ticks if t["hop_us"] > 0)
+        assert reads == (rt.stats["hop_sparse"] - before["hop_sparse"]
+                         + rt.stats["hop_dense"] - before["hop_dense"])
+        # a 40-vertex graph: every frontier is under the budget
+        assert sum(t["hop_sparse"] for t in ticks) == reads
+        assert all(0 <= t["hop_sparse"] <= t["hop_reads"] for t in ticks)
+        pushed = [t for t in ticks if t["hop_sparse"]]
+        assert pushed and all(
+            0 < t["hop_slots"] < 42 * 512 * t["hop_reads"] for t in pushed)
+
+    def test_show_timeline_renders_the_branch(self, graph):
+        c, g, ok = graph
+        _burst(c, _mixed(4))
+        r = ok("SHOW TIMELINE 64")
+        detail = [row[5] for row in r.rows if row[3] == "tick"]
+        assert detail
+        for field in ("hop_reads=", "hop_sparse=", "hop_slots="):
+            assert all(field in d for d in detail), (field, detail[0])
+
+    def test_reading_the_branch_never_waits_on_a_hop(self, graph):
+        """A hop still in flight keeps its info vector for a later
+        read: nothing in the session blocks on it (hop_us stays an
+        enqueue time)."""
+        import inspect
+        from nebula_tpu.tpu.runtime import _ContinuousGoSession
+        src = inspect.getsource(_ContinuousGoSession)
+        assert "block_until_ready" not in src
+        assert "is_ready()" in inspect.getsource(
+            _ContinuousGoSession.read_hop_info)
+
+        class InFlight:
+            asked = 0
+
+            def is_ready(self):
+                InFlight.asked += 1
+                return False
+
+            def __array__(self, *a, **k):
+                raise AssertionError("read before it was ready")
+
+        c, g, ok = graph
+        st = next(iter(c.tpu_runtime.dispatcher.continuous.streams()))
+        sess = st.session
+        assert sess is not None
+        sess.hop_reads()                    # drain what is there
+        sess._hop_info.append(InFlight())
+        try:
+            assert sess.hop_reads() == (0, 0, 0)
+            assert InFlight.asked >= 1 and len(sess._hop_info) == 1
+        finally:
+            sess._hop_info.clear()
+
+
 # ============================= SHOW TIMELINE / timeline detail
 class TestTimelineDetail:
     def test_show_timeline_detail_has_the_new_fields(self, graph):
