@@ -1,4 +1,9 @@
-"""Per-layer metric readers, one module each, found by name."""
+"""Per-layer metric readers, one module each, found by name:
+``read(select, record) -> float or None``.  None means "nothing to
+read": the harness leaves the metric out of the result line and names
+it on stderr (a reader of what a later PR adds to the program reads
+nothing on that PR's parent).  A reader never returns 0 for a share of
+a roofline or of a peak."""
 from __future__ import annotations
 
 import statistics

@@ -1,13 +1,18 @@
 """The plain reference: the same statements on the same data, answered
 from the generated arrays with numpy and nothing of the program.
 
-Semantics (nebula's, stated by the configurations' ``guarantees``):
-``GO n STEPS FROM v OVER e YIELD ...`` walks n-1 hops keeping the SET
-of vertices reached at each hop, then returns one row per out-edge of
-that set — a multiset, duplicates of a destination kept; piped into
-``YIELD COUNT(*)`` it returns the one row (count), and no row where
-the GO returned none (a pipe with no input yields nothing, on both of
-the program's backends).
+This module holds what every statement shape shares: the CSR over the
+labelled edge list, and the comparison of answers (an order-free
+digest and exact multiset equality).  What ONE shape of statement
+means is a module of its own, ``semantics/<kind>.py``, found by the
+``kind`` a traffic file's ``semantics`` gives:
+
+    answer(graph, semantics, key) -> Answer
+    ARITY = 2       # optional: vertices in one statement's key (1)
+
+so a deployment brings its statement shapes as new files.  ``key`` is
+one vertex label where ARITY is 1 and a tuple of ARITY labels where it
+is more.
 
 An answer is held in one of two forms: numeric columns (int64 arrays,
 compared as sorted row arrays or by an order-free digest) or, where a
@@ -15,12 +20,26 @@ column is not numeric, a sorted list of tuples.
 """
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple, Union
+import importlib
+from typing import List, Tuple, Union
 
 import numpy as np
 
 Answer = Union[Tuple[np.ndarray, ...], List[tuple]]
 _M1, _M2 = np.uint64(0x9E3779B97F4A7C15), np.uint64(0xC2B2AE3D27D4EB4F)
+
+
+def semantics_module(kind: str):
+    """``semantics/<kind>.py``; a kind with no such file is an error
+    that names the file to add."""
+    name = f"{__package__}.semantics.{kind}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ValueError(f"the reference has no semantics {kind!r}: add "
+                         f"benchmark/semantics/{kind}.py") from None
 
 
 class Graph:
@@ -39,16 +58,16 @@ class Graph:
         np.cumsum(np.bincount(src, minlength=top), out=self.ptr[1:])
         self.deg = np.diff(self.ptr)
 
-    def _frontier(self, start: int, hops: int) -> np.ndarray:
+    def frontier(self, start: int, hops: int) -> np.ndarray:
         """The set of vertices reached after ``hops`` hops."""
         frontier = np.asarray([start], np.int64)
         for _ in range(hops):
             seen = np.zeros(len(self.deg), bool)
-            seen[self.dst[self._edge_positions(frontier)]] = True
+            seen[self.dst[self.edge_positions(frontier)]] = True
             frontier = np.nonzero(seen)[0]
         return frontier
 
-    def _edge_positions(self, frontier: np.ndarray) -> np.ndarray:
+    def edge_positions(self, frontier: np.ndarray) -> np.ndarray:
         n = self.deg[frontier]
         total = int(n.sum())
         if total == 0:
@@ -57,30 +76,9 @@ class Graph:
                            - np.concatenate(([0], np.cumsum(n)[:-1])), n)
         return starts + np.arange(total)
 
-    def go(self, start: int, steps: int, yields: Sequence[str]
-           ) -> Tuple[np.ndarray, ...]:
-        pos = self._edge_positions(self._frontier(start, steps - 1))
-        cols = []
-        for y in yields:
-            if y == "_dst":
-                cols.append(self.dst[pos])
-            else:
-                table = np.asarray([row[y] for row in self.etable],
-                                   np.int64)
-                cols.append(table[self.eidx[pos]])
-        return tuple(cols)
-
-    def go_count(self, start: int, steps: int) -> int:
-        return int(self.deg[self._frontier(start, steps - 1)].sum())
-
-    def answer(self, semantics: dict, key: int) -> Answer:
-        kind = semantics["kind"]
-        if kind == "go":
-            return self.go(key, int(semantics["steps"]), semantics["yield"])
-        if kind == "go_count":      # GO ... | YIELD COUNT(*)
-            n = self.go_count(key, int(semantics["steps"]))
-            return [(n,)] if n else []
-        raise ValueError(f"the reference has no semantics {kind!r}")
+    def answer(self, semantics: dict, key) -> Answer:
+        return semantics_module(semantics["kind"]).answer(
+            self, semantics, key)
 
 
 def n_rows(ans: Answer) -> int:

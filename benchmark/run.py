@@ -46,12 +46,22 @@ def load_json(*parts: str) -> dict:
 
 
 def resolve(spec: dict, workload: str) -> dict:
-    """The cell's files, by the names BENCHMARK.json gives."""
+    """The cell's files, by the names BENCHMARK.json gives.  A
+    statement class whose ``semantics.kind`` has no module under
+    ``semantics/`` ends the run here, before any set-up."""
+    from benchmark import reference
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
         raise SystemExit(f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     files = {c["name"]: c["file"] for c in spec["configs"]}
+    traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
+    for name, cls in traffic["classes"].items():
+        try:
+            reference.semantics_module(cls["semantics"]["kind"])
+        except ValueError as e:
+            raise SystemExit(f"traffic {cell['traffic']!r}, class "
+                             f"{name!r}: {e}") from None
     here = lambda m: "workloads" not in m or workload in m["workloads"]  # noqa: E731
     # a per-layer metric's file is named by the part of its name before
     # the first ".": one reader definition serves <family>.<suffix> for
@@ -59,7 +69,7 @@ def resolve(spec: dict, workload: str) -> dict:
     return {
         "cell": cell,
         "config": load_json(ROOT, files[cell["config"]]),
-        "traffic": load_json(HERE, "traffic", cell["traffic"] + ".json"),
+        "traffic": traffic,
         "harness": load_json(HERE, "harness.json"),
         "end_to_end": [
             {**load_json(HERE, "end_metrics", m["name"] + ".json"), **m}
@@ -278,6 +288,12 @@ def compare(ev: dict) -> bool:
         r["traversal"] = mix.is_traversal(r["cls"])
         r["late"] = bool(deadline_s) and r["done"] - r["due"] > deadline_s
         r["failed"] = bool(r["problem"]) or r["wrong"] or r["late"]
+    ev["compared"] = {
+        "digest_mismatches": {"value": wrong, "limit": 0},
+        "exact_mismatches": {"value": exact_wrong, "limit": 0},
+        "served_counter_short": {"value": len(served_short), "limit": 0},
+        "health_problems": {"value": len(ev["health"]), "limit": 0},
+        "responses": {"value": len(records), "at_least": 1}}
     say("compared", responses=len(records), distinct_statements=len(wanted),
         digest_mismatches=wrong, digest_mismatch_limit=0,
         exact_compared=len(exact), exact_mismatches=exact_wrong,
@@ -294,23 +310,25 @@ def compare(ev: dict) -> bool:
         and not ev["health"] and bool(records)
 
 
-def traced_metrics(parts: dict, ev: dict, device: dict, peaks) -> tuple:
+def traced_metrics(parts: dict, ev: dict, device: dict, peaks,
+                   window: dict) -> tuple:
     """Reduce the profiler trace and run the cell's per-layer readers;
     returns (metrics, missing names, breakdown or None) and adds
-    busy_s / window_s to ``device``."""
+    busy_s / window_s to ``device``.  A trace that could not be read
+    leaves ``breakdown`` None and every device_trace metric missing."""
     from benchmark import reduce_trace, spans
-    window, records = ev["window"], ev["records"]
-    reduced = breakdown = None
-    path = reduce_trace.newest_trace(window.dir)
-    planes = None if window.error or path is None else \
+    trace, records = ev["window"], ev["records"]
+    reduced = breakdown = traced_us = None
+    path = reduce_trace.newest_trace(trace.dir)
+    planes = None if trace.error or path is None else \
         reduce_trace.read_planes(path)
     if planes is None or planes["sync_ns"] is None:
-        say("trace_missing", error=window.error, file=path)
+        say("trace_missing", error=trace.error, file=path)
     else:
-        off = window.sync_wall_ns - planes["sync_ns"]
-        reduced = reduce_trace.reduce(
-            planes, ev["t0"] * 1e9 + ev["wall_minus_perf_ns"] - off,
-            window.stop_wall_ns - off)
+        off = trace.sync_wall_ns - planes["sync_ns"]
+        lo_wall_ns = ev["t0"] * 1e9 + ev["wall_minus_perf_ns"]
+        reduced = reduce_trace.reduce(planes, lo_wall_ns - off,
+                                      trace.stop_wall_ns - off)
     if reduced:
         host = [(n, s - off, e - off)
                 for n, s, e in spans.flat(ev["trees"])]
@@ -322,7 +340,8 @@ def traced_metrics(parts: dict, ev: dict, device: dict, peaks) -> tuple:
                          reduced.pop("idle_gaps_ns"), host)}
         device.update(busy_s=reduced["busy_s"],
                       window_s=reduced["window_s"])
-        say("trace", start_s=window.start_s, stop_s=window.stop_s,
+        traced_us = (lo_wall_ns / 1e3, trace.stop_wall_ns / 1e3)
+        say("trace", start_s=trace.start_s, stop_s=trace.stop_s,
             programs=reduced["program_s"], runs=reduced["program_runs"],
             file=path, bytes=os.path.getsize(path))
     record = {"trees": ev["trees"], "flight": ev["flight"],
@@ -330,7 +349,12 @@ def traced_metrics(parts: dict, ev: dict, device: dict, peaks) -> tuple:
               "statements_done": sum(1 for r in records
                                      if r["done"] <= ev["t_end"]),
               "stages": ev["stages"], "trace": reduced,
+              # the traced interval on the clock of the flight
+              # records' time_us, None where there is no trace
+              "traced_us": traced_us,
               "facts": ev["facts"], "peaks": peaks,
+              # what the end-to-end quantities read (quantities.py)
+              "window": window,
               "late_s": [r["sent"] - r["due"] for r in records
                          if ev["mix"].groups[r["group"]]["spec"]["loop"]
                          == "open"]}
@@ -368,6 +392,15 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
         value = QUANTITIES[m["quantity"]](m, window)
         if value is not None:
             end_to_end[m["name"]] = {"value": value, "unit": m["unit"]}
+    # one line a statement, for whoever wants another statistic of the
+    # window than the metrics take: class, due / sent / done in seconds
+    # from the window's start, rows, failed
+    with open(os.path.join(OUT_DIR, "statements.jsonl"), "w") as fh:
+        for r in records:
+            fh.write(json.dumps([ev["mix"].class_names[r["cls"]],
+                                 r["due"] - ev["t0"],
+                                 r["sent"] - ev["t0"], r["done"] - ev["t0"],
+                                 r.get("rows"), r["failed"]]) + "\n")
     before, after = ev["counters"]["before"], ev["counters"]["after"]
     notes = {"stages": ev["stages"], "reference_s": ev["reference_s"],
              "compiles_in_window": after["compile.backend_compiles"]
@@ -395,9 +428,12 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
            "notes": notes}
     if trace:
         out["metrics"], notes["missing_per_layer"], breakdown = \
-            traced_metrics(parts, ev, out["device"], peaks)
+            traced_metrics(parts, ev, out["device"], peaks, window)
         if breakdown:
             out["breakdown"] = breakdown
+        else:
+            notes["trace_missing"] = True
+    out["compared"] = ev["compared"]    # last in the line, by contract
     return out
 
 
@@ -424,8 +460,16 @@ def main(argv=None) -> int:
               f"{parts['cell']['chips']} TPU chip(s); no result",
               file=sys.stderr)
         return 1
-    result = run_cell(parts, args.seed, args.seconds, bool(args.trace),
-                      device)
+    return finish(run_cell(parts, args.seed, args.seconds, bool(args.trace),
+                           device), bool(args.trace))
+
+
+def finish(result: dict, trace: bool) -> int:
+    """Print the notes, what stderr owes and the result line; returns
+    the exit code.  A listed per-layer metric whose reader found nothing
+    is left out of the line and named; a traced run prints no result
+    only where the profiler trace could not be read or no per-layer
+    metric at all found anything."""
     notes = result.pop("notes")
     say("notes", **notes)
     if notes["compiles_in_window"]:
@@ -434,9 +478,18 @@ def main(argv=None) -> int:
               f"inside the window: {notes['compiled_in_window']}",
               file=sys.stderr)
     if notes.get("missing_per_layer"):
-        print(f"per-layer metrics with nothing to read: "
+        # a reader of spans or counters that a later PR adds to the
+        # program finds nothing on that PR's parent
+        print(f"per-layer metrics with nothing to read, left out: "
               f"{notes['missing_per_layer']}", file=sys.stderr)
+    if trace and (notes.get("trace_missing") or not result["metrics"]):
+        print("no result: the profiler trace could not be read"
+              if notes.get("trace_missing") else
+              "no result: no per-layer metric found anything to read",
+              file=sys.stderr)
         return 1
+    for name, number in result["compared"].items():
+        print(f"compared {name}: {json.dumps(number)}", file=sys.stderr)
     print(json.dumps(result), flush=True)
     return 0
 

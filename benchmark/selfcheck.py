@@ -96,11 +96,11 @@ def check_reference_against_cpu_executor(spec: dict) -> list:
                           4_000_000_007, 2.0)
                 client = dep.client()
                 seq = mix.groups[0]["measured"]
-                for ci, key in list(zip(seq["cls"], seq["key"]))[:24]:
-                    stmt = mix.statement(int(ci), int(key))
+                for i in range(min(24, len(seq["cls"]))):
+                    ci, key = mix.at(seq, i)
+                    stmt = mix.statement(ci, key)
                     resp = client.execute(stmt)
-                    want = graph.answer(mix.classes[ci]["semantics"],
-                                        int(key))
+                    want = graph.answer(mix.classes[ci]["semantics"], key)
                     if not resp.ok() or not reference.same_rows(
                             columns_of(resp), want):
                         bad.append(f"{cell['name']}: CPU executor and "

@@ -26,6 +26,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import reference, selfcheck  # noqa: E402
+from benchmark.semantics.go import go  # noqa: E402
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 
@@ -45,7 +46,7 @@ def test_control_is_not_correct(seed, weaken):
     g = _graph(seed)
     sound = failed = 0
     for start in range(1, 40):
-        want = g.go(start, 2, ["_dst", "w"])
+        want = go(g, start, 2, ["_dst", "w"])
         if not reference.n_rows(want):
             continue
         again = tuple(c[::-1] for c in want)        # sound: another order

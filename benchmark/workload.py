@@ -6,8 +6,10 @@ it by, whether it is a traversal), groups of load (``open``: a Poisson
 schedule at ``rate_per_s``; ``closed``: ``clients`` callers that each
 wait for their reply, working through ``sequence`` statements), the
 start-key distribution, the warm-up and the traced sub-window.  Every
-statement has a start vertex of its own: nothing is replayed unless a
-closed group outruns its ``sequence``.
+statement has a key of its own, as many vertices as its semantics
+module's ``ARITY`` says (one unless it says otherwise; the template
+takes ``{v}``, or ``{v0}``, ``{v1}``, ... where there are more):
+nothing is replayed unless a closed group outruns its ``sequence``.
 
 What the seed changes and what it does not: the statement sequence and
 the arrival gaps are drawn from the configuration's ``structure_seed``
@@ -21,7 +23,7 @@ import itertools
 import queue
 import threading
 import time
-from typing import List, Optional, Tuple
+from typing import List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -34,14 +36,16 @@ SHUFFLE_BLOCK = 256
 
 class Mix:
     """The statement sequences of one traffic file on one labelled
-    data set: per group and phase, a class and a start vertex per
-    position (and a due time where the loop is open)."""
+    data set: per group and phase, a class and a key per position
+    (and a due time where the loop is open)."""
 
     def __init__(self, traffic: dict, data: dict, structure_seed: int,
                  seed: int, seconds: float):
         self.traffic = traffic
         self.class_names = list(traffic["classes"])
         self.classes = [traffic["classes"][n] for n in self.class_names]
+        self.arity = [int(getattr(reference.semantics_module(
+            c["semantics"]["kind"]), "ARITY", 1)) for c in self.classes]
         if traffic["start_keys"]["distribution"] != "uniform":
             raise ValueError("start_keys.distribution must be 'uniform'")
         self._cand = data["structural_with_out_edge"]
@@ -57,11 +61,16 @@ class Mix:
 
     def _sequence(self, g: dict, gi: int, seed: int, seconds: float,
                   phase: int) -> dict:
-        """(class index, start vertex[, due offset]) per position:
-        drawn from the structure seed, every start vertex uniformly
-        and without repeats from the vertices with an out-edge, put in
-        another order by ``seed`` (a closed group's inside blocks of
-        SHUFFLE_BLOCK positions)."""
+        """(class index, key[, due offset]) per position: drawn from
+        the structure seed, every vertex of a key uniformly from the
+        vertices with an out-edge (none twice in one place of the
+        key; a key may, once in half a million, name one vertex in
+        two places), put in another order by ``seed`` (a closed
+        group's inside blocks of SHUFFLE_BLOCK positions).  ``key`` is
+        one column where every class of the group has arity 1, and
+        one column a place of the widest class's key where not: each
+        further place draws from a generator of its own, so a mix of
+        arity 1 is offered as it was before there were others."""
         rng = np.random.default_rng([self._structure_seed, 0x5e9, gi,
                                      phase])
         order = np.random.default_rng([seed, 0x0d3, gi, phase])
@@ -75,6 +84,12 @@ class Mix:
                          size=n, p=shares / shares.sum())
         key = self._label[rng.choice(self._cand, size=n,
                                      replace=n > len(self._cand))]
+        arity = max(self.arity[self.class_names.index(x)] for x in names)
+        if arity > 1:
+            key = np.stack([key] + [self._label[np.random.default_rng(
+                [self._structure_seed, 0x5e9, gi, phase, place]).choice(
+                    self._cand, size=n, replace=n > len(self._cand))]
+                for place in range(1, arity)], axis=1)
         # a closed group completes a prefix of its sequence, so its
         # order changes inside blocks only: every seed then works
         # through the same statements, whatever prefix it reaches
@@ -88,12 +103,25 @@ class Mix:
             out["due"] = np.cumsum(gaps)
         return out
 
+    def at(self, seq: dict, i: int) -> Tuple[int, Union[int, tuple]]:
+        """(class index, key) of position ``i``: the key one vertex
+        label where the class's arity is 1, else a tuple of them."""
+        ci, key = int(seq["cls"][i]), np.atleast_1d(seq["key"][i])
+        if self.arity[ci] == 1:
+            return ci, int(key[0])
+        return ci, tuple(int(k) for k in key[:self.arity[ci]])
+
     def statement(self, ci: int, key) -> str:
-        """The class's template on one start vertex, or on several (a
-        warm-up statement: ``key`` is then a sequence)."""
+        """The class's template on its key: ``{v}`` takes the one
+        start vertex of a class of arity 1, or several (a warm-up
+        statement: ``key`` is then a sequence); ``{v0}``, ``{v1}``,
+        ... take the places of a longer key."""
         starts = [key] if np.isscalar(key) else key
-        return self.classes[ci]["template"].format(
-            v=", ".join(str(int(k)) for k in starts))
+        template = self.classes[ci]["template"]
+        if self.arity[ci] == 1:
+            return template.format(v=", ".join(str(int(k)) for k in starts))
+        return template.format(**{f"v{place}": int(k)
+                                  for place, k in enumerate(starts)})
 
     def is_traversal(self, ci: int) -> bool:
         return bool(self.classes[ci].get("traversal"))
@@ -101,15 +129,21 @@ class Mix:
     def warm(self, statements: int, starts: int, salt: int
              ) -> List[Tuple[int, tuple]]:
         """``statements`` traversal statements of ``starts`` start
-        vertices each, for a warm-up step (the same for every seed, up
+        vertices each (of one key each where the class's arity is
+        more than 1), for a warm-up step (the same for every seed, up
         to the labels)."""
         trav = [i for i in range(len(self.classes)) if self.is_traversal(i)]
         if not trav:
             return []
         rng = np.random.default_rng([self._structure_seed, 0xb07, salt])
-        keys = self._label[rng.choice(self._cand, size=(statements, starts))]
-        return [(trav[(j + salt) % len(trav)], tuple(keys[j]))
-                for j in range(statements)]
+        cls = [trav[(j + salt) % len(trav)] for j in range(statements)]
+        # a class of arity 1 takes ``starts`` vertices, another its key
+        take = [starts if self.arity[ci] == 1 else self.arity[ci]
+                for ci in cls]
+        keys = self._label[rng.choice(
+            self._cand, size=(statements, max([starts] + take)))]
+        return [(ci, tuple(keys[j][:take[j]]))
+                for j, ci in enumerate(cls)]
 
 
 def columns_of(resp) -> reference.Answer:
@@ -181,8 +215,8 @@ class Driver:
                 if now >= t_end:
                     return
                 i = pos % n
-                self._one(client, gi, pos, int(seq["cls"][i]),
-                          int(seq["key"][i]), now, bool(keep[i]))
+                self._one(client, gi, pos, *self.mix.at(seq, i), now,
+                          bool(keep[i]))
         except Exception as e:   # noqa: BLE001 — a dead client is a
             with self._lock:     # failed run, reported, not a lost one
                 self.errors.append(f"client of group {gi}: "
@@ -196,9 +230,8 @@ class Driver:
                 pos = jobs.get()
                 if pos is None:
                     return
-                self._one(client, gi, pos, int(seq["cls"][pos]),
-                          int(seq["key"][pos]), t0 + float(seq["due"][pos]),
-                          bool(keep[pos]))
+                self._one(client, gi, pos, *self.mix.at(seq, pos),
+                          t0 + float(seq["due"][pos]), bool(keep[pos]))
         except Exception as e:   # noqa: BLE001 — as above
             with self._lock:
                 self.errors.append(f"worker of group {gi}: "
