@@ -139,6 +139,11 @@ flags.define("expired_threshold_sec", 10 * 60, "host liveness TTL")
 flags.define("max_handlers_per_req", 10, "per-request bucket fan-out")
 flags.define("min_vertices_per_bucket", 3, "min vertices per bucket")
 flags.define("storage_backend", "auto", "storage traversal backend: cpu|tpu|auto")
+flags.define("find_path_max_paths", 1000,
+             "most rows one FIND PATH answers; over it the first that many "
+             "under the order docs/STATUS.md states (FIND PATH).  Read by "
+             "graphd's executor and by the device runtime's path walk, "
+             "so a storaged that serves deviceFindPath takes the same value")
 flags.define("storage_engine", "auto",
              "kv engine: native (C++ kv_engine.cc) | mem | auto")
 flags.define("store_type", None,

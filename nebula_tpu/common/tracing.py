@@ -87,6 +87,13 @@ SPAN_NAMES = (
     "tpu.launch",             # batch leader: frontier launch half
     "tpu.fetch",              # device→host result gather
     "tpu.assemble",           # host row materialization
+    "tpu.path_index",         # FIND PATH: the in-edge order of one
+                              # mirror generation and OVER set, built at
+                              # its first path statement (tag: edges)
+    "tpu.path_reconstruct",   # FIND PATH host half: the parent walk
+                              # from the BFS depths to path rows (tags:
+                              # depth, paths, on_path_vertices, capped,
+                              # cpu_us: the walk's own thread time)
     "rpc.fault",              # zero-duration marker: injected fault
     "graph.admission",        # zero-duration marker: admission decision
                               # (shed / deadline drop — batch_dispatch)
@@ -94,6 +101,11 @@ SPAN_NAMES = (
                               # trajectory through the continuous lane
                               # batch (lane, join tick, midflight —
                               # batch_dispatch _ContinuousStream)
+    "graph.batched",          # zero-duration marker: a rider's time
+                              # in the windowed tier (submit_batched):
+                              # tags method, riders, pool_wait_us (its
+                              # batch starts), run_us (its batch ends),
+                              # wake_us (its thread runs again)
     "tpu.breaker",            # zero-duration marker: device breaker
                               # decline / classified runtime failure
                               # (tpu/runtime.py, docs/durability.md)

@@ -201,7 +201,7 @@ class AdmissionShed(DeadlineExceeded):
 
 class _Request:
     __slots__ = ("payload", "done", "result", "mirror", "error",
-                 "deadline", "enq_t", "qid")
+                 "deadline", "enq_t", "qid", "run_t", "done_t", "riders")
 
     def __init__(self, payload, deadline=None):
         self.payload = payload   # per-query input, method-defined (GO:
@@ -212,6 +212,10 @@ class _Request:
         self.error = None
         self.deadline = deadline         # common/deadline.py Deadline|None
         self.enq_t = time.perf_counter()
+        # set by the leader that took this request into its batch
+        # (_run): when the batch started and ended, how many rode it
+        self.run_t = self.done_t = None
+        self.riders = 0
         # live-query-registry id (KILL QUERY's handle on this waiter),
         # captured thread-locally like the deadline budget
         self.qid = current_qid()
@@ -1718,6 +1722,17 @@ class GoBatchDispatcher:
                     st.cond.notify_all()
         finally:
             st.cond.release()
+        if req.done_t is not None:
+            # the rider's time in here, in order, on its OWN trace:
+            # pooled behind the batch in flight and the window, its
+            # batch on the device and through the fetch, until its
+            # thread runs again
+            woke = time.perf_counter()
+            tracing.annotate(
+                "graph.batched", method=key[0], riders=req.riders,
+                pool_wait_us=int((req.run_t - req.enq_t) * 1e6),
+                run_us=int((req.done_t - req.run_t) * 1e6),
+                wake_us=int((woke - req.done_t) * 1e6))
         if req.error is not None:
             if isinstance(req.error, DeadlineExceeded) \
                     and not isinstance(req.error, AdmissionShed):
@@ -1760,6 +1775,8 @@ class GoBatchDispatcher:
         t_run0 = time.perf_counter()
         n_errors = 0
         live = batch
+        for r in batch:
+            r.run_t, r.riders = t_run0, len(batch)
         try:
             if flags.get("admission_control", True):
                 # pre-launch expiry drop: entries whose budget ran out
@@ -1838,5 +1855,7 @@ class GoBatchDispatcher:
                 self.stats["query_errors"] += n_errors
                 self.stats["max_batch"] = max(self.stats["max_batch"],
                                               len(batch))
+            t_done = time.perf_counter()
             for r in batch:
+                r.done_t = t_done
                 r.done = True

@@ -1076,10 +1076,13 @@ class AssignmentExecutor(Executor):
 class FindPathExecutor(Executor):
     """FIND SHORTEST|ALL PATH — layered BFS with parent tracking over the
     getNeighbors seam (CPU path; the TPU runtime runs the same search as a
-    jitted bidirectional BFS over the CSR mirror)."""
+    jitted batched BFS over the ELL tables).  At most
+    ``find_path_max_paths`` rows (the flag), the first under the order
+    tpu/runtime.py states above its path walk: targets by ascending
+    id, each vertex's parent edges by ascending (source id, edge type,
+    rank), depth first."""
 
     NAME = "FindPathExecutor"
-    MAX_PATHS = 1000
 
     def execute(self) -> InterimResult:
         self.check_space_chosen()
@@ -1169,7 +1172,12 @@ class FindPathExecutor(Executor):
                                 unfound.discard(dst)
             frontier = nxt
 
+        # the cut at find_path_max_paths is a rule over ids, not over the order
+        # the responses came in
+        for edges_in in parents.values():
+            edges_in.sort()
         paths: List[str] = []
+        max_paths = int(flags.get("find_path_max_paths"))
 
         def fmt(chain: List, start: int) -> str:
             parts = [str(start)]
@@ -1179,7 +1187,7 @@ class FindPathExecutor(Executor):
             return " ".join(parts)
 
         def build_shortest(v: int, acc: List, depth: int):
-            if len(paths) >= self.MAX_PATHS:
+            if len(paths) >= max_paths:
                 return
             if depth == 0:
                 if v in src_set:
@@ -1190,7 +1198,7 @@ class FindPathExecutor(Executor):
                     build_shortest(prev, [(et, rank, v)] + acc, depth - 1)
 
         def build_all(v: int, acc: List, visited: Set[int]):
-            if len(paths) >= self.MAX_PATHS or len(acc) > max_steps:
+            if len(paths) >= max_paths or len(acc) > max_steps:
                 return
             if v in src_set and acc:
                 paths.append(fmt(acc, v))
@@ -1199,7 +1207,7 @@ class FindPathExecutor(Executor):
                 if prev not in visited:
                     build_all(prev, [(et, rank, v)] + acc, visited | {prev})
 
-        for d in dsts:
+        for d in sorted(target_set):
             if s.shortest:
                 if d in depth_of and depth_of[d] > 0:
                     build_shortest(d, [], depth_of[d])

@@ -15,12 +15,14 @@ from .client import MetaClient
 _MANAGED = {
     ConfigModule.GRAPH: ["session_idle_timeout_secs",
                          "session_reclaim_interval_secs",
-                         "storage_backend"],
+                         "storage_backend",
+                         "find_path_max_paths"],
     ConfigModule.META: ["expired_threshold_sec"],
     ConfigModule.STORAGE: ["heartbeat_interval_secs",
                            "load_data_interval_secs",
                            "max_handlers_per_req",
                            "min_vertices_per_bucket",
+                           "find_path_max_paths",
                            "raft_heartbeat_interval_s",
                            "raft_election_timeout_s",
                            "wal_buffer_size_bytes"],

@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+import threading
 import time
 
 from ..codec.rows import RowReader
@@ -239,6 +240,11 @@ class CsrMirror:
         self.has_tag: Dict[int, np.ndarray] = {}
         self.build_version = -1
         self._device = None   # populated lazily by runtime/kernels
+        # FIND PATH's in-edge order by OVER set, built by the runtime
+        # at this generation's first path statement under this lock
+        # (runtime._path_index); host memory, goes with the generation
+        self._path_index: Dict[Tuple[int, ...], tuple] = {}
+        self._path_index_lock = threading.Lock()
         # earliest future TTL expiry among mirrored rows (seconds), or
         # None; the runtime rebuilds once this passes so aging rows drop
         # out in lockstep with the CPU read path

@@ -1268,8 +1268,9 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
     cost center); the depth matrix stays per-lane (its updates are
     streaming elementwise, and it IS the result).
 
-    fn(f0p, t0p, eslot, hrows, *tables) -> depth [n_rows+1, B] (int8
-    with -1 = unreachable when max_steps fits, else int16)."""
+    fn(f0p, t0p, eslot, hrows, *tables) -> (depth [n_rows+1, B] (int8
+    with -1 = unreachable when max_steps fits, else int16), the levels
+    the loop ran (int32 scalar))."""
     import jax
     import jax.numpy as jnp
     n, n_extras, nb_count = ell.n, len(ell.extra_owner), \
@@ -1297,11 +1298,11 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
             d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
             return d, _pack_lanes(jnp, newly), step + 1
 
-        d, _, _ = jax.lax.while_loop(cond, body,
-                                     (d0, f0p, jnp.int32(0)))
+        d, _, levels = jax.lax.while_loop(cond, body,
+                                          (d0, f0p, jnp.int32(0)))
         if small:
-            return jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
-        return d
+            d = jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
+        return d, levels
 
     return jax.jit(bfs, donate_argnums=(0, 1) if donate else ())
 
@@ -1798,7 +1799,8 @@ def make_batched_bfs_kernel(ell: EllIndex, max_steps: int,
                             etypes: Tuple[int, ...],
                             stop_when_found: bool = True,
                             donate: bool = False):
-    """fn(f0, targets, owner, *tables) -> depth [n_rows+1, B]:
+    """fn(f0, targets, owner, *tables) -> (depth [n_rows+1, B], the
+    levels the loop ran): depth is
     int8 with -1 = unreachable when max_steps fits (the transfer is 2x
     smaller and depths are tiny), else int16 with INT16_INF.  Batched
     analogue of kernels.make_bfs_kernel; early exit when every query
@@ -1829,10 +1831,11 @@ def make_batched_bfs_kernel(ell: EllIndex, max_steps: int,
             d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
             return d, newly.astype(jnp.int8), step + 1
 
-        d, _, _ = jax.lax.while_loop(cond, body, (d0, f0, jnp.int32(0)))
+        d, _, levels = jax.lax.while_loop(cond, body,
+                                          (d0, f0, jnp.int32(0)))
         if small:
-            return jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
-        return d
+            d = jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
+        return d, levels
 
     # both frontier matrices are built fresh per dispatch by
     # runtime._bfs_depths — single-use there, so the runtime opts in
@@ -1987,9 +1990,9 @@ def make_sharded_batched_bfs_kernel(mesh, axis: str, ell: EllIndex,
     of make_batched_bfs_lanes_kernel, same depth/early-exit/compression
     semantics: the frontier rides the hops (and the per-hop ICI
     re-replication) bit-packed while the depth matrix stays per-lane
-    (it IS the result).  fn(f0p, t0p, eslot, hrows, *tables) -> depth
-    [n_rows+1, B] (int8 with -1 = unreachable when max_steps fits,
-    else int16)."""
+    (it IS the result).  fn(f0p, t0p, eslot, hrows, *tables) ->
+    (depth [n_rows+1, B] (int8 with -1 = unreachable when max_steps
+    fits, else int16), the levels the loop ran)."""
     import jax
     import jax.numpy as jnp
     hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, nbr_shards,
@@ -2015,11 +2018,11 @@ def make_sharded_batched_bfs_kernel(mesh, axis: str, ell: EllIndex,
             d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
             return d, _pack_lanes(jnp, newly), step + 1
 
-        d, _, _ = jax.lax.while_loop(
+        d, _, levels = jax.lax.while_loop(
             cond, body, (d0, f0p, jnp.int32(0)))
         if small:
-            return jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
-        return d
+            d = jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
+        return d, levels
 
     return jax.jit(bfs, donate_argnums=(0, 1) if donate else ())
 

@@ -446,8 +446,9 @@ DEVICE_PHASES = {
     # published generation's device arrays — nothing crosses the link
     # back)
     "ell_absorb": {"phases": ("tpu.absorb",), "h2d": 3, "d2h": 2},
+    # the second fetch is the level count the loop ran (4 bytes)
     "ell_bfs": {"phases": ("tpu.kernel", "tpu.fetch"), "h2d": 2,
-                "d2h": 1},
+                "d2h": 2},
     # continuous hop-boundary batching (graph/batch_dispatch.py,
     # docs/admission.md "Continuous dispatch"): the resident frontier
     # pair never crosses the link — hop/join/clear "fetches" are the
@@ -465,7 +466,7 @@ DEVICE_PHASES = {
                                   "tpu.fetch", "tpu.assemble"),
                        "h2d": 1, "d2h": 1},
     "ell_bfs_sharded": {"phases": ("tpu.kernel", "tpu.fetch"),
-                        "h2d": 2, "d2h": 1},
+                        "h2d": 2, "d2h": 2},
     "mesh_sparse_go": {"phases": ("tpu.launch", "tpu.kernel",
                                   "tpu.fetch", "tpu.assemble"),
                        "h2d": 2, "d2h": 1},
@@ -518,7 +519,14 @@ class TpuQueryRuntime:
         self._dispatcher = None   # lazy GoBatchDispatcher
         # observability (tests assert the device path actually ran;
         # webservice /get_stats exports these)
-        self.stats = {"go_device": 0, "path_device": 0, "mirror_builds": 0,
+        self.stats = {"go_device": 0, "path_device": 0,
+                      # FIND PATH: BFS levels the device loops ran,
+                      # rows answered, statements cut at find_path_max_paths,
+                      # in-edge orders built (one a mirror generation
+                      # and OVER set, at its first path statement)
+                      "path_levels": 0, "path_rows": 0, "path_capped": 0,
+                      "path_index_builds": 0,
+                      "mirror_builds": 0,
                       "mirror_deltas": 0, "mirror_absorbs": 0,
                       "mirror_absorb_failed": 0,
                       "mirror_delta_overflow": 0,
@@ -3381,9 +3389,12 @@ class TpuQueryRuntime:
                     targets_per_query, et_tuple: Tuple[int, ...],
                     max_steps: int, shortest: bool) -> np.ndarray:
         """Batched BFS core against an already-fetched mirror: int16
-        [B, n] depths (INT16_INF = unreached)."""
+        [B, n] depths (INT16_INF = unreached).  The dispatch record
+        carries the levels the device loop ran and the lanes used."""
         from .ell import (INT16_INF, make_batched_bfs_kernel,
                           make_sharded_batched_bfs_kernel)
+        import time
+        stamps = [time.perf_counter()]
         ix = self.ell(m)
         nq = len(starts_per_query)
         B = self._batch_width(nq)
@@ -3440,16 +3451,32 @@ class TpuQueryRuntime:
                 ix, *self._flat_coords(m, ix, targets_per_query, nq), B)
             call_args = (f0_dev, t0_dev, *args)
         self._bump("path_device", nq)
+        stamps.append(time.perf_counter())
         with tracing.span("tpu.kernel",
                           kind="ell_bfs" if mt is None
                           else "ell_bfs_sharded", queries=nq):
-            d_dev = kern(*call_args)
+            d_dev, levels_dev = kern(*call_args)
         from .ell import dense_hop_bytes, lanes_width
         self._maybe_time_device(
             d_dev,
             dense_hop_bytes(ix, lanes_width(B) if packed_mode else B,
                             max_steps + 1),
             kind="ell_bfs")
+        stamps.append(time.perf_counter())
+        nqp = min(B, max(8, -(-nq // 8) * 8))
+        with tracing.span("tpu.fetch"):
+            host = np.asarray(d_dev[:, :nqp])[:, :nq]   # device slice
+            levels = int(levels_dev)
+            self._note_fetch(host)
+        stamps.append(time.perf_counter())
+        self._bump("path_levels", levels)
+        # the record is written once the level count is on the host,
+        # so its time is the dispatch's end; its stages (tables +
+        # kernel lookup + frontier upload, the asynchronous launch, the
+        # wait for the device + the copy) tile the dispatch, traced or
+        # not, so a dispatch that stood still says where
+        stages = {k: int((b - a) * 1e6) for k, a, b in zip(
+            ("upload_us", "enqueue_us", "fetch_us"), stamps, stamps[1:])}
         if mt is not None:
             # live ICI accounting: the spec declares the frontier
             # re-replication PER LEVEL; trips scales both sides by the
@@ -3460,14 +3487,12 @@ class TpuQueryRuntime:
                 [("sharding_constraint", fbytes * max_steps)],
                 trips=max_steps, ell=ix, widths=(B,),
                 fields={"rung": B, "steps": max_steps,
-                        "h2d_bytes": 2 * fbytes})
+                        "h2d_bytes": 2 * fbytes, "levels": levels,
+                        "queries": nq, **stages})
         else:
             _flight.recorder.note_dispatch(
-                "ell_bfs", rung=B, steps=max_steps)
-        nqp = min(B, max(8, -(-nq // 8) * 8))
-        with tracing.span("tpu.fetch"):
-            host = np.asarray(d_dev[:, :nqp])[:, :nq]   # device slice
-            self._note_fetch(host)
+                "ell_bfs", rung=B, steps=max_steps, levels=levels,
+                queries=nq, **stages)
         if host.dtype == np.int8:        # in-kernel compression (-1=INF)
             d = np.where(host < 0, INT16_INF, host).astype(np.int16)
         else:
@@ -3609,7 +3634,6 @@ class TpuQueryRuntime:
                       dsts: List[int], etypes: List[int], max_steps: int,
                       shortest: bool, etype_names: Dict[int, str]
                       ) -> InterimResult:
-        from .ell import INT16_INF
         from ..storage.device import TpuDecline, classify_device_failure
         if not srcs or not dsts:
             return InterimResult(["path"])
@@ -3646,12 +3670,53 @@ class TpuQueryRuntime:
         self.breaker.record_success(bkey)
         if m.m == 0:
             return InterimResult(["path"])
-        depth = np.where(d16 == INT16_INF, kernels.INT32_INF,
-                         d16.astype(np.int32))
 
-        # --- host half: parent-DAG reconstruction -------------------
-        return _reconstruct_paths(m, depth, srcs, dsts, et_tuple, max_steps,
-                                  shortest, etype_names)
+        # --- host half: parent-DAG reconstruction over the in-edge
+        # order this mirror generation keeps -------------------------
+        import time
+        index = self._path_index(m, et_tuple)
+        with tracing.span("tpu.path_reconstruct") as sp:
+            # the span's wall is shared with every other walk of the
+            # batch under the interpreter lock; cpu_us is this one's own
+            cpu0 = time.thread_time()
+            paths, found = _reconstruct_paths(
+                m, index, d16, srcs, dsts, max_steps, shortest,
+                etype_names)
+            if sp is not None:
+                sp.tag(paths=len(paths), **found, cpu_us=int(
+                    (time.thread_time() - cpu0) * 1e6))
+        self._bump("path_rows", len(paths))
+        if found["capped"]:
+            self._bump("path_capped")
+        return InterimResult(["path"], [[p] for p in sorted(paths)])
+
+    def _path_index(self, m: CsrMirror, et_tuple: Tuple[int, ...]):
+        """The in-edge order of one mirror generation for one OVER set:
+        (ptr int64 [n+1], edge ids) with vertex v's in-edges of those
+        types at ``[ptr[v]:ptr[v+1]]`` by ascending (source vid, etype,
+        rank) — the order _reconstruct_paths cuts by.  Built at the
+        generation's first path statement and kept on the mirror (host
+        memory only: 4 bytes an edge of those types), so it goes when
+        the generation does."""
+        with m._path_index_lock:
+            if et_tuple not in m._path_index:
+                with tracing.span("tpu.path_index", edges=int(m.m)):
+                    of_type = np.nonzero(np.isin(
+                        m.edge_etype, np.asarray(et_tuple, np.int32)))[0]
+                    dst = m.edge_dst[of_type]
+                    # the edge arrays are in (src, etype, rank, dst)
+                    # order and dense ids ascend with vids, so a stable
+                    # sort by destination leaves each vertex's in-edges
+                    # in that order
+                    edge = of_type[np.argsort(dst, kind="stable")]
+                    ptr = np.zeros(m.n + 1, np.int64)
+                    np.cumsum(np.bincount(dst, minlength=m.n),
+                              out=ptr[1:])
+                    if m.m < 2 ** 31:
+                        edge = edge.astype(np.int32)
+                m._path_index[et_tuple] = (ptr, edge)
+                self._bump("path_index_builds")
+            return m._path_index[et_tuple]
 
     def serve_find_path(self, space_id: int, srcs: List[int],
                         dsts: List[int], etypes: List[int], max_steps: int,
@@ -3888,79 +3953,151 @@ class _LaneFetch:
 
 
 # ================================================== path reconstruction
-MAX_PATHS = 1000
+# A FIND PATH answers at most ``find_path_max_paths`` rows (the flag,
+# common/flags.py), chosen by a rule over vertex ids (docs/STATUS.md
+# "FIND PATH"): a path is read from its target backwards, one step an
+# edge (source vid, then edge type, then rank), the smaller step first
+# and a path before its extensions; the first that many under that
+# order are the answer.  FindPathExecutor's CPU walk
+# (graph/executors/traverse.py) cuts by the same order.
 
 
-def _reconstruct_paths(m: CsrMirror, depth: np.ndarray, srcs, dsts,
-                       et_tuple, max_steps: int, shortest: bool,
-                       etype_names: Dict[int, str]) -> InterimResult:
-    """Host half of FIND PATH — mirrors FindPathExecutor's parent walk
-    (traverse.py) over the CSR's in-edge view instead of RPC responses."""
-    etype_ok = np.isin(m.edge_etype, np.asarray(et_tuple, dtype=np.int32))
-    # in-edge index: edges sorted by dst
-    order = np.argsort(m.edge_dst, kind="stable")
-    sorted_dst = m.edge_dst[order]
-
+def _reconstruct_paths(m: CsrMirror, index, depth: np.ndarray, srcs, dsts,
+                       max_steps: int, shortest: bool,
+                       etype_names: Dict[int, str]):
+    """Host half of FIND PATH: the parent walk of FindPathExecutor
+    (traverse.py) over the mirror generation's in-edge order (``index``,
+    TpuRuntime._path_index) instead of RPC responses.  ``depth`` is the
+    BFS's row for this statement (INT16_INF = unreached).  The work is
+    the in-edges of the vertices on answered paths, never the edge
+    table.  Returns (path strings, the span's tags: ``capped`` whether
+    more than the cap existed, ``on_path_vertices`` the distinct
+    vertices visited, ``depth`` the longest answered path's edges)."""
+    from .ell import INT16_INF
+    targets = np.unique(m.to_dense(dsts))
+    targets = targets[targets >= 0]
     src_set = {int(i) for i in m.to_dense(srcs) if i >= 0}
     paths: List[str] = []
+    max_paths = int(flags.get("find_path_max_paths"))
+    found = {"capped": False, "on_path_vertices": 0, "depth": 0}
 
-    def in_edges(v: int) -> np.ndarray:
-        lo = np.searchsorted(sorted_dst, v, "left")
-        hi = np.searchsorted(sorted_dst, v, "right")
-        return order[lo:hi]
+    def rows(verts: np.ndarray, eids: np.ndarray) -> List[str]:
+        """Path strings of chains of one length: ``verts`` [k, D+1]
+        dense ids target first, ``eids`` [k, D] the edges between."""
+        vids = m.vids[verts[:, ::-1]].tolist()
+        ets = m.edge_etype[eids[:, ::-1]].tolist()
+        ranks = m.edge_rank[eids[:, ::-1]].tolist()
+        return [" ".join([str(vs[0])] + [
+            f"<{etype_names.get(et, et)},{rank}> {v}"
+            for et, rank, v in zip(es, rs, vs[1:])])
+            for vs, es, rs in zip(vids, ets, ranks)]
 
-    def fmt(chain, start_dense: int) -> str:
-        parts = [str(int(m.vids[start_dense]))]
-        for (etype, rank, node) in chain:
-            parts.append(f"<{etype_names.get(etype, etype)},{rank}>")
-            parts.append(str(int(m.vids[node])))
-        return " ".join(parts)
+    for t in targets.tolist():
+        if shortest and not 0 < depth[t] < INT16_INF:
+            continue
+        budget = max_paths - len(paths)
+        if shortest and not budget:     # a further target with paths
+            found["capped"] = True
+            break
+        if shortest:
+            verts, eids, more, seen = _shortest_chains(
+                m, index, depth, t, budget)
+            paths += rows(verts, eids)
+            longest = eids.shape[1]
+        else:
+            chains, more, seen = _all_chains(
+                m, index, depth, t, src_set, max_steps, budget)
+            for v, e in chains:
+                paths += rows(np.asarray([v]), np.asarray([e]))
+            longest = max((len(e) for _, e in chains), default=0)
+        found["capped"] |= more
+        found["on_path_vertices"] += seen
+        found["depth"] = max(found["depth"], longest)
+    return paths, found
 
-    if shortest:
-        def build_shortest(v: int, acc, d: int):
-            if len(paths) >= MAX_PATHS:
-                return
-            if d == 0:
-                if v in src_set:
-                    paths.append(fmt(acc, v))
-                return
-            for e in in_edges(v):
-                if not etype_ok[e]:
-                    continue
-                u = int(m.edge_src[e])
-                if depth[u] == d - 1:
-                    build_shortest(u, [(int(m.edge_etype[e]),
-                                        int(m.edge_rank[e]), v)] + acc,
-                                   d - 1)
 
-        for dd in m.to_dense(dsts):
-            dd = int(dd)
-            if dd >= 0 and 0 < depth[dd] < kernels.INT32_INF:
-                build_shortest(dd, [], int(depth[dd]))
-    else:
-        # ALL: every edge whose src was discovered within max_steps-1
-        # is a parent edge (FindPathExecutor records exactly those)
-        parent_edge = etype_ok & (depth[m.edge_src] <= max_steps - 1)
+def _shortest_chains(m: CsrMirror, index, depth: np.ndarray, t: int,
+                     budget: int):
+    """The first ``budget`` least-length paths into ``t`` in the cap's
+    order, level by level from the target: every partial
+    path is extended by its last vertex's in-edges whose source lies
+    one level nearer a start.  Each such partial has a completion, so
+    the first ``budget`` partials of a level hold the first ``budget``
+    paths and the rest are dropped there.  Returns (vertices [k, D+1]
+    target first, edge ids [k, D], whether paths were dropped, distinct
+    vertices visited)."""
+    ptr, edge = index
+    verts = np.asarray([[t]], np.int64)
+    eids = np.zeros((1, 0), np.int64)
+    dropped, seen = False, 0
+    for d in range(int(depth[t]), 0, -1):
+        uniq, inv = np.unique(verts[:, -1], return_inverse=True)
+        inv = inv.reshape(-1)
+        seen += len(uniq)
+        # the in-edges of the level's distinct vertices, kept where
+        # the source's depth is d - 1, as a CSR over ``uniq``
+        lo, cnt = ptr[uniq], ptr[uniq + 1] - ptr[uniq]
+        cand = edge[np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+                    + np.arange(int(cnt.sum()))]
+        keep = depth[m.edge_src[cand]] == d - 1
+        cand = cand[keep]
+        pcnt = np.bincount(np.repeat(np.arange(len(uniq)), cnt)[keep],
+                           minlength=len(uniq))
+        pstart = np.cumsum(pcnt) - pcnt
+        # partial i takes its vertex's parents, in order, until the
+        # budget is spent
+        take = pcnt[inv]
+        before = np.cumsum(take) - take
+        dropped |= int(take.sum()) > budget
+        take = np.minimum(take, np.maximum(budget - before, 0))
+        before = np.cumsum(take) - take
+        rows = np.repeat(np.arange(len(verts)), take)
+        chosen = cand[np.repeat(pstart[inv] - before, take)
+                      + np.arange(int(take.sum()))]
+        verts = np.concatenate([verts[rows], m.edge_src[chosen][:, None]],
+                               axis=1)
+        eids = np.concatenate([eids[rows], chosen[:, None]], axis=1)
+    return verts, eids, dropped, seen + len(np.unique(verts[:, -1]))
 
-        def build_all(v: int, acc, visited):
-            if len(paths) >= MAX_PATHS or len(acc) > max_steps:
-                return
-            if v in src_set and acc:
-                paths.append(fmt(acc, v))
-            for e in in_edges(v):
-                if not parent_edge[e]:
-                    continue
-                u = int(m.edge_src[e])
-                if u not in visited:
-                    build_all(u, [(int(m.edge_etype[e]),
-                                   int(m.edge_rank[e]), v)] + acc,
-                              visited | {u})
 
-        for dd in m.to_dense(dsts):
-            dd = int(dd)
-            if dd >= 0:
-                build_all(dd, [], {dd})
-    return InterimResult(["path"], [[p] for p in sorted(paths)])
+def _all_chains(m: CsrMirror, index, depth: np.ndarray, t: int,
+                src_set, max_steps: int, budget: int):
+    """FIND ALL PATH into ``t``: the first ``budget`` simple paths of at
+    most ``max_steps`` edges from a start, in the cap's order
+    (depth first, a vertex's in-edges in index order).  Every
+    edge whose source was discovered within max_steps - 1 levels is a
+    parent edge, as FindPathExecutor records them.  Returns
+    ([(vertices target first, edge ids)], whether paths were dropped,
+    distinct vertices visited)."""
+    ptr, edge = index
+
+    def parents(v: int):
+        into = edge[ptr[v]:ptr[v + 1]]
+        return iter(into[depth[m.edge_src[into]] <= max_steps - 1].tolist())
+
+    chains, seen = [], {t}
+    verts, eids, stack = [t], [], [parents(t)]
+    while stack:
+        e = next(stack[-1], None)
+        if e is None:
+            stack.pop()
+            verts.pop()
+            if eids:
+                eids.pop()
+            continue
+        u = int(m.edge_src[e])
+        if u in verts:
+            continue
+        seen.add(u)
+        if u in src_set:
+            if len(chains) >= budget:
+                return chains, True, len(seen)
+            chains.append((verts + [u], eids + [e]))
+        if len(eids) + 1 < max_steps:
+            verts.append(u)
+            eids.append(e)
+            stack.append(parents(u))
+    return chains, False, len(seen)
 
 
 # ================================================== small helpers

@@ -24,7 +24,8 @@ def run_go(ix, steps, etypes, f0):
 def run_bfs(ix, max_steps, etypes, f0, t0, stop_when_found=True):
     k = E.make_batched_bfs_kernel(ix, max_steps, etypes,
                                   stop_when_found=stop_when_found)
-    d = np.asarray(k(jnp.asarray(f0), jnp.asarray(t0), *ix.kernel_args()))
+    d = np.asarray(k(jnp.asarray(f0), jnp.asarray(t0),
+                     *ix.kernel_args())[0])
     if d.dtype == np.int8:           # in-kernel compression (-1 = INF)
         d = np.where(d < 0, E.INT16_INF, d).astype(np.int16)
     return d

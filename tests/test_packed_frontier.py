@@ -83,17 +83,21 @@ class TestPackedKernelParity:
         B = 16
         f0 = ix.start_frontier(_starts(rng, ix.n, B, per=2), B=B)
         t0 = ix.start_frontier(_starts(rng, ix.n, B, per=2), B=B)
-        ref = np.asarray(E.make_batched_bfs_kernel(
+        ref, ref_levels = E.make_batched_bfs_kernel(
             ix, 5, ETYPES, stop_when_found=shortest)(
-            jnp.asarray(f0), jnp.asarray(t0), *ix.kernel_args()))
+            jnp.asarray(f0), jnp.asarray(t0), *ix.kernel_args())
         eslot, hrows = ix.hub_merge()
-        out = np.asarray(E.make_batched_bfs_lanes_kernel(
+        out, levels = E.make_batched_bfs_lanes_kernel(
             ix, 5, ETYPES, stop_when_found=shortest)(
             jnp.asarray(E.pack_lanes_host(f0)),
             jnp.asarray(E.pack_lanes_host(t0)),
             jnp.asarray(eslot), jnp.asarray(hrows),
-            *ix.kernel_args()[1:]))
-        assert (ref[:ix.n] == out[:ix.n]).all()
+            *ix.kernel_args()[1:])
+        assert (np.asarray(ref)[:ix.n] == np.asarray(out)[:ix.n]).all()
+        # both report the levels the loop ran: at least the deepest
+        # depth it stamped, at most the cap
+        assert int(levels) == int(ref_levels)
+        assert np.asarray(out)[:ix.n].max() <= int(levels) <= 5
 
     def test_absorbed_tables_match_int8_and_packed_hops(self):
         """Absorb a delta into the resident tables (plan + host apply
@@ -580,19 +584,21 @@ class TestShardedPackedParity:
         f0 = ix.start_frontier(_starts(rng, ix.n, B), B=B)
         t0 = ix.start_frontier(
             [rng.integers(0, ix.n, 2) for _ in range(B)], B=B)
-        ref = np.asarray(E.make_batched_bfs_kernel(
+        ref, ref_levels = E.make_batched_bfs_kernel(
             ix, max_steps, ETYPES, stop_when_found=shortest)(
-            jnp.asarray(f0), jnp.asarray(t0), *ix.kernel_args()))
+            jnp.asarray(f0), jnp.asarray(t0), *ix.kernel_args())
+        ref = np.asarray(ref)
         eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
         mesh = self._mesh(k)
         nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
         bfs = E.make_sharded_batched_bfs_kernel(
             mesh, "parts", ix, max_steps, ETYPES, nbrs, ets, reals,
             stop_when_found=shortest)
-        d = np.asarray(bfs(jnp.asarray(E.pack_lanes_host(f0)),
-                           jnp.asarray(E.pack_lanes_host(t0)),
-                           eslot, hrows, *nbrs, *ets))
-        np.testing.assert_array_equal(d, ref)
+        d, levels = bfs(jnp.asarray(E.pack_lanes_host(f0)),
+                        jnp.asarray(E.pack_lanes_host(t0)),
+                        eslot, hrows, *nbrs, *ets)
+        np.testing.assert_array_equal(np.asarray(d), ref)
+        assert int(levels) == int(ref_levels)
 
     def test_sharded_donation_consumes_frontier(self):
         """donate=True (the runtime's dispatch configuration) must
