@@ -127,7 +127,11 @@ class FlightRecorder:
         stream keyed ``stream``: seat churn counts, per-phase micros
         in pump order (seat, then the join/hop/extract/clear ENQUEUES,
         then the leave cohort's fetch_wait/d2h/unpack/rows/handover,
-        whose sum is assemble_us), leaver_rows, the hops whose branch
+        whose sum is assemble_us), what unpack_us met (unpack_leavers
+        unpacked in the tick's cohorts, of them unpack_live out of the
+        live rows of the fetched block and not out of the whole table,
+        unpack_rows those live rows, summed over the cohorts —
+        tpu/runtime.py _unpack_lanes), leaver_rows, the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
         the live slot rows; hop_slots the ELL slots they visited),
         idle gap since the previous tick, mirror generation, tick wall

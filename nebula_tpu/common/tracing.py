@@ -128,8 +128,8 @@ SPAN_NAMES = (
                               # extract buffer (the device, seen from
                               # the pump)
     "pump.d2h",               # the copy after that wait
-    "pump.unpack",            # per-leaver bit extraction + perm
-                              # gather + nonzero
+    "pump.unpack",            # the cohort's live rows found once,
+                              # per leaver its bit + inv + sort
     "pump.rows",              # COUNT folds + grouped row assembly
     "pump.handover",          # results published, waiters notified
     "pump.idle",              # root: no tick in flight (tag: why)

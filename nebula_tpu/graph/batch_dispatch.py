@@ -963,8 +963,9 @@ class _ContinuousStream:
 
         # hop k's work is on the device; assemble hop k-1's leavers
         # NOW — host post-processing overlaps device compute.  Each
-        # _finish returns its stamps; the tick record's assemble_us is
-        # the sum of their parts
+        # _finish returns its stamps, the rows it handed over and what
+        # its unpack met; the tick record's assemble_us is the sum of
+        # the stamps' parts
         finishes = []
         pending_leavers = pending[1] if pending is not None else []
         if pending is not None:
@@ -984,9 +985,9 @@ class _ContinuousStream:
         # start, or the tick's end): the parts then tile the tick's
         # tail, and a pump that lost the interpreter between two stamps
         # is charged to a part instead of to nothing
-        handed = [stamps[0] for stamps, _n in finishes[1:]] + [t_end]
-        finishes = [(stamps + (t_hand,), n)
-                    for (stamps, n), t_hand in zip(finishes, handed)]
+        handed = [f[0][0] for f in finishes[1:]] + [t_end]
+        finishes = [(stamps + (t_hand,), n, met)
+                    for (stamps, n, met), t_hand in zip(finishes, handed)]
         with self.cond:
             self.hop_ema_s = dur if self.hop_ema_s == 0.0 \
                 else 0.7 * self.hop_ema_s + 0.3 * dur
@@ -1001,9 +1002,11 @@ class _ContinuousStream:
             # this call takes in what else is ready.  Never a wait
             hop_reads, hop_sparse, hop_slots = sess.hop_reads()
             parts = [0] * 5     # fetch_wait, d2h, unpack, rows, handover
-            for stamps, _n in finishes:
+            for stamps, _n, _met in finishes:
                 for i in range(5):
                     parts[i] += int((stamps[i + 1] - stamps[i]) * 1e6)
+            # unpack: leavers, of them out of the live rows, live rows
+            met = [sum(f[2][i] for f in finishes) for i in range(3)]
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1014,7 +1017,9 @@ class _ContinuousStream:
                 fetch_wait_us=parts[0], d2h_us=parts[1],
                 unpack_us=parts[2], rows_us=parts[3],
                 handover_us=parts[4], assemble_us=sum(parts),
-                leaver_rows=sum(n for _stamps, n in finishes),
+                unpack_leavers=met[0], unpack_live=met[1],
+                unpack_rows=met[2],
+                leaver_rows=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
                 hop_slots=hop_slots,
                 idle_us=int(idle_us),
@@ -1056,8 +1061,9 @@ class _ContinuousStream:
         tick_delay — a generation change / the test hook held it; loop
         — it was between two ticks: recording, the condition, the
         interpreter lock).  ``finishes`` holds, per finished cohort,
-        _finish's stamps plus the one its handover ran to, and its
-        rows.  Only called for a tick that touched a traced rider."""
+        _finish's stamps plus the one its handover ran to, its rows
+        and what its unpack met.  Only called for a tick that touched
+        a traced rider."""
         t0, t_seat, t_end = tick_stamps
         # ONE wall-minus-perf offset for the whole tick: the spans land
         # on the now_micros() clock every other span uses
@@ -1077,13 +1083,15 @@ class _ContinuousStream:
         t_enq = finishes[0][0][0] if finishes else t_end   # first ta
         tracing.emit("pump.enqueue", tid, root, us(t_seat),
                      us(t_enq) - us(t_seat))
-        for (ta, t_wait, t_d2h, t_unpack, t_rows, t_hand), n in finishes:
+        for (ta, t_wait, t_d2h, t_unpack, t_rows, t_hand), n, met \
+                in finishes:
             tracing.emit("pump.fetch_wait", tid, root, us(ta),
                          us(t_wait) - us(ta))
             tracing.emit("pump.d2h", tid, root, us(t_wait),
                          us(t_d2h) - us(t_wait))
             tracing.emit("pump.unpack", tid, root, us(t_d2h),
-                         us(t_unpack) - us(t_d2h))
+                         us(t_unpack) - us(t_d2h), leavers=met[0],
+                         live=met[1], rows=met[2])
             tracing.emit("pump.rows", tid, root, us(t_unpack),
                          us(t_rows) - us(t_unpack), rows=n)
             tracing.emit("pump.handover", tid, root, us(t_rows),
@@ -1100,7 +1108,9 @@ class _ContinuousStream:
 
         Returns (the stamps that split this stretch of the pump's
         time: start, fetch_wait end, d2h end, unpack end, rows end;
-        the result rows handed over).  The handover ends where the
+        the result rows handed over; what the unpack met: leavers
+        unpacked, of them out of the live rows, live rows found —
+        tpu/runtime.py _unpack_lanes).  The handover ends where the
         caller stamps next."""
         resolver, leavers, m = pending
         rt = self.sched.runtime
@@ -1119,10 +1129,13 @@ class _ContinuousStream:
             results = [ex] * len(leavers)
         t_rows = time.perf_counter()
         # a resolver that failed (or a test's stand-in) has no stamps:
-        # its whole stretch reads as the part it died in
+        # its whole stretch reads as the part it died in, and it
+        # unpacked nothing
         t_unpack = t_unpack or t_rows
         t_wait = getattr(resolver, "t_wait", 0.0) or t_unpack
         t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
+        met = tuple(int(getattr(resolver, name, 0)) for name in
+                    ("unpack_leavers", "unpack_live", "unpack_rows"))
         stats.add_value("graph.continuous.leaves", len(leavers))
         n_rows = 0
         with self.cond:
@@ -1137,7 +1150,7 @@ class _ContinuousStream:
                 r.done_t = t_done
                 r.done = True
             self.cond.notify_all()
-        return (ta, t_wait, t_d2h, t_unpack, t_rows), n_rows
+        return (ta, t_wait, t_d2h, t_unpack, t_rows), n_rows, met
 
     # ------------------------------------------------------- submit
     def submit(self, key: Tuple, payload, steps: int, upto: bool,

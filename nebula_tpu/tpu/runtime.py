@@ -3911,16 +3911,84 @@ class _ContinuousGoSession:
             self.fp, self.accp = kern(self.fp, self.accp, keep)
 
 
+# A leave cohort whose live rows PER LEAVER pass this share of the
+# table's rows takes the whole-column unpack (_unpack_lanes).  On the
+# v5e's host at 665,000 rows, the block column-contiguous as the TPU
+# hands it over (PERF.md section 6, PR 28), one leaver costs 0.16 ms
+# out of 30 live rows, 0.9 out of 30,000, 2.3 out of 130,000 and 3.4
+# out of 200,000, where its whole column through ``perm`` costs
+# 1.5-2.8 ms at any of those counts: the two cross at 0.21-0.24 of the
+# table.  Two leavers of 100,000 rows each are even at 185,000 live
+# rows in all, and twenty-one leavers never cross (12 ms against 34 out
+# of 190,000 rows, 18 against 35 out of 470,000): they share the one
+# pass that finds the live rows.  A speed choice only: both ways give
+# the same arrays.
+LANE_UNPACK_LIVE_SHARE = 0.2
+
+
+def _unpack_lanes(cols: np.ndarray, n: int, perm: np.ndarray,
+                  inv: np.ndarray, np_pairs: int, leavers, cols_of):
+    """The leave cohort's frontiers out of its fetched block.
+
+    ``cols`` is the extract kernel's [n_rows + 1, P] uint8 block:
+    column j < ``np_pairs`` is one (word, carrier) pair with at least
+    one leaver, bit ``lane & 7`` of it one lane.  The TPU hands the
+    block over column by column (each column contiguous), CPU jax row
+    by row; nothing here depends on which.  Rows >= n (hub extra rows)
+    hold an earlier pull's partial ORs, columns >= np_pairs repeat
+    frontier word 0: neither is read.  Returns (per leaver the
+    ascending old dense ids of its set rows, int64: element for element
+    ``np.nonzero(((cols[:, j] >> (lane & 7)) & 1)[perm])[0]``; how many
+    leavers were unpacked out of the live rows; the live rows found).
+
+    One pass over the real columns finds the rows < n with any bit
+    set.  Where they are few for the leavers that share them
+    (LANE_UNPACK_LIVE_SHARE), every later step works on them alone:
+    their old ids (``inv``) are gathered once a cohort, a column is cut
+    to its own live rows, a leaver tests its bit over those and sorts
+    its ids (``inv`` of ascending rows is one ascending run a degree
+    bucket)."""
+    real = cols[:n]
+    acc = real[:, 0]
+    for j in range(1, np_pairs):
+        acc = acc | real[:, j]
+    live = acc != 0
+    n_live = int(np.count_nonzero(live))
+    bits = [np.uint8(1 << (lane & 7)) for lane, _upto in leavers]
+    if n_live > LANE_UNPACK_LIVE_SHARE * n * len(leavers):
+        return [np.flatnonzero(((real[:, j] & bit) != 0)[perm])
+                .astype(np.int64, copy=False)
+                for bit, j in zip(bits, cols_of)], 0, n_live
+    rows = np.flatnonzero(live)
+    old = inv[rows]
+    members: List[List[int]] = [[] for _ in range(np_pairs)]
+    for i, j in enumerate(cols_of):
+        members[j].append(i)
+    outs: List[np.ndarray] = [None] * len(leavers)
+    for j, idx in enumerate(members):
+        col, ids = real[:, j][rows], old
+        if np_pairs > 1:
+            own = col != 0
+            col, ids = col[own], ids[own]
+        for i in idx:
+            outs[i] = np.sort(ids[(col & bits[i]) != 0]).astype(np.int64)
+    return outs, len(leavers), n_live
+
+
 class _LaneFetch:
     """Zero-arg resolver of one leave cohort's lane extraction ->
     per-leaver ascending old-dense-id frontier arrays.  It stamps
     perf_counter where the device wait ends (``t_wait``) and where the
     copy ends (``t_d2h``) — the pump reads both into the tick record
     and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
-    still wraps wait + copy, as the windowed resolvers' does."""
+    still wraps wait + copy, as the windowed resolvers' does.  What
+    the unpack met is left beside them for the same reader:
+    ``unpack_leavers``, of them ``unpack_live`` out of the live rows,
+    ``unpack_rows`` the live rows (_unpack_lanes)."""
 
     __slots__ = ("session", "out_dev", "leavers", "cols_of",
-                 "np_pairs", "t_wait", "t_d2h")
+                 "np_pairs", "t_wait", "t_d2h", "unpack_leavers",
+                 "unpack_live", "unpack_rows")
 
     def __init__(self, session, out_dev, leavers, cols_of, np_pairs):
         self.session = session
@@ -3929,6 +3997,7 @@ class _LaneFetch:
         self.cols_of = cols_of
         self.np_pairs = np_pairs
         self.t_wait = self.t_d2h = 0.0
+        self.unpack_leavers = self.unpack_live = self.unpack_rows = 0
 
     def __call__(self):
         import time
@@ -3943,12 +4012,11 @@ class _LaneFetch:
             cols = np.asarray(self.out_dev)         # [R1, P] uint8
             self.t_d2h = time.perf_counter()
         self.session.rt._note_fetch(cols[:, :self.np_pairs])
-        perm = self.session.ix.perm
-        outs = []
-        for (lane, _upto), j in zip(self.leavers, self.cols_of):
-            bit = (cols[:, j] >> (lane & 7)) & np.uint8(1)
-            old = bit[perm]                         # old dense order
-            outs.append(np.nonzero(old)[0].astype(np.int64))
+        ix = self.session.ix
+        outs, self.unpack_live, self.unpack_rows = _unpack_lanes(
+            cols, ix.n, ix.perm, ix.inv, self.np_pairs, self.leavers,
+            self.cols_of)
+        self.unpack_leavers = len(self.leavers)
         return outs
 
 

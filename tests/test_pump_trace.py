@@ -171,6 +171,75 @@ class TestTickTrace:
                    if t["assemble_us"] == 0)
 
 
+class TestUnpackCounters:
+    """What ``unpack_us`` met rides the same way the stamps do: from
+    the resolver (tpu/runtime.py _LaneFetch) through _finish to the
+    tick record and the ``pump.unpack`` span."""
+    FIELDS = ("unpack_leavers", "unpack_live", "unpack_rows")
+
+    def test_tick_record_says_what_the_unpack_met(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(9))
+        ticks = _ticks()
+        for t in ticks:
+            for f in self.FIELDS:
+                assert isinstance(t[f], int) and t[f] >= 0, (f, t)
+            assert t["unpack_live"] <= t["unpack_leavers"]
+            # the five parts are what they were
+            assert t["assemble_us"] == sum(t[p] for p in PARTS)
+            if t["assemble_us"] == 0:
+                assert not any(t[f] for f in self.FIELDS)
+        # every statement left once, through the resolver
+        assert sum(t["unpack_leavers"] for t in ticks) \
+            == sum(t["leaves"] for t in ticks) == 9
+        assert sum(t["unpack_rows"] for t in ticks) > 0
+        # 40 vertices: a lone leaver's frontier can pass a fifth of
+        # them, a cohort's live rows per leaver need not
+        assert 0 <= sum(t["unpack_live"] for t in ticks) <= 9
+        # the span carries the same numbers, cohort by cohort
+        for f, tag in zip(self.FIELDS, ("leavers", "live", "rows")):
+            spans = [k for r in _pump_roots("pump.tick")
+                     for k in r["children"] if k["name"] == "pump.unpack"]
+            assert sum(k["tags"][tag] for k in spans) \
+                == sum(t[f] for t in ticks)
+
+    def test_a_resolver_without_the_counters_still_yields_a_record(
+            self, graph):
+        """A stand-in resolver (a plain callable: no stamps, no
+        counters) reads as zeros, and the parts still sum."""
+        c, g, ok = graph
+        d = c.tpu_runtime.dispatcher
+        st = next(s for s in d.continuous.streams()
+                  if s.session is not None)
+        sess = st.session
+        real = sess.extract
+
+        def bare(leavers):
+            resolver = real(leavers)
+            return lambda: resolver()
+
+        sess.extract = bare
+        try:
+            r = ok("GO 3 STEPS FROM 2 OVER e YIELD e._dst")
+        finally:
+            del sess.extract
+        _settle(c)
+        flags.set("storage_backend", "cpu")
+        try:
+            want = ok("GO 3 STEPS FROM 2 OVER e YIELD e._dst")
+        finally:
+            flags.set("storage_backend", "tpu")
+        assert sorted(map(tuple, r.rows)) == sorted(map(tuple, want.rows))
+        done = [t for t in _ticks() if t["assemble_us"] > 0]
+        assert done
+        for t in done:
+            assert not any(t[f] for f in self.FIELDS)
+            # no stamps: the stretch up to the rows is one part
+            assert t["d2h_us"] == t["unpack_us"] == 0
+            assert t["assemble_us"] == sum(t[p] for p in PARTS)
+
+
 # =================================================== (b) the rider
 class TestRiderWaits:
     def test_four_waits_sum_to_the_submit_wall(self, graph, monkeypatch):
