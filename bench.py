@@ -1,5 +1,7 @@
-"""Headline benchmark — SERVED batched multi-hop GO through graphd:
-edges-traversed/sec/chip on the full query path.
+"""A TPU-only smoke of SERVED batched multi-hop GO through graphd:
+edges-traversed/sec/chip on the full query path.  (The repo's benchmark
+is BENCHMARK.json + benchmark/; this script predates it and feeds no
+ledger.)
 
 Measures what a client actually experiences (VERDICT round-1 weak #2):
 concurrent `GO 4 STEPS` nGQL statements through the whole serving
@@ -89,12 +91,14 @@ def kernel_bench(n, m, B, steps, edge_src, edge_dst, edge_etype):
     traversed_per_query = float(np.mean(traversed))
 
     ix = E.EllIndex.build(edge_src, edge_dst, edge_etype, n)
-    go = E.make_batched_go_kernel(ix, steps, (1,))
-    args = ix.kernel_args()
-    f0 = jnp.asarray(ix.start_frontier(starts, B=B))
+    go = E.make_batched_go_lanes_kernel(ix, steps, (1,))
+    eslot, hrows = ix.hub_merge()
+    args = (jnp.asarray(eslot), jnp.asarray(hrows),
+            *ix.kernel_args()[1:])
+    f0 = jnp.asarray(E.pack_lanes_host(ix.start_frontier(starts, B=B)))
     out = go(f0, *args)                            # compile + warmup
     _ = int(jnp.sum(out, dtype=jnp.int32))         # force completion
-    got = ix.to_old(np.asarray(out[:, :sample])) > 0
+    got = ix.to_old(E.unpack_lanes_host(np.asarray(out), sample))
     for q in range(sample):
         np.testing.assert_array_equal(got[:, q], cpu_frontiers[q])
 
@@ -276,7 +280,7 @@ def main():
         runtime_stats = {k: (round(rt.stats.get(k, 0), 2)
                              if isinstance(rt.stats.get(k, 0), float)
                              else rt.stats.get(k, 0)) for k in
-                         ("go_sparse", "go_dense", "go_adaptive",
+                         ("go_sparse", "go_dense",
                           "sparse_overflows", "mirror_builds",
                           "prewarm_compiled", "prewarm_hits",
                           "prewarm_misses",

@@ -222,7 +222,7 @@ class CompileMeter:
 
 
 _SERVE_COUNTERS = ("go_device", "path_device", "go_sparse", "go_dense",
-                   "go_adaptive", "go_sparse_split", "go_reduced",
+                   "go_sparse_split", "go_reduced",
                    "go_mesh_sparse", "bfs_mesh_sparse",
                    "sparse_overflows", "kernel_compiles",
                    "mirror_builds", "mirror_absorbs")

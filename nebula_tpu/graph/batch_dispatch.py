@@ -49,8 +49,9 @@ dispatch"): the windowed pipeline above still serves DISCRETE batches
 and a device-idle gap before the next window forms.  With
 ``go_dispatch_mode=continuous`` (the default) multi-hop GO queries
 instead join and leave ONE in-flight lane batch per (space, OVER set)
-at hop boundaries, LLM-serving style: the 1-bit-packed uint8 lane
-dimension of the dense frontier is the seat map (_LaneLedger), a
+at hop boundaries, LLM-serving style: the lane dimension of the
+resident frontier (one bit a query; tpu/ell.py owns the layout) is
+the seat map (_LaneLedger), a
 finishing query's lanes clear at its last hop, and a queued arrival's
 start frontier is scatter-merged into the freed lanes before the next
 hop dispatches (tpu/runtime.py _ContinuousGoSession).  No recompile
@@ -370,11 +371,11 @@ class _DeviceBusyMeter:
 
 
 class _LaneLedger:
-    """The continuous batch's seat map: which of the B packed lanes
-    (bit k of word k>>3 in the resident uint8 frontier) are occupied.
-    Lanes hand out lowest-index-first so a lightly loaded stream's
-    occupancy clusters into few WORDS (the leave-extract fetch is per
-    word, docs/admission.md).  Pure bookkeeping — the caller (the
+    """The continuous batch's seat map: which of the resident
+    frontier's B lanes are occupied.  Lanes hand out lowest-index-first
+    so a lightly loaded stream's occupancy clusters into few of the
+    device's lane words (the leave-extract fetch is per word,
+    docs/admission.md).  Pure bookkeeping — the caller (the
     stream, under its condition) sequences it against the device-side
     clear: a lane re-enters the free heap only after its bits were
     cleared from the resident pair, which is what makes the join
@@ -413,7 +414,7 @@ class _LaneLedger:
 
 
 # an idle continuous stream releases its resident device frontier
-# pair (two uint8 [n_rows+1, W] buffers + table references) after this
+# pair (two [n_rows+1, lanes] buffers + table references) after this
 # long with no riders — the next arrival re-anchors against the
 # then-current mirror generation, which the drain path already
 # supports.  Keeps per-(space, OVER set) HBM from accumulating on
@@ -430,8 +431,8 @@ PUMP_TICK_RIDER_TAGS = 8
 
 class ContinuousUnavailable(Exception):
     """The stream could not anchor a device session for this space
-    (empty mirror, mesh-sharded tables, packing off): the submit
-    falls back to the windowed pipeline.  Internal control flow —
+    (empty mirror, mesh-sharded tables): the submit falls back to the
+    windowed pipeline.  Internal control flow —
     never surfaces to a caller of submit_batched.
 
     ``reason`` is a protocol.PROTOCOL_REASONS "continuous-bounce"
@@ -1315,8 +1316,6 @@ class ContinuousGoScheduler:
         Session-level eligibility (empty mirror, mesh tables) is the
         pump's ContinuousUnavailable fallback."""
         if flags.get("go_dispatch_mode") != "continuous":
-            return False
-        if not flags.get("tpu_packed_frontier", True):
             return False
         if int(flags.get("tpu_mesh_devices") or 0) > 1:
             return False

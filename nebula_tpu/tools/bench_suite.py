@@ -312,7 +312,7 @@ _MESH_DRIVER = r"""
 import json, sys, time
 import numpy as np
 from nebula_tpu.tpu.ell import (
-    EllIndex, build_sharded_ell, make_batched_go_kernel,
+    EllIndex, build_sharded_ell, make_batched_go_lanes_kernel,
     make_batched_sparse_go_kernel, make_frontier_sharded_sparse_go_kernel,
     make_sharded_batched_go_kernel, pack_lanes_host, shard_ell,
     sharded_device_args, sharded_sparse_pairs, sparse_caps,
@@ -336,7 +336,7 @@ assert len(devs) >= 8, f"need 8 virtual devices, got {devs}"
 mesh = Mesh(np.array(devs[:8]), ("parts",))
 rng = np.random.default_rng(1)
 starts = [rng.integers(0, persons, 1, np.int32) for _ in range(B)]
-f0 = jnp.asarray(ix.start_frontier(starts, B=B))
+f0p = jnp.asarray(pack_lanes_host(ix.start_frontier(starts, B=B)))
 out = {"persons": persons, "edges": int(len(src)), "devices": 8,
        "B": B, "steps": steps, "device": device_info()}
 
@@ -352,16 +352,15 @@ nbrs, ets, reals = shard_ell(mesh, "parts", ix)
 go8 = make_sharded_batched_go_kernel(mesh, "parts", ix, steps, (1,),
                                      nbrs, ets, reals)
 eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
-f0p = jnp.asarray(pack_lanes_host(np.asarray(f0)))
-single = make_batched_go_kernel(ix, steps, (1,))
-ref = single(f0, *ix.kernel_args())
+tables = ix.kernel_args()[1:]
+single = make_batched_go_lanes_kernel(ix, steps, (1,))
+ref = single(f0p, eslot, hrows, *tables)
 np.testing.assert_array_equal(
-    unpack_lanes_host(np.asarray(go8(f0p, eslot, hrows, *nbrs, *ets)), B),
-    np.asarray(ref) > 0)
+    np.asarray(go8(f0p, eslot, hrows, *nbrs, *ets)), np.asarray(ref))
 out["dense_sharded_dispatch_s"] = round(
     timeit(lambda: go8(f0p, eslot, hrows, *nbrs, *ets)), 3)
 out["dense_1dev_dispatch_s"] = round(
-    timeit(lambda: single(f0, *ix.kernel_args())), 3)
+    timeit(lambda: single(f0p, eslot, hrows, *tables)), 3)
 
 # ---- frontier-sharded sparse vs 1-device sparse, SAME graph --------
 # interactive shape (2-hop IS-style reads): bounded frontiers are what
@@ -387,8 +386,10 @@ ovf, oq, ou = sharded_sparse_pairs(np.asarray(run8()))
 assert not ovf, "sharded sparse caps must hold the 2-hop frontier"
 got = np.zeros((persons, B), bool)
 got[ix.inv[ou], oq] = True
-ref2 = make_batched_go_kernel(ix, steps_s, (1,))(f0, *ix.kernel_args())
-np.testing.assert_array_equal(got, ix.to_old(np.asarray(ref2)) > 0)
+ref2 = make_batched_go_lanes_kernel(ix, steps_s, (1,))(
+    f0p, eslot, hrows, *tables)
+np.testing.assert_array_equal(
+    got, ix.to_old(unpack_lanes_host(np.asarray(ref2), B)))
 out["sparse_sharded_dispatch_s"] = round(timeit(run8), 3)
 
 caps1 = sparse_caps(B, d_max, steps_s, 1 << 17)

@@ -6,7 +6,7 @@ kernel factory registers a KernelSpec (tpu/kernels.py KERNEL_REGISTRY
 — the GO/BFS/sharded families, the ELL table kernels, the expr_compile
 filter entry), and the auditor traces each one with ``jax.make_jaxpr``
 across the runtime's REAL shape buckets (the pinned go_batch_widths /
-tpu_sparse_c0s / tpu_adaptive_k ladders), proving on the jaxpr:
+tpu_sparse_c0s ladders), proving on the jaxpr:
 
   * no host callbacks (``pure_callback``/``io_callback``/
     ``debug_callback``) inside ``while``/``scan`` loop bodies — a

@@ -635,89 +635,6 @@ def bench_continuous_path(reps: int,
     }
 
 
-def bench_kernel_roofline(reps: int,
-                          slowdown_budget: float = 2.0) -> dict:
-    """Packed-vs-int8 frontier hop roofline (docs/roofline.md).
-
-    Times the SAME multi-hop batched GO dispatch with the int8
-    [rows, B] frontier and the bit-packed uint8 [rows, B/8] one over a
-    synthetic ELL index, reports ms/dispatch and achieved GB/s under
-    the shared ell.dense_hop_bytes traffic model, and verifies bit-
-    exact parity between the two layouts.  Budget guard (like
-    lint/admission/recovery): the packed hop must never run more than
-    ``slowdown_budget`` x the int8 hop — on HBM-bound hardware it is
-    the ~8x WIN the packing exists for; on cache-resident CPU shapes
-    the two converge, and anything past the budget is a packed-path
-    regression."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-
-    from ..tpu import ell as E
-
-    rng = np.random.default_rng(11)
-    n = 1 << 10 if reps <= 5 else (1 << 13 if reps <= 50 else 1 << 15)
-    m = n * 8
-    B, steps, etypes = 256, 4, (1,)
-    src = rng.integers(0, n, m, dtype=np.int32)
-    dst = rng.integers(0, n, m, dtype=np.int32)
-    et = np.ones(m, np.int32)
-    s2 = np.concatenate([src, dst])
-    d2 = np.concatenate([dst, src])
-    e2 = np.concatenate([et, -et])
-    ix = E.EllIndex.build(s2, d2, e2, n, use_native=False)
-    starts = [rng.integers(0, n, 4) for _ in range(B)]
-    f0 = ix.start_frontier(starts, B=B)
-    f0p = E.pack_lanes_host(f0)
-    args = ix.kernel_args()
-    eslot, hrows = ix.hub_merge()
-    k8 = E.make_batched_go_kernel(ix, steps, etypes)
-    kp = E.make_batched_go_lanes_kernel(ix, steps, etypes)
-
-    def run8():
-        return k8(jnp.asarray(f0), *args)
-
-    def runp():
-        return kp(jnp.asarray(f0p), jnp.asarray(eslot),
-                  jnp.asarray(hrows), *args[1:])
-
-    out8 = np.asarray(jax.block_until_ready(run8()))    # compile+warm
-    outp = np.asarray(jax.block_until_ready(runp()))
-    parity = bool(
-        (E.unpack_lanes_host(outp, B)[:ix.n]
-         == (out8[:ix.n] > 0)).all())
-    inner = 3 if reps <= 50 else 5
-
-    def best_of(fn):
-        best = float("inf")
-        for _ in range(3):
-            t0 = _t.perf_counter()
-            for _ in range(inner):
-                jax.block_until_ready(fn())
-            best = min(best, (_t.perf_counter() - t0) / inner)
-        return best
-
-    t8 = best_of(run8)
-    tp = best_of(runp)
-    bytes8 = E.dense_hop_bytes(ix, B, steps)
-    bytesp = E.dense_hop_bytes(ix, E.lanes_width(B), steps)
-    ratio = t8 / tp if tp > 0 else float("inf")
-    return {"graph": f"n=2^{n.bit_length() - 1}, slots={ix.m}",
-            "batch": B, "steps": steps,
-            "int8_ms_per_dispatch": round(t8 * 1e3, 3),
-            "packed_ms_per_dispatch": round(tp * 1e3, 3),
-            "packed_speedup": round(ratio, 3),
-            "int8_achieved_gbps": round(bytes8 / t8 / 1e9, 3),
-            "packed_achieved_gbps": round(bytesp / tp / 1e9, 3),
-            "frontier_bytes_per_hop_int8": bytes8 // max(steps - 1, 1),
-            "frontier_bytes_per_hop_packed":
-                bytesp // max(steps - 1, 1),
-            "parity": parity,
-            "slowdown_budget": slowdown_budget,
-            "within_budget": parity and tp <= t8 * slowdown_budget}
-
-
 def bench_lint(budget_s: float) -> dict:
     """Wall time of the whole-package nebulint run (all nineteen
     checks — the jaxpr tracing of every registered kernel bucket, the
@@ -871,7 +788,6 @@ def main(argv=None) -> int:
         "absorb_path": bench_absorb(reps),
         "peer_absorb_path": bench_peer_absorb(reps),
         "continuous_path": bench_continuous_path(reps),
-        "kernel_roofline": bench_kernel_roofline(reps),
         "timeline_path": bench_timeline_path(reps),
         "lint": bench_lint(args.lint_budget_s),
         "mc_path": bench_mc(args.mc_budget_s),
@@ -886,7 +802,6 @@ def main(argv=None) -> int:
         and out["absorb_path"]["within_budget"] \
         and out["peer_absorb_path"]["within_budget"] \
         and out["continuous_path"]["within_budget"] \
-        and out["kernel_roofline"]["within_budget"] \
         and out["timeline_path"]["within_budget"]
     return 0 if ok else 1
 

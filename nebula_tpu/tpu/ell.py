@@ -6,10 +6,11 @@ the per-row cost is flat whether the row is 1 byte or 2 KB).  A
 single-query BFS hop over an m-edge graph therefore costs m x 30 ns no
 matter how it is phrased, and loses to host numpy.  The TPU-native
 answer is to *batch queries*: B concurrent traversals share one
-[n, B] int8 frontier matrix, so each (unavoidable) row access moves B
-query-bits at once and the 30 ns is amortised B ways.  A hop becomes
+[n, B] frontier matrix (stored one BIT per query lane, see "Bit-packed
+frontier" below), so each (unavoidable) row access moves B query-bits
+at once and the 30 ns is amortised B ways.  A hop becomes
 
-    next[v, :] = max_j  f[in_slot[v, j], :] * etype_ok[v, j]
+    next[v, :] = OR_j  f[in_slot[v, j], :] * etype_ok[v, j]
 
 which is D row-gathers plus a free reshape-reduce — no scatter at all.
 This mirrors how the reference amortises per-request cost by bulking
@@ -31,7 +32,7 @@ Structure built host-side from the CsrMirror (build_ell):
     signed etype of each slot so one static mask per query selects the
     OVER set (padding uses etype 0 which is never a real etype).
   * hub vertices (degree > cap) own several rows in the largest bucket;
-    the extra rows are appended after all real vertices and max-merged
+    the extra rows are appended after all real vertices and OR-merged
     back into their owner row by a tiny scatter (hubs are rare, the
     scatter is O(#extra rows)).
 
@@ -180,8 +181,8 @@ class EllIndex:
     def spare_sentinel(self) -> int:
         """The extra_owner value marking an UNCLAIMED growth-spare row
         (== n_rows, the same out-of-range row the slot sentinel names:
-        both the hub merge scatter and the int8 owner scatter drop
-        indices past the table, so an unclaimed spare merges nowhere)."""
+        the hub merge scatter drops indices past the table, so an
+        unclaimed spare merges nowhere)."""
         return self.n_rows
 
     @staticmethod
@@ -261,7 +262,8 @@ class EllIndex:
     # ----------------------------------------------------------- frontiers
     def start_frontier(self, start_dense_per_query: Sequence[np.ndarray],
                        B: Optional[int] = None) -> np.ndarray:
-        """[n_rows+1, B] int8 frontier from per-query old-dense-id lists."""
+        """Host [n_rows+1, B] 0/1 lane matrix from per-query old-dense-id
+        lists (pack_lanes_host turns it into the device layout)."""
         nq = len(start_dense_per_query)
         B = B or max(128, nq)
         f = np.zeros((self.n_rows + 1, B), dtype=np.int8)
@@ -298,30 +300,15 @@ class EllIndex:
     def hub_merge(self) -> Tuple[np.ndarray, np.ndarray]:
         """(extra_slot int32[n_extras], hub_rows int32[n_hubs]): each
         extra row's index into the compact hub-owner list, and that
-        list itself — the packed kernels' OR-merge targets (a packed
-        frontier cannot scatter-max duplicate owners the way the int8
-        one does: max of packed BYTES loses bits, so the merge runs
-        per-bit over a compact per-hub accumulator and lands with ONE
-        unique-row scatter; see _scatter_or_rows)."""
+        list itself — the hop's OR-merge targets (a packed frontier
+        cannot scatter-max duplicate owners: max of packed BYTES loses
+        bits, so the merge runs per-bit over a compact per-hub
+        accumulator and lands with ONE unique-row scatter; see
+        _scatter_or_rows)."""
         if not len(self.extra_owner):
             return (np.zeros(0, np.int32), np.zeros(0, np.int32))
         owners, slot = np.unique(self.extra_owner, return_inverse=True)
         return slot.astype(np.int32), owners.astype(np.int32)
-
-    def hub_table(self) -> np.ndarray:
-        """bool[n+1]: vertex owns hub extra rows (slot spill) — the
-        adaptive single-query kernel switches to the dense pull when
-        one enters its frontier, because a push from the main row
-        alone would miss the spilled slots.  (The batched sparse
-        kernel instead EXPANDS hubs into their extra rows on device —
-        hub_expansion below.)  Unclaimed growth spares (owner = the
-        spare sentinel, past every real vertex) are filtered: they
-        belong to nobody yet."""
-        is_hub = np.zeros(self.n + 1, dtype=bool)
-        if len(self.extra_owner):
-            u = np.unique(self.extra_owner)
-            is_hub[u[u < self.n]] = True
-        return is_hub
 
     def hub_expansion(self) -> Tuple[np.ndarray, np.ndarray]:
         """(ecnt int32[n+1], e0 int32[n+1]): per-vertex extra-row run —
@@ -365,40 +352,6 @@ def _etype_ok(jnp, et_col, etypes: Tuple[int, ...]):
     return ok
 
 
-def _bucket_expand(jnp, jax, f, nbr, et, etypes: Tuple[int, ...]):
-    """Expand one bucket: max over D in-slots of f[slot src] (masked by
-    the OVER etype set).  THE hop inner loop — shared by the
-    single-chip and sharded kernels so their semantics cannot skew."""
-    nb, D = nbr.shape
-    nbr_T = nbr.T                          # [D, nb] static transposes
-    ok_T = _etype_ok(jnp, et, etypes).T.astype(jnp.int8)
-
-    def body(j, acc):
-        g = f[nbr_T[j]]                    # [nb, B] row-gather
-        return jnp.maximum(acc, g * ok_T[j][:, None])
-
-    acc0 = jnp.zeros((nb, f.shape[1]), dtype=jnp.int8)
-    return jax.lax.fori_loop(0, D, body, acc0)
-
-
-def _hop_body(jnp, jax, n: int, n_extras: int, etypes: Tuple[int, ...],
-              nbr_dev, et_dev, extra_owner_dev, f):
-    """One frontier advance: f [n_rows+1, B] int8 -> same shape."""
-    outs = [_bucket_expand(jnp, jax, f, nbr, et, etypes)
-            for nbr, et in zip(nbr_dev, et_dev)]
-    if not outs:                           # empty graph: nothing moves
-        return jnp.zeros_like(f)
-    nxt = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
-    if n_extras:                           # hub fix-up (tiny scatter)
-        extras = nxt[n:]
-        nxt = nxt.at[extra_owner_dev].max(extras)
-        # extra rows keep their value; they are ignored as gather
-        # sources (no slot ever points at row >= n) and re-derived
-        # next hop, so no need to zero them.
-    pad = jnp.zeros((1, f.shape[1]), dtype=jnp.int8)
-    return jnp.concatenate([nxt, pad], axis=0)
-
-
 def _segmented_hub_iota(jnp, cnt_raw, e0_vals, qid, EX: int,
                         sentinel: int, BIG_Q):
     """The hub-expansion core shared by the single-device and mesh
@@ -436,48 +389,26 @@ def _segmented_hub_iota(jnp, cnt_raw, e0_vals, qid, EX: int,
     return rows, qs, overflow
 
 
-def pack_bits(jnp, x):
-    """[R, B] truthy -> bit-packed uint8 [ceil(R/8), B] (row-major bits,
-    little bit order — np.unpackbits(bitorder="little") inverts it).
-    Fused into kernels so the device->host transfer shrinks 8x.
-
-    All-uint8 arithmetic: products are <= 128 and the 8-term sum < 256,
-    so uint8 accumulation is exact — int32 intermediates here cost
-    GIGABYTES of HLO temp at 10M+-row frontiers (measured: the
-    int32 version OOM'd a 16.7M-row B=256 pack on v5e)."""
-    R1, B = x.shape
-    G = -(-R1 // 8)
-    padded = jnp.pad((x > 0).astype(jnp.uint8), ((0, G * 8 - R1), (0, 0)))
-    w = jnp.asarray((1 << np.arange(8)).astype(np.uint8))
-    return jnp.sum(padded.reshape(G, 8, B) * w[None, :, None],
-                   axis=1, dtype=jnp.uint8)
-
-
-def unpack_bits(packed: np.ndarray, R1: int) -> np.ndarray:
-    """Host half of pack_bits: uint8 [G, B] -> bool [R1, B]."""
-    return np.unpackbits(packed, axis=0, bitorder="little")[:R1] > 0
-
-
 # ====================================================================
-# Bit-packed (1-bit-per-lane) frontier — the roofline arc.
+# Bit-packed (1-bit-per-lane) frontier — THE frontier layout.
 #
-# The int8 [n_rows+1, B] frontier spends one BYTE per query lane, so a
-# hop's D row-gathers move B bytes per visited row while carrying B
-# BITS of information — 7/8 of every gathered byte is padding (the
-# graph-accelerator survey's memory-bound analysis, PAPERS.md arxiv
-# 1902.10130; its on-chip roofline share is not measured on today's
-# code — ROADMAP S4).  Packing
-# 8 lanes into one uint8 word ([n_rows+1, B/8]) cuts frontier gather
-# traffic 8x; the hop max becomes a bitwise OR and the etype mask a
-# 0/1 word multiply, both free against the gather.
+# A frontier on the device is a uint8 [n_rows+1, W = ceil(B/8)] matrix:
+# bit k of word j is query lane j*8+k.  A hop's D row-gathers are the
+# cost, and a gathered row carries B bits of information, so a byte per
+# lane would move 8x the traffic for nothing (the graph-accelerator
+# survey's memory-bound analysis, PAPERS.md arxiv 1902.10130; its
+# on-chip roofline share is not measured on today's code — ROADMAP
+# S4).  The hop max is a bitwise OR and the etype mask a 0/1 word
+# multiply, both free against the gather.  Nothing outside this module
+# knows the layout: callers size with lanes_width, enter with
+# pack_lanes_host and leave with unpack_lanes_host.
 #
-# The one op that does NOT translate is the hub fix-up scatter:
-# ``nxt.at[owner].max(extras)`` is correct on 0/1 lanes but max of
-# packed BYTES drops bits (max(0b01, 0b10) = 0b10, OR = 0b11).  The
-# packed merge instead max-scatters each extra row's 8 BIT-PLANES into
-# a compact [n_hubs, 8, W] accumulator (per-plane values are 0/1, so
-# max IS or), recombines, and lands with one unique-row scatter — work
-# stays O(n_extras x B) like the int8 fix-up, never O(n x B).
+# The one op that needs care is the hub fix-up scatter: a scatter-max
+# of packed BYTES onto duplicate owners drops bits (max(0b01, 0b10) =
+# 0b10, OR = 0b11).  The merge instead max-scatters each extra row's 8
+# BIT-PLANES into a compact [n_hubs, 8, W] accumulator (per-plane
+# values are 0/1, so max IS or), recombines, and lands with one
+# unique-row scatter — work stays O(n_extras x B), never O(n x B).
 # ====================================================================
 LANE_BITS = 8
 
@@ -538,8 +469,9 @@ def _scatter_or_rows(jnp, nxt, vals, slot, rows):
 
 
 def _bucket_expand_packed(jnp, jax, fp, nbr, et, etypes):
-    """Packed-lane twin of _bucket_expand: OR over D in-slot word
-    gathers, the OVER mask a 0/1 uint8 multiply per word."""
+    """Expand one bucket: OR over D in-slot word gathers, the OVER mask
+    a 0/1 uint8 multiply per word.  THE hop inner loop — shared by the
+    single-chip and sharded kernels so their semantics cannot skew."""
     nb, D = nbr.shape
     nbr_T = nbr.T
     ok_T = _etype_ok(jnp, et, etypes).T.astype(jnp.uint8)
@@ -580,15 +512,23 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
                                  upto: bool = False,
                                  donate: bool = False,
                                  count: bool = False):
-    """Bit-packed batched GO — the default dense path.
+    """Batched GO — the dense path.
 
     fn(f0p uint8 [n_rows+1, W], eslot int32[n_extras],
        hrows int32[n_hubs], *tables) -> uint8 [n_rows+1, W] frontier
     after ``steps-1`` advances (lane q of word j is query j*8+q;
-    unpack_lanes_host inverts).  With ``count`` the signature gains a
-    mirror-resident per-row final-hop degree vector and the output
-    collapses to int32 [W*8] per-query candidate-edge counts — the
-    COUNT(*) pushdown's fetch is B words instead of a bitmap:
+    unpack_lanes_host inverts; the final hop's edge set is
+    frontier[src] & etype_ok, materialised by the caller — same split
+    as kernels._go_body).  ``tables`` = (*bucket_nbr, *bucket_et) from
+    EllIndex.kernel_args()[1:]; only static shapes are read off
+    ``ell``, so the compiled fn serves any mirror with the same
+    shape_sig.  With ``upto`` the output is the OR of every depth's
+    frontier (0..steps-1 — GO UPTO's pre-final-hop vertex set; one
+    extra OR per advance, free against the gather cost).  With
+    ``count`` the signature gains a mirror-resident per-row final-hop
+    degree vector and the output collapses to int32 [W*8] per-query
+    candidate-edge counts — the COUNT(*) pushdown's fetch is B words
+    instead of a bitmap:
     fn(f0p, eslot, hrows, deg int32[n_rows+1], *tables)."""
     import jax
     import jax.numpy as jnp
@@ -625,8 +565,12 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
             nbrs, ets = tables[:nb], tables[nb:]
             return advance(f0p, eslot, hrows, nbrs, ets)
 
-    # donation contract matches make_batched_go_kernel: f0p is built
-    # fresh per dispatch by the runtime (single-use), opt-in only
+    # ``donate`` is the RUNTIME's dispatch configuration: _launch_dense
+    # builds f0p fresh per dispatch, so handing the buffer to XLA lets
+    # the hop loop reuse its HBM instead of holding both live (jaxaudit
+    # verifies the claim on the traced pjit).  OPT-IN because a donated
+    # frontier is CONSUMED: callers that re-dispatch one frontier (bench
+    # drivers, parity tests) must keep the default
     return jax.jit(go, donate_argnums=(0,) if donate else ())
 
 
@@ -1263,14 +1207,20 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
                                   etypes: Tuple[int, ...],
                                   stop_when_found: bool = True,
                                   donate: bool = False):
-    """Packed twin of make_batched_bfs_kernel: the frontier rides the
-    hop gathers 1-bit packed (the gather traffic is the level loop's
-    cost center); the depth matrix stays per-lane (its updates are
-    streaming elementwise, and it IS the result).
+    """Batched BFS (the analogue of kernels.make_bfs_kernel): the
+    frontier rides the hop gathers 1-bit packed (the gather traffic is
+    the level loop's cost center); the depth matrix stays per-lane (its
+    updates are streaming elementwise, and it IS the result).  The loop
+    exits early when every query either stalled or (``stop_when_found``,
+    shortest mode) covered its targets.
 
     fn(f0p, t0p, eslot, hrows, *tables) -> (depth [n_rows+1, B] (int8
-    with -1 = unreachable when max_steps fits, else int16), the levels
-    the loop ran (int32 scalar))."""
+    with -1 = unreachable when max_steps fits — the transfer is 2x
+    smaller and depths are tiny — else int16 with INT16_INF), the
+    levels the loop ran (int32 scalar)).  Both frontier matrices are
+    built fresh per dispatch by runtime._bfs_depths, which opts in to
+    ``donate`` (see make_batched_go_lanes_kernel for why the default
+    stays off)."""
     import jax
     import jax.numpy as jnp
     n, n_extras, nb_count = ell.n, len(ell.extra_owner), \
@@ -1309,64 +1259,15 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
 
 def dense_hop_bytes(ell: EllIndex, lane_bytes_per_row: int,
                     steps: int) -> int:
-    """HBM traffic model of one packed/int8 dense GO dispatch: per
-    advance, each bucket row pays D word-gathers of
-    ``lane_bytes_per_row`` plus an accumulator read+write; the hub
-    fix-up and pad are O(n_extras) noise.  The roofline numbers in
-    runtime_stats / micro_bench kernel_roofline / docs/roofline.md all
-    come from THIS model so they are comparable."""
+    """HBM traffic model of one dense GO dispatch: per advance, each
+    bucket row pays D word-gathers of ``lane_bytes_per_row`` (=
+    lanes_width(B)) plus an accumulator read+write; the hub fix-up and
+    pad are O(n_extras) noise.  The roofline numbers in runtime_stats
+    and docs/roofline.md come from THIS model so they are
+    comparable."""
     per_advance = sum(nbr.shape[0] * (nbr.shape[1] + 2)
                       for nbr in ell.bucket_nbr) * lane_bytes_per_row
     return max(steps - 1, 1) * per_advance
-
-
-def make_batched_go_kernel(ell: EllIndex, steps: int,
-                           etypes: Tuple[int, ...], pack: bool = False,
-                           upto: bool = False, donate: bool = False):
-    """fn(f0 [n_rows+1, B] int8, owner, *tables) -> frontier after
-    ``steps-1`` advances (the final hop's edge set is frontier[src] &
-    etype_ok, materialised by the caller — same split as
-    kernels._go_body).  ``tables`` = (*bucket_nbr, *bucket_et) from
-    EllIndex.kernel_args(); only static shapes are read off ``ell``, so
-    the compiled fn serves any mirror with the same shape_sig.  With
-    ``pack`` the output is bit-packed uint8 (see pack_bits).  With
-    ``upto`` the output is the OR of every depth's frontier (0..steps-1
-    — GO UPTO's pre-final-hop vertex set; one extra max per advance,
-    free against the gather cost)."""
-    import jax
-    import jax.numpy as jnp
-    n, n_extras, nb = ell.n, len(ell.extra_owner), len(ell.bucket_nbr)
-
-    def go(f0, owner, *tables):
-        nbrs, ets = tables[:nb], tables[nb:]
-
-        def one(_, f):
-            return _hop_body(jnp, jax, n, n_extras, etypes, nbrs, ets,
-                             owner, f)
-
-        def one_acc(_, carry):
-            f, acc = carry
-            nxt = _hop_body(jnp, jax, n, n_extras, etypes, nbrs, ets,
-                            owner, f)
-            return nxt, jnp.maximum(acc, nxt)
-
-        if steps <= 1:
-            out = f0
-        elif upto:
-            _, out = jax.lax.fori_loop(0, steps - 1, one_acc, (f0, f0))
-        else:
-            out = jax.lax.fori_loop(0, steps - 1, one, f0)
-        return pack_bits(jnp, out) if pack else out
-
-    # ``donate`` (the RUNTIME's dispatch configuration —
-    # _launch_dense builds f0 fresh per dispatch, so handing the
-    # [n_rows+1, B] buffer to XLA lets the hop loop reuse its HBM
-    # instead of holding both live; jaxaudit verifies the claim on the
-    # traced pjit).  OPT-IN because a donated frontier is CONSUMED:
-    # callers that re-dispatch one frontier (bench drivers, parity
-    # tests) — or that pass a numpy array jax may zero-copy alias on
-    # CPU — must keep the default
-    return jax.jit(go, donate_argnums=(0,) if donate else ())
 
 
 def sparse_caps(c0: int, d_max: int, steps: int, cap: int,
@@ -1414,12 +1315,11 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
 
     Per hop: bucketed row-gathers pull each pair's out-slots (etypes
     negated — csr.py stores the reverse direction under -etype, so a
-    row's -T slots are its OUT-neighbors over T, exactly like
-    make_adaptive_go_kernel), then a lexicographic sort + shift-compare
-    dedups (query, vertex) pairs and compacts them to the next static
-    cap.  Work scales with the LIVE frontier (the reference's
-    per-vertex prefix scans touch only frontier vertices too —
-    QueryBaseProcessor.inl:336-405), not with the whole table the way
+    row's -T slots are its OUT-neighbors over T), then a lexicographic
+    sort + shift-compare dedups (query, vertex) pairs and compacts them
+    to the next static cap.  Work scales with the LIVE frontier (the
+    reference's per-vertex prefix scans touch only frontier vertices
+    too — QueryBaseProcessor.inl:336-405), not with the whole table the way
     the dense pull does; at interactive frontier sizes this is an order
     of magnitude less device work AND the result transfer is the pair
     list, not a bitmap.
@@ -1672,177 +1572,6 @@ def sparse_go_pairs(kern, out: np.ndarray):
     return cnt, overflow, qids[live], ids[live]
 
 
-def make_adaptive_go_kernel(ell: EllIndex, steps: int,
-                            etypes: Tuple[int, ...], K: int = 2048):
-    """Single-query GO with sparse-frontier hops — the interactive
-    short-read path (LDBC IS-style): while the frontier fits in K ids,
-    a hop is a push over just the frontier's slot rows (a few K row
-    gathers + a list-sized sort/dedup, ~ms) instead of the dense pull
-    over every vertex row (n*D row gathers, ~100s of ms at 16M edges).
-    When a hop's result overflows K — or the frontier contains a hub
-    vertex whose slots spill into extra rows, which would make the
-    push's cost scale with the hub's degree instead of the frontier —
-    the kernel switches permanently to the dense pull on a complete
-    bitmap, so results are exact for any frontier size.
-
-    Direction note: table slots of row-owner v are v's IN-edges over
-    +et plus v's OUT-edges recorded under -et (csr.py writes both
-    directions), so pushing OUT of a frontier member means selecting
-    slots with NEGATED etypes.
-
-    fn(start_new_ids int32[K] (padded with n_rows — pad host-side so
-    one compiled program serves every start count), hub bool[n+1],
-    owner, *tables) -> bit-packed frontier uint8[ceil((n_rows+1)/8)]
-    after steps-1 advances (same contract as make_batched_go_kernel's
-    column 0 under pack_bits; hub extra rows may hold junk exactly like
-    the batched kernel's)."""
-    import jax
-    import jax.numpy as jnp
-    n, n_rows = ell.n, ell.n_rows
-    n_extras, nb_count = len(ell.extra_owner), len(ell.bucket_nbr)
-    sentinel = n_rows
-    neg = tuple(-t for t in etypes)
-    d_max = max(ell.bucket_D) if ell.bucket_D else 1
-
-    # bucket start rows (static) — new ids are contiguous per bucket
-    bstarts = []
-    acc = 0
-    for nbr_np in ell.bucket_nbr:
-        bstarts.append(acc)
-        acc += nbr_np.shape[0]
-
-    def slot_rows(fr, nbrs, ets_t):
-        """[K, d_max] slot targets of each frontier row (sentinel where
-        absent), OVER-set mask applied."""
-        cand = jnp.full((fr.shape[0], d_max), jnp.int32(sentinel))
-        for nbr, et, bstart in zip(nbrs, ets_t, bstarts):
-            nbk, D = nbr.shape
-            loc = fr - bstart
-            inb = (loc >= 0) & (loc < nbk)
-            safe = jnp.where(inb, loc, 0)
-            rows = nbr[safe]                     # [K, D] row gathers
-            ets = et[safe]
-            ok = inb[:, None] & _etype_ok(jnp, ets, neg)
-            block = jnp.where(ok, rows, sentinel)
-            if D < d_max:
-                block = jnp.pad(block, ((0, 0), (0, d_max - D)),
-                                constant_values=sentinel)
-            cand = jnp.where(inb[:, None], block, cand)
-        return cand
-
-    def bitmap_of(ids):
-        return jnp.zeros((n_rows + 1,), jnp.int8) \
-            .at[ids].max(jnp.int8(1)).at[sentinel].set(0)
-
-    @jax.jit
-    def go(fr0, hub, owner, *tables):
-        nbrs, ets_t = tables[:nb_count], tables[nb_count:]
-
-        def sparse_hop(state):
-            fr, cnt, bitmap, sparse = state
-            cand = slot_rows(fr, nbrs, ets_t).reshape(-1)
-            srt = jnp.sort(cand)
-            uniq = (srt != jnp.roll(srt, 1)) & (srt != sentinel)
-            # index 0 is always a first occurrence (roll compares it to
-            # the LAST element, which is wrong for it)
-            uniq = uniq.at[0].set(srt[0] != sentinel)
-            pref = jnp.cumsum(uniq.astype(jnp.int32))
-            cnt2 = pref[-1]
-            pos = jnp.where(uniq & (pref <= K), pref - 1, K)
-            fr2 = jnp.full((K,), jnp.int32(sentinel)) \
-                .at[pos].set(srt, mode="drop")
-            overflow = cnt2 > K
-            # invariant: bitmap always reflects the current frontier, so
-            # the dense branch can take over at any hop (cheap: K-scatter
-            # when staying sparse, full-cand scatter on overflow)
-            bitmap2 = jax.lax.cond(
-                overflow,
-                lambda: bitmap_of(cand),
-                lambda: bitmap_of(fr2))
-            return fr2, cnt2, bitmap2, jnp.logical_not(overflow)
-
-        def dense_hop(state):
-            fr, cnt, bitmap, sparse = state
-            nxt = _hop_body(jnp, jax, n, n_extras, etypes, nbrs, ets_t,
-                            owner, bitmap[:, None])[:, 0]
-            return (jnp.full((K,), jnp.int32(sentinel)),
-                    jnp.int32(K + 1), nxt, jnp.bool_(False))
-
-        bm0 = bitmap_of(fr0)
-        cnt0 = jnp.sum(fr0 != sentinel).astype(jnp.int32)
-        state = (fr0, cnt0, bm0, cnt0 <= K)
-
-        def one(_, st):
-            fr = st[0]
-            hub_in_frontier = jnp.any(
-                hub[jnp.where(fr < n, fr, n)] & (fr != sentinel))
-            sparse_ok = st[3] & jnp.logical_not(hub_in_frontier)
-            return jax.lax.cond(sparse_ok, sparse_hop, dense_hop, st)
-
-        if steps > 1:
-            state = jax.lax.fori_loop(0, steps - 1, one, state)
-        fr, cnt, bitmap, sparse = state
-        return pack_bits(jnp, bitmap[:, None])[:, 0]
-
-    def entry(start_ids, hub, owner, *tables):
-        ids = np.asarray(start_ids, np.int32)[:K]
-        fr0 = np.full((K,), np.int32(sentinel))
-        fr0[: len(ids)] = ids
-        import jax.numpy as jnp2
-        return go(jnp2.asarray(fr0), hub, owner, *tables)
-
-    entry._jitted = go          # jaxaudit traces the device half
-    return entry
-
-
-def make_batched_bfs_kernel(ell: EllIndex, max_steps: int,
-                            etypes: Tuple[int, ...],
-                            stop_when_found: bool = True,
-                            donate: bool = False):
-    """fn(f0, targets, owner, *tables) -> (depth [n_rows+1, B], the
-    levels the loop ran): depth is
-    int8 with -1 = unreachable when max_steps fits (the transfer is 2x
-    smaller and depths are tiny), else int16 with INT16_INF.  Batched
-    analogue of kernels.make_bfs_kernel; early exit when every query
-    either stalled or (shortest mode) covered its targets."""
-    import jax
-    import jax.numpy as jnp
-    n, n_extras, nb_count = ell.n, len(ell.extra_owner), len(ell.bucket_nbr)
-    small = max_steps <= 120
-
-    def bfs(f0, targets, owner, *tables):
-        nbrs, ets = tables[:nb_count], tables[nb_count:]
-        d0 = jnp.where(f0 > 0, jnp.int16(0), INT16_INF)
-
-        def cond(state):
-            d, f, step = state
-            alive = (f > 0).any()
-            go_on = (step < max_steps) & alive
-            if stop_when_found:
-                unfound = ((targets > 0) & (d == INT16_INF)).any()
-                go_on = go_on & unfound
-            return go_on
-
-        def body(state):
-            d, f, step = state
-            nxt = _hop_body(jnp, jax, n, n_extras, etypes, nbrs, ets,
-                            owner, f)
-            newly = (nxt > 0) & (d == INT16_INF)
-            d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
-            return d, newly.astype(jnp.int8), step + 1
-
-        d, _, levels = jax.lax.while_loop(cond, body,
-                                          (d0, f0, jnp.int32(0)))
-        if small:
-            d = jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
-        return d, levels
-
-    # both frontier matrices are built fresh per dispatch by
-    # runtime._bfs_depths — single-use there, so the runtime opts in
-    # (see make_batched_go_kernel for why the default stays off)
-    return jax.jit(bfs, donate_argnums=(0, 1) if donate else ())
-
-
 # ====================================================================
 # Multi-chip, two designs:
 #
@@ -1852,10 +1581,9 @@ def make_batched_bfs_kernel(ell: EllIndex, max_steps: int,
 #    Adding chips adds FLOPs but not servable scale — every chip still
 #    holds the whole frontier matrix — but packing the lanes cuts BOTH
 #    the per-hop ICI re-replication and the per-chip frontier gather
-#    traffic 8x versus the int8 carrier (same argument as the
-#    single-chip roofline arc, docs/roofline.md; the re-replication is
-#    the link cost meshaudit's ICI model prices).  Kept for the
-#    batched-BFS path.
+#    traffic 8x versus a byte per lane (docs/roofline.md; the
+#    re-replication is the link cost meshaudit's ICI model prices).
+#    Kept for the batched-BFS path.
 #
 # 2. FRONTIER-SHARDED sparse (build_sharded_ell +
 #    make_frontier_sharded_sparse_go_kernel): the new-id row space is
@@ -1903,9 +1631,7 @@ def make_sharded_batched_go_kernel(mesh, axis: str, ell: EllIndex,
     uint8 [n_rows+1, W] — same lane layout as the single-chip
     make_batched_go_lanes_kernel (pack_lanes_host / unpack_lanes_host
     invert), so the sharded result is bit-exact against it.  eslot/
-    hrows are the hub OR-merge grouping (EllIndex.hub_merge): a packed
-    frontier cannot scatter-max duplicate hub owners the way the old
-    int8 carrier did — max of packed BYTES drops bits."""
+    hrows are the hub OR-merge grouping (EllIndex.hub_merge)."""
     import jax
     import jax.numpy as jnp
     hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, nbr_shards,
@@ -1963,10 +1689,10 @@ def _make_sharded_hop_packed(mesh, axis: str, ell: EllIndex,
             if len(trimmed) > 1 else trimmed[0]
         # re-replicate BEFORE the hub OR-merge: _scatter_or_rows ends
         # in a scatter-SET, which the SPMD partitioner cannot mask to
-        # an identity on shards that don't own the target row (unlike
-        # the int8 path's scatter-max) — partitioned, it clamped the
-        # out-of-range index onto each shard's LAST row and corrupted
-        # row k*chunk-1 on every chip (caught by the mesh-driver
+        # an identity on shards that don't own the target row (as it
+        # can a scatter-max) — partitioned, it clamped the out-of-range
+        # index onto each shard's LAST row and corrupted row
+        # k*chunk-1 on every chip (caught by the mesh-driver
         # parity gate).  Replicated, the merge is the same tiny
         # O(n_extras x W) work on every chip, and the per-hop ICI
         # cost — (k-1)/k of the packed frontier — is unchanged.
@@ -2377,7 +2103,7 @@ def make_frontier_sharded_sparse_bfs_kernel(mesh, axis: str,
     hub extra rows) -> route candidates to their owner -> owner keeps
     only rows whose depth is still unset, stamps them with the level,
     and they become the next local frontier.  Early exit mirrors
-    make_batched_bfs_kernel: stop when every query stalled or (shortest
+    make_batched_bfs_lanes_kernel: stop when every query stalled or (shortest
     mode) covered its targets — both reductions ride a psum.
 
     fn(ids0 [k, cap], qid0 [k, cap], tids [k, cap], tqid [k, cap],
@@ -2670,15 +2396,6 @@ def _sparse_go_count_buckets(fx):
     return out
 
 
-def _adaptive_go_buckets(fx):
-    entry = make_adaptive_go_kernel(fx.ell, fx.steps, fx.etypes,
-                                    K=fx.adaptive_k)
-    return [(("adaptive_go", fx.ell.shape_sig(), fx.etypes, fx.steps,
-              fx.adaptive_k), entry._jitted,
-             (fx.aval((fx.adaptive_k,), np.int32),
-              fx.aval((fx.ell.n + 1,), np.bool_)) + fx.table_avals())]
-
-
 def _ell_bfs_buckets(fx):
     out = []
     for shortest in (True, False):
@@ -2791,9 +2508,6 @@ register_kernel(KernelSpec(
     # qmax count vector, never the caps[-1] pair tail
     budget=2, instantiate=_sparse_go_count_buckets, dispatch=(0, 1),
     d2h_bytes_max=lambda fx: 4 * (2 + fx.qmax)))
-register_kernel(KernelSpec(
-    "adaptive_go", make_adaptive_go_kernel, phase_kind="adaptive_go",
-    budget=1, instantiate=_adaptive_go_buckets, dispatch=(0,)))
 register_kernel(KernelSpec(
     "ell_bfs", make_batched_bfs_lanes_kernel, phase_kind="ell_bfs",
     budget=4, instantiate=_ell_bfs_buckets, donate=(0, 1),

@@ -198,7 +198,6 @@ class AuditFixture:
         self.c0s = sorted(int(x) for x in
                           str(flags.get("tpu_sparse_c0s") or
                               "256,2048").split(",") if x.strip())
-        self.adaptive_k = int(flags.get("tpu_adaptive_k") or 2048)
         self.sparse_cap = int(flags.get("tpu_sparse_cap") or (1 << 17))
         self.sparse_growth = int(flags.get("tpu_sparse_growth") or 8)
         self.qmax = int(flags.get("go_batch_max") or 1024)

@@ -25,8 +25,9 @@ Serving architecture:
 * Small frontiers run the sparse pair-list kernel
   (ell.make_batched_sparse_go_kernel): device work scales with the live
   frontier and the transfer is a compact pair list.  Overflow or hub
-  contact falls back to the dense bitmap kernel, whose output crosses
-  the link bit-packed (ell.pack_bits).
+  contact falls back to the dense bitmap kernel, whose frontier is
+  bit-packed on the device and across the link (ell.py owns the
+  layout).
 * Multi-hop GO dispatch is CONTINUOUS by default (round 15,
   ``go_dispatch_mode``): queries join and leave an in-flight lane
   batch at hop boundaries over a resident packed frontier pair
@@ -188,13 +189,6 @@ flags.define(
     "shape batches through the dispatcher) or 'device' (fuse always; "
     "no cross-query batching)")
 flags.define(
-    "tpu_packed_frontier", True,
-    "dense ELL GO/BFS frontiers ride BIT-PACKED uint8 lanes (8 "
-    "queries per byte) through the hop loop instead of int8-per-lane "
-    "— 8x less frontier gather traffic per hop, the ROADMAP item-1 "
-    "roofline claim (docs/roofline.md); off restores the int8 layout "
-    "(parity fallback, and the micro_bench kernel_roofline baseline)")
-flags.define(
     "tpu_device_timing_every", 16,
     "sample every Nth dense/sparse device dispatch with a "
     "block_until_ready timestamp around the kernel — the device-"
@@ -207,15 +201,6 @@ flags.define(
     "tick record's fetch_wait_us, read on every leaving tick.  0 "
     "disables sampling (no serialization of the dispatch pipeline at "
     "all, and no timing rows)")
-flags.define(
-    "tpu_adaptive_single", True,
-    "single-query GO runs the adaptive sparse-frontier kernel "
-    "(ell.make_adaptive_go_kernel): while the frontier fits in "
-    "tpu_adaptive_k ids a hop costs ~ms instead of a full dense pull — "
-    "the interactive short-read path. Exact for any frontier size "
-    "(overflow switches to the dense pull mid-query)")
-flags.define("tpu_adaptive_k", 2048,
-             "sparse-frontier capacity for tpu_adaptive_single")
 flags.define(
     "tpu_sparse_go", True,
     "batched GO prefers the sparse pair-list kernel "
@@ -438,8 +423,6 @@ DEVICE_PHASES = {
                                 "tpu.assemble"), "h2d": 1, "d2h": 1},
     "sparse_go": {"phases": ("tpu.launch", "tpu.kernel", "tpu.fetch",
                              "tpu.assemble"), "h2d": 2, "d2h": 1},
-    "adaptive_go": {"phases": ("tpu.launch", "tpu.kernel", "tpu.fetch",
-                               "tpu.assemble"), "h2d": 1, "d2h": 1},
     # delta absorption: per-dispatch uploads are the O(delta)
     # replacement-row triples; the two "fetches" are the next
     # generation's tables, which STAY resident (they become the
@@ -540,7 +523,7 @@ class TpuQueryRuntime:
                       # slot-overflow rebuild — ell.plan_ell_absorb)
                       "mirror_slot_grows": 0,
                       "go_sparse": 0, "go_dense": 0,
-                      "go_adaptive": 0, "sparse_overflows": 0,
+                      "sparse_overflows": 0,
                       # continuous hops by the branch the program took
                       # on the device (ell.make_continuous_hop_kernel):
                       # push out of the live slot rows / pull over the
@@ -1166,13 +1149,12 @@ class TpuQueryRuntime:
         # carry what stays valid across generations: the warm ledger
         # (kernels are shape-keyed) and the structural hub metadata —
         # UNLESS a growth claim just changed extra_owner, which is
-        # exactly what those caches derive from (hub table, expansion
-        # runs, merge slots): a grown generation re-derives them
+        # exactly what those caches derive from (expansion runs, merge
+        # slots): a grown generation re-derives them
         if hasattr(m, "_prewarm_done"):
             new_m._prewarm_done = m._prewarm_done
         if not claims:
-            for cache_attr in ("_hub_dev_cache", "_hub_exp_cache",
-                               "_hub_merge_cache"):
+            for cache_attr in ("_hub_exp_cache", "_hub_merge_cache"):
                 val = getattr(m, cache_attr, None)
                 if val is not None:
                     setattr(new_m, cache_attr, val)
@@ -1591,11 +1573,9 @@ class TpuQueryRuntime:
         the hop/join/clear/extract kernels over the CURRENT mirror
         generation.  Returns None when the space cannot ride the
         seat-map path — mesh-sharded tables (the replicated-frontier
-        mesh kernels have no resident-pair protocol yet), bit-packing
-        disabled, or an empty/unbuildable mirror — and the caller
-        falls back to the windowed pipeline."""
-        if not flags.get("tpu_packed_frontier", True):
-            return None
+        mesh kernels have no resident-pair protocol yet) or an
+        empty/unbuildable mirror — and the caller falls back to the
+        windowed pipeline."""
         # flag check, not _mesh_only(): the mesh cache is request-path
         # state and the pump must not warm it from its own thread
         if int(flags.get("tpu_mesh_devices") or 0) > 1:
@@ -1668,10 +1648,10 @@ class TpuQueryRuntime:
         """Start the device work for ``steps - 1`` frontier advances of
         B queries; returns a zero-arg resolver -> (per-query ascending
         dense-id frontier arrays, mirror).  Selection order: host-only
-        (steps==1) → sparse pair-list → adaptive single → dense
-        bit-packed, with sparse overflow re-running dense.  ``upto``
-        selects the cumulative-frontier kernel variants (the returned
-        per-query arrays are the UNION of depths 0..steps-1).
+        (steps==1) → sparse → sparse split → dense bit-packed, with
+        sparse overflow re-running dense.  ``upto`` selects the
+        cumulative-frontier kernel variants (the returned per-query
+        arrays are the UNION of depths 0..steps-1).
 
         The start sets ride ONE flat (dense_id, query) pair vector,
         deduped with a single lexsort — per-query Python loops here ran
@@ -1747,13 +1727,6 @@ class TpuQueryRuntime:
                 qbounds, upto=upto, reduce=reduce)
             if launched is not None:
                 return launched
-
-        if nq == 1 and mesh_mt is None and not upto \
-                and reduce is None \
-                and flags.get("tpu_adaptive_single") \
-                and len(d_all) <= int(flags.get("tpu_adaptive_k") or 2048):
-            return self._launch_adaptive(space_id, m, ix, d_all,
-                                         et_tuple, steps)
 
         return self._launch_dense(space_id, m, ix, d_all, q_all, nq,
                                   et_tuple, steps, mesh_mt,
@@ -2062,61 +2035,27 @@ class TpuQueryRuntime:
 
         return resolve
 
-    def _launch_adaptive(self, space_id: int, m: CsrMirror, ix: EllIndex,
-                         d_all: np.ndarray, et_tuple: Tuple[int, ...],
-                         steps: int):
-        from .ell import make_adaptive_go_kernel, unpack_bits
-        K = int(flags.get("tpu_adaptive_k") or 2048)
-        kern = self._kernel(
-            ("adaptive_go", ix.shape_sig(), et_tuple, steps, K),
-            lambda: make_adaptive_go_kernel(ix, steps, et_tuple, K=K))
-        hub = self._hub_dev(m, ix)
-        with tracing.span("tpu.kernel", kind="adaptive_go"):
-            out_dev = kern(ix.perm[d_all], hub, *ix.kernel_args())
-        self._bump("go_adaptive")
-
-        def resolve():
-            packed = np.asarray(out_dev)
-            self._note_fetch(packed)
-            bitmap = unpack_bits(packed[:, None], ix.n_rows + 1)[:, 0]
-            vs_old = np.nonzero(bitmap[ix.perm])[0]
-            return [vs_old], m
-
-        return resolve
-
     def _launch_dense(self, space_id: int, m: CsrMirror, ix: EllIndex,
                       d_all: np.ndarray, q_all: np.ndarray, nq: int,
                       et_tuple: Tuple[int, ...], steps: int,
                       mesh_mt, upto: bool = False,
                       reduce=None):
         from .ell import (dense_hop_bytes, lanes_width,
-                          make_batched_go_kernel,
                           make_batched_go_lanes_kernel,
-                          make_sharded_batched_go_kernel, unpack_bits,
+                          make_sharded_batched_go_kernel,
                           unpack_lanes_host)
         # callers guarantee: upto never reaches the sharded variants
         # (the mesh gate declines); a count reduction only rides the
-        # packed single-chip kernels
+        # single-chip kernel
         assert not (upto and mesh_mt is not None)
         B = self._batch_width(nq)
-        # the replicated-frontier mesh kernels are bit-packed ONLY (the
-        # int8 carriers were retired with them — lint enforces the
-        # layout via KernelSpec.packed), so a mesh dispatch is always
-        # packed regardless of the single-chip flag
-        packed_mode = bool(flags.get("tpu_packed_frontier", True)) \
-            or mesh_mt is not None
         count_mode = reduce is not None and reduce[0] == "count" \
-            and packed_mode and mesh_mt is None
+            and mesh_mt is None
         args = ix.kernel_args()
-        if packed_mode:
-            f0_dev = self._upload_frontier_packed(
-                ix, ix.perm[d_all], q_all.astype(np.int32), B)
-            eslot, hrows = self._hub_merge_dev(m, ix)
-            hop_bytes = dense_hop_bytes(ix, lanes_width(B), steps)
-        else:
-            f0_dev = self._upload_frontier(ix, ix.perm[d_all],
-                                           q_all.astype(np.int32), B)
-            hop_bytes = dense_hop_bytes(ix, B, steps)
+        f0_dev = self._upload_frontier_packed(
+            ix, ix.perm[d_all], q_all.astype(np.int32), B)
+        eslot, hrows = self._hub_merge_dev(m, ix)
+        hop_bytes = dense_hop_bytes(ix, lanes_width(B), steps)
         if mesh_mt is not None:
             mesh, nbrs, ets, reals = mesh_mt
             kern = self._kernel(
@@ -2156,31 +2095,18 @@ class TpuQueryRuntime:
             first = (et_tuple, steps) not in getattr(m, "_prewarm_done",
                                                      set())
             self._prewarm_family(m, ix, et_tuple, steps)
-            if packed_mode:
-                kern = self._kernel(
-                    ("ell_go_packed", ix.shape_sig(), et_tuple, steps,
-                     upto),
-                    # donate=True: f0p is built fresh per dispatch
-                    # right above — single-use by construction
-                    lambda: make_batched_go_lanes_kernel(
-                        ix, steps, et_tuple, upto=upto, donate=True))
-                self._note_live_shape(
-                    ("ell_go_packed", ix.shape_sig(), et_tuple, steps,
-                     B), first_of_family=first or upto)
-                with tracing.span("tpu.kernel", kind="ell_go",
-                                  width=B, packed=True):
-                    out_dev = kern(f0_dev, eslot, hrows, *args[1:])
-            else:
-                kern = self._kernel(
-                    ("ell_go", ix.shape_sig(), et_tuple, steps, upto),
-                    lambda: make_batched_go_kernel(ix, steps, et_tuple,
-                                                   pack=True, upto=upto,
-                                                   donate=True))
-                self._note_live_shape(("ell_go", ix.shape_sig(),
-                                       et_tuple, steps, B),
-                                      first_of_family=first or upto)
-                with tracing.span("tpu.kernel", kind="ell_go", width=B):
-                    out_dev = kern(f0_dev, *args)
+            kern = self._kernel(
+                ("ell_go_packed", ix.shape_sig(), et_tuple, steps, upto),
+                # donate=True: f0p is built fresh per dispatch right
+                # above — single-use by construction
+                lambda: make_batched_go_lanes_kernel(
+                    ix, steps, et_tuple, upto=upto, donate=True))
+            self._note_live_shape(
+                ("ell_go_packed", ix.shape_sig(), et_tuple, steps, B),
+                first_of_family=first or upto)
+            with tracing.span("tpu.kernel", kind="ell_go", width=B,
+                              packed=True):
+                out_dev = kern(f0_dev, eslot, hrows, *args[1:])
         self._bump("go_dense")
         if mesh_mt is None:
             # sharded dispatches already logged a (richer) row above
@@ -2200,16 +2126,10 @@ class TpuQueryRuntime:
             # slice to the live query columns ON DEVICE before the
             # fetch — transferring all B padded columns at small nq
             # re-pays the cost the bit-packing exists to remove
-            if packed_mode:
-                nwp = min(lanes_width(B), max(1, -(-nq // 8)))
-                lanes = np.asarray(out_dev[:, :nwp])  # [R1, nwp] uint8
-                self._note_fetch(lanes)
-                bits = unpack_lanes_host(lanes, nq)
-            else:
-                nqp = min(B, max(8, -(-nq // 8) * 8))
-                packed = np.asarray(out_dev[:, :nqp])  # [G, nqp] uint8
-                self._note_fetch(packed)
-                bits = unpack_bits(packed[:, :nq], ix.n_rows + 1)
+            nwp = min(lanes_width(B), max(1, -(-nq // 8)))
+            lanes = np.asarray(out_dev[:, :nwp])      # [R1, nwp] uint8
+            self._note_fetch(lanes)
+            bits = unpack_lanes_host(lanes, nq)
             old = bits[ix.perm]                   # [n, nq] old dense ids
             qs, vs = np.nonzero(old.T)
             bounds = np.searchsorted(qs, np.arange(nq + 1))
@@ -2246,7 +2166,8 @@ class TpuQueryRuntime:
         def run():
             try:
                 import jax
-                from .ell import (make_batched_go_kernel,
+                from .ell import (lanes_width,
+                                  make_batched_go_lanes_kernel,
                                   make_batched_sparse_go_kernel,
                                   sparse_caps)
                 d_max = max(ix.bucket_D) if ix.bucket_D else 1
@@ -2280,12 +2201,7 @@ class TpuQueryRuntime:
                     with self._lock:
                         self._prewarmed_shapes.add(shape_key)
                         self.stats["prewarm_compiled"] += 1
-                packed_mode = bool(flags.get("tpu_packed_frontier",
-                                             True))
-                if packed_mode:
-                    from .ell import (lanes_width,
-                                      make_batched_go_lanes_kernel)
-                    eslot, hrows = self._hub_merge_dev(m, ix)
+                eslot, hrows = self._hub_merge_dev(m, ix)
                 for B in sorted(int(w) for w in
                                 str(flags.get("go_batch_widths") or
                                     "128,1024").split(",") if w.strip()):
@@ -2293,29 +2209,17 @@ class TpuQueryRuntime:
                         return
                     if steps <= 1:
                         continue
-                    if packed_mode:
-                        kern = self._kernel(
-                            ("ell_go_packed", ix.shape_sig(), et_tuple,
-                             steps, False),
-                            lambda: make_batched_go_lanes_kernel(
-                                ix, steps, et_tuple, donate=True))
-                        kern.lower(
-                            i32((ix.n_rows + 1, lanes_width(B)),
-                                np.uint8),
-                            eslot, hrows, *args[1:]).compile()
-                        shape_key = ("ell_go_packed", ix.shape_sig(),
-                                     et_tuple, steps, B)
-                    else:
-                        kern = self._kernel(
-                            ("ell_go", ix.shape_sig(), et_tuple, steps,
-                             False),
-                            lambda: make_batched_go_kernel(
-                                ix, steps, et_tuple, pack=True,
-                                donate=True))   # must match live dispatch
-                        kern.lower(i32((ix.n_rows + 1, B), np.int8),
-                                   *args).compile()
-                        shape_key = ("ell_go", ix.shape_sig(), et_tuple,
-                                     steps, B)
+                    kern = self._kernel(
+                        ("ell_go_packed", ix.shape_sig(), et_tuple,
+                         steps, False),
+                        lambda: make_batched_go_lanes_kernel(
+                            ix, steps, et_tuple,
+                            donate=True))       # must match live dispatch
+                    kern.lower(
+                        i32((ix.n_rows + 1, lanes_width(B)), np.uint8),
+                        eslot, hrows, *args[1:]).compile()
+                    shape_key = ("ell_go_packed", ix.shape_sig(),
+                                 et_tuple, steps, B)
                     with self._lock:
                         self._prewarmed_shapes.add(shape_key)
                         self.stats["prewarm_compiled"] += 1
@@ -2369,13 +2273,6 @@ class TpuQueryRuntime:
         deadline = time.monotonic() + timeout_s
         for t in threads:
             t.join(timeout=max(0.0, deadline - time.monotonic()))
-
-    def _hub_dev(self, m: CsrMirror, ix: EllIndex):
-        import jax.numpy as jnp
-        cached = getattr(m, "_hub_dev_cache", None)
-        if cached is None:
-            cached = m._hub_dev_cache = jnp.asarray(ix.hub_table())
-        return cached
 
     def _hub_expansion_dev(self, m: CsrMirror, ix: EllIndex):
         """(ecnt, e0) device arrays for the sparse kernel's exact hub
@@ -3198,7 +3095,7 @@ class TpuQueryRuntime:
 
     # ================================================== batched GO/BFS
     # The throughput path: B concurrent queries share one [rows, B]
-    # int8 frontier so the per-row-access cost (the TPU's serial
+    # frontier so the per-row-access cost (the TPU's serial
     # gather floor) is amortised across the whole batch — see
     # ell.py's module docstring.  graphd-level batching (many client
     # sessions, one device dispatch) and the perf tool drive these.
@@ -3296,32 +3193,14 @@ class TpuQueryRuntime:
         return kern
 
     @staticmethod
-    def _upload_frontier(ix: EllIndex, new_ids: np.ndarray,
-                         qcols: np.ndarray, B: int):
-        """Device [rows+1, B] start frontier built ON the device from
-        flat (new-id row, query col) coordinates — the host→device
-        transfer is the start list (bytes), not the dense mostly-zero
-        matrix (tens of MB at million-vertex scale)."""
-        import jax.numpy as jnp
-        S = len(new_ids)
-        Sp = max(8, 1 << (max(S, 1) - 1).bit_length())   # stable shapes
-        pad_row = ix.n_rows                              # always-zero row
-        rows_p = np.full(Sp, pad_row, np.int32)
-        cols_p = np.zeros(Sp, np.int32)
-        vals_p = np.zeros(Sp, np.int8)
-        rows_p[:S] = new_ids
-        cols_p[:S] = qcols
-        vals_p[:S] = 1
-        f0 = jnp.zeros((ix.n_rows + 1, B), jnp.int8)
-        return f0.at[jnp.asarray(rows_p), jnp.asarray(cols_p)].max(
-            jnp.asarray(vals_p))
-
-    @staticmethod
     def _upload_frontier_packed(ix: EllIndex, new_ids: np.ndarray,
                                 qcols: np.ndarray, B: int):
-        """Bit-packed twin of _upload_frontier: the device builds the
-        uint8 [rows+1, B/8] lane matrix from the same flat coordinate
-        upload.  (row, query) pairs are deduped HERE, so two bits never
+        """Device [rows+1, B/8] uint8 start frontier built ON the device
+        from flat (new-id row, query col) coordinates — the host→device
+        transfer is the start list (bytes), not the dense mostly-zero
+        matrix (MBs at million-vertex scale).  Padded to a power of two
+        for stable shapes.  (row, query) pairs are deduped HERE, so two
+        bits never
         collide in one scatter cell and scatter-ADD of distinct powers
         of two is exact (a scatter-max would lose bits; see
         ell._scatter_or_rows)."""
@@ -3391,7 +3270,8 @@ class TpuQueryRuntime:
         """Batched BFS core against an already-fetched mirror: int16
         [B, n] depths (INT16_INF = unreached).  The dispatch record
         carries the levels the device loop ran and the lanes used."""
-        from .ell import (INT16_INF, make_batched_bfs_kernel,
+        from .ell import (INT16_INF, dense_hop_bytes, lanes_width,
+                          make_batched_bfs_lanes_kernel,
                           make_sharded_batched_bfs_kernel)
         import time
         stamps = [time.perf_counter()]
@@ -3408,16 +3288,11 @@ class TpuQueryRuntime:
             # placement/overflow: replicated-frontier fallback below
         args = ix.kernel_args()
         mt = self._mesh_tables(m, ix)
-        # the sharded BFS frontier is bit-packed ONLY, like the sharded
-        # GO (KernelSpec.packed enforces the layout)
-        packed_mode = bool(flags.get("tpu_packed_frontier", True)) \
-            or mt is not None
-        if packed_mode:
-            eslot, hrows = self._hub_merge_dev(m, ix)
-            f0_dev = self._upload_frontier_packed(
-                ix, *self._flat_coords(m, ix, starts_per_query, nq), B)
-            t0_dev = self._upload_frontier_packed(
-                ix, *self._flat_coords(m, ix, targets_per_query, nq), B)
+        eslot, hrows = self._hub_merge_dev(m, ix)
+        f0_dev = self._upload_frontier_packed(
+            ix, *self._flat_coords(m, ix, starts_per_query, nq), B)
+        t0_dev = self._upload_frontier_packed(
+            ix, *self._flat_coords(m, ix, targets_per_query, nq), B)
         if mt is not None:
             mesh, nbrs, ets, reals = mt
             kern = self._kernel(
@@ -3428,8 +3303,7 @@ class TpuQueryRuntime:
                     mesh, "parts", ix, max_steps, et_tuple, nbrs, ets,
                     reals, stop_when_found=shortest, donate=True))
             call_args = (f0_dev, t0_dev, eslot, hrows, *nbrs, *ets)
-        elif packed_mode:
-            from .ell import make_batched_bfs_lanes_kernel
+        else:
             kern = self._kernel(
                 ("ell_bfs_packed", ix.shape_sig(), et_tuple, max_steps,
                  shortest),
@@ -3438,29 +3312,14 @@ class TpuQueryRuntime:
                     ix, max_steps, et_tuple, stop_when_found=shortest,
                     donate=True))
             call_args = (f0_dev, t0_dev, eslot, hrows, *args[1:])
-        else:
-            kern = self._kernel(
-                ("ell_bfs", ix.shape_sig(), et_tuple, max_steps, shortest),
-                # donate=True: f0/t0 are built fresh per dispatch below
-                lambda: make_batched_bfs_kernel(
-                    ix, max_steps, et_tuple, stop_when_found=shortest,
-                    donate=True))
-            f0_dev = self._upload_frontier(
-                ix, *self._flat_coords(m, ix, starts_per_query, nq), B)
-            t0_dev = self._upload_frontier(
-                ix, *self._flat_coords(m, ix, targets_per_query, nq), B)
-            call_args = (f0_dev, t0_dev, *args)
         self._bump("path_device", nq)
         stamps.append(time.perf_counter())
         with tracing.span("tpu.kernel",
                           kind="ell_bfs" if mt is None
                           else "ell_bfs_sharded", queries=nq):
             d_dev, levels_dev = kern(*call_args)
-        from .ell import dense_hop_bytes, lanes_width
         self._maybe_time_device(
-            d_dev,
-            dense_hop_bytes(ix, lanes_width(B) if packed_mode else B,
-                            max_steps + 1),
+            d_dev, dense_hop_bytes(ix, lanes_width(B), max_steps + 1),
             kind="ell_bfs")
         stamps.append(time.perf_counter())
         nqp = min(B, max(8, -(-nq // 8) * 8))

@@ -17,9 +17,8 @@
 #   4. micro_bench — the performance-budget components (`--quick`
 #      statistics are noisier but the budgets are sized for it); the
 #      lint cold-wall budget (40 s), the mc smoke-sweep budget, the
-#      admission/recovery/absorb/continuous/timeline path budgets and
-#      the kernel roofline all gate here via micro_bench's own exit
-#      status.
+#      admission/recovery/absorb/continuous/timeline path budgets
+#      all gate here via micro_bench's own exit status.
 #
 # The Perfetto golden (tests/golden_timeline.json, the byte-stable
 # chrome_trace pin) rides along to $CI_ARTIFACT_DIR beside the SARIF

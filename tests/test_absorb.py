@@ -77,28 +77,25 @@ class TestAbsorbDifferential:
         "FIND SHORTEST PATH FROM 100 TO 115 OVER follow UPTO 4 STEPS",
     ]
 
-    @pytest.mark.parametrize("mesh,mesh_mode,packed", [
-        (0, "sparse", True),       # single chip, packed default
-        (0, "sparse", False),      # single chip, int8 layout
-        (2, "sparse", True),       # frontier-sharded mesh design
-        (8, "dense", True),        # replicated-frontier mesh design
+    @pytest.mark.parametrize("mesh,mesh_mode", [
+        (0, "sparse"),             # single chip
+        (2, "sparse"),             # frontier-sharded mesh design
+        (8, "dense"),              # replicated-frontier mesh design
     ])
     def test_randomized_stream_absorbs_with_parity(self, mesh,
-                                                   mesh_mode, packed):
+                                                   mesh_mode):
         import random
-        c, cl, ok = _boot(space=f"ab{mesh}{int(packed)}")
+        c, cl, ok = _boot(space=f"ab{mesh}")
         saved = {k: flags.get(k) for k in
-                 ("tpu_mesh_devices", "tpu_mesh_mode",
-                  "tpu_packed_frontier")}
+                 ("tpu_mesh_devices", "tpu_mesh_mode")}
         flags.set("tpu_mesh_devices", mesh)
         flags.set("tpu_mesh_mode", mesh_mode)
-        flags.set("tpu_packed_frontier", packed)
         try:
             rt = c.tpu_runtime
             for q in self.QUERIES:
                 ok(q)                        # build + warm under mesh
             builds0 = rt.stats["mirror_builds"]
-            rng = random.Random(17 + mesh + int(packed))
+            rng = random.Random(18 + mesh)
             live = {(100 + i, 100 + (i + 1) % 40, 0)
                     for i in range(40)}      # (src, dst, rank)
             for step in range(10):
@@ -239,13 +236,10 @@ class TestGenerationSemantics:
                 assert np.array_equal(a, b)
             # the retired generation still ANSWERS (an in-flight
             # dispatch would): hop over its tables finds the old view
-            import jax.numpy as jnp
-            from nebula_tpu.tpu import ell as E
+            from test_ell import run_go
             et = rt.sm.to_edge_type(space, "follow").value()
             f0 = ix0.start_frontier([m0.to_dense([100])], B=8)
-            out = np.asarray(E.make_batched_go_kernel(
-                ix0, 2, (et,))(jnp.asarray(f0), *ix0.kernel_args()))
-            assert out[:, 0].any()
+            assert run_go(ix0, 2, (et,), f0)[:, 0].any()
         finally:
             c.stop()
 

@@ -38,6 +38,64 @@ def nba():
     flags.set("go_dispatch_mode", "continuous")
 
 
+def test_route_eligible_table(monkeypatch):
+    """The continuous tier's routing rule, written down once: a key
+    rides the seat map iff dispatch is continuous, the tables are not
+    mesh-sharded, and the key is a multi-hop batched GO whose
+    reduction (if any) is a count or a limit.  The rule reads two
+    flags and the key — how a frontier is stored is not the
+    dispatcher's business."""
+    from nebula_tpu.graph.batch_dispatch import ContinuousGoScheduler
+    from nebula_tpu.tpu import runtime  # noqa: F401 — defines the mesh flag
+
+    def key(steps, reduce=None, method="go_batch_execute", upto=False):
+        return (method, 1, (1,), steps, upto, reduce)
+
+    table = [
+        # mode,        mesh, key,                              eligible
+        ("continuous", 0, key(2),                               True),
+        ("continuous", 0, key(3, upto=True),                    True),
+        ("continuous", 0, key(3, ("count",)),                   True),
+        ("continuous", 0, key(2, ("limit", 10)),                True),
+        ("continuous", 1, key(2),                               True),
+        ("continuous", 0, key(1),                               False),
+        ("continuous", 0, key(0),                               False),
+        ("continuous", 0, key("x"),                             False),
+        ("continuous", 0, key(2, ("sum", "w")),                 False),
+        ("continuous", 0, key(4, method="bfs_batch_execute"),   False),
+        ("continuous", 0, ("go_batch_execute", 1, (1,), 2),     False),
+        ("continuous", 2, key(2),                               False),
+        ("continuous", 8, key(3, ("count",)),                   False),
+        ("windowed",   0, key(2),                               False),
+        ("windowed",   0, key(3, ("limit", 5)),                 False),
+        ("windowed",   4, key(2),                               False),
+    ]
+    saved = {k: flags.get(k) for k in ("go_dispatch_mode",
+                                       "tpu_mesh_devices")}
+    read = set()
+    real_get = flags.get
+
+    def spy(name, default=None):
+        read.add(name)
+        return real_get(name, default)
+
+    try:
+        for mode, mesh, k, want in table:
+            flags.set("go_dispatch_mode", mode)
+            flags.set("tpu_mesh_devices", mesh)
+            assert ContinuousGoScheduler.route_eligible(k) is want, \
+                (mode, mesh, k)
+        flags.set("go_dispatch_mode", "continuous")
+        flags.set("tpu_mesh_devices", 0)
+        monkeypatch.setattr(flags, "get", spy)
+        assert ContinuousGoScheduler.route_eligible(key(2)) is True
+        monkeypatch.undo()
+        assert read == {"go_dispatch_mode", "tpu_mesh_devices"}
+    finally:
+        for k, v in saved.items():
+            flags.set(k, v)
+
+
 def test_unfiltered_go_uses_dispatcher(nba):
     c, ok = nba
     r = ok("GO 2 STEPS FROM 1 OVER follow YIELD follow._dst")

@@ -714,16 +714,11 @@ def test_micro_bench_tool_runs():
         "key_codec": MB.bench_keys(2000),
         "wal": MB.bench_wal(500),
         "query_path": MB.bench_query(5),
-        "kernel_roofline": MB.bench_kernel_roofline(2),
     }
     assert out["parser"]["statements_per_s"] > 0
     assert out["row_codec"]["encode_rows_per_s"] > 0
     assert out["wal"]["append_entries_per_s"] > 0
     assert out["query_path"]["go_queries_per_s"] > 0
-    # packed-vs-int8 parity is a hard gate; the speed budget is only
-    # asserted by the full micro_bench run (tiny CI graphs are noisy)
-    assert out["kernel_roofline"]["parity"] is True
-    assert out["kernel_roofline"]["packed_ms_per_dispatch"] > 0
 
 
 class TestStoreTypeGate:

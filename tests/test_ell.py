@@ -14,42 +14,88 @@ from nebula_tpu.tpu import ell as E  # noqa: E402
 from nebula_tpu.tpu import kernels as K  # noqa: E402
 
 
-def run_go(ix, steps, etypes, f0):
-    """Build + invoke the batched GO kernel with the round-3 calling
-    convention (tables as args); returns the raw int8 frontier."""
-    k = E.make_batched_go_kernel(ix, steps, etypes)
-    return np.asarray(k(jnp.asarray(f0), *ix.kernel_args()))
+def _lanes_args(ix, *host_frontiers):
+    """(packed frontiers..., eslot, hrows, *tables): the lanes kernels'
+    positional arguments from host [n_rows+1, B] 0/1 matrices."""
+    eslot, hrows = ix.hub_merge()
+    return (*(jnp.asarray(E.pack_lanes_host(f)) for f in host_frontiers),
+            jnp.asarray(eslot), jnp.asarray(hrows),
+            *ix.kernel_args()[1:])
+
+
+def run_go(ix, steps, etypes, f0, upto=False):
+    """Build + invoke the batched GO kernel (tables as args) on a host
+    [n_rows+1, B] 0/1 matrix; returns the frontier as bool
+    [n_rows+1, B] (hub extra rows may hold junk)."""
+    k = E.make_batched_go_lanes_kernel(ix, steps, etypes, upto=upto)
+    return E.unpack_lanes_host(np.asarray(k(*_lanes_args(ix, f0))),
+                               f0.shape[1])
+
+
+def run_bfs_levels(ix, max_steps, etypes, f0, t0, stop_when_found=True):
+    """(int16 depths [n_rows+1, B] with INT16_INF = unreached, the
+    levels the device loop ran)."""
+    k = E.make_batched_bfs_lanes_kernel(ix, max_steps, etypes,
+                                        stop_when_found=stop_when_found)
+    d, levels = k(*_lanes_args(ix, f0, t0))
+    d = np.asarray(d)
+    if d.dtype == np.int8:           # in-kernel compression (-1 = INF)
+        d = np.where(d < 0, E.INT16_INF, d).astype(np.int16)
+    return d, int(levels)
 
 
 def run_bfs(ix, max_steps, etypes, f0, t0, stop_when_found=True):
-    k = E.make_batched_bfs_kernel(ix, max_steps, etypes,
-                                  stop_when_found=stop_when_found)
-    d = np.asarray(k(jnp.asarray(f0), jnp.asarray(t0),
-                     *ix.kernel_args())[0])
-    if d.dtype == np.int8:           # in-kernel compression (-1 = INF)
-        d = np.where(d < 0, E.INT16_INF, d).astype(np.int16)
-    return d
+    return run_bfs_levels(ix, max_steps, etypes, f0, t0,
+                          stop_when_found)[0]
 
 
-def run_adaptive(ix, steps, etypes, K, start_new_ids):
-    k = E.make_adaptive_go_kernel(ix, steps, etypes, K=K)
-    hub = jnp.asarray(ix.hub_table())
-    packed = np.asarray(k(start_new_ids, hub, *ix.kernel_args()))
-    return E.unpack_bits(packed[:, None], ix.n_rows + 1)[:, 0]
-
-
-def np_multi_hop(n, es, ed, ok, starts_per_query, steps):
+# The numpy oracles every kernel-parity test in this file AND
+# tests/test_packed_frontier.py compares against: plain per-query
+# expansion over the edge list in the OLD dense-id space, sharing
+# nothing with the ELL tables.
+def np_multi_hop(n, es, ed, ok, starts_per_query, steps, upto=False):
+    """bool [n, nq] frontier after ``steps - 1`` advances over the edges
+    selected by ``ok``; with ``upto`` the union of depths 0..steps-1."""
     nq = len(starts_per_query)
     fr = np.zeros((n, nq), bool)
     for q, s in enumerate(starts_per_query):
         fr[np.asarray(s), q] = True
+    acc = fr.copy()
     for _ in range(steps - 1):
         nxt = np.zeros_like(fr)
         for q in range(nq):
             act = fr[es, q] & ok
             nxt[ed[act], q] = True
         fr = nxt
-    return fr
+        acc |= fr
+    return acc if upto else fr
+
+
+def np_bfs_depths(n, es, ed, ok, starts_per_query, targets_per_query,
+                  max_steps, shortest):
+    """(int16 [n, nq] BFS depths with INT16_INF = unreached, levels
+    run).  The whole batch advances level by level and stops at
+    ``max_steps``, when no query has a live frontier, or (``shortest``)
+    when no query has an unreached target — the batched kernels' exit
+    rule."""
+    nq = len(starts_per_query)
+    d = np.full((n, nq), E.INT16_INF, np.int16)
+    tgt = np.zeros((n, nq), bool)
+    for q, (s, t) in enumerate(zip(starts_per_query, targets_per_query)):
+        d[np.asarray(s), q] = 0
+        tgt[np.asarray(t), q] = True
+    fr = d == 0
+    level = 0
+    while level < max_steps and fr.any() \
+            and not (shortest and not (tgt & (d == E.INT16_INF)).any()):
+        nxt = np.zeros_like(fr)
+        for q in range(nq):
+            act = fr[es, q] & ok
+            nxt[ed[act], q] = True
+        fr = nxt & (d == E.INT16_INF)
+        level += 1
+        d[fr] = level
+    return d, level
 
 
 @pytest.mark.parametrize("cap,min_d", [(4, 1), (16, 8), (512, 8)])
@@ -70,14 +116,8 @@ def test_batched_go_parity_random(cap, min_d):
 
         ix = E.EllIndex.build(es, ed, ee, n, cap=cap, min_d=min_d)
         f0 = ix.start_frontier([np.asarray(s) for s in starts], B=128)
-        got = ix.to_old(run_go(ix, steps, etypes, f0))[:, :5] > 0
+        got = ix.to_old(run_go(ix, steps, etypes, f0))[:, :5]
         np.testing.assert_array_equal(got, exp)
-
-        # packed output variant must round-trip to the same frontier
-        kp = E.make_batched_go_kernel(ix, steps, etypes, pack=True)
-        packed = np.asarray(kp(jnp.asarray(f0), *ix.kernel_args()))
-        unp = E.unpack_bits(packed, ix.n_rows + 1)
-        np.testing.assert_array_equal(ix.to_old(unp)[:, :5], exp)
 
 
 def test_hub_rows_split_and_merge():
@@ -170,8 +210,7 @@ def test_sharded_batched_go_parity():
     got = np.asarray(go(jnp.asarray(E.pack_lanes_host(f0)),
                         jnp.asarray(eslot), jnp.asarray(hrows),
                         *nbrs, *ets))
-    np.testing.assert_array_equal(E.unpack_lanes_host(got, 128),
-                                  np.asarray(ref) > 0)
+    np.testing.assert_array_equal(E.unpack_lanes_host(got, 128), ref)
 
 
 def test_runtime_go_batch_small_cluster():
@@ -354,38 +393,8 @@ def test_native_builder_identical():
                 np.testing.assert_array_equal(x, y)
 
 
-def test_adaptive_kernel_parity_random():
-    """Adaptive sparse-frontier kernel vs the batched kernel on random
-    mirror-shaped graphs (both directions present), across K values
-    that force mid-query overflow to the dense pull."""
-    rng = np.random.default_rng(23)
-    for _ in range(5):
-        n = int(rng.integers(10, 400))
-        m = int(rng.integers(0, 3000))
-        es = rng.integers(0, n, m).astype(np.int32)
-        ed = rng.integers(0, n, m).astype(np.int32)
-        ee = rng.choice([1, 2], m).astype(np.int32)
-        es2 = np.concatenate([es, ed])
-        ed2 = np.concatenate([ed, es])
-        ee2 = np.concatenate([ee, -ee])
-        steps = int(rng.integers(2, 6))
-        K = int(rng.choice([16, 64, 2048]))
-        ix = E.EllIndex.build(es2, ed2, ee2, n, cap=int(rng.choice([8, 64])),
-                              min_d=4)
-        starts = rng.integers(0, n, int(rng.integers(1, 5)))
-        exp = ix.to_old(run_go(ix, steps, (1,),
-                               ix.start_frontier([starts],
-                                                 B=128)))[:, 0] > 0
-        got = ix.to_old(run_adaptive(ix, steps, (1,), K,
-                                     ix.perm[starts])) > 0
-        np.testing.assert_array_equal(got, exp)
-
-
-def test_adaptive_runtime_single_query():
-    """A lone GO through the runtime rides the adaptive kernel and
-    returns the same rows as the batched path."""
+def _lone_go_cluster():
     from nebula_tpu.cluster import LocalCluster
-    from nebula_tpu.common.flags import flags
     c = LocalCluster(num_storage=1, tpu_backend=True)
     g = c.client()
     assert g.execute("CREATE SPACE ak(partition_num=3, replica_factor=1)").ok()
@@ -395,42 +404,63 @@ def test_adaptive_runtime_single_query():
     c.refresh_all()
     assert g.execute("INSERT EDGE e(w) VALUES 1->2:(1), 2->3:(1), "
                      "3->4:(1), 2->5:(1)").ok()
-    r1 = g.execute("GO 2 STEPS FROM 1 OVER e YIELD e._dst")
-    assert r1.ok() and sorted(x[0] for x in r1.rows) == [3, 5]
-    # same query with the adaptive path disabled must match
-    flags.set("tpu_adaptive_single", False)
+    return c, g
+
+
+def _cpu_rows(g, stmt):
+    from nebula_tpu.common.flags import flags
+    flags.set("storage_backend", "cpu")
     try:
-        r2 = g.execute("GO 2 STEPS FROM 1 OVER e YIELD e._dst")
+        r = g.execute(stmt)
     finally:
-        flags.set("tpu_adaptive_single", True)
-    assert sorted(map(tuple, r1.rows)) == sorted(map(tuple, r2.rows))
-    c.stop()
+        flags.set("storage_backend", "tpu")
+    assert r.ok(), r.error_msg
+    return sorted(map(tuple, r.rows))
 
 
-def test_adaptive_hub_in_frontier_switches_dense():
-    """A frontier containing a hub vertex (slots spilling into extra
-    rows) must produce exact results — the kernel switches to the
-    dense pull for that hop instead of materializing hub-degree-scaled
-    candidate lists."""
-    rng = np.random.default_rng(9)
-    n = 300
-    # hub vertex 7: 200 out-edges; plus background edges
-    hub_dst = rng.integers(0, n, 200).astype(np.int32)
-    es = np.concatenate([np.full(200, 7, np.int32),
-                         rng.integers(0, n, 500).astype(np.int32)])
-    ed = np.concatenate([hub_dst, rng.integers(0, n, 500).astype(np.int32)])
-    ee = np.ones(len(es), np.int32)
-    es2 = np.concatenate([es, ed]); ed2 = np.concatenate([ed, es])
-    ee2 = np.concatenate([ee, -ee])
-    ix = E.EllIndex.build(es2, ed2, ee2, n, cap=16, min_d=4)
-    assert len(ix.extra_owner) > 0                 # hub rows exist
-    for steps in (2, 4):
-        exp = ix.to_old(run_go(ix, steps, (1,),
-                               ix.start_frontier([np.asarray([7])],
-                                                 B=128)))[:, 0] > 0
-        got = ix.to_old(run_adaptive(ix, steps, (1,), 64,
-                                     ix.perm[np.asarray([7])])) > 0
-        np.testing.assert_array_equal(got, exp)
+def test_lone_go_serves_cpu_rows():
+    """A lone GO through the runtime is device-served and returns the
+    CPU executor's rows."""
+    c, g = _lone_go_cluster()
+    try:
+        stmt = "GO 2 STEPS FROM 1 OVER e YIELD e._dst"
+        served0 = c.tpu_runtime.stats["go_device"]
+        r1 = g.execute(stmt)
+        assert r1.ok() and sorted(x[0] for x in r1.rows) == [3, 5]
+        assert c.tpu_runtime.stats["go_device"] > served0
+        assert sorted(map(tuple, r1.rows)) == _cpu_rows(g, stmt)
+    finally:
+        c.stop()
+
+
+@pytest.mark.parametrize("mode", ["windowed", "continuous"])
+def test_lone_go_with_unknown_start_is_empty(mode):
+    """The one input whose route changed when the adaptive kernel
+    went: a lone GO none of whose start vertices the mirror knows.
+    Windowed, it is a dense dispatch over an empty frontier; either
+    tier answers no rows, as the CPU executor does, on the device,
+    without a decline and without moving the breaker."""
+    from nebula_tpu.common.flags import flags
+    c, g = _lone_go_cluster()
+    rt = c.tpu_runtime
+    stmt = "GO 2 STEPS FROM 777, 778 OVER e YIELD e._dst"
+    flags.set("go_dispatch_mode", mode)
+    try:
+        assert g.execute("GO 2 STEPS FROM 1 OVER e").ok()   # mirror up
+        s0 = dict(rt.stats)
+        r = g.execute(stmt)
+        assert r.ok(), r.error_msg
+        assert r.rows == [] and not r.warnings
+        assert _cpu_rows(g, stmt) == []
+        assert rt.stats["go_device"] == s0["go_device"] + 1
+        if mode == "windowed":
+            assert rt.stats["go_dense"] == s0["go_dense"] + 1
+            assert rt.stats["go_sparse"] == s0["go_sparse"]
+        assert all(state == "closed"
+                   for _k, state, _why in rt.breaker.cells_snapshot())
+    finally:
+        flags.set("go_dispatch_mode", "continuous")
+        c.stop()
 
 
 def test_sparse_batched_go_parity_random():

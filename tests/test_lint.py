@@ -767,7 +767,7 @@ def test_jaxaudit_package_registry_is_clean_within_budgets():
 
     registry = kernel_registry()
     assert {"go", "go_filtered", "bfs", "sharded_go", "ell_go",
-            "sparse_go", "adaptive_go", "ell_bfs", "ell_absorb",
+            "sparse_go", "ell_bfs", "ell_absorb",
             "ell_absorb_sharded", "expr_filter"} <= set(registry)
     fx = AuditFixture()
     vs, kinds = audit_specs(registry.values(), fx, rt.DEVICE_PHASES,

@@ -979,9 +979,8 @@ class TestUptoDevice:
 
     def test_upto_dense_kernel_union(self):
         """Dense UPTO variant ORs every depth's frontier."""
-        import jax.numpy as jnp
-
         from nebula_tpu.tpu import ell as E
+        from test_ell import run_go
 
         rng = np.random.default_rng(5)
         n, m = 200, 900
@@ -995,8 +994,7 @@ class TestUptoDevice:
         f0 = ix.start_frontier([np.asarray([3]), np.asarray([7, 11])],
                                B=8)
         steps = 3
-        kern = E.make_batched_go_kernel(ix, steps, (1,), upto=True)
-        out = np.asarray(kern(jnp.asarray(f0), *ix.kernel_args()))
+        out = run_go(ix, steps, (1,), f0, upto=True)
         # numpy oracle: OR of frontiers at depths 0..steps-1
         adj = {}
         for s_, d_ in zip(es.tolist(), ed.tolist()):
