@@ -608,7 +608,11 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #                                        _hop_body_packed sweep of every
 #                                        slot of the table, unchanged.
 #                                ``info`` says which ran and what it
-#                                visited; nothing on the host chooses
+#                                visited; nothing on the host chooses.
+#                                The choice lives in ONE place,
+#                                _make_frontier_step: every level of
+#                                the batched BFS lanes program takes
+#                                the same step under the same budget
 #   make_lane_join_kernel        scatter-ADD of single lane bits into
 #                                FREE lanes.  Exact by the clear
 #                                contract: a freed lane's bit is zero
@@ -732,51 +736,40 @@ def _hop_push_packed(jnp, jax, n: int, n_rows: int,
     return nxt
 
 
-def make_continuous_hop_kernel(ell: EllIndex,
-                               etypes: Tuple[int, ...],
-                               donate: bool = True,
-                               push_rows: Optional[int] = None):
-    """One continuous-mode frontier advance.
+def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
+                         push_rows: Optional[int] = None):
+    """THE place where a frontier advance chooses push or pull — the
+    continuous hop (make_continuous_hop_kernel) and every BFS level
+    (make_batched_bfs_lanes_kernel) call the step this returns, so the
+    rule exists once.
 
-    fn(fp uint8 [n_rows+1, W], accp uint8 [n_rows+1, W],
-       eslot int32[n_extras], hrows int32[n_hubs], *tables)
-    -> (fp', accp', info int32[3]): fp' is one packed hop of fp, accp'
-    accumulates the union (the per-lane UPTO carrier — exact-depth
-    lanes simply never read it).  Unlike the windowed kernels the hop
-    count is NOT baked in: one jitted program serves every mix of
-    per-query depths, so the cache key space per (mirror, OVER) family
-    is ONE entry per lane-width rung.
-
-    The program measures the frontier it is handed — the live slot
+    step(fp uint8 [n_rows+1, W], eslot, hrows, nbrs, ets) ->
+    (next frontier, sparse bool, live slot rows int32, their slots
+    int32).  It measures the frontier it is handed — the live slot
     rows: a real row v < n with any lane bit set, plus the hub extra
     rows of such a v (rows >= n of ``fp`` hold a previous pull's
-    partial ORs and are never read as sources) — and takes the push
-    (_hop_push_packed) when they number at most ``push_rows``
-    (HOP_PUSH_ROWS unless a test passes its own), else the pull over
-    every slot (_hop_body_packed, as the windowed kernels run it).
-    Both are exact on rows < n and the pad row; they differ only in
-    what they leave in the extra rows, which nothing reads.
-    ``info`` = [1 if the push ran else 0, live slot rows, ELL slots
-    the hop visited (the live rows' widths, or the whole table)]; the
-    session reads it without waiting on the hop."""
+    partial ORs and are never read as sources) — and takes, on the
+    device, the push (_hop_push_packed) when they number at most
+    ``push_rows`` (HOP_PUSH_ROWS unless a test passes its own), else
+    the pull over every slot (_hop_body_packed, as the windowed
+    kernels run it).  Both are exact on rows < n and the pad row; they
+    differ only in what they leave in the extra rows, which nothing
+    reads.  ``slots`` is the live rows' widths whichever branch ran: a
+    pull visited table_slots(ell)."""
     import jax
     import jax.numpy as jnp
     n, n_rows = ell.n, ell.n_rows
     n_extras, nb = len(ell.extra_owner), len(ell.bucket_nbr)
-    all_slots = table_slots(ell)
     if push_rows is None:
         push_rows = HOP_PUSH_ROWS
 
-    def hop(fp, accp, eslot, hrows, *tables):
-        nbrs, ets = tables[:nb], tables[nb:]
-
+    def step(fp, eslot, hrows, nbrs, ets):
         def pull(fp):
             return _hop_body_packed(jnp, jax, n, n_extras, etypes,
                                     nbrs, ets, eslot, hrows, fp)
 
         if not nb:                     # empty graph: nothing moves
-            nxt = pull(fp)
-            return nxt, accp | nxt, jnp.zeros((3,), jnp.int32)
+            return pull(fp), jnp.bool_(False), jnp.int32(0), jnp.int32(0)
         with jax.named_scope("hop/frontier"):
             row_live = jnp.any(fp[:n] != 0, axis=1)
             owner = None
@@ -803,6 +796,40 @@ def make_continuous_hop_kernel(ell: EllIndex,
                                     push_rows)
 
         nxt = jax.lax.cond(sparse, push, pull, fp)
+        return nxt, sparse, live_rows, slots
+
+    return step
+
+
+def make_continuous_hop_kernel(ell: EllIndex,
+                               etypes: Tuple[int, ...],
+                               donate: bool = True,
+                               push_rows: Optional[int] = None):
+    """One continuous-mode frontier advance.
+
+    fn(fp uint8 [n_rows+1, W], accp uint8 [n_rows+1, W],
+       eslot int32[n_extras], hrows int32[n_hubs], *tables)
+    -> (fp', accp', info int32[3]): fp' is one packed hop of fp, accp'
+    accumulates the union (the per-lane UPTO carrier — exact-depth
+    lanes simply never read it).  Unlike the windowed kernels the hop
+    count is NOT baked in: one jitted program serves every mix of
+    per-query depths, so the cache key space per (mirror, OVER) family
+    is ONE entry per lane-width rung.
+
+    The advance is _make_frontier_step's: a push out of the live slot
+    rows or the pull over every slot, chosen on the device.
+    ``info`` = [1 if the push ran else 0, live slot rows, ELL slots
+    the hop visited (the live rows' widths, or the whole table)]; the
+    session reads it without waiting on the hop."""
+    import jax
+    import jax.numpy as jnp
+    nb = len(ell.bucket_nbr)
+    all_slots = table_slots(ell)
+    step = _make_frontier_step(ell, etypes, push_rows)
+
+    def hop(fp, accp, eslot, hrows, *tables):
+        nxt, sparse, live_rows, slots = step(fp, eslot, hrows,
+                                             tables[:nb], tables[nb:])
         info = jnp.stack([sparse.astype(jnp.int32), live_rows,
                           jnp.where(sparse, slots,
                                     jnp.int32(all_slots))])
@@ -1203,10 +1230,25 @@ def make_sharded_ell_absorb_kernel(mesh, axis: str, ell: EllIndex,
     return jax.jit(fn)
 
 
+# info vector of the batched BFS program (int32[3]): levels the loop
+# ran, how many of them pushed, and the ELL slots the PUSHED levels
+# visited (a pulled level visited table_slots(ell): bfs_slots adds
+# them on the host, where an integer cannot overflow)
+BFS_INFO_LEVELS, BFS_INFO_PUSHED, BFS_INFO_PUSH_SLOTS = 0, 1, 2
+
+
+def bfs_slots(ell: EllIndex, info) -> int:
+    """ELL slots the levels of one BFS dispatch visited: a pushed
+    level its live slot rows' widths, a pulled one the whole table."""
+    pulled = int(info[BFS_INFO_LEVELS]) - int(info[BFS_INFO_PUSHED])
+    return int(info[BFS_INFO_PUSH_SLOTS]) + pulled * table_slots(ell)
+
+
 def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
                                   etypes: Tuple[int, ...],
                                   stop_when_found: bool = True,
-                                  donate: bool = False):
+                                  donate: bool = False,
+                                  push_rows: Optional[int] = None):
     """Batched BFS (the analogue of kernels.make_bfs_kernel): the
     frontier rides the hop gathers 1-bit packed (the gather traffic is
     the level loop's cost center); the depth matrix stays per-lane (its
@@ -1214,45 +1256,64 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
     exits early when every query either stalled or (``stop_when_found``,
     shortest mode) covered its targets.
 
+    A level FOLLOWS ITS FRONTIER: it is _make_frontier_step's advance,
+    the one the continuous hop takes — a push out of the live slot rows
+    while they number at most ``push_rows`` (HOP_PUSH_ROWS unless a
+    test passes its own), the pull over every slot when they do not; a
+    BFS from one source a lane starts with the smallest frontiers the
+    system sees.  The two branches agree on rows < n and differ in the
+    hub extra rows (>= n), so the loop reads rows < n only: depths,
+    ``levels`` and the stall test are the same whichever branch ran a
+    level.
+
     fn(f0p, t0p, eslot, hrows, *tables) -> (depth [n_rows+1, B] (int8
     with -1 = unreachable when max_steps fits — the transfer is 2x
-    smaller and depths are tiny — else int16 with INT16_INF), the
-    levels the loop ran (int32 scalar)).  Both frontier matrices are
-    built fresh per dispatch by runtime._bfs_depths, which opts in to
+    smaller and depths are tiny — else int16 with INT16_INF), info
+    int32[3] = [levels the loop ran, levels that pushed, ELL slots the
+    pushed levels visited]; bfs_slots(ell, info) is what all the levels
+    visited).  Rows >= n of the depth matrix stay as the start
+    frontier leaves them, unreached (it holds no bit there): nothing
+    the host reads (EllIndex.to_old).  Both frontier matrices are built
+    fresh per dispatch by runtime._bfs_depths, which opts in to
     ``donate`` (see make_batched_go_lanes_kernel for why the default
     stays off)."""
     import jax
     import jax.numpy as jnp
-    n, n_extras, nb_count = ell.n, len(ell.extra_owner), \
-        len(ell.bucket_nbr)
+    n, nb_count = ell.n, len(ell.bucket_nbr)
     small = max_steps <= 120
+    advance = _make_frontier_step(ell, etypes, push_rows)
 
     def bfs(f0p, t0p, eslot, hrows, *tables):
         nbrs, ets = tables[:nb_count], tables[nb_count:]
+        real = (jnp.arange(f0p.shape[0]) < n)[:, None]
         tb = _unpack_lanes(jnp, t0p) > 0
         d0 = jnp.where(_unpack_lanes(jnp, f0p) > 0, jnp.int16(0),
                        INT16_INF)
 
         def cond(state):
-            d, fp, step = state
-            go_on = (step < max_steps) & (fp != 0).any()
+            d, fp, step = state[:3]
+            go_on = (step < max_steps) & (fp[:n] != 0).any()
             if stop_when_found:
                 go_on = go_on & (tb & (d == INT16_INF)).any()
             return go_on
 
         def body(state):
-            d, fp, step = state
-            nxtp = _hop_body_packed(jnp, jax, n, n_extras, etypes,
-                                    nbrs, ets, eslot, hrows, fp)
-            newly = (_unpack_lanes(jnp, nxtp) > 0) & (d == INT16_INF)
+            d, fp, step, pushed, push_slots = state
+            nxtp, sparse, _rows, slots = advance(fp, eslot, hrows,
+                                                 nbrs, ets)
+            newly = (_unpack_lanes(jnp, nxtp) > 0) & (d == INT16_INF) \
+                & real
             d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
-            return d, _pack_lanes(jnp, newly), step + 1
+            return (d, _pack_lanes(jnp, newly), step + 1,
+                    pushed + sparse.astype(jnp.int32),
+                    push_slots + jnp.where(sparse, slots, 0))
 
-        d, _, levels = jax.lax.while_loop(cond, body,
-                                          (d0, f0p, jnp.int32(0)))
+        zero = jnp.int32(0)
+        d, _, levels, pushed, push_slots = jax.lax.while_loop(
+            cond, body, (d0, f0p, zero, zero, zero))
         if small:
             d = jnp.where(d == INT16_INF, -1, d).astype(jnp.int8)
-        return d, levels
+        return d, jnp.stack([levels, pushed, push_slots])
 
     return jax.jit(bfs, donate_argnums=(0, 1) if donate else ())
 
@@ -2510,6 +2571,10 @@ register_kernel(KernelSpec(
     d2h_bytes_max=lambda fx: 4 * (2 + fx.qmax)))
 register_kernel(KernelSpec(
     "ell_bfs", make_batched_bfs_lanes_kernel, phase_kind="ell_bfs",
+    # one retrace per pinned batch width per shortest/all variant; a
+    # level's push and pull are two branches of the ONE program (the
+    # hop's step).  Outputs: the depth matrix and the int32[3] info
+    # vector (BFS_INFO_*)
     budget=4, instantiate=_ell_bfs_buckets, donate=(0, 1),
     dispatch=(0, 1), frontier=(0, 1), packed=(0, 1)))
 register_kernel(KernelSpec(

@@ -429,7 +429,8 @@ DEVICE_PHASES = {
     # published generation's device arrays — nothing crosses the link
     # back)
     "ell_absorb": {"phases": ("tpu.absorb",), "h2d": 3, "d2h": 2},
-    # the second fetch is the level count the loop ran (4 bytes)
+    # the second fetch is the loop's int32[3] info vector: levels run,
+    # levels that pushed, slots the pushed levels visited (12 bytes)
     "ell_bfs": {"phases": ("tpu.kernel", "tpu.fetch"), "h2d": 2,
                 "d2h": 2},
     # continuous hop-boundary batching (graph/batch_dispatch.py,
@@ -507,7 +508,8 @@ class TpuQueryRuntime:
                       # rows answered, statements cut at find_path_max_paths,
                       # in-edge orders built (one a mirror generation
                       # and OVER set, at its first path statement)
-                      "path_levels": 0, "path_rows": 0, "path_capped": 0,
+                      "path_levels": 0, "path_levels_push": 0,
+                      "path_rows": 0, "path_capped": 0,
                       "path_index_builds": 0,
                       "mirror_builds": 0,
                       "mirror_deltas": 0, "mirror_absorbs": 0,
@@ -3269,8 +3271,11 @@ class TpuQueryRuntime:
                     max_steps: int, shortest: bool) -> np.ndarray:
         """Batched BFS core against an already-fetched mirror: int16
         [B, n] depths (INT16_INF = unreached).  The dispatch record
-        carries the levels the device loop ran and the lanes used."""
-        from .ell import (INT16_INF, dense_hop_bytes, lanes_width,
+        carries the levels the device loop ran, how many of them pushed
+        out of their live rows, the slots they visited in all, and the
+        lanes used."""
+        from .ell import (BFS_INFO_LEVELS, BFS_INFO_PUSHED, INT16_INF,
+                          bfs_slots, dense_hop_bytes, lanes_width,
                           make_batched_bfs_lanes_kernel,
                           make_sharded_batched_bfs_kernel)
         import time
@@ -3317,7 +3322,7 @@ class TpuQueryRuntime:
         with tracing.span("tpu.kernel",
                           kind="ell_bfs" if mt is None
                           else "ell_bfs_sharded", queries=nq):
-            d_dev, levels_dev = kern(*call_args)
+            d_dev, info_dev = kern(*call_args)
         self._maybe_time_device(
             d_dev, dense_hop_bytes(ix, lanes_width(B), max_steps + 1),
             kind="ell_bfs")
@@ -3325,9 +3330,12 @@ class TpuQueryRuntime:
         nqp = min(B, max(8, -(-nq // 8) * 8))
         with tracing.span("tpu.fetch"):
             host = np.asarray(d_dev[:, :nqp])[:, :nq]   # device slice
-            levels = int(levels_dev)
+            # the lanes program's int32[3] (ell.BFS_INFO_*); the sharded
+            # one's level count alone
+            info = np.asarray(info_dev).reshape(-1)
             self._note_fetch(host)
         stamps.append(time.perf_counter())
+        levels = int(info[BFS_INFO_LEVELS])
         self._bump("path_levels", levels)
         # the record is written once the level count is on the host,
         # so its time is the dispatch's end; its stages (tables +
@@ -3349,8 +3357,11 @@ class TpuQueryRuntime:
                         "h2d_bytes": 2 * fbytes, "levels": levels,
                         "queries": nq, **stages})
         else:
+            levels_push = int(info[BFS_INFO_PUSHED])
+            self._bump("path_levels_push", levels_push)
             _flight.recorder.note_dispatch(
                 "ell_bfs", rung=B, steps=max_steps, levels=levels,
+                levels_push=levels_push, slots=bfs_slots(ix, info),
                 queries=nq, **stages)
         if host.dtype == np.int8:        # in-kernel compression (-1=INF)
             d = np.where(host < 0, INT16_INF, host).astype(np.int16)

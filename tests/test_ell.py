@@ -23,6 +23,18 @@ def _lanes_args(ix, *host_frontiers):
             *ix.kernel_args()[1:])
 
 
+def _mirror_edges(es, ed, ee):
+    """An edge list in the mirror's form: every edge stored both ways,
+    the reverse under -etype (EllIndex.build's contract; a level that
+    pushes walks a row's -t slots)."""
+    return (np.concatenate([es, ed]), np.concatenate([ed, es]),
+            np.concatenate([ee, -ee]))
+
+
+def _mirror_ell(es, ed, ee, n, **kw):
+    return E.EllIndex.build(*_mirror_edges(es, ed, ee), n, **kw)
+
+
 def run_go(ix, steps, etypes, f0, upto=False):
     """Build + invoke the batched GO kernel (tables as args) on a host
     [n_rows+1, B] 0/1 matrix; returns the frontier as bool
@@ -32,16 +44,26 @@ def run_go(ix, steps, etypes, f0, upto=False):
                                f0.shape[1])
 
 
-def run_bfs_levels(ix, max_steps, etypes, f0, t0, stop_when_found=True):
+def run_bfs_info(ix, max_steps, etypes, f0, t0, stop_when_found=True,
+                 push_rows=None):
     """(int16 depths [n_rows+1, B] with INT16_INF = unreached, the
-    levels the device loop ran)."""
+    program's info vector: levels run, levels that pushed, slots the
+    pushed levels visited)."""
     k = E.make_batched_bfs_lanes_kernel(ix, max_steps, etypes,
-                                        stop_when_found=stop_when_found)
-    d, levels = k(*_lanes_args(ix, f0, t0))
+                                        stop_when_found=stop_when_found,
+                                        push_rows=push_rows)
+    d, info = k(*_lanes_args(ix, f0, t0))
     d = np.asarray(d)
     if d.dtype == np.int8:           # in-kernel compression (-1 = INF)
         d = np.where(d < 0, E.INT16_INF, d).astype(np.int16)
-    return d, int(levels)
+    return d, np.asarray(info)
+
+
+def run_bfs_levels(ix, max_steps, etypes, f0, t0, stop_when_found=True):
+    """(depths as run_bfs_info gives them, the levels the device loop
+    ran)."""
+    d, info = run_bfs_info(ix, max_steps, etypes, f0, t0, stop_when_found)
+    return d, int(info[E.BFS_INFO_LEVELS])
 
 
 def run_bfs(ix, max_steps, etypes, f0, t0, stop_when_found=True):
@@ -163,7 +185,7 @@ def test_batched_bfs_depths():
     ed = np.array([1, 2, 3, 4, 5, 6, 7, 8, 9, 5], np.int32)
     ee = np.ones(10, np.int32)
     n = 10
-    ix = E.EllIndex.build(es, ed, ee, n, cap=4, min_d=1)
+    ix = _mirror_ell(es, ed, ee, n, cap=4, min_d=1)
     f0 = ix.start_frontier([np.asarray([0]), np.asarray([3])], B=128)
     t0 = ix.start_frontier([np.asarray([9]), np.asarray([9])], B=128)
     d = run_bfs(ix, 8, (1,), f0, t0, stop_when_found=False)[ix.perm]
@@ -179,7 +201,7 @@ def test_bfs_early_exit_shortest():
     es = np.array([0, 1], np.int32)
     ed = np.array([1, 2], np.int32)
     ee = np.ones(2, np.int32)
-    ix = E.EllIndex.build(es, ed, ee, 3, cap=2, min_d=1)
+    ix = _mirror_ell(es, ed, ee, 3, cap=2, min_d=1)
     f0 = ix.start_frontier([np.asarray([0])], B=128)
     t0 = ix.start_frontier([np.asarray([1])], B=128)
     d = run_bfs(ix, 100, (1,), f0, t0, stop_when_found=True)[ix.perm]
@@ -213,25 +235,30 @@ def test_sharded_batched_go_parity():
     np.testing.assert_array_equal(E.unpack_lanes_host(got, 128), ref)
 
 
-def test_runtime_go_batch_small_cluster():
-    """go_batch/bfs_batch through the full runtime on a real in-process
-    cluster (the batched dispatch graphd-level batching rides on)."""
+def _follow_cluster(space):
+    """(cluster, runtime, space id, edge type) of a real in-process
+    cluster holding 1->2->3->4 and 1->5 over ``follow``."""
     from nebula_tpu.cluster import LocalCluster
     c = LocalCluster(num_storage=1, tpu_backend=True)
     g = c.client()
-    for stmt in ("CREATE SPACE s(partition_num=3, replica_factor=1)",):
-        assert g.execute(stmt).ok()
+    assert g.execute(
+        f"CREATE SPACE {space}(partition_num=3, replica_factor=1)").ok()
     c.refresh_all()
-    assert g.execute("USE s").ok()
+    assert g.execute(f"USE {space}").ok()
     assert g.execute("CREATE EDGE follow(w int)").ok()
     c.refresh_all()
     assert g.execute(
         "INSERT EDGE follow(w) VALUES 1->2:(1), 2->3:(1), "
         "3->4:(1), 1->5:(1)").ok()
-
-    rt = c.tpu_runtime
-    sid = c.graph_meta_client.get_space_id_by_name("s").value()
+    sid = c.graph_meta_client.get_space_id_by_name(space).value()
     et = c.schema_man.to_edge_type(sid, "follow").value()
+    return c, c.tpu_runtime, sid, et
+
+
+def test_runtime_go_batch_small_cluster():
+    """go_batch/bfs_batch through the full runtime on a real in-process
+    cluster (the batched dispatch graphd-level batching rides on)."""
+    c, rt, sid, et = _follow_cluster("s")
     out = rt.go_batch(sid, [[1], [2], [1]], [et], 2)
     m = rt.mirror(sid)
 
@@ -706,23 +733,22 @@ def test_frontier_sharded_sparse_bfs_bitmatch():
 # the pull leaves partial ORs there, the push zeros.
 # ============================================================
 def _hop_graph(seed, n=300, m=5000, cap=16, min_d=2, etypes=(1, 2),
-               multi_edges=False):
+               multi_edges=False, src_below=None, with_edges=False):
     """A skewed two-edge-type graph in the mirror's form (both
     directions, reverse under -etype) with hubs of several extra
-    rows."""
+    rows.  ``src_below`` keeps the vertices from it up without an
+    out-edge; ``with_edges`` also returns the mirror's edge list."""
     rng = np.random.default_rng(seed)
     dst = (rng.zipf(1.5, m) % n).astype(np.int32)
-    src = rng.integers(0, n, m).astype(np.int32)
+    src = rng.integers(0, src_below or n, m).astype(np.int32)
     et = rng.choice(np.asarray(etypes, np.int32), m)
     if not multi_edges:
         _, first = np.unique(
             (src.astype(np.int64) * n + dst) * 4 + et, return_index=True)
         src, dst, et = src[first], dst[first], et[first]
-    ix = E.EllIndex.build(np.concatenate([src, dst]),
-                        np.concatenate([dst, src]),
-                        np.concatenate([et, -et]), n, cap=cap, min_d=min_d,
-                        growth_slack=3)
-    return ix
+    edges = _mirror_edges(src, dst, et)
+    ix = E.EllIndex.build(*edges, n, cap=cap, min_d=min_d, growth_slack=3)
+    return (ix, edges) if with_edges else ix
 
 
 def _hop_frontier(ix, rng, n_live, W, words=None, junk=True):
@@ -744,6 +770,16 @@ def _live_slot_rows(ix, rows):
     each plus their hub extra rows."""
     ecnt, _e0 = ix.hub_expansion()
     return int(len(rows) + ecnt[np.asarray(rows, np.int64)].sum())
+
+
+def _live_slots(ix, rows):
+    """ELL slots a push out of these live vertices (new ids) visits:
+    the widths of their main rows and of their hub extra rows."""
+    widths = np.concatenate([np.full(nbr.shape[0], nbr.shape[1])
+                             for nbr in ix.bucket_nbr])
+    ecnt, e0 = ix.hub_expansion()
+    return sum(int(widths[r]) + int(widths[e0[r]:e0[r] + ecnt[r]].sum())
+               for r in rows)
 
 
 def _pull_reference(ix, etypes, fp, accp):
@@ -820,12 +856,7 @@ def test_continuous_hop_agrees_with_the_pull(name, etypes, n_live, words,
     assert info[E.HOP_INFO_SPARSE] == int(pushed)
     assert info[E.HOP_INFO_ROWS] == live
     if pushed:
-        widths = np.concatenate([np.full(nbr.shape[0], nbr.shape[1])
-                                 for nbr in ix.bucket_nbr])
-        _e, e0 = ix.hub_expansion()
-        visited = sum(int(widths[r]) + int(widths[e0[r]:e0[r] + ecnt[r]]
-                                           .sum()) for r in rows)
-        assert info[E.HOP_INFO_SLOTS] == visited
+        assert info[E.HOP_INFO_SLOTS] == _live_slots(ix, rows)
     else:
         assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix)
 
@@ -862,3 +893,108 @@ def test_set_positions_is_nonzero_with_a_size():
         idx = np.nonzero(mask)[0][:cap]
         want[:len(idx)] = idx
         assert np.array_equal(got, want), (R, k, cap)
+
+
+# ============================================================
+# A BFS level follows its frontier (PR 30): the lanes program takes the
+# continuous hop's step every level, so depths, the levels run and the
+# stall test must not depend on which branch ran a level, and the
+# program must say what it did.
+# ============================================================
+BFS_GRAPHS = {
+    # name: (seed, etypes the BFS runs over, levels that push under
+    # the middle budget, which is the live slot rows of the level
+    # that ^ follows)
+    "reversely_the_frontier_grows": (22, (-1, -2), "11^000"),
+    "over_a_hubs_then_thins_out": (21, (1,), "101^01"),
+}
+
+
+def _bfs_push_case(graph):
+    """(ix, etypes, starts, targets, oracle edges, the middle budget's
+    level, the levels it lets push): hubs of several extra rows, pairs
+    named twice, a target no edge enters, a source no edge leaves."""
+    seed, etypes, mid = BFS_GRAPHS[graph]
+    n = 300
+    ix, (es, ed, ee) = _hop_graph(seed, n=n, m=1500, multi_edges=True,
+                                  src_below=n - 8, with_edges=True)
+    ecnt, _ = ix.hub_expansion()
+    assert ecnt.max() >= 3, "the graph must have hubs of several extra rows"
+    ok = np.isin(ee, etypes)
+    key = (es[ok].astype(np.int64) * n + ed[ok]) * 8 + ee[ok] + 4
+    assert len(np.unique(key)) < len(key), "no pair is named twice"
+    no_in = np.setdiff1d(np.arange(n), ed[ok])
+    no_out = np.setdiff1d(np.arange(n), es[ok])
+    assert len(no_in) and len(no_out)
+    starts = [[3], [17], [int(no_out[0])], [41]]
+    targets = [[250], [int(no_in[0])], [5], [299, 40]]
+    pushes = [c == "1" for c in mid.replace("^", "")]
+    return (ix, etypes, starts, targets, (n, es, ed, ok),
+            mid.index("^") - 1, pushes)
+
+
+@pytest.mark.parametrize("graph", sorted(BFS_GRAPHS))
+@pytest.mark.parametrize("shortest", [True, False],
+                         ids=["shortest", "all_levels"])
+@pytest.mark.parametrize("budget", ["pulls", "pushes_then_pulls", "pushes"])
+def test_bfs_level_follows_its_frontier(budget, shortest, graph):
+    ix, etypes, starts, targets, oracle, mid, mid_pushes = \
+        _bfs_push_case(graph)
+    max_steps = 5
+    want, want_levels = np_bfs_depths(*oracle, starts, targets, max_steps,
+                                      shortest)
+    # the union frontier entering level k holds the vertices some lane
+    # reached at depth k: its live slot rows decide push or pull
+    fronts = [ix.perm[np.flatnonzero((want == k).any(axis=1))]
+              for k in range(want_levels)]
+    live = [_live_slot_rows(ix, rows) for rows in fronts]
+    # (a budget of 0 rows cannot be traced: the push would gather out
+    # of an empty list; 1 is under every level's four sources)
+    push_rows = {"pulls": 1, "pushes_then_pulls": live[mid],
+                 "pushes": max(live)}[budget]
+    pushed = [rows <= push_rows for rows in live]
+    assert pushed == {"pulls": [False] * want_levels,
+                      "pushes_then_pulls": mid_pushes,
+                      "pushes": [True] * want_levels}[budget], live
+    f0 = ix.start_frontier([np.asarray(s) for s in starts], B=128)
+    t0 = ix.start_frontier([np.asarray(t) for t in targets], B=128)
+    d, info = run_bfs_info(ix, max_steps, etypes, f0, t0,
+                           stop_when_found=shortest, push_rows=push_rows)
+    # every budget gives THE SAME matrix: the oracle's depths on rows
+    # < n, unreached on the hub extra rows, the pad row and idle lanes
+    assert np.array_equal(ix.to_old(d)[:, :len(starts)], want)
+    assert (d[ix.n:] == E.INT16_INF).all()
+    assert (d[:, len(starts):] == E.INT16_INF).all()
+    assert info[E.BFS_INFO_LEVELS] == want_levels
+    assert info[E.BFS_INFO_PUSHED] == sum(pushed)
+    push_slots = sum(_live_slots(ix, rows)
+                     for rows, p in zip(fronts, pushed) if p)
+    assert info[E.BFS_INFO_PUSH_SLOTS] == push_slots
+    assert E.bfs_slots(ix, info) == push_slots \
+        + (want_levels - sum(pushed)) * E.table_slots(ix)
+
+
+def test_runtime_bfs_record_says_how_its_levels_ran():
+    """rt.bfs_batch on a real in-process cluster: the ell_bfs dispatch
+    record carries the levels, how many pushed and the slots they
+    visited, and a lone pair's levels push."""
+    from nebula_tpu.common.flight import recorder
+    c, rt, sid, et = _follow_cluster("sp")
+    try:
+        before = dict(rt.stats)
+        d = rt.bfs_batch(sid, [[1]], [[4]], [et], 10, shortest=True)
+        m = rt.mirror(sid)
+        assert d[0, int(m.to_dense([4])[0])] == 3
+        record = max((r for r in recorder.dump(limit=1 << 16)
+                      if r.get("kernel") == "ell_bfs"),
+                     key=lambda r: r["time_us"])
+        assert record["queries"] == 1 and record["levels"] == 3
+        # frontiers of one or two vertices: every level pushes, and
+        # visits the live rows' slots, not the table
+        assert record["levels_push"] == 3
+        assert 0 < record["slots"] < 3 * E.table_slots(rt.ell(m))
+        assert rt.stats["path_levels"] - before["path_levels"] == 3
+        assert rt.stats["path_levels_push"] \
+            - before["path_levels_push"] == 3
+    finally:
+        c.stop()

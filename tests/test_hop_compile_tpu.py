@@ -1,7 +1,8 @@
-"""The continuous hop program compiled for the v5e at the benchmark
-cell's real table shapes — no chip needed: the TPU's compiler is
-installed here and compiles for a described, unattached chip
-(PERF.md §6, PR 25).
+"""The continuous hop program, and the batched BFS program whose
+levels take the same step (PR 30), compiled for the v5e at the
+benchmark cell's real table shapes — no chip needed: the TPU's
+compiler is installed here and compiles for a described, unattached
+chip (PERF.md §6, PR 25).
 
 What it guards is what no CPU test can see: the push branch once made
 the compiler lay whole slot tables out row-major for a single row read
@@ -90,3 +91,24 @@ def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip):
     assert scratch <= scratch_pull + 48 * 2**20, (scratch, scratch_pull)
     # measured 5-6 s; the flat running sum alone was 33 s
     assert hop_s < 25.0, hop_s
+
+
+def test_bfs_program_compiles_for_the_v5e_at_cell_size(one_chip):
+    """jit_bfs as graph500-s20-path.closed16 dispatches it (the
+    128-lane rung, UPTO 5 STEPS, shortest): every level is the hop's
+    step, so the conditional sits inside the level loop, beside a
+    171 MB depth matrix that is live across it."""
+    from nebula_tpu.tpu import ell as E
+    bfs, bfs_s = _compile(
+        E.make_batched_bfs_lanes_kernel(_Shapes(), 5, (1,),
+                                        stop_when_found=True, donate=True),
+        one_chip)
+    text = bfs.as_text()
+    assert "conditional" in text and "while" in text
+    # measured 578.5 MB (PR 29's sweep-only program: 516.0); a slot
+    # table laid out anew for a row read inside the loop shows as
+    # +390 MB (a budget of ONE row reads 908.5 MB)
+    scratch = bfs.memory_analysis().temp_size_in_bytes
+    assert scratch <= 640e6, scratch
+    # measured 4-8 s, as the sweep-only program
+    assert bfs_s < 30.0, bfs_s
