@@ -131,7 +131,10 @@ class FlightRecorder:
         unpacked in the tick's cohorts, of them unpack_live out of the
         live rows of the fetched block and not out of the whole table,
         unpack_rows those live rows, summed over the cohorts —
-        tpu/runtime.py _unpack_lanes), leaver_rows, the hops whose branch
+        tpu/runtime.py _unpack_lanes), what the cohorts' WHEREs met at
+        assembly (where_stmts filtered statements, where_candidates the
+        edges their predicates ran over, where_rows the rows kept —
+        tpu/runtime.py _assemble_group), leaver_rows, the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
         the live slot rows; hop_slots the ELL slots they visited),
         idle gap since the previous tick, mirror generation, tick wall

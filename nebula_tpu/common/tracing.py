@@ -94,6 +94,13 @@ SPAN_NAMES = (
                               # from the BFS depths to path rows (tags:
                               # depth, paths, on_path_vertices, capped,
                               # cpu_us: the walk's own thread time)
+    "tpu.where",              # a GO's WHERE over the final frontier's
+                              # candidate edges, one a signature group
+                              # of a batch or leave cohort (tags:
+                              # queries, candidates, kept, site:
+                              # assembly, cpu_us: the pass's own
+                              # thread time — tpu/runtime.py
+                              # _assemble_group)
     "rpc.fault",              # zero-duration marker: injected fault
     "graph.admission",        # zero-duration marker: admission decision
                               # (shed / deadline drop — batch_dispatch)
@@ -539,6 +546,7 @@ _PHASE_OF = {
     "tpu.kernel": PHASE_KERNEL,
     "tpu.fetch": PHASE_FETCH,
     "tpu.assemble": PHASE_ASSEMBLE,
+    "tpu.where": PHASE_ASSEMBLE,
 }
 
 stats.register_histogram("graph.query.phase_us")

@@ -1006,8 +1006,12 @@ class _ContinuousStream:
             for stamps, _n, _met in finishes:
                 for i in range(5):
                     parts[i] += int((stamps[i + 1] - stamps[i]) * 1e6)
-            # unpack: leavers, of them out of the live rows, live rows
-            met = [sum(f[2][i] for f in finishes) for i in range(3)]
+            # what the cohorts' unpacks and WHEREs met, under the
+            # record's own field names (_finish)
+            met = {name: sum(f[2][name] for f in finishes)
+                   for name in ("unpack_leavers", "unpack_live",
+                                "unpack_rows", "where_stmts",
+                                "where_candidates", "where_rows")}
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1018,8 +1022,7 @@ class _ContinuousStream:
                 fetch_wait_us=parts[0], d2h_us=parts[1],
                 unpack_us=parts[2], rows_us=parts[3],
                 handover_us=parts[4], assemble_us=sum(parts),
-                unpack_leavers=met[0], unpack_live=met[1],
-                unpack_rows=met[2],
+                **met,
                 leaver_rows=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
                 hop_slots=hop_slots,
@@ -1091,8 +1094,10 @@ class _ContinuousStream:
             tracing.emit("pump.d2h", tid, root, us(t_wait),
                          us(t_d2h) - us(t_wait))
             tracing.emit("pump.unpack", tid, root, us(t_d2h),
-                         us(t_unpack) - us(t_d2h), leavers=met[0],
-                         live=met[1], rows=met[2])
+                         us(t_unpack) - us(t_d2h),
+                         leavers=met["unpack_leavers"],
+                         live=met["unpack_live"],
+                         rows=met["unpack_rows"])
             tracing.emit("pump.rows", tid, root, us(t_unpack),
                          us(t_rows) - us(t_unpack), rows=n)
             tracing.emit("pump.handover", tid, root, us(t_rows),
@@ -1109,20 +1114,22 @@ class _ContinuousStream:
 
         Returns (the stamps that split this stretch of the pump's
         time: start, fetch_wait end, d2h end, unpack end, rows end;
-        the result rows handed over; what the unpack met: leavers
-        unpacked, of them out of the live rows, live rows found —
-        tpu/runtime.py _unpack_lanes).  The handover ends where the
-        caller stamps next."""
+        the result rows handed over; what the cohort met, under the
+        tick record's field names: unpack_leavers, unpack_live,
+        unpack_rows — tpu/runtime.py _unpack_lanes — and where_stmts,
+        where_candidates, where_rows — _assemble_group).  The handover
+        ends where the caller stamps next."""
         resolver, leavers, m = pending
         rt = self.sched.runtime
         ta = time.perf_counter()
         t_unpack = 0.0
+        where_met = (0, 0, 0)
         try:
             # fetch + assembly spans land on the first leaver's trace
             with tracing.attach_captured(leavers[0].tctx):
                 vs_lists = resolver()
                 t_unpack = time.perf_counter()
-                results = rt.continuous_results(
+                results, where_met = rt.continuous_results(
                     self.space_id, m, [r.payload for r in leavers],
                     [r.reduce for r in leavers], vs_lists,
                     self.et_tuple)
@@ -1135,8 +1142,10 @@ class _ContinuousStream:
         t_unpack = t_unpack or t_rows
         t_wait = getattr(resolver, "t_wait", 0.0) or t_unpack
         t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
-        met = tuple(int(getattr(resolver, name, 0)) for name in
-                    ("unpack_leavers", "unpack_live", "unpack_rows"))
+        met = {name: int(getattr(resolver, name, 0)) for name in
+               ("unpack_leavers", "unpack_live", "unpack_rows")}
+        met.update(zip(("where_stmts", "where_candidates", "where_rows"),
+                       where_met))
         stats.add_value("graph.continuous.leaves", len(leavers))
         n_rows = 0
         with self.cond:

@@ -16,7 +16,8 @@ _MANAGED = {
     ConfigModule.GRAPH: ["session_idle_timeout_secs",
                          "session_reclaim_interval_secs",
                          "storage_backend",
-                         "find_path_max_paths"],
+                         "find_path_max_paths",
+                         "tpu_filter_mode"],
     ConfigModule.META: ["expired_threshold_sec"],
     ConfigModule.STORAGE: ["heartbeat_interval_secs",
                            "load_data_interval_secs",

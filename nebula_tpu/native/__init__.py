@@ -152,6 +152,11 @@ def lib() -> Optional[ctypes.CDLL]:
              [ctypes.c_int64, i32p, i32p, i32p, i32p, i32p])
         _sig(L.ell_free, None, [ctypes.c_int64])
 
+    # run gather (tpu/runtime.py _EdgeRuns). Guarded like ell_build
+    if hasattr(L, "neb_gather_runs"):
+        _sig(L.neb_gather_runs, None,
+             [vp, ctypes.c_int64, vp, vp, ctypes.c_int64, vp])
+
     _LIB = L
     return _LIB
 

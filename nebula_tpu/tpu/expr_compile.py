@@ -85,8 +85,13 @@ class ExprCompiler:
     """
 
     def __init__(self, mirror: CsrMirror, space_id: int, schema_man,
-                 alias_to_etype: Dict[str, int]):
+                 alias_to_etype: Dict[str, int], host_only: bool = False):
         self.mirror = mirror
+        # the compiled value will only ever run over the host's numpy
+        # columns (int64 / float64, the CPU executor's precision), so a
+        # column the device cannot hold exactly (Column.device_ok) is
+        # no reason to decline
+        self.host_only = host_only
         self.sm = schema_man
         self.space_id = space_id
         self.alias_to_etype = alias_to_etype
@@ -110,7 +115,7 @@ class ExprCompiler:
             # edge type exists but column doesn't -> always-missing prop:
             # the CPU path errors per-row; decline so it handles it.
             raise CompileError(f"no column {alias}.{prop}")
-        if not col.device_ok:
+        if not col.device_ok and not self.host_only:
             raise CompileError(f"column {alias}.{prop} not device-representable")
         key = f"e:{et}:{prop}"
         self.used[key] = ("edge", et, prop)
@@ -124,7 +129,7 @@ class ExprCompiler:
         col = self.mirror.vertex_cols.get((tag_id, prop))
         if col is None:
             raise CompileError(f"no column {tag}.{prop}")
-        if not col.device_ok:
+        if not col.device_ok and not self.host_only:
             raise CompileError(f"column {tag}.{prop} not device-representable")
         key = f"v:{which}:{tag_id}:{prop}"
         self.used[key] = ("vertex", tag_id, prop, which)

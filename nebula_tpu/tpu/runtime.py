@@ -82,10 +82,10 @@ class _GoPlan:
     """Prepared per-query state handed from can_run_go to run_go."""
 
     __slots__ = ("mirror", "alias_to_etype", "filter_cval", "filter_used",
-                 "pushed_mode", "compiler", "expr_str", "sc_or")
+                 "pushed_mode", "compiler", "expr_str", "sc_or", "fuse")
 
     def __init__(self, mirror, alias_to_etype, filter_cval, filter_used,
-                 pushed_mode, compiler, expr_str, sc_or=False):
+                 pushed_mode, compiler, expr_str, sc_or=False, fuse=False):
         self.mirror = mirror
         self.alias_to_etype = alias_to_etype
         self.filter_cval = filter_cval
@@ -99,6 +99,11 @@ class _GoPlan:
         # invalid used props must decline to the CPU loop then
         # (pure-conjunction masks match skip-on-error exactly)
         self.sc_or = sc_or
+        # the WHERE was compiled for the device (float32 / int32
+        # columns, TpuQueryRuntime._where_fuses) and may fuse into a
+        # hop program of the statement's own; False: compiled for the
+        # host's float64 columns alone, whatever the device could hold
+        self.fuse = fuse
 
 
 def _filter_has_or(expr) -> bool:
@@ -173,6 +178,99 @@ class _DeviceCounts:
         self.arr = arr
 
 
+class _EdgeRuns:
+    """Candidate edges held as runs of consecutive mirror rows:
+    ``lo[i] : lo[i] + cnt[i]``, back to back in that order.  The edge
+    arrays are in (src, etype, rank, dst) order, so a frontier vertex's
+    edges of one OVER set are one run (_over_ranges), and a WHERE that
+    keeps a hundredth of its candidates never needs a row index for
+    each of them: ``take`` copies the runs of an edge-aligned array
+    (one memcpy a run through the native library; without it, numpy's
+    gather through the index), ``rows`` gives the mirror rows of the
+    few positions that were kept."""
+
+    __slots__ = ("lo", "cnt", "ends", "total", "_index")
+
+    def __init__(self, lo: np.ndarray, cnt: np.ndarray):
+        self.lo = np.ascontiguousarray(lo, np.int64)
+        self.cnt = np.ascontiguousarray(cnt, np.int64)
+        self.ends = np.cumsum(self.cnt)     # candidates up to each run's end
+        self.total = int(self.ends[-1]) if len(self.ends) else 0
+        self._index = None
+
+    def __len__(self) -> int:
+        return self.total
+
+    def index(self) -> np.ndarray:
+        """The mirror row of every candidate (int64[total])."""
+        if self._index is None:
+            # multi-range arange: global position -> within-range
+            # offset + range start, fully vectorized
+            idx = np.repeat(self.lo - (self.ends - self.cnt), self.cnt)
+            idx += np.arange(self.total, dtype=np.int64)
+            self._index = idx
+        return self._index
+
+    def take(self, arr: np.ndarray) -> np.ndarray:
+        """``arr[self.index()]`` for an edge-aligned array."""
+        from ..native import lib
+        L = lib()
+        if L is None or not hasattr(L, "neb_gather_runs"):
+            _say_once("[tpu] native run gather missing: a WHERE's "
+                      "candidates are gathered through an index for "
+                      "each edge (several times slower)")
+            return arr[self.index()]
+        if arr.ndim != 1 or not arr.flags.c_contiguous \
+                or not len(self.lo):
+            return arr[self.index()]
+        if int(self.lo.min()) < 0 or int(self.cnt.min()) < 0 \
+                or int((self.lo + self.cnt).max()) > len(arr):
+            raise IndexError("edge run outside the array")
+        out = np.empty(self.total, arr.dtype)
+        L.neb_gather_runs(arr.ctypes.data, arr.itemsize,
+                          self.lo.ctypes.data, self.cnt.ctypes.data,
+                          len(self.lo), out.ctypes.data)
+        return out
+
+    def pieces(self, limit: int):
+        """The runs in order, cut after the run that brings a piece to
+        ``limit`` candidates (a longer run is a piece of its own)."""
+        cuts = np.searchsorted(self.ends,
+                               np.arange(limit, self.total, limit))
+        bounds = np.unique(np.concatenate(
+            ([0], cuts + 1, [len(self.cnt)])))
+        for a, b in zip(bounds[:-1].tolist(), bounds[1:].tolist()):
+            yield _EdgeRuns(self.lo[a:b], self.cnt[a:b])
+
+    def rows(self, at: np.ndarray) -> np.ndarray:
+        """Mirror rows of the candidates at positions ``at``."""
+        run = np.searchsorted(self.ends, at, side="right")
+        return self.lo[run] + (at - (self.ends[run] - self.cnt[run]))
+
+
+# candidates a WHERE evaluates at a time where it has them as runs
+# (_assemble_group): 2 MB of doubles, so the copied columns, the mask
+# and the compiled predicate's temporaries stay in the cache and out
+# of the allocator's way, whatever a cohort's millions of candidates
+WHERE_PIECE_EDGES = 1 << 18
+
+
+_said: set = set()
+
+
+def _say_once(what: str) -> None:
+    """A degraded mode named once a process on stderr, where the
+    runtime's other start-up lines go."""
+    if what not in _said:
+        _said.add(what)
+        print(what, file=sys.stderr, flush=True)
+
+
+def _take(arr: np.ndarray, idx) -> np.ndarray:
+    """``arr[idx]`` where ``idx`` is a row index array or _EdgeRuns."""
+    return idx.take(arr) if isinstance(idx, _EdgeRuns) else arr[idx]
+
+
 def _pad_pow2(arr: np.ndarray, fill=-1, min_size: int = 8) -> np.ndarray:
     size = max(min_size, 1 << (max(len(arr), 1) - 1).bit_length())
     return kernels.pad_to(arr, size, fill)
@@ -181,13 +279,18 @@ def _pad_pow2(arr: np.ndarray, fill=-1, min_size: int = 8) -> np.ndarray:
 flags.define(
     "tpu_filter_mode", "auto",
     "where a GO's WHERE filter evaluates on the device path: 'auto' "
-    "(default — the mask fuses into the XLA hop program whenever "
-    "expr_compile covers the predicate, so fetch returns only "
-    "surviving rows; anything uncompilable keeps the host float64 "
-    "parity path), 'host' (always float64 numpy over the candidate "
-    "edges, bit-identical to the CPU executor path, and every GO "
-    "shape batches through the dispatcher) or 'device' (fuse always; "
-    "no cross-query batching)")
+    "(default — on one device a filtered GO rides the dispatcher and "
+    "the ELL hop program like any other GO, lanes included, and the "
+    "compiled predicate runs in float64 numpy over the final "
+    "frontier's candidate edges at assembly, as under 'host'; on a "
+    "mesh it fuses as under 'device'), 'host' (always that float64 "
+    "pass, bit-identical to the CPU executor path, and every GO "
+    "shape batches through the dispatcher) or 'device' (the mask "
+    "fuses into a CSR hop program of the statement's own: float32, "
+    "O(edges) a statement, no cross-query batching; a column float32 "
+    "does not hold exactly declines there to the CPU executor, where "
+    "the float64 pass serves it).  Managed: UPDATE CONFIGS "
+    "graph:tpu_filter_mode=...")
 flags.define(
     "tpu_device_timing_every", 16,
     "sample every Nth dense/sparse device dispatch with a "
@@ -511,6 +614,11 @@ class TpuQueryRuntime:
                       "path_levels": 0, "path_levels_push": 0,
                       "path_rows": 0, "path_capped": 0,
                       "path_index_builds": 0,
+                      # filtered GO served through the dispatcher:
+                      # statements, candidate edges their predicates
+                      # were evaluated over, rows kept (_assemble_group)
+                      "go_where": 0, "where_candidates": 0,
+                      "where_rows": 0,
                       "mirror_builds": 0,
                       "mirror_deltas": 0, "mirror_absorbs": 0,
                       "mirror_absorb_failed": 0,
@@ -1270,14 +1378,20 @@ class TpuQueryRuntime:
             return None
         filter_cval = None
         filter_used: Dict[str, Tuple] = {}
-        compiler = ExprCompiler(m, space_id, self.sm, alias_to_etype)
+        # a WHERE that will meet the candidates on the host in float64
+        # (_host_filter) needs no column the device can hold exactly:
+        # only the fused program compares in float32
+        fuse = where_expr is not None and self._where_fuses()
+        compiler = ExprCompiler(m, space_id, self.sm, alias_to_etype,
+                                host_only=not fuse)
         if where_expr is not None:
             try:
                 filter_cval = compiler.compile(where_expr)
             except CompileError:
                 return None
             filter_used = dict(compiler.used)
-            if "rank" in filter_used and not self._rank_device_ok(m):
+            if fuse and "rank" in filter_used \
+                    and not self._rank_device_ok(m):
                 # host-side representability check: forcing the lazy
                 # _device_csr upload here would cost an O(m) transfer
                 # per absorbed generation just to answer a plan gate
@@ -1290,7 +1404,21 @@ class TpuQueryRuntime:
             m, alias_to_etype, filter_cval, filter_used,
             pushed_mode=pushed_mode, compiler=compiler,
             expr_str=(str(where_expr) if where_expr is not None else None),
-            sc_or=_filter_has_or(where_expr))
+            sc_or=_filter_has_or(where_expr), fuse=fuse)
+
+    @staticmethod
+    def _where_fuses() -> bool:
+        """tpu_filter_mode: 'device' fuses a compiled WHERE into a CSR
+        hop program of the statement's own (float32, no batching,
+        O(edges) a statement); 'auto' (the shipped default) does that
+        only on a mesh, where no benchmark cell has judged the other
+        route yet — on one device a filtered GO takes the dispatcher
+        like any other and its predicate runs in float64 over the final
+        frontier's candidate edges, as under 'host'."""
+        fmode = flags.get("tpu_filter_mode")
+        return fmode == "device" or (
+            fmode == "auto"
+            and int(flags.get("tpu_mesh_devices") or 0) > 1)
 
     def can_run_go(self, space_id: int, etypes: List[int], sentence,
                    pushed: Optional[bytes], remnant: Optional[Expression],
@@ -1407,11 +1535,14 @@ class TpuQueryRuntime:
                            yield_cols, distinct: bool, where_expr,
                            ExcType, upto: bool = False, reduce=None):
         """Submit one GO onto the coalescing dispatcher; the batch
-        leader runs the whole device + host pipeline for every rider
-        (go_batch_execute).  The fused device-filter mode bypasses the
-        dispatcher (its kernel bakes the query's filter; UPTO keeps
-        the dispatcher + host-filter path — the fused kernels have no
-        union accumulator)."""
+        leader (or the continuous pump) runs the whole device + host
+        pipeline for every rider (go_batch_execute), a WHERE included:
+        its hops ride beside unfiltered statements of the same key and
+        the predicate meets the final frontier's candidate edges at
+        assembly (_assemble_group).  Only the fused device-filter mode
+        bypasses the dispatcher (its kernel bakes the query's filter;
+        UPTO and reductions keep the dispatcher there too — the fused
+        kernels have no union accumulator)."""
         from ..storage.device import TpuDecline, classify_device_failure
         bkey = (space_id, "go")
         why = self.breaker.admit(bkey)
@@ -1425,16 +1556,12 @@ class TpuQueryRuntime:
             raise TpuDecline(why, degraded=True)
         et_tuple = tuple(sorted(set(etypes)))
         self._bump("go_device")
-        # tpu_filter_mode: 'device' always fuses a compiled WHERE into
-        # the hop program; 'auto' (the shipped default, VERDICT r5 ask
-        # #5) fuses whenever expr_compile covered the predicate — fetch
-        # then returns only surviving rows — and keeps the host float64
-        # parity path for everything _plan_go declined (which routed to
-        # the CPU executor before we ever got here)
-        fmode = flags.get("tpu_filter_mode")
+        # what _plan_go declined went to the CPU executor before we
+        # ever got here; whether a WHERE fuses was decided there too
+        # (_where_fuses), with the precision it was compiled for
         try:
             if plan.filter_cval is not None and not upto \
-                    and reduce is None and fmode in ("device", "auto"):
+                    and reduce is None and plan.fuse:
                 result = self._execute_fused(space_id, plan, start_vids,
                                              et_tuple, steps,
                                              etype_to_alias, yield_cols,
@@ -1534,7 +1661,7 @@ class TpuQueryRuntime:
                             self.stats["go_reduced"] += len(live)
                     with tracing.span("tpu.assemble",
                                       queries=len(live)):
-                        results = self._assemble_results(
+                        results, _met = self._assemble_results(
                             space_id, m, live, vs_lists, et_tuple)
             self._tick("t_assemble_s", t1)
             # whole-dispatch latency (launch -> fetch -> assemble),
@@ -1609,10 +1736,12 @@ class TpuQueryRuntime:
         riders fold the cached degree vector over their extracted
         frontier (route-independent — identical to the windowed
         non-device count fold), everything else (full fetch, LIMIT
-        riders whose pipe slices, UPTO unions) runs the same grouped
-        assembly the windowed leader uses.  results[i] is
-        (columns, rows) or an Exception for per-query failures."""
+        riders whose pipe slices, UPTO unions, a WHERE) runs the same
+        grouped assembly the windowed leader uses.  Returns (results,
+        what the cohort's WHEREs met: _assemble_results'); results[i]
+        is (columns, rows) or an Exception for per-query failures."""
         results: List[object] = [None] * len(queries)
+        where_met = (0, 0, 0)
         other_idx = []
         count_idx = []
         for i, red in enumerate(reduces):
@@ -1630,7 +1759,7 @@ class TpuQueryRuntime:
                 self.stats["go_reduced"] += len(count_idx)
         if other_idx:
             with tracing.span("tpu.assemble", queries=len(other_idx)):
-                sub = self._assemble_results(
+                sub, where_met = self._assemble_results(
                     space_id, m, [queries[i] for i in other_idx],
                     [vs_lists[i] for i in other_idx], et_tuple)
             n_lim = 0
@@ -1641,7 +1770,7 @@ class TpuQueryRuntime:
             if n_lim:
                 with self._lock:
                     self.stats["go_reduced"] += n_lim
-        return results
+        return results, where_met
 
     # ------------------------------------------------ frontier launch
     def _launch_frontiers(self, space_id: int, starts_per_query,
@@ -2411,7 +2540,9 @@ class TpuQueryRuntime:
         (WHERE, YIELD, mode) signature, then per group do ONE candidate
         assembly + filter + materialization over the concatenated
         frontier, splitting rows back per query.  Per-query failures
-        become Exception entries."""
+        become Exception entries.  Returns (results, what the groups
+        that filter met: statements, candidate edges, rows kept — the
+        tick record's where_* fields)."""
         results: List[object] = [None] * len(queries)
         groups: Dict[Tuple, List[int]] = {}
         for i, q in enumerate(queries):
@@ -2420,21 +2551,27 @@ class TpuQueryRuntime:
                    tuple((str(c.expr), c.alias) for c in q.yield_cols),
                    q.distinct)
             groups.setdefault(sig, []).append(i)
+        where_met = np.zeros(3, np.int64)
         for sig, idxs in groups.items():
             try:
-                self._assemble_group(space_id, m, queries, idxs,
-                                     vs_lists, et_tuple, results)
+                where_met += self._assemble_group(
+                    space_id, m, queries, idxs, vs_lists, et_tuple,
+                    results)
             except Exception as ex:     # noqa: BLE001 — group-level
                 for i in idxs:          # failure hits only its riders
                     if results[i] is None:
                         results[i] = ex
-        return results
+        return results, tuple(int(n) for n in where_met)
 
     def _assemble_group(self, space_id: int, m: CsrMirror,
                         queries: List[_GoQuery], idxs: List[int],
                         vs_lists, et_tuple: Tuple[int, ...],
-                        results: List[object]) -> None:
+                        results: List[object]) -> Tuple[int, int, int]:
+        """One signature group's candidates, filter and rows into
+        ``results``; returns what its WHERE met: statements filtered,
+        candidate edges, rows kept (zeros where it has none)."""
         rep = queries[idxs[0]]
+        met = (0, 0, 0)
         plan = rep.plan
         columns = [c.alias or _default_col_name(c.expr)
                    for c in rep.yield_cols]
@@ -2443,22 +2580,28 @@ class TpuQueryRuntime:
         # (dictionary codes, vid ranks)
         if plan.mirror is not m and plan.filter_cval is not None:
             compiler = ExprCompiler(m, space_id, self.sm,
-                                    plan.alias_to_etype)
+                                    plan.alias_to_etype, host_only=True)
             try:
                 cval = compiler.compile(rep.where_expr)
             except CompileError:
                 for i in idxs:
                     results[i] = queries[i].exc_type(
                         "schema changed while the query ran")
-                return
+                return met
             plan = _GoPlan(m, plan.alias_to_etype, cval,
                            dict(compiler.used), plan.pushed_mode,
                            compiler, plan.expr_str, sc_or=plan.sc_or)
 
-        # concatenated final-hop candidates across the group
+        # concatenated final-hop candidates across the group.  A WHERE
+        # keeps few of them, so it takes them as runs of mirror rows
+        # and reads a kept candidate's query off the bounds; a row
+        # index and a query segment for each candidate are made only
+        # where validity has to be told apart query by query
+        filtered = plan.filter_cval is not None
+        by_query = filtered and (not plan.pushed_mode or plan.sc_or)
         vs_concat = [vs_lists[i] for i in idxs]
-        cand, qseg, qbounds = self._frontier_edges_multi(m, vs_concat,
-                                                         et_tuple)
+        cand, qseg, qbounds = self._frontier_edges_multi(
+            m, vs_concat, et_tuple, as_runs=filtered and not by_query)
 
         # WHERE validity: the compiled filter evaluates EVERY operand
         # over vectorized columns, but the CPU executor SHORT-CIRCUITS
@@ -2472,8 +2615,7 @@ class TpuQueryRuntime:
         # baseline) stays vectorized.
         from ..storage.device import TpuDecline
         bad = np.zeros(len(idxs), dtype=bool)
-        if plan.filter_cval is not None \
-                and (not plan.pushed_mode or plan.sc_or):
+        if by_query:
             # pure-conjunction pushed filters keep the mask: skip-on-
             # invalid == AND-with-validity.  Everything else declines
             # the AFFECTED queries only (their batch neighbours keep
@@ -2492,14 +2634,42 @@ class TpuQueryRuntime:
                 # bits, and a group-level raise would decline every
                 # healthy neighbour too
                 keep_rows = ~bad[qseg]
-                cand, qseg = cand[keep_rows], qseg[keep_rows]
+                cand = cand[keep_rows]
+                qbounds = np.searchsorted(qseg[keep_rows],
+                                          np.arange(len(idxs) + 1))
 
-        if plan.filter_cval is not None:
-            mask = self._host_filter(m, plan, cand)
-            cand2, qseg2 = cand[mask], qseg[mask]
+        if filtered:
+            import time
+            with tracing.span("tpu.where", queries=len(idxs),
+                              site="assembly") as sp:
+                # the span's wall may be shared with other threads
+                # under the interpreter lock; cpu_us is this pass's own
+                cpu0 = time.thread_time()
+                if isinstance(cand, _EdgeRuns):
+                    kept, seen = [np.zeros(0, np.int64)], 0
+                    for piece in cand.pieces(WHERE_PIECE_EDGES):
+                        kept.append(seen + np.flatnonzero(
+                            self._host_filter(m, plan, piece)))
+                        seen += len(piece)
+                    kept = np.concatenate(kept)
+                    cand2 = cand.rows(kept)
+                else:
+                    kept = np.flatnonzero(
+                        self._host_filter(m, plan, cand))
+                    cand2 = cand[kept]
+                qb2 = np.searchsorted(kept, qbounds)
+                qseg2 = np.repeat(np.arange(len(idxs), dtype=np.int64),
+                                  np.diff(qb2))
+                met = (len(idxs) - int(bad.sum()), len(cand), len(cand2))
+                if sp is not None:
+                    sp.tag(candidates=met[1], kept=met[2], cpu_us=int(
+                        (time.thread_time() - cpu0) * 1e6))
+            with self._lock:
+                for key, n in zip(("go_where", "where_candidates",
+                                   "where_rows"), met):
+                    self.stats[key] += n
         else:
-            cand2, qseg2 = cand, qseg
-        qb2 = np.searchsorted(qseg2, np.arange(len(idxs) + 1))
+            cand2, qseg2, qb2 = cand, qseg, qbounds
 
         rows_per_query = self._materialize_group(
             m, space_id, plan.alias_to_etype, rep.etype_to_alias,
@@ -2524,6 +2694,7 @@ class TpuQueryRuntime:
                         out.append(r)
                 rows = out
             results[i] = (columns, rows)
+        return met
 
     def _invalid_candidates(self, m: CsrMirror, used: Dict[str, Tuple],
                             cand: np.ndarray) -> Optional[np.ndarray]:
@@ -2598,8 +2769,14 @@ class TpuQueryRuntime:
                 raise TpuDecline(
                     "WHERE reads a prop invalid on candidate rows; "
                     "CPU short-circuit semantics decide")
-        rows = self._materialize(m, space_id, plan.alias_to_etype,
-                                 etype_to_alias, yield_cols, idx, ExcType)
+        # columnar like the dispatcher's rows (one group of one query),
+        # so a client takes both routes' answers in one form
+        rows = self._materialize_group(
+            m, space_id, plan.alias_to_etype, etype_to_alias, yield_cols,
+            idx, np.zeros(len(idx), np.int64),
+            np.asarray([0, len(idx)], np.int64), 1, [ExcType])[0]
+        if isinstance(rows, Exception):
+            raise rows
         if distinct:
             seen = set()
             out = []
@@ -2616,35 +2793,38 @@ class TpuQueryRuntime:
                      used: Dict[str, Tuple],
                      idx: np.ndarray) -> Dict[str, np.ndarray]:
         """numpy columns for compiled-expression eval over edge rows
-        ``idx`` — the one descriptor->array mapping shared by the host
-        WHERE filter and YIELD materialization."""
+        ``idx`` (a row index array, or _EdgeRuns) — the one
+        descriptor->array mapping shared by the host WHERE filter and
+        YIELD materialization."""
         cols: Dict[str, np.ndarray] = {}
         for k, desc in used.items():
             if desc[0] == "edge":
-                cols[k] = m.edge_cols[(desc[1], desc[2])].values[idx]
+                cols[k] = _take(
+                    m.edge_cols[(desc[1], desc[2])].values, idx)
             elif desc[0] == "vertex":
                 col = m.vertex_cols[(desc[1], desc[2])]
-                gather = m.edge_src[idx] if desc[3] == "src" \
-                    else m.edge_dst[idx]
+                gather = _take(m.edge_src if desc[3] == "src"
+                               else m.edge_dst, idx)
                 cols[k] = col.values[gather]
             elif desc[0] == "rank":
-                cols["rank"] = m.edge_rank[idx]
+                cols["rank"] = _take(m.edge_rank, idx)
             elif desc[0] == "src_idx":
-                cols["src_idx"] = m.edge_src[idx]
+                cols["src_idx"] = _take(m.edge_src, idx)
             elif desc[0] == "dst_idx":
-                cols["dst_idx"] = m.edge_dst[idx]
+                cols["dst_idx"] = _take(m.edge_dst, idx)
             elif desc[0] == "etype_alias":
-                cols["etype_alias"] = \
-                    self._etype_alias_codes(m, alias_to_etype)[idx]
+                cols["etype_alias"] = _take(
+                    self._etype_alias_codes(m, alias_to_etype), idx)
         return cols
 
     # -------------------------------------------------- host filter
     def _host_filter(self, m: CsrMirror, plan: _GoPlan,
-                     idx: np.ndarray) -> np.ndarray:
-        """Evaluate the compiled WHERE over candidate edges ``idx`` in
-        numpy float64 — the same cval the device path would run, with
-        the same pushed-mode validity/div-guard semantics, but with the
-        CPU executor's exact precision."""
+                     idx) -> np.ndarray:
+        """Evaluate the compiled WHERE over candidate edges ``idx`` (a
+        row index array, or _EdgeRuns) in numpy float64 — the same
+        cval the device path would run, with the same pushed-mode
+        validity/div-guard semantics, but with the CPU executor's exact
+        precision."""
         if len(idx) == 0:
             return np.zeros(0, dtype=bool)
         # pushed-mode validity is snapshotted BEFORE the value gather:
@@ -2657,11 +2837,11 @@ class TpuQueryRuntime:
         if plan.pushed_mode:
             for k, desc in plan.filter_used.items():
                 if desc[0] == "edge":
-                    valid_snap[k] = \
-                        m.edge_cols[(desc[1], desc[2])].valid[idx]
+                    valid_snap[k] = _take(
+                        m.edge_cols[(desc[1], desc[2])].valid, idx)
                 elif desc[0] == "vertex":
-                    gather = m.edge_src[idx] if desc[3] == "src" \
-                        else m.edge_dst[idx]
+                    gather = _take(m.edge_src if desc[3] == "src"
+                                   else m.edge_dst, idx)
                     valid_snap[k] = \
                         m.vertex_cols[(desc[1], desc[2])].valid[gather]
             if plan.sc_or and valid_snap \
@@ -2680,7 +2860,7 @@ class TpuQueryRuntime:
                                         plan.filter_used, idx))
         with np.errstate(divide="ignore", invalid="ignore"):
             mask = np.broadcast_to(np.asarray(plan.filter_cval.fn(env)),
-                                   idx.shape)
+                                   (len(idx),))
             if mask.dtype != np.bool_:
                 # numeric WHERE: CPU-path truthiness (nonzero = keep) —
                 # and callers fancy-index with this mask, so it MUST be
@@ -2691,7 +2871,8 @@ class TpuQueryRuntime:
             for g in plan.compiler.div_guards:
                 # a real x/0 drops the row in pushed mode (can_run_go
                 # declines div guards in graphd/remnant mode)
-                mask &= ~np.broadcast_to(np.asarray(g(env)), idx.shape)
+                mask &= ~np.broadcast_to(np.asarray(g(env)),
+                                        (len(idx),))
         if plan.pushed_mode:
             for k in valid_snap:
                 mask &= valid_snap[k]
@@ -2851,43 +3032,86 @@ class TpuQueryRuntime:
         idx, _, _ = self._frontier_edges_multi(m, [vs], et_tuple)
         return idx
 
+    def _over_ranges(self, m: CsrMirror, et_tuple: Tuple[int, ...]):
+        """(lo, cnt) int64[n]: vertex v's edges of the OVER set lie at
+        mirror rows ``lo[v] : lo[v] + cnt[v]`` — the edge arrays are in
+        (src, etype, rank, dst) order, so one edge type (or types
+        adjacent in that order) is one run a vertex.  None where some
+        vertex's are not one run (another type sorts between them):
+        the caller then walks whole rows and masks.  O(m) once per
+        (mirror, OVER), cached beside _etype_edge_mask."""
+        cache = getattr(m, "_over_range_cache", None)
+        if cache is None:
+            cache = m._over_range_cache = {}
+        if et_tuple not in cache:
+            if len(cache) >= 8:
+                cache.clear()
+            mask = self._etype_edge_mask(m, et_tuple)
+            cnt = self._deg_host(m, et_tuple)
+            # a run starts where an edge of the set follows an edge
+            # outside it, or another vertex's
+            head = mask.copy()
+            head[1:] &= ~(mask[:-1] & (m.edge_src[1:] == m.edge_src[:-1]))
+            first = np.flatnonzero(head)
+            if len(first) == int(np.count_nonzero(cnt)):
+                lo = np.zeros(m.n, np.int64)
+                lo[m.edge_src[first]] = first
+                cache[et_tuple] = (lo, cnt)
+            else:
+                cache[et_tuple] = None
+        return cache[et_tuple]
+
     def _frontier_edges_multi(self, m: CsrMirror, vs_lists,
-                              et_tuple: Tuple[int, ...]):
+                              et_tuple: Tuple[int, ...],
+                              as_runs: bool = False):
         """Batched candidate assembly: per-query frontier vertex lists
         -> (edge idx concat, per-edge query segment, per-query bounds).
         One vectorized pass for the whole batch — the round-3 answer to
-        per-query Python loops dominating the serving profile."""
+        per-query Python loops dominating the serving profile.  A
+        caller that keeps few of the candidates (a WHERE) passes
+        ``as_runs``: where the OVER set's edges are one run a vertex it
+        gets them as _EdgeRuns and no segment array (a kept
+        candidate's query is read off the bounds), else arrays as
+        ever."""
         nq = len(vs_lists)
         vq_counts = np.fromiter((len(v) for v in vs_lists), np.int64,
                                 count=nq)
+        none = (np.zeros(0, np.int64), np.zeros(0, np.int64),
+                np.zeros(nq + 1, np.int64))
         if vq_counts.sum() == 0:
-            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
-                    np.zeros(nq + 1, np.int64))
+            return none
         vs = np.concatenate([np.asarray(v, np.int64) for v in vs_lists])
-        vq = np.repeat(np.arange(nq, dtype=np.int64), vq_counts)
-        starts = m.row_ptr[vs].astype(np.int64)
-        counts = (m.row_ptr[vs + 1].astype(np.int64) - starts)
+        ranges = self._over_ranges(m, et_tuple)
+        if ranges is not None:
+            starts, counts = ranges[0][vs], ranges[1][vs]
+        else:
+            starts = m.row_ptr[vs].astype(np.int64)
+            counts = (m.row_ptr[vs + 1].astype(np.int64) - starts)
         total = int(counts.sum())
         if total == 0:
-            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
-                    np.zeros(nq + 1, np.int64))
-        if nq == 1 and total * 5 >= m.m:
-            # saturated single frontier: one flat bool gather over all m
-            # edges beats per-row index assembly (measured break-even
-            # ~20% density)
+            return none
+        if ranges is None and nq == 1 and total * 5 >= m.m:
+            # saturated single frontier over whole rows: one flat bool
+            # gather over all m edges beats per-row index assembly and
+            # its mask (measured break-even ~20% density); the OVER
+            # set's own runs are cheaper than the flat pass at any size
             frontier = np.zeros(m.n, dtype=bool)
             frontier[vs] = True
             idx = np.nonzero(frontier[m.edge_src]
                              & self._etype_edge_mask(m, et_tuple))[0]
-            qseg = np.zeros(len(idx), np.int64)
-            return idx, qseg, np.searchsorted(qseg, np.arange(nq + 1))
+            return (idx, np.zeros(len(idx), np.int64),
+                    np.asarray([0, len(idx)], np.int64))
+        # a query's candidates end where its last vertex's do
+        ends = np.concatenate(([0], np.cumsum(counts)))
+        per_q = ends[np.concatenate(([0], np.cumsum(vq_counts)))]
         nz = counts > 0
-        s2, c2, q2 = starts[nz], counts[nz], vq[nz]
-        # multi-range arange: global position -> within-range offset +
-        # range start, fully vectorized
-        excl = np.concatenate(([0], np.cumsum(c2)[:-1]))
-        idx = np.repeat(s2 - excl, c2) + np.arange(total, dtype=np.int64)
-        qseg = np.repeat(q2, c2)
+        runs = _EdgeRuns(starts[nz], counts[nz])
+        if ranges is not None and as_runs:
+            return runs, None, per_q
+        idx = runs.index()
+        qseg = np.repeat(np.arange(nq, dtype=np.int64), np.diff(per_q))
+        if ranges is not None:
+            return idx, qseg, per_q
         keep = self._etype_edge_mask(m, et_tuple)[idx]
         idx, qseg = idx[keep], qseg[keep]
         # no dead-row exclusion pass: deletes fold into the published

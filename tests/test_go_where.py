@@ -1,0 +1,325 @@
+"""A filtered GO through the system's normal entry (LocalCluster,
+tpu_backend=True, the shipped flags) against the benchmark's plain
+reference (benchmark/semantics/go_where.py): under the shipped
+tpu_filter_mode=auto on one device it rides the dispatcher and the
+lanes like any other GO, its predicate meets the final frontier's
+candidate edges at assembly in float64, and the counters and the
+tpu.where span say what was filtered.  CPU jax: no number here is a
+device number."""
+from __future__ import annotations
+
+import threading
+
+import numpy as np
+import pytest
+
+from benchmark import reference
+from benchmark.deploy import flags_set, shipped_defaults
+from benchmark.workload import columns_of
+import nebula_tpu.graph.backend_router    # noqa: F401 — define the flags
+import nebula_tpu.tpu.runtime             # noqa: F401   the conf files set
+from nebula_tpu.cluster import LocalCluster
+from nebula_tpu.common import flight, tracing
+from nebula_tpu.common.flags import flags
+
+LEVELS = 16
+N = 90
+ISOLATED = N + 5        # a vertex the graph never mentions
+OPS = [(">", 0.5), (">=", 0.5), ("<", 0.5),     # 0.5 is a stored level
+       (">", 0.9), (">", 1.5), (">=", 0.0)]     # few / none / all kept
+
+
+def _semantics(steps, op, value):
+    return {"kind": "go_where", "steps": steps, "prop": "w", "op": op,
+            "value": value, "yield": ["_dst"]}
+
+
+def _statement(steps, op, value, start):
+    return (f"GO {steps} STEPS FROM {start} OVER knows "
+            f"WHERE knows.w {op} {value} YIELD knows._dst")
+
+
+@pytest.fixture(scope="module")
+def served():
+    """(cluster, client, reference graph); vertex N has in-edges only."""
+    rng = np.random.default_rng(31)
+    key = np.unique(rng.integers(0, N, 900) * N + rng.integers(0, N, 900))
+    src, dst = key // N + 1, key % N + 1
+    keep = (src != dst) & (src != N)     # vertex N: in-edges only
+    src, dst = src[keep], dst[keep]
+    idx = rng.integers(0, LEVELS, len(src))
+    graph = reference.Graph(src, dst,
+                            [{"w": k / LEVELS} for k in range(LEVELS)], idx)
+    with flags_set({**shipped_defaults(), "go_backend_router": False}):
+        assert flags.get("tpu_filter_mode") == "auto"
+        c = LocalCluster(num_storage=1, tpu_backend=True)
+        g = c.client()
+
+        def ok(stmt):
+            r = g.execute(stmt)
+            assert r.ok(), f"{stmt}: {r.error_msg}"
+            return r
+        ok("CREATE SPACE w(partition_num=4, replica_factor=1)")
+        c.refresh_all()
+        ok("USE w")
+        ok("CREATE EDGE knows(w double)")
+        c.refresh_all()
+        ok("INSERT EDGE knows(w) VALUES " + ", ".join(
+            f"{s}->{d}:({k / LEVELS})" for s, d, k in zip(src, dst, idx)))
+        try:
+            yield c, g, graph
+        finally:
+            c.stop()
+
+
+def _served_rows(client, stmt):
+    resp = client.execute(stmt)
+    assert resp.ok(), f"{stmt}: {resp.error_msg}"
+    assert not resp.warnings and resp.completeness == 100, stmt
+    return columns_of(resp)
+
+
+@pytest.mark.parametrize("op,value", OPS)
+@pytest.mark.parametrize("steps", [1, 2, 3])
+def test_filtered_go_matches_the_plain_reference(served, steps, op, value):
+    c, g, graph = served
+    rt = c.tpu_runtime
+    before = dict(rt.stats)
+    rows = candidates = 0
+    starts = list(range(1, 13))
+    for start in starts:
+        want = graph.answer(_semantics(steps, op, value), start)
+        got = _served_rows(g, _statement(steps, op, value, start))
+        assert reference.same_rows(got, want), (steps, op, value, start)
+        rows += reference.n_rows(want)
+        candidates += int(graph.deg[graph.frontier(start, steps - 1)].sum())
+    if (op, value) == (">", 1.5):
+        assert rows == 0
+    elif (op, value) == (">=", 0.0):
+        assert rows == candidates > 0
+    else:
+        assert 0 < rows < candidates
+    # the counters grow by what the reference says they should
+    grew = {k: rt.stats[k] - before[k] for k in
+            ("go_device", "go_where", "where_candidates", "where_rows")}
+    assert grew == {"go_device": len(starts), "go_where": len(starts),
+                    "where_candidates": candidates, "where_rows": rows}
+
+
+@pytest.mark.parametrize("piece", [1, 5, 64])
+def test_a_cohort_s_candidates_are_filtered_piece_by_piece(
+        served, monkeypatch, piece):
+    """The predicate meets the candidates a piece of runs at a time;
+    the answer does not depend on where the pieces are cut."""
+    c, g, graph = served
+    monkeypatch.setattr(nebula_tpu.tpu.runtime, "WHERE_PIECE_EDGES", piece)
+    for start in range(1, 9):
+        want = graph.answer(_semantics(3, ">", 0.5), start)
+        assert reference.n_rows(want) > piece
+        got = _served_rows(g, _statement(3, ">", 0.5, start))
+        assert reference.same_rows(got, want), (piece, start)
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
+@pytest.mark.parametrize("start", [N, ISOLATED])
+def test_a_start_with_no_out_edge_answers_nothing(served, steps, start):
+    c, g, graph = served
+    assert start >= len(graph.deg) or graph.deg[start] == 0
+    got = _served_rows(g, _statement(steps, ">", 0.5, start))
+    assert reference.n_rows(got) == 0
+
+
+def _spans(tree):
+    stack = list(tree["roots"])
+    while stack:
+        node = stack.pop()
+        yield node
+        stack.extend(node.get("children", ()))
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
+def test_a_traced_filtered_go_shows_the_dispatcher_and_tpu_where(
+        served, steps):
+    c, g, graph = served
+    start = 3
+    want = graph.answer(_semantics(steps, ">", 0.5), start)
+    resp = g.execute("PROFILE " + _statement(steps, ">", 0.5, start))
+    assert resp.ok(), resp.error_msg
+    assert reference.same_rows(columns_of(resp), want)
+    spans = list(_spans(resp.raw["profile"]))
+    names = {s["name"] for s in spans}
+    # the dispatcher's spans: a lane ride for 2 and 3 steps (the
+    # continuous tier), the windowed batch for 1
+    assert ("graph.continuous" if steps > 1 else "graph.batched") in names
+    assert {"tpu.launch", "tpu.fetch", "tpu.assemble"} <= names
+    assert not [s for s in spans if s["name"] == "tpu.kernel"
+                and s["tags"].get("kind") == "go_fused"]
+    where = [s for s in spans if s["name"] == "tpu.where"]
+    assert len(where) == 1
+    tags = where[0]["tags"]
+    assert tags["site"] == "assembly" and tags["queries"] == 1
+    assert tags["kept"] == reference.n_rows(want)
+    assert tags["candidates"] == int(
+        graph.deg[graph.frontier(start, steps - 1)].sum())
+    assert tags["cpu_us"] >= 0
+    assert tracing.critical_path(resp.raw["profile"])["assemble"] \
+        >= where[0]["duration_us"]
+
+
+def test_a_filtered_go_rides_the_lanes_beside_unfiltered_ones(served):
+    """Same dispatcher key (space, OVER, steps, no reduction): a
+    filtered 3-step GO and unfiltered ones leave in one cohort, and
+    each gets its own rows."""
+    c, g, graph = served
+    rt = c.tpu_runtime
+    jobs = []
+    for i, start in enumerate(range(1, 13)):
+        if i % 3 == 0:
+            jobs.append((_statement(3, ">", 0.5, start),
+                         _semantics(3, ">", 0.5), start))
+        else:
+            jobs.append((f"GO 3 STEPS FROM {start} OVER knows "
+                         f"YIELD knows._dst",
+                         {"kind": "go", "steps": 3, "yield": ["_dst"]},
+                         start))
+    _served_rows(g, jobs[1][0])                 # the stream exists
+    streams = rt.dispatcher.continuous.streams()
+    assert streams
+    since = flight.recorder.note_tick(stream=-1)    # a mark in the ring
+    results, errors = {}, []
+    barrier = threading.Barrier(len(jobs))
+
+    def worker(i):
+        try:
+            client = c.client()
+            assert client.execute("USE w").ok()
+            barrier.wait()
+            results[i] = _served_rows(client, jobs[i][0])
+        except Exception as ex:     # noqa: BLE001 — reported below
+            errors.append(ex)
+
+    for st in streams:
+        st.tick_delay_s = 0.05      # arrivals land in one tick
+    try:
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(len(jobs))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        for st in streams:
+            st.tick_delay_s = 0.0
+    assert not errors, errors
+    for i, (_stmt, sem, start) in enumerate(jobs):
+        assert reference.same_rows(results[i], graph.answer(sem, start)), i
+    ticks = [r for r in flight.recorder.dump(limit=1 << 20)
+             if r.get("kind") == "tick" and r.get("id", 0) > since
+             and r.get("stream", -1) >= 0]
+    assert sum(t["where_stmts"] for t in ticks) == 4
+    mixed = [t for t in ticks if 0 < t["where_stmts"] < t["leaves"]]
+    assert mixed, [(t["leaves"], t["where_stmts"]) for t in ticks]
+    t = mixed[0]
+    assert t["where_candidates"] >= t["where_rows"] > 0
+
+
+@pytest.mark.parametrize("mode,fused", [("auto", False), ("host", False),
+                                        ("device", True)])
+def test_which_filter_modes_still_fuse_on_one_device(served, mode, fused):
+    """'device' keeps the first-generation program of the statement's
+    own; 'auto' and 'host' go through the dispatcher."""
+    c, g, graph = served
+    rt = c.tpu_runtime
+    want = graph.answer(_semantics(2, ">", 0.5), 4)
+    before = rt.stats["go_where"]
+    flags.set("tpu_filter_mode", mode)
+    try:
+        resp = g.execute("PROFILE " + _statement(2, ">", 0.5, 4))
+    finally:
+        flags.set("tpu_filter_mode", "auto")
+    assert resp.ok(), resp.error_msg
+    # both routes hand a client int64 columns, so a harness compares
+    # them by content
+    assert reference.same_rows(columns_of(resp), want)
+    assert reference.digest(columns_of(resp)) == reference.digest(want)
+    kinds = [s["tags"].get("kind") for s in _spans(resp.raw["profile"])
+             if s["name"] == "tpu.kernel"]
+    assert ("go_fused" in kinds) == fused
+    assert rt.stats["go_where"] - before == (0 if fused else 1)
+
+
+# ---- weights float32 does not hold (the cell's split levels) --------
+SPLIT_LEVELS = 64
+
+
+@pytest.fixture(scope="module")
+def served_split():
+    """(cluster, client, reference graph) over the benchmark's split
+    weight table at 64 levels: beside 0.9 two stored doubles that are
+    one float32, so the column is not device-representable
+    (Column.device_ok) and a float32 evaluation answers one wrong."""
+    from benchmark.generators.kronecker_split import split_levels
+    rng = np.random.default_rng(313)
+    key = np.unique(rng.integers(0, N, 1500) * N + rng.integers(0, N, 1500))
+    src, dst = key // N + 1, key % N + 1
+    keep = src != dst
+    src, dst = src[keep], dst[keep]
+    levels = [k / SPLIT_LEVELS for k in range(SPLIT_LEVELS)]
+    moved = split_levels(SPLIT_LEVELS, [0.9])
+    for k, v in moved.items():
+        levels[k] = v
+    idx = rng.integers(0, SPLIT_LEVELS, len(src))
+    assert all(int((idx == k).sum()) > 5 for k in moved)
+    graph = reference.Graph(src, dst, [{"w": w} for w in levels], idx)
+    with flags_set({**shipped_defaults(), "go_backend_router": False}):
+        c = LocalCluster(num_storage=1, tpu_backend=True)
+        g = c.client()
+        for stmt in ("CREATE SPACE ws(partition_num=4, replica_factor=1)",
+                     "USE ws", "CREATE EDGE knows(w double)"):
+            r = g.execute(stmt)
+            assert r.ok(), f"{stmt}: {r.error_msg}"
+            c.refresh_all()
+        r = g.execute("INSERT EDGE knows(w) VALUES " + ", ".join(
+            f"{s}->{d}:({levels[k]!r})" for s, d, k in zip(src, dst, idx)))
+        assert r.ok(), r.error_msg
+        try:
+            yield c, g, graph
+        finally:
+            c.stop()
+
+
+@pytest.mark.parametrize("steps", [1, 2, 3])
+@pytest.mark.parametrize("mode,device_served", [("auto", True),
+                                                ("host", True),
+                                                ("device", False)])
+def test_doubles_float32_does_not_hold_are_filtered_in_float64(
+        served_split, steps, mode, device_served):
+    """The dispatcher's route filters on the host in float64, so it
+    serves a column the device could not hold exactly; the fused
+    float32 program still declines it, to the CPU executor.  Either
+    way the rows are the float64 reference's, and not what a float32
+    evaluation of the reference keeps."""
+    c, g, graph = served_split
+    rt = c.tpu_runtime
+    mirror = rt.mirror(c.graph_meta_client.get_space_id_by_name("ws")
+                       .value())
+    assert not any(col.device_ok for col in mirror.edge_cols.values())
+    before = dict(rt.stats)
+    told_apart = 0
+    flags.set("tpu_filter_mode", mode)
+    try:
+        for start in range(1, 13):
+            sem = _semantics(steps, ">", 0.9)
+            want = graph.answer(sem, start)
+            in32 = graph.answer({**sem, "precision": "float32"}, start)
+            got = _served_rows(g, _statement(steps, ">", 0.9, start))
+            if not device_served:   # the CPU executor hands row tuples
+                got = (np.asarray([r[0] for r in got], np.int64),)
+            assert reference.same_rows(got, want), (steps, mode, start)
+            told_apart += reference.digest(in32) != reference.digest(want)
+    finally:
+        flags.set("tpu_filter_mode", "auto")
+    assert told_apart >= (3 if steps == 1 else 10)
+    served = 12 if device_served else 0
+    assert rt.stats["go_device"] - before["go_device"] == served
+    assert rt.stats["go_where"] - before["go_where"] == served

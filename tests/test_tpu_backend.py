@@ -515,6 +515,83 @@ class TestFrontierEdges:
         assert np.array_equal(got, flat)
 
 
+    @pytest.mark.parametrize("as_runs", [False, True])
+    @pytest.mark.parametrize("et_tuple,one_run", [
+        ((1,), True), ((-1, 1), True), ((-1, 2), False), ((2,), True)])
+    def test_over_runs_match_the_masked_walk(self, et_tuple, one_run,
+                                             as_runs):
+        """A mirror in (src, etype) order, as the real one is: an OVER
+        set whose edges are one run a vertex is walked run by run
+        (_over_ranges; handed over as _EdgeRuns where asked), any other
+        row by row under the mask, and both give the flat gather's
+        edges, per query."""
+        from nebula_tpu.tpu.runtime import TpuQueryRuntime, _EdgeRuns
+        n, m = 512, 6000
+        mir = self._mirror(n, m, seed=3)
+        rng = np.random.default_rng(4)
+        mir.edge_etype = rng.choice([-1, 1, 2], m).astype(np.int32)
+        order = np.lexsort((mir.edge_etype, mir.edge_src))
+        mir.edge_etype = mir.edge_etype[order]
+        rt = TpuQueryRuntime.__new__(TpuQueryRuntime)
+        assert (rt._over_ranges(mir, et_tuple) is not None) == one_run
+        vs_lists = [np.sort(rng.choice(n, k, replace=False))
+                    for k in (0, 7, 1, 40, 0)]
+        cand, qseg, qb = rt._frontier_edges_multi(mir, vs_lists, et_tuple,
+                                                  as_runs=as_runs)
+        assert isinstance(cand, _EdgeRuns) == (as_runs and one_run)
+        assert len(qb) == len(vs_lists) + 1 and qb[-1] == len(cand)
+        if isinstance(cand, _EdgeRuns):
+            assert qseg is None
+            idx = cand.index()
+            # the runs' copy of an edge-aligned array is the gather
+            # through their index, for every width the mirror holds
+            for arr in (mir.edge_dst, mir.edge_etype.astype(np.int64),
+                        mir.edge_etype > 0,
+                        mir.edge_dst.astype(np.float64) / 7):
+                assert np.array_equal(cand.take(arr), arr[idx])
+            at = np.flatnonzero(mir.edge_dst[idx] % 3 == 0)
+            assert np.array_equal(cand.rows(at), idx[at])
+            # cut into pieces, the runs are the same runs in order
+            for limit in (1, 7, 50, 10 ** 6):
+                pieces = list(cand.pieces(limit))
+                assert np.array_equal(np.concatenate(
+                    [p.index() for p in pieces]), idx)
+                assert all(len(p) - int(p.cnt[-1]) < limit
+                           for p in pieces)
+        else:
+            idx = cand
+        in_set = np.isin(mir.edge_etype, np.asarray(et_tuple, np.int32))
+        for q, vs in enumerate(vs_lists):
+            frontier = np.zeros(n, dtype=bool)
+            frontier[vs] = True
+            flat = np.nonzero(frontier[mir.edge_src] & in_set)[0]
+            assert np.array_equal(idx[qb[q]:qb[q + 1]], flat)
+            if qseg is not None:
+                assert (qseg[qb[q]:qb[q + 1]] == q).all()
+
+    def test_edge_runs_without_the_native_library(self, monkeypatch):
+        """``take`` falls back to numpy's gather through the index."""
+        import nebula_tpu.native as native
+        from nebula_tpu.tpu.runtime import _EdgeRuns
+        monkeypatch.setattr(native, "lib", lambda: None)
+        runs = _EdgeRuns(np.asarray([5, 0, 9]), np.asarray([2, 3, 1]))
+        arr = np.arange(10, dtype=np.float64) * 1.5
+        assert np.array_equal(runs.take(arr), arr[[5, 6, 0, 1, 2, 9]])
+        assert len(runs) == 6
+
+    def test_edge_runs_refuse_a_run_outside_the_array(self):
+        from nebula_tpu.native import lib
+        from nebula_tpu.tpu.runtime import _EdgeRuns
+        runs = _EdgeRuns(np.asarray([2, 8]), np.asarray([3, 4]))
+        arr = np.arange(10, dtype=np.int64)
+        if lib() is not None and hasattr(lib(), "neb_gather_runs"):
+            with pytest.raises(IndexError):
+                runs.take(arr)
+        assert np.array_equal(runs.take(np.arange(12)), [2, 3, 4, 8, 9, 10, 11])
+        assert np.array_equal(runs.rows(np.asarray([0, 2, 3, 6])),
+                              [2, 4, 8, 11])
+
+
 class TestIncrementalDelta:
     """SURVEY §7 hard part (a): committed edge inserts ride a small
     overlay (delta kernel + overlay mirror) instead of forcing the
