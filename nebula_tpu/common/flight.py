@@ -127,14 +127,16 @@ class FlightRecorder:
         stream keyed ``stream``: seat churn counts, per-phase micros
         in pump order (seat, then the join/hop/extract/clear ENQUEUES,
         then the leave cohort's fetch_wait/d2h/unpack/rows/handover,
-        whose sum is assemble_us), what unpack_us met (unpack_leavers
-        unpacked in the tick's cohorts, of them unpack_live out of the
-        live rows of the fetched block and not out of the whole table,
-        unpack_rows those live rows, summed over the cohorts —
-        tpu/runtime.py _unpack_lanes), what the cohorts' WHEREs met at
-        assembly (where_stmts filtered statements, where_candidates the
-        edges their predicates ran over, where_rows the rows kept —
-        tpu/runtime.py _assemble_group), leaver_rows, the hops whose branch
+        whose sum is assemble_us; rows is what the pump answers
+        itself: the COUNT riders' fold, a WHERE that filters in
+        numpy), what unpack_us met (unpack_leavers unpacked in the tick's cohorts,
+        of them unpack_live out of the live rows of the fetched block
+        and not out of the whole table, unpack_rows those live rows,
+        summed over the cohorts — tpu/runtime.py _unpack_lanes), handed
+        (the leavers whose frontier went to their own thread, which
+        filters and makes the rows: every leaver but a COUNT rider and
+        a WHERE the native pass cannot take — graph/batch_dispatch.py
+        _finish), the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
         the live slot rows; hop_slots the ELL slots they visited),
         idle gap since the previous tick, mirror generation, tick wall

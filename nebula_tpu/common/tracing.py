@@ -95,10 +95,14 @@ SPAN_NAMES = (
                               # depth, paths, on_path_vertices, capped,
                               # cpu_us: the walk's own thread time)
     "tpu.where",              # a GO's WHERE over the final frontier's
-                              # candidate edges, one a signature group
-                              # of a batch or leave cohort (tags:
+                              # candidate edges: one a signature group
+                              # of a windowed batch or of what a
+                              # continuous pump kept, one a continuous
+                              # leaver on its own trace (tags:
                               # queries, candidates, kept, site:
-                              # assembly, cpu_us: the pass's own
+                              # assembly, native: the statements the
+                              # one native pass filtered, 0 where
+                              # numpy did, cpu_us: the pass's own
                               # thread time — tpu/runtime.py
                               # _assemble_group)
     "rpc.fault",              # zero-duration marker: injected fault
@@ -137,15 +141,21 @@ SPAN_NAMES = (
     "pump.d2h",               # the copy after that wait
     "pump.unpack",            # the cohort's live rows found once,
                               # per leaver its bit + inv + sort
-    "pump.rows",              # COUNT folds + grouped row assembly
-    "pump.handover",          # results published, waiters notified
+    "pump.rows",              # what the pump answers itself: the
+                              # cohort's COUNT fold, a WHERE that
+                              # filters in numpy (tag handed: the
+                              # leavers whose frontier went to their
+                              # own thread, which makes the rows)
+    "pump.handover",          # frontiers and counts published,
+                              # waiters notified
     "pump.idle",              # root: no tick in flight (tag: why)
 )
 
 # the waits a continuous rider's time in submit() is made of, in
 # order (batch_dispatch _ContinuousStream._waits): tags of its
 # graph.continuous marker, keys of its seat markers and slow-log entry
-RIDER_WAITS = ("seat_wait_us", "ride_us", "result_wait_us", "wake_us")
+RIDER_WAITS = ("seat_wait_us", "ride_us", "result_wait_us", "wake_us",
+               "assemble_us")
 
 _tls = threading.local()          # .ctx = (trace_id, span_id, True)
 _rng = random.Random()            # ids; independent of seeded test RNGs
@@ -432,10 +442,10 @@ class SlowQueryLog:
         """``seat`` carries the continuous-dispatch markers of a slow
         statement that rode a lane batch — lane, joined_tick,
         left_tick, hops, the typed ``ending`` (common/protocol.py
-        continuous-ending vocabulary), the four waits its submit() was
-        made of (seat_wait_us / ride_us / result_wait_us / wake_us:
-        which wait was slow) and the ``timeline`` anchor (first/last flight-
-        recorder tick ids for the rider's stream, common/flight.py) —
+        continuous-ending vocabulary), the five waits its submit() was
+        made of (RIDER_WAITS: which wait was slow) and the
+        ``timeline`` anchor (first/last flight-recorder tick ids for
+        the rider's stream, common/flight.py) —
         so the slow log attributes a slow rider to its seat trajectory
         and its `/timeline` window, not just its wall time (windowed
         statements pass None and keep the PR 3 entry shape)."""

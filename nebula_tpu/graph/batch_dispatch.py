@@ -446,16 +446,18 @@ class ContinuousUnavailable(Exception):
 
 class _Rider:
     """One query riding the continuous batch: queued until a lane
-    frees, seated for steps-1 hop ticks, extracted + assembled at its
-    last hop (or evicted at its deadline).  Fields are written by the
-    stream pump under the stream condition; the submitting thread
-    reads result/error after ``done`` flips."""
+    frees, seated for steps-1 hop ticks, extracted at its last hop (or
+    evicted at its deadline).  Fields are written by the stream pump
+    under the stream condition; the submitting thread reads them after
+    ``done`` flips: its ``frontier``, which its own thread assembles
+    (submit()), or the ``result`` of a leaver the pump answered itself
+    (a COUNT rider, a WHERE that filters in numpy: _finish)."""
 
     __slots__ = ("payload", "steps", "upto", "reduce", "deadline",
                  "tctx", "enq_t", "seated_t", "left_t", "done_t",
                  "lane", "remaining", "joined_tick", "left_tick",
-                 "midflight", "done", "result", "mirror", "error",
-                 "qid")
+                 "midflight", "done", "result", "frontier", "mirror",
+                 "error", "qid")
 
     def __init__(self, payload, steps: int, upto: bool, reduce,
                  deadline):
@@ -471,8 +473,8 @@ class _Rider:
         self.tctx = tracing.capture()
         self.enq_t = time.perf_counter()
         # perf_counter stamps the PUMP writes as the rider moves on:
-        # seated, left the seat map, result (or error) published.
-        # submit() turns them into the marker's four waits
+        # seated, left the seat map, frontier (or count, or error)
+        # handed over.  submit() turns them into the marker's waits
         self.seated_t = self.left_t = self.done_t = 0.0
         self.lane = -1
         self.remaining = 0
@@ -481,6 +483,7 @@ class _Rider:
         self.midflight = False
         self.done = False
         self.result = None
+        self.frontier = None
         self.mirror = None
         self.error = None
         # live-query-registry id — the pump reports this rider's seat /
@@ -495,11 +498,15 @@ class _ContinuousStream:
 
         seat joiners -> scatter-merge their start frontiers ->
         dispatch hop k -> mark leavers/evictions -> enqueue their
-        lane extraction + clear -> assemble hop k-1's leavers while
-        hop k computes -> wake their waiters
+        lane extraction + clear -> fetch + unpack hop k-1's leavers
+        while hop k computes -> hand each its frontier and wake it
 
     so the device always has the next hop enqueued while the host
-    does per-query work (the double-buffer overlap).  Mirror
+    does per-query work (the double-buffer overlap), and a leaver's
+    filter and rows are its own thread's work (submit()), not the
+    pump's, wherever that pass leaves the interpreter and the
+    allocator alone (_finish): the next tick does not wait for the
+    slowest answer.  Mirror
     generation changes drain the stream: seated riders finish on the
     generation they captured (the published-generation contract,
     docs/durability.md), new arrivals wait for the re-anchor —
@@ -962,11 +969,11 @@ class _ContinuousStream:
                     r.done = True
                 self.cond.notify_all()
 
-        # hop k's work is on the device; assemble hop k-1's leavers
-        # NOW — host post-processing overlaps device compute.  Each
-        # _finish returns its stamps, the rows it handed over and what
-        # its unpack met; the tick record's assemble_us is the sum of
-        # the stamps' parts
+        # hop k's work is on the device; fetch and hand over hop k-1's
+        # leavers NOW — host post-processing overlaps device compute.
+        # Each _finish returns its stamps, the leavers it handed their
+        # frontier and what its unpack met; the tick record's
+        # assemble_us is the sum of the stamps' parts
         finishes = []
         pending_leavers = pending[1] if pending is not None else []
         if pending is not None:
@@ -986,9 +993,10 @@ class _ContinuousStream:
         # start, or the tick's end): the parts then tile the tick's
         # tail, and a pump that lost the interpreter between two stamps
         # is charged to a part instead of to nothing
-        handed = [f[0][0] for f in finishes[1:]] + [t_end]
+        hand_ends = [f[0][0] for f in finishes[1:]] + [t_end]
         finishes = [(stamps + (t_hand,), n, met)
-                    for (stamps, n, met), t_hand in zip(finishes, handed)]
+                    for (stamps, n, met), t_hand
+                    in zip(finishes, hand_ends)]
         with self.cond:
             self.hop_ema_s = dur if self.hop_ema_s == 0.0 \
                 else 0.7 * self.hop_ema_s + 0.3 * dur
@@ -1006,12 +1014,11 @@ class _ContinuousStream:
             for stamps, _n, _met in finishes:
                 for i in range(5):
                     parts[i] += int((stamps[i + 1] - stamps[i]) * 1e6)
-            # what the cohorts' unpacks and WHEREs met, under the
-            # record's own field names (_finish)
+            # what the cohorts' unpacks met, under the record's own
+            # field names (_finish)
             met = {name: sum(f[2][name] for f in finishes)
                    for name in ("unpack_leavers", "unpack_live",
-                                "unpack_rows", "where_stmts",
-                                "where_candidates", "where_rows")}
+                                "unpack_rows")}
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1023,7 +1030,7 @@ class _ContinuousStream:
                 unpack_us=parts[2], rows_us=parts[3],
                 handover_us=parts[4], assemble_us=sum(parts),
                 **met,
-                leaver_rows=sum(f[1] for f in finishes),
+                handed=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
                 hop_slots=hop_slots,
                 idle_us=int(idle_us),
@@ -1065,9 +1072,9 @@ class _ContinuousStream:
         tick_delay — a generation change / the test hook held it; loop
         — it was between two ticks: recording, the condition, the
         interpreter lock).  ``finishes`` holds, per finished cohort,
-        _finish's stamps plus the one its handover ran to, its rows
-        and what its unpack met.  Only called for a tick that touched
-        a traced rider."""
+        _finish's stamps plus the one its handover ran to, the leavers
+        handed their frontier and what its unpack met.  Only called
+        for a tick that touched a traced rider."""
         t0, t_seat, t_end = tick_stamps
         # ONE wall-minus-perf offset for the whole tick: the spans land
         # on the now_micros() clock every other span uses
@@ -1099,7 +1106,7 @@ class _ContinuousStream:
                          live=met["unpack_live"],
                          rows=met["unpack_rows"])
             tracing.emit("pump.rows", tid, root, us(t_unpack),
-                         us(t_rows) - us(t_unpack), rows=n)
+                         us(t_rows) - us(t_unpack), handed=n)
             tracing.emit("pump.handover", tid, root, us(t_rows),
                          us(t_hand) - us(t_rows))
         if idle_us >= PUMP_IDLE_SPAN_MIN_US:
@@ -1107,34 +1114,56 @@ class _ContinuousStream:
                          int(idle_us), stream=self.space_id, why=why)
 
     def _finish(self, pending) -> Tuple:
-        """Force the leave cohort's extraction fetch, run the same
-        grouped assembly the windowed leader uses, wake the waiters.
-        Per-query failures stay per-query (Exception entries); a
-        cohort-level failure wakes every cohort member with it.
+        """Force the leave cohort's extraction fetch and hand every
+        leaver what its own thread goes on from (submit()): its
+        frontier with the generation it was extracted under — its
+        filter and rows are then its thread's work, not the pump's —
+        or, for the leavers the pump answers itself, its result: a
+        COUNT rider's number (one vectorised degree fold over the
+        cohort's COUNT riders) and the rows of a WHERE that filters in
+        numpy (rt.rider_assembles: one thread running those passes in
+        turn is faster than the riders' threads running them at once).
+        A cohort-level failure (the resolver's, the fold's) wakes
+        every cohort member with it.
 
         Returns (the stamps that split this stretch of the pump's
-        time: start, fetch_wait end, d2h end, unpack end, rows end;
-        the result rows handed over; what the cohort met, under the
-        tick record's field names: unpack_leavers, unpack_live,
-        unpack_rows — tpu/runtime.py _unpack_lanes — and where_stmts,
-        where_candidates, where_rows — _assemble_group).  The handover
-        ends where the caller stamps next."""
+        time: start, fetch_wait end, d2h end, unpack end, rows end —
+        "rows" being what the pump answered itself; the leavers handed
+        their frontier; what the unpack met, under the tick record's
+        field names: unpack_leavers, unpack_live, unpack_rows —
+        tpu/runtime.py _unpack_lanes).  The handover ends where the
+        caller stamps next."""
         resolver, leavers, m = pending
         rt = self.sched.runtime
         ta = time.perf_counter()
         t_unpack = 0.0
-        where_met = (0, 0, 0)
+        # the leavers the pump answers itself: COUNT riders (one fold
+        # over the cohort's) and a WHERE that filters in numpy, which
+        # sixteen threads at once run slower than one in turn
+        # (rt.rider_assembles).  Every other leaver takes its frontier
+        own_idx = [i for i, r in enumerate(leavers)
+                   if (r.reduce is not None and r.reduce[0] == "count")
+                   or not rt.rider_assembles(m, r.payload,
+                                             self.et_tuple)]
+        # per leaver: the pump's result, the cohort's failure, or None
+        # — the leaver takes its frontier
+        outs: List[object] = [None] * len(leavers)
+        vs_lists = None
         try:
-            # fetch + assembly spans land on the first leaver's trace
+            # fetch spans land on the first leaver's trace
             with tracing.attach_captured(leavers[0].tctx):
                 vs_lists = resolver()
                 t_unpack = time.perf_counter()
-                results, where_met = rt.continuous_results(
-                    self.space_id, m, [r.payload for r in leavers],
-                    [r.reduce for r in leavers], vs_lists,
-                    self.et_tuple)
+                if own_idx:
+                    own = rt.continuous_results(
+                        self.space_id, m,
+                        [leavers[i].payload for i in own_idx],
+                        [leavers[i].reduce for i in own_idx],
+                        [vs_lists[i] for i in own_idx], self.et_tuple)
+                    for i, out in zip(own_idx, own):
+                        outs[i] = out
         except Exception as ex:         # noqa: BLE001 — cohort-level
-            results = [ex] * len(leavers)
+            outs = [ex] * len(leavers)
         t_rows = time.perf_counter()
         # a resolver that failed (or a test's stand-in) has no stamps:
         # its whole stretch reads as the part it died in, and it
@@ -1144,23 +1173,24 @@ class _ContinuousStream:
         t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
         met = {name: int(getattr(resolver, name, 0)) for name in
                ("unpack_leavers", "unpack_live", "unpack_rows")}
-        met.update(zip(("where_stmts", "where_candidates", "where_rows"),
-                       where_met))
         stats.add_value("graph.continuous.leaves", len(leavers))
-        n_rows = 0
+        handed = 0
         with self.cond:
             t_done = time.perf_counter()
-            for r, out in zip(leavers, results):
+            for i, (r, out) in enumerate(zip(leavers, outs)):
                 if isinstance(out, Exception):
                     r.error = out
                 else:
-                    r.result = out
                     r.mirror = m
-                    n_rows += len(out[1])
+                    if out is not None:
+                        r.result = out
+                    else:
+                        r.frontier = vs_lists[i]
+                        handed += 1
                 r.done_t = t_done
                 r.done = True
             self.cond.notify_all()
-        return (ta, t_wait, t_d2h, t_unpack, t_rows), n_rows, met
+        return (ta, t_wait, t_d2h, t_unpack, t_rows), handed, met
 
     # ------------------------------------------------------- submit
     def submit(self, key: Tuple, payload, steps: int, upto: bool,
@@ -1239,13 +1269,16 @@ class _ContinuousStream:
                             "go: deadline expired mid-flight")
                         disp._note_deadline_drop(key)
                         break
+        t_wake = time.perf_counter()
+        if rider.error is None and rider.frontier is not None:
+            self._assemble_own(key, rider)
         # the seat trajectory lands on the WAITER's own trace: a
         # PROFILE of the query shows its lane, join and leave tick,
-        # whether it merged into an already-running batch, the four
-        # waits its time here was made of, and HOW its wait ended —
-        # one of protocol's closed "continuous-ending" kinds, the
-        # vocabulary the eviction dashboards key on
-        waits = self._waits(rider, time.perf_counter())
+        # whether it merged into an already-running batch, the waits
+        # its time here was made of, and HOW its wait ended — one of
+        # protocol's closed "continuous-ending" kinds, the vocabulary
+        # the eviction dashboards key on
+        waits = self._waits(rider, t_wake, time.perf_counter())
         query_registry.note_waits(rider.qid, rider.left_tick, waits)
         if rider.error is not None:
             if isinstance(rider.error, ContinuousUnavailable):
@@ -1276,19 +1309,53 @@ class _ContinuousStream:
                                                 0) + 1
         return rider.result, rider.mirror
 
+    def _assemble_own(self, key: Tuple, rider: _Rider) -> None:
+        """The post-frontier half of a leaver the pump handed its
+        frontier, on the rider's own thread and outside the stream
+        condition: the same continuous_results the pump answers its
+        own leavers through, over this one statement, against the
+        generation the pump extracted under — candidate runs, the
+        WHERE in float64, the rows.  Its tpu.assemble / tpu.where
+        spans land on the rider's own trace.  A per-query failure (an
+        Exception entry) becomes this rider's error; a rider killed or
+        out of budget since the handover skips the pass."""
+        if query_registry.is_killed(rider.qid):
+            rider.error = KilledError(
+                "go: ended by KILL QUERY before its rows were "
+                "assembled")
+            return
+        if rider.deadline is not None and rider.deadline.expired():
+            rider.error = DeadlineExceeded(
+                "go: deadline expired mid-flight")
+            self.sched.dispatcher._note_deadline_drop(key)
+            return
+        try:
+            out = self.sched.runtime.continuous_results(
+                self.space_id, rider.mirror, [rider.payload],
+                [rider.reduce], [rider.frontier], self.et_tuple)[0]
+        except Exception as ex:         # noqa: BLE001 — this rider's
+            out = ex
+        if isinstance(out, Exception):
+            rider.error = out
+        else:
+            rider.result = out
+
     @staticmethod
-    def _waits(rider: _Rider, t_wake: float) -> Dict[str, int]:
-        """The rider's time in submit(), split where the pump stamped
-        it: queued until seated, riding until it left the seat map,
-        waiting for its cohort's fetch + assembly, and from the result
-        published to this thread running again.  A rider that left
-        normally has all four and they sum to enq_t -> t_wake; one
-        that ended early has the waits it got as far as."""
+    def _waits(rider: _Rider, t_wake: float,
+               t_end: float) -> Dict[str, int]:
+        """The rider's time in submit(), split where the pump and then
+        its own thread stamped it: queued until seated, riding until
+        it left the seat map, waiting for its cohort's fetch and
+        handover, from the handover to this thread running again, and
+        assembling its own rows (the fifth of a leaver the pump
+        answered is what it took to get here).  A rider that left normally has all five and
+        they sum to enq_t -> t_end; one that ended early has the waits
+        it got as far as."""
         out: Dict[str, int] = {}
         t = rider.enq_t
         for key, stamp in zip(tracing.RIDER_WAITS,
                               (rider.seated_t, rider.left_t,
-                               rider.done_t, t_wake)):
+                               rider.done_t, t_wake, t_end)):
             if not stamp:
                 break
             out[key] = int((stamp - t) * 1e6)

@@ -225,10 +225,9 @@ class QueryRegistry:
     def seat_markers(self, qid: Optional[int]) -> Optional[dict]:
         """The continuous-tier seat trajectory of a still-registered
         statement — lane, joined_tick, left_tick, hop count, typed
-        ending, the four waits (seat_wait_us / ride_us /
-        result_wait_us / wake_us) and the [first, last] recorder
-        tick-id window — or None when it
-        never rode a lane batch.  The engine folds this into
+        ending, the five waits (tracing.RIDER_WAITS) and the [first,
+        last] recorder tick-id window — or None when it never rode a
+        lane batch.  The engine folds this into
         slow-query-log entries before unregistering."""
         e = self._entries.get(qid) if qid is not None else None
         if e is None or (e.lane < 0 and e.ending is None):

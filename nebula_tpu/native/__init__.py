@@ -156,6 +156,12 @@ def lib() -> Optional[ctypes.CDLL]:
     if hasattr(L, "neb_gather_runs"):
         _sig(L.neb_gather_runs, None,
              [vp, ctypes.c_int64, vp, vp, ctypes.c_int64, vp])
+    # one double column against a constant over candidate runs
+    # (tpu/runtime.py _EdgeRuns.keep_f64). Guarded like ell_build
+    if hasattr(L, "neb_filter_runs_f64"):
+        _sig(L.neb_filter_runs_f64, ctypes.c_int64,
+             [vp, vp, vp, vp, ctypes.c_int64, ctypes.c_int32,
+              ctypes.c_double, vp])
 
     _LIB = L
     return _LIB

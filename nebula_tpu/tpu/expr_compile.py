@@ -52,13 +52,18 @@ class CVal:
     dynamic checks, mismatches surface before the query runs).
     """
 
-    __slots__ = ("kind", "fn", "dictionary", "const")
+    __slots__ = ("kind", "fn", "dictionary", "const", "col", "cmp")
 
-    def __init__(self, kind, fn, dictionary=None, const=None):
+    def __init__(self, kind, fn, dictionary=None, const=None, col=None):
         self.kind = kind
         self.fn = fn
         self.dictionary = dictionary  # sorted strings, for K_STRCODE
         self.const = const            # python literal when constant
+        self.col = col                # env key when a bare edge column
+        # (column key, op, constant) when the whole value is one float
+        # edge column compared with a numeric literal, written with
+        # the column on the left; ``fn`` computes the same
+        self.cmp = None
 
 
 class Env:
@@ -163,7 +168,7 @@ class ExprCompiler:
             key, col = self._edge_col(expr.alias, expr.prop)
             return CVal(self._kind_of(col),
                         lambda env, _k=key: env.cols[_k],
-                        dictionary=col.dictionary)
+                        dictionary=col.dictionary, col=key)
 
         if isinstance(expr, SourcePropExpr):
             key, col = self._vertex_col("src", expr.tag, expr.prop)
@@ -340,7 +345,13 @@ class ExprCompiler:
                 return CVal(K_BOOL, lambda env: True, const=True)
             raise CompileError("type mismatch in comparison")
         if num_a and num_b:
-            return CVal(K_BOOL, _cmp_fn(a, b, op))
+            out = CVal(K_BOOL, _cmp_fn(a, b, op))
+            for x, y, flip in ((a, b, False), (b, a, True)):
+                if x.col is not None and x.kind == K_FLOAT \
+                        and y.const is not None:
+                    out.cmp = (x.col, _FLIPPED.get(op, op) if flip else op,
+                               float(y.const))
+            return out
         raise CompileError(f"compare {a.kind} {op} {b.kind}")
 
     def _rank_cmp(self, x: CVal, lit: int, op: str, flip: bool) -> CVal:
@@ -441,6 +452,9 @@ def _to_bool(v: CVal) -> CVal:
     if v.kind in _NUMERIC:
         return CVal(K_BOOL, lambda env: v.fn(env) != 0)
     raise CompileError("cannot use value as a boolean")
+
+
+_FLIPPED = {"<": ">", ">": "<", "<=": ">=", ">=": "<="}
 
 
 def _cmp_fn(a: CVal, b: CVal, op: str):

@@ -232,6 +232,28 @@ class _EdgeRuns:
                           len(self.lo), out.ctypes.data)
         return out
 
+    _OPS = {"<": 0, "<=": 1, ">": 2, ">=": 3, "==": 4, "!=": 5}
+
+    def keep_f64(self, values: np.ndarray, valid: np.ndarray, op: str,
+                 c: float) -> np.ndarray:
+        """Positions (ascending, over the runs back to back) of the
+        candidates whose ``valid`` is set and whose float64 ``values``
+        satisfy ``value op c``: one native pass that reads a candidate
+        once and writes only what is kept.  The caller has checked
+        that it can run (TpuQueryRuntime._native_filter)."""
+        from ..native import lib
+        if not len(self.lo):
+            return np.zeros(0, np.int64)
+        if int(self.lo.min()) < 0 or int(self.cnt.min()) < 0 \
+                or int((self.lo + self.cnt).max()) > len(values):
+            raise IndexError("edge run outside the array")
+        out = np.empty(self.total, np.int64)
+        n = lib().neb_filter_runs_f64(
+            values.ctypes.data, valid.ctypes.data, self.lo.ctypes.data,
+            self.cnt.ctypes.data, len(self.lo), self._OPS[op], float(c),
+            out.ctypes.data)
+        return out[:n]
+
     def pieces(self, limit: int):
         """The runs in order, cut after the run that brings a piece to
         ``limit`` candidates (a longer run is a piece of its own)."""
@@ -248,10 +270,42 @@ class _EdgeRuns:
         return self.lo[run] + (at - (self.ends[run] - self.cnt[run]))
 
 
+# one lock for the per-mirror host tables below: reentrant, because
+# one table's fill asks for another (_over_ranges)
+_MIRROR_TABLE_LOCK = threading.RLock()
+_NO_TABLE = object()
+
+
+def _mirror_table(m: CsrMirror, attr: str, key, fill):
+    """The O(m) host table ``fill()`` builds, once per (mirror, key),
+    kept in the dict ``m.<attr>`` (at most 8 keys: each entry is O(m)).
+    Riders assemble on their own threads, so after a generation change
+    a whole leave cohort asks for the same table in the same instant:
+    the first fills it under the lock and the others wait for that one
+    pass instead of each running its own."""
+    cache = getattr(m, attr, None)
+    got = _NO_TABLE if cache is None else cache.get(key, _NO_TABLE)
+    if got is not _NO_TABLE:
+        return got
+    with _MIRROR_TABLE_LOCK:
+        cache = getattr(m, attr, None)
+        if cache is None:
+            cache = {}
+            setattr(m, attr, cache)
+        got = cache.get(key, _NO_TABLE)
+        if got is _NO_TABLE:
+            if len(cache) >= 8:
+                cache.clear()
+            got = cache[key] = fill()
+    return got
+
+
 # candidates a WHERE evaluates at a time where it has them as runs
-# (_assemble_group): 2 MB of doubles, so the copied columns, the mask
+# (_filter_runs): 2 MB of doubles, so the copied columns, the mask
 # and the compiled predicate's temporaries stay in the cache and out
 # of the allocator's way, whatever a cohort's millions of candidates
+# (the native pass of a column against a constant copies nothing, and
+# takes the same pieces: its kept positions then fit 2 MB too)
 WHERE_PIECE_EDGES = 1 << 18
 
 
@@ -616,9 +670,11 @@ class TpuQueryRuntime:
                       "path_index_builds": 0,
                       # filtered GO served through the dispatcher:
                       # statements, candidate edges their predicates
-                      # were evaluated over, rows kept (_assemble_group)
+                      # were evaluated over, rows kept, and how many
+                      # of the statements the native pass filtered
+                      # (_assemble_group, _native_filter)
                       "go_where": 0, "where_candidates": 0,
-                      "where_rows": 0,
+                      "where_rows": 0, "where_native": 0,
                       "mirror_builds": 0,
                       "mirror_deltas": 0, "mirror_absorbs": 0,
                       "mirror_absorb_failed": 0,
@@ -1661,7 +1717,7 @@ class TpuQueryRuntime:
                             self.stats["go_reduced"] += len(live)
                     with tracing.span("tpu.assemble",
                                       queries=len(live)):
-                        results, _met = self._assemble_results(
+                        results = self._assemble_results(
                             space_id, m, live, vs_lists, et_tuple)
             self._tick("t_assemble_s", t1)
             # whole-dispatch latency (launch -> fetch -> assemble),
@@ -1732,16 +1788,18 @@ class TpuQueryRuntime:
     def continuous_results(self, space_id: int, m: CsrMirror,
                            queries: List[_GoQuery], reduces,
                            vs_lists, et_tuple: Tuple[int, ...]):
-        """Post-frontier half for a continuous leave cohort: COUNT
-        riders fold the cached degree vector over their extracted
-        frontier (route-independent — identical to the windowed
-        non-device count fold), everything else (full fetch, LIMIT
-        riders whose pipe slices, UPTO unions, a WHERE) runs the same
-        grouped assembly the windowed leader uses.  Returns (results,
-        what the cohort's WHEREs met: _assemble_results'); results[i]
-        is (columns, rows) or an Exception for per-query failures."""
+        """Post-frontier half for continuous leavers: COUNT riders
+        fold the cached degree vector over their extracted frontier
+        (route-independent — identical to the windowed non-device
+        count fold), everything else (full fetch, LIMIT riders whose
+        pipe slices, UPTO unions, a WHERE) runs the same grouped
+        assembly the windowed leader uses.  The pump calls it over the
+        leavers of a cohort it answers itself (COUNT riders, a WHERE
+        that filters in numpy: rider_assembles), every other leaver
+        over its own statement on its own thread
+        (graph/batch_dispatch.py _finish, _assemble_own).  results[i] is (columns, rows) or an Exception
+        for per-query failures."""
         results: List[object] = [None] * len(queries)
-        where_met = (0, 0, 0)
         other_idx = []
         count_idx = []
         for i, red in enumerate(reduces):
@@ -1759,7 +1817,7 @@ class TpuQueryRuntime:
                 self.stats["go_reduced"] += len(count_idx)
         if other_idx:
             with tracing.span("tpu.assemble", queries=len(other_idx)):
-                sub, where_met = self._assemble_results(
+                sub = self._assemble_results(
                     space_id, m, [queries[i] for i in other_idx],
                     [vs_lists[i] for i in other_idx], et_tuple)
             n_lim = 0
@@ -1770,7 +1828,7 @@ class TpuQueryRuntime:
             if n_lim:
                 with self._lock:
                     self.stats["go_reduced"] += n_lim
-        return results, where_met
+        return results
 
     # ------------------------------------------------ frontier launch
     def _launch_frontiers(self, space_id: int, starts_per_query,
@@ -2432,18 +2490,11 @@ class TpuQueryRuntime:
         """int64[n]: per-vertex final-hop candidate-edge count over the
         OVER set — the COUNT(*)/LIMIT pushdown's degree vector, cached
         per (mirror, OVER) beside _etype_edge_mask."""
-        cache = getattr(m, "_deg_cache", None)
-        if cache is None:
-            cache = m._deg_cache = {}
-        deg = cache.get(et_tuple)
-        if deg is None:
-            if len(cache) >= 8:
-                cache.clear()
+        def fill():
             mask = self._etype_edge_mask(m, et_tuple)
-            deg = np.bincount(m.edge_src[mask], minlength=m.n) \
+            return np.bincount(m.edge_src[mask], minlength=m.n) \
                 .astype(np.int64)
-            cache[et_tuple] = deg
-        return deg
+        return _mirror_table(m, "_deg_cache", et_tuple, fill)
 
     def _deg_dev(self, m: CsrMirror, ix: EllIndex,
                  et_tuple: Tuple[int, ...]):
@@ -2540,9 +2591,7 @@ class TpuQueryRuntime:
         (WHERE, YIELD, mode) signature, then per group do ONE candidate
         assembly + filter + materialization over the concatenated
         frontier, splitting rows back per query.  Per-query failures
-        become Exception entries.  Returns (results, what the groups
-        that filter met: statements, candidate edges, rows kept — the
-        tick record's where_* fields)."""
+        become Exception entries."""
         results: List[object] = [None] * len(queries)
         groups: Dict[Tuple, List[int]] = {}
         for i, q in enumerate(queries):
@@ -2551,27 +2600,27 @@ class TpuQueryRuntime:
                    tuple((str(c.expr), c.alias) for c in q.yield_cols),
                    q.distinct)
             groups.setdefault(sig, []).append(i)
-        where_met = np.zeros(3, np.int64)
         for sig, idxs in groups.items():
             try:
-                where_met += self._assemble_group(
+                self._assemble_group(
                     space_id, m, queries, idxs, vs_lists, et_tuple,
                     results)
             except Exception as ex:     # noqa: BLE001 — group-level
                 for i in idxs:          # failure hits only its riders
                     if results[i] is None:
                         results[i] = ex
-        return results, tuple(int(n) for n in where_met)
+        return results
 
     def _assemble_group(self, space_id: int, m: CsrMirror,
                         queries: List[_GoQuery], idxs: List[int],
                         vs_lists, et_tuple: Tuple[int, ...],
-                        results: List[object]) -> Tuple[int, int, int]:
+                        results: List[object]) -> None:
         """One signature group's candidates, filter and rows into
-        ``results``; returns what its WHERE met: statements filtered,
-        candidate edges, rows kept (zeros where it has none)."""
+        ``results``; what its WHERE met (statements filtered,
+        candidate edges, rows kept) goes to the tpu.where span and the
+        go_where / where_candidates / where_rows / where_native
+        counters."""
         rep = queries[idxs[0]]
-        met = (0, 0, 0)
         plan = rep.plan
         columns = [c.alias or _default_col_name(c.expr)
                    for c in rep.yield_cols]
@@ -2587,7 +2636,7 @@ class TpuQueryRuntime:
                 for i in idxs:
                     results[i] = queries[i].exc_type(
                         "schema changed while the query ran")
-                return met
+                return
             plan = _GoPlan(m, plan.alias_to_etype, cval,
                            dict(compiler.used), plan.pushed_mode,
                            compiler, plan.expr_str, sc_or=plan.sc_or)
@@ -2640,18 +2689,19 @@ class TpuQueryRuntime:
 
         if filtered:
             import time
+            # which pass filters: the span and where_native say, so a
+            # library without the native pass shows in a trace (where
+            # it can run, candidates that are not runs are none at all)
+            native = self._native_filter(m, plan, et_tuple)
             with tracing.span("tpu.where", queries=len(idxs),
-                              site="assembly") as sp:
+                              site="assembly",
+                              native=0 if native is None else len(idxs)
+                              ) as sp:
                 # the span's wall may be shared with other threads
                 # under the interpreter lock; cpu_us is this pass's own
                 cpu0 = time.thread_time()
                 if isinstance(cand, _EdgeRuns):
-                    kept, seen = [np.zeros(0, np.int64)], 0
-                    for piece in cand.pieces(WHERE_PIECE_EDGES):
-                        kept.append(seen + np.flatnonzero(
-                            self._host_filter(m, plan, piece)))
-                        seen += len(piece)
-                    kept = np.concatenate(kept)
+                    kept = self._filter_runs(m, plan, cand, native)
                     cand2 = cand.rows(kept)
                 else:
                     kept = np.flatnonzero(
@@ -2660,13 +2710,14 @@ class TpuQueryRuntime:
                 qb2 = np.searchsorted(kept, qbounds)
                 qseg2 = np.repeat(np.arange(len(idxs), dtype=np.int64),
                                   np.diff(qb2))
-                met = (len(idxs) - int(bad.sum()), len(cand), len(cand2))
+                met = (len(idxs) - int(bad.sum()), len(cand), len(cand2),
+                       0 if native is None else len(idxs))
                 if sp is not None:
                     sp.tag(candidates=met[1], kept=met[2], cpu_us=int(
                         (time.thread_time() - cpu0) * 1e6))
             with self._lock:
                 for key, n in zip(("go_where", "where_candidates",
-                                   "where_rows"), met):
+                                   "where_rows", "where_native"), met):
                     self.stats[key] += n
         else:
             cand2, qseg2, qb2 = cand, qseg, qbounds
@@ -2694,7 +2745,6 @@ class TpuQueryRuntime:
                         out.append(r)
                 rows = out
             results[i] = (columns, rows)
-        return met
 
     def _invalid_candidates(self, m: CsrMirror, used: Dict[str, Tuple],
                             cand: np.ndarray) -> Optional[np.ndarray]:
@@ -2818,6 +2868,70 @@ class TpuQueryRuntime:
         return cols
 
     # -------------------------------------------------- host filter
+    def _native_filter(self, m: CsrMirror, plan: _GoPlan,
+                       et_tuple: Tuple[int, ...]):
+        """(column, op, constant) where the plan's WHERE is ONE native
+        pass over its candidate runs (_EdgeRuns.keep_f64), else None:
+        the compiled value is one float64 edge column against a
+        constant (``cmp``), a pushed pure conjunction with no division
+        guard, the OVER set's edges are one run a vertex, and the
+        library has the pass.  Every other WHERE gathers its columns
+        and evaluates in numpy, which holds the allocator and, between
+        its calls, the interpreter: rider_assembles() keeps that one
+        on the pump."""
+        from ..native import lib
+        cmp_ = plan.filter_cval.cmp if plan.filter_cval is not None \
+            else None
+        if cmp_ is None or not plan.pushed_mode or plan.sc_or \
+                or plan.compiler.div_guards \
+                or self._over_ranges(m, et_tuple) is None:
+            return None
+        if not hasattr(lib(), "neb_filter_runs_f64"):
+            _say_once("[tpu] native run filter missing: a WHERE of a "
+                      "column against a constant filters in numpy, on "
+                      "the pump's thread in the continuous tier")
+            return None
+        key, op, c = cmp_
+        col = m.edge_cols.get(plan.filter_used[key][1:])
+        if col is None or col.values.dtype != np.float64 \
+                or col.valid.dtype != np.bool_ or col.values.ndim != 1 \
+                or col.valid.shape != col.values.shape \
+                or not col.values.flags.c_contiguous \
+                or not col.valid.flags.c_contiguous:
+            return None
+        return col, op, c
+
+    def rider_assembles(self, m: CsrMirror, q: _GoQuery,
+                        et_tuple: Tuple[int, ...]) -> bool:
+        """Whether a continuous leaver's own thread runs its
+        post-frontier half (graph/batch_dispatch.py _finish): yes
+        unless its WHERE filters in numpy.  Sixteen such passes beside
+        each other and the pump cost the claimed cell a fifth on the
+        one-chip machine (PERF.md section 6, PR 32); one thread
+        running them in turn, the pump, is what the parent does."""
+        return q.plan.filter_cval is None \
+            or self._native_filter(m, q.plan, et_tuple) is not None
+
+    def _filter_runs(self, m: CsrMirror, plan: _GoPlan, cand: _EdgeRuns,
+                     native) -> np.ndarray:
+        """Positions of the candidates (runs: a pushed, conjunctive
+        WHERE — _assemble_group) the predicate keeps, ascending, a
+        piece of WHERE_PIECE_EDGES at a time: ``native``
+        (_native_filter's) is one native pass over a piece's runs;
+        without it a piece's columns are gathered and the compiled
+        predicate evaluates in numpy.  Both compare the stored double
+        in float64 and AND the column's validity."""
+        kept, seen = [np.zeros(0, np.int64)], 0
+        for piece in cand.pieces(WHERE_PIECE_EDGES):
+            if native is not None:
+                col, op, c = native
+                got = piece.keep_f64(col.values, col.valid, op, c)
+            else:
+                got = np.flatnonzero(self._host_filter(m, plan, piece))
+            kept.append(seen + got)
+            seen += len(piece)
+        return np.concatenate(kept)
+
     def _host_filter(self, m: CsrMirror, plan: _GoPlan,
                      idx) -> np.ndarray:
         """Evaluate the compiled WHERE over candidate edges ``idx`` (a
@@ -2983,22 +3097,15 @@ class TpuQueryRuntime:
         """int32[m]: per-edge code into the sorted alias dictionary
         (cached per mirror+alias map — O(m) to build, reused across
         queries)."""
-        cache = getattr(m, "_alias_code_cache", None)
-        if cache is None:
-            cache = m._alias_code_cache = {}
-        key = tuple(sorted(alias_to_etype.items()))
-        codes = cache.get(key)
-        if codes is not None:
+        def fill():
+            alias_pos = {a: i
+                         for i, a in enumerate(sorted(alias_to_etype))}
+            codes = np.zeros(m.m, dtype=np.int32)
+            for a, et in alias_to_etype.items():
+                codes[m.edge_etype == et] = alias_pos[a]
             return codes
-        if len(cache) >= 8:   # each entry is O(m) — bound the memory
-            cache.clear()
-        alias_pos = {a: i for i, a in enumerate(sorted(alias_to_etype))}
-        et_to_code = {et: alias_pos[a] for a, et in alias_to_etype.items()}
-        codes = np.zeros(m.m, dtype=np.int32)
-        for et, code in et_to_code.items():
-            codes[m.edge_etype == et] = code
-        cache[key] = codes
-        return codes
+        return _mirror_table(m, "_alias_code_cache",
+                             tuple(sorted(alias_to_etype.items())), fill)
 
     # -------------------------------------------------- final-hop edges
     @staticmethod
@@ -3007,17 +3114,10 @@ class TpuQueryRuntime:
         """bool[m]: edge etype in the OVER set — cached per mirror so
         the O(m) isin pass is paid once per (mirror, OVER), not per
         query."""
-        cache = getattr(m, "_etype_mask_cache", None)
-        if cache is None:
-            cache = m._etype_mask_cache = {}
-        mask = cache.get(et_tuple)
-        if mask is None:
-            if len(cache) >= 8:   # each entry is O(m) — bound the memory
-                cache.clear()
-            mask = np.isin(m.edge_etype,
-                           np.asarray(et_tuple, dtype=np.int32))
-            cache[et_tuple] = mask
-        return mask
+        return _mirror_table(
+            m, "_etype_mask_cache", et_tuple,
+            lambda: np.isin(m.edge_etype,
+                            np.asarray(et_tuple, dtype=np.int32)))
 
     def _frontier_edges(self, m: CsrMirror, vs: np.ndarray,
                         et_tuple: Tuple[int, ...]) -> np.ndarray:
@@ -3040,12 +3140,7 @@ class TpuQueryRuntime:
         vertex's are not one run (another type sorts between them):
         the caller then walks whole rows and masks.  O(m) once per
         (mirror, OVER), cached beside _etype_edge_mask."""
-        cache = getattr(m, "_over_range_cache", None)
-        if cache is None:
-            cache = m._over_range_cache = {}
-        if et_tuple not in cache:
-            if len(cache) >= 8:
-                cache.clear()
+        def fill():
             mask = self._etype_edge_mask(m, et_tuple)
             cnt = self._deg_host(m, et_tuple)
             # a run starts where an edge of the set follows an edge
@@ -3053,13 +3148,12 @@ class TpuQueryRuntime:
             head = mask.copy()
             head[1:] &= ~(mask[:-1] & (m.edge_src[1:] == m.edge_src[:-1]))
             first = np.flatnonzero(head)
-            if len(first) == int(np.count_nonzero(cnt)):
-                lo = np.zeros(m.n, np.int64)
-                lo[m.edge_src[first]] = first
-                cache[et_tuple] = (lo, cnt)
-            else:
-                cache[et_tuple] = None
-        return cache[et_tuple]
+            if len(first) != int(np.count_nonzero(cnt)):
+                return None
+            lo = np.zeros(m.n, np.int64)
+            lo[m.edge_src[first]] = first
+            return lo, cnt
+        return _mirror_table(m, "_over_range_cache", et_tuple, fill)
 
     def _frontier_edges_multi(self, m: CsrMirror, vs_lists,
                               et_tuple: Tuple[int, ...],

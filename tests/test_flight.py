@@ -229,7 +229,7 @@ class TestDriftFold:
 # ============================================== chrome_trace + golden
 def _golden_inputs():
     """Fixed inputs for the byte-stable pin: one host span tree with a
-    seat marker (with the four waits), one tick with all ten pump
+    seat marker (with the five waits), one tick with all ten pump
     phases (assemble_us is the sum of its five parts), one sharded
     dispatch, one timing probe, one second-stream tick."""
     tree = {
@@ -249,13 +249,13 @@ def _golden_inputs():
     seat = {"lane": 3, "joined_tick": 17, "left_tick": 19, "hops": 2,
             "ending": "left-batch", "timeline": [41, 44],
             "seat_wait_us": 120, "ride_us": 410, "result_wait_us": 90,
-            "wake_us": 30}
+            "wake_us": 30, "assemble_us": 45}
     ticks = [
         {"kind": "tick", "stream": 0, "id": 41, "time_us": 1400,
          "dur_us": 360, "seat_us": 15, "join_us": 20, "hop_us": 180,
          "extract_us": 30, "clear_us": 10, "fetch_wait_us": 60,
          "d2h_us": 5, "unpack_us": 12, "rows_us": 18, "handover_us": 5,
-         "assemble_us": 100, "leaver_rows": 7, "seats": 2, "joins": 1,
+         "assemble_us": 100, "handed": 1, "seats": 2, "joins": 1,
          "leaves": 1, "evictions": 0, "generation": 5},
         {"kind": "dispatch", "kernel": "ell_go_sharded", "id": 42,
          "time_us": 1500, "k": 8, "rung": 1024, "steps": 3,
@@ -267,7 +267,7 @@ def _golden_inputs():
          "dur_us": 150, "seat_us": 4, "join_us": 0, "hop_us": 120,
          "extract_us": 20, "clear_us": 0, "fetch_wait_us": 0,
          "d2h_us": 0, "unpack_us": 0, "rows_us": 0, "handover_us": 0,
-         "assemble_us": 0, "leaver_rows": 0, "seats": 1},
+         "assemble_us": 0, "handed": 0, "seats": 1},
     ]
     return tree, ticks, seat
 

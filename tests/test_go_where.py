@@ -106,13 +106,52 @@ def test_filtered_go_matches_the_plain_reference(served, steps, op, value):
                     "where_candidates": candidates, "where_rows": rows}
 
 
+@pytest.mark.parametrize("native", [True, False],
+                         ids=["one_native_pass", "numpy"])
 @pytest.mark.parametrize("piece", [1, 5, 64])
 def test_a_cohort_s_candidates_are_filtered_piece_by_piece(
-        served, monkeypatch, piece):
+        served, monkeypatch, piece, native):
     """The predicate meets the candidates a piece of runs at a time;
-    the answer does not depend on where the pieces are cut."""
+    the answer does not depend on where the pieces are cut, nor on
+    which of the two ways a piece is filtered: the one native pass a
+    double column against a constant takes (_EdgeRuns.keep_f64), or
+    the gathered columns and the compiled predicate in numpy."""
     c, g, graph = served
-    monkeypatch.setattr(nebula_tpu.tpu.runtime, "WHERE_PIECE_EDGES", piece)
+    runtime = nebula_tpu.tpu.runtime
+    monkeypatch.setattr(runtime, "WHERE_PIECE_EDGES", piece)
+    passes = {"native": 0, "numpy": 0}
+    rt = c.tpu_runtime
+    real_keep = runtime._EdgeRuns.keep_f64
+    real_filter = rt._host_filter
+    before = {k: rt.stats[k] for k in ("go_where", "where_native")}
+
+    def keep(self, values, valid, op, const):
+        passes["native"] += 1
+        return real_keep(self, values, valid, op, const)
+
+    def host_filter(m, plan, idx):
+        passes["numpy"] += 1
+        return real_filter(m, plan, idx)
+
+    monkeypatch.setattr(runtime._EdgeRuns, "keep_f64", keep)
+    monkeypatch.setattr(rt, "_host_filter", host_filter)
+    if not native:              # a library without the pass
+        monkeypatch.setattr(rt, "_native_filter", lambda m, plan, et: None)
+    try:
+        _pieces(g, graph, piece)
+    finally:
+        monkeypatch.undo()
+    grew = {k: rt.stats[k] - before[k] for k in before}
+    from nebula_tpu.native import lib
+    if native and hasattr(lib(), "neb_filter_runs_f64"):
+        assert passes["native"] > 8 and passes["numpy"] == 0, passes
+        assert grew == {"go_where": 8, "where_native": 8}
+    else:
+        assert passes["numpy"] > 8 and passes["native"] == 0, passes
+        assert grew == {"go_where": 8, "where_native": 0}
+
+
+def _pieces(g, graph, piece):
     for start in range(1, 9):
         want = graph.answer(_semantics(3, ">", 0.5), start)
         assert reference.n_rows(want) > piece
@@ -186,6 +225,10 @@ def test_a_filtered_go_rides_the_lanes_beside_unfiltered_ones(served):
     streams = rt.dispatcher.continuous.streams()
     assert streams
     since = flight.recorder.note_tick(stream=-1)    # a mark in the ring
+    before = dict(rt.stats)
+    sampled = flags.get("trace_sample_rate")
+    flags.set("trace_sample_rate", 1.0)
+    tracing.trace_store.clear_for_tests()
     results, errors = {}, []
     barrier = threading.Barrier(len(jobs))
 
@@ -210,17 +253,48 @@ def test_a_filtered_go_rides_the_lanes_beside_unfiltered_ones(served):
     finally:
         for st in streams:
             st.tick_delay_s = 0.0
+        flags.set("trace_sample_rate", sampled)
     assert not errors, errors
     for i, (_stmt, sem, start) in enumerate(jobs):
         assert reference.same_rows(results[i], graph.answer(sem, start)), i
+    # the pump hands every leaver its frontier and knows no WHERE: what
+    # a predicate met is on the tpu.where span of the rider's OWN trace
+    # (one statement a span) and in the counters
     ticks = [r for r in flight.recorder.dump(limit=1 << 20)
              if r.get("kind") == "tick" and r.get("id", 0) > since
              and r.get("stream", -1) >= 0]
-    assert sum(t["where_stmts"] for t in ticks) == 4
-    mixed = [t for t in ticks if 0 < t["where_stmts"] < t["leaves"]]
-    assert mixed, [(t["leaves"], t["where_stmts"]) for t in ticks]
-    t = mixed[0]
-    assert t["where_candidates"] >= t["where_rows"] > 0
+    assert sum(t["leaves"] for t in ticks) == len(jobs)
+    assert all(t["handed"] == t["leaves"] for t in ticks)
+    assert not any(k.startswith("where_") for t in ticks for k in t)
+    riders = {}                 # left_tick -> [its riders' tpu.where]
+    for summary in tracing.trace_store.summaries():
+        tree = tracing.trace_store.tree(int(summary["id"], 16))
+        nodes = [n for root in tree["roots"] for n in _walk(root)]
+        marks = [n for n in nodes if n["name"] == "graph.continuous"]
+        if marks:
+            riders.setdefault(marks[0]["tags"]["left_tick"], []).append(
+                [n["tags"] for n in nodes if n["name"] == "tpu.where"])
+    wheres = [w for cohort in riders.values() for ws in cohort for w in ws]
+    assert len(wheres) == 4
+    assert all(w["queries"] == 1 and w["site"] == "assembly"
+               and w["candidates"] >= w["kept"] > 0 and w["cpu_us"] >= 0
+               for w in wheres)
+    grew = {k: rt.stats[k] - before[k] for k in
+            ("go_where", "where_candidates", "where_rows")}
+    assert grew == {"go_where": 4,
+                    "where_candidates": sum(w["candidates"]
+                                            for w in wheres),
+                    "where_rows": sum(w["kept"] for w in wheres)}
+    mixed = [cohort for cohort in riders.values()
+             if 0 < sum(bool(ws) for ws in cohort) < len(cohort)]
+    assert mixed, {tick: [len(ws) for ws in cohort]
+                   for tick, cohort in riders.items()}
+
+
+def _walk(node):
+    yield node
+    for child in node.get("children", ()):
+        yield from _walk(child)
 
 
 @pytest.mark.parametrize("mode,fused", [("auto", False), ("host", False),

@@ -6,7 +6,7 @@ pump trace"): one set of perf_counter stamps per tick feeds
   * a ``pump.tick`` trace of its own (only for ticks that touched a
     traced rider), emitted post hoc from those stamps, with the idle
     stretch before it as ``pump.idle``;
-  * each rider's ``graph.continuous`` marker: the four waits its
+  * each rider's ``graph.continuous`` marker: the five waits its
     ``submit()`` was made of.
 
 And the instrument that measured by stopping the pump is off it: the
@@ -165,9 +165,12 @@ class TestTickTrace:
             assert t["assemble_us"] == sum(t[p] for p in PARTS)
             assert t["seat_us"] >= 0
             assert t["seat_us"] + t["assemble_us"] <= t["dur_us"]
-        # rows handed over are counted where leavers were finished
-        assert sum(t["leaver_rows"] for t in ticks) > 0
-        assert all(t["leaver_rows"] == 0 for t in ticks
+        # frontiers are handed over where leavers were finished: every
+        # statement of the burst yields rows, so every leaver got its
+        # own to assemble
+        assert sum(t["handed"] for t in ticks) \
+            == sum(t["leaves"] for t in ticks) == 8
+        assert all(t["handed"] == 0 for t in ticks
                    if t["assemble_us"] == 0)
 
 
@@ -242,7 +245,7 @@ class TestUnpackCounters:
 
 # =================================================== (b) the rider
 class TestRiderWaits:
-    def test_four_waits_sum_to_the_submit_wall(self, graph, monkeypatch):
+    def test_five_waits_sum_to_the_submit_wall(self, graph, monkeypatch):
         c, g, ok = graph
         flags.set("trace_sample_rate", 1.0)
         walls = {}
@@ -275,9 +278,10 @@ class TestRiderWaits:
             assert all(m[w] >= 0 for w in WAITS), m
             wall = walls[int(tree["trace_id"], 16)]
             total = sum(m[w] for w in WAITS)
-            # the stamps tile enq_t -> wake; submit() adds admission
-            # before and the marker after: a few us each, unless this
-            # thread loses the interpreter there (eight run at once)
+            # the stamps tile enq_t -> its rows; submit() adds
+            # admission before and the marker after: a few us each,
+            # unless this thread loses the interpreter there (eight
+            # run at once)
             assert total <= wall
             outside.append(wall - total)
             assert m["joined_tick"] < m["left_tick"]
@@ -595,7 +599,7 @@ class TestTimelineDetail:
         r = ok("SHOW TIMELINE 64")
         detail = [row[5] for row in r.rows if row[3] == "tick"]
         assert detail
-        for field in ("seat_us=", "leaver_rows=") + tuple(
+        for field in ("seat_us=", "handed=") + tuple(
                 p + "=" for p in PARTS):
             assert all(field in d for d in detail), (field, detail[0])
 

@@ -579,6 +579,80 @@ class TestFrontierEdges:
         assert np.array_equal(runs.take(arr), arr[[5, 6, 0, 1, 2, 9]])
         assert len(runs) == 6
 
+    @pytest.mark.parametrize("op", ["<", "<=", ">", ">=", "==", "!="])
+    def test_one_native_pass_keeps_what_the_numpy_pass_keeps(self, op):
+        """``keep_f64`` (one double column against a constant, over
+        runs) gives the positions numpy's float64 comparison ANDed
+        with the validity gives: NaNs, invalid rows, empty runs, runs
+        longer than the pass's block, equality on a stored value."""
+        import operator
+        from nebula_tpu.native import lib
+        from nebula_tpu.tpu.runtime import _EdgeRuns
+        if lib() is None or not hasattr(lib(), "neb_filter_runs_f64"):
+            pytest.skip("no native library")
+        rng = np.random.default_rng(32)
+        m = 200_000
+        values = rng.random(m)
+        values[rng.integers(0, m, 500)] = np.nan
+        valid = rng.random(m) > 0.05
+        lo = np.sort(rng.integers(0, m - 5000, 300))
+        cnt = rng.integers(0, 40, 300)
+        cnt[::50] = 4321                # longer than a block of 1,024
+        runs = _EdgeRuns(lo, cnt)
+        idx = runs.index()
+        c = float(values[idx[11]]) if op in ("==", "<=", ">=") else 0.9
+        fn = {"<": operator.lt, "<=": operator.le, ">": operator.gt,
+              ">=": operator.ge, "==": operator.eq, "!=": operator.ne}[op]
+        with np.errstate(invalid="ignore"):
+            want = np.flatnonzero(fn(values[idx], c) & valid[idx])
+        got = runs.keep_f64(values, valid, op, c)
+        assert got.dtype == np.int64 and np.array_equal(got, want)
+        assert len(want) > 0
+        assert len(_EdgeRuns(lo[:0], cnt[:0]).keep_f64(
+            values, valid, op, c)) == 0
+        with pytest.raises(IndexError):
+            _EdgeRuns(np.asarray([m - 2]), np.asarray([5])).keep_f64(
+                values, valid, op, c)
+
+    def test_a_column_against_a_constant_is_tagged_by_the_compiler(self):
+        """``CVal.cmp`` names the one shape the native pass evaluates:
+        a bare float edge column compared with a numeric literal, the
+        column on the left whichever side it was written on."""
+        from nebula_tpu.filter.expressions import (
+            AliasPropExpr, ArithmeticExpr, LogicalExpr, PrimaryExpr,
+            RelationalExpr)
+        from nebula_tpu.interface.common import SupportedType
+        from nebula_tpu.tpu.expr_compile import ExprCompiler
+
+        class Col:
+            dictionary = None
+            device_ok = True
+
+            def __init__(self, stype):
+                self.stype = stype
+
+        class Mirror:
+            edge_cols = {(7, "w"): Col(SupportedType.DOUBLE),
+                         (7, "n"): Col(SupportedType.INT)}
+
+        def compiled(expr):
+            return ExprCompiler(Mirror(), 1, None, {"e": 7}).compile(expr)
+
+        w, n = AliasPropExpr("e", "w"), AliasPropExpr("e", "n")
+        assert compiled(RelationalExpr(">", w, PrimaryExpr(0.5))).cmp \
+            == ("e:7:w", ">", 0.5)
+        assert compiled(RelationalExpr("<=", PrimaryExpr(2), w)).cmp \
+            == ("e:7:w", ">=", 2.0)
+        assert compiled(RelationalExpr("!=", PrimaryExpr(0.25), w)).cmp \
+            == ("e:7:w", "!=", 0.25)
+        # an int column, arithmetic on the column, a conjunction: numpy
+        assert compiled(RelationalExpr(">", n, PrimaryExpr(3))).cmp is None
+        assert compiled(RelationalExpr(">", ArithmeticExpr(
+            "+", w, PrimaryExpr(1.0)), PrimaryExpr(0.5))).cmp is None
+        assert compiled(LogicalExpr("&&", RelationalExpr(
+            ">", w, PrimaryExpr(0.5)), RelationalExpr(
+                "<", w, PrimaryExpr(0.9)))).cmp is None
+
     def test_edge_runs_refuse_a_run_outside_the_array(self):
         from nebula_tpu.native import lib
         from nebula_tpu.tpu.runtime import _EdgeRuns
