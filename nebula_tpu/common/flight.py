@@ -136,7 +136,10 @@ class FlightRecorder:
         (the leavers whose frontier went to their own thread, which
         filters and makes the rows: every leaver but a COUNT rider and
         a WHERE the native pass cannot take — graph/batch_dispatch.py
-        _finish), the hops whose branch
+        _finish), counted and count_us (the leavers answered by the
+        device's per-lane count, k-hop neighbourhood counts, and the
+        pump's wait for and read of it: the head of fetch_wait_us),
+        the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
         the live slot rows; hop_slots the ELL slots they visited),
         idle gap since the previous tick, mirror generation, tick wall

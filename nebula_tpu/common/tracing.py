@@ -86,6 +86,12 @@ SPAN_NAMES = (
     "tpu.kernel",             # device kernel dispatch (async launch)
     "tpu.launch",             # batch leader: frontier launch half
     "tpu.fetch",              # device→host result gather
+    "tpu.count",              # a continuous leave cohort's per-lane
+                              # count: the wait for the count program
+                              # and the read of its B int32, on the
+                              # first counting leaver's trace (tags:
+                              # leavers, bytes — tpu/runtime.py
+                              # _LaneCount)
     "tpu.assemble",           # host row materialization
     "tpu.path_index",         # FIND PATH: the in-edge order of one
                               # mirror generation and OVER set, built at
@@ -135,6 +141,10 @@ SPAN_NAMES = (
                               # tick, seats, joins, leaves, riders)
     "pump.seat",              # anchor + seat-map bookkeeping
     "pump.enqueue",           # join + hop + extract + clear enqueues
+    "pump.count",             # host blocked on the leave cohort's
+                              # per-lane count and reading it (tag
+                              # counted: the leavers it answers); the
+                              # head of the tick record's fetch_wait_us
     "pump.fetch_wait",        # host blocked on the leave cohort's
                               # extract buffer (the device, seen from
                               # the pump)
@@ -555,6 +565,7 @@ _PHASE_OF = {
     "tpu.launch": PHASE_KERNEL,
     "tpu.kernel": PHASE_KERNEL,
     "tpu.fetch": PHASE_FETCH,
+    "tpu.count": PHASE_FETCH,
     "tpu.assemble": PHASE_ASSEMBLE,
     "tpu.where": PHASE_ASSEMBLE,
 }

@@ -162,7 +162,8 @@ flags.define("go_dispatch_mode", "continuous",
              "— 'windowed' restores the discrete coalescing pipeline "
              "(the bit-exact parity oracle and rollback).  BFS, "
              "single-hop GO, fused-filter and mesh-sharded dispatch "
-             "always use the windowed pipeline")
+             "always use the windowed pipeline.  Managed: UPDATE "
+             "CONFIGS graph:go_dispatch_mode=...")
 flags.define("autoscale_max_replicas", 8,
              "ceiling of the graph.autoscale.recommended_replicas "
              "gauge — the window controller's depth EMA plus the "
@@ -446,14 +447,21 @@ class ContinuousUnavailable(Exception):
 
 class _Rider:
     """One query riding the continuous batch: queued until a lane
-    frees, seated for steps-1 hop ticks, extracted at its last hop (or
-    evicted at its deadline).  Fields are written by the stream pump
-    under the stream condition; the submitting thread reads them after
-    ``done`` flips: its ``frontier``, which its own thread assembles
-    (submit()), or the ``result`` of a leaver the pump answered itself
-    (a COUNT rider, a WHERE that filters in numpy: _finish)."""
+    frees, seated for ``hops`` hop ticks, extracted at its last hop (or
+    evicted at its deadline).  ``hops`` is steps-1 — the last step's
+    edges are the host's to assemble from the frontier — but for a
+    k-hop neighbourhood count (reduce "count_distinct": ``counts``),
+    which rides all of its steps and leaves with the size of its last
+    frontier, counted on the device, and no extraction.  Fields are
+    written by the stream pump under the stream condition; the
+    submitting thread reads them after ``done`` flips: its
+    ``frontier``, which its own thread assembles (submit()), or the
+    ``result`` of a leaver the pump answered itself (a counting
+    leaver's number, a COUNT rider, a WHERE that filters in numpy:
+    _finish)."""
 
-    __slots__ = ("payload", "steps", "upto", "reduce", "deadline",
+    __slots__ = ("payload", "steps", "upto", "reduce", "counts", "hops",
+                 "deadline",
                  "tctx", "enq_t", "seated_t", "left_t", "done_t",
                  "lane", "remaining", "joined_tick", "left_tick",
                  "midflight", "done", "result", "frontier", "mirror",
@@ -465,6 +473,9 @@ class _Rider:
         self.steps = int(steps)
         self.upto = bool(upto)
         self.reduce = tuple(reduce) if reduce is not None else None
+        self.counts = self.reduce is not None \
+            and self.reduce[0] == "count_distinct"
+        self.hops = self.steps if self.counts else self.steps - 1
         self.deadline = deadline
         # the submitter's trace snapshot: the pump attaches it around
         # the device phases this rider participates in, so a PROFILE
@@ -498,8 +509,9 @@ class _ContinuousStream:
 
         seat joiners -> scatter-merge their start frontiers ->
         dispatch hop k -> mark leavers/evictions -> enqueue their
-        lane extraction + clear -> fetch + unpack hop k-1's leavers
-        while hop k computes -> hand each its frontier and wake it
+        lane extraction (a counting leaver's per-lane count) + clear ->
+        fetch + unpack hop k-1's leavers while hop k computes -> hand
+        each its frontier (or its number) and wake it
 
     so the device always has the next hop enqueued while the host
     does per-query work (the double-buffer overlap), and a leaver's
@@ -649,7 +661,7 @@ class _ContinuousStream:
         """Wake an extracted-but-unassembled leave cohort with ``ex``
         — its riders already left the seat map, so _fail_all cannot
         reach them."""
-        _resolver, leavers, _m = pending
+        leavers = pending[1]
         with self.cond:
             for r in leavers:
                 if r.error is None and r.result is None:
@@ -796,7 +808,7 @@ class _ContinuousStream:
                     # seat map via _fail_all
                     # nebulint: obligation=handed-off/seat-map-retired-by-fail-all
                     r.lane = self.ledger.alloc()
-                    r.remaining = r.steps - 1
+                    r.remaining = r.hops
                     r.joined_tick = self.tick_no
                     r.seated_t = t_seated
                     r.midflight = was_running
@@ -875,7 +887,7 @@ class _ContinuousStream:
             # the windowed equivalence (the leader thread's PROFILE
             # shows launch/kernel; riders see the seat markers)
             jctx = joiners[0].tctx if joiners else None
-            resolver = None
+            resolver = counter = None
             try:
                 with tracing.attach_captured(jctx):
                     with tracing.span("tpu.launch",
@@ -899,7 +911,7 @@ class _ContinuousStream:
                                     r.remaining -= 1
                                     query_registry.note_hop(
                                         r.qid,
-                                        r.steps - 1 - r.remaining)
+                                        r.hops - r.remaining)
                                     if r.remaining <= 0:
                                         del self.seated[lane]
                                         r.left_t = t_left
@@ -907,8 +919,25 @@ class _ContinuousStream:
                                         leavers.append(r)
                     if leavers:
                         tx = time.perf_counter()
-                        resolver = sess.extract([(r.lane, r.upto)
-                                                 for r in leavers])
+                        # a cohort is its fetching leavers, then its
+                        # counting ones: the first take their lanes'
+                        # columns off the device, the second one
+                        # number each out of ONE count over the
+                        # resident frontier (_finish)
+                        fetching = [r for r in leavers if not r.counts]
+                        counting = [r for r in leavers if r.counts]
+                        leavers[:] = fetching + counting
+                        if counting:
+                            # its kernel span on the first counting
+                            # leaver's trace, where its tpu.count
+                            # lands too (a leave tick may seat nobody)
+                            with tracing.attach_captured(
+                                    counting[0].tctx):
+                                counter = sess.count(
+                                    [r.lane for r in counting])
+                        if fetching:
+                            resolver = sess.extract([(r.lane, r.upto)
+                                                     for r in fetching])
                         extract_us = (time.perf_counter() - tx) * 1e6
                     if leavers or evicted:
                         tc = time.perf_counter()
@@ -935,7 +964,7 @@ class _ContinuousStream:
                     if r.midflight:
                         journal.record(
                             "query.joined_midflight",
-                            detail=f"lane={r.lane} hops={r.steps - 1} "
+                            detail=f"lane={r.lane} hops={r.hops} "
                                    f"tick={r.joined_tick}",
                             space=self.space_id)
             if leavers or evicted:
@@ -950,7 +979,7 @@ class _ContinuousStream:
             stats.observe("graph.continuous.lane_occupancy",
                           float(occupancy))
             if leavers:
-                new_pending = (resolver, leavers, sess.m)
+                new_pending = (resolver, leavers, sess.m, counter)
         if evicted:
             stats.add_value("graph.continuous.evictions",
                             len(evicted))
@@ -1018,7 +1047,7 @@ class _ContinuousStream:
             # field names (_finish)
             met = {name: sum(f[2][name] for f in finishes)
                    for name in ("unpack_leavers", "unpack_live",
-                                "unpack_rows")}
+                                "unpack_rows", "counted", "count_us")}
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1096,8 +1125,14 @@ class _ContinuousStream:
                      us(t_enq) - us(t_seat))
         for (ta, t_wait, t_d2h, t_unpack, t_rows, t_hand), n, met \
                 in finishes:
-            tracing.emit("pump.fetch_wait", tid, root, us(ta),
-                         us(t_wait) - us(ta))
+            # the count's wait and read head the fetch wait
+            t_count = ta + met["count_us"] / 1e6
+            if met["counted"]:
+                tracing.emit("pump.count", tid, root, us(ta),
+                             us(t_count) - us(ta),
+                             counted=met["counted"])
+            tracing.emit("pump.fetch_wait", tid, root, us(t_count),
+                         us(t_wait) - us(t_count))
             tracing.emit("pump.d2h", tid, root, us(t_wait),
                          us(t_d2h) - us(t_wait))
             tracing.emit("pump.unpack", tid, root, us(t_d2h),
@@ -1114,34 +1149,42 @@ class _ContinuousStream:
                          int(idle_us), stream=self.space_id, why=why)
 
     def _finish(self, pending) -> Tuple:
-        """Force the leave cohort's extraction fetch and hand every
-        leaver what its own thread goes on from (submit()): its
-        frontier with the generation it was extracted under — its
-        filter and rows are then its thread's work, not the pump's —
-        or, for the leavers the pump answers itself, its result: a
-        COUNT rider's number (one vectorised degree fold over the
-        cohort's COUNT riders) and the rows of a WHERE that filters in
-        numpy (rt.rider_assembles: one thread running those passes in
-        turn is faster than the riders' threads running them at once).
-        A cohort-level failure (the resolver's, the fold's) wakes
-        every cohort member with it.
+        """Force the leave cohort's fetches and hand every leaver what
+        its own thread goes on from (submit()).  A counting leaver (a
+        k-hop neighbourhood count) gets its number: the cohort's ONE
+        per-lane count is waited for and read first — B int32, no
+        column, no unpack — and is all such a leaver needs.  A
+        fetching leaver gets its frontier with the generation it was
+        extracted under — its filter and rows are then its thread's
+        work, not the pump's — or, for the leavers the pump answers
+        itself, its result: a COUNT rider's number (one vectorised
+        degree fold over the cohort's COUNT riders) and the rows of a
+        WHERE that filters in numpy (rt.rider_assembles: one thread
+        running those passes in turn is faster than the riders'
+        threads running them at once).  A cohort-level failure (a
+        resolver's, the fold's) wakes every cohort member with it.
 
         Returns (the stamps that split this stretch of the pump's
         time: start, fetch_wait end, d2h end, unpack end, rows end —
         "rows" being what the pump answered itself; the leavers handed
-        their frontier; what the unpack met, under the tick record's
+        their frontier; what the fetches met, under the tick record's
         field names: unpack_leavers, unpack_live, unpack_rows —
-        tpu/runtime.py _unpack_lanes).  The handover ends where the
-        caller stamps next."""
-        resolver, leavers, m = pending
+        tpu/runtime.py _unpack_lanes — and counted, count_us: the
+        counting leavers and the wait for and read of their counts,
+        which is the head of the fetch wait).  The handover ends where
+        the caller stamps next."""
+        resolver, leavers, m, counter = pending
         rt = self.sched.runtime
         ta = time.perf_counter()
-        t_unpack = 0.0
-        # the leavers the pump answers itself: COUNT riders (one fold
-        # over the cohort's) and a WHERE that filters in numpy, which
-        # sixteen threads at once run slower than one in turn
-        # (rt.rider_assembles).  Every other leaver takes its frontier
-        own_idx = [i for i, r in enumerate(leavers)
+        # the cohort is its fetching leavers, then its counting ones
+        n_fetch = sum(not r.counts for r in leavers)
+        fetching, counting = leavers[:n_fetch], leavers[n_fetch:]
+        t_count = t_unpack = 0.0
+        # the fetching leavers the pump answers itself: COUNT riders
+        # (one fold over the cohort's) and a WHERE that filters in
+        # numpy, which sixteen threads at once run slower than one in
+        # turn (rt.rider_assembles).  Every other takes its frontier
+        own_idx = [i for i, r in enumerate(fetching)
                    if (r.reduce is not None and r.reduce[0] == "count")
                    or not rt.rider_assembles(m, r.payload,
                                              self.et_tuple)]
@@ -1150,29 +1193,43 @@ class _ContinuousStream:
         outs: List[object] = [None] * len(leavers)
         vs_lists = None
         try:
-            # fetch spans land on the first leaver's trace
-            with tracing.attach_captured(leavers[0].tctx):
-                vs_lists = resolver()
-                t_unpack = time.perf_counter()
-                if own_idx:
-                    own = rt.continuous_results(
-                        self.space_id, m,
-                        [leavers[i].payload for i in own_idx],
-                        [leavers[i].reduce for i in own_idx],
-                        [vs_lists[i] for i in own_idx], self.et_tuple)
-                    for i, out in zip(own_idx, own):
-                        outs[i] = out
+            if counting:
+                # the count's span lands on the first counting
+                # leaver's trace
+                with tracing.attach_captured(counting[0].tctx):
+                    counts = counter()
+                t_count = counter.t_done
+                outs[n_fetch:] = rt.count_distinct_results(
+                    counts, [r.hops for r in counting])
+            if fetching:
+                # fetch spans land on the first fetching leaver's trace
+                with tracing.attach_captured(fetching[0].tctx):
+                    vs_lists = resolver()
+                    t_unpack = time.perf_counter()
+                    if own_idx:
+                        own = rt.continuous_results(
+                            self.space_id, m,
+                            [fetching[i].payload for i in own_idx],
+                            [fetching[i].reduce for i in own_idx],
+                            [vs_lists[i] for i in own_idx],
+                            self.et_tuple)
+                        for i, out in zip(own_idx, own):
+                            outs[i] = out
         except Exception as ex:         # noqa: BLE001 — cohort-level
             outs = [ex] * len(leavers)
         t_rows = time.perf_counter()
         # a resolver that failed (or a test's stand-in) has no stamps:
         # its whole stretch reads as the part it died in, and it
-        # unpacked nothing
-        t_unpack = t_unpack or t_rows
+        # unpacked nothing.  A cohort of counting leavers alone has no
+        # extract: its fetch wait is its count
+        t_count = t_count or ta
+        t_unpack = t_unpack or (t_rows if fetching else t_count)
         t_wait = getattr(resolver, "t_wait", 0.0) or t_unpack
         t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
         met = {name: int(getattr(resolver, name, 0)) for name in
                ("unpack_leavers", "unpack_live", "unpack_rows")}
+        met["counted"] = len(counting)
+        met["count_us"] = int((t_count - ta) * 1e6)
         stats.add_value("graph.continuous.leaves", len(leavers))
         handed = 0
         with self.cond:
@@ -1218,7 +1275,7 @@ class _ContinuousStream:
                     elif self.hop_ema_s > 0.0:
                         # seats free at hop boundaries: if every free
                         # lane seats someone ahead of us we wait >= 1
-                        # tick for churn, then ride steps-1 hops — a
+                        # tick for churn, then ride our hops — a
                         # conservative LOWER bound, so a shed is
                         # provably unmeetable
                         free = self.ledger.free_count() \
@@ -1226,7 +1283,7 @@ class _ContinuousStream:
                         wait_ticks = 0 if (free is None
                                            or free > depth) else 1
                         est_s = self.hop_ema_s \
-                            * (wait_ticks + max(1, steps - 1))
+                            * (wait_ticks + max(1, rider.hops))
                         if rem < est_s:
                             if depth > 0:
                                 disp._shed(
@@ -1300,7 +1357,8 @@ class _ContinuousStream:
         tracing.annotate("graph.continuous", lane=rider.lane,
                          joined_tick=rider.joined_tick,
                          left_tick=rider.left_tick,
-                         hops=rider.steps - 1,
+                         hops=rider.hops,
+                         reduce=rider.reduce[0] if rider.reduce else "",
                          midflight=rider.midflight,
                          ending=protocol.END_LEFT, **waits)
         with self.sched.dispatcher._lock:
@@ -1402,6 +1460,10 @@ class ContinuousGoScheduler:
         except (TypeError, ValueError):
             return False
         reduce = key[5]
+        if reduce is not None and reduce[0] == "count_distinct":
+            # a k-hop neighbourhood count rides every one of its steps
+            # and leaves with a number: one step is a hop to ride too
+            return steps >= 1
         if reduce is not None and reduce[0] not in ("count", "limit"):
             return False
         return steps >= 2

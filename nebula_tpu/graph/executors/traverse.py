@@ -956,29 +956,39 @@ class SetExecutor(Executor):
 
 
 def _go_reduce_shape(left, right):
-    """-> ("limit", cap) | ("count", col_name) | None: the GO|LIMIT and
+    """-> ("limit", cap) | ("count", col_name) |
+    ("count_distinct", col_name) | None: the GO|LIMIT and
     GO|YIELD COUNT(*) pipe shapes whose result the device can REDUCE
     before the fetch (ROADMAP item 2 pushdown).  The gate is
     conservative: the left GO must be unable to raise per-row errors
     (meta-only YIELD columns — _dst/_src/_rank/_type never error — no
-    WHERE, no DISTINCT, no UPTO), because a truncated/counted result
-    would skip rows whose evaluation the CPU path would have failed
-    on."""
+    WHERE, no UPTO), because a truncated/counted result would skip
+    rows whose evaluation the CPU path would have failed on.  A
+    DISTINCT is reduced in one shape only: ``YIELD DISTINCT <e>._dst``
+    as the GO's one column, over one edge type, piped into the bare
+    COUNT(*) — the k-hop neighbourhood count, whose distinct
+    destinations are the next frontier, so the device rides one hop
+    more and counts it.  Every other DISTINCT stays unreduced."""
     if not isinstance(left, ast.GoSentence):
         return None
     if left.where is not None:
         return None
     if getattr(left.step, "upto", False) and left.step.steps > 1:
         return None
+    distinct = left.yield_ is not None and left.yield_.distinct
     if left.yield_ is not None:
-        if left.yield_.distinct:
-            return None
         for c in left.yield_.columns:
             if not isinstance(c.expr, (EdgeDstIdExpr, EdgeSrcIdExpr,
                                        EdgeRankExpr, EdgeTypeExpr)):
                 return None
+    if distinct and not (
+            len(left.yield_.columns) == 1
+            and isinstance(left.yield_.columns[0].expr, EdgeDstIdExpr)
+            and not left.over.is_all and not left.over.reversely
+            and len(left.over.edges) == 1):
+        return None
     if isinstance(right, ast.LimitSentence):
-        if right.count < 0 or right.offset < 0:
+        if distinct or right.count < 0 or right.offset < 0:
             return None
         return ("limit", right.offset + right.count)
     if isinstance(right, ast.YieldSentence):
@@ -990,7 +1000,8 @@ def _go_reduce_shape(left, right):
         e = cols[0].expr
         if isinstance(e, FunctionCallExpr) and e.name.lower() == "count" \
                 and not e.args:
-            return ("count", cols[0].alias or default_col_name(e))
+            return ("count_distinct" if distinct else "count",
+                    cols[0].alias or default_col_name(e))
     return None
 
 
@@ -1020,7 +1031,11 @@ class PipeExecutor(Executor):
     def _try_reduced_pipe(self, s) -> Optional[InterimResult]:
         """GO|LIMIT / GO|YIELD COUNT(*) fusion: run the left GO with a
         reduction hint so the device fetch carries only the
-        surviving/reduced rows, then finish the pipe inline.  When the
+        surviving/reduced rows, then finish the pipe inline (a k-hop
+        neighbourhood count, YIELD DISTINCT <e>._dst | YIELD COUNT(*),
+        comes back as one number counted on the device, like a COUNT;
+        from the CPU path its de-duplicated rows arrive and their
+        number is the same).  When the
         GO served on the CPU path instead (decline, has_input, router)
         the hint was ignored and the FULL rows arrive — the same
         slice/count below is then plain pipe semantics.  Live writes
@@ -1040,7 +1055,7 @@ class PipeExecutor(Executor):
         kind = shape[0]
         saved_hint = self.ectx.go_reduce
         self.ectx.go_reduce = ("limit", int(shape[1])) \
-            if kind == "limit" else ("count",)
+            if kind == "limit" else (kind,)
         try:
             left = traced_execute(make_executor(s.left, self.ectx),
                                   self.ectx)
@@ -1051,7 +1066,7 @@ class PipeExecutor(Executor):
             lo = s.right.offset
             hi = lo + s.right.count
             return InterimResult(left.columns, left.rows[lo:hi])
-        if getattr(left, "reduced", None) == ("count",):
+        if getattr(left, "reduced", None) == (kind,):
             total = int(left.rows[0][0]) if left.rows else 0
         else:
             total = len(left.rows)

@@ -17,7 +17,8 @@ _MANAGED = {
                          "session_reclaim_interval_secs",
                          "storage_backend",
                          "find_path_max_paths",
-                         "tpu_filter_mode"],
+                         "tpu_filter_mode",
+                         "go_dispatch_mode"],
     ConfigModule.META: ["expired_threshold_sec"],
     ConfigModule.STORAGE: ["heartbeat_interval_secs",
                            "load_data_interval_secs",

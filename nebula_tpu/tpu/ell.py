@@ -629,6 +629,12 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #                                frontier or the UPTO accumulator) —
 #                                the d2h fetch is R1 bytes per leaving
 #                                word, never the whole matrix
+#   make_lane_count_kernel       set bits per lane over the real vertex
+#                                rows: a leaver whose statement is a
+#                                k-hop neighbourhood count (YIELD
+#                                DISTINCT e._dst | YIELD COUNT(*)) rode
+#                                k hops and leaves with this number —
+#                                B int32 cross the link, no column does
 # ====================================================================
 # Push budget of the continuous hop, in live SLOT ROWS (a live vertex's
 # main row plus its hub extra rows).  The push pays XLA's row scatter,
@@ -894,6 +900,39 @@ def make_lane_extract_kernel():
             return jnp.where(sel[None, :] != 0, ag, fg)
 
     return jax.jit(extract)
+
+
+def make_lane_count_kernel(ell: EllIndex):
+    """Set bits per lane of the resident frontier, over the real
+    vertex rows: fn(fp uint8 [n_rows+1, W]) -> int32 [W*8], entry
+    j*8+k the vertices whose bit k of word j is set.  A row v < n is
+    one vertex of the mirror (its ELL row under the degree-bucket
+    relabelling: every vertex has one, a sink too, because rows hold
+    IN-slots); rows n..n_rows-1 are the hub extra rows and growth
+    spares, which after a pull hold partial ORs already merged into
+    their owners' rows and are never read as sources, and row n_rows is
+    the pad: none of them is counted, so a lane's count is its
+    frontier's distinct vertices — what ``GO k STEPS ... YIELD DISTINCT
+    e._dst | YIELD COUNT(*)`` returns after k hops (the distinct
+    destinations of the k-th hop ARE the k-th frontier).  Not donated:
+    the carrier keeps serving the lanes that stay seated.  The program
+    reads n x W bytes and writes 4 B a lane (benchmark/count_bytes.py);
+    its own jit name (``jit_count``) tells it from ``jit_hop`` in a
+    device trace."""
+    import jax
+    import jax.numpy as jnp
+    n = ell.n
+
+    def count(fp):
+        with jax.named_scope("lane/count"):
+            real = fp[:n]
+            # one plane a bit position: [n, W] 0/1 summed down the rows
+            planes = [jnp.sum((real >> jnp.uint8(k)) & jnp.uint8(1),
+                              axis=0, dtype=jnp.int32)
+                      for k in range(LANE_BITS)]
+            return jnp.stack(planes, axis=1).reshape(-1)
+
+    return jax.jit(count)
 
 
 # ====================================================================
@@ -2392,6 +2431,12 @@ def _ell_lane_extract_buckets(fx):
     return out
 
 
+def _ell_lane_count_buckets(fx):
+    kern = make_lane_count_kernel(fx.ell)
+    return [(("ell_lane_count", fx.ell.shape_sig()), kern,
+             (_packed_frontier_avals(fx, B)[0],)) for B in fx.widths]
+
+
 def _sparse_go_buckets(fx):
     d_max = max(fx.ell.bucket_D) if fx.ell.bucket_D else 1
     n1 = fx.ell.n + 1
@@ -2551,6 +2596,15 @@ register_kernel(KernelSpec(
     # where every seat leaves in one tick)
     d2h_bytes_max=lambda fx: (fx.ell.n_rows + 1)
     * lanes_width(fx.qmax)))
+register_kernel(KernelSpec(
+    "ell_lane_count", make_lane_count_kernel,
+    phase_kind="ell_lane_count",
+    # one retrace per lane-width rung; reads the resident frontier in
+    # place (no donation, nothing uploaded) and the fetch is one int32
+    # a lane
+    budget=2, instantiate=_ell_lane_count_buckets,
+    frontier=(0,), packed=(0,),
+    d2h_bytes_max=lambda fx: 4 * lanes_width(max(fx.widths)) * 8))
 register_kernel(KernelSpec(
     "sparse_go", make_batched_sparse_go_kernel, phase_kind="sparse_go",
     # per steps value: one retrace per sparse c0 rung per variant

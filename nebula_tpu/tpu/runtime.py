@@ -603,6 +603,10 @@ DEVICE_PHASES = {
     "ell_lane_clear": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 2},
     "ell_lane_extract": {"phases": ("tpu.kernel", "tpu.fetch"),
                          "h2d": 2, "d2h": 1},
+    # the k-hop count's leave: reads the resident frontier in place,
+    # one int32 a lane comes back (_LaneCount)
+    "ell_lane_count": {"phases": ("tpu.kernel", "tpu.count"),
+                       "h2d": 0, "d2h": 1},
     "ell_go_sharded": {"phases": ("tpu.launch", "tpu.kernel",
                                   "tpu.fetch", "tpu.assemble"),
                        "h2d": 1, "d2h": 1},
@@ -706,7 +710,14 @@ class TpuQueryRuntime:
                       # fetch pulled over the link
                       "t_device_s": 0.0, "device_bytes_moved": 0,
                       "device_timed_dispatches": 0,
-                      "fetch_bytes": 0, "go_reduced": 0}
+                      "fetch_bytes": 0, "go_reduced": 0,
+                      # k-hop neighbourhood counts (GO k STEPS ... YIELD
+                      # DISTINCT e._dst | YIELD COUNT(*)) answered by k
+                      # hops and a count of the k-th frontier, either
+                      # tier: statements, the hops they rode, the sum
+                      # of their answers (count_distinct_results)
+                      "go_count_distinct": 0, "count_distinct_hops": 0,
+                      "count_distinct_vertices": 0}
         self._timing_seq = 0
         # shapes the AOT pre-warm compiled / shapes live dispatch used
         # (prewarm_hits/misses make the pre-warm's p99 effect auditable:
@@ -1685,11 +1696,22 @@ class TpuQueryRuntime:
         if not live:
             return [expired[i] for i in range(len(queries))], None
         starts = [q.start_vids for q in live]
+        # a k-hop neighbourhood count is the size of the k-th frontier:
+        # one advance more than a GO's rows need, and nothing reduced
+        # on the way (the dense count program counts EDGES of the last
+        # hop and must not answer it) — the launch is a plain
+        # (steps + 1)-step GO's, on whichever program serves that
+        count_distinct = reduce is not None \
+            and reduce[0] == "count_distinct"
         with tracing.span("tpu.launch", queries=len(live),
                           steps=steps):
-            launch = self._launch_frontiers(space_id, starts, et_tuple,
-                                            steps, upto=upto,
-                                            reduce=reduce)
+            if count_distinct:
+                launch = self._launch_frontiers(space_id, starts,
+                                                et_tuple, steps + 1)
+            else:
+                launch = self._launch_frontiers(space_id, starts,
+                                                et_tuple, steps,
+                                                upto=upto, reduce=reduce)
         self._tick("t_launch_s", t0)
         # finish() may run on a different thread (the dispatcher
         # pipelines batches) — carry the leader's trace context across
@@ -1701,7 +1723,15 @@ class TpuQueryRuntime:
                 with tracing.span("tpu.fetch"):
                     vs_lists, m = launch()
                 t1 = self._tick("t_fetch_s", t1)
-                if reduce is not None and reduce[0] == "count":
+                if count_distinct:
+                    # the frontier arrays are ascending and without
+                    # repeats by construction: their lengths are the
+                    # answers (a mirror with no edge advances nothing
+                    # and hands the starts back: nobody is reached)
+                    results = self.count_distinct_results(
+                        [len(vs) if m.m else 0 for vs in vs_lists],
+                        [steps] * len(live))
+                elif reduce is not None and reduce[0] == "count":
                     # COUNT(*) pushdown: no candidate assembly, no row
                     # materialization — the result per query is one
                     # number (device-counted on the dense path, a
@@ -1747,6 +1777,19 @@ class TpuQueryRuntime:
             counts = [int(deg[np.asarray(vs, np.int64)].sum())
                       if len(vs) else 0 for vs in vs_lists]
         return [(["__count__"], [[int(c)]]) for c in counts[:nq]]
+
+    def count_distinct_results(self, counts, hops):
+        """Results of k-hop neighbourhood counts from the sizes of
+        their k-th frontiers, in the form _count_results gives (the
+        fused pipe reads the number off the one row), and the counters
+        that say such statements were answered this way: statements,
+        hops ridden, vertices counted."""
+        with self._lock:
+            self.stats["go_count_distinct"] += len(counts)
+            self.stats["count_distinct_hops"] += int(sum(hops))
+            self.stats["count_distinct_vertices"] += int(sum(counts))
+            self.stats["go_reduced"] += len(counts)
+        return [(["__count__"], [[int(c)]]) for c in counts]
 
     # ------------------------------------- continuous dispatch seam
     def continuous_session(self, space_id: int,
@@ -2736,14 +2779,7 @@ class TpuQueryRuntime:
                 continue
             rows = rows_per_query[g]
             if queries[i].distinct:
-                seen = set()
-                out = []
-                for r in rows:
-                    key = tuple(r)
-                    if key not in seen:
-                        seen.add(key)
-                        out.append(r)
-                rows = out
+                rows = _distinct_rows(rows)
             results[i] = (columns, rows)
 
     def _invalid_candidates(self, m: CsrMirror, used: Dict[str, Tuple],
@@ -4082,6 +4118,24 @@ class _ContinuousGoSession:
                    for lane, upto in leavers]
         return _LaneFetch(self, out_dev, leavers, cols_of, np_pairs)
 
+    def count(self, lanes):
+        """Count the set bits of every lane of the resident frontier
+        over the real vertex rows (ell.make_lane_count_kernel) and
+        return a zero-arg resolver (_LaneCount) -> the counts of
+        ``lanes``, the leavers whose statement is a k-hop
+        neighbourhood count: they rode k hops and the k-th frontier is
+        their distinct destinations.  Enqueued behind the hop that
+        made that frontier and before the clear that drops it; one
+        int32 a lane crosses the link, no column does."""
+        from .ell import make_lane_count_kernel
+        kern = self.rt._kernel(
+            ("ell_lane_count", self.ix.shape_sig()),
+            lambda: make_lane_count_kernel(self.ix))
+        with tracing.span("tpu.kernel", kind="ell_lane_count",
+                          width=self.B):
+            out_dev = kern(self.fp)
+        return _LaneCount(self, out_dev, list(lanes))
+
     def clear(self, lanes) -> None:
         """Zero the freed lanes' bits in both carriers — the seat-map
         half of a leave/evict; the ledger hands the lanes out again
@@ -4206,6 +4260,35 @@ class _LaneFetch:
             self.cols_of)
         self.unpack_leavers = len(self.leavers)
         return outs
+
+
+class _LaneCount:
+    """Zero-arg resolver of one leave cohort's per-lane count -> the
+    counting leavers' numbers, in the order of ``lanes``.  The wait for
+    the count program (it sits behind the hop that made the frontier)
+    and the read of its B int32 are one ``tpu.count`` span; ``t_done``
+    is the stamp the pump splits its fetch wait at
+    (graph/batch_dispatch.py _finish: count_us)."""
+
+    __slots__ = ("session", "out_dev", "lanes", "t_done")
+
+    def __init__(self, session, out_dev, lanes):
+        self.session = session
+        self.out_dev = out_dev
+        self.lanes = lanes
+        self.t_done = 0.0
+
+    def __call__(self):
+        import time
+        with tracing.span("tpu.count", leavers=len(self.lanes),
+                          bytes=4 * self.session.B):
+            self.out_dev.block_until_ready()
+            # every hop queued before this count is done (_LaneFetch)
+            self.session.read_hop_info()
+            counts = np.asarray(self.out_dev)       # [B] int32
+        self.session.rt._note_fetch(counts)
+        self.t_done = time.perf_counter()
+        return [int(counts[lane]) for lane in self.lanes]
 
 
 # ================================================== path reconstruction
@@ -4357,6 +4440,33 @@ def _all_chains(m: CsrMirror, index, depth: np.ndarray, t: int,
 
 
 # ================================================== small helpers
+def _distinct_rows(rows):
+    """YIELD DISTINCT: the first occurrence of every row, in order.
+    Rows still held as integer columns are de-duplicated in one
+    vectorised pass (the first index of each distinct row, ascending);
+    anything else (rows already materialised, a float, string or
+    dictionary column) keeps the row-by-row loop.  Same rows, same
+    order, either way."""
+    from ..graph.interim import ColumnarRows
+    cols = rows._cols if isinstance(rows, ColumnarRows) else None
+    if cols and all(
+            isinstance(c, np.ndarray) and c.ndim == 1
+            and c.dtype.kind in "iu" and c.dtype == cols[0].dtype
+            for c in cols):
+        table = cols[0] if len(cols) == 1 else np.stack(cols, axis=1)
+        first = np.unique(table, axis=0, return_index=True)[1]
+        first.sort()
+        return ColumnarRows([c[first] for c in cols], len(first))
+    seen = set()
+    out = []
+    for r in rows:
+        key = tuple(r)
+        if key not in seen:
+            seen.add(key)
+            out.append(r)
+    return out
+
+
 def _default_col_name(expr) -> str:
     from ..graph.executors.traverse import default_col_name
     return default_col_name(expr)

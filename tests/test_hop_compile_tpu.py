@@ -1,5 +1,6 @@
-"""The continuous hop program, and the batched BFS program whose
-levels take the same step (PR 30), compiled for the v5e at the
+"""The continuous hop program, the batched BFS program whose levels
+take the same step (PR 30) and the per-lane count of the resident
+frontier (PR 33), compiled for the v5e at the
 benchmark cell's real table shapes — no chip needed: the TPU's
 compiler is installed here and compiles for a described, unattached
 chip (PERF.md §6, PR 25).
@@ -112,3 +113,24 @@ def test_bfs_program_compiles_for_the_v5e_at_cell_size(one_chip):
     assert scratch <= 640e6, scratch
     # measured 4-8 s, as the sweep-only program
     assert bfs_s < 30.0, bfs_s
+
+
+def test_count_program_compiles_for_the_v5e_at_cell_size(one_chip):
+    """jit_count as graph500-s20-khop.count16 runs it a tick with
+    counting leavers (the 128-lane rung): one pass over the resident
+    frontier's vertex rows, 10.3 MB read, 512 B written, no scratch
+    to speak of — a bit plane materialised at [646 k, 128] would show
+    as 83 MB of it."""
+    import jax
+    from nebula_tpu.tpu import ell as E
+    fp = jax.ShapeDtypeStruct((S20_ROWS + 1, E.lanes_width(LANES)),
+                              np.uint8, sharding=one_chip)
+    t0 = time.perf_counter()
+    count = E.make_lane_count_kernel(_Shapes()).lower(fp).compile()
+    count_s = time.perf_counter() - t0
+    mem = count.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * LANES
+    # measured 0.29 MB
+    assert mem.temp_size_in_bytes <= 4 * 2**20, mem.temp_size_in_bytes
+    # measured 2.5 s
+    assert count_s < 20.0, count_s
