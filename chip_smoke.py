@@ -317,11 +317,12 @@ class PhaseA:
         stages["upload_s"] = time.perf_counter() - t0
 
         from nebula_tpu.tpu.runtime import HBM_MODEL
-        host_table_bytes = 2 * 4 * sum(a.size for a in ix.bucket_nbr)
+        host_table_bytes = sum(nbr.nbytes + et.nbytes
+                               for nbr, et in ix.tables_host())
         self.emit(
             "loaded", seed=cfg["seed"], vertices=n, edges=m,
             mirror_rows=int(mir.m),
-            ell_slots=int(sum(a.size for a in ix.bucket_nbr)),
+            ell_slots=int(2 * sum(a.size for a in ix.bucket_nbr)),
             ell_hub_rows=len(ix.extra_owner),
             stages={k: round(v, 2) for k, v in stages.items()},
             table_bytes_host_shapes=host_table_bytes,

@@ -4,23 +4,31 @@
 // differential-test oracle).
 //
 // Same layout contract as the Python builder:
-//   * rows grouped by DST (slots = in-edges over both stored
-//     directions), vertices relabeled so each degree bucket is
-//     contiguous (new id = rank in (bucket_D, old_id) order)
-//   * bucket width D = clamp(next_pow2(min(deg, cap)), min_d, cap)
+//   * rows grouped by DST, one table per stored direction over ONE row
+//     layout: a +etype row (u, v) is v's in-slot u (in-table), a
+//     -etype row (v, u) is v's out-slot u (out-table); both hold the
+//     etype's magnitude
+//   * vertices relabeled so each degree bucket is contiguous (new id =
+//     rank in (bucket_D, old_id) order)
+//   * bucket width D = clamp(next_pow2(min(deg, cap)), min_d, cap),
+//     deg = max(in-degree, out-degree)
 //   * hub vertices (deg > cap) get extra rows appended after all real
-//     vertices; extra_owner maps each extra row to its owner's new id
+//     vertices, the same rows in both tables; extra_owner maps each
+//     extra row to its owner's new id
 //   * slot padding: nbr = n_rows (the pinned-zero frontier row),
 //     etype = 0 (never a real etype)
 //
 // ABI (ctypes, two-phase):
 //   ell_build(src, dst, et, m, n, cap, min_d) -> handle (>=0) or -1
 //   ell_counts(handle, out int64[4])   -> {n_rows, n_extras, n_buckets,
-//                                          total_cells}
+//                                          cells of ONE table}
 //   ell_bucket_dims(handle, out int64[2*n_buckets])  (rows_b, D_b)...
-//   ell_fill(handle, perm, inv, extra_owner, nbr_flat, et_flat)
-//       fills caller-allocated buffers; bucket tables are concatenated
-//       row-major in ascending-D order inside nbr_flat/et_flat.
+//   ell_fill_split(handle, src, dst, et, m, perm, inv, extra_owner,
+//                  in_nbr, in_et, out_nbr, out_et, et_itemsize)
+//       fills caller-allocated buffers from the same edge rows; each
+//       table's buckets are concatenated row-major in ascending-D
+//       order, the etype columns in the caller's integer type
+//       (et_itemsize 1, 2 or 4 bytes).
 //   ell_free(handle)
 #include <algorithm>
 #include <cstdint>
@@ -37,8 +45,34 @@ struct EllResult {
   int64_t n_rows = 0;
   std::vector<int32_t> perm, inv, extra_owner;
   std::vector<int64_t> bucket_rows, bucket_D;
-  std::vector<int32_t> nbr_flat, et_flat;   // concatenated bucket tables
+  int64_t cap = 0, total_cells = 0;   // cells of ONE table
+  // where a vertex's slots go, the same in both tables: the cell of
+  // its main row's first slot, and of its first extra row's (hubs)
+  std::vector<int64_t> main_cell, extra_cell;
 };
+
+// ell_fill_split's slot pass, over the etype column's integer type
+template <typename E>
+void fill_slots(const EllResult& r, const int32_t* src, const int32_t* dst,
+                const int32_t* et, int64_t m, int32_t* const nbr_out[2],
+                void* const et_out[2]) {
+  std::vector<int64_t> fill[2];
+  fill[0].assign(size_t(r.n), 0);
+  fill[1].assign(size_t(r.n), 0);
+  const int64_t cap = r.cap;
+  // edge order = the stable sort by dst, per direction
+  for (int64_t i = 0; i < m; i++) {
+    int64_t v = dst[i];
+    int side = et[i] > 0 ? 0 : 1;
+    int64_t off = fill[side][v]++;
+    // a hub's slots past its main row run on through its extra rows,
+    // which sit one after the other in the cap bucket
+    int64_t cell = off < cap ? r.main_cell[v] + off
+                             : r.extra_cell[v] + (off - cap);
+    nbr_out[side][cell] = r.perm[src[i]];
+    static_cast<E*>(et_out[side])[cell] = E(et[i] > 0 ? et[i] : -et[i]);
+  }
+}
 
 std::mutex g_mu;
 std::map<int64_t, EllResult*> g_results;
@@ -73,11 +107,14 @@ int64_t ell_build(const int32_t* src, const int32_t* dst,
     return g_next++;
   }
 
-  // order edges by dst (stable; counting sort via per-vertex offsets)
+  // per-direction degrees; a vertex is as wide as its larger side
+  std::vector<int64_t> deg_side[2];
+  deg_side[0].assign(n, 0);
+  deg_side[1].assign(n, 0);
+  for (int64_t i = 0; i < m; i++) deg_side[et[i] > 0 ? 0 : 1][dst[i]]++;
   std::vector<int64_t> deg(n, 0);
-  for (int64_t i = 0; i < m; i++) deg[dst[i]]++;
-  std::vector<int64_t> row_start(n + 1, 0);
-  for (int64_t v = 0; v < n; v++) row_start[v + 1] = row_start[v] + deg[v];
+  for (int64_t v = 0; v < n; v++)
+    deg[v] = std::max(deg_side[0][v], deg_side[1][v]);
 
   // bucket width per vertex + relabeling (stable sort by D, old id)
   std::vector<int64_t> D_v(n);
@@ -128,27 +165,17 @@ int64_t ell_build(const int32_t* src, const int32_t* dst,
     r->bucket_rows.push_back(rows_of[D]);
     r->bucket_D.push_back(D);
   }
-  int32_t sentinel = int32_t(r->n_rows);
-  r->nbr_flat.assign(total_cells, sentinel);
-  r->et_flat.assign(total_cells, 0);
-
-  // fill slots: bucket-local row = global row - row_base[D]
-  std::vector<int64_t> fill(n, 0);
-  for (int64_t i = 0; i < m; i++) {
-    int64_t v = dst[i];
-    int64_t off = fill[v]++;
-    int64_t k_of = off / cap;
-    int64_t col = (k_of == 0) ? off : off % cap;
+  r->cap = cap;
+  r->total_cells = total_cells;
+  r->main_cell.resize(n);
+  r->extra_cell.assign(n, 0);
+  for (int64_t v = 0; v < n; v++) {
     int64_t D = D_v[v];
-    int64_t grow = (k_of == 0) ? int64_t(r->perm[v])
-                               : first_extra[v] + k_of - 1;
-    // extra rows sit in the cap bucket after its real vertices
-    int64_t base = (k_of == 0) ? row_base[D] : row_base[cap];
-    int64_t local = grow - ((k_of == 0) ? base : row_base[cap]);
-    int64_t cell = cell_base[(k_of == 0) ? D : cap]
-        + local * ((k_of == 0) ? D : cap) + col;
-    r->nbr_flat[size_t(cell)] = r->perm[src[i]];
-    r->et_flat[size_t(cell)] = et[i];
+    r->main_cell[v] = cell_base[D] + (int64_t(r->perm[v]) - row_base[D]) * D;
+    // a non-hub never reaches its extra cell: off < D <= cap
+    if (deg[v] > cap)
+      r->extra_cell[v] =
+          cell_base[cap] + (first_extra[v] - row_base[cap]) * cap;
   }
 
   std::lock_guard<std::mutex> lk(g_mu);
@@ -164,7 +191,7 @@ int64_t ell_counts(int64_t handle, int64_t* out4) {
   out4[0] = r->n_rows;
   out4[1] = int64_t(r->extra_owner.size());
   out4[2] = int64_t(r->bucket_D.size());
-  out4[3] = int64_t(r->nbr_flat.size());
+  out4[3] = r->total_cells;
   return 0;
 }
 
@@ -180,9 +207,11 @@ int64_t ell_bucket_dims(int64_t handle, int64_t* out) {
   return 0;
 }
 
-int64_t ell_fill(int64_t handle, int32_t* perm, int32_t* inv,
-                 int32_t* extra_owner, int32_t* nbr_flat,
-                 int32_t* et_flat) {
+int64_t ell_fill_split(int64_t handle, const int32_t* src,
+                       const int32_t* dst, const int32_t* et, int64_t m,
+                       int32_t* perm, int32_t* inv, int32_t* extra_owner,
+                       int32_t* in_nbr, void* in_et, int32_t* out_nbr,
+                       void* out_et, int64_t et_itemsize) {
   std::lock_guard<std::mutex> lk(g_mu);
   auto it = g_results.find(handle);
   if (it == g_results.end()) return -1;
@@ -192,10 +221,23 @@ int64_t ell_fill(int64_t handle, int32_t* perm, int32_t* inv,
   if (!r->extra_owner.empty())
     std::memcpy(extra_owner, r->extra_owner.data(),
                 r->extra_owner.size() * 4);
-  if (!r->nbr_flat.empty()) {
-    std::memcpy(nbr_flat, r->nbr_flat.data(), r->nbr_flat.size() * 4);
-    std::memcpy(et_flat, r->et_flat.data(), r->et_flat.size() * 4);
+  // padding first, straight into the caller's buffers: the sentinel
+  // row, etype 0
+  int32_t* const nbr_out[2] = {in_nbr, out_nbr};
+  void* const et_out[2] = {in_et, out_et};
+  for (int side = 0; side < 2; side++) {
+    std::fill(nbr_out[side], nbr_out[side] + r->total_cells,
+              int32_t(r->n_rows));
+    std::memset(et_out[side], 0, size_t(r->total_cells * et_itemsize));
   }
+  if (et_itemsize == 1)
+    fill_slots<int8_t>(*r, src, dst, et, m, nbr_out, et_out);
+  else if (et_itemsize == 2)
+    fill_slots<int16_t>(*r, src, dst, et, m, nbr_out, et_out);
+  else if (et_itemsize == 4)
+    fill_slots<int32_t>(*r, src, dst, et, m, nbr_out, et_out);
+  else
+    return -1;
   return 0;
 }
 

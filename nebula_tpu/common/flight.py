@@ -141,7 +141,8 @@ class FlightRecorder:
         pump's wait for and read of it: the head of fetch_wait_us),
         the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
-        the live slot rows; hop_slots the ELL slots they visited),
+        the live slot rows; hop_slots the ELL slots they visited;
+        hop_onesided those that read one direction's table only),
         idle gap since the previous tick, mirror generation, tick wall
         micros."""
         rec = {"kind": "tick", "stream": int(stream)}

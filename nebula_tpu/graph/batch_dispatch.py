@@ -1038,7 +1038,8 @@ class _ContinuousStream:
             # session has read since the last record: the fetch reads
             # it where it has just waited (tpu/runtime.py _LaneFetch),
             # this call takes in what else is ready.  Never a wait
-            hop_reads, hop_sparse, hop_slots = sess.hop_reads()
+            hop_reads, hop_sparse, hop_slots, hop_onesided = \
+                sess.hop_reads()
             parts = [0] * 5     # fetch_wait, d2h, unpack, rows, handover
             for stamps, _n, _met in finishes:
                 for i in range(5):
@@ -1061,7 +1062,7 @@ class _ContinuousStream:
                 **met,
                 handed=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
-                hop_slots=hop_slots,
+                hop_slots=hop_slots, hop_onesided=hop_onesided,
                 idle_us=int(idle_us),
                 dur_us=int(dur * 1e6),
                 generation=int(getattr(getattr(sess, "m", None),

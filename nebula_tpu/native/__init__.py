@@ -140,16 +140,18 @@ def lib() -> Optional[ctypes.CDLL]:
               ctypes.c_int64, u8p, ctypes.c_int64])
 
     # ELL slot-table builder (tpu/ell.py fast path). Guarded: a stale
-    # .so built before ell_build.cc existed must degrade this feature,
-    # not break the whole native layer with AttributeError
-    if hasattr(L, "ell_build"):
+    # .so built before ell_build.cc filled one table a direction must
+    # degrade this feature, not break the whole native layer with
+    # AttributeError
+    if hasattr(L, "ell_fill_split"):
         _sig(L.ell_build, ctypes.c_int64,
              [i32p, i32p, i32p, ctypes.c_int64, ctypes.c_int64,
               ctypes.c_int64, ctypes.c_int64])
         _sig(L.ell_counts, ctypes.c_int64, [ctypes.c_int64, i64p])
         _sig(L.ell_bucket_dims, ctypes.c_int64, [ctypes.c_int64, i64p])
-        _sig(L.ell_fill, ctypes.c_int64,
-             [ctypes.c_int64, i32p, i32p, i32p, i32p, i32p])
+        _sig(L.ell_fill_split, ctypes.c_int64,
+             [ctypes.c_int64, i32p, i32p, i32p, ctypes.c_int64,
+              i32p, i32p, i32p, i32p, vp, i32p, vp, ctypes.c_int64])
         _sig(L.ell_free, None, [ctypes.c_int64])
 
     # run gather (tpu/runtime.py _EdgeRuns). Guarded like ell_build

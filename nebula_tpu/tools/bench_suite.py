@@ -348,17 +348,17 @@ def timeit(fn, reps=3):
     return (time.perf_counter() - t0) / reps
 
 # ---- replicated-frontier dense: sharded vs 1-device, SAME graph ----
-nbrs, ets, reals = shard_ell(mesh, "parts", ix)
+shards, reals = shard_ell(mesh, "parts", ix)
 go8 = make_sharded_batched_go_kernel(mesh, "parts", ix, steps, (1,),
-                                     nbrs, ets, reals)
+                                     reals)
 eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
 tables = ix.kernel_args()[1:]
 single = make_batched_go_lanes_kernel(ix, steps, (1,))
 ref = single(f0p, eslot, hrows, *tables)
 np.testing.assert_array_equal(
-    np.asarray(go8(f0p, eslot, hrows, *nbrs, *ets)), np.asarray(ref))
+    np.asarray(go8(f0p, eslot, hrows, *shards)), np.asarray(ref))
 out["dense_sharded_dispatch_s"] = round(
-    timeit(lambda: go8(f0p, eslot, hrows, *nbrs, *ets)), 3)
+    timeit(lambda: go8(f0p, eslot, hrows, *shards)), 3)
 out["dense_1dev_dispatch_s"] = round(
     timeit(lambda: single(f0p, eslot, hrows, *tables)), 3)
 
@@ -381,7 +381,7 @@ assert placed is not None
 sargs = sharded_device_args(mesh, "parts", sh)
 def run8():
     return kern8(jnp.asarray(placed[0]), jnp.asarray(placed[1]),
-                 sargs[0], sargs[1], sargs[2], *sargs[3], *sargs[4])
+                 sargs[0], sargs[1], sargs[2], *sargs[3])
 ovf, oq, ou = sharded_sparse_pairs(np.asarray(run8()))
 assert not ovf, "sharded sparse caps must hold the 2-hop frontier"
 got = np.zeros((persons, B), bool)
@@ -407,11 +407,13 @@ _c, ovf1, _q, _u = sparse_go_pairs(kern1, np.asarray(run1()))
 out["sparse_1dev_dispatch_s"] = None if ovf1 else round(timeit(run1), 3)
 
 # per-device memory: the sharded-sparse design holds graph/k per chip
-# and NO dense frontier anywhere
-slots = sum(b.size for b in ix.bucket_nbr)
+# and NO dense frontier anywhere (slots of both directions' tables)
+slots = 2 * sum(b.size for b in ix.bucket_nbr)
 out["slots_total"] = int(slots)
-out["slots_per_device"] = int(sum(a.shape[1] * a.shape[2]
-                                  for a in sh.nbr_s))
+nb = len(ix.bucket_nbr)           # tables_s: in nbr, in et, out nbr, out et
+out["slots_per_device"] = int(sum(
+    a.shape[1] * a.shape[2]
+    for a in sh.tables_s[:nb] + sh.tables_s[2 * nb:3 * nb]))
 out["dense_frontier_bytes_per_device"] = int((ix.n_rows + 1) * (B // 8))
 out["sparse_frontier_bytes_per_device"] = int(8 * caps[-1])
 print(json.dumps(out))

@@ -391,7 +391,7 @@ def mesh_traffic_table(fx, registry, mesh_model: dict,
         hops = max(fx.steps - 1, 1)
         per_hop = total // hops
         compute = dense_hop_bytes(
-            fx.ell, lanes_width(max(fx.widths)), fx.steps) \
+            fx.ell, fx.etypes, lanes_width(max(fx.widths)), fx.steps) \
             // hops // k
         link_s = per_hop / (mesh_model["ici_gbps"] * 1e9)
         comp_s = compute / (mesh_model["hbm_gbps"] * 1e9)

@@ -208,14 +208,15 @@ def main():
         t0 = time.perf_counter()
         ix = rt.ell(mir)
         out["t_ell_s"] = round(time.perf_counter() - t0, 1)
-        slots = sum(a.size for a in ix.bucket_nbr)
+        slots = 2 * sum(a.size for a in ix.bucket_nbr)   # both tables
         out["ell_slots"] = int(slots)
         out["ell_extra_rows"] = len(ix.extra_owner)
         log(f"ELL build: {out['t_ell_s']}s ({slots:,} slots, "
             f"{len(ix.extra_owner):,} hub extra rows)")
         t0 = time.perf_counter()
         ix.device_arrays()
-        table_bytes = sum(a.size * 4 for a in ix.bucket_nbr) * 2
+        table_bytes = sum(nbr.nbytes + et.nbytes
+                          for nbr, et in ix.tables_host())
         out["t_upload_s"] = round(time.perf_counter() - t0, 1)
         out["table_bytes"] = int(table_bytes)
         out["table_bytes_per_edge"] = round(table_bytes / m, 1)

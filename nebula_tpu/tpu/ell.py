@@ -24,17 +24,30 @@ Structure built host-side from the CsrMirror (build_ell):
   * vertices are **relabeled** so that all vertices of one degree
     bucket are contiguous (new id = rank in (bucket_D, old_id) order);
     bucket outputs then concatenate into the next frontier with zero
-    data movement.
-  * per bucket a dense slot table ``nbr[rows, D]`` holds *new* ids of
-    the vertex's neighbors over BOTH edge directions (the mirror stores
-    a reverse edge under -etype, csr.py), padded with a sentinel row
-    ``n`` whose frontier value is pinned to 0; ``et[rows, D]`` holds the
-    signed etype of each slot so one static mask per query selects the
-    OVER set (padding uses etype 0 which is never a real etype).
-  * hub vertices (degree > cap) own several rows in the largest bucket;
-    the extra rows are appended after all real vertices and OR-merged
-    back into their owner row by a tiny scatter (hubs are rare, the
-    scatter is O(#extra rows)).
+    data movement.  A vertex's bucket is the power of two over the
+    LARGER of its in- and out-degree (floored at ``min_d``, capped at
+    ``cap``).
+  * the slots live in TWO tables over that one row layout, one per
+    stored direction (the mirror stores a reverse edge under -etype,
+    csr.py), so a step reads only the direction it asks for:
+      - the **in-table** ``bucket_nbr[b]`` / ``bucket_et[b]``
+        ``[rows_b, D_b]``: row v holds the *new* ids u of its in-edges
+        u -> v (the mirror's +etype rows).  A pull over +t gathers
+        these; a push over -t (REVERSELY) scatters to them;
+      - the **out-table** ``out_nbr[b]`` / ``out_et[b]``, same shapes:
+        row u holds the targets v of its out-edges (the -etype rows).
+        A push over +t scatters to these; a pull over -t gathers them.
+    Both pad with a sentinel row ``n_rows`` whose frontier value is
+    pinned to 0.  The table says the sign, so ``et`` holds the etype's
+    MAGNITUDE, in the narrowest signed integer type that holds the
+    mirror's largest (int8 up to 127, int16, int32; read off the input
+    at build, part of shape_sig); padding is 0, never a real etype, so
+    one static mask per query selects the OVER set.
+  * hub vertices (larger degree > cap) own several rows in the largest
+    bucket, the SAME extra rows in both tables; the extra rows are
+    appended after all real vertices and OR-merged back into their
+    owner row by a tiny scatter (hubs are rare, the scatter is
+    O(#extra rows)).
 
 The reference's analogue of this file is the storaged read hot loop
 (QueryBoundProcessor::processVertex + QueryBaseProcessor.inl:336-405
@@ -57,12 +70,23 @@ def _next_pow2(x: np.ndarray) -> np.ndarray:
     return (1 << np.ceil(np.log2(x)).astype(np.int64)).astype(np.int64)
 
 
+def _etype_dtype(edge_etype: np.ndarray) -> np.dtype:
+    """Narrowest signed integer type that holds the largest |etype|."""
+    top = max(int(np.max(edge_etype)), -int(np.min(edge_etype))) \
+        if len(edge_etype) else 0
+    for dt in (np.int8, np.int16):
+        if top <= np.iinfo(dt).max:
+            return np.dtype(dt)
+    return np.dtype(np.int32)
+
+
 class EllIndex:
-    """Degree-bucketed in-slot table over relabeled dense vertex ids."""
+    """Degree-bucketed slot tables, one per stored direction, over one
+    relabeling of dense vertex ids."""
 
     __slots__ = ("n", "m", "perm", "inv", "bucket_D", "bucket_nbr",
-                 "bucket_et", "extra_owner", "n_rows", "_device",
-                 "_n_hubs")
+                 "bucket_et", "out_nbr", "out_et", "extra_owner",
+                 "n_rows", "_device", "_n_hubs")
 
     def __init__(self):
         self.n = 0                     # real vertices
@@ -70,8 +94,12 @@ class EllIndex:
         self.perm = np.zeros(0, np.int32)   # old dense id -> new id
         self.inv = np.zeros(0, np.int32)    # new id -> old dense id
         self.bucket_D: List[int] = []       # slot width per bucket (asc)
+        # in-table: row v's in-edge sources (what a forward pull sweeps)
         self.bucket_nbr: List[np.ndarray] = []  # [rows_b, D_b] new ids
-        self.bucket_et: List[np.ndarray] = []   # [rows_b, D_b] signed etype
+        self.bucket_et: List[np.ndarray] = []   # [rows_b, D_b] |etype|
+        # out-table: row u's out-edge targets, same shapes
+        self.out_nbr: List[np.ndarray] = []
+        self.out_et: List[np.ndarray] = []
         self.extra_owner = np.zeros(0, np.int32)  # hub extra row -> new id
         self.n_rows = 0                # n + len(extra_owner)
         self._device = None            # lazy jnp copies of bucket arrays
@@ -83,11 +111,14 @@ class EllIndex:
               edge_etype: np.ndarray, n: int, cap: int = 512,
               min_d: int = 8, use_native: bool = True,
               growth_slack: int = 0) -> "EllIndex":
-        """Group the mirror's edge rows by dst into bucketed slot tables.
+        """Group the mirror's edge rows by dst into the two bucketed
+        slot tables.
 
         ``edge_*`` are the CsrMirror arrays (dense ids, signed etypes,
-        both directions present).  ``cap`` bounds slot width; vertices
-        with more slots get extra rows merged by the fix-up scatter.
+        both directions present): a +etype row (u, v) is v's in-slot u,
+        a -etype row (v, u) is v's out-slot u.  ``cap`` bounds slot
+        width; vertices with more slots in either direction get extra
+        rows merged by the fix-up scatter.
         ``min_d`` floors the bucket width — fewer buckets compile into
         fewer fori kernels at the price of a little padding.
         ``growth_slack`` appends that many SPARE all-sentinel rows to
@@ -103,9 +134,10 @@ class EllIndex:
         and the differential-test oracle (both produce identical
         arrays, tests/test_ell.py::test_native_builder_identical).
         """
+        et_dt = _etype_dtype(edge_etype)
         if use_native:
             ell = EllIndex._build_native(edge_src, edge_dst, edge_etype,
-                                         n, cap, min_d)
+                                         n, cap, min_d, et_dt)
             if ell is not None:
                 return _append_growth_spares(ell, growth_slack)
         ell = EllIndex()
@@ -116,14 +148,19 @@ class EllIndex:
             ell.n_rows = 0
             return ell
 
-        # rows are grouped by DST (slots = in-edges): a hop pulls
-        # next[v] = max over in-slots of f[src], so ``deg`` here is the
-        # in-degree over both stored directions.
+        # rows are grouped by DST, one stable sort for both tables: a
+        # +etype row is the owner's in-slot, a -etype row its out-slot
         order = np.argsort(edge_dst, kind="stable")
         es = np.asarray(edge_dst, np.int64)[order]   # row owner (dst)
         ed = np.asarray(edge_src, np.int64)[order]   # slot neighbor (src)
-        ee = np.asarray(edge_etype, np.int32)[order]
-        deg = np.bincount(es, minlength=n).astype(np.int64)
+        ee = np.asarray(edge_etype, np.int64)[order]
+        inward = ee > 0
+        sides = []
+        for sel in (inward, ~inward):
+            sides.append((es[sel], ed[sel], np.abs(ee[sel]),
+                          np.bincount(es[sel], minlength=n)
+                          .astype(np.int64)))
+        deg = np.maximum(sides[0][3], sides[1][3])
 
         cap = max(cap, min_d)
         per_row = np.minimum(deg, cap)
@@ -134,7 +171,8 @@ class EllIndex:
         ell.perm = perm
         ell.inv = np.asarray(vorder, np.int32)
 
-        # hub extra rows (degree > cap), appended after all real vertices
+        # hub extra rows (larger degree > cap), appended after all real
+        # vertices, the same rows in both tables
         hub_vs = np.nonzero(deg > cap)[0]
         n_extra_v = np.zeros(n, dtype=np.int64)          # extra rows per v
         n_extra_v[hub_vs] = np.ceil(deg[hub_vs] / cap).astype(np.int64) - 1
@@ -146,36 +184,39 @@ class EllIndex:
             .astype(np.int32)
         ell.n_rows = n + n_extras
 
-        # per-edge (row, col) destination slot
-        row_start = np.concatenate([[0], np.cumsum(deg)])
-        off = np.arange(m, dtype=np.int64) - row_start[es]
-        k_of = off // cap
-        col = np.where(k_of == 0, off, off % cap).astype(np.int64)
-        row = np.where(k_of == 0, perm[es].astype(np.int64),
-                       first_extra[es] + k_of - 1)
-
         # bucket layout: new ids are contiguous per D (vorder sorted by D_v)
         Ds = sorted(set(D_v.tolist()))
         sentinel = np.int32(ell.n_rows)  # frontier row pinned to 0
         D_new = D_v[vorder]              # slot width per new id
-        bstart = 0
-        for D in Ds:
-            nb = int(np.count_nonzero(D_new == D))
-            if D == cap:
-                nb += n_extras           # extras live in the cap bucket
-            nbr = np.full((nb, D), sentinel, dtype=np.int32)
-            et = np.zeros((nb, D), dtype=np.int32)
-            # buckets are contiguous in new-id order, and extra rows
-            # (>= n) all belong to the last (cap) bucket
-            sel = np.nonzero((row >= bstart) & (row < bstart + nb))[0]
-            if len(sel):
-                flat = (row[sel] - bstart) * D + col[sel]
-                nbr.reshape(-1)[flat] = perm[ed[sel]]
-                et.reshape(-1)[flat] = ee[sel]
-            ell.bucket_D.append(int(D))
-            ell.bucket_nbr.append(nbr)
-            ell.bucket_et.append(et)
-            bstart += nb
+        ell.bucket_D = [int(D) for D in Ds]
+        # rows per bucket, the same in both tables; extras live in the
+        # cap bucket
+        bucket_rows = [int(np.count_nonzero(D_new == D))
+                       + (n_extras if D == cap else 0) for D in Ds]
+        for (s_es, s_ed, s_ee, s_deg), nbr_out, et_out in zip(
+                sides, (ell.bucket_nbr, ell.out_nbr),
+                (ell.bucket_et, ell.out_et)):
+            # per-edge (row, col) destination slot in this direction
+            row_start = np.concatenate([[0], np.cumsum(s_deg)])
+            off = np.arange(len(s_es), dtype=np.int64) - row_start[s_es]
+            k_of = off // cap
+            col = np.where(k_of == 0, off, off % cap).astype(np.int64)
+            row = np.where(k_of == 0, perm[s_es].astype(np.int64),
+                           first_extra[s_es] + k_of - 1)
+            bstart = 0
+            for D, nb in zip(Ds, bucket_rows):
+                nbr = np.full((nb, D), sentinel, dtype=np.int32)
+                et = np.zeros((nb, D), dtype=et_dt)
+                # buckets are contiguous in new-id order, and extra rows
+                # (>= n) all belong to the last (cap) bucket
+                sel = np.nonzero((row >= bstart) & (row < bstart + nb))[0]
+                if len(sel):
+                    flat = (row[sel] - bstart) * D + col[sel]
+                    nbr.reshape(-1)[flat] = perm[s_ed[sel]]
+                    et.reshape(-1)[flat] = s_ee[sel]
+                nbr_out.append(nbr)
+                et_out.append(et)
+                bstart += nb
         return _append_growth_spares(ell, growth_slack)
 
     def spare_sentinel(self) -> int:
@@ -187,27 +228,25 @@ class EllIndex:
 
     @staticmethod
     def _build_native(edge_src, edge_dst, edge_etype, n: int, cap: int,
-                      min_d: int) -> Optional["EllIndex"]:
+                      min_d: int, et_dt: np.dtype) -> Optional["EllIndex"]:
         """C++ builder via ctypes; None when the library is unavailable
         (callers fall back to the numpy path)."""
         import ctypes
         from ..native import lib
         L = lib()
-        if L is None or not hasattr(L, "ell_build"):
+        if L is None or not hasattr(L, "ell_fill_split"):
             return None              # absent or stale .so: numpy path
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
 
         def p32(a):
-            return np.ascontiguousarray(a, dtype=np.int32) \
-                .ctypes.data_as(i32p)
+            return a.ctypes.data_as(i32p)
 
         src = np.ascontiguousarray(edge_src, dtype=np.int32)
         dst = np.ascontiguousarray(edge_dst, dtype=np.int32)
         et = np.ascontiguousarray(edge_etype, dtype=np.int32)
         m = len(src)
-        h = L.ell_build(src.ctypes.data_as(i32p), dst.ctypes.data_as(i32p),
-                        et.ctypes.data_as(i32p), m, n, cap, min_d)
+        h = L.ell_build(p32(src), p32(dst), p32(et), m, n, cap, min_d)
         if h < 0:
             return None
         try:
@@ -226,11 +265,17 @@ class EllIndex:
             perm = np.zeros(n, dtype=np.int32)
             inv = np.zeros(n, dtype=np.int32)
             owner = np.zeros(max(n_extras, 1), dtype=np.int32)
-            nbr_flat = np.zeros(max(total_cells, 1), dtype=np.int32)
-            et_flat = np.zeros(max(total_cells, 1), dtype=np.int32)
-            L.ell_fill(h, p32(perm), p32(inv), owner.ctypes.data_as(i32p),
-                       nbr_flat.ctypes.data_as(i32p),
-                       et_flat.ctypes.data_as(i32p))
+            # one table's cells, each direction; the builder writes the
+            # padding too
+            cells = max(total_cells, 1)
+            nbrs = [np.empty(cells, dtype=np.int32) for _ in range(2)]
+            ets = [np.empty(cells, dtype=et_dt) for _ in range(2)]
+            if L.ell_fill_split(h, p32(src), p32(dst), p32(et), m,
+                                p32(perm), p32(inv), p32(owner),
+                                p32(nbrs[0]), ets[0].ctypes.data,
+                                p32(nbrs[1]), ets[1].ctypes.data,
+                                et_dt.itemsize) != 0:
+                return None
             ell.perm, ell.inv = perm, inv
             ell.extra_owner = owner[:n_extras]
             off = 0
@@ -238,10 +283,11 @@ class EllIndex:
                 rows, D = int(dims[2 * b]), int(dims[2 * b + 1])
                 cells = rows * D
                 ell.bucket_D.append(D)
-                ell.bucket_nbr.append(
-                    nbr_flat[off:off + cells].reshape(rows, D))
-                ell.bucket_et.append(
-                    et_flat[off:off + cells].reshape(rows, D))
+                for out, flat in ((ell.bucket_nbr, nbrs[0]),
+                                  (ell.bucket_et, ets[0]),
+                                  (ell.out_nbr, nbrs[1]),
+                                  (ell.out_et, ets[1])):
+                    out.append(flat[off:off + cells].reshape(rows, D))
                 off += cells
             return ell
         finally:
@@ -249,14 +295,15 @@ class EllIndex:
 
     # -------------------------------------------------------------- device
     def device_arrays(self):
-        """jnp copies of the bucket tables (cached)."""
+        """jnp copies of every resident array (cached): (in-table nbr,
+        in-table et, out-table nbr, out-table et, extra_owner)."""
         if self._device is None:
             import jax.numpy as jnp
-            self._device = (
-                [jnp.asarray(a) for a in self.bucket_nbr],
-                [jnp.asarray(a) for a in self.bucket_et],
-                jnp.asarray(self.extra_owner),
-            )
+            self._device = tuple(
+                [jnp.asarray(a) for a in group]
+                for group in (self.bucket_nbr, self.bucket_et,
+                              self.out_nbr, self.out_et)) \
+                + (jnp.asarray(self.extra_owner),)
         return self._device
 
     # ----------------------------------------------------------- frontiers
@@ -286,7 +333,15 @@ class EllIndex:
         instead of recompiling; see the kernel builders below)."""
         return (self.n, self.n_rows, len(self.extra_owner), self.n_hubs,
                 tuple((nbr.shape[0], nbr.shape[1])
-                      for nbr in self.bucket_nbr))
+                      for nbr in self.bucket_nbr),
+                self.et_dtype.name)
+
+    @property
+    def et_dtype(self) -> np.dtype:
+        """The etype columns' integer type (part of shape_sig: a table
+        argument's dtype is part of the compiled program)."""
+        return self.bucket_et[0].dtype if self.bucket_et \
+            else np.dtype(np.int8)
 
     @property
     def n_hubs(self) -> int:
@@ -330,11 +385,20 @@ class EllIndex:
             e0[owners[real]] = (self.n + first[real]).astype(np.int32)
         return ecnt, e0
 
+    def tables_host(self) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """Every (nbr, et) bucket pair, the in-table's buckets then the
+        out-table's: table index ``side * n_buckets + b`` (what an
+        absorb plan keys its replacement rows by)."""
+        return list(zip(self.bucket_nbr, self.bucket_et)) \
+            + list(zip(self.out_nbr, self.out_et))
+
     def kernel_args(self):
         """The device arrays every args-style kernel takes positionally:
-        (owner, *bucket_nbr, *bucket_et)."""
-        nbr_dev, et_dev, owner_dev = self.device_arrays()
-        return (owner_dev, *nbr_dev, *et_dev)
+        (owner, *bucket_nbr, *bucket_et, *out_nbr, *out_et) — the
+        ``tables`` the kernels split with _read_sides."""
+        nbr_dev, et_dev, onbr_dev, oet_dev, owner_dev = \
+            self.device_arrays()
+        return (owner_dev, *nbr_dev, *et_dev, *onbr_dev, *oet_dev)
 
 
 # ====================================================================
@@ -345,11 +409,48 @@ class EllIndex:
 # over the tables embeds ~100 MB as HLO constants — measured 64 s
 # compiles and 6x slower execution on v5e.)
 # ====================================================================
-def _etype_ok(jnp, et_col, etypes: Tuple[int, ...]):
+def _etype_ok(jnp, et_col, mags: Tuple[int, ...]):
+    """bool like ``et_col``: the slot's etype magnitude is one of
+    ``mags``, compared in the column's own integer type (a magnitude
+    the type cannot hold matches no slot of this mirror)."""
     ok = jnp.zeros(et_col.shape, dtype=bool)
-    for t in etypes:
-        ok = ok | (et_col == t)
+    top = np.iinfo(et_col.dtype).max
+    for t in mags:
+        if 0 < t <= top:
+            ok = ok | (et_col == np.asarray(t, et_col.dtype))
     return ok
+
+
+def _split_signs(etypes: Tuple[int, ...]):
+    """(magnitudes of the positive members, of the negative ones)."""
+    return (tuple(t for t in etypes if t > 0),
+            tuple(-t for t in etypes if t < 0))
+
+
+def sides_read(etypes: Tuple[int, ...]) -> int:
+    """How many of the two tables a step over ``etypes`` reads: one
+    for a one-signed OVER set (every statement the grammar makes:
+    REVERSELY flips the whole set), two for a mixed-sign one."""
+    return sum(1 for mags in _split_signs(etypes) if mags)
+
+
+def _read_sides(etypes: Tuple[int, ...], tables, nb: int,
+                push: bool = False):
+    """The tables a frontier step over ``etypes`` reads, as
+    (nbrs, ets, magnitudes) per table.  ``tables`` =
+    (*in_nbr, *in_et, *out_nbr, *out_et), ``nb`` buckets each
+    (EllIndex.kernel_args()[1:]).  A PULL over +t gathers the sources
+    of in-edges (in-table), over -t those of out-edges (out-table); a
+    PUSH — and every pair-list kernel, which expands a frontier row
+    into its neighbours — takes its targets from the opposite table.
+    A table with no member in ``etypes`` is not read at all."""
+    ins = (tables[:nb], tables[nb:2 * nb])
+    outs = (tables[2 * nb:3 * nb], tables[3 * nb:4 * nb])
+    if push:
+        ins, outs = outs, ins
+    return [(*table, mags)
+            for table, mags in zip((ins, outs), _split_signs(etypes))
+            if mags]
 
 
 def _segmented_hub_iota(jnp, cnt_raw, e0_vals, qid, EX: int,
@@ -468,13 +569,14 @@ def _scatter_or_rows(jnp, nxt, vals, slot, rows):
     return nxt.at[rows].set(upd, mode="drop")
 
 
-def _bucket_expand_packed(jnp, jax, fp, nbr, et, etypes):
-    """Expand one bucket: OR over D in-slot word gathers, the OVER mask
-    a 0/1 uint8 multiply per word.  THE hop inner loop — shared by the
-    single-chip and sharded kernels so their semantics cannot skew."""
+def _bucket_expand_packed(jnp, jax, fp, nbr, et, mags):
+    """Expand one bucket of one table: OR over D slot word gathers, the
+    OVER mask a 0/1 uint8 multiply per word.  THE hop inner loop —
+    shared by the single-chip and sharded kernels so their semantics
+    cannot skew."""
     nb, D = nbr.shape
     nbr_T = nbr.T
-    ok_T = _etype_ok(jnp, et, etypes).T.astype(jnp.uint8)
+    ok_T = _etype_ok(jnp, et, mags).T.astype(jnp.uint8)
 
     def body(j, acc):
         g = fp[nbr_T[j]]                   # [nb, W] word-gather
@@ -484,18 +586,30 @@ def _bucket_expand_packed(jnp, jax, fp, nbr, et, etypes):
     return jax.lax.fori_loop(0, D, body, acc0)
 
 
-def _hop_body_packed(jnp, jax, n: int, n_extras: int,
-                     etypes: Tuple[int, ...], nbr_dev, et_dev,
-                     eslot, hrows, fp):
-    """One packed frontier advance: fp [n_rows+1, W] uint8 -> same.
-    Each bucket's expansion and the hub merge sit in a named scope, so
-    a device trace's op names say which bucket a loop or fusion is
-    (``hop/bucket_w<D>``, ``hop/hub_merge`` in the HLO op_name)."""
+def _buckets_expand_packed(jnp, jax, fp, sides):
+    """Per bucket, the OR of its expansion over each table in ``sides``
+    (_read_sides: one table for a one-signed OVER set).  Each bucket
+    sits in a named scope, so a device trace's op names say which
+    bucket a loop or fusion is (``hop/bucket_w<D>`` in the HLO
+    op_name)."""
     outs = []
-    for nbr, et in zip(nbr_dev, et_dev):
-        with jax.named_scope(f"hop/bucket_w{nbr.shape[1]}"):
-            outs.append(_bucket_expand_packed(jnp, jax, fp, nbr, et,
-                                              etypes))
+    for b in range(len(sides[0][0]) if sides else 0):
+        with jax.named_scope(f"hop/bucket_w{sides[0][0][b].shape[1]}"):
+            acc = None
+            for nbrs, ets, mags in sides:
+                o = _bucket_expand_packed(jnp, jax, fp, nbrs[b], ets[b],
+                                          mags)
+                acc = o if acc is None else acc | o
+            outs.append(acc)
+    return outs
+
+
+def _hop_body_packed(jnp, jax, n: int, n_extras: int, sides,
+                     eslot, hrows, fp):
+    """One packed frontier advance by PULL: fp [n_rows+1, W] uint8 ->
+    same, over the tables in ``sides`` (_read_sides).  The hub merge
+    sits in a named scope of its own (``hop/hub_merge``)."""
+    outs = _buckets_expand_packed(jnp, jax, fp, sides)
     if not outs:
         return jnp.zeros_like(fp)
     nxt = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
@@ -519,8 +633,10 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
     after ``steps-1`` advances (lane q of word j is query j*8+q;
     unpack_lanes_host inverts; the final hop's edge set is
     frontier[src] & etype_ok, materialised by the caller — same split
-    as kernels._go_body).  ``tables`` = (*bucket_nbr, *bucket_et) from
-    EllIndex.kernel_args()[1:]; only static shapes are read off
+    as kernels._go_body).  ``tables`` = (*bucket_nbr, *bucket_et,
+    *out_nbr, *out_et) from EllIndex.kernel_args()[1:], of which the
+    program reads those ``etypes`` has a member for (_read_sides); only
+    static shapes are read off
     ``ell``, so the compiled fn serves any mirror with the same
     shape_sig.  With ``upto`` the output is the OR of every depth's
     frontier (0..steps-1 — GO UPTO's pre-final-hop vertex set; one
@@ -534,15 +650,16 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
     import jax.numpy as jnp
     n, n_extras, nb = ell.n, len(ell.extra_owner), len(ell.bucket_nbr)
 
-    def advance(f0p, eslot, hrows, nbrs, ets):
+    def advance(f0p, eslot, hrows, tables):
+        sides = _read_sides(etypes, tables, nb)
+
         def one(_, f):
-            return _hop_body_packed(jnp, jax, n, n_extras, etypes,
-                                    nbrs, ets, eslot, hrows, f)
+            return _hop_body_packed(jnp, jax, n, n_extras, sides,
+                                    eslot, hrows, f)
 
         def one_acc(_, carry):
             f, acc = carry
-            nxt = _hop_body_packed(jnp, jax, n, n_extras, etypes,
-                                   nbrs, ets, eslot, hrows, f)
+            nxt = one(None, f)
             return nxt, acc | nxt
 
         if steps <= 1:
@@ -554,16 +671,14 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 
     if count:
         def go(f0p, eslot, hrows, deg, *tables):
-            nbrs, ets = tables[:nb], tables[nb:]
-            out = advance(f0p, eslot, hrows, nbrs, ets)
+            out = advance(f0p, eslot, hrows, tables)
             bits = _unpack_lanes(jnp, out).astype(jnp.int32)
             # deg is zero for hub extra rows and the pad row, so junk
             # extras never count; [R1] @ [R1, B] -> [B]
             return deg @ bits
     else:
         def go(f0p, eslot, hrows, *tables):
-            nbrs, ets = tables[:nb], tables[nb:]
-            return advance(f0p, eslot, hrows, nbrs, ets)
+            return advance(f0p, eslot, hrows, tables)
 
     # ``donate`` is the RUNTIME's dispatch configuration: _launch_dense
     # builds f0p fresh per dispatch, so handing the buffer to XLA lets
@@ -603,10 +718,13 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #                                        rows) each live row ORs its
 #                                        words into its out-neighbours'
 #                                        rows of a zeroed frontier:
-#                                        work ~ the live rows' slots;
+#                                        work ~ the live rows' slots
+#                                        in the table of its targets;
 #                                  pull  (over the budget) the full
 #                                        _hop_body_packed sweep of every
-#                                        slot of the table, unchanged.
+#                                        slot of the table of its
+#                                        sources (each direction has a
+#                                        table of its own: _read_sides).
 #                                ``info`` says which ran and what it
 #                                visited; nothing on the host chooses.
 #                                The choice lives in ONE place,
@@ -638,22 +756,29 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 # ====================================================================
 # Push budget of the continuous hop, in live SLOT ROWS (a live vertex's
 # main row plus its hub extra rows).  The push pays XLA's row scatter,
-# which the TPU runs one index after the other: 47 ns a slot on the
-# v5e (24 us a 512-wide row, 1.2 us an 8-wide one) beside 3.5 ms
-# fixed, against the pull's 2.7 ns a slot over EVERY slot of the table
-# (114 ms at 42.2 M).  4,096 rows of the widest bucket are 2.1 M
-# slots, ~100 ms: the budget is where the worst push still undercuts
-# the sweep at that table size (PERF.md §5-§6, PR 25's chip runs).  A
-# speed choice only: both branches are exact.
-HOP_PUSH_ROWS = 4096
+# which the TPU runs one index after the other: 48.5 ns a slot on the
+# v5e (24.8 us a 512-wide row; 1.5 us an 8-wide one, where the row's
+# own turn of the loop is most of it) beside 2.2 ms fixed, against the
+# pull's 2.76 ns a slot over EVERY slot of the one table it reads
+# (68.5 ms at 24.8 M).  The budget is where the WORST push, every
+# live row 512 wide, still undercuts the sweep: 2.2 ms + R x 24.8 us
+# < 68.5 ms holds to R = 2,670, and 2,048 rows (1.05 M slots, 53 ms)
+# is the power of two under it.  While a row held both directions the
+# same rule gave 4,096: a sweep of 113.4 ms at 42.2 M slots, a push of
+# 2.5 ms + 49.7 ns a slot, R < 4,360 (PERF.md §6, PR 35's chip runs;
+# PR 25 first read 114 ms and 47 ns).  A speed choice only: both
+# branches are exact.
+HOP_PUSH_ROWS = 2048
 
 # info vector of the continuous hop program (int32[3])
 HOP_INFO_SPARSE, HOP_INFO_ROWS, HOP_INFO_SLOTS = 0, 1, 2
 
 
-def table_slots(ell: EllIndex) -> int:
-    """Slots of the whole table — what one pull hop visits."""
-    return int(sum(nbr.shape[0] * nbr.shape[1] for nbr in ell.bucket_nbr))
+def table_slots(ell: EllIndex, etypes: Tuple[int, ...]) -> int:
+    """Slots one pull over ``etypes`` visits: every slot of each table
+    it reads (the two tables share their shapes)."""
+    return sides_read(etypes) * int(
+        sum(nbr.shape[0] * nbr.shape[1] for nbr in ell.bucket_nbr))
 
 
 def _set_positions(jnp, mask, cap: int, group: int = 128):
@@ -682,8 +807,7 @@ def _set_positions(jnp, mask, cap: int, group: int = 128):
     return jnp.where(g < G, gc * group + pos, jnp.int32(R))
 
 
-def _hop_push_packed(jnp, jax, n: int, n_rows: int,
-                     etypes: Tuple[int, ...], nbrs, ets, owner, fp,
+def _hop_push_packed(jnp, jax, n: int, n_rows: int, sides, owner, fp,
                      row_live, counts, cap: int):
     """One packed frontier advance by PUSH: every live slot row ORs its
     source's word row into the rows of its out-neighbours.
@@ -691,15 +815,15 @@ def _hop_push_packed(jnp, jax, n: int, n_rows: int,
     ``row_live`` bool[n_rows] marks the live slot rows (a live vertex's
     main row and its hub extra rows — ``owner`` int32[n_extras] names
     an extra row's vertex), ``counts`` their number per bucket, at most
-    ``cap`` in all.  A row's out-neighbours over t are its -t slots
-    (csr.py stores the reverse direction under -etype; the windowed
-    sparse kernels push the same way).  Targets inside one slot row
+    ``cap`` in all.  ``sides`` are the tables the targets come from
+    (_read_sides with push=True: a row's out-neighbours over +t are
+    its out-table slots of magnitude t), so a row's scatter is as wide
+    as its bucket in ONE table.  Targets inside one slot row
     take one value (old | source), so a plain set is exact there even
     where a row names a neighbour twice; rows run one after the other,
     so a target shared by two rows ORs both.  Rows >= n of the result
     stay zero (no slot points there) and the pad row is never written:
     masked and sentinel slots scatter out of range and drop."""
-    neg = tuple(-t for t in etypes)
     R1 = n_rows + 1
     # bucket b's live rows are the run [sum(counts[:b]),
     # sum(counts[:b+1])) of the ascending list
@@ -715,8 +839,9 @@ def _hop_push_packed(jnp, jax, n: int, n_rows: int,
     rows = jnp.concatenate([rows, jnp.full((cap,), n_rows, jnp.int32)])
     lo = jnp.int32(0)
     bstart = 0
-    for nbr, et, cnt in zip(nbrs, ets, counts):
-        with jax.named_scope(f"hop/push_w{nbr.shape[1]}"):
+    for b, cnt in enumerate(counts):
+        nb_rows, D = sides[0][0][b].shape
+        with jax.named_scope(f"hop/push_w{D}"):
             # the bucket's live slot rows in one gather (entries past
             # ``cnt`` belong to later buckets and are never looped
             # over).  One gather, not a row read per loop turn: the TPU
@@ -727,8 +852,12 @@ def _hop_push_packed(jnp, jax, n: int, n_rows: int,
             # the TPU compiler's memory analysis); a gather reads it
             # where it lies
             loc = jnp.clip(jax.lax.dynamic_slice(rows, (lo,), (cap,))
-                           - bstart, 0, nbr.shape[0] - 1)
-            tgts = jnp.where(_etype_ok(jnp, et[loc], neg), nbr[loc], R1)
+                           - bstart, 0, nb_rows - 1)
+            tgts = [jnp.where(_etype_ok(jnp, ets[b][loc], mags),
+                              nbrs[b][loc], R1)
+                    for nbrs, ets, mags in sides]
+            tgts = tgts[0] if len(tgts) == 1 \
+                else jnp.concatenate(tgts, axis=1)
 
             def body(i, nxt, tgts=tgts, lo=lo):
                 tgt = tgts[i]                              # [D]
@@ -738,7 +867,7 @@ def _hop_push_packed(jnp, jax, n: int, n_rows: int,
 
             nxt = jax.lax.fori_loop(0, cnt, body, nxt)
         lo = lo + cnt
-        bstart += nbr.shape[0]
+        bstart += nb_rows
     return nxt
 
 
@@ -749,7 +878,7 @@ def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
     (make_batched_bfs_lanes_kernel) call the step this returns, so the
     rule exists once.
 
-    step(fp uint8 [n_rows+1, W], eslot, hrows, nbrs, ets) ->
+    step(fp uint8 [n_rows+1, W], eslot, hrows, tables) ->
     (next frontier, sparse bool, live slot rows int32, their slots
     int32).  It measures the frontier it is handed — the live slot
     rows: a real row v < n with any lane bit set, plus the hub extra
@@ -758,23 +887,27 @@ def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
     device, the push (_hop_push_packed) when they number at most
     ``push_rows`` (HOP_PUSH_ROWS unless a test passes its own), else
     the pull over every slot (_hop_body_packed, as the windowed
-    kernels run it).  Both are exact on rows < n and the pad row; they
+    kernels run it).  Each reads only the table(s) ``etypes`` has a
+    member for (_read_sides): the pull the sources' side, the push the
+    targets'.  Both are exact on rows < n and the pad row; they
     differ only in what they leave in the extra rows, which nothing
-    reads.  ``slots`` is the live rows' widths whichever branch ran: a
-    pull visited table_slots(ell)."""
+    reads.  ``slots`` is the live rows' widths in the tables read,
+    whichever branch ran: a pull visited table_slots(ell, etypes)."""
     import jax
     import jax.numpy as jnp
     n, n_rows = ell.n, ell.n_rows
     n_extras, nb = len(ell.extra_owner), len(ell.bucket_nbr)
+    n_sides = sides_read(etypes)
     if push_rows is None:
         push_rows = HOP_PUSH_ROWS
 
-    def step(fp, eslot, hrows, nbrs, ets):
+    def step(fp, eslot, hrows, tables):
         def pull(fp):
-            return _hop_body_packed(jnp, jax, n, n_extras, etypes,
-                                    nbrs, ets, eslot, hrows, fp)
+            return _hop_body_packed(jnp, jax, n, n_extras,
+                                    _read_sides(etypes, tables, nb),
+                                    eslot, hrows, fp)
 
-        if not nb:                     # empty graph: nothing moves
+        if not nb or not n_sides:      # empty graph or OVER set
             return pull(fp), jnp.bool_(False), jnp.int32(0), jnp.int32(0)
         with jax.named_scope("hop/frontier"):
             row_live = jnp.any(fp[:n] != 0, axis=1)
@@ -787,19 +920,20 @@ def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
                     [row_live, (owner < n)
                      & row_live[jnp.minimum(owner, n - 1)]])
             counts, slots, b0 = [], jnp.int32(0), 0
-            for nbr in nbrs:
+            for nbr in tables[:nb]:
                 c = jnp.sum(row_live[b0:b0 + nbr.shape[0]],
                             dtype=jnp.int32)
                 counts.append(c)
-                slots = slots + c * nbr.shape[1]
+                slots = slots + c * (nbr.shape[1] * n_sides)
                 b0 += nbr.shape[0]
             live_rows = sum(counts)
             sparse = live_rows <= push_rows
 
         def push(fp):
-            return _hop_push_packed(jnp, jax, n, n_rows, etypes, nbrs,
-                                    ets, owner, fp, row_live, counts,
-                                    push_rows)
+            return _hop_push_packed(
+                jnp, jax, n, n_rows,
+                _read_sides(etypes, tables, nb, push=True), owner, fp,
+                row_live, counts, push_rows)
 
         nxt = jax.lax.cond(sparse, push, pull, fp)
         return nxt, sparse, live_rows, slots
@@ -825,17 +959,16 @@ def make_continuous_hop_kernel(ell: EllIndex,
     The advance is _make_frontier_step's: a push out of the live slot
     rows or the pull over every slot, chosen on the device.
     ``info`` = [1 if the push ran else 0, live slot rows, ELL slots
-    the hop visited (the live rows' widths, or the whole table)]; the
-    session reads it without waiting on the hop."""
+    the hop visited (the live rows' widths, or every slot of the
+    table(s) ``etypes`` reads)]; the session reads it without waiting
+    on the hop."""
     import jax
     import jax.numpy as jnp
-    nb = len(ell.bucket_nbr)
-    all_slots = table_slots(ell)
+    all_slots = table_slots(ell, etypes)
     step = _make_frontier_step(ell, etypes, push_rows)
 
     def hop(fp, accp, eslot, hrows, *tables):
-        nxt, sparse, live_rows, slots = step(fp, eslot, hrows,
-                                             tables[:nb], tables[nb:])
+        nxt, sparse, live_rows, slots = step(fp, eslot, hrows, tables)
         info = jnp.stack([sparse.astype(jnp.int32), live_rows,
                           jnp.where(sparse, slots,
                                     jnp.int32(all_slots))])
@@ -907,8 +1040,8 @@ def make_lane_count_kernel(ell: EllIndex):
     vertex rows: fn(fp uint8 [n_rows+1, W]) -> int32 [W*8], entry
     j*8+k the vertices whose bit k of word j is set.  A row v < n is
     one vertex of the mirror (its ELL row under the degree-bucket
-    relabelling: every vertex has one, a sink too, because rows hold
-    IN-slots); rows n..n_rows-1 are the hub extra rows and growth
+    relabelling: every vertex has one, in both tables, a sink and a
+    source too); rows n..n_rows-1 are the hub extra rows and growth
     spares, which after a pull hold partial ORs already merged into
     their owners' rows and are never read as sources, and row n_rows is
     the pad: none of them is counted, so a lane's count is its
@@ -971,7 +1104,7 @@ def make_lane_count_kernel(ell: EllIndex):
 # ====================================================================
 def _append_growth_spares(ell: EllIndex, slack: int) -> EllIndex:
     """Provision ``slack`` spare all-sentinel rows in the widest bucket
-    (owner = the spare sentinel) so plan_ell_absorb can GROW an
+    of both tables (owner = the spare sentinel) so plan_ell_absorb can GROW an
     overflowing vertex's slot capacity in place — the degree-growth
     path that used to be an unconditional slot-overflow rebuild.
     Every pre-spare sentinel slot is re-pointed at the NEW pad row
@@ -982,15 +1115,15 @@ def _append_growth_spares(ell: EllIndex, slack: int) -> EllIndex:
         return ell
     old_sent = np.int32(ell.n_rows)
     new_sent = np.int32(ell.n_rows + int(slack))
-    for b in range(len(ell.bucket_nbr)):
-        nbr = ell.bucket_nbr[b]
-        nbr[nbr == old_sent] = new_sent
     D = int(ell.bucket_nbr[-1].shape[1])
-    ell.bucket_nbr[-1] = np.vstack(
-        [ell.bucket_nbr[-1],
-         np.full((int(slack), D), new_sent, np.int32)])
-    ell.bucket_et[-1] = np.vstack(
-        [ell.bucket_et[-1], np.zeros((int(slack), D), np.int32)])
+    for nbrs, ets in ((ell.bucket_nbr, ell.bucket_et),
+                      (ell.out_nbr, ell.out_et)):
+        for nbr in nbrs:
+            nbr[nbr == old_sent] = new_sent
+        nbrs[-1] = np.vstack(
+            [nbrs[-1], np.full((int(slack), D), new_sent, np.int32)])
+        ets[-1] = np.vstack(
+            [ets[-1], np.zeros((int(slack), D), ets[-1].dtype)])
     ell.extra_owner = np.concatenate(
         [ell.extra_owner,
          np.full(int(slack), new_sent, np.int32)]).astype(np.int32)
@@ -1007,19 +1140,26 @@ def plan_ell_absorb(ell: EllIndex,
 
     Inputs are OLD-dense-id edge rows exactly as the CsrMirror stores
     them (both directions present as separate rows; reverse rides
-    -etype).  Returns {bucket: (local_rows int32[k], nbr [k, D_b],
-    et [k, D_b])} — the full new content of every affected row — or
+    -etype): a +etype row lands in its dst's in-table row, a -etype
+    row in its dst's out-table row.  Returns {table: (local_rows
+    int32[k], nbr [k, D_b], et [k, D_b])}, ``table`` = side * n_buckets
+    + b as EllIndex.tables_host orders them — the full new content of
+    every affected row of each table — or
     None when any owner's new slot count outgrows its resident
     capacity (main row + existing extra rows), which only the rebuild
-    can serve.  Work is O(delta x row width): only affected owners'
+    can serve, or an inserted etype outgrows the etype column's integer
+    type.  Work is O(delta x row width): only affected owners'
     rows are read and rewritten.
 
     In-place slot growth: when ``claims_out`` is a list and the index
     holds unclaimed growth spares (EllIndex.build growth_slack), an
     overflowing owner that is NOT already a hub claims enough spare
-    rows to hold its new degree — ``(spare_index, owner_new_id)``
+    rows to hold its new degree in whichever direction overflowed —
+    ``(spare_index, owner_new_id)``
     pairs are appended to ``claims_out`` and the plan rewrites the
-    claimed rows like any other.  Narrow by design: existing-vertex
+    claimed rows like any other (a claimed row is the owner's in both
+    tables, as a hub's extra rows are).  Narrow by design:
+    existing-vertex
     slot extension only — hubs (and previously-grown vertices, which
     look like hubs) and new-vertex ingest still take the rebuild, and
     claims always consume the LOWEST free spares so the free set stays
@@ -1029,8 +1169,12 @@ def plan_ell_absorb(ell: EllIndex,
 
     if ell.n == 0:
         return None if (len(ins_dst) or len(del_dst)) else {}
+    if _etype_dtype(ins_et).itemsize > ell.et_dtype.itemsize:
+        return None                  # the column's type cannot say it
     sentinel = np.int32(ell.n_rows)
     ecnt, e0 = ell.hub_expansion()
+    nb = len(ell.bucket_nbr)
+    tables = ell.tables_host()
     bstarts: List[int] = []
     acc = 0
     for nbr in ell.bucket_nbr:
@@ -1042,53 +1186,60 @@ def plan_ell_absorb(ell: EllIndex,
             ell.extra_owner == np.int32(ell.spare_sentinel()))[0] \
             .tolist()
 
-    owners: Dict[int, Tuple[Counter, list]] = {}
+    # owner row -> side (0 in-table, 1 out-table) -> (deletes, inserts)
+    owners: Dict[int, Dict[int, Tuple[Counter, list]]] = {}
 
-    def owner_of(dst_old: int):
-        r = int(ell.perm[dst_old])
-        o = owners.get(r)
-        if o is None:
-            o = owners[r] = (Counter(), [])
-        return o
+    def owner_of(dst_old: int, et: int):
+        by_side = owners.setdefault(int(ell.perm[dst_old]), {})
+        return by_side.setdefault(0 if et > 0 else 1, (Counter(), []))
 
     for i in range(len(ins_dst)):
-        owner_of(int(ins_dst[i]))[1].append(
-            (int(ell.perm[int(ins_src[i])]), int(ins_et[i])))
+        et = int(ins_et[i])
+        owner_of(int(ins_dst[i]), et)[1].append(
+            (int(ell.perm[int(ins_src[i])]), abs(et)))
     for i in range(len(del_dst)):
-        owner_of(int(del_dst[i]))[0][
-            (int(ell.perm[int(del_src[i])]), int(del_et[i]))] += 1
+        et = int(del_et[i])
+        owner_of(int(del_dst[i]), et)[0][
+            (int(ell.perm[int(del_src[i])]), abs(et))] += 1
 
     upd: Dict[int, Tuple[list, list, list]] = {}
-    for r, (dels_c, ins_l) in owners.items():
+    for r, by_side in owners.items():
         rows = [r] + list(range(int(e0[r]), int(e0[r]) + int(ecnt[r])))
-        entries: list = []
+        # (bucket, local row, width) of the owner's rows: the same in
+        # both tables
         widths: List[Tuple[int, int, int]] = []
         for row in rows:
             b = bisect.bisect_right(bstarts, row) - 1
-            local = row - bstarts[b]
-            nbr_row = ell.bucket_nbr[b][local]
-            et_row = ell.bucket_et[b][local]
-            widths.append((b, local, int(nbr_row.shape[0])))
-            fill = nbr_row != sentinel
-            entries.extend(zip(nbr_row[fill].tolist(),
-                               et_row[fill].tolist()))
-        if dels_c:
-            left = Counter(dels_c)
-            kept = []
-            for ent in entries:
-                if left.get(ent, 0) > 0:
-                    left[ent] -= 1
-                else:
-                    kept.append(ent)
-            if any(v > 0 for v in left.values()):
-                # a tombstone names an edge the table doesn't hold —
-                # the overlay and the tables disagree; only the
-                # rebuild can reconcile
-                return None
-            entries = kept
-        entries.extend(ins_l)
+            widths.append((b, row - bstarts[b],
+                           int(ell.bucket_nbr[b].shape[1])))
         total_w = sum(w for _b, _l, w in widths)
-        if len(entries) > total_w:
+        new_entries: Dict[int, list] = {}
+        for side, (dels_c, ins_l) in by_side.items():
+            entries: list = []
+            for b, local, _w in widths:
+                nbr_row = tables[side * nb + b][0][local]
+                et_row = tables[side * nb + b][1][local]
+                fill = nbr_row != sentinel
+                entries.extend(zip(nbr_row[fill].tolist(),
+                                   et_row[fill].tolist()))
+            if dels_c:
+                left = Counter(dels_c)
+                kept = []
+                for ent in entries:
+                    if left.get(ent, 0) > 0:
+                        left[ent] -= 1
+                    else:
+                        kept.append(ent)
+                if any(v > 0 for v in left.values()):
+                    # a tombstone names an edge the table doesn't hold —
+                    # the overlay and the tables disagree; only the
+                    # rebuild can reconcile
+                    return None
+                entries = kept
+            entries.extend(ins_l)
+            new_entries[side] = entries
+        over = max(len(e) for e in new_entries.values()) - total_w
+        if over > 0:
             # in-place slot growth: claim spare rows for a NON-hub
             # owner whose degree outgrew its resident width (narrow
             # scope — a hub, or a vertex grown in an earlier window,
@@ -1097,7 +1248,7 @@ def plan_ell_absorb(ell: EllIndex,
             if not free_spares or int(ecnt[r]) > 0:
                 return None      # slot overflow past the hub budget
             d_spare = int(ell.bucket_nbr[-1].shape[1])
-            need = -(-(len(entries) - total_w) // d_spare)
+            need = -(-over // d_spare)
             if need > len(free_spares):
                 return None      # growth slack exhausted: rebuild
             take, free_spares[:need] = free_spares[:need], []
@@ -1106,22 +1257,23 @@ def plan_ell_absorb(ell: EllIndex,
                 b = bisect.bisect_right(bstarts, row) - 1
                 widths.append((b, row - bstarts[b], d_spare))
                 claims_out.append((int(idx), int(r)))
-        pos = 0
-        for b, local, w in widths:
-            take = entries[pos:pos + w]
-            pos += w
-            nn = np.full(w, sentinel, np.int32)
-            ne = np.zeros(w, np.int32)
-            if take:
-                nn[:len(take)] = [t[0] for t in take]
-                ne[:len(take)] = [t[1] for t in take]
-            rb = upd.setdefault(b, ([], [], []))
-            rb[0].append(local)
-            rb[1].append(nn)
-            rb[2].append(ne)
-    return {b: (np.asarray(v[0], np.int32), np.vstack(v[1]),
+        for side, entries in new_entries.items():
+            pos = 0
+            for b, local, w in widths:
+                take = entries[pos:pos + w]
+                pos += w
+                nn = np.full(w, sentinel, np.int32)
+                ne = np.zeros(w, ell.et_dtype)
+                if take:
+                    nn[:len(take)] = [t[0] for t in take]
+                    ne[:len(take)] = [t[1] for t in take]
+                rb = upd.setdefault(side * nb + b, ([], [], []))
+                rb[0].append(local)
+                rb[1].append(nn)
+                rb[2].append(ne)
+    return {t: (np.asarray(v[0], np.int32), np.vstack(v[1]),
                 np.vstack(v[2]))
-            for b, v in upd.items()}
+            for t, v in upd.items()}
 
 
 def apply_ell_absorb_host(ell: EllIndex, plan, m_new: int,
@@ -1147,20 +1299,23 @@ def apply_ell_absorb_host(ell: EllIndex, plan, m_new: int,
             eo[idx] = owner
         out.extra_owner = eo
     out.n_rows = ell.n_rows
-    out.bucket_nbr = list(ell.bucket_nbr)
-    out.bucket_et = list(ell.bucket_et)
-    for b, (rows, nn, ne) in plan.items():
-        nbr = ell.bucket_nbr[b].copy()
-        et = ell.bucket_et[b].copy()
+    nb = len(ell.bucket_nbr)
+    tables = ell.tables_host()
+    for t, (rows, nn, ne) in plan.items():
+        nbr, et = tables[t][0].copy(), tables[t][1].copy()
         nbr[rows] = nn
         et[rows] = ne
-        out.bucket_nbr[b] = nbr
-        out.bucket_et[b] = et
+        tables[t] = (nbr, et)
+    out.bucket_nbr = [nbr for nbr, _ in tables[:nb]]
+    out.bucket_et = [et for _, et in tables[:nb]]
+    out.out_nbr = [nbr for nbr, _ in tables[nb:]]
+    out.out_et = [et for _, et in tables[nb:]]
     return out
 
 
 def absorb_update_arrays(ell: EllIndex, plan):
-    """Device-kernel argument form of an absorb plan: per bucket,
+    """Device-kernel argument form of an absorb plan: per table bucket
+    (EllIndex.tables_host order),
     (rows, nbr_rows, et_rows) padded to ONE UNIFORM pow-2 count — the
     rung of the largest per-bucket update set — so the jitted scatter
     sees a bounded shape space.  Uniformity is what bounds it: a
@@ -1177,11 +1332,12 @@ def absorb_update_arrays(ell: EllIndex, plan):
     — and the per-bucket arrays)."""
     per_bucket = []
     kmax = 1
-    for b, nbr_np in enumerate(ell.bucket_nbr):
+    et_dt = ell.et_dtype
+    for t, (nbr_np, _et) in enumerate(ell.tables_host()):
         nbk, D = nbr_np.shape
-        rows, nn, ne = plan.get(b, (np.zeros(0, np.int32),
+        rows, nn, ne = plan.get(t, (np.zeros(0, np.int32),
                                     np.zeros((0, D), np.int32),
-                                    np.zeros((0, D), np.int32)))
+                                    np.zeros((0, D), et_dt)))
         per_bucket.append((nbk, D, rows, nn, ne))
         kmax = max(kmax, len(rows))
     kp = max(8, 1 << (kmax - 1).bit_length())
@@ -1191,7 +1347,7 @@ def absorb_update_arrays(ell: EllIndex, plan):
         k = len(rows)
         rp = np.full(kp, nbk, np.int32)
         pn = np.full((kp, D), np.int32(ell.n_rows), np.int32)
-        pe = np.zeros((kp, D), np.int32)
+        pe = np.zeros((kp, D), et_dt)
         rp[:k] = rows
         pn[:k] = nn
         pe[:k] = ne
@@ -1200,9 +1356,29 @@ def absorb_update_arrays(ell: EllIndex, plan):
     return tuple(counts), outs
 
 
+def _absorb_split(args, nb: int):
+    """(rows, nbr updates, et updates, resident nbr tables, resident et
+    tables), each in EllIndex.tables_host order (2 * nb entries), from
+    an absorb kernel's positional arguments: the three update groups,
+    then ``tables`` as every kernel takes them (*in_nbr, *in_et,
+    *out_nbr, *out_et)."""
+    t = 2 * nb
+    tables = args[3 * t:]
+    return (args[0:t], args[t:2 * t], args[2 * t:3 * t],
+            tables[:nb] + tables[2 * nb:3 * nb],
+            tables[nb:2 * nb] + tables[3 * nb:])
+
+
+def _absorb_join(new_nbrs, new_ets, nb: int):
+    """The next generation's ``tables``, in kernel_args order."""
+    return tuple(new_nbrs[:nb]) + tuple(new_ets[:nb]) \
+        + tuple(new_nbrs[nb:]) + tuple(new_ets[nb:])
+
+
 def make_ell_absorb_kernel(ell: EllIndex, counts: Tuple[int, ...]):
-    """fn(*rows_per_bucket, *nbr_upd_per_bucket, *et_upd_per_bucket,
-    *tables) -> (new bucket_nbr..., new bucket_et...): whole-row
+    """fn(*rows_per_table, *nbr_upd_per_table, *et_upd_per_table,
+    *tables) -> the new ``tables`` (*bucket_nbr, *bucket_et, *out_nbr,
+    *out_et): whole-row
     scatter of the replacement rows into the resident tables.  The
     inputs are NOT donated — the old tables are the still-published
     generation — so the output generation is a fresh HBM allocation
@@ -1211,16 +1387,12 @@ def make_ell_absorb_kernel(ell: EllIndex, counts: Tuple[int, ...]):
     nb = len(ell.bucket_nbr)
 
     def absorb(*args):
-        rows = args[0:nb]
-        un = args[nb:2 * nb]
-        ue = args[2 * nb:3 * nb]
-        tables = args[3 * nb:]
-        nbrs, ets = tables[:nb], tables[nb:]
-        outs = [nbrs[b].at[rows[b]].set(un[b], mode="drop")
-                for b in range(nb)]
-        outs += [ets[b].at[rows[b]].set(ue[b], mode="drop")
-                 for b in range(nb)]
-        return tuple(outs)
+        rows, un, ue, nbrs, ets = _absorb_split(args, nb)
+        return _absorb_join(
+            [nbrs[t].at[rows[t]].set(un[t], mode="drop")
+             for t in range(2 * nb)],
+            [ets[t].at[rows[t]].set(ue[t], mode="drop")
+             for t in range(2 * nb)], nb)
 
     return jax.jit(absorb)
 
@@ -1228,7 +1400,8 @@ def make_ell_absorb_kernel(ell: EllIndex, counts: Tuple[int, ...]):
 def make_sharded_ell_absorb_kernel(mesh, axis: str, ell: EllIndex,
                                    padded_rows, counts: Tuple[int, ...]):
     """Shard-local twin of make_ell_absorb_kernel for the row-sharded
-    replicated-frontier tables (shard_ell): the tiny replacement-row
+    replicated-frontier tables (shard_ell; ``padded_rows`` per bucket,
+    the same in both tables): the tiny replacement-row
     set replicates to every chip, and each shard applies ONLY the rows
     it owns (non-owned indices push out of range and drop) — zero
     declared collectives, zero ICI exchange; hub rows live in the cap
@@ -1244,43 +1417,41 @@ def make_sharded_ell_absorb_kernel(mesh, axis: str, ell: EllIndex,
     ks = mesh.shape[axis]
 
     def per_shard(*args):
-        rows = args[0:nb]
-        un = args[nb:2 * nb]
-        ue = args[2 * nb:3 * nb]
-        tables = args[3 * nb:]
-        nbrs, ets = tables[:nb], tables[nb:]
+        rows, un, ue, nbrs, ets = _absorb_split(args, nb)
         d = jax.lax.axis_index(axis)
         outs_n, outs_e = [], []
-        for b in range(nb):
-            chunk = padded_rows[b] // ks
-            loc = rows[b] - d * chunk
+        for t in range(2 * nb):
+            chunk = padded_rows[t % nb] // ks
+            loc = rows[t] - d * chunk
             # a NEGATIVE local index would wrap (python-style) into a
             # neighbour's row — push every non-owned update out of
             # range instead, where mode="drop" discards it
             loc = jnp.where((loc >= 0) & (loc < chunk), loc,
                             jnp.int32(chunk))
-            outs_n.append(nbrs[b].at[loc].set(un[b], mode="drop"))
-            outs_e.append(ets[b].at[loc].set(ue[b], mode="drop"))
-        return tuple(outs_n + outs_e)
+            outs_n.append(nbrs[t].at[loc].set(un[t], mode="drop"))
+            outs_e.append(ets[t].at[loc].set(ue[t], mode="drop"))
+        return _absorb_join(outs_n, outs_e, nb)
 
-    in_spec = (P(),) * (3 * nb) + (P(axis),) * (2 * nb)
+    in_spec = (P(),) * (6 * nb) + (P(axis),) * (4 * nb)
     fn = shard_map(per_shard, mesh=mesh, in_specs=in_spec,
-                   out_specs=(P(axis),) * (2 * nb), check_vma=False)
+                   out_specs=(P(axis),) * (4 * nb), check_vma=False)
     return jax.jit(fn)
 
 
 # info vector of the batched BFS program (int32[3]): levels the loop
 # ran, how many of them pushed, and the ELL slots the PUSHED levels
-# visited (a pulled level visited table_slots(ell): bfs_slots adds
-# them on the host, where an integer cannot overflow)
+# visited (a pulled level visited table_slots(ell, etypes): bfs_slots
+# adds them on the host, where an integer cannot overflow)
 BFS_INFO_LEVELS, BFS_INFO_PUSHED, BFS_INFO_PUSH_SLOTS = 0, 1, 2
 
 
-def bfs_slots(ell: EllIndex, info) -> int:
+def bfs_slots(ell: EllIndex, etypes: Tuple[int, ...], info) -> int:
     """ELL slots the levels of one BFS dispatch visited: a pushed
-    level its live slot rows' widths, a pulled one the whole table."""
+    level its live slot rows' widths, a pulled one every slot of the
+    table(s) ``etypes`` reads."""
     pulled = int(info[BFS_INFO_LEVELS]) - int(info[BFS_INFO_PUSHED])
-    return int(info[BFS_INFO_PUSH_SLOTS]) + pulled * table_slots(ell)
+    return int(info[BFS_INFO_PUSH_SLOTS]) \
+        + pulled * table_slots(ell, etypes)
 
 
 def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
@@ -1309,8 +1480,8 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
     with -1 = unreachable when max_steps fits — the transfer is 2x
     smaller and depths are tiny — else int16 with INT16_INF), info
     int32[3] = [levels the loop ran, levels that pushed, ELL slots the
-    pushed levels visited]; bfs_slots(ell, info) is what all the levels
-    visited).  Rows >= n of the depth matrix stay as the start
+    pushed levels visited]; bfs_slots(ell, etypes, info) is what all the
+    levels visited).  Rows >= n of the depth matrix stay as the start
     frontier leaves them, unreached (it holds no bit there): nothing
     the host reads (EllIndex.to_old).  Both frontier matrices are built
     fresh per dispatch by runtime._bfs_depths, which opts in to
@@ -1318,12 +1489,11 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
     stays off)."""
     import jax
     import jax.numpy as jnp
-    n, nb_count = ell.n, len(ell.bucket_nbr)
+    n = ell.n
     small = max_steps <= 120
     advance = _make_frontier_step(ell, etypes, push_rows)
 
     def bfs(f0p, t0p, eslot, hrows, *tables):
-        nbrs, ets = tables[:nb_count], tables[nb_count:]
         real = (jnp.arange(f0p.shape[0]) < n)[:, None]
         tb = _unpack_lanes(jnp, t0p) > 0
         d0 = jnp.where(_unpack_lanes(jnp, f0p) > 0, jnp.int16(0),
@@ -1339,7 +1509,7 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
         def body(state):
             d, fp, step, pushed, push_slots = state
             nxtp, sparse, _rows, slots = advance(fp, eslot, hrows,
-                                                 nbrs, ets)
+                                                 tables)
             newly = (_unpack_lanes(jnp, nxtp) > 0) & (d == INT16_INF) \
                 & real
             d = jnp.where(newly, (step + 1).astype(jnp.int16), d)
@@ -1357,15 +1527,17 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
     return jax.jit(bfs, donate_argnums=(0, 1) if donate else ())
 
 
-def dense_hop_bytes(ell: EllIndex, lane_bytes_per_row: int,
-                    steps: int) -> int:
+def dense_hop_bytes(ell: EllIndex, etypes: Tuple[int, ...],
+                    lane_bytes_per_row: int, steps: int) -> int:
     """HBM traffic model of one dense GO dispatch: per advance, each
-    bucket row pays D word-gathers of ``lane_bytes_per_row`` (=
-    lanes_width(B)) plus an accumulator read+write; the hub fix-up and
+    bucket row of each table the OVER set reads (_read_sides) pays D
+    word-gathers of ``lane_bytes_per_row`` (= lanes_width(B)), and each
+    row an accumulator read+write; the hub fix-up and
     pad are O(n_extras) noise.  The roofline numbers in runtime_stats
     and docs/roofline.md come from THIS model so they are
     comparable."""
-    per_advance = sum(nbr.shape[0] * (nbr.shape[1] + 2)
+    k = sides_read(etypes)
+    per_advance = sum(nbr.shape[0] * (k * nbr.shape[1] + 2)
                       for nbr in ell.bucket_nbr) * lane_bytes_per_row
     return max(steps - 1, 1) * per_advance
 
@@ -1403,6 +1575,39 @@ def sparse_limit_cap(caps: Tuple[int, ...], c0: int, limit: int) -> int:
                    1 << (max(8, limit * max(c0, 1)) - 1).bit_length()))
 
 
+def _gather_out_slots(jnp, gids, sides, bucket_ranges, sentinel: int,
+                      d_max: int):
+    """[g, d_max * len(sides)] neighbour ids of each row in ``gids``
+    over the tables in ``sides`` (_read_sides with push=True), sentinel
+    where a slot is padding, masked, or the row is not in range.
+    ``bucket_ranges[b]`` = (first local row's id, rows held, lowest and
+    one past the highest id the bucket owns): the single-device kernel
+    holds whole buckets, a mesh device one block of each.  THE
+    pair-list expansion — shared by the single-device and the
+    frontier-sharded sparse kernels so their semantics cannot skew."""
+    g = gids.shape[0]
+    cands = []
+    for nbrs, ets, mags in sides:
+        cand = jnp.full((g, d_max), jnp.int32(sentinel))
+        for nbr, et, (start, held, lo, hi) in zip(nbrs, ets,
+                                                  bucket_ranges):
+            D = nbr.shape[1]
+            loc = gids - start
+            inb = (loc >= 0) & (loc < held) & (gids >= lo) & (gids < hi)
+            safe = jnp.where(inb, loc, 0)
+            rows = nbr[safe]                      # [g, D] row-gathers
+            ok = inb[:, None] & _etype_ok(jnp, et[safe], mags)
+            block = jnp.where(ok, rows, sentinel)
+            if D < d_max:
+                block = jnp.pad(block, ((0, 0), (0, d_max - D)),
+                                constant_values=sentinel)
+            cand = jnp.where(inb[:, None], block, cand)
+        cands.append(cand)
+    if not cands:
+        return jnp.full((g, d_max), jnp.int32(sentinel))
+    return cands[0] if len(cands) == 1 else jnp.concatenate(cands, axis=1)
+
+
 def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
                                   etypes: Tuple[int, ...],
                                   caps: Tuple[int, ...],
@@ -1413,9 +1618,9 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
     """Sparse batched GO — B queries' frontiers ride ONE flat sorted
     (query, vertex) pair list instead of a dense [n_rows, B] bitmap.
 
-    Per hop: bucketed row-gathers pull each pair's out-slots (etypes
-    negated — csr.py stores the reverse direction under -etype, so a
-    row's -T slots are its OUT-neighbors over T), then a lexicographic
+    Per hop: bucketed row-gathers pull each pair's out-slots (a row's
+    OUT-neighbours over +T are its out-table slots of magnitude T:
+    _read_sides with push=True), then a lexicographic
     sort + shift-compare dedups (query, vertex) pairs and compacts them
     to the next static cap.  Work scales with the LIVE frontier (the
     reference's per-vertex prefix scans touch only frontier vertices
@@ -1455,15 +1660,15 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
     import jax.numpy as jnp
     n, n_rows = ell.n, ell.n_rows
     sentinel = n_rows
-    neg = tuple(-t for t in etypes)
     d_max = max(ell.bucket_D) if ell.bucket_D else 1
     nb_count = len(ell.bucket_nbr)
     has_hubs = len(ell.extra_owner) > 0
-    bstarts = []
+    bucket_ranges = []
     acc = 0
     for nbr_np in ell.bucket_nbr:
-        bstarts.append(acc)
-        acc += nbr_np.shape[0]
+        nbk = nbr_np.shape[0]
+        bucket_ranges.append((acc, nbk, acc, acc + nbk))
+        acc += nbk
     BIG_Q = jnp.int32(2**30)
     # when (query, vertex) packs into one int32, the per-hop dedup is a
     # single-operand sort — measurably cheaper than the 2-key
@@ -1495,7 +1700,7 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
     ex_pow2 = 1 << max(n_extras_total * max(qmax, 1) - 1, 1).bit_length() \
         if n_extras_total else 0
 
-    def hop(ids, qid, ecnt, e0, nbrs, ets, c_out):
+    def hop(ids, qid, ecnt, e0, sides, c_out):
         c_in = ids.shape[0]
         if has_hubs:
             # push sources = main rows + every frontier hub's extra
@@ -1506,22 +1711,10 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
             gqs = jnp.concatenate([qid, ext_q])
         else:
             gids, gqs, ovf_hub = ids, qid, jnp.bool_(False)
-        g_in = gids.shape[0]
-        cand = jnp.full((g_in, d_max), jnp.int32(sentinel))
-        for nbr, et, bstart in zip(nbrs, ets, bstarts):
-            nbk, D = nbr.shape
-            loc = gids - bstart
-            inb = (loc >= 0) & (loc < nbk)
-            safe = jnp.where(inb, loc, 0)
-            rows = nbr[safe]                      # [g_in, D] row-gathers
-            ok = inb[:, None] & _etype_ok(jnp, et[safe], neg)
-            block = jnp.where(ok, rows, sentinel)
-            if D < d_max:
-                block = jnp.pad(block, ((0, 0), (0, d_max - D)),
-                                constant_values=sentinel)
-            cand = jnp.where(inb[:, None], block, cand)
+        cand = _gather_out_slots(jnp, gids, sides, bucket_ranges,
+                                 sentinel, d_max)
         flat_i = cand.reshape(-1)
-        flat_q = jnp.repeat(gqs, d_max)
+        flat_q = jnp.repeat(gqs, cand.shape[1])
         out_i, out_q, cnt = dedup_compact(flat_q, flat_i, c_out)
         overflow = (cnt > c_out) | ovf_hub
         return out_i, out_q, overflow, cnt
@@ -1587,7 +1780,7 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
         return out_i, out_q, kcnt, overflow | (kcnt > c_red)
 
     def go_impl(ids0, qid0, ecnt, e0, deg, *tables):
-        nbrs, ets = tables[:nb_count], tables[nb_count:]
+        sides = _read_sides(etypes, tables, nb_count, push=True)
         ids, qid = ids0, jnp.where(ids0 == sentinel, BIG_Q, qid0)
         overflow = jnp.bool_(False)
         cnt = jnp.sum(ids != sentinel).astype(jnp.int32)
@@ -1603,7 +1796,7 @@ def make_batched_sparse_go_kernel(ell: EllIndex, steps: int,
             acc_q = jnp.pad(qid, (0, c_fin - qid.shape[0]),
                             constant_values=BIG_Q)
         for h in range(max(steps - 1, 0)):
-            ids, qid, ovf_h, cnt = hop(ids, qid, ecnt, e0, nbrs, ets,
+            ids, qid, ovf_h, cnt = hop(ids, qid, ecnt, e0, sides,
                                        caps[h + 1])
             overflow = overflow | ovf_h
             if upto:
@@ -1699,43 +1892,45 @@ def sparse_go_pairs(kern, out: np.ndarray):
 # ====================================================================
 def shard_ell(mesh, axis: str, ell: EllIndex):
     """Pad each bucket's rows to a multiple of the axis size and place
-    the tables row-sharded.  Returns (nbr_shards, et_shards, real_rows)."""
+    both tables row-sharded.  Returns (tables, real_rows): ``tables``
+    in kernel_args order (*in_nbr, *in_et, *out_nbr, *out_et),
+    ``real_rows`` the unpadded row count per bucket."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     k = mesh.shape[axis]
     sharding = NamedSharding(mesh, P(axis))
-    nbrs, ets, reals = [], [], []
     sentinel = np.int32(ell.n_rows)
-    for nbr, et in zip(ell.bucket_nbr, ell.bucket_et):
-        nb, D = nbr.shape
+
+    def place(a, fill):
+        nb, D = a.shape
         padded = ((nb + k - 1) // k) * k if nb else k
         if padded != nb:
-            nbr = np.concatenate(
-                [nbr, np.full((padded - nb, D), sentinel, np.int32)])
-            et = np.concatenate(
-                [et, np.zeros((padded - nb, D), np.int32)])
-        nbrs.append(jax.device_put(nbr, sharding))
-        ets.append(jax.device_put(et, sharding))
-        reals.append(nb)
-    return nbrs, ets, reals
+            a = np.concatenate(
+                [a, np.full((padded - nb, D), fill, a.dtype)])
+        return jax.device_put(a, sharding)
+
+    tables = tuple(place(a, fill)
+                   for group, fill in ((ell.bucket_nbr, sentinel),
+                                       (ell.bucket_et, 0),
+                                       (ell.out_nbr, sentinel),
+                                       (ell.out_et, 0))
+                   for a in group)
+    return tables, [int(nbr.shape[0]) for nbr in ell.bucket_nbr]
 
 
 def make_sharded_batched_go_kernel(mesh, axis: str, ell: EllIndex,
                                    steps: int, etypes: Tuple[int, ...],
-                                   nbr_shards, et_shards, real_rows,
-                                   donate: bool = False):
+                                   real_rows, donate: bool = False):
     """Sharded-bucket batched GO over a BIT-PACKED replicated frontier.
 
     fn(f0p replicated uint8 [n_rows+1, W], eslot, hrows, *tables) ->
     uint8 [n_rows+1, W] — same lane layout as the single-chip
     make_batched_go_lanes_kernel (pack_lanes_host / unpack_lanes_host
     invert), so the sharded result is bit-exact against it.  eslot/
-    hrows are the hub OR-merge grouping (EllIndex.hub_merge)."""
+    hrows are the hub OR-merge grouping (EllIndex.hub_merge);
+    ``tables`` and ``real_rows`` are shard_ell's."""
     import jax
-    import jax.numpy as jnp
-    hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, nbr_shards,
-                                   et_shards, real_rows)
+    hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, real_rows)
 
     def go(f0p, eslot, hrows, *tables):
         return f0p if steps <= 1 else jax.lax.fori_loop(
@@ -1748,13 +1943,13 @@ def make_sharded_batched_go_kernel(mesh, axis: str, ell: EllIndex,
 
 
 def _make_sharded_hop_packed(mesh, axis: str, ell: EllIndex,
-                             etypes: Tuple[int, ...], nbr_shards,
-                             et_shards, real_rows):
+                             etypes: Tuple[int, ...], real_rows):
     """hop(fp, eslot, hrows, *tables) -> next packed frontier, with
     bucket rows expanded on their owning device and the result
     re-replicated over ICI.  Shared by the sharded GO and BFS builders
     (same split as _hop_body_packed vs its callers on the single-chip
-    side).  The re-replication sharding constraint is THE per-hop ICI
+    side); only the table(s) ``etypes`` reads enter the shard_map.
+    The re-replication sharding constraint is THE per-hop ICI
     cost of this design — (k-1)/k of the [n_rows+1, W] frontier per
     chip per hop, declared in the kernel registry's COLLECTIVE_MODEL
     and priced by meshaudit's static traffic model."""
@@ -1763,27 +1958,34 @@ def _make_sharded_hop_packed(mesh, axis: str, ell: EllIndex,
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax import shard_map
 
-    n_buckets = len(nbr_shards)
+    n_buckets = len(real_rows)
     n_extras = len(ell.extra_owner)
     n = ell.n
+    # the magnitudes each table read is masked by, in _read_sides order
+    mags = [m for m in _split_signs(etypes) if m]
+    n_sides = len(mags)
 
-    def per_shard(fp, *tables):
-        nbrs, ets = tables[:n_buckets], tables[n_buckets:]
-        return tuple(_bucket_expand_packed(jnp, jax, fp, nbr, et, etypes)
-                     for nbr, et in zip(nbrs, ets))
+    def per_shard(fp, *flat):
+        w = 2 * n_buckets
+        sides = [(flat[i * w:i * w + n_buckets],
+                  flat[i * w + n_buckets:(i + 1) * w], mags[i])
+                 for i in range(n_sides)]
+        return tuple(_buckets_expand_packed(jnp, jax, fp, sides))
 
     sharded_hop = shard_map(
         per_shard, mesh=mesh,
-        in_specs=(P(),) + (P(axis),) * (2 * n_buckets),
+        in_specs=(P(),) + (P(axis),) * (2 * n_buckets * n_sides),
         out_specs=(P(axis),) * n_buckets,
         check_vma=False)
 
     replicate = NamedSharding(mesh, P())
 
     def hop(fp, eslot, hrows, *tables):
-        if n_buckets == 0:                   # empty graph: nothing moves
+        if not n_buckets or not n_sides:     # empty graph or OVER set
             return jnp.zeros_like(fp)
-        outs = sharded_hop(fp, *tables)
+        outs = sharded_hop(fp, *[
+            a for nbrs, ets, _ in _read_sides(etypes, tables, n_buckets)
+            for a in (*nbrs, *ets)])
         trimmed = [o[:r] for o, r in zip(outs, real_rows)]
         nxt = jnp.concatenate(trimmed, axis=0) \
             if len(trimmed) > 1 else trimmed[0]
@@ -1808,8 +2010,7 @@ def _make_sharded_hop_packed(mesh, axis: str, ell: EllIndex,
 
 def make_sharded_batched_bfs_kernel(mesh, axis: str, ell: EllIndex,
                                     max_steps: int,
-                                    etypes: Tuple[int, ...],
-                                    nbr_shards, et_shards, real_rows,
+                                    etypes: Tuple[int, ...], real_rows,
                                     stop_when_found: bool = True,
                                     donate: bool = False):
     """Sharded-bucket batched BFS depths — the multi-chip counterpart
@@ -1821,8 +2022,7 @@ def make_sharded_batched_bfs_kernel(mesh, axis: str, ell: EllIndex,
     fits, else int16), the levels the loop ran)."""
     import jax
     import jax.numpy as jnp
-    hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, nbr_shards,
-                                   et_shards, real_rows)
+    hop = _make_sharded_hop_packed(mesh, axis, ell, etypes, real_rows)
     small = max_steps <= 120
 
     def bfs(f0p, t0p, eslot, hrows, *tables):
@@ -1866,10 +2066,11 @@ class ShardedEll:
     arrays [k, mx_b, D_b] shard evenly on the mesh axis).  Hub
     expansion metadata (ecnt, e0 per owner vertex) shards by the same
     chunks, so NOTHING a device holds scales with the whole graph or
-    the whole frontier.
+    the whole frontier.  ``tables_s`` holds the blocks of both tables in
+    kernel_args order (*in_nbr, *in_et, *out_nbr, *out_et).
     """
 
-    __slots__ = ("k", "chunk", "bstarts", "mx", "D", "nbr_s", "et_s",
+    __slots__ = ("k", "chunk", "bstarts", "mx", "D", "tables_s",
                  "starts_s", "ecnt_s", "e0_s", "n", "n_rows",
                  "n_extras", "_device")
 
@@ -1886,32 +2087,33 @@ def build_sharded_ell(ell: EllIndex, k: int) -> ShardedEll:
     sh.n, sh.n_rows = ell.n, ell.n_rows
     sh.n_extras = len(ell.extra_owner)
     sh.bstarts, sh.mx, sh.D = [], [], []
-    sh.nbr_s, sh.et_s = [], []
+    groups = ([], [], [], [])          # in nbr, in et, out nbr, out et
     starts = np.zeros((k, len(ell.bucket_nbr)), np.int32)
     sentinel = np.int32(ell.n_rows)
     bstart = 0
-    for b, (nbr, et) in enumerate(zip(ell.bucket_nbr, ell.bucket_et)):
+    for b, nbr in enumerate(ell.bucket_nbr):
         nb, D = nbr.shape
         lo = np.maximum(bstart, np.arange(k, dtype=np.int64) * sh.chunk)
         hi = np.minimum(bstart + nb,
                         (np.arange(k, dtype=np.int64) + 1) * sh.chunk)
         cnt = np.maximum(hi - lo, 0)
         mx = max(int(cnt.max()), 1) if nb else 1
-        nbr_k = np.full((k, mx, D), sentinel, np.int32)
-        et_k = np.zeros((k, mx, D), np.int32)
-        for d in range(k):
-            c = int(cnt[d])
-            if c:
-                s = int(lo[d]) - bstart
-                nbr_k[d, :c] = nbr[s:s + c]
-                et_k[d, :c] = et[s:s + c]
-            starts[d, b] = int(lo[d])     # global row id of my block
+        for group, src, fill in zip(
+                groups, (nbr, ell.bucket_et[b], ell.out_nbr[b],
+                         ell.out_et[b]), (sentinel, 0, sentinel, 0)):
+            blk = np.full((k, mx, D), fill, src.dtype)
+            for d in range(k):
+                c = int(cnt[d])
+                if c:
+                    s0 = int(lo[d]) - bstart
+                    blk[d, :c] = src[s0:s0 + c]
+            group.append(blk)
+        starts[:, b] = lo                 # global row id of each block
         sh.bstarts.append(bstart)
         sh.mx.append(mx)
         sh.D.append(D)
-        sh.nbr_s.append(nbr_k)
-        sh.et_s.append(et_k)
         bstart += nb
+    sh.tables_s = tuple(a for group in groups for a in group)
     sh.starts_s = starts
     ecnt, e0 = ell.hub_expansion()        # length n+1, indexed by row<n
     pad = k * sh.chunk
@@ -1926,7 +2128,7 @@ def build_sharded_ell(ell: EllIndex, k: int) -> ShardedEll:
 
 def sharded_device_args(mesh, axis: str, sh: ShardedEll):
     """device_put the per-device arrays with P(axis) on their leading
-    dim (cached on the ShardedEll)."""
+    dim (cached on the ShardedEll): (starts, ecnt, e0, tables)."""
     if sh._device is None:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1935,8 +2137,7 @@ def sharded_device_args(mesh, axis: str, sh: ShardedEll):
             jax.device_put(sh.starts_s, s),
             jax.device_put(sh.ecnt_s, s),
             jax.device_put(sh.e0_s, s),
-            tuple(jax.device_put(a, s) for a in sh.nbr_s),
-            tuple(jax.device_put(a, s) for a in sh.et_s),
+            tuple(jax.device_put(a, s) for a in sh.tables_s),
         )
     return sh._device
 
@@ -1970,35 +2171,23 @@ def _mesh_sparse_tools(jnp, jax, axis: str, k: int, chunk: int,
     metadata arrives as plain ints/lists so the returned closures never
     pin a ShardedEll (whose device-table cache is gigabytes)."""
     sentinel = n_rows
-    neg = tuple(-t for t in etypes)
     d_max = max(Ds) if Ds else 1
     nb_count = len(Ds)
     BIG_Q = jnp.int32(2**30)
     bucket_end = [bstarts[b + 1] if b + 1 < nb_count else n_rows
                   for b in range(nb_count)]
 
-    def local_gather(rows, nbrs, ets, starts):
-        """[g, d_max] candidate MAIN-row ids of each local row's
-        out-slots (neg etypes), sentinel elsewhere.  Rows are owned by
-        this device by invariant; each selects exactly one bucket's
+    def local_gather(rows, tables, starts):
+        """Candidate MAIN-row ids of each local row's out-slots
+        (_gather_out_slots over the device's blocks of the table(s)
+        ``etypes`` pushes through), sentinel elsewhere.  Rows are owned
+        by this device by invariant; each selects exactly one bucket's
         local block by its global bucket range."""
-        g = rows.shape[0]
-        cand = jnp.full((g, d_max), jnp.int32(sentinel))
-        for b in range(nb_count):
-            nbr, et = nbrs[b], ets[b]          # [mx_b, D_b]
-            mxb, D = nbr.shape
-            loc = rows - starts[b]
-            inb = (loc >= 0) & (loc < mxb) \
-                & (rows >= bstarts[b]) & (rows < bucket_end[b])
-            safe = jnp.where(inb, loc, 0)
-            rr = nbr[safe]
-            ok = inb[:, None] & _etype_ok(jnp, et[safe], neg)
-            block = jnp.where(ok, rr, sentinel)
-            if D < d_max:
-                block = jnp.pad(block, ((0, 0), (0, d_max - D)),
-                                constant_values=sentinel)
-            cand = jnp.where(inb[:, None], block, cand)
-        return cand
+        sides = _read_sides(etypes, tables, nb_count, push=True)
+        ranges = [(starts[b], tables[b].shape[0], bstarts[b],
+                   bucket_end[b]) for b in range(nb_count)]
+        return _gather_out_slots(jnp, rows, sides, ranges, sentinel,
+                                 d_max)
 
     def route(q, u, slot_cap):
         """Sort (q, u) pairs by destination owner and pack them into
@@ -2107,8 +2296,7 @@ def make_frontier_sharded_sparse_go_kernel(mesh, axis: str,
         qid = jnp.where(ids == sentinel, BIG_Q, qid0[0])
         starts = starts[0]
         ecnt_l, e0_l = ecnt_l[0], e0_l[0]
-        nbrs = [t[0] for t in tables[:nb_count]]
-        ets = [t[0] for t in tables[nb_count:]]
+        tables = [t[0] for t in tables]
         d = jax.lax.axis_index(axis)
         base = (d * chunk).astype(jnp.int32)
         overflow = jnp.bool_(False)
@@ -2131,9 +2319,9 @@ def make_frontier_sharded_sparse_go_kernel(mesh, axis: str,
                 g_q = jnp.concatenate([qid, ext_q])
             else:
                 g_rows, g_q = ids, qid
-            cand = local_gather(g_rows, nbrs, ets, starts)
+            cand = local_gather(g_rows, tables, starts)
             flat_u = cand.reshape(-1)
-            flat_q = jnp.repeat(g_q, d_max)
+            flat_q = jnp.repeat(g_q, cand.shape[1])
             qx, ux, ovf_x = route(flat_q, flat_u, cap_x)
             qr = jax.lax.all_to_all(qx, axis, 0, 0, tiled=False)
             ur = jax.lax.all_to_all(ux, axis, 0, 0, tiled=False)
@@ -2163,7 +2351,7 @@ def make_frontier_sharded_sparse_go_kernel(mesh, axis: str,
         return out[None, :]
 
     import jax as _jax
-    in_spec = (P(axis),) * (5 + 2 * nb_count)
+    in_spec = (P(axis),) * (5 + 4 * nb_count)
     fn = shard_map(per_device, mesh=mesh, in_specs=in_spec,
                    out_specs=P(axis), check_vma=False)
     return _jax.jit(fn)
@@ -2240,8 +2428,7 @@ def make_frontier_sharded_sparse_bfs_kernel(mesh, axis: str,
             t_i, t_q = tids[0], tqid[0]
             starts_l = starts[0]
             ecnt_l, e0_l = ecnt_l[0], e0_l[0]
-            nbrs = [t[0] for t in tables[:nb_count]]
-            ets = [t[0] for t in tables[nb_count:]]
+            tables = [t[0] for t in tables]
             d = jax.lax.axis_index(axis)
             base = (d * chunk).astype(jnp.int32)
 
@@ -2280,7 +2467,7 @@ def make_frontier_sharded_sparse_bfs_kernel(mesh, axis: str,
                 er, eq, ovf_h = hub_pairs(qid, ids)
                 g_rows = jnp.concatenate([ids, er])
                 g_q = jnp.concatenate([qid, eq])
-                cand = local_gather(g_rows, nbrs, ets, starts_l)
+                cand = local_gather(g_rows, tables, starts_l)
                 flat_u = cand.reshape(-1)
                 flat_q = jnp.repeat(g_q, cand.shape[1])
                 q_r, u_r, ovf_x = exchange(flat_q, flat_u, cap_x)
@@ -2325,7 +2512,7 @@ def make_frontier_sharded_sparse_bfs_kernel(mesh, axis: str,
                 cond, body, state)
             return dep[None], ovf.astype(jnp.int32)[None]
 
-        in_spec = (P(axis),) * (7 + 2 * nb_count)
+        in_spec = (P(axis),) * (7 + 4 * nb_count)
         return jax.jit(shard_map(per_device, mesh=mesh,
                                  in_specs=in_spec,
                                  out_specs=(P(axis), P(axis)),
@@ -2518,22 +2705,20 @@ def _ell_bfs_buckets(fx):
 
 
 def _absorb_update_avals(fx, kp: int):
-    """(rows, nbr_upd, et_upd) avals per bucket at padded count kp —
-    the single-bucket audit fixture keeps this flat."""
-    out = []
-    for nbr in fx.ell.bucket_nbr:
-        out.append(fx.aval((kp,), np.int32))
-    for nbr in fx.ell.bucket_nbr:
-        out.append(fx.aval((kp, nbr.shape[1]), np.int32))
-    for nbr in fx.ell.bucket_nbr:
-        out.append(fx.aval((kp, nbr.shape[1]), np.int32))
-    return tuple(out)
+    """(rows, nbr_upd, et_upd) avals per table bucket at padded count
+    kp (EllIndex.tables_host order)."""
+    tables = fx.ell.tables_host()
+    return tuple(fx.aval((kp,), np.int32) for _ in tables) \
+        + tuple(fx.aval((kp, nbr.shape[1]), np.int32)
+                for nbr, _et in tables) \
+        + tuple(fx.aval((kp, et.shape[1]), et.dtype)
+                for _nbr, et in tables)
 
 
 def _ell_absorb_buckets(fx):
     out = []
     for kp in (8, 64):              # the pow-2 update-count ladder's ends
-        counts = tuple(kp for _ in fx.ell.bucket_nbr)
+        counts = tuple(kp for _ in fx.ell.tables_host())
         kern = make_ell_absorb_kernel(  # nebulint: disable=jax-hotpath
             fx.ell, counts)
         out.append((("ell_absorb", fx.ell.shape_sig(), counts), kern,
@@ -2637,33 +2822,32 @@ register_kernel(KernelSpec(
     # rungs bound the ladder); NO donation: the resident tables are
     # the still-published generation in-flight dispatches read — the
     # output generation must be a fresh allocation (docs/durability.md)
-    budget=12, instantiate=_ell_absorb_buckets, dispatch=(0, 1, 2)))
+    budget=12, instantiate=_ell_absorb_buckets,
+    dispatch=tuple(range(6))))
 
 
-def _sharded_table_avals(fx, nbrs, ets):
-    return tuple(fx.aval(a.shape, np.int32) for a in nbrs) \
-        + tuple(fx.aval(a.shape, np.int32) for a in ets)
+def _sharded_table_avals(fx, tables):
+    return tuple(fx.aval(a.shape, a.dtype) for a in tables)
 
 
 def _ell_sharded_arg_indices(fx):
     """Replicated-frontier sharded GO: everything after the
     (f0p, eslot, hrows) prefix is a row-sharded bucket table."""
     nb = len(fx.ell.bucket_nbr)
-    return tuple(range(3, 3 + 2 * nb))
+    return tuple(range(3, 3 + 4 * nb))
 
 
 def _ell_bfs_sharded_arg_indices(fx):
     nb = len(fx.ell.bucket_nbr)
-    return tuple(range(4, 4 + 2 * nb))
+    return tuple(range(4, 4 + 4 * nb))
 
 
 def _ell_go_sharded_mesh_buckets(fx, mesh):
     k = mesh.shape["parts"]
-    nbrs, ets, reals = shard_ell(mesh, "parts", fx.ell)
+    tables, reals = shard_ell(mesh, "parts", fx.ell)
     kern = make_sharded_batched_go_kernel(
-        mesh, "parts", fx.ell, fx.steps, fx.etypes, nbrs, ets, reals,
-        donate=True)
-    tables = _sharded_table_avals(fx, nbrs, ets)
+        mesh, "parts", fx.ell, fx.steps, fx.etypes, reals, donate=True)
+    tables = _sharded_table_avals(fx, tables)
     return [(("ell_go_sharded", fx.ell.shape_sig(), fx.etypes,
               fx.steps, k), kern,
              _packed_frontier_avals(fx, B) + tables)
@@ -2676,14 +2860,14 @@ def _ell_go_sharded_buckets(fx):
 
 def _ell_bfs_sharded_mesh_buckets(fx, mesh):
     k = mesh.shape["parts"]
-    nbrs, ets, reals = shard_ell(mesh, "parts", fx.ell)
+    tables, reals = shard_ell(mesh, "parts", fx.ell)
     B = fx.widths[0]
-    tables = _sharded_table_avals(fx, nbrs, ets)
+    tables = _sharded_table_avals(fx, tables)
     out = []
     for shortest in (True, False):
         kern = make_sharded_batched_bfs_kernel(  # nebulint: disable=jax-hotpath
-            mesh, "parts", fx.ell, fx.steps, fx.etypes, nbrs, ets,
-            reals, stop_when_found=shortest, donate=True)
+            mesh, "parts", fx.ell, fx.steps, fx.etypes, reals,
+            stop_when_found=shortest, donate=True)
         pk = _packed_frontier_avals(fx, B)
         out.append((("ell_bfs_sharded", fx.ell.shape_sig(), fx.etypes,
                      fx.steps, shortest, k), kern,
@@ -2732,17 +2916,18 @@ register_kernel(KernelSpec(
 
 def _ell_absorb_sharded_mesh_buckets(fx, mesh):
     k = mesh.shape["parts"]
-    nbrs, ets, _reals = shard_ell(mesh, "parts", fx.ell)
-    padded = [int(a.shape[0]) for a in nbrs]
+    tables, _reals = shard_ell(mesh, "parts", fx.ell)
+    nb = len(fx.ell.bucket_nbr)
+    padded = [int(a.shape[0]) for a in tables[:nb]]
     out = []
     for kp in (8, 64):
-        counts = tuple(kp for _ in fx.ell.bucket_nbr)
+        counts = tuple(kp for _ in fx.ell.tables_host())
         kern = make_sharded_ell_absorb_kernel(  # nebulint: disable=jax-hotpath
             mesh, "parts", fx.ell, padded, counts)
         out.append((("ell_absorb_sharded", fx.ell.shape_sig(), counts,
                      k), kern,
                     _absorb_update_avals(fx, kp)
-                    + _sharded_table_avals(fx, nbrs, ets)))
+                    + _sharded_table_avals(fx, tables)))
     return out
 
 
@@ -2752,14 +2937,14 @@ def _ell_absorb_sharded_buckets(fx):
 
 def _ell_absorb_sharded_arg_indices(fx):
     nb = len(fx.ell.bucket_nbr)
-    return tuple(range(3 * nb, 5 * nb))
+    return tuple(range(6 * nb, 10 * nb))
 
 
 register_kernel(KernelSpec(
     "ell_absorb_sharded", make_sharded_ell_absorb_kernel,
     phase_kind="ell_absorb",
     budget=12, instantiate=_ell_absorb_sharded_buckets,
-    dispatch=(0, 1, 2),
+    dispatch=tuple(range(6)),
     mesh_instantiate=_ell_absorb_sharded_mesh_buckets,
     # COLLECTIVE_MODEL: EMPTY by design — absorption is shard-local
     # (each chip applies only the replacement rows it owns; the
@@ -2769,7 +2954,7 @@ register_kernel(KernelSpec(
     collective=(),
     ici_bytes=lambda fx, k: 0,
     shard_args=_ell_absorb_sharded_arg_indices,
-    shard_outs=tuple(range(2))))
+    shard_outs=tuple(range(4))))
 
 
 # ------------------------------------------------ frontier-sharded (mesh)
@@ -2811,8 +2996,7 @@ def _mesh_sparse_go_mesh_buckets(fx, mesh):
               fx.aval(sh.starts_s.shape, np.int32),
               fx.aval(sh.ecnt_s.shape, np.int32),
               fx.aval(sh.e0_s.shape, np.int32))
-             + tuple(fx.aval(a.shape, np.int32) for a in sh.nbr_s)
-             + tuple(fx.aval(a.shape, np.int32) for a in sh.et_s))
+             + _sharded_table_avals(fx, sh.tables_s))
     return [(("mesh_sparse_go", fx.ell.shape_sig(), fx.etypes,
               fx.steps, caps, k, cap_x, cap_e), kern, avals)]
 
@@ -2834,8 +3018,7 @@ def _mesh_sparse_bfs_mesh_buckets(fx, mesh):
               fx.aval(sh.starts_s.shape, np.int32),
               fx.aval(sh.ecnt_s.shape, np.int32),
               fx.aval(sh.e0_s.shape, np.int32))
-             + tuple(fx.aval(a.shape, np.int32) for a in sh.nbr_s)
-             + tuple(fx.aval(a.shape, np.int32) for a in sh.et_s))
+             + _sharded_table_avals(fx, sh.tables_s))
     return [(("mesh_sparse_bfs", fx.ell.shape_sig(), fx.etypes,
               fx.steps, cap, k, cap_x, cap_e, fx.qmax, True), kern,
              avals)]
@@ -2866,7 +3049,7 @@ register_kernel(KernelSpec(
     # plus the pre-loop hub exchange
     ici_bytes=lambda fx, k: _mesh_sparse_ici(fx, k) * fx.steps,
     shard_args=lambda fx: tuple(
-        range(5 + 2 * len(fx.ell.bucket_nbr))),
+        range(5 + 4 * len(fx.ell.bucket_nbr))),
     shard_outs=(0,)))
 def _mesh_sparse_bfs_ici(fx, k):
     """Per BFS level (the while body traces once): the candidate
@@ -2885,5 +3068,5 @@ register_kernel(KernelSpec(
     collective=(("all_to_all", ("parts",)), ("psum", ("parts",))),
     ici_bytes=_mesh_sparse_bfs_ici,
     shard_args=lambda fx: tuple(
-        range(7 + 2 * len(fx.ell.bucket_nbr))),
+        range(7 + 4 * len(fx.ell.bucket_nbr))),
     shard_outs=(0, 1)))

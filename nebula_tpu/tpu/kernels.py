@@ -210,13 +210,14 @@ class AuditFixture:
         return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
 
     def table_avals(self) -> Tuple:
-        """(owner, *bucket_nbr, *bucket_et) avals — mirror-resident."""
+        """(owner, *bucket_nbr, *bucket_et, *out_nbr, *out_et) avals —
+        mirror-resident (EllIndex.kernel_args order)."""
         ix = self.ell
         return ((self.aval((len(ix.extra_owner),), np.int32),)
-                + tuple(self.aval(a.shape, np.int32)
-                        for a in ix.bucket_nbr)
-                + tuple(self.aval(a.shape, np.int32)
-                        for a in ix.bucket_et))
+                + tuple(self.aval(a.shape, a.dtype)
+                        for group in (ix.bucket_nbr, ix.bucket_et,
+                                      ix.out_nbr, ix.out_et)
+                        for a in group))
 
     def edge_avals(self) -> Tuple:
         i32 = np.int32

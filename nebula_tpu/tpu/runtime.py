@@ -432,7 +432,7 @@ flags.define(
     "degree growth overflows an existing vertex's resident slot row "
     "claims one IN PLACE instead of paying the slot-overflow "
     "re-bucketing rebuild (narrow scope: non-hub existing vertices; "
-    "new-vertex ingest still rebuilds).  ~tpu_ell_cap*8 bytes of HBM "
+    "new-vertex ingest still rebuilds).  ~tpu_ell_cap*10 bytes of HBM "
     "per spare; 0 disables growth (docs/durability.md decision table)")
 flags.define(
     "mirror_refresh_mode", "sync",
@@ -581,11 +581,12 @@ DEVICE_PHASES = {
     "sparse_go": {"phases": ("tpu.launch", "tpu.kernel", "tpu.fetch",
                              "tpu.assemble"), "h2d": 2, "d2h": 1},
     # delta absorption: per-dispatch uploads are the O(delta)
-    # replacement-row triples; the two "fetches" are the next
-    # generation's tables, which STAY resident (they become the
-    # published generation's device arrays — nothing crosses the link
-    # back)
-    "ell_absorb": {"phases": ("tpu.absorb",), "h2d": 3, "d2h": 2},
+    # replacement-row triples of each direction's table; the four
+    # "fetches" are the next generation's tables (index and etype
+    # columns of the in- and the out-table), which STAY resident (they
+    # become the published generation's device arrays — nothing
+    # crosses the link back)
+    "ell_absorb": {"phases": ("tpu.absorb",), "h2d": 6, "d2h": 4},
     # the second fetch is the loop's int32[3] info vector: levels run,
     # levels that pushed, slots the pushed levels visited (12 bytes)
     "ell_bfs": {"phases": ("tpu.kernel", "tpu.fetch"), "h2d": 2,
@@ -696,9 +697,13 @@ class TpuQueryRuntime:
                       "sparse_overflows": 0,
                       # continuous hops by the branch the program took
                       # on the device (ell.make_continuous_hop_kernel):
-                      # push out of the live slot rows / pull over the
-                      # whole table
+                      # push out of the live slot rows / pull over
+                      # every slot of the table(s) read; and the hops
+                      # (BFS levels too) that read one direction's
+                      # table only: all of them unless an OVER set has
+                      # both signs
                       "hop_sparse": 0, "hop_dense": 0,
+                      "hop_onesided": 0,
                       "prewarm_compiled": 0, "prewarm_hits": 0,
                       "prewarm_misses": 0, "prewarm_failed": 0,
                       "t_launch_s": 0.0, "t_fetch_s": 0.0,
@@ -1293,32 +1298,31 @@ class TpuQueryRuntime:
             # tables; the outputs seed the next generation's device
             # arrays (the old generation's buffers are not donated —
             # in-flight dispatches still read them)
-            nbr_dev, et_dev, owner_dev = ix.device_arrays()
+            owner_dev, *tables = ix.kernel_args()
             kern = self._kernel(
                 ("ell_absorb", ix.shape_sig(), counts),
                 lambda: make_ell_absorb_kernel(ix, counts))
-            outs = kern(*rows_a, *nn_a, *ne_a, *nbr_dev, *et_dev)
+            outs = kern(*rows_a, *nn_a, *ne_a, *tables)
             if claims:
                 # a claimed spare changed extra_owner content: the
                 # next generation's owner scatter needs the NEW array
                 # on device (a few bytes — never the table re-upload)
                 owner_dev = jnp.asarray(ix2.extra_owner)
-            ix2._device = (list(outs[:nb]), list(outs[nb:]), owner_dev)
+            ix2._device = tuple(list(outs[g * nb:(g + 1) * nb])
+                                for g in range(4)) + (owner_dev,)
         cached = getattr(m, "_mesh_tables_cache", None)
         if cached is not None and cached[1] is not None:
             # per-shard absorption of the resident replicated-frontier
             # mesh tables: each chip applies only the rows it owns —
             # zero collectives, zero ICI (meshaudit-declared)
-            k, tables = cached
-            mesh, nbrs, ets, reals = tables
-            padded = [int(a.shape[0]) for a in nbrs]
+            k, (mesh, tables, reals) = cached
+            padded = [int(a.shape[0]) for a in tables[:nb]]
             skern = self._kernel(
                 ("ell_absorb_sharded", ix.shape_sig(), counts, k),
                 lambda: make_sharded_ell_absorb_kernel(
                     mesh, "parts", ix, padded, counts))
-            souts = skern(*rows_a, *nn_a, *ne_a, *nbrs, *ets)
-            new_m._mesh_tables_cache = (
-                k, (mesh, list(souts[:nb]), list(souts[nb:]), reals))
+            souts = skern(*rows_a, *nn_a, *ne_a, *tables)
+            new_m._mesh_tables_cache = (k, (mesh, tuple(souts), reals))
         # the frontier-sharded (ShardedEll) per-chunk tables rebuild
         # lazily from the UPDATED host arrays on the next mesh-sparse
         # query — a device_put, never a store re-scan
@@ -2236,7 +2240,7 @@ class TpuQueryRuntime:
         args = sharded_device_args(mesh, "parts", sh)
         with tracing.span("tpu.kernel", kind="mesh_sparse_go"):
             out_dev = kern(jnp.asarray(placed[0]), jnp.asarray(placed[1]),
-                           args[0], args[1], args[2], *args[3], *args[4])
+                           args[0], args[1], args[2], *args[3])
         self._bump("go_mesh_sparse")
         # live ICI accounting: per hop the candidate router ships two
         # [k, cap_x] int32 planes, the hub router two [k, cap_e], and
@@ -2287,20 +2291,20 @@ class TpuQueryRuntime:
         f0_dev = self._upload_frontier_packed(
             ix, ix.perm[d_all], q_all.astype(np.int32), B)
         eslot, hrows = self._hub_merge_dev(m, ix)
-        hop_bytes = dense_hop_bytes(ix, lanes_width(B), steps)
+        hop_bytes = dense_hop_bytes(ix, et_tuple, lanes_width(B), steps)
         if mesh_mt is not None:
-            mesh, nbrs, ets, reals = mesh_mt
+            mesh, tables, reals = mesh_mt
             kern = self._kernel(
                 ("ell_go_sharded", ix.shape_sig(), et_tuple, steps,
                  mesh.shape["parts"]),
                 # donate=True: f0p is fresh per dispatch, same as the
                 # single-chip packed kernel
                 lambda: make_sharded_batched_go_kernel(
-                    mesh, "parts", ix, steps, et_tuple, nbrs, ets, reals,
+                    mesh, "parts", ix, steps, et_tuple, reals,
                     donate=True))
             with tracing.span("tpu.kernel", kind="ell_go_sharded",
                               width=B, packed=True):
-                out_dev = kern(f0_dev, eslot, hrows, *nbrs, *ets)
+                out_dev = kern(f0_dev, eslot, hrows, *tables)
             # live ICI accounting: steps-1 frontier re-replications,
             # (k-1)/k of the packed [n_rows+1, W] matrix each
             fbytes = (ix.n_rows + 1) * lanes_width(B)
@@ -3505,7 +3509,7 @@ class TpuQueryRuntime:
         return mesh
 
     def _mesh_tables(self, m: CsrMirror, ix: EllIndex):
-        """(mesh, nbr_shards, et_shards, real_rows) when
+        """(mesh, tables, real_rows) — ell.shard_ell's — when
         tpu_mesh_devices > 1, else None.  Sharded tables are cached on
         the mirror alongside the ELL so they follow its lifecycle."""
         mesh = self._mesh_only()
@@ -3631,7 +3635,7 @@ class TpuQueryRuntime:
         from .ell import (BFS_INFO_LEVELS, BFS_INFO_PUSHED, INT16_INF,
                           bfs_slots, dense_hop_bytes, lanes_width,
                           make_batched_bfs_lanes_kernel,
-                          make_sharded_batched_bfs_kernel)
+                          make_sharded_batched_bfs_kernel, sides_read)
         import time
         stamps = [time.perf_counter()]
         ix = self.ell(m)
@@ -3653,15 +3657,15 @@ class TpuQueryRuntime:
         t0_dev = self._upload_frontier_packed(
             ix, *self._flat_coords(m, ix, targets_per_query, nq), B)
         if mt is not None:
-            mesh, nbrs, ets, reals = mt
+            mesh, tables, reals = mt
             kern = self._kernel(
                 ("ell_bfs_sharded", ix.shape_sig(), et_tuple, max_steps,
                  shortest, mesh.shape["parts"]),
                 # donate=True: f0p/t0p are built fresh per dispatch
                 lambda: make_sharded_batched_bfs_kernel(
-                    mesh, "parts", ix, max_steps, et_tuple, nbrs, ets,
-                    reals, stop_when_found=shortest, donate=True))
-            call_args = (f0_dev, t0_dev, eslot, hrows, *nbrs, *ets)
+                    mesh, "parts", ix, max_steps, et_tuple, reals,
+                    stop_when_found=shortest, donate=True))
+            call_args = (f0_dev, t0_dev, eslot, hrows, *tables)
         else:
             kern = self._kernel(
                 ("ell_bfs_packed", ix.shape_sig(), et_tuple, max_steps,
@@ -3678,7 +3682,8 @@ class TpuQueryRuntime:
                           else "ell_bfs_sharded", queries=nq):
             d_dev, info_dev = kern(*call_args)
         self._maybe_time_device(
-            d_dev, dense_hop_bytes(ix, lanes_width(B), max_steps + 1),
+            d_dev, dense_hop_bytes(ix, et_tuple, lanes_width(B),
+                                   max_steps + 1),
             kind="ell_bfs")
         stamps.append(time.perf_counter())
         nqp = min(B, max(8, -(-nq // 8) * 8))
@@ -3713,9 +3718,14 @@ class TpuQueryRuntime:
         else:
             levels_push = int(info[BFS_INFO_PUSHED])
             self._bump("path_levels_push", levels_push)
+            # the levels that read one direction's table only: all of
+            # them, or (a mixed-sign OVER set) none
+            onesided = levels if sides_read(et_tuple) == 1 else 0
+            self._bump("hop_onesided", onesided)
             _flight.recorder.note_dispatch(
                 "ell_bfs", rung=B, steps=max_steps, levels=levels,
-                levels_push=levels_push, slots=bfs_slots(ix, info),
+                levels_push=levels_push, hop_onesided=onesided,
+                slots=bfs_slots(ix, et_tuple, info),
                 queries=nq, **stages)
         if host.dtype == np.int8:        # in-kernel compression (-1=INF)
             d = np.where(host < 0, INT16_INF, host).astype(np.int16)
@@ -3784,7 +3794,7 @@ class TpuQueryRuntime:
             dep_dev, ovf_dev = kern(
                 jnp.asarray(ps[0]), jnp.asarray(ps[1]),
                 jnp.asarray(pt[0]), jnp.asarray(pt[1]),
-                args[0], args[1], args[2], *args[3], *args[4])
+                args[0], args[1], args[2], *args[3])
         if np.asarray(ovf_dev).any():
             self._bump("sparse_overflows")
             return None
@@ -3983,12 +3993,14 @@ class _ContinuousGoSession:
     def __init__(self, rt, space_id: int, m: CsrMirror, ix: EllIndex,
                  et_tuple: Tuple[int, ...], B: int):
         import jax.numpy as jnp
-        from .ell import lanes_width
+        from .ell import lanes_width, sides_read
         self.rt = rt
         self.space_id = space_id
         self.m = m
         self.ix = ix
         self.et_tuple = et_tuple
+        # every hop of this stream reads one direction's table only
+        self._onesided = sides_read(et_tuple) == 1
         self.B = B                          # lane count (width rung)
         self.W = lanes_width(B)
         self._tables = ix.kernel_args()[1:]  # mirror-resident buckets
@@ -4002,7 +4014,7 @@ class _ContinuousGoSession:
         # first (hop_reads); bounded, so a caller that never reads
         # them forgets the oldest
         self._hop_info: collections.deque = collections.deque(maxlen=64)
-        self._hop_read = [0, 0, 0]      # read, not yet in a tick record
+        self._hop_read = [0, 0, 0, 0]   # read, not yet in a tick record
 
     def join(self, joiners) -> None:
         """Scatter the arrivals' start frontiers into their assigned
@@ -4076,18 +4088,22 @@ class _ContinuousGoSession:
             sparse += int(info[HOP_INFO_SPARSE])
             self._hop_read[2] += int(info[HOP_INFO_SLOTS])
         if reads:
+            onesided = reads if self._onesided else 0
             self._hop_read[0] += reads
             self._hop_read[1] += sparse
+            self._hop_read[3] += onesided
             self.rt._bump("hop_sparse", sparse)
             self.rt._bump("hop_dense", reads - sparse)
+            self.rt._bump("hop_onesided", onesided)
 
-    def hop_reads(self) -> Tuple[int, int, int]:
-        """(hops read, of them pushed, ELL slots they visited) since
-        the last call — the tick record's hop_reads / hop_sparse /
-        hop_slots."""
+    def hop_reads(self) -> Tuple[int, int, int, int]:
+        """(hops read, of them pushed, ELL slots they visited, hops
+        that read one direction's table only) since the last call —
+        the tick record's hop_reads / hop_sparse / hop_slots /
+        hop_onesided."""
         self.read_hop_info()
         out = tuple(self._hop_read)
-        self._hop_read = [0, 0, 0]
+        self._hop_read = [0, 0, 0, 0]
         return out
 
     def extract(self, leavers):

@@ -25,8 +25,8 @@ def _lanes_args(ix, *host_frontiers):
 
 def _mirror_edges(es, ed, ee):
     """An edge list in the mirror's form: every edge stored both ways,
-    the reverse under -etype (EllIndex.build's contract; a level that
-    pushes walks a row's -t slots)."""
+    the reverse under -etype (EllIndex.build's contract: the -etype
+    rows fill the out-table, which a level that pushes walks)."""
     return (np.concatenate([es, ed]), np.concatenate([ed, es]),
             np.concatenate([ee, -ee]))
 
@@ -225,13 +225,13 @@ def test_sharded_batched_go_parity():
     ref = run_go(ix, steps, (1,), f0)
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("parts",))
-    nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
+    shards, reals = E.shard_ell(mesh, "parts", ix)
     go = E.make_sharded_batched_go_kernel(mesh, "parts", ix, steps, (1,),
-                                          nbrs, ets, reals)
+                                          reals)
     eslot, hrows = ix.hub_merge()
     got = np.asarray(go(jnp.asarray(E.pack_lanes_host(f0)),
                         jnp.asarray(eslot), jnp.asarray(hrows),
-                        *nbrs, *ets))
+                        *shards))
     np.testing.assert_array_equal(E.unpack_lanes_host(got, 128), ref)
 
 
@@ -414,10 +414,11 @@ def test_native_builder_identical():
             np.testing.assert_array_equal(a.perm, b.perm)
             np.testing.assert_array_equal(a.inv, b.inv)
             np.testing.assert_array_equal(a.extra_owner, b.extra_owner)
-            for x, y in zip(a.bucket_nbr, b.bucket_nbr):
-                np.testing.assert_array_equal(x, y)
-            for x, y in zip(a.bucket_et, b.bucket_et):
-                np.testing.assert_array_equal(x, y)
+            assert a.shape_sig() == b.shape_sig()
+            for x, y in zip(a.tables_host(), b.tables_host()):
+                for xa, ya in zip(x, y):
+                    assert xa.dtype == ya.dtype
+                    np.testing.assert_array_equal(xa, ya)
 
 
 def _lone_go_cluster():
@@ -652,7 +653,7 @@ def test_frontier_sharded_sparse_go_bitmatch():
         assert placed is not None
         args = E.sharded_device_args(mesh, "parts", sh)
         out = kern(jnp.asarray(placed[0]), jnp.asarray(placed[1]),
-                   args[0], args[1], args[2], *args[3], *args[4])
+                   args[0], args[1], args[2], *args[3])
         overflow, oq, ou = E.sharded_sparse_pairs(np.asarray(out))
         if overflow:
             continue
@@ -713,7 +714,7 @@ def test_frontier_sharded_sparse_bfs_bitmatch():
         a = E.sharded_device_args(mesh, "parts", sh)
         dep, ovf = kern(jnp.asarray(ps[0]), jnp.asarray(ps[1]),
                         jnp.asarray(pt[0]), jnp.asarray(pt[1]),
-                        a[0], a[1], a[2], *a[3], *a[4])
+                        a[0], a[1], a[2], *a[3])
         assert not np.asarray(ovf).any()
         got = np.asarray(dep).reshape(8 * sh.chunk, 128)[:ix.n_rows + 1]
         # strict equality incl. shortest mode: both kernels run whole
@@ -772,14 +773,16 @@ def _live_slot_rows(ix, rows):
     return int(len(rows) + ecnt[np.asarray(rows, np.int64)].sum())
 
 
-def _live_slots(ix, rows):
+def _live_slots(ix, rows, etypes):
     """ELL slots a push out of these live vertices (new ids) visits:
-    the widths of their main rows and of their hub extra rows."""
+    the widths of their main rows and of their hub extra rows, in each
+    table the OVER set reads."""
     widths = np.concatenate([np.full(nbr.shape[0], nbr.shape[1])
                              for nbr in ix.bucket_nbr])
     ecnt, e0 = ix.hub_expansion()
-    return sum(int(widths[r]) + int(widths[e0[r]:e0[r] + ecnt[r]].sum())
-               for r in rows)
+    return E.sides_read(etypes) * sum(
+        int(widths[r]) + int(widths[e0[r]:e0[r] + ecnt[r]].sum())
+        for r in rows)
 
 
 def _pull_reference(ix, etypes, fp, accp):
@@ -787,7 +790,7 @@ def _pull_reference(ix, etypes, fp, accp):
     tables = ix.kernel_args()[1:]
     eslot, hrows = ix.hub_merge()
     nxt = E._hop_body_packed(jnp, jax, ix.n, len(ix.extra_owner),
-                             tuple(etypes), tables[:nb], tables[nb:],
+                             E._read_sides(tuple(etypes), tables, nb),
                              jnp.asarray(eslot), jnp.asarray(hrows),
                              jnp.asarray(fp))
     return np.asarray(nxt), np.asarray(jnp.asarray(accp) | nxt)
@@ -856,9 +859,9 @@ def test_continuous_hop_agrees_with_the_pull(name, etypes, n_live, words,
     assert info[E.HOP_INFO_SPARSE] == int(pushed)
     assert info[E.HOP_INFO_ROWS] == live
     if pushed:
-        assert info[E.HOP_INFO_SLOTS] == _live_slots(ix, rows)
+        assert info[E.HOP_INFO_SLOTS] == _live_slots(ix, rows, etypes)
     else:
-        assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix)
+        assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix, etypes)
 
 
 def test_continuous_hop_chain_push_after_pull_after_push():
@@ -967,11 +970,11 @@ def test_bfs_level_follows_its_frontier(budget, shortest, graph):
     assert (d[:, len(starts):] == E.INT16_INF).all()
     assert info[E.BFS_INFO_LEVELS] == want_levels
     assert info[E.BFS_INFO_PUSHED] == sum(pushed)
-    push_slots = sum(_live_slots(ix, rows)
+    push_slots = sum(_live_slots(ix, rows, etypes)
                      for rows, p in zip(fronts, pushed) if p)
     assert info[E.BFS_INFO_PUSH_SLOTS] == push_slots
-    assert E.bfs_slots(ix, info) == push_slots \
-        + (want_levels - sum(pushed)) * E.table_slots(ix)
+    assert E.bfs_slots(ix, etypes, info) == push_slots \
+        + (want_levels - sum(pushed)) * E.table_slots(ix, etypes)
 
 
 def test_runtime_bfs_record_says_how_its_levels_ran():
@@ -992,9 +995,307 @@ def test_runtime_bfs_record_says_how_its_levels_ran():
         # frontiers of one or two vertices: every level pushes, and
         # visits the live rows' slots, not the table
         assert record["levels_push"] == 3
-        assert 0 < record["slots"] < 3 * E.table_slots(rt.ell(m))
+        # OVER one edge type forwards: every level read one table
+        assert record["hop_onesided"] == 3
+        assert rt.stats["hop_onesided"] - before["hop_onesided"] == 3
+        assert 0 < record["slots"] < 3 * E.table_slots(rt.ell(m), (et,))
         assert rt.stats["path_levels"] - before["path_levels"] == 3
         assert rt.stats["path_levels_push"] \
             - before["path_levels_push"] == 3
     finally:
         c.stop()
+
+
+# ============================================================
+# One table a direction (PR 35): the in-table holds a vertex's in-edge
+# sources, the out-table its out-edge targets, over one row layout; a
+# step reads only the table(s) its OVER set has a member for.
+# ============================================================
+SPLIT_CAP = 8
+
+
+def _split_graph():
+    """(n, src, dst, etype) of a directed two-type graph whose shapes
+    the split has to get right: vertex 0 takes 20 in-edges (past the
+    cap of 8) and has ONE out-edge, vertex 2 the reverse, 4 is a sink,
+    5 a source, 39 isolated; pairs under both types and a pair named
+    twice under one."""
+    rng = np.random.default_rng(35)
+    n = 40
+    fans = np.arange(10, 30)
+    src = [fans, np.full(20, 2), [0, 3, 5, 6, 5]]
+    dst = [np.zeros(20, int), fans, [1, 2, 4, 4, 7]]
+    et = [rng.choice([1, 2], 20), rng.choice([1, 2], 20), [1, 1, 1, 2, 2]]
+    extra = 60
+    es = rng.integers(6, 39, extra)
+    src.append(es)
+    dst.append((es + rng.integers(1, 30, extra) - 6) % 33 + 6)
+    et.append(rng.choice([1, 2], extra))
+    src, dst, et = (np.concatenate([np.asarray(a, np.int32) for a in x])
+                    for x in (src, dst, et))
+    src = np.append(src, [8, 8]).astype(np.int32)      # one pair twice
+    dst = np.append(dst, [9, 9]).astype(np.int32)
+    et = np.append(et, [1, 1]).astype(np.int32)
+    touched = np.union1d(src, dst)
+    assert 39 not in touched and 4 not in src and 5 not in dst
+    return n, src, dst, et
+
+
+def _split_ell(**kw):
+    n, src, dst, et = _split_graph()
+    return _mirror_ell(src, dst, et, n, cap=SPLIT_CAP, min_d=2, **kw), \
+        (n, src, dst, et)
+
+
+def _walk_step(n, src, dst, et, etypes, f):
+    """Brute force, one edge at a time: bool [n, B] next frontier of
+    ``f`` over a signed OVER set (+t follows u -> v, -t walks it
+    back)."""
+    nxt = np.zeros_like(f)
+    for u, v, t in zip(src, dst, et):
+        if t in etypes:
+            nxt[v] |= f[u]
+        if -t in etypes:
+            nxt[u] |= f[v]
+    return nxt
+
+
+def _rows_of(ix, v_new):
+    """Global slot rows of a vertex (new id): main row + extra rows."""
+    ecnt, e0 = ix.hub_expansion()
+    return [v_new] + list(range(e0[v_new], e0[v_new] + ecnt[v_new]))
+
+
+def _slots_of(ix, nbrs, ets, v_new):
+    """Sorted (old neighbour id, |etype|) entries of a vertex's rows in
+    one table."""
+    bstarts = np.cumsum([0] + [a.shape[0] for a in ix.bucket_nbr])
+    out = []
+    for row in _rows_of(ix, v_new):
+        b = int(np.searchsorted(bstarts, row, side="right")) - 1
+        nbr, et = nbrs[b][row - bstarts[b]], ets[b][row - bstarts[b]]
+        fill = nbr != ix.n_rows
+        assert (et[~fill] == 0).all()
+        out += list(zip(ix.inv[nbr[fill]].tolist(), et[fill].tolist()))
+    return sorted(out)
+
+
+@pytest.mark.parametrize("native", [False, True], ids=["numpy", "native"])
+def test_split_build_holds_each_direction_in_its_own_table(native):
+    ix, (n, src, dst, et) = _split_ell(use_native=native, growth_slack=2)
+    # one row layout: both tables have the same shapes, rows sum to
+    # n_rows, the bucket is the power of two over the LARGER side
+    assert [a.shape for a in ix.bucket_nbr] == \
+        [a.shape for a in ix.out_nbr] == \
+        [a.shape for a in ix.bucket_et] == [a.shape for a in ix.out_et]
+    assert sum(a.shape[0] for a in ix.bucket_nbr) == ix.n_rows
+    deg_in = np.bincount(dst, minlength=n)
+    deg_out = np.bincount(src, minlength=n)
+    widths = np.concatenate([np.full(a.shape[0], a.shape[1])
+                             for a in ix.bucket_nbr])
+    want_w = np.clip(E._next_pow2(np.minimum(
+        np.maximum(deg_in, deg_out), SPLIT_CAP)), 2, SPLIT_CAP)
+    assert (widths[ix.perm] == want_w).all()
+    # a hub by EITHER side owns ceil(max / cap) - 1 extra rows, the
+    # same rows in both tables; nobody else owns any
+    ecnt, _ = ix.hub_expansion()
+    assert deg_in[0] == 20 and deg_out[0] == 1
+    assert deg_out[2] == 20 and deg_in[2] == 1
+    big = np.maximum(deg_in, deg_out)
+    want_extra = np.where(big > SPLIT_CAP, -(-big // SPLIT_CAP) - 1, 0)
+    assert (ecnt[ix.perm] == want_extra).all() and want_extra[0] == 2
+    assert len(ix.extra_owner) == want_extra.sum() + 2   # + the spares
+    # every vertex: its in-table rows hold its in-edges' sources, its
+    # out-table rows its out-edges' targets, magnitudes only
+    for v in range(n):
+        r = int(ix.perm[v])
+        assert _slots_of(ix, ix.bucket_nbr, ix.bucket_et, r) == sorted(
+            zip(src[dst == v].tolist(), et[dst == v].tolist())), v
+        assert _slots_of(ix, ix.out_nbr, ix.out_et, r) == sorted(
+            zip(dst[src == v].tolist(), et[src == v].tolist())), v
+
+
+SPLIT_OVER = {"over_e": (1,), "over_e_reversely": (-1,),
+              "mixed_sign": (1, -2), "two_types": (1, 2),
+              "two_types_reversely": (-1, -2)}
+
+
+@pytest.mark.parametrize("branch", ["pull", "push"])
+@pytest.mark.parametrize("over", sorted(SPLIT_OVER))
+def test_split_step_is_the_brute_force_walk(over, branch):
+    """push = pull = the edge-by-edge walk on the same frontiers, hubs
+    of either side, the sink, the source and the isolated vertex among
+    the live rows; and the step says which table(s) it read."""
+    etypes = SPLIT_OVER[over]
+    ix, (n, src, dst, et) = _split_ell(growth_slack=2)
+    B = 16
+    starts = [[0], [2], [4], [5], [39], [1, 3], [10, 29], [0, 2, 8],
+              [12], [6, 7]]
+    f0 = ix.start_frontier([np.asarray(s) for s in starts], B=B)
+    fp = E.pack_lanes_host(f0)
+    one_table = sum(a.size for a in ix.bucket_nbr)
+    assert E.table_slots(ix, etypes) == E.sides_read(etypes) * one_table
+    sel = np.r_[0:ix.n, ix.n_rows]
+    want = ix.to_old(f0).astype(bool)
+    for _hop in range(3):
+        live = _live_slot_rows(ix, np.flatnonzero(
+            E.unpack_lanes_host(fp, B)[:ix.n].any(axis=1)))
+        budget = max(live, 1) if branch == "push" else 1
+        nxt, _acc, info = _run_hop(ix, etypes, fp, fp, budget)
+        want = _walk_step(n, src, dst, et, etypes, want)
+        got = E.unpack_lanes_host(nxt, B)
+        assert np.array_equal(ix.to_old(got), want), (over, _hop)
+        assert not nxt[ix.n_rows].any()
+        pushed = branch == "push" or live <= 1
+        assert info[E.HOP_INFO_SPARSE] == int(pushed)
+        if not pushed:
+            # a pull sweeps every slot of the table(s) read, no more
+            assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix, etypes)
+        # extra rows are scratch: clear them as a reader would see them
+        fp = np.zeros_like(nxt)
+        fp[sel] = nxt[sel]
+    # the windowed programs read the same tables: dense and pair-list
+    dense = run_go(ix, 4, etypes, f0)
+    assert np.array_equal(ix.to_old(dense), want)
+
+
+@pytest.mark.parametrize("top,dtype", [(1, np.int8), (127, np.int8),
+                                       (128, np.int16), (32767, np.int16),
+                                       (32768, np.int32)])
+def test_etype_column_is_as_narrow_as_the_largest_etype(top, dtype):
+    """The etype columns take the narrowest signed integer type that
+    holds the mirror's largest |etype|: read off the input at build,
+    part of shape_sig, and what the mask compares in."""
+    n, src, dst, et = _split_graph()
+    et = np.where(et == 2, top, 1).astype(np.int32)
+    sigs = set()
+    for native in (False, True):
+        ix = _mirror_ell(src, dst, et, n, cap=SPLIT_CAP, min_d=2,
+                         use_native=native)
+        assert ix.et_dtype == dtype
+        assert all(a.dtype == dtype for a in ix.bucket_et + ix.out_et)
+        assert all(a.dtype == np.int32 for a in ix.bucket_nbr + ix.out_nbr)
+        assert ix.shape_sig()[-1] == np.dtype(dtype).name
+        sigs.add(ix.shape_sig())
+    assert len(sigs) == 1
+    narrow = _mirror_ell(src, dst, np.minimum(et, 2), n, cap=SPLIT_CAP,
+                         min_d=2)
+    if dtype != np.int8:
+        assert narrow.shape_sig() != ix.shape_sig()
+    f0 = ix.start_frontier([np.asarray([0, 2, 8]), np.asarray([5])], B=8)
+    for etypes in ((top,), (-top,), (1, top), (top + 1,)):
+        got = run_go(ix, 3, etypes, f0)
+        want = ix.to_old(f0).astype(bool)
+        for _ in range(2):
+            want = _walk_step(n, src, dst, et, etypes, want)
+        assert np.array_equal(ix.to_old(got), want), etypes
+
+
+def _absorb_case(kind, ix, n, src, dst, et):
+    """(inserted (u, v, t) edges, deleted ones) for one absorb window."""
+    if kind == "delete":
+        gone = [(int(src[i]), int(dst[i]), int(et[i])) for i in (0, 21, 45)]
+        return [], gone
+    widths = np.concatenate([np.full(a.shape[0], a.shape[1])
+                             for a in ix.bucket_nbr])[ix.perm]
+    # free slots of each non-hub vertex's one row, by direction
+    free_in = widths - np.bincount(dst, minlength=n)
+    free_out = widths - np.bincount(src, minlength=n)
+    free_in[[0, 2]] = free_out[[0, 2]] = 0
+    have = set(zip(src.tolist(), dst.tolist()))
+
+    def fresh_sources(v, k):
+        out = []
+        for u in range(n):
+            if len(out) < k and u != v and (u, v) not in have \
+                    and free_out[u] > 0:
+                free_out[u] -= 1
+                out.append(u)
+        assert len(out) == k
+        return out
+
+    if kind == "insert":
+        # into rows with room: the isolated vertex gains an out-edge,
+        # the source an in-edge, the sink an out-edge
+        targets = [v for v in (5, 6, 12) if free_in[v] > 0]
+        assert 5 in targets
+        return ([(u, v, 1 + i % 2) for i, v in enumerate(targets)
+                 for u in fresh_sources(v, 1)]
+                + [(39, 38, 2), (4, 39, 1)]), []
+    # claim: vertex 7's in-edges outgrow its one row by three; no hub
+    return [(u, 7, 1) for u in fresh_sources(7, int(free_in[7]) + 3)], []
+
+
+@pytest.mark.parametrize("kind", ["insert", "delete", "claim"])
+def test_absorb_lands_in_the_right_table(kind):
+    """An overlay's +etype rows rewrite in-table rows, its -etype rows
+    out-table rows; a claimed spare is the owner's in both tables; the
+    absorbed tables walk like a rebuild."""
+    ix, (n, src, dst, et) = _split_ell(growth_slack=3)
+    ins, dels = _absorb_case(kind, ix, n, src, dst, et)
+
+    def rows(edges):
+        e = np.asarray(edges, np.int32).reshape(-1, 3)
+        # the mirror's form: (dst, src, etype) rows, both directions
+        return (np.concatenate([e[:, 1], e[:, 0]]),
+                np.concatenate([e[:, 0], e[:, 1]]),
+                np.concatenate([e[:, 2], -e[:, 2]]))
+
+    claims = []
+    plan = E.plan_ell_absorb(ix, *rows(ins), *rows(dels),
+                             claims_out=claims)
+    assert plan is not None
+    nb = len(ix.bucket_nbr)
+    assert all(0 <= t < 2 * nb for t in plan)
+    ix2 = E.apply_ell_absorb_host(ix, plan, ix.m, claims=claims)
+    assert ix2.shape_sig()[:3] == ix.shape_sig()[:3]
+    keep = np.ones(len(src), bool)
+    for u, v, t in dels:
+        keep[np.flatnonzero((src == u) & (dst == v) & (et == t))[0]] = False
+    src2 = np.concatenate([src[keep], [e[0] for e in ins]]).astype(np.int32)
+    dst2 = np.concatenate([dst[keep], [e[1] for e in ins]]).astype(np.int32)
+    et2 = np.concatenate([et[keep], [e[2] for e in ins]]).astype(np.int32)
+    for v in range(n):
+        r = int(ix2.perm[v])
+        assert _slots_of(ix2, ix2.bucket_nbr, ix2.bucket_et, r) == sorted(
+            zip(src2[dst2 == v].tolist(), et2[dst2 == v].tolist())), v
+        assert _slots_of(ix2, ix2.out_nbr, ix2.out_et, r) == sorted(
+            zip(dst2[src2 == v].tolist(), et2[src2 == v].tolist())), v
+    if kind == "claim":
+        assert claims and all(o == ix.perm[7] for _i, o in claims)
+        # the spare is vertex 7's row in BOTH tables, and its
+        # out-table row stays empty: only in-edges arrived
+        for idx, _o in claims:
+            assert (ix2.out_nbr[-1][ix.n + idx - (ix.n_rows
+                    - ix.out_nbr[-1].shape[0])] == ix.n_rows).all()
+    else:
+        assert not claims
+    # untouched buckets share memory with the old generation
+    for t, (a, b) in enumerate(zip(ix.tables_host(), ix2.tables_host())):
+        assert (a[0] is b[0]) == (t not in plan)
+    # the device scatter gives the same tables as the host apply
+    counts, upd = E.absorb_update_arrays(ix, plan)
+    outs = E.make_ell_absorb_kernel(ix, counts)(
+        *[jnp.asarray(u[0]) for u in upd],
+        *[jnp.asarray(u[1]) for u in upd],
+        *[jnp.asarray(u[2]) for u in upd], *ix.kernel_args()[1:])
+    for got, w in zip(outs, ix2.bucket_nbr + ix2.bucket_et
+                      + ix2.out_nbr + ix2.out_et):
+        assert got.dtype == w.dtype and np.array_equal(np.asarray(got), w)
+    f0 = ix2.start_frontier([np.asarray([5]), np.asarray([0, 2, 39]),
+                             np.asarray([4, 7])], B=8)
+    for etypes in ((1,), (-1, -2), (2, -1)):
+        want = ix2.to_old(f0).astype(bool)
+        for _ in range(2):
+            want = _walk_step(n, src2, dst2, et2, etypes, want)
+        assert np.array_equal(ix2.to_old(run_go(ix2, 3, etypes, f0)),
+                              want), (kind, etypes)
+
+
+def test_absorb_declines_an_etype_the_column_cannot_hold():
+    ix, _ = _split_ell(growth_slack=2)
+    assert ix.et_dtype == np.int8
+    one = lambda *v: np.asarray(v, np.int32)          # noqa: E731
+    none = np.zeros(0, np.int32)
+    assert E.plan_ell_absorb(ix, one(4), one(5), one(200),
+                             none, none, none) is None

@@ -59,8 +59,10 @@ def served():
     E.HOP_PUSH_ROWS = 8
     # no background compiles of the other rungs: on CPU jax the
     # windowed tier's sort-based programs take minutes each
+    # slot width capped at 256: the rehearsal graph's widest vertex has
+    # 403 in-edges, a hub with an extra row at that cap
     with flags_set({**shipped_defaults(), "go_backend_router": False,
-                    "tpu_prewarm_kernels": False}):
+                    "tpu_prewarm_kernels": False, "tpu_ell_cap": 256}):
         c = LocalCluster(num_storage=1, tpu_backend=True)
         g = c.client()
 

@@ -17,11 +17,13 @@ import time
 import numpy as np
 import pytest
 
-# graph500-s20's buckets (rows, width) as the cell builds them, its
-# vertices, slot rows and hub extra rows (PERF.md §5)
-S20_BUCKETS = [(385823, 8), (94937, 16), (30842, 32), (73934, 64),
-               (9372, 128), (29473, 256), (45260, 512)]
-S20_N, S20_ROWS, S20_EXTRAS, S20_HUBS = 646081, 669641, 23560, 6198
+# graph500-s20's buckets (rows, width) as the cell builds them — the
+# same in each direction's table (PR 35) — its vertices, slot rows and
+# hub extra rows with the 8 growth spares (PERF.md §5); the etype
+# columns are int8 there (one edge type)
+S20_BUCKETS = [(452588, 8), (56666, 16), (74253, 32), (6223, 64),
+               (34651, 128), (15422, 256), (17871, 512)]
+S20_N, S20_ROWS, S20_EXTRAS, S20_HUBS = 646081, 657674, 11593, 6197
 LANES = 128
 
 
@@ -47,7 +49,8 @@ class _Shapes:
         self.n, self.n_rows = S20_N, S20_ROWS
         self.extra_owner = np.zeros(S20_EXTRAS, np.int32)
         self.bucket_nbr = [np.zeros(s, np.int32) for s in S20_BUCKETS]
-        self.bucket_et = self.bucket_nbr
+        self.bucket_et = [np.zeros(s, np.int8) for s in S20_BUCKETS]
+        self.out_nbr, self.out_et = self.bucket_nbr, self.bucket_et
 
 
 def _compile(fn, one_chip):
@@ -58,8 +61,11 @@ def _compile(fn, one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fp = sd((S20_ROWS + 1, E.lanes_width(LANES)), np.uint8)
+    # tables as EllIndex.kernel_args orders them: (*in_nbr, *in_et,
+    # *out_nbr, *out_et)
     args = (fp, fp, sd((S20_EXTRAS,), np.int32), sd((S20_HUBS,), np.int32)) \
-        + tuple(sd(s, np.int32) for s in S20_BUCKETS) * 2
+        + (tuple(sd(s, np.int32) for s in S20_BUCKETS)
+           + tuple(sd(s, np.int8) for s in S20_BUCKETS)) * 2
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     return compiled, time.perf_counter() - t0
@@ -75,8 +81,9 @@ def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip):
         E.make_continuous_hop_kernel(ix, (1,), donate=True), one_chip)
 
     def pull_only(fp, accp, eslot, hrows, *tables):
-        nxt = E._hop_body_packed(jnp, jax, ix.n, S20_EXTRAS, (1,),
-                                 tables[:nb], tables[nb:], eslot, hrows, fp)
+        nxt = E._hop_body_packed(jnp, jax, ix.n, S20_EXTRAS,
+                                 E._read_sides((1,), tables, nb),
+                                 eslot, hrows, fp)
         return nxt, accp | nxt
 
     pull, _s = _compile(jax.jit(pull_only, donate_argnums=(0, 1)),

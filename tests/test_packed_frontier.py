@@ -135,13 +135,17 @@ class TestPackedKernelParity:
             *[jnp.asarray(u[0]) for u in upd],
             *[jnp.asarray(u[1]) for u in upd],
             *[jnp.asarray(u[2]) for u in upd],
-            *[jnp.asarray(a) for a in ix.bucket_nbr],
-            *[jnp.asarray(a) for a in ix.bucket_et])
-        nb = len(ix.bucket_nbr)
-        for b in range(nb):     # device scatter == host apply
-            assert np.array_equal(np.asarray(outs[b]), ix2.bucket_nbr[b])
-            assert np.array_equal(np.asarray(outs[nb + b]),
-                                  ix2.bucket_et[b])
+            *ix.kernel_args()[1:])
+        # device scatter == host apply, in both directions' tables (the
+        # +etype inserts land in the in-table; the out-table is as it
+        # was)
+        want = ix2.bucket_nbr + ix2.bucket_et + ix2.out_nbr + ix2.out_et
+        assert len(outs) == len(want)
+        for got, w in zip(outs, want):
+            assert got.dtype == w.dtype
+            assert np.array_equal(np.asarray(got), w)
+        for a, b in zip(ix.out_nbr, ix2.out_nbr):
+            assert a is b
         # oracle: rebuild from scratch on the merged edge list (same
         # shapes by construction: inserts stay within slot slack)
         ms = np.concatenate([s2, ins_src])
@@ -572,11 +576,11 @@ class TestShardedPackedParity:
         f0 = ix.start_frontier(starts, B=B)
         eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
         mesh = self._mesh(k)
-        nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
+        shards, reals = E.shard_ell(mesh, "parts", ix)
         go = E.make_sharded_batched_go_kernel(
-            mesh, "parts", ix, steps, ETYPES, nbrs, ets, reals)
+            mesh, "parts", ix, steps, ETYPES, reals)
         out = np.asarray(go(jnp.asarray(E.pack_lanes_host(f0)),
-                            eslot, hrows, *nbrs, *ets))
+                            eslot, hrows, *shards))
         bits = E.unpack_lanes_host(out, B)
         # vs the numpy oracle (real rows; extras may hold junk)
         assert (ix.to_old(bits) == _ref_go(ix.n, s2, d2, e2, starts,
@@ -596,13 +600,13 @@ class TestShardedPackedParity:
         t0 = ix.start_frontier(targets, B=B)
         eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
         mesh = self._mesh(k)
-        nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
+        shards, reals = E.shard_ell(mesh, "parts", ix)
         bfs = E.make_sharded_batched_bfs_kernel(
-            mesh, "parts", ix, max_steps, ETYPES, nbrs, ets, reals,
+            mesh, "parts", ix, max_steps, ETYPES, reals,
             stop_when_found=shortest)
         d, levels = bfs(jnp.asarray(E.pack_lanes_host(f0)),
                         jnp.asarray(E.pack_lanes_host(t0)),
-                        eslot, hrows, *nbrs, *ets)
+                        eslot, hrows, *shards)
         d = np.asarray(d)
         # vs the single-chip kernel: every real row and the pad row
         # (hub extra rows are scratch: the single-chip program leaves
@@ -628,13 +632,13 @@ class TestShardedPackedParity:
         B = 64
         f0 = ix.start_frontier(_starts(rng, ix.n, B), B=B)
         mesh = self._mesh(2)
-        nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
+        shards, reals = E.shard_ell(mesh, "parts", ix)
         go = E.make_sharded_batched_go_kernel(
-            mesh, "parts", ix, 3, ETYPES, nbrs, ets, reals,
+            mesh, "parts", ix, 3, ETYPES, reals,
             donate=True)
         eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
         f0p = jnp.asarray(E.pack_lanes_host(f0))
-        out = go(f0p, eslot, hrows, *nbrs, *ets)
+        out = go(f0p, eslot, hrows, *shards)
         jax.block_until_ready(out)
         assert f0p.is_deleted(), \
             "donated sharded frontier must be consumed"
@@ -713,11 +717,11 @@ class TestShardedPackedParity:
         f0 = ix.start_frontier(starts, B=B)
         eslot, hrows = (jnp.asarray(a) for a in ix.hub_merge())
         mesh = self._mesh(8)
-        nbrs, ets, reals = E.shard_ell(mesh, "parts", ix)
+        shards, reals = E.shard_ell(mesh, "parts", ix)
         go = E.make_sharded_batched_go_kernel(
-            mesh, "parts", ix, steps, (1,), nbrs, ets, reals)
+            mesh, "parts", ix, steps, (1,), reals)
         out = np.asarray(go(jnp.asarray(E.pack_lanes_host(f0)),
-                            eslot, hrows, *nbrs, *ets))
+                            eslot, hrows, *shards))
         bits = E.unpack_lanes_host(out, B)
         assert (ix.to_old(bits) == _ref_go(persons, es, ed, ee, starts,
                                            steps, etypes=(1,))).all()

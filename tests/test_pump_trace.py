@@ -537,9 +537,14 @@ class TestHopBranchFields:
         before = dict(rt.stats)
         _burst(c, _mixed(6))
         ticks = _ticks()
-        for field in ("hop_reads", "hop_sparse", "hop_slots"):
+        for field in ("hop_reads", "hop_sparse", "hop_slots",
+                      "hop_onesided"):
             assert all(field in t for t in ticks), field
         reads = sum(t["hop_reads"] for t in ticks)
+        # OVER one edge type forwards: every hop read one direction's
+        # table only (PR 35), in the records and in rt.stats
+        assert all(t["hop_onesided"] == t["hop_reads"] for t in ticks)
+        assert rt.stats["hop_onesided"] - before["hop_onesided"] == reads
         # every hop of the burst was read into exactly one record
         assert reads == sum(1 for t in ticks if t["hop_us"] > 0)
         assert reads == (rt.stats["hop_sparse"] - before["hop_sparse"]
@@ -588,7 +593,7 @@ class TestHopBranchFields:
         sess.hop_reads()                    # drain what is there
         sess._hop_info.append(InFlight())
         try:
-            assert sess.hop_reads() == (0, 0, 0)
+            assert sess.hop_reads() == (0, 0, 0, 0)
             assert InFlight.asked >= 1 and len(sess._hop_info) == 1
         finally:
             sess._hop_info.clear()
