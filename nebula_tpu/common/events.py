@@ -107,6 +107,16 @@ EVENT_KINDS = (
                              # re-arms when the cell returns in-bound;
                              # docs/observability.md "The device
                              # timeline")
+    "host.stall",            # the host stood still: a beat of
+                             # common/hostclock.py was over 100 ms
+                             # late, or the pump waited that long for
+                             # the device with both beats on time.
+                             # ``who``: host (the beat that needs no
+                             # interpreter was late: the guest was
+                             # paused or had no core), interpreter
+                             # (the Python beat alone: something held
+                             # the lock), device (docs/observability.md
+                             # "The device timeline")
 )
 
 _rng = random.Random()       # event ids; independent of seeded test RNGs

@@ -149,6 +149,17 @@ class FlightRecorder:
         rec.update(fields)
         return self._note(rec)
 
+    def note_beat(self, **fields) -> int:
+        """One second of the host's two beats (common/hostclock.py):
+        ``clock`` (schedstat, or thread_time where the machine has no
+        run-queue clock and every ``*_runq_us`` is left off), the
+        Python beat's ``n``, ``py_late_sum_us``, ``py_late_max_us``
+        and, where the native beat runs, its ``nat_n``,
+        ``nat_late_sum_us``, ``nat_late_max_us``."""
+        rec = {"kind": "beat"}
+        rec.update(fields)
+        return self._note(rec)
+
     def note_dispatch(self, kernel: str, **fields) -> int:
         """One windowed/mesh kernel dispatch: kernel class, shape
         rung, h2d/d2h bytes, per-collective ICI rows when sharded."""
@@ -346,6 +357,8 @@ def chrome_trace(tree: Optional[dict] = None,
                            "cat": "phase", "name": phase[:-3],
                            "ts": cursor, "dur": us, "args": {}})
                 cursor += us
+        elif kind == "beat":
+            continue               # the host's beats are no device row
         elif kind == "timing":
             dur = int(rec.get("wall_us") or 0)
             ev.append({"ph": "X", "pid": _PUMP_PID,

@@ -79,6 +79,7 @@ from typing import Dict, List, Tuple
 
 from ..common import deadline as deadlines
 from ..common import flight
+from ..common import hostclock
 from ..common import mc_hooks
 from ..common import protocol
 from ..common import tracing
@@ -429,6 +430,47 @@ CONTINUOUS_IDLE_RELEASE_S = 30.0
 PUMP_IDLE_SPAN_MIN_US = 1000
 PUMP_TICK_RIDER_TAGS = 8
 
+# the phases of a tick that have a ``<p>_us`` in its flight record, in
+# pump order (common/flight.py note_tick): the seating block, the four
+# enqueues, and the five parts of a leave cohort's assembly.  With
+# ``other_us`` they tile ``dur_us``
+_ENQUEUE_PARTS = ("join", "hop", "extract", "clear")
+_ASSEMBLE_PARTS = ("fetch_wait", "d2h", "unpack", "rows", "handover")
+PUMP_PHASES = ("seat",) + _ENQUEUE_PARTS + _ASSEMBLE_PARTS
+
+
+class _PhaseClocks:
+    """One tick's phases on the pump thread's three clocks: per phase
+    the wall, run and runnable micros between the stamps that bound it
+    (common/hostclock.py), summed over the tick's cohorts.  Where the
+    tick's first stamp has no run-queue reading no phase has one."""
+
+    __slots__ = ("_us",)
+
+    def __init__(self, t0):
+        runq = None if t0[2] is None else 0
+        self._us = {p: [0, 0, runq] for p in PUMP_PHASES}
+
+    def add(self, phase: str, a, b) -> None:
+        wall, cpu, runq = hostclock.split(a, b)
+        acc = self._us[phase]
+        acc[0] += wall
+        acc[1] += cpu
+        acc[2] = None if runq is None or acc[2] is None \
+            else acc[2] + runq
+
+    def wall(self, phase: str) -> int:
+        return self._us[phase][0]
+
+    def fields(self) -> Dict[str, int]:
+        """``<p>_us``, ``<p>_cpu_us`` and, where the machine has the
+        clock, ``<p>_runq_us`` of every phase: the tick record's."""
+        out: Dict[str, int] = {}
+        for p, (wall, cpu, runq) in self._us.items():
+            out[p + "_us"] = wall
+            out.update(hostclock.host_fields(p + "_", cpu, runq))
+        return out
+
 
 class ContinuousUnavailable(Exception):
     """The stream could not anchor a device session for this space
@@ -462,7 +504,7 @@ class _Rider:
 
     __slots__ = ("payload", "steps", "upto", "reduce", "counts", "hops",
                  "deadline",
-                 "tctx", "enq_t", "seated_t", "left_t", "done_t",
+                 "tctx", "enq", "enq_t", "seated_t", "left_t", "done_t",
                  "lane", "remaining", "joined_tick", "left_tick",
                  "midflight", "done", "result", "frontier", "mirror",
                  "error", "qid")
@@ -482,7 +524,10 @@ class _Rider:
         # still shows mirror/launch/kernel/fetch/assemble exactly like
         # a windowed batch leader's would
         self.tctx = tracing.capture()
-        self.enq_t = time.perf_counter()
+        # the submitter's own three clocks at the enqueue
+        # (common/hostclock.py): submit() splits its wait against them
+        self.enq = hostclock.stamp()
+        self.enq_t = self.enq[0]
         # perf_counter stamps the PUMP writes as the rider moves on:
         # seated, left the seat map, frontier (or count, or error)
         # handed over.  submit() turns them into the marker's waits
@@ -741,10 +786,13 @@ class _ContinuousStream:
         (or None).  ``pending`` is the PREVIOUS tick's cohort — its
         fetch+assembly runs here, after this tick's hop is enqueued,
         which is the overlap the idle-frac gauge measures."""
-        t0 = time.perf_counter()
+        # every stamp that bounds a phase of the tick is the pump
+        # thread's three clocks (common/hostclock.py): wall, run time,
+        # time runnable without a core
+        t0 = hostclock.stamp()
         # idle gap since the previous tick ended (0 on the first tick)
         # — one column of the flight-recorder tick record
-        idle_us = (t0 - self._last_tick_end) * 1e6 \
+        idle_us = (t0[0] - self._last_tick_end) * 1e6 \
             if self._last_tick_end else 0.0
         saw_no_work = self._saw_no_work
         # pump-thread-only state (see __init__)
@@ -860,12 +908,14 @@ class _ContinuousStream:
                 self._widen_min = width + 1  # nebulint: disable=lock-discipline
         # end of the seating block (anchor + seat-map bookkeeping):
         # the tick record's seat_us, the trace's pump.seat
-        t_seat = time.perf_counter()
+        t_seat = hostclock.stamp()
+        host = _PhaseClocks(t0)
+        host.add("seat", t0, t_seat)
 
         new_pending = None
         leavers: List[_Rider] = []
         occupancy = 0
-        join_us = hop_us = extract_us = clear_us = 0.0
+        join_map_us = join_pack_us = 0
         busy = sess is not None and bool(joiners or evicted
                                          or seated_now)
         if busy:
@@ -893,17 +943,26 @@ class _ContinuousStream:
                     with tracing.span("tpu.launch",
                                       joiners=len(joiners), steps=1):
                         if joiners:
-                            tj = time.perf_counter()
+                            tj = hostclock.stamp()
                             sess.join([(r.lane, r.payload.start_vids)
                                        for r in joiners])
-                            join_us = (time.perf_counter() - tj) * 1e6
+                            host.add("join", tj, hostclock.stamp())
+                            # where the session's own marks split it
+                            # (tpu/runtime.py join): the joiners
+                            # mapped, the arrays packed; the enqueue
+                            # is what is left of join_us
+                            t_map, t_pack = getattr(
+                                sess, "join_marks", None) or (tj[0], tj[0])
+                            join_map_us = int((t_map - tj[0]) * 1e6)
+                            join_pack_us = int((t_pack - t_map) * 1e6)
                         with self.cond:
                             has_work = bool(self.seated)
                         if has_work:
-                            th = time.perf_counter()
+                            th = hostclock.stamp()
                             sess.hop()
-                            t_left = time.perf_counter()
-                            hop_us = (t_left - th) * 1e6
+                            t_hop = hostclock.stamp()
+                            host.add("hop", th, t_hop)
+                            t_left = t_hop[0]
                             with self.cond:
                                 self.tick_no += 1
                                 for lane, r in \
@@ -918,7 +977,7 @@ class _ContinuousStream:
                                         r.left_tick = self.tick_no
                                         leavers.append(r)
                     if leavers:
-                        tx = time.perf_counter()
+                        tx = hostclock.stamp()
                         # a cohort is its fetching leavers, then its
                         # counting ones: the first take their lanes'
                         # columns off the device, the second one
@@ -938,13 +997,13 @@ class _ContinuousStream:
                         if fetching:
                             resolver = sess.extract([(r.lane, r.upto)
                                                      for r in fetching])
-                        extract_us = (time.perf_counter() - tx) * 1e6
+                        host.add("extract", tx, hostclock.stamp())
                     if leavers or evicted:
-                        tc = time.perf_counter()
+                        tc = hostclock.stamp()
                         sess.clear([r.lane for r in leavers]
                                    + [r.lane for r in evicted
                                       if r.lane >= 0])
-                        clear_us = (time.perf_counter() - tc) * 1e6
+                        host.add("clear", tc, hostclock.stamp())
             except BaseException as ex:
                 # leavers/evicted already left the seat map — the
                 # pump-level _fail_all can no longer reach them, so
@@ -1016,8 +1075,8 @@ class _ContinuousStream:
             if empty:
                 finishes.append(self._finish(new_pending))
                 new_pending = None
-        t_end = time.perf_counter()
-        dur = t_end - t0
+        t_end = hostclock.stamp()
+        dur = t_end[0] - t0[0]
         # a handover runs to where the pump stamps next (the flush's
         # start, or the tick's end): the parts then tile the tick's
         # tail, and a pump that lost the interpreter between two stamps
@@ -1040,10 +1099,13 @@ class _ContinuousStream:
             # this call takes in what else is ready.  Never a wait
             hop_reads, hop_sparse, hop_slots, hop_onesided = \
                 sess.hop_reads()
-            parts = [0] * 5     # fetch_wait, d2h, unpack, rows, handover
-            for stamps, _n, _met in finishes:
-                for i in range(5):
-                    parts[i] += int((stamps[i + 1] - stamps[i]) * 1e6)
+            # per cohort: start, (count end,) fetch_wait end, d2h end,
+            # unpack end, rows end, handover end
+            for (ta, _t_count, *ends), _n, _met in finishes:
+                for name, a, b in zip(_ASSEMBLE_PARTS, [ta] + ends, ends):
+                    host.add(name, a, b)
+            dur_us = int(dur * 1e6)
+            _w, tick_cpu_us, tick_runq_us = hostclock.split(t0, t_end)
             # what the cohorts' unpacks met, under the record's own
             # field names (_finish)
             met = {name: sum(f[2][name] for f in finishes)
@@ -1053,18 +1115,23 @@ class _ContinuousStream:
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
                 leaves=len(leavers), evictions=len(evicted),
-                seat_us=int((t_seat - t0) * 1e6),
-                join_us=int(join_us), hop_us=int(hop_us),
-                extract_us=int(extract_us), clear_us=int(clear_us),
-                fetch_wait_us=parts[0], d2h_us=parts[1],
-                unpack_us=parts[2], rows_us=parts[3],
-                handover_us=parts[4], assemble_us=sum(parts),
+                **host.fields(),
+                join_map_us=join_map_us, join_pack_us=join_pack_us,
+                join_enqueue_us=host.wall("join") - join_map_us
+                - join_pack_us,
+                assemble_us=sum(host.wall(p) for p in _ASSEMBLE_PARTS),
+                # the bookkeeping between the stamps (ledger release,
+                # stats.observe, the journal): with it the ten parts
+                # tile dur_us
+                other_us=dur_us - sum(host.wall(p)
+                                      for p in PUMP_PHASES),
+                **hostclock.host_fields("", tick_cpu_us, tick_runq_us),
                 **met,
                 handed=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
                 hop_slots=hop_slots, hop_onesided=hop_onesided,
                 idle_us=int(idle_us),
-                dur_us=int(dur * 1e6),
+                dur_us=dur_us,
                 generation=int(getattr(getattr(sess, "m", None),
                                        "generation", -1)))
             # advance every touched rider's slow-log timeline anchor
@@ -1085,15 +1152,16 @@ class _ContinuousStream:
                        "loop")
                 self._emit_pump_trace(
                     riders, (t0, t_seat, t_end), finishes, idle_us, why,
+                    {p + "_us": host.wall(p) for p in _ENQUEUE_PARTS},
                     tick=tick_done, rec=rec_id, seats=occupancy,
                     joins=len(joiners), leaves=len(leavers))
         return new_pending
 
     def _emit_pump_trace(self, riders: List[int], tick_stamps,
                          finishes, idle_us: float, why: str,
-                         **tags) -> None:
+                         enqueues: Dict[str, int], **tags) -> None:
         """The tick just ended, as a trace of its own, post hoc from
-        the perf_counter stamps the tick took anyway: root pump.tick,
+        the stamps the tick took anyway: root pump.tick,
         children that tile it in pump order (they lie inside it and do
         not overlap, by construction: consecutive stamps on one
         clock), and a root pump.idle over the stretch since the
@@ -1101,17 +1169,22 @@ class _ContinuousStream:
         (``why``: no_work — the pump slept for want of riders; drain /
         tick_delay — a generation change / the test hook held it; loop
         — it was between two ticks: recording, the condition, the
-        interpreter lock).  ``finishes`` holds, per finished cohort,
-        _finish's stamps plus the one its handover ran to, the leavers
-        handed their frontier and what its unpack met.  Only called
-        for a tick that touched a traced rider."""
+        interpreter lock).  Every child carries what the pump thread
+        did in its stretch beside the wall: ``cpu_us`` it ran,
+        ``runq_us`` it was runnable without a core (left off where the
+        machine has no such clock); pump.enqueue also the walls of the
+        four enqueues it is made of (``enqueues``).  ``finishes``
+        holds, per finished cohort, _finish's stamps plus the one its
+        handover ran to, the leavers handed their frontier and what
+        its unpack met.  Only called for a tick that touched a traced
+        rider."""
         t0, t_seat, t_end = tick_stamps
         # ONE wall-minus-perf offset for the whole tick: the spans land
         # on the now_micros() clock every other span uses
         off = now_micros() - time.perf_counter() * 1e6
 
-        def us(t: float) -> int:
-            return int(t * 1e6 + off)
+        def us(t) -> int:
+            return int(t[0] * 1e6 + off)
 
         tid = tracing.new_trace_id()
         root = tracing.emit(
@@ -1119,32 +1192,37 @@ class _ContinuousStream:
             stream=self.space_id,
             riders=[f"{r:016x}" for r in riders[:PUMP_TICK_RIDER_TAGS]],
             **tags)
-        tracing.emit("pump.seat", tid, root, us(t0),
-                     us(t_seat) - us(t0))
+
+        def at(a, b) -> Tuple:
+            """Where a child of the root lies: emit's placing."""
+            return tid, root, us(a), us(b) - us(a)
+
+        def ran(a, b) -> Dict[str, int]:
+            return hostclock.span_fields("", a, b)
+
+        tracing.emit("pump.seat", *at(t0, t_seat), **ran(t0, t_seat))
         t_enq = finishes[0][0][0] if finishes else t_end   # first ta
-        tracing.emit("pump.enqueue", tid, root, us(t_seat),
-                     us(t_enq) - us(t_seat))
-        for (ta, t_wait, t_d2h, t_unpack, t_rows, t_hand), n, met \
-                in finishes:
+        tracing.emit("pump.enqueue", *at(t_seat, t_enq),
+                     **ran(t_seat, t_enq), **enqueues)
+        for (ta, t_count, t_wait, t_d2h, t_unpack, t_rows,
+             t_hand), n, met in finishes:
             # the count's wait and read head the fetch wait
-            t_count = ta + met["count_us"] / 1e6
             if met["counted"]:
-                tracing.emit("pump.count", tid, root, us(ta),
-                             us(t_count) - us(ta),
-                             counted=met["counted"])
-            tracing.emit("pump.fetch_wait", tid, root, us(t_count),
-                         us(t_wait) - us(t_count))
-            tracing.emit("pump.d2h", tid, root, us(t_wait),
-                         us(t_d2h) - us(t_wait))
-            tracing.emit("pump.unpack", tid, root, us(t_d2h),
-                         us(t_unpack) - us(t_d2h),
+                tracing.emit("pump.count", *at(ta, t_count),
+                             **ran(ta, t_count), counted=met["counted"])
+            tracing.emit("pump.fetch_wait", *at(t_count, t_wait),
+                         **ran(t_count, t_wait))
+            tracing.emit("pump.d2h", *at(t_wait, t_d2h),
+                         **ran(t_wait, t_d2h))
+            tracing.emit("pump.unpack", *at(t_d2h, t_unpack),
+                         **ran(t_d2h, t_unpack),
                          leavers=met["unpack_leavers"],
                          live=met["unpack_live"],
                          rows=met["unpack_rows"])
-            tracing.emit("pump.rows", tid, root, us(t_unpack),
-                         us(t_rows) - us(t_unpack), handed=n)
-            tracing.emit("pump.handover", tid, root, us(t_rows),
-                         us(t_hand) - us(t_rows))
+            tracing.emit("pump.rows", *at(t_unpack, t_rows),
+                         **ran(t_unpack, t_rows), handed=n)
+            tracing.emit("pump.handover", *at(t_rows, t_hand),
+                         **ran(t_rows, t_hand))
         if idle_us >= PUMP_IDLE_SPAN_MIN_US:
             tracing.emit("pump.idle", tid, None, us(t0) - int(idle_us),
                          int(idle_us), stream=self.space_id, why=why)
@@ -1166,7 +1244,9 @@ class _ContinuousStream:
         resolver's, the fold's) wakes every cohort member with it.
 
         Returns (the stamps that split this stretch of the pump's
-        time: start, fetch_wait end, d2h end, unpack end, rows end —
+        time, each the pump thread's three clocks (common/
+        hostclock.py): start, count end, fetch_wait end, d2h end,
+        unpack end, rows end —
         "rows" being what the pump answered itself; the leavers handed
         their frontier; what the fetches met, under the tick record's
         field names: unpack_leavers, unpack_live, unpack_rows —
@@ -1176,11 +1256,11 @@ class _ContinuousStream:
         the caller stamps next."""
         resolver, leavers, m, counter = pending
         rt = self.sched.runtime
-        ta = time.perf_counter()
+        ta = hostclock.stamp()
         # the cohort is its fetching leavers, then its counting ones
         n_fetch = sum(not r.counts for r in leavers)
         fetching, counting = leavers[:n_fetch], leavers[n_fetch:]
-        t_count = t_unpack = 0.0
+        t_count = t_unpack = None
         # the fetching leavers the pump answers itself: COUNT riders
         # (one fold over the cohort's) and a WHERE that filters in
         # numpy, which sixteen threads at once run slower than one in
@@ -1206,7 +1286,7 @@ class _ContinuousStream:
                 # fetch spans land on the first fetching leaver's trace
                 with tracing.attach_captured(fetching[0].tctx):
                     vs_lists = resolver()
-                    t_unpack = time.perf_counter()
+                    t_unpack = hostclock.stamp()
                     if own_idx:
                         own = rt.continuous_results(
                             self.space_id, m,
@@ -1218,19 +1298,21 @@ class _ContinuousStream:
                             outs[i] = out
         except Exception as ex:         # noqa: BLE001 — cohort-level
             outs = [ex] * len(leavers)
-        t_rows = time.perf_counter()
+        t_rows = hostclock.stamp()
         # a resolver that failed (or a test's stand-in) has no stamps:
         # its whole stretch reads as the part it died in, and it
         # unpacked nothing.  A cohort of counting leavers alone has no
         # extract: its fetch wait is its count
         t_count = t_count or ta
         t_unpack = t_unpack or (t_rows if fetching else t_count)
-        t_wait = getattr(resolver, "t_wait", 0.0) or t_unpack
-        t_d2h = getattr(resolver, "t_d2h", 0.0) or t_unpack
+        t_wait = getattr(resolver, "t_wait", None) or t_unpack
+        t_d2h = getattr(resolver, "t_d2h", None) or t_unpack
+        # a wait this long with both beats on time is the device's
+        hostclock.note_wait("fetch_wait", ta, t_wait)
         met = {name: int(getattr(resolver, name, 0)) for name in
                ("unpack_leavers", "unpack_live", "unpack_rows")}
         met["counted"] = len(counting)
-        met["count_us"] = int((t_count - ta) * 1e6)
+        met["count_us"] = hostclock.split(ta, t_count)[0]
         stats.add_value("graph.continuous.leaves", len(leavers))
         handed = 0
         with self.cond:
@@ -1248,7 +1330,7 @@ class _ContinuousStream:
                 r.done_t = t_done
                 r.done = True
             self.cond.notify_all()
-        return (ta, t_wait, t_d2h, t_unpack, t_rows), handed, met
+        return (ta, t_count, t_wait, t_d2h, t_unpack, t_rows), handed, met
 
     # ------------------------------------------------------- submit
     def submit(self, key: Tuple, payload, steps: int, upto: bool,
@@ -1327,17 +1409,27 @@ class _ContinuousStream:
                             "go: deadline expired mid-flight")
                         disp._note_deadline_drop(key)
                         break
-        t_wake = time.perf_counter()
+        t_wake = hostclock.stamp()
         if rider.error is None and rider.frontier is not None:
             self._assemble_own(key, rider)
+        t_end = hostclock.stamp()
         # the seat trajectory lands on the WAITER's own trace: a
         # PROFILE of the query shows its lane, join and leave tick,
         # whether it merged into an already-running batch, the waits
         # its time here was made of, and HOW its wait ended — one of
         # protocol's closed "continuous-ending" kinds, the vocabulary
         # the eviction dashboards key on
-        waits = self._waits(rider, t_wake, time.perf_counter())
+        waits = self._waits(rider, t_wake[0], t_end[0])
         query_registry.note_waits(rider.qid, rider.left_tick, waits)
+        # what this thread did meanwhile, on its own clocks: from the
+        # enqueue to its wake it slept in cond.wait, and every
+        # notify_all of the stream woke it to test rider.done and
+        # sleep again: wait_cpu_us / wait_runq_us are what that herd
+        # cost this thread in run time and in waiting for a core.
+        # assemble_cpu_us / assemble_runq_us split assemble_us.  On
+        # the marker only: the registry and the slow log keep the waits
+        host = {**hostclock.span_fields("wait_", rider.enq, t_wake),
+                **hostclock.span_fields("assemble_", t_wake, t_end)}
         if rider.error is not None:
             if isinstance(rider.error, ContinuousUnavailable):
                 ending = protocol.END_BOUNCED
@@ -1352,7 +1444,7 @@ class _ContinuousStream:
             tracing.annotate("graph.continuous", lane=rider.lane,
                              joined_tick=rider.joined_tick,
                              left_tick=rider.left_tick,
-                             ending=ending, **waits)
+                             ending=ending, **waits, **host)
             raise rider.error
         query_registry.note_ending(rider.qid, protocol.END_LEFT)
         tracing.annotate("graph.continuous", lane=rider.lane,
@@ -1361,7 +1453,7 @@ class _ContinuousStream:
                          hops=rider.hops,
                          reduce=rider.reduce[0] if rider.reduce else "",
                          midflight=rider.midflight,
-                         ending=protocol.END_LEFT, **waits)
+                         ending=protocol.END_LEFT, **waits, **host)
         with self.sched.dispatcher._lock:
             self.sched.dispatcher.stats["continuous_queries"] = \
                 self.sched.dispatcher.stats.get("continuous_queries",
@@ -1521,6 +1613,9 @@ class GoBatchDispatcher:
         # runtime without continuous_session (the micro-bench fakes)
         # keeps the windowed pipeline only
         self.meter = _DeviceBusyMeter()
+        # the host's two beats, one pair a process however many
+        # runtimes it holds (common/hostclock.py)
+        hostclock.ensure_started()
         self.continuous = (ContinuousGoScheduler(runtime, self)
                            if hasattr(runtime, "continuous_session")
                            else None)

@@ -165,6 +165,12 @@ def lib() -> Optional[ctypes.CDLL]:
              [vp, vp, vp, vp, ctypes.c_int64, ctypes.c_int32,
               ctypes.c_double, vp])
 
+    # the native beat (common/hostclock.py). Guarded like ell_build
+    if hasattr(L, "neb_beat_start"):
+        _sig(L.neb_beat_start, ctypes.c_int, [ctypes.c_int64])
+        _sig(L.neb_beat_read, None, [i64p])
+        _sig(L.neb_beat_stop, None, [])
+
     _LIB = L
     return _LIB
 

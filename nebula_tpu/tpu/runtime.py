@@ -53,6 +53,7 @@ import numpy as np
 
 from ..common import deadline as deadlines
 from ..common import flight as _flight
+from ..common import hostclock
 from ..common import mc_hooks
 from ..common import protocol
 from ..common import tracing
@@ -2735,7 +2736,6 @@ class TpuQueryRuntime:
                                           np.arange(len(idxs) + 1))
 
         if filtered:
-            import time
             # which pass filters: the span and where_native say, so a
             # library without the native pass shows in a trace (where
             # it can run, candidates that are not runs are none at all)
@@ -2746,7 +2746,8 @@ class TpuQueryRuntime:
                               ) as sp:
                 # the span's wall may be shared with other threads
                 # under the interpreter lock; cpu_us is this pass's own
-                cpu0 = time.thread_time()
+                # run time, runq_us its wait for a core
+                h0 = hostclock.stamp() if sp is not None else None
                 if isinstance(cand, _EdgeRuns):
                     kept = self._filter_runs(m, plan, cand, native)
                     cand2 = cand.rows(kept)
@@ -2760,8 +2761,9 @@ class TpuQueryRuntime:
                 met = (len(idxs) - int(bad.sum()), len(cand), len(cand2),
                        0 if native is None else len(idxs))
                 if sp is not None:
-                    sp.tag(candidates=met[1], kept=met[2], cpu_us=int(
-                        (time.thread_time() - cpu0) * 1e6))
+                    sp.tag(candidates=met[1], kept=met[2],
+                           **hostclock.span_fields(
+                               "", h0, hostclock.stamp()))
             with self._lock:
                 for key, n in zip(("go_where", "where_candidates",
                                    "where_rows", "where_native"), met):
@@ -3907,18 +3909,18 @@ class TpuQueryRuntime:
 
         # --- host half: parent-DAG reconstruction over the in-edge
         # order this mirror generation keeps -------------------------
-        import time
         index = self._path_index(m, et_tuple)
         with tracing.span("tpu.path_reconstruct") as sp:
             # the span's wall is shared with every other walk of the
             # batch under the interpreter lock; cpu_us is this one's own
-            cpu0 = time.thread_time()
+            # run time, runq_us its wait for a core
+            h0 = hostclock.stamp() if sp is not None else None
             paths, found = _reconstruct_paths(
                 m, index, d16, srcs, dsts, max_steps, shortest,
                 etype_names)
             if sp is not None:
-                sp.tag(paths=len(paths), **found, cpu_us=int(
-                    (time.thread_time() - cpu0) * 1e6))
+                sp.tag(paths=len(paths), **found,
+                       **hostclock.span_fields("", h0, hostclock.stamp()))
         self._bump("path_rows", len(paths))
         if found["capped"]:
             self._bump("path_capped")
@@ -4015,6 +4017,9 @@ class _ContinuousGoSession:
         # them forgets the oldest
         self._hop_info: collections.deque = collections.deque(maxlen=64)
         self._hop_read = [0, 0, 0, 0]   # read, not yet in a tick record
+        # perf_counter marks of the last join: its map loop's end and
+        # its pack's end (join)
+        self.join_marks = None
 
     def join(self, joiners) -> None:
         """Scatter the arrivals' start frontiers into their assigned
@@ -4022,6 +4027,7 @@ class _ContinuousGoSession:
         drop exactly like the windowed upload; the (row, lane-bit)
         scatter coordinates are deduped per lane so the add lands on
         zero bits only (the clear contract)."""
+        import time
         from .ell import make_lane_join_kernel
         rows_l: List[np.ndarray] = []
         words_l: List[np.ndarray] = []
@@ -4037,6 +4043,11 @@ class _ContinuousGoSession:
             vals_l.append(np.full(len(r), np.uint8(1) << (lane & 7),
                                   np.uint8))
         S = sum(len(r) for r in rows_l)
+        t_map = time.perf_counter()
+        # where the pump splits join_us (graph/batch_dispatch.py
+        # _tick): the joiners mapped, the arrays packed, and what is
+        # left, the enqueue
+        self.join_marks = (t_map, t_map)
         if S == 0:
             return
         Sp = max(8, 1 << (S - 1).bit_length())   # stable shapes
@@ -4046,6 +4057,7 @@ class _ContinuousGoSession:
         rows_p[:S] = np.concatenate(rows_l)
         words_p[:S] = np.concatenate(words_l)
         vals_p[:S] = np.concatenate(vals_l)
+        self.join_marks = (t_map, time.perf_counter())
         kern = self.rt._kernel(
             ("ell_lane_join", self.ix.shape_sig()),
             lambda: make_lane_join_kernel(self.ix, donate=True))
@@ -4235,8 +4247,9 @@ def _unpack_lanes(cols: np.ndarray, n: int, perm: np.ndarray,
 
 class _LaneFetch:
     """Zero-arg resolver of one leave cohort's lane extraction ->
-    per-leaver ascending old-dense-id frontier arrays.  It stamps
-    perf_counter where the device wait ends (``t_wait``) and where the
+    per-leaver ascending old-dense-id frontier arrays.  It stamps the
+    calling thread's three clocks (common/hostclock.py) where the
+    device wait ends (``t_wait``) and where the
     copy ends (``t_d2h``) — the pump reads both into the tick record
     and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
     still wraps wait + copy, as the windowed resolvers' does.  What
@@ -4254,21 +4267,20 @@ class _LaneFetch:
         self.leavers = leavers
         self.cols_of = cols_of
         self.np_pairs = np_pairs
-        self.t_wait = self.t_d2h = 0.0
+        self.t_wait = self.t_d2h = None
         self.unpack_leavers = self.unpack_live = self.unpack_rows = 0
 
     def __call__(self):
-        import time
         with tracing.span("tpu.fetch"):
             # the only point the pump waits on the device
             self.out_dev.block_until_ready()
-            self.t_wait = time.perf_counter()
+            self.t_wait = hostclock.stamp()
             # every hop queued before this extract is done: their info
             # vectors are read here, before the results are handed
             # over, so the tick's record is not held up after it
             self.session.read_hop_info()
             cols = np.asarray(self.out_dev)         # [R1, P] uint8
-            self.t_d2h = time.perf_counter()
+            self.t_d2h = hostclock.stamp()
         self.session.rt._note_fetch(cols[:, :self.np_pairs])
         ix = self.session.ix
         outs, self.unpack_live, self.unpack_rows = _unpack_lanes(
@@ -4292,10 +4304,9 @@ class _LaneCount:
         self.session = session
         self.out_dev = out_dev
         self.lanes = lanes
-        self.t_done = 0.0
+        self.t_done = None
 
     def __call__(self):
-        import time
         with tracing.span("tpu.count", leavers=len(self.lanes),
                           bytes=4 * self.session.B):
             self.out_dev.block_until_ready()
@@ -4303,7 +4314,7 @@ class _LaneCount:
             self.session.read_hop_info()
             counts = np.asarray(self.out_dev)       # [B] int32
         self.session.rt._note_fetch(counts)
-        self.t_done = time.perf_counter()
+        self.t_done = hostclock.stamp()
         return [int(counts[lane]) for lane in self.lanes]
 
 

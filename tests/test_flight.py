@@ -86,6 +86,20 @@ class TestRecorderRing:
         finally:
             flags.set("flight_recorder_size", saved)
 
+    def test_note_beat_is_one_record_like_the_others(self):
+        r = flight.FlightRecorder()
+        a = r.note_tick(0, tick=1)
+        b = r.note_beat(clock="thread_time", n=100, py_late_sum_us=7000,
+                        py_late_max_us=250)
+        assert b == a + 1
+        newest = r.dump()[0]
+        assert newest["kind"] == "beat" and newest["id"] == b
+        assert newest["clock"] == "thread_time" and newest["n"] == 100
+        assert newest["time_us"] > 0
+        # no native beat: its fields are left off, not written as 0
+        assert not any(k.startswith("nat_") for k in newest)
+        assert [e["kind"] for e in r.export()] == ["tick", "beat"]
+
     def test_export_clamped_by_flag(self):
         saved = flags.get("timeline_export_max_ticks")
         flags.set("timeline_export_max_ticks", 4)
@@ -330,6 +344,19 @@ class TestChromeTrace:
         tim = [e for e in ev if e.get("cat") == "timing"]
         assert tim and tim[0]["name"] == "ell_go"
         assert tim[0]["dur"] == 120
+
+    def test_beat_records_are_no_device_row(self):
+        """The host's beats (common/hostclock.py) live in the same ring
+        and the export skips them: the golden stands."""
+        tree, ticks, seat = _golden_inputs()
+        beat = {"kind": "beat", "id": 45, "time_us": 1950,
+                "clock": "schedstat", "n": 99, "py_late_sum_us": 9000,
+                "py_late_max_us": 400, "nat_n": 100,
+                "nat_late_sum_us": 8000, "nat_late_max_us": 300}
+        want = flight.chrome_trace(tree=tree, ticks=ticks, seat=seat)
+        got = flight.chrome_trace(tree=tree, ticks=ticks[:2] + [beat]
+                                  + ticks[2:] + [beat], seat=seat)
+        assert got == want
 
     def test_empty_inputs_still_valid(self):
         trace = flight.chrome_trace()

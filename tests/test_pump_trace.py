@@ -22,7 +22,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from nebula_tpu.common import clock, flight, tracing
+from nebula_tpu.common import clock, flight, hostclock, tracing
 from nebula_tpu.common.flags import flags
 from nebula_tpu.common.tracing import slow_log, trace_store
 from nebula_tpu.graph import batch_dispatch as bd
@@ -597,6 +597,186 @@ class TestHopBranchFields:
             assert InFlight.asked >= 1 and len(sess._hop_info) == 1
         finally:
             sess._hop_info.clear()
+
+
+# ================================== (g) the host's time has an owner
+PHASES = bd.PUMP_PHASES
+JOIN_PARTS = ("join_map_us", "join_pack_us", "join_enqueue_us")
+RIDER_HOST = ("wait_cpu_us", "wait_runq_us", "assemble_cpu_us",
+              "assemble_runq_us")
+
+
+def _marks():
+    return [n["tags"] for t in _trees() for r in t["roots"]
+            for n in _walk(r) if n["name"] == "graph.continuous"]
+
+
+class TestHostClocks:
+    """Every stamp that bounds a phase is the thread's three clocks
+    (common/hostclock.py): the tick record, the pump.* children and
+    the rider's marker say how long the thread ran and how long it was
+    runnable without a core beside the wall."""
+
+    def test_the_parts_tile_the_tick_and_the_join(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 0.0)     # always on
+        _burst(c, _mixed(9))
+        ticks = _ticks()
+        assert len(ticks) >= 3
+        has_runq = hostclock.stamp()[2] is not None
+        for t in ticks:
+            assert sum(t[p + "_us"] for p in PHASES) + t["other_us"] \
+                == t["dur_us"], t
+            assert t["other_us"] >= 0
+            assert sum(t[k] for k in JOIN_PARTS) == t["join_us"], t
+            assert all(t[k] >= 0 for k in JOIN_PARTS), t
+            assert t["assemble_us"] == sum(t[p] for p in PARTS)
+            for prefix in ("",) + tuple(p + "_" for p in PHASES):
+                assert t[prefix + "cpu_us"] >= 0, (prefix, t)
+                assert (prefix + "runq_us" in t) == has_runq, (prefix, t)
+            # a thread cannot run for longer than the wall says (the
+            # clocks are read a microsecond apart, once a stamp)
+            assert t["cpu_us"] <= t["dur_us"] + 50, t
+            if t["joins"] == 0:
+                assert t["join_us"] == 0 and t["join_cpu_us"] == 0
+        assert any(t["join_map_us"] > 0 for t in ticks)
+        assert any(t["join_enqueue_us"] > 0 for t in ticks)
+
+    def test_every_pump_child_says_what_the_thread_did(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(8))
+        ticks = {t["id"]: t for t in _ticks()}
+        roots = _pump_roots("pump.tick")
+        assert roots
+        has_runq = hostclock.stamp()[2] is not None
+        for root in roots:
+            rec = ticks[root["tags"]["rec"]]
+            for k in root["children"]:
+                assert k["name"] in CHILDREN
+                assert k["tags"]["cpu_us"] >= 0, k
+                assert ("runq_us" in k["tags"]) == has_runq, k
+            enq = [k for k in root["children"]
+                   if k["name"] == "pump.enqueue"]
+            assert len(enq) == 1
+            for f in ("join_us", "hop_us", "extract_us", "clear_us"):
+                assert enq[0]["tags"][f] == rec[f], (f, enq[0], rec)
+            # the children's run time is the record's, phase by phase
+            for part in PARTS + ("seat_us",):
+                names = {"pump." + part[:-3]} | (
+                    {"pump.count"} if part == "fetch_wait_us" else set())
+                got = sum(k["tags"]["cpu_us"] for k in root["children"]
+                          if k["name"] in names)
+                assert abs(got - rec[part[:-3] + "_cpu_us"]) <= 4, \
+                    (part, got, rec)
+
+    def test_the_riders_marker_has_its_own_threads_clocks(self, graph):
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(6))
+        marks = _marks()
+        assert len(marks) == 6
+        has_runq = hostclock.stamp()[2] is not None
+        for m in marks:
+            for tag in RIDER_HOST:
+                if "runq" in tag and not has_runq:
+                    assert tag not in m
+                else:
+                    assert m[tag] >= 0, (tag, m)
+            # the waits are what they were
+            assert all(m[w] >= 0 for w in WAITS), m
+            assert m["assemble_cpu_us"] <= m["assemble_us"] + 50
+
+    def test_without_schedstat_no_runq_anywhere_and_no_zero(
+            self, graph, monkeypatch):
+        c, g, ok = graph
+        monkeypatch.setattr(hostclock, "_runq", False)
+        flags.set("trace_sample_rate", 1.0)
+        _burst(c, _mixed(6))
+        ticks = _ticks()
+        assert ticks
+        for t in ticks:
+            assert not [k for k in t if "runq" in k], t
+            assert sum(t[p + "_us"] for p in PHASES) + t["other_us"] \
+                == t["dur_us"]
+            assert "cpu_us" in t and "unpack_cpu_us" in t
+        for root in _pump_roots("pump.tick"):
+            for k in root["children"]:
+                assert "cpu_us" in k["tags"]
+                assert "runq_us" not in k["tags"], k
+        marks = _marks()
+        assert len(marks) == 6
+        for m in marks:
+            assert "wait_cpu_us" in m and "assemble_cpu_us" in m
+            assert not [k for k in m if "runq" in k], m
+
+    def test_a_rider_woken_for_nothing_pays_for_it(self, monkeypatch):
+        """A stream driven by hand: the rider's thread sleeps in
+        cond.wait, N notify_all wake it to test rider.done and sleep
+        again, then its answer comes.  Its wait_cpu_us grows with N;
+        no time is asserted, only that more wake-ups cost more."""
+
+        class Woken(threading.Condition):
+            wakes = 0
+
+            def wait(self, timeout=None):
+                got = super().wait(timeout)
+                self.wakes += 1
+                return got
+
+        class Disp:
+            _lock = threading.Lock()
+            stats = {}
+
+        class Sched:
+            dispatcher = Disp()
+            runtime = None
+
+        class Hand(bd._ContinuousStream):
+            def _pump(self):            # the test is the pump
+                return
+
+        got = []
+        monkeypatch.setattr(
+            bd.tracing, "annotate",
+            lambda name, **tags: got.append(tags)
+            if name == "graph.continuous" else None)
+
+        def ride(n_wakes):
+            st = Hand(Sched(), 1, (1,))
+            st.cond = Woken()
+            out = []
+            t = threading.Thread(target=lambda: out.append(
+                st.submit(("k",), object(), 2, False, None)))
+            t.start()
+            end = time.monotonic() + 30.0
+            while time.monotonic() < end:
+                with st.cond:
+                    if st.queue:
+                        rider = st.queue.pop(0)
+                        break
+                time.sleep(0.001)
+            for i in range(n_wakes):
+                with st.cond:
+                    st.cond.notify_all()
+                while st.cond.wakes <= i and time.monotonic() < end:
+                    time.sleep(0)       # until it has woken and slept
+            assert st.cond.wakes >= n_wakes
+            with st.cond:
+                rider.result, rider.mirror = (["c"], []), "m"
+                rider.done = True
+                st.cond.notify_all()
+            t.join(30.0)
+            assert out == [((["c"], []), "m")]
+            return got.pop()
+
+        few = ride(0)
+        many = ride(3000)
+        assert few["ending"] == many["ending"] == "left-batch"
+        assert many["wait_cpu_us"] > few["wait_cpu_us"]
+        # three thousand wake-ups are at least a microsecond each
+        assert many["wait_cpu_us"] >= 3000
+        assert "assemble_cpu_us" in many
 
 
 # ============================= SHOW TIMELINE / timeline detail

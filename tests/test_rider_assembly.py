@@ -470,8 +470,11 @@ def test_spans_counters_handed_and_the_five_waits(served, monkeypatch):
             assert mark["assemble_us"] >= min(n["duration_us"]
                                               for n in own)
         if i == NATIVE:
-            assert len(wheres) == 1, (stmt, names)
-            tags = wheres[0]["tags"]
+            # (the pump's two numpy passes land here too where this
+            # rider is its cohort's first leaver)
+            mine = [n for n in wheres if n["tags"]["native"] == 1]
+            assert len(mine) == 1, (stmt, names)
+            tags = mine[0]["tags"]
             assert tags["queries"] == 1 and tags["site"] == "assembly"
             assert tags["native"] == 1
             assert tags["candidates"] >= tags["kept"] > 0
