@@ -130,9 +130,10 @@ class FlightRecorder:
         whose sum is assemble_us; rows is what the pump answers
         itself: the COUNT riders' fold, a WHERE that filters in
         numpy), what unpack_us met (unpack_leavers unpacked in the tick's cohorts,
-        of them unpack_live out of the live rows of the fetched block
-        and not out of the whole table, unpack_rows those live rows,
-        summed over the cohorts — tpu/runtime.py _unpack_lanes), handed
+        of them unpack_live out of the non-zero bytes of their fetched
+        bitmap and not out of the whole of it, unpack_rows the set rows
+        of all of them, summed over the cohorts — tpu/runtime.py
+        _unpack_lanes), handed
         (the leavers whose frontier went to their own thread, which
         filters and makes the rows: every leaver but a COUNT rider and
         a WHERE the native pass cannot take — graph/batch_dispatch.py

@@ -533,17 +533,16 @@ def bench_continuous_path(reps: int,
     acc = fp.copy()
     joink = E.make_lane_join_kernel(ix, donate=True)
     clear = E.make_lane_clear_kernel(donate=True)
-    ext = E.make_lane_extract_kernel()
+    ext = E.make_lane_extract_kernel(ix)
     Sp = 64
     rows = rng.integers(0, ix.n_rows, Sp).astype(np.int32)
     words = np.zeros(Sp, np.int32)
     vals = np.full(Sp, 1, np.uint8)
-    ewords = np.zeros(8, np.int32)
-    esel = np.zeros(8, np.uint8)
+    elanes = np.zeros((3, 4), np.int32)      # the least rung; word 0, bit 0
     keep = np.full(W, 0xFE, np.uint8)
     # compile outside the timed region
     fp, acc = joink(fp, acc, rows, words, vals)
-    np.asarray(ext(fp, acc, ewords, esel))
+    np.asarray(ext(fp, acc, elanes))
     fp, acc = clear(fp, acc, keep)
     jax.block_until_ready(fp)
     rounds = max(20, reps // 10)
@@ -554,7 +553,7 @@ def bench_continuous_path(reps: int,
         jax.block_until_ready(fp)
         t_join += time.perf_counter() - t0
         t0 = time.perf_counter()
-        np.asarray(ext(fp, acc, ewords, esel))
+        np.asarray(ext(fp, acc, elanes))
         t_ext += time.perf_counter() - t0
         t0 = time.perf_counter()
         fp, acc = clear(fp, acc, keep)

@@ -742,11 +742,13 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #   make_lane_clear_kernel       AND with a per-word keep mask: the
 #                                leavers' lane bits drop from both
 #                                carriers in one fused op
-#   make_lane_extract_kernel     gather the leaving lanes' WORD columns
-#                                (per column choosing the exact-depth
-#                                frontier or the UPTO accumulator) —
-#                                the d2h fetch is R1 bytes per leaving
-#                                word, never the whole matrix
+#   make_lane_extract_kernel     pack each leaving LANE down the real
+#                                vertex rows, eight rows a byte, plane
+#                                by plane (per lane choosing the
+#                                exact-depth frontier or the UPTO
+#                                accumulator) — the d2h fetch is n / 8
+#                                bytes per leaver, never a word column
+#                                and never the matrix
 #   make_lane_count_kernel       set bits per lane over the real vertex
 #                                rows: a leaver whose statement is a
 #                                k-hop neighbourhood count (YIELD
@@ -1014,23 +1016,92 @@ def make_lane_clear_kernel(donate: bool = True):
     return jax.jit(clear, donate_argnums=(0, 1) if donate else ())
 
 
-def make_lane_extract_kernel():
-    """Slice the leaving lanes' word columns off the resident pair:
-    fn(fp, accp, words int32[P], sel uint8[P]) -> uint8 [n_rows+1, P]
-    where column j is accp[:, words[j]] when sel[j] else fp[:, words[j]]
-    (UPTO leavers read the union accumulator, exact-depth leavers the
-    frontier).  Not donated: the carriers keep serving the lanes that
-    stay seated — the output is a fresh fetch-sized buffer the host
-    np.asarray()s while the NEXT hop computes (the double-buffer
-    overlap, docs/admission.md)."""
+def lane_bitmap_bytes(n: int) -> int:
+    """Bytes of one leaving lane's bitmap: a bit a real vertex row,
+    ceil(n / 8) bytes rounded up to whole 64-bit words (7 bytes at
+    most), so the host counts its set bits a word at a time."""
+    return 8 * (-(-n // 64))
+
+
+def lane_bitmap_rows(at: np.ndarray, bit: np.ndarray,
+                     n: int) -> np.ndarray:
+    """The vertex rows of set bits of a lane's bitmap: bit ``bit`` of
+    byte ``at`` is row ``bit * lane_bitmap_bytes(n) + at`` — the
+    bitmap holds the rows plane by plane, eight planes of nb rows,
+    plane k in bit k of every byte (make_lane_extract_kernel)."""
+    return bit * lane_bitmap_bytes(n) + at
+
+
+def lane_extract_rungs(B: int) -> Tuple[int, ...]:
+    """The leaver counts the extract program is compiled for at the
+    B-lane width rung: 4, the powers of two between 4 and B, then B.
+    A leave cohort of k fetching leavers runs the least rung >= k
+    (lane_extract_rung).  The rung is the program's one shape
+    decision and depends on nothing but k.  The ladder starts at 4:
+    the TPU pads an output of fewer rows to four, and on the v5e's
+    link 1, 2 and 4 bitmaps of 80.8 kB take the same 0.5 ms; 8 take
+    0.65, 16 0.85, 32 1.2, 64 1.8 and 128 3.0 (PERF.md section 6,
+    PR 37).  A rung is half a second to a second of compile and half
+    a megabyte of program on the device while it is loaded."""
+    rungs, L = [], 4
+    while L < B:
+        rungs.append(L)
+        L *= 2
+    return tuple(rungs) + (B,)
+
+
+def lane_extract_rung(k: int, B: int) -> int:
+    """The least of lane_extract_rungs(B) that holds k <= B leavers."""
+    return min(B, max(4, 1 << (k - 1).bit_length()))
+
+
+def make_lane_extract_kernel(ell: EllIndex):
+    """Hand back each leaving lane, bit-packed down the real vertex
+    rows: fn(fp, accp, lanes int32[3, L]) -> uint8 [L, nb =
+    lane_bitmap_bytes(n)].  ``lanes[:, l]`` = (word, bit, carrier) of
+    leaver l: row l of the result holds bit ``bit`` of word ``word``
+    of accp (carrier 1: an UPTO leaver reads the union accumulator)
+    or of fp (carrier 0: an exact-depth leaver the frontier) over the
+    rows v < n, eight rows a byte, PLANE BY PLANE: bit k of byte i is
+    row k * nb + i (lane_bitmap_rows), so the pack is eight contiguous
+    slices of the lane's column ORed together, shifted, and no row
+    moves across the TPU's lanes (neighbouring rows in one byte,
+    np.packbits' order, does that to every row: PERF.md section 6,
+    PR 37).  The hub extra rows and growth spares (a pull's partial
+    ORs), the pad row and the bits from n on are not packed: the host
+    never reads them.  Padding leavers (word 0, bit 0, carrier 0)
+    fill a rung; their rows are fetched and not read.
+
+    One lane a turn of ``lax.map``, its word column cut out by one
+    dynamic slice: the resident pair lies column-major on the TPU, so
+    the column is one contiguous run and nothing frontier-sized is
+    laid out anew (0.2 MB of temporaries and 0.5 MB of program at any
+    rung, compiled for the v5e: tests/test_hop_compile_tpu.py).  Not
+    donated: the carriers keep serving the lanes that stay seated —
+    the output is a fresh fetch-sized buffer the host np.asarray()s
+    while the NEXT hop computes (the double-buffer overlap,
+    docs/admission.md)."""
     import jax
     import jax.numpy as jnp
+    n = ell.n
+    nb = lane_bitmap_bytes(n)
 
-    def extract(fp, accp, words, sel):
+    def extract(fp, accp, lanes):
+        def pack(lane):                         # (word, bit, carrier)
+            # [1, rows]: the rows along the TPU's lanes, as they lie
+            col = jnp.where(
+                lane[2] != 0,
+                jax.lax.dynamic_slice_in_dim(accp, lane[0], 1, 1),
+                jax.lax.dynamic_slice_in_dim(fp, lane[0], 1, 1))[:n].T
+            b = (col >> lane[1].astype(jnp.uint8)) & jnp.uint8(1)
+            b = jnp.pad(b, ((0, 0), (0, nb * LANE_BITS - n)))
+            out = b[:, :nb]
+            for k in range(1, LANE_BITS):
+                out = out | (b[:, k * nb:(k + 1) * nb] << jnp.uint8(k))
+            return out[0]                       # [nb]
+
         with jax.named_scope("lane/extract"):
-            fg = jnp.take(fp, words, axis=1)     # [R1, P]
-            ag = jnp.take(accp, words, axis=1)
-            return jnp.where(sel[None, :] != 0, ag, fg)
+            return jax.lax.map(pack, lanes.T)   # [L, nb]
 
     return jax.jit(extract)
 
@@ -2606,15 +2677,13 @@ def _ell_lane_clear_buckets(fx):
 
 
 def _ell_lane_extract_buckets(fx):
-    kern = make_lane_extract_kernel()
+    kern = make_lane_extract_kernel(fx.ell)
     out = []
     for B in fx.widths:
         pk = _packed_frontier_avals(fx, B)
-        for P in (8,):              # leaving-word pow-2 pad rung
+        for L in lane_extract_rungs(B):     # every leaver-count rung
             out.append((("ell_lane_extract", fx.ell.shape_sig()), kern,
-                        (pk[0], pk[0],
-                         fx.aval((P,), np.int32),
-                         fx.aval((P,), np.uint8))))
+                        (pk[0], pk[0], fx.aval((3, L), np.int32))))
     return out
 
 
@@ -2773,14 +2842,14 @@ register_kernel(KernelSpec(
 register_kernel(KernelSpec(
     "ell_lane_extract", make_lane_extract_kernel,
     phase_kind="ell_lane_extract",
-    # one retrace per (width rung, pow-2 leaving-word rung) pair
+    # one retrace per (width rung, leaver-count rung) pair
     budget=48, instantiate=_ell_lane_extract_buckets,
-    dispatch=(2, 3), frontier=(0, 1), packed=(0, 1),
-    # the leave-extract fetch is R1 bytes per leaving word column —
-    # never the [R1, W] matrix (lanes_width(qmax) words bound a batch
-    # where every seat leaves in one tick)
-    d2h_bytes_max=lambda fx: (fx.ell.n_rows + 1)
-    * lanes_width(fx.qmax)))
+    dispatch=(2,), frontier=(0, 1), packed=(0, 1),
+    # the leave-extract fetch is one bitmap of the vertex rows a
+    # leaver — never a word column, never the [R1, W] matrix (a batch
+    # where every seat leaves in one tick is bounded by its lanes)
+    d2h_bytes_max=lambda fx: lane_bitmap_bytes(fx.ell.n)
+    * max(fx.widths)))
 register_kernel(KernelSpec(
     "ell_lane_count", make_lane_count_kernel,
     phase_kind="ell_lane_count",

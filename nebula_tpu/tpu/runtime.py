@@ -69,7 +69,8 @@ from .expr_compile import (CompileError, CVal, Env, ExprCompiler, K_BOOL,
                            K_FLOAT, K_INT, K_STR, K_STRCODE, K_VIDRANK)
 from .jax_setup import device_info, ensure_jax_configured
 from . import kernels
-from .ell import EllIndex
+from .ell import (EllIndex, lane_bitmap_rows, lane_extract_rung,
+                  lane_extract_rungs)
 
 
 class MeshUnavailable(DeviceExecError):
@@ -596,7 +597,7 @@ DEVICE_PHASES = {
     # docs/admission.md "Continuous dispatch"): the resident frontier
     # pair never crosses the link — hop/join/clear "fetches" are the
     # next resident (fp, accp) generation (donated in, stays on
-    # device); only the leave-extract's word columns actually move d2h.
+    # device); only the leave-extract's lane bitmaps actually move d2h.
     # The hop's third output is its 12-byte info vector (which branch
     # ran, live slot rows, slots visited): read once it is ready,
     # never waited for (_ContinuousGoSession.hop_reads)
@@ -604,7 +605,7 @@ DEVICE_PHASES = {
     "ell_lane_join": {"phases": ("tpu.kernel",), "h2d": 3, "d2h": 2},
     "ell_lane_clear": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 2},
     "ell_lane_extract": {"phases": ("tpu.kernel", "tpu.fetch"),
-                         "h2d": 2, "d2h": 1},
+                         "h2d": 1, "d2h": 1},
     # the k-hop count's leave: reads the resident frontier in place,
     # one int32 a lane comes back (_LaneCount)
     "ell_lane_count": {"phases": ("tpu.kernel", "tpu.count"),
@@ -660,6 +661,9 @@ class TpuQueryRuntime:
         self.mirrors: Dict[int, CsrMirror] = {}
         self._plans: Dict[int, _GoPlan] = {}
         self._kernels: Dict[Tuple, object] = {}
+        # (table shapes, width rung) whose extract has run at every
+        # rung of leavers (_ContinuousGoSession._extract_kernel)
+        self.extract_rungs_run: set = set()
         self._lock = threading.Lock()
         self._build_locks: Dict[int, threading.Lock] = {}
         self._rebuilding: set = set()           # spaces rebuilding now
@@ -4079,6 +4083,10 @@ class _ContinuousGoSession:
             self.fp, self.accp, info = kern(self.fp, self.accp,
                                             self.eslot, self.hrows,
                                             *self._tables)
+        # its 12 bytes start for the host when the hop ends: a fetch
+        # has a floor of half a millisecond on the v5e's host whatever
+        # it carries, and read_hop_info should not pay it on the pump
+        info.copy_to_host_async()
         self._hop_info.append(info)
         self.hops += 1
         # no tpu_device_timing_every probe here: blocking on the hop
@@ -4118,33 +4126,46 @@ class _ContinuousGoSession:
         self._hop_read = [0, 0, 0, 0]
         return out
 
-    def extract(self, leavers):
-        """Slice the leaving lanes' word columns (UPTO lanes read the
-        accumulator) and return a zero-arg resolver (_LaneFetch) ->
-        per-leaver ascending old-dense-id frontier arrays.  The
-        resolver is where the d2h fetch forces — call it AFTER
-        enqueueing the next hop
-        so the host assembly overlaps the device compute."""
+    def _extract_kernel(self):
+        """The extract program.  The first fetching cohort over tables
+        of these shapes at this width runs it once at every rung
+        (ell.lane_extract_rungs), so that no later cohort meets a
+        shape for the first time: which rung a tick needs is its
+        leavers' number, and no warm-up can promise to have met them
+        all.  A stream whose leavers only count never comes here and
+        loads none of them; a session re-anchored over tables of the
+        same shapes finds them run (rt.extract_rungs_run)."""
         from .ell import make_lane_extract_kernel
-        pair_ix: Dict[Tuple[int, bool], int] = {}
-        for lane, upto in leavers:
-            pair_ix.setdefault((lane >> 3, bool(upto)), len(pair_ix))
-        np_pairs = len(pair_ix)
-        P = max(8, 1 << (np_pairs - 1).bit_length())
-        words_p = np.zeros(P, np.int32)
-        sel_p = np.zeros(P, np.uint8)
-        for (word, upto), j in pair_ix.items():
-            words_p[j] = word
-            sel_p[j] = 1 if upto else 0
-        kern = self.rt._kernel(
-            ("ell_lane_extract", self.ix.shape_sig()),
-            make_lane_extract_kernel)
+        sig = self.ix.shape_sig()
+        kern = self.rt._kernel(("ell_lane_extract", sig),
+                               lambda: make_lane_extract_kernel(self.ix))
+        if (sig, self.B) not in self.rt.extract_rungs_run:
+            for L in lane_extract_rungs(self.B):
+                kern(self.fp, self.accp, np.zeros((3, L), np.int32))
+            self.rt.extract_rungs_run.add((sig, self.B))
+        return kern
+
+    def extract(self, leavers):
+        """Pack each leaving lane down the vertex rows (an UPTO lane
+        out of the accumulator) and return a zero-arg resolver
+        (_LaneFetch) -> per-leaver ascending old-dense-id frontier
+        arrays.  The cohort is padded to its rung
+        (ell.lane_extract_rung) with leavers of word 0, bit 0, which
+        nothing reads.  The resolver is where the d2h fetch forces —
+        call it AFTER enqueueing the next hop
+        so the host assembly overlaps the device compute."""
+        lanes = np.zeros((3, lane_extract_rung(len(leavers), self.B)),
+                         np.int32)
+        for i, (lane, upto) in enumerate(leavers):
+            lanes[:, i] = (lane >> 3, lane & 7, 1 if upto else 0)
+        kern = self._extract_kernel()
         with tracing.span("tpu.kernel", kind="ell_lane_extract",
                           width=self.B):
-            out_dev = kern(self.fp, self.accp, words_p, sel_p)
-        cols_of = [pair_ix[(lane >> 3, bool(upto))]
-                   for lane, upto in leavers]
-        return _LaneFetch(self, out_dev, leavers, cols_of, np_pairs)
+            out_dev = kern(self.fp, self.accp, lanes)
+        # the copy starts when the pack ends, a tick before the
+        # resolver asks for it
+        out_dev.copy_to_host_async()
+        return _LaneFetch(self, out_dev, len(leavers))
 
     def count(self, lanes):
         """Count the set bits of every lane of the resident frontier
@@ -4181,68 +4202,73 @@ class _ContinuousGoSession:
             self.fp, self.accp = kern(self.fp, self.accp, keep)
 
 
-# A leave cohort whose live rows PER LEAVER pass this share of the
-# table's rows takes the whole-column unpack (_unpack_lanes).  On the
-# v5e's host at 665,000 rows, the block column-contiguous as the TPU
-# hands it over (PERF.md section 6, PR 28), one leaver costs 0.16 ms
-# out of 30 live rows, 0.9 out of 30,000, 2.3 out of 130,000 and 3.4
-# out of 200,000, where its whole column through ``perm`` costs
-# 1.5-2.8 ms at any of those counts: the two cross at 0.21-0.24 of the
-# table.  Two leavers of 100,000 rows each are even at 185,000 live
-# rows in all, and twenty-one leavers never cross (12 ms against 34 out
-# of 190,000 rows, 18 against 35 out of 470,000): they share the one
-# pass that finds the live rows.  A speed choice only: both ways give
-# the same arrays.
+# A leaver whose set rows pass this share of the table's vertex rows
+# takes its whole bitmap through ``perm`` (_unpack_lanes); under it, the
+# bits of its non-zero bytes through ``inv`` and a sort.  On the v5e's
+# host at 646,081 rows (PERF.md section 6, PR 37) a leaver costs 0.03
+# ms out of 30 set rows, 0.06 out of 1,000, 0.5 out of 18,000, 2.1 out
+# of 100,000, 2.6 out of 130,000 and 3.9 out of 200,000 that way, and
+# 1.6-1.9 ms at the low counts, 2.5 at 130,000 and 2.9 at 200,000
+# through ``perm``: the two cross at 0.19-0.20 of the table, where
+# the column block's routes crossed too (PR 28).  A speed choice only:
+# both ways give the same array.
 LANE_UNPACK_LIVE_SHARE = 0.2
 
 
-def _unpack_lanes(cols: np.ndarray, n: int, perm: np.ndarray,
-                  inv: np.ndarray, np_pairs: int, leavers, cols_of):
-    """The leave cohort's frontiers out of its fetched block.
+def _unpack_lanes(packed: np.ndarray, n: int, perm: np.ndarray,
+                  inv: np.ndarray, n_leavers: int):
+    """The leave cohort's frontiers out of its fetched buffer.
 
-    ``cols`` is the extract kernel's [n_rows + 1, P] uint8 block:
-    column j < ``np_pairs`` is one (word, carrier) pair with at least
-    one leaver, bit ``lane & 7`` of it one lane.  The TPU hands the
-    block over column by column (each column contiguous), CPU jax row
-    by row; nothing here depends on which.  Rows >= n (hub extra rows)
-    hold an earlier pull's partial ORs, columns >= np_pairs repeat
-    frontier word 0: neither is read.  Returns (per leaver the
-    ascending old dense ids of its set rows, int64: element for element
-    ``np.nonzero(((cols[:, j] >> (lane & 7)) & 1)[perm])[0]``; how many
-    leavers were unpacked out of the live rows; the live rows found).
+    ``packed`` is the extract's result (ell.make_lane_extract_kernel):
+    uint8 [L, nb], nb a multiple of eight; row i < ``n_leavers`` is
+    leaver i's bitmap, bit k of byte j vertex row k * nb + j
+    (ell.lane_bitmap_rows), the bits of rows from n on zero; the rows
+    past the leavers are the rung's padding, never read.  However the
+    device hands the buffer over, the leavers' rows are made one
+    contiguous run each before they are read (on the TPU and on CPU
+    jax they already are).  Returns (per leaver the ascending old
+    dense ids of its set rows, int64: element for element what
+    ``np.nonzero(column_bit[perm])[0]`` gives over the lane's word
+    column; how many leavers were unpacked out of their non-zero bytes
+    and not out of the whole bitmap; the set rows found, summed over
+    the leavers).
 
-    One pass over the real columns finds the rows < n with any bit
-    set.  Where they are few for the leavers that share them
-    (LANE_UNPACK_LIVE_SHARE), every later step works on them alone:
-    their old ids (``inv``) are gathered once a cohort, a column is cut
-    to its own live rows, a leaver tests its bit over those and sorts
-    its ids (``inv`` of ascending rows is one ascending run a degree
-    bucket)."""
-    real = cols[:n]
-    acc = real[:, 0]
-    for j in range(1, np_pairs):
-        acc = acc | real[:, j]
-    live = acc != 0
-    n_live = int(np.count_nonzero(live))
-    bits = [np.uint8(1 << (lane & 7)) for lane, _upto in leavers]
-    if n_live > LANE_UNPACK_LIVE_SHARE * n * len(leavers):
-        return [np.flatnonzero(((real[:, j] & bit) != 0)[perm])
-                .astype(np.int64, copy=False)
-                for bit, j in zip(bits, cols_of)], 0, n_live
-    rows = np.flatnonzero(live)
-    old = inv[rows]
-    members: List[List[int]] = [[] for _ in range(np_pairs)]
-    for i, j in enumerate(cols_of):
-        members[j].append(i)
-    outs: List[np.ndarray] = [None] * len(leavers)
-    for j, idx in enumerate(members):
-        col, ids = real[:, j][rows], old
-        if np_pairs > 1:
-            own = col != 0
-            col, ids = col[own], ids[own]
-        for i in idx:
-            outs[i] = np.sort(ids[(col & bits[i]) != 0]).astype(np.int64)
-    return outs, len(leavers), n_live
+    The set bits are counted first, a leaver at a time in one pass
+    over the 64-bit words, and each leaver's route follows from its
+    own count (LANE_UNPACK_LIVE_SHARE): a cohort's routes can differ.
+    The leavers of few set rows share every later pass, so a cohort
+    costs the interpreter what one leaver does: the non-zero bytes of
+    all their bitmaps are found and unpacked together, a set bit's
+    place gives its leaver and its row, ``inv`` its old id, and one
+    sort by (leaver, id) puts every leaver's ids in order; they leave
+    as slices of that one array.  A leaver of many set rows has its
+    whole bitmap unpacked, put in row order and read in old-id order
+    through ``perm``, which needs no sort."""
+    nb = packed.shape[1]
+    own = np.ascontiguousarray(packed[:n_leavers])
+    found = np.bitwise_count(own.view(np.uint64)).sum(
+        axis=1, dtype=np.int64)
+    few = found <= LANE_UNPACK_LIVE_SHARE * n
+    outs: List[np.ndarray] = [None] * n_leavers
+    for i in np.flatnonzero(~few):
+        bits = np.unpackbits(own[i], bitorder="little").view(np.bool_)
+        rows = bits.reshape(nb, 8).T.ravel()[:n]
+        outs[i] = np.flatnonzero(rows[perm]).astype(np.int64, copy=False)
+    who = np.flatnonzero(few)
+    if len(who):
+        flat = (own if len(who) == n_leavers else own[who]).ravel()
+        at = np.flatnonzero(flat != 0)
+        pos = np.flatnonzero(np.unpackbits(
+            flat[at], bitorder="little").view(np.bool_))
+        # the leaver of every set bit: the bytes lie leaver by leaver
+        of = np.repeat(np.arange(len(who)), found[who])
+        rows = lane_bitmap_rows(at[pos >> 3] - of * nb, pos & 7, n)
+        ids = np.sort(inv[rows] + of * n)
+        ids -= of * n
+        for i, own_ids in zip(who, np.split(
+                ids, np.cumsum(found[who])[:-1])):
+            outs[i] = own_ids
+    return outs, len(who), int(found.sum())
 
 
 class _LaneFetch:
@@ -4254,19 +4280,17 @@ class _LaneFetch:
     and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
     still wraps wait + copy, as the windowed resolvers' does.  What
     the unpack met is left beside them for the same reader:
-    ``unpack_leavers``, of them ``unpack_live`` out of the live rows,
-    ``unpack_rows`` the live rows (_unpack_lanes)."""
+    ``unpack_leavers``, of them ``unpack_live`` out of their non-zero
+    bytes, ``unpack_rows`` the set rows of all of them
+    (_unpack_lanes)."""
 
-    __slots__ = ("session", "out_dev", "leavers", "cols_of",
-                 "np_pairs", "t_wait", "t_d2h", "unpack_leavers",
-                 "unpack_live", "unpack_rows")
+    __slots__ = ("session", "out_dev", "n_leavers", "t_wait", "t_d2h",
+                 "unpack_leavers", "unpack_live", "unpack_rows")
 
-    def __init__(self, session, out_dev, leavers, cols_of, np_pairs):
+    def __init__(self, session, out_dev, n_leavers):
         self.session = session
         self.out_dev = out_dev
-        self.leavers = leavers
-        self.cols_of = cols_of
-        self.np_pairs = np_pairs
+        self.n_leavers = n_leavers
         self.t_wait = self.t_d2h = None
         self.unpack_leavers = self.unpack_live = self.unpack_rows = 0
 
@@ -4279,14 +4303,14 @@ class _LaneFetch:
             # vectors are read here, before the results are handed
             # over, so the tick's record is not held up after it
             self.session.read_hop_info()
-            cols = np.asarray(self.out_dev)         # [R1, P] uint8
+            packed = np.asarray(self.out_dev)       # [L, n / 8] uint8
             self.t_d2h = hostclock.stamp()
-        self.session.rt._note_fetch(cols[:, :self.np_pairs])
+        # the buffer that crossed the link, the rung's padding too
+        self.session.rt._note_fetch(packed)
         ix = self.session.ix
         outs, self.unpack_live, self.unpack_rows = _unpack_lanes(
-            cols, ix.n, ix.perm, ix.inv, self.np_pairs, self.leavers,
-            self.cols_of)
-        self.unpack_leavers = len(self.leavers)
+            packed, ix.n, ix.perm, ix.inv, self.n_leavers)
+        self.unpack_leavers = self.n_leavers
         return outs
 
 

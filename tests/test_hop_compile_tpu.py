@@ -1,6 +1,7 @@
 """The continuous hop program, the batched BFS program whose levels
-take the same step (PR 30) and the per-lane count of the resident
-frontier (PR 33), compiled for the v5e at the
+take the same step (PR 30), the per-lane count of the resident
+frontier (PR 33) and the leavers' extract at every rung of leavers
+(PR 37), compiled for the v5e at the
 benchmark cell's real table shapes — no chip needed: the TPU's
 compiler is installed here and compiles for a described, unattached
 chip (PERF.md §6, PR 25).
@@ -141,3 +142,51 @@ def test_count_program_compiles_for_the_v5e_at_cell_size(one_chip):
     assert mem.temp_size_in_bytes <= 4 * 2**20, mem.temp_size_in_bytes
     # measured 2.5 s
     assert count_s < 20.0, count_s
+
+
+@pytest.mark.parametrize("lanes", [128, 1024])
+def test_extract_program_compiles_for_the_v5e_at_every_rung(one_chip, lanes):
+    """jit_extract as a leave cohort runs it, at every rung of leavers
+    of the 128-lane width the cells run and of the 1,024-lane width
+    the ladder widens to: a lane's bitmap of the vertex rows a leaver
+    comes out (80,768 B at this table's 646,081 vertices), packed
+    plane by plane out of eight contiguous slices of its word column,
+    one lane a turn.
+
+    What the rungs cost the device while they are loaded is their
+    PROGRAMS (generated code), and what they cost while they run is
+    scratch beside the hop's own 179.6 MB, which sets the process's
+    peak (PERF.md section 6, PR 35 and PR 37): both are held here.
+    The 128-lane pair lies column-major on the device, so a lane's
+    word column is a contiguous run; the 1,024-lane pair lies
+    row-major (128 bytes a row fill the TPU's lanes), and there every
+    rung first lays one carrier out anew into 84.4 MB of scratch and
+    the other beside it: a cost no cell runs and no chip has timed
+    (PERF.md section 7)."""
+    import jax
+    from nebula_tpu.tpu import ell as E
+    fp = jax.ShapeDtypeStruct((S20_ROWS + 1, E.lanes_width(lanes)),
+                              np.uint8, sharding=one_chip)
+    kern = E.make_lane_extract_kernel(_Shapes())
+    rungs = E.lane_extract_rungs(lanes)
+    assert rungs[0] == 4 and rungs[-1] == lanes
+    code = 0
+    for L in rungs:
+        cohort = jax.ShapeDtypeStruct((3, L), np.int32, sharding=one_chip)
+        t0 = time.perf_counter()
+        ext = kern.lower(fp, fp, cohort).compile()
+        ext_s = time.perf_counter() - t0
+        mem = ext.memory_analysis()
+        assert mem.output_size_in_bytes == L * 80768, \
+            (L, mem.output_size_in_bytes)
+        # measured 0.19 MB at every rung of 128 lanes, 84.39 MB (one
+        # carrier's [657,675, 128] bytes) at every rung of 1,024
+        assert mem.temp_size_in_bytes <= \
+            (2**20 if lanes == 128 else 85 * 10**6), \
+            (L, mem.temp_size_in_bytes)
+        code += mem.generated_code_size_in_bytes
+        # measured 0.5-1.5 s a rung
+        assert ext_s < 20.0, (L, ext_s)
+    # measured 0.50-0.51 MB a rung at 128 lanes (3.0 MB over the six),
+    # 0.66-0.67 MB at 1,024
+    assert code <= 0.75e6 * len(rungs), code

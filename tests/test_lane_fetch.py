@@ -1,16 +1,21 @@
-"""The lane's way out (tpu/runtime.py _LaneFetch / _unpack_lanes): a
-leave cohort's frontiers are unpacked out of the live rows of its
-fetched block, found once a cohort, or out of each leaver's whole
-column where the live rows per leaver pass LANE_UNPACK_LIVE_SHARE of
-the table.  Either way the arrays are, element for element and dtype
-for dtype, what the formula the resolver used before gives — kept
-here as the reference.  CPU jax: no number here is a device number.
+"""The lane's way out (tpu/ell.py make_lane_extract_kernel, tpu/runtime.py
+_LaneFetch / _unpack_lanes): the extract packs each leaving lane down
+the vertex rows on the device, eight rows a byte, plane by plane, and
+a leaver's frontier is unpacked out of the non-zero bytes of its own
+bitmap (one pass for all such leavers of a cohort), or out of the
+whole bitmap through ``perm`` where its set rows pass
+LANE_UNPACK_LIVE_SHARE of the table.  Either way the arrays are,
+element for element and dtype for dtype, what the formula the resolver
+used before PR 28 gives over the lane's word column — kept here as the
+reference, and every hand-made cohort it was held to is kept with it.
+CPU jax: no number here is a device number.
 """
 import numpy as np
 import pytest
 
 from nebula_tpu.cluster import LocalCluster
 from nebula_tpu.common.flags import flags
+from nebula_tpu.tpu import ell as E
 from nebula_tpu.tpu.runtime import (LANE_UNPACK_LIVE_SHARE, _LaneFetch,
                                     _unpack_lanes)
 
@@ -50,9 +55,37 @@ def _block(n, extra, P, perm, frontiers):
     return cols
 
 
+def _pack_rows(bits, n):
+    """A lane's bitmap as the extract lays it: ``bits`` is the lane's
+    0/1 column over the rows < n; plane k (rows k * nb .. k * nb + nb
+    - 1) goes to bit k of the nb bytes — np.packbits, little, of the
+    column laid plane by plane."""
+    nb = E.lane_bitmap_bytes(n)
+    planes = np.zeros(8 * nb, np.uint8)
+    planes[:n] = bits
+    return np.packbits(planes.reshape(8, nb).T, axis=1,
+                       bitorder="little")[:, 0]
+
+
+def _packed(cols, n, leavers, cols_of, rung=None, order="C"):
+    """What the extract hands back for a cohort whose word columns are
+    ``cols`` (the block the extract returned until PR 37, which the
+    reference still reads): uint8 [rung, lane_bitmap_bytes(n)], a
+    leaver's row its bit of its column over the rows < n; the rung's
+    padding rows hold another lane's bits, as a padding leaver's do
+    (word 0, bit 0)."""
+    L = rung or E.lane_extract_rung(len(leavers), 128)
+    out = np.zeros((L, E.lane_bitmap_bytes(n)), np.uint8)
+    for i, ((lane, _upto), j) in enumerate(zip(leavers, cols_of)):
+        out[i] = _pack_rows((cols[:n, j] >> (lane & 7)) & np.uint8(1), n)
+    out[len(leavers):] = _pack_rows(cols[:n, 0] & np.uint8(1), n)
+    return np.asarray(out, order=order)
+
+
 def _case(name):
     """(cols, n, perm, inv, np_pairs, leavers, cols_of) of one
-    hand-made cohort."""
+    hand-made cohort: ``cols`` is the word-column block of the
+    reference, _packed turns it into what _unpack_lanes is fed."""
     rng = np.random.default_rng(7)
     n, extra, P = 400, 6, 8
     perm, inv = _index(n, 5)
@@ -105,6 +138,13 @@ def _case(name):
         fr = [(i >> 3, i, parts[i]) for i in range(20)]
         leavers = [(i, False) for i in range(20)]
         cols_of, pairs = [i >> 3 for i in range(20)], 3
+    elif name == "full_lanes_among_sparse_ones":
+        # each leaver by its own count: two go through perm, three out
+        # of their non-zero bytes, and keep their places in the cohort
+        fr = [(0, 0, few(5, 1)), (0, 1, np.arange(n)), (1, 8, few(30, 2)),
+              (1, 9, few(300, 3)), (1, 10, few(1, 4))]
+        leavers = [(i, False) for i in (0, 1, 8, 9, 10)]
+        cols_of, pairs = [0, 0, 1, 1, 1], 2
     elif name == "just_under_the_share":
         fr = [(0, 0, few(int(LANE_UNPACK_LIVE_SHARE * n), 1))]
         leavers, cols_of, pairs = [(0, False)], [0], 1
@@ -126,8 +166,10 @@ def _case(name):
     return cols, n, perm, inv, pairs, leavers, cols_of
 
 
-# name -> does the cohort go out of the live rows?
+# name -> do the leavers go out of their non-zero bytes?  (a number:
+# so many of them do)
 CASES = {
+    "full_lanes_among_sparse_ones": 3,
     "one_leaver": True, "leavers_sharing_one_word": True,
     "a_seated_lane_in_the_leavers_word": True,
     "exact_and_upto_of_one_word": True, "several_words_P8": True,
@@ -143,25 +185,24 @@ CASES = {
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_unpack_equals_the_whole_column_formula(name, order):
     cols, n, perm, inv, pairs, leavers, cols_of = _case(name)
-    if order == "columns_contiguous":
-        # what np.asarray gives on the TPU for P of 8 and 16 (PERF.md
-        # section 6, PR 28); CPU jax and P = 128 give rows
-        cols = np.asfortranarray(cols)
-        assert cols.flags.f_contiguous
-    cols.setflags(write=False)                  # as np.asarray's is
-    assert not cols[-1].any()                   # the pad row
+    # a leaver's bitmap contiguous (what np.asarray gives on the TPU
+    # and on CPU jax) or strided (a buffer handed over the other way
+    # round, as the column block was on the TPU: PERF.md section 6,
+    # PR 28): the unpack reads either
+    packed = _packed(cols, n, leavers, cols_of,
+                     order="C" if order == "rows_contiguous" else "F")
+    packed.setflags(write=False)                # as np.asarray's is
     want = _reference(cols, perm, leavers, cols_of)
-    outs, live, rows = _unpack_lanes(cols, n, perm, inv, pairs, leavers,
-                                     cols_of)
+    outs, live, rows = _unpack_lanes(packed, n, perm, inv, len(leavers))
     assert len(outs) == len(leavers)
     for got, ref in zip(outs, want):
         assert got.dtype == np.int64 and got.ndim == 1
         assert np.array_equal(got, ref)
         assert np.all(np.diff(got) > 0)         # ascending, no repeat
-    assert live == (len(leavers) if CASES[name] else 0)
-    # the rows the pass found: those under n with a bit in a real pair
-    # (rows >= n and the pad columns never count)
-    assert rows == int(np.count_nonzero(cols[:n, :pairs].any(axis=1)))
+    assert live == (len(leavers) if CASES[name] is True else CASES[name])
+    # the set rows found, leaver by leaver (rows >= n, the seated
+    # lanes of a leaver's word and the rung's padding never count)
+    assert rows == sum(len(ref) for ref in want)
 
 
 def test_inv_of_ascending_rows_is_not_ascending():
@@ -174,13 +215,148 @@ def test_inv_of_ascending_rows_is_not_ascending():
 
 
 def test_junk_in_the_hub_rows_reaches_no_answer():
+    """The hub extra rows' junk never reaches the packed form, so
+    both blocks pack to the same buffer and unpack to one answer."""
     cols, n, perm, inv, pairs, leavers, cols_of = _case("one_leaver")
     clean = cols.copy()
     clean[n:-1] = 0
     assert cols[n:-1].any()
-    a = _unpack_lanes(cols, n, perm, inv, pairs, leavers, cols_of)
-    b = _unpack_lanes(clean, n, perm, inv, pairs, leavers, cols_of)
+    pa, pb = (_packed(c, n, leavers, cols_of) for c in (cols, clean))
+    assert np.array_equal(pa, pb)
+    a = _unpack_lanes(pa, n, perm, inv, len(leavers))
+    b = _unpack_lanes(pb, n, perm, inv, len(leavers))
     assert np.array_equal(a[0][0], b[0][0]) and a[1:] == b[1:]
+
+
+@pytest.mark.parametrize("k", [0, 1, 79, 80, 81, 200, 399, 400])
+def test_both_routes_give_one_array_on_either_side_of_the_share(
+        k, monkeypatch):
+    """A leaver of k set rows of 400, unpacked with the share put
+    under and over k: the route is a speed choice only."""
+    from nebula_tpu.tpu import runtime
+    n = 400
+    perm, inv = _index(n, 5)
+    old_ids = np.sort(np.random.default_rng(k).choice(n, k, replace=False))
+    bits = np.zeros(n, np.uint8)
+    bits[perm[old_ids]] = 1
+    bitmap = _pack_rows(bits, n)
+    packed = np.stack([bitmap, bitmap])     # the second row: padding
+    got = {}
+    for share, sparse in ((1.0, 1), (-1.0, 0)):
+        monkeypatch.setattr(runtime, "LANE_UNPACK_LIVE_SHARE", share)
+        (ids,), was_sparse, found = _unpack_lanes(packed, n, perm, inv, 1)
+        assert was_sparse == sparse and found == k
+        assert ids.dtype == np.int64
+        got[sparse] = ids
+    assert np.array_equal(got[1], got[0])
+    assert np.array_equal(got[1], old_ids)
+    monkeypatch.undo()
+    # the shipped share puts the turn at 80 of 400
+    assert _unpack_lanes(packed, n, perm, inv, 1)[1] \
+        == (k <= LANE_UNPACK_LIVE_SHARE * n)
+
+
+# ============================== the device's pack, on CPU jax
+class _N:
+    """What make_lane_extract_kernel reads of an EllIndex."""
+
+    def __init__(self, n):
+        self.n = n
+
+
+def _pair(n, extra, W, seed):
+    """A resident pair with bits everywhere, junk in the hub extra
+    rows and (against the contract, to show it is never read) in the
+    pad row."""
+    rng = np.random.default_rng(seed)
+    fp = rng.integers(0, 256, (n + extra + 1, W), dtype=np.uint8) \
+        & rng.integers(0, 256, (n + extra + 1, W), dtype=np.uint8)
+    acc = fp | rng.integers(0, 256, fp.shape, dtype=np.uint8)
+    return fp, acc
+
+
+def _want(fp, acc, n, lanes):
+    return np.stack([
+        _pack_rows(((acc if carrier else fp)[:n, word] >> bit) & 1, n)
+        for word, bit, carrier in lanes.T])
+
+
+def _rows_of(bitmap, n):
+    """The set rows of one bitmap, ascending, by the map the host
+    uses (ell.lane_bitmap_rows)."""
+    at, bit = np.nonzero(np.unpackbits(bitmap[:, None], axis=1,
+                                       bitorder="little"))
+    return np.sort(E.lane_bitmap_rows(at, bit, n))
+
+
+@pytest.mark.parametrize("n", [61, 1000, 1003, 1024])
+@pytest.mark.parametrize("L", E.lane_extract_rungs(1024))
+def test_device_pack_equals_packbits_at_every_rung(n, L):
+    """n under 64, a multiple of 8 and not of 64, of neither, of
+    both; every rung of the 128-lane width and of the 1,024-lane
+    width the ladder widens to (the first's are among the second's);
+    each lane against np.packbits(..., bitorder="little") of its
+    column, plane by plane, and against the column itself by the
+    host's map."""
+    import jax.numpy as jnp
+    assert set(E.lane_extract_rungs(128)) < set(E.lane_extract_rungs(1024))
+    W = E.lanes_width(max(L, 128))
+    fp, acc = _pair(n, 5, W, seed=n + L)
+    rng = np.random.default_rng(L)
+    lanes = np.stack([rng.integers(0, W, L), rng.integers(0, 8, L),
+                      rng.integers(0, 2, L)]).astype(np.int32)
+    got = np.asarray(E.make_lane_extract_kernel(_N(n))(
+        jnp.asarray(fp), jnp.asarray(acc), lanes))
+    assert got.dtype == np.uint8
+    assert got.shape == (L, E.lane_bitmap_bytes(n))
+    assert got.shape[1] % 8 == 0 and got.shape[1] * 8 >= n
+    assert np.array_equal(got, _want(fp, acc, n, lanes))
+    for row, (word, bit, carrier) in zip(got[:3], lanes.T):
+        col = (acc if carrier else fp)[:n, word]
+        assert np.array_equal(_rows_of(row, n),
+                              np.flatnonzero((col >> bit) & 1))
+
+
+def test_rungs_hold_every_leaver_count():
+    assert E.lane_extract_rungs(128) == (4, 8, 16, 32, 64, 128)
+    for B in (128, 1024, 96, 100, 4):
+        rungs = E.lane_extract_rungs(B)
+        assert rungs[-1] == B and list(rungs) == sorted(set(rungs))
+        for k in range(1, B + 1):
+            L = E.lane_extract_rung(k, B)
+            assert L in rungs and L >= k
+            assert all(r < k for r in rungs if r < L)
+
+
+def test_device_pack_of_an_empty_and_a_full_lane_and_one_word_twice():
+    """One cohort: a lane with no bit, a lane with every bit, and an
+    exact-depth and an UPTO leaver of one word (two carriers, one
+    word, two rows of the result); hub rows and pad row all ones."""
+    import jax.numpy as jnp
+    n, extra, W = 203, 4, E.lanes_width(128)
+    fp = np.zeros((n + extra + 1, W), np.uint8)
+    acc = np.zeros_like(fp)
+    fp[:n, 2] |= 1 << 5                     # lane 21: full
+    fp[::3, 1] |= 1 << 1                    # lane 9, the frontier
+    acc[::2, 1] |= 1 << 1                   # lane 9, the accumulator
+    fp[n:] = 0xFF                           # junk: never packed
+    acc[n:] = 0xFF
+    # lane 40 (word 5, bit 0): no bit anywhere
+    lanes = np.array([[5, 0, 0], [2, 5, 0], [1, 1, 0], [1, 1, 1]],
+                     np.int32).T
+    got = np.asarray(E.make_lane_extract_kernel(_N(n))(
+        jnp.asarray(fp), jnp.asarray(acc), lanes))
+    rows = [_rows_of(r, n) for r in got]
+    assert rows[0].tolist() == []
+    assert rows[1].tolist() == list(range(n))       # nothing past n
+    assert rows[2].tolist() == list(range(0, n, 3))
+    assert rows[3].tolist() == list(range(0, n, 2))
+    clean_fp, clean_acc = fp.copy(), acc.copy()
+    clean_fp[n:] = 0
+    clean_acc[n:] = 0
+    again = np.asarray(E.make_lane_extract_kernel(_N(n))(
+        jnp.asarray(clean_fp), jnp.asarray(clean_acc), lanes))
+    assert np.array_equal(got, again)
 
 
 # ====================================== a real session, both sides
@@ -238,9 +414,14 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     sess.join([(9, [start])])
     sess.hop()
     sess.hop()
+    before = sess.rt.stats["fetch_bytes"]
     resolver = sess.extract([(9, False), (9, True)])
     assert isinstance(resolver, _LaneFetch)
     exact, upto = resolver()
+    # what crossed the link: the rung's buffer, the least rung's four
+    # bitmaps of the vertex rows for two leavers
+    assert sess.rt.stats["fetch_bytes"] - before \
+        == 4 * E.lane_bitmap_bytes(n)
     want = sorted({row[0] for row in _cpu(
         ok, f"GO 2 STEPS FROM {start} OVER e YIELD e._dst").rows})
     assert sess.m.vids[exact].tolist() == want
@@ -251,12 +432,45 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     assert sess.m.vids[upto].tolist() == want_upto
     for arr in (exact, upto):
         assert arr.dtype == np.int64 and np.all(np.diff(arr) > 0)
-    # which side of the share: live rows per leaver against the table
+    # which side of the share: each leaver's own set rows against the
+    # table (here both leavers fall on one side)
     assert resolver.unpack_leavers == 2
-    assert resolver.unpack_rows == len(want_upto)
-    assert (resolver.unpack_rows <= LANE_UNPACK_LIVE_SHARE * n * 2) \
-        == bool(live)
+    assert resolver.unpack_rows == len(want) + len(want_upto)
+    for rows in (want, want_upto):
+        assert (len(rows) <= LANE_UNPACK_LIVE_SHARE * n) == bool(live)
     assert resolver.unpack_live == 2 * live
+
+
+def test_every_rung_runs_once_and_only_where_a_stream_fetches(star):
+    """A session whose leavers only count runs no extract program, so
+    loads none; the first fetching cohort over these table shapes at
+    this width runs every rung of leavers once, and a session
+    re-anchored over the same shapes runs only its cohort's."""
+    c, _ok = star
+    rt = c.tpu_runtime
+    sess = _session(c)
+    sig = sess.ix.shape_sig()
+    assert (sig, sess.B) in rt.extract_rungs_run    # the fixture's GO
+    rt.extract_rungs_run.discard((sig, sess.B))
+    kern, ran = rt._kernels[("ell_lane_extract", sig)], []
+    rt._kernels[("ell_lane_extract", sig)] = \
+        lambda fp, accp, lanes: ran.append(lanes.shape[1]) or kern(
+            fp, accp, lanes)
+    try:
+        sess.join([(9, [1])])
+        sess.hop()
+        assert list(sess.count([9])()) == [1]
+        assert not ran and (sig, sess.B) not in rt.extract_rungs_run
+        first = sess.extract([(9, False)])()
+        assert ran == list(E.lane_extract_rungs(sess.B)) + [4]
+        assert (sig, sess.B) in rt.extract_rungs_run
+        again = _session(c)
+        again.join([(9, [1])])
+        again.hop()
+        assert np.array_equal(again.extract([(9, False)])()[0], first[0])
+        assert ran[len(E.lane_extract_rungs(sess.B)):] == [4, 4]
+    finally:
+        rt._kernels[("ell_lane_extract", sig)] = kern
 
 
 @pytest.mark.parametrize("start", [1, 10])

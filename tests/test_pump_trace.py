@@ -838,7 +838,8 @@ class TestKernelScopes:
             low = E.make_lane_join_kernel(ix, donate=False).lower(
                 fp, fp, i32, i32, u8)
         elif make == "make_lane_extract_kernel":
-            low = E.make_lane_extract_kernel().lower(fp, fp, i32, u8)
+            low = E.make_lane_extract_kernel(ix).lower(
+                fp, fp, jnp.zeros((3, 8), jnp.int32))
         else:
             low = E.make_lane_clear_kernel(donate=False).lower(
                 fp, fp, jnp.zeros(W, jnp.uint8))

@@ -319,8 +319,18 @@ def test_a_parked_rider_holds_neither_the_pump_nor_a_later_rider(served):
 
     t = threading.Thread(target=park)
     try:
+        before = flight.recorder.note_tick(stream=-1)
         t.start()
         assert entered.wait(30), "the first rider never assembled"
+        # the record of the tick that handed the first rider its
+        # frontier lands after the rider is woken, and under a loaded
+        # host the rider can be here first: wait for that record, so
+        # the mark falls after it
+        end = time.monotonic() + 5.0
+        while not any(r["handed"] for r in _ticks()
+                      if r["id"] > before and r["stream"] >= 0) \
+                and time.monotonic() < end:
+            time.sleep(0.01)
         mark = flight.recorder.note_tick(stream=-1)
         resp = ok(later)        # seated after it, leaves and returns
         assert t.is_alive() and "resp" not in first
