@@ -140,6 +140,8 @@ class FlightRecorder:
         _finish), counted and count_us (the leavers answered by the
         device's per-lane count, k-hop neighbourhood counts, and the
         pump's wait for and read of it: the head of fetch_wait_us),
+        distinct (the fetching leavers whose frontier IS their answer:
+        k-hop neighbourhoods, reduce "distinct"),
         the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
         the live slot rows; hop_slots the ELL slots they visited;

@@ -494,7 +494,11 @@ class _Rider:
     edges are the host's to assemble from the frontier — but for a
     k-hop neighbourhood count (reduce "count_distinct": ``counts``),
     which rides all of its steps and leaves with the size of its last
-    frontier, counted on the device, and no extraction.  Fields are
+    frontier, counted on the device, and no extraction, and for the
+    k-hop neighbourhood itself (reduce "distinct": ``distinct``),
+    which rides all of its steps too and is a fetching leaver like any
+    other: its last frontier, extracted and unpacked, IS its answer
+    (_assemble_own turns the vertex rows into ids).  Fields are
     written by the stream pump under the stream condition; the
     submitting thread reads them after ``done`` flips: its
     ``frontier``, which its own thread assembles (submit()), or the
@@ -502,8 +506,8 @@ class _Rider:
     leaver's number, a COUNT rider, a WHERE that filters in numpy:
     _finish)."""
 
-    __slots__ = ("payload", "steps", "upto", "reduce", "counts", "hops",
-                 "deadline",
+    __slots__ = ("payload", "steps", "upto", "reduce", "counts",
+                 "distinct", "hops", "deadline",
                  "tctx", "enq", "enq_t", "seated_t", "left_t", "done_t",
                  "lane", "remaining", "joined_tick", "left_tick",
                  "midflight", "done", "result", "frontier", "mirror",
@@ -517,7 +521,10 @@ class _Rider:
         self.reduce = tuple(reduce) if reduce is not None else None
         self.counts = self.reduce is not None \
             and self.reduce[0] == "count_distinct"
-        self.hops = self.steps if self.counts else self.steps - 1
+        self.distinct = self.reduce is not None \
+            and self.reduce[0] == "distinct"
+        self.hops = self.steps if self.counts or self.distinct \
+            else self.steps - 1
         self.deadline = deadline
         # the submitter's trace snapshot: the pump attaches it around
         # the device phases this rider participates in, so a PROFILE
@@ -1110,7 +1117,8 @@ class _ContinuousStream:
             # field names (_finish)
             met = {name: sum(f[2][name] for f in finishes)
                    for name in ("unpack_leavers", "unpack_live",
-                                "unpack_rows", "counted", "count_us")}
+                                "unpack_rows", "counted", "count_us",
+                                "distinct")}
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1252,8 +1260,10 @@ class _ContinuousStream:
         field names: unpack_leavers, unpack_live, unpack_rows —
         tpu/runtime.py _unpack_lanes — and counted, count_us: the
         counting leavers and the wait for and read of their counts,
-        which is the head of the fetch wait).  The handover ends where
-        the caller stamps next."""
+        which is the head of the fetch wait — and distinct: the
+        fetching leavers whose frontier is their answer, k-hop
+        neighbourhoods).  The handover ends where the caller stamps
+        next."""
         resolver, leavers, m, counter = pending
         rt = self.sched.runtime
         ta = hostclock.stamp()
@@ -1313,6 +1323,7 @@ class _ContinuousStream:
                ("unpack_leavers", "unpack_live", "unpack_rows")}
         met["counted"] = len(counting)
         met["count_us"] = hostclock.split(ta, t_count)[0]
+        met["distinct"] = sum(r.distinct for r in fetching)
         stats.add_value("graph.continuous.leaves", len(leavers))
         handed = 0
         with self.cond:
@@ -1466,10 +1477,12 @@ class _ContinuousStream:
         condition: the same continuous_results the pump answers its
         own leavers through, over this one statement, against the
         generation the pump extracted under — candidate runs, the
-        WHERE in float64, the rows.  Its tpu.assemble / tpu.where
-        spans land on the rider's own trace.  A per-query failure (an
-        Exception entry) becomes this rider's error; a rider killed or
-        out of budget since the handover skips the pass."""
+        WHERE in float64, the rows; a k-hop neighbourhood's frontier
+        is its rows already (rt.distinct_results).  Its tpu.assemble /
+        tpu.where spans land on the rider's own trace.  A per-query
+        failure (an Exception entry) becomes this rider's error; a
+        rider killed or out of budget since the handover skips the
+        pass."""
         if query_registry.is_killed(rider.qid):
             rider.error = KilledError(
                 "go: ended by KILL QUERY before its rows were "
@@ -1480,10 +1493,18 @@ class _ContinuousStream:
                 "go: deadline expired mid-flight")
             self.sched.dispatcher._note_deadline_drop(key)
             return
+        rt = self.sched.runtime
         try:
-            out = self.sched.runtime.continuous_results(
-                self.space_id, rider.mirror, [rider.payload],
-                [rider.reduce], [rider.frontier], self.et_tuple)[0]
+            if rider.distinct:
+                # what the pump handed is final: the vertex rows'
+                # ids are the one column, no candidate edge assembled
+                out = rt.distinct_results(
+                    rider.mirror, [rider.payload], [rider.frontier],
+                    [rider.hops])[0]
+            else:
+                out = rt.continuous_results(
+                    self.space_id, rider.mirror, [rider.payload],
+                    [rider.reduce], [rider.frontier], self.et_tuple)[0]
         except Exception as ex:         # noqa: BLE001 — this rider's
             out = ex
         if isinstance(out, Exception):
@@ -1553,9 +1574,11 @@ class ContinuousGoScheduler:
         except (TypeError, ValueError):
             return False
         reduce = key[5]
-        if reduce is not None and reduce[0] == "count_distinct":
-            # a k-hop neighbourhood count rides every one of its steps
-            # and leaves with a number: one step is a hop to ride too
+        if reduce is not None \
+                and reduce[0] in ("count_distinct", "distinct"):
+            # a k-hop neighbourhood (and its count) rides every one of
+            # its steps and leaves with its last frontier (a number):
+            # one step is a hop to ride too
             return steps >= 1
         if reduce is not None and reduce[0] not in ("count", "limit"):
             return False

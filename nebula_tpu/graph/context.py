@@ -53,9 +53,12 @@ class ExecutionContext:
         # engine-scoped so estimates persist across queries
         self.router = router
         # pipe-reduction hint (traverse.PipeExecutor → GoExecutor):
-        # ("limit", n) / ("count",) when the enclosing pipe can consume
-        # a device-reduced GO result (LIMIT/COUNT pushdown — fetch
-        # returns only surviving/reduced rows, docs/roofline.md)
+        # ("limit", n) / ("count",) / ("count_distinct",) when the
+        # enclosing pipe can consume a device-reduced GO result
+        # (LIMIT/COUNT pushdown — fetch returns only surviving/reduced
+        # rows, docs/roofline.md); where none is set the GO executor
+        # sets ("distinct",) itself for the one DISTINCT the device
+        # answers (traverse._go_distinct_dst)
         self.go_reduce = None
 
     def note_partial(self, resp) -> None:

@@ -212,6 +212,9 @@ class _RowCtx(ExprContext):
 # ================================================================== GO
 class GoExecutor(Executor):
     NAME = "GoExecutor"
+    # set by a pipe whose right side is a LIMIT: the cut takes the rows
+    # as they come, so this GO keeps the order _distinct_rows gives
+    cut_behind = False
 
     def execute(self) -> InterimResult:
         self.check_space_chosen()
@@ -298,6 +301,13 @@ class GoExecutor(Executor):
         # meaningful on the device path — the CPU loop below ignores it
         # and serves full rows, which the fused pipe handles identically
         reduce = self.ectx.go_reduce
+        if reduce is None and not self.cut_behind \
+                and s.from_.ref is None and _go_distinct_dst(s):
+            # the k-hop neighbourhood itself: the distinct destinations
+            # of hop k are frontier k, so the device rides one hop more
+            # and the frontier is the answer; the CPU loop below
+            # de-duplicates the last hop's rows to the same set
+            reduce = ("distinct",)
         if rt is not None and prefer_device \
                 and rt.can_run_go(space, etypes, s, pushed, remnant,
                                   src_refs, dst_refs,
@@ -955,37 +965,50 @@ class SetExecutor(Executor):
                              [r for r in left.rows if tuple(r) in keep])
 
 
+def _go_plain(go) -> bool:
+    """A GO that cannot raise per-row errors: meta-only YIELD columns
+    (_dst/_src/_rank/_type never error), no WHERE, no UPTO."""
+    if go.where is not None:
+        return False
+    if getattr(go.step, "upto", False) and go.step.steps > 1:
+        return False
+    return go.yield_ is None or all(
+        isinstance(c.expr, (EdgeDstIdExpr, EdgeSrcIdExpr, EdgeRankExpr,
+                            EdgeTypeExpr))
+        for c in go.yield_.columns)
+
+
+def _go_distinct_dst(go) -> bool:
+    """The one DISTINCT the device answers: ``YIELD DISTINCT <e>._dst``
+    as a plain GO's only column, over one edge type, forwards.  Its
+    rows are the next frontier: on their own the k-hop neighbourhood
+    (GoExecutor: reduce "distinct"), piped into a bare COUNT(*) its
+    size (_go_reduce_shape: "count_distinct")."""
+    return go.yield_ is not None and go.yield_.distinct \
+        and _go_plain(go) \
+        and len(go.yield_.columns) == 1 \
+        and isinstance(go.yield_.columns[0].expr, EdgeDstIdExpr) \
+        and not go.over.is_all and not go.over.reversely \
+        and len(go.over.edges) == 1
+
+
 def _go_reduce_shape(left, right):
     """-> ("limit", cap) | ("count", col_name) |
     ("count_distinct", col_name) | None: the GO|LIMIT and
     GO|YIELD COUNT(*) pipe shapes whose result the device can REDUCE
     before the fetch (ROADMAP item 2 pushdown).  The gate is
     conservative: the left GO must be unable to raise per-row errors
-    (meta-only YIELD columns — _dst/_src/_rank/_type never error — no
-    WHERE, no UPTO), because a truncated/counted result would skip
+    (_go_plain), because a truncated/counted result would skip
     rows whose evaluation the CPU path would have failed on.  A
-    DISTINCT is reduced in one shape only: ``YIELD DISTINCT <e>._dst``
-    as the GO's one column, over one edge type, piped into the bare
-    COUNT(*) — the k-hop neighbourhood count, whose distinct
-    destinations are the next frontier, so the device rides one hop
-    more and counts it.  Every other DISTINCT stays unreduced."""
-    if not isinstance(left, ast.GoSentence):
-        return None
-    if left.where is not None:
-        return None
-    if getattr(left.step, "upto", False) and left.step.steps > 1:
+    DISTINCT is reduced in one shape only (_go_distinct_dst), piped
+    into the bare COUNT(*) — the k-hop neighbourhood count, whose
+    distinct destinations are the next frontier, so the device rides
+    one hop more and counts it.  Every other DISTINCT stays
+    unreduced."""
+    if not isinstance(left, ast.GoSentence) or not _go_plain(left):
         return None
     distinct = left.yield_ is not None and left.yield_.distinct
-    if left.yield_ is not None:
-        for c in left.yield_.columns:
-            if not isinstance(c.expr, (EdgeDstIdExpr, EdgeSrcIdExpr,
-                                       EdgeRankExpr, EdgeTypeExpr)):
-                return None
-    if distinct and not (
-            len(left.yield_.columns) == 1
-            and isinstance(left.yield_.columns[0].expr, EdgeDstIdExpr)
-            and not left.over.is_all and not left.over.reversely
-            and len(left.over.edges) == 1):
+    if distinct and not _go_distinct_dst(left):
         return None
     if isinstance(right, ast.LimitSentence):
         if distinct or right.count < 0 or right.offset < 0:
@@ -1018,8 +1041,10 @@ class PipeExecutor(Executor):
         fused = self._try_reduced_pipe(s)
         if fused is not None:
             return fused
-        left = traced_execute(make_executor(s.left, self.ectx),
-                              self.ectx)
+        left_ex = make_executor(s.left, self.ectx)
+        if isinstance(s.right, ast.LimitSentence):
+            left_ex.cut_behind = True
+        left = traced_execute(left_ex, self.ectx)
         saved = self.ectx.input
         self.ectx.input = left if left is not None else InterimResult([])
         try:

@@ -47,7 +47,8 @@ flags.define("query_deadline_ms", 300000,
              "dispatcher, which drops expired entries before device "
              "launch.  Per-statement `TIMEOUT n` prefix or the "
              "client's timeout_ms execute option override it; 0 "
-             "disables the default deadline")
+             "disables the default deadline.  Managed: UPDATE CONFIGS "
+             "graph:query_deadline_ms=...")
 
 
 # statement Kind → declared-SLO query class (common/slo.py

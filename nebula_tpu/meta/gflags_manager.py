@@ -18,7 +18,8 @@ _MANAGED = {
                          "storage_backend",
                          "find_path_max_paths",
                          "tpu_filter_mode",
-                         "go_dispatch_mode"],
+                         "go_dispatch_mode",
+                         "query_deadline_ms"],
     ConfigModule.META: ["expired_threshold_sec"],
     ConfigModule.STORAGE: ["heartbeat_interval_secs",
                            "load_data_interval_secs",

@@ -727,7 +727,13 @@ class TpuQueryRuntime:
                       # tier: statements, the hops they rode, the sum
                       # of their answers (count_distinct_results)
                       "go_count_distinct": 0, "count_distinct_hops": 0,
-                      "count_distinct_vertices": 0}
+                      "count_distinct_vertices": 0,
+                      # k-hop neighbourhoods (the same GO with no pipe
+                      # behind it) answered by k hops and the k-th
+                      # frontier itself, either tier: statements, hops
+                      # ridden, rows returned (distinct_results)
+                      "go_distinct": 0, "distinct_hops": 0,
+                      "distinct_vertices": 0}
         self._timing_seq = 0
         # shapes the AOT pre-warm compiled / shapes live dispatch used
         # (prewarm_hits/misses make the pre-warm's p99 effect auditable:
@@ -1712,9 +1718,11 @@ class TpuQueryRuntime:
         # (steps + 1)-step GO's, on whichever program serves that
         count_distinct = reduce is not None \
             and reduce[0] == "count_distinct"
+        # ... and the k-hop neighbourhood itself is that frontier
+        distinct = reduce is not None and reduce[0] == "distinct"
         with tracing.span("tpu.launch", queries=len(live),
                           steps=steps):
-            if count_distinct:
+            if count_distinct or distinct:
                 launch = self._launch_frontiers(space_id, starts,
                                                 et_tuple, steps + 1)
             else:
@@ -1740,6 +1748,9 @@ class TpuQueryRuntime:
                     results = self.count_distinct_results(
                         [len(vs) if m.m else 0 for vs in vs_lists],
                         [steps] * len(live))
+                elif distinct:
+                    results = self.distinct_results(
+                        m, live, vs_lists, [steps] * len(live))
                 elif reduce is not None and reduce[0] == "count":
                     # COUNT(*) pushdown: no candidate assembly, no row
                     # materialization — the result per query is one
@@ -1799,6 +1810,38 @@ class TpuQueryRuntime:
             self.stats["count_distinct_vertices"] += int(sum(counts))
             self.stats["go_reduced"] += len(counts)
         return [(["__count__"], [[int(c)]]) for c in counts]
+
+    def distinct_results(self, m: CsrMirror, queries: List[_GoQuery],
+                         vs_lists, hops):
+        """Results of k-hop neighbourhoods (GO k STEPS ... YIELD
+        DISTINCT e._dst, reduce "distinct") from their k-th frontiers:
+        a frontier array is ascending and without repeats by
+        construction, so its vertices' ids are the one column, each
+        once, and there is no row where nobody is reached (a mirror
+        with no edge advances nothing and hands the starts back).  No
+        candidate edge, no gather, no sort.  The span is the assembly's
+        own name with tags ``reduce`` and ``vertices`` (a windowed
+        leader's, or a continuous rider's on its own thread); counters:
+        statements, hops ridden, rows returned."""
+        from ..graph.interim import ColumnarRows
+        results = []
+        with tracing.span("tpu.assemble", queries=len(queries),
+                          reduce="distinct") as sp:
+            for q, vs in zip(queries, vs_lists):
+                ids = m.vids[np.asarray(vs if m.m else (), np.int64)]
+                results.append((
+                    [c.alias or _default_col_name(c.expr)
+                     for c in q.yield_cols],
+                    ColumnarRows([ids], len(ids)) if len(ids) else []))
+            vertices = sum(len(rows) for _c, rows in results)
+            if sp is not None:
+                sp.tag(vertices=vertices)
+        with self._lock:
+            self.stats["go_distinct"] += len(results)
+            self.stats["distinct_hops"] += int(sum(hops))
+            self.stats["distinct_vertices"] += vertices
+            self.stats["go_reduced"] += len(results)
+        return results
 
     # ------------------------------------- continuous dispatch seam
     def continuous_session(self, space_id: int,
@@ -4494,7 +4537,10 @@ def _all_chains(m: CsrMirror, index, depth: np.ndarray, t: int,
 def _distinct_rows(rows):
     """YIELD DISTINCT: the first occurrence of every row, in order.
     Rows still held as integer columns are de-duplicated in one
-    vectorised pass (the first index of each distinct row, ascending);
+    vectorised pass (the first index of each distinct row, ascending;
+    a single column through numpy's plain 1-D unique, which sorts the
+    integers themselves: ``axis=0`` goes through a structured view and
+    takes eight times as long, so only several columns take it);
     anything else (rows already materialised, a float, string or
     dictionary column) keeps the row-by-row loop.  Same rows, same
     order, either way."""
@@ -4504,8 +4550,11 @@ def _distinct_rows(rows):
             isinstance(c, np.ndarray) and c.ndim == 1
             and c.dtype.kind in "iu" and c.dtype == cols[0].dtype
             for c in cols):
-        table = cols[0] if len(cols) == 1 else np.stack(cols, axis=1)
-        first = np.unique(table, axis=0, return_index=True)[1]
+        if len(cols) == 1:
+            first = np.unique(cols[0], return_index=True)[1]
+        else:
+            first = np.unique(np.stack(cols, axis=1), axis=0,
+                              return_index=True)[1]
         first.sort()
         return ColumnarRows([c[first] for c in cols], len(first))
     seen = set()

@@ -680,6 +680,7 @@ def test_a_rider_killed_or_late_after_the_handover_skips_its_pass(
         qid = None
         deadline = None
         frontier = [1]
+        distinct = False
         error = result = mirror = payload = reduce = None
 
     class Spent:
