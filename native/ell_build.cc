@@ -8,8 +8,10 @@
 //     layout: a +etype row (u, v) is v's in-slot u (in-table), a
 //     -etype row (v, u) is v's out-slot u (out-table); both hold the
 //     etype's magnitude
-//   * vertices relabeled so each degree bucket is contiguous (new id =
-//     rank in (bucket_D, old_id) order)
+//   * vertices relabeled so each degree bucket is contiguous and,
+//     inside it, stands in descending order of in-degree (new id =
+//     rank in (bucket_D, -in-degree, old_id) order): the rows a pull
+//     has to gather at a column are a prefix of the bucket
 //   * bucket width D = clamp(next_pow2(min(deg, cap)), min_d, cap),
 //     deg = max(in-degree, out-degree)
 //   * hub vertices (deg > cap) get extra rows appended after all real
@@ -116,7 +118,8 @@ int64_t ell_build(const int32_t* src, const int32_t* dst,
   for (int64_t v = 0; v < n; v++)
     deg[v] = std::max(deg_side[0][v], deg_side[1][v]);
 
-  // bucket width per vertex + relabeling (stable sort by D, old id)
+  // bucket width per vertex + relabeling (stable sort by D, then the
+  // fullest in-row first, ties by old id)
   std::vector<int64_t> D_v(n);
   for (int64_t v = 0; v < n; v++) {
     int64_t per_row = std::min(deg[v], cap);
@@ -124,8 +127,12 @@ int64_t ell_build(const int32_t* src, const int32_t* dst,
   }
   std::vector<int32_t> vorder(n);
   std::iota(vorder.begin(), vorder.end(), 0);
+  const std::vector<int64_t>& in_deg = deg_side[0];
   std::stable_sort(vorder.begin(), vorder.end(),
-                   [&](int32_t a, int32_t b) { return D_v[a] < D_v[b]; });
+                   [&](int32_t a, int32_t b) {
+                     return D_v[a] != D_v[b] ? D_v[a] < D_v[b]
+                                             : in_deg[a] > in_deg[b];
+                   });
   r->inv.assign(vorder.begin(), vorder.end());
   r->perm.resize(n);
   for (int64_t i = 0; i < n; i++) r->perm[vorder[i]] = int32_t(i);

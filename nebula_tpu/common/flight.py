@@ -144,8 +144,11 @@ class FlightRecorder:
         k-hop neighbourhoods, reduce "distinct"),
         the hops whose branch
         the tick learned (hop_reads; of them hop_sparse pushed out of
-        the live slot rows; hop_slots the ELL slots they visited;
-        hop_onesided those that read one direction's table only),
+        the live slot rows; hop_slots the ELL slots they visited, a
+        pull the whole of the table it read; hop_swept the slots they
+        gathered, a pull what its reach leaves of the table:
+        ell.swept_slots; hop_onesided those that read one direction's
+        table only),
         idle gap since the previous tick, mirror generation, tick wall
         micros."""
         rec = {"kind": "tick", "stream": int(stream)}

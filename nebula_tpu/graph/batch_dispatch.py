@@ -1104,7 +1104,7 @@ class _ContinuousStream:
             # session has read since the last record: the fetch reads
             # it where it has just waited (tpu/runtime.py _LaneFetch),
             # this call takes in what else is ready.  Never a wait
-            hop_reads, hop_sparse, hop_slots, hop_onesided = \
+            hop_reads, hop_sparse, hop_slots, hop_onesided, hop_swept = \
                 sess.hop_reads()
             # per cohort: start, (count end,) fetch_wait end, d2h end,
             # unpack end, rows end, handover end
@@ -1138,6 +1138,7 @@ class _ContinuousStream:
                 handed=sum(f[1] for f in finishes),
                 hop_reads=hop_reads, hop_sparse=hop_sparse,
                 hop_slots=hop_slots, hop_onesided=hop_onesided,
+                hop_swept=hop_swept,
                 idle_us=int(idle_us),
                 dur_us=dur_us,
                 generation=int(getattr(getattr(sess, "m", None),

@@ -22,11 +22,18 @@ and the workers are TPU lanes.
 Structure built host-side from the CsrMirror (build_ell):
 
   * vertices are **relabeled** so that all vertices of one degree
-    bucket are contiguous (new id = rank in (bucket_D, old_id) order);
-    bucket outputs then concatenate into the next frontier with zero
-    data movement.  A vertex's bucket is the power of two over the
-    LARGER of its in- and out-degree (floored at ``min_d``, capped at
-    ``cap``).
+    bucket are contiguous, and inside a bucket stand in DESCENDING
+    order of in-degree (new id = rank in (bucket_D, -in-degree, old_id)
+    order); bucket outputs then concatenate into the next frontier
+    with zero data movement.  A vertex's bucket is the power of two
+    over the LARGER of its in- and out-degree (floored at ``min_d``,
+    capped at ``cap``), so a row's in-slots fill a prefix of its
+    columns that shrinks down the bucket: the rows that hold a real
+    slot at column c or later are a PREFIX of the bucket's rows.  The
+    index says how long, per table, bucket and column range
+    (``reach``, pull_reach), and a pull's loop over a column range
+    gathers that prefix only (_bucket_expand_packed).  Nothing outside
+    this module may depend on the order of ids inside a bucket.
   * the slots live in TWO tables over that one row layout, one per
     stored direction (the mirror stores a reverse edge under -etype,
     csr.py), so a step reads only the direction it asks for:
@@ -80,13 +87,105 @@ def _etype_dtype(edge_etype: np.ndarray) -> np.dtype:
     return np.dtype(np.int32)
 
 
+# A pull's loop over a bucket is cut into this many equal column
+# ranges, each gathering only the leading rows that hold a real slot
+# there (EllIndex.reach, _bucket_expand_packed).  More ranges skip more
+# padding and cost a loop with a gather each: 0.3-0.4 MB of program,
+# which is device memory while it is loaded, and a turn of a loop has
+# a floor of ~5 us whatever it gathers, so the narrow prefixes of the
+# last ranges stop paying.  jit_hop alone on the v5e, every row live,
+# over graph500-s20's in-table of 24,835,040 slots (PERF.md section 6,
+# PR 39; slots gathered, ms a hop, program code, compile):
+#   whole  24.84 M  69.6 ms  10.4 MB  6.4 s
+#   1      24.04 M  67.5     10.5     6.5
+#   2      22.06 M  61.9     12.2     6.3
+#   4      20.18 M  57.3     14.5     5.8
+#   8      18.99 M  55.2     18.6     8.1
+#   16     18.62 M  54.3     23.5     9.9
+# 2.8 ns a gathered slot up to 4 ranges, 2.9 from 8 on.  From 4 to 8
+# the hop gains 2.1 ms (3.6 %) and each of the three programs that
+# pull (jit_hop, jit_bfs, the windowed GO: a GO cell holds two of them
+# loaded) takes 4.2 MB more, where device_bytes_per_edge's whole bound
+# is 8 MB on that graph, and the windowed GO's scratch + code comes out
+# ABOVE the whole sweep's (tests/test_hop_compile_tpu.py).  A speed
+# choice only: every cut gives the same bits.
+PULL_COLUMN_RANGES = 4
+
+# A reach is rounded up to this many rows, so that a few absorbed
+# edges do not move it: it is a static shape of every program that
+# pulls (shape_sig), and a reach that moves is a compile.
+PULL_REACH_STEP = 1024
+
+
+def _range_bounds(D: int, ranges: int) -> List[int]:
+    """Column bounds of a D-wide bucket's ranges: ``min(ranges, D)``
+    equal ones (D and ``ranges`` powers of two; floor division keeps
+    the bounds strictly rising for any other pair)."""
+    R = max(1, min(int(ranges), D))
+    return [r * D // R for r in range(R + 1)]
+
+
+def _main_rows(n: int, nbrs) -> List[int]:
+    """Main rows (global row < n: a vertex's own row) of each bucket of
+    a table; the rest of the last bucket are hub extra rows and growth
+    spares."""
+    out, b0 = [], 0
+    for nbr in nbrs:
+        out.append(min(max(n - b0, 0), nbr.shape[0]))
+        b0 += nbr.shape[0]
+    return out
+
+
+def _bucket_reach(nbr: np.ndarray, n_main: int, sentinel: int,
+                  ranges: int, step: int) -> Tuple[int, ...]:
+    """Per column range [c, c') of one bucket of one table: the number
+    of leading main rows one of which holds a real slot at a column
+    >= c, rounded up to whole ``step``s, one at the least (the first
+    edge into a range nothing reached moves no shape either, and a
+    bucket of a step's rows or fewer sweeps whole: a small graph's
+    programs are the ones it had), and capped at ``n_main``.  Read off
+    the slots (``nbr != sentinel``), so it holds for any content: every
+    slot at a column >= c of a main row past the prefix is padding."""
+    D = nbr.shape[1]
+    real = nbr[:n_main] != np.int32(sentinel)
+    # a row's last real column, -1 where it has none
+    last = np.where(real.any(axis=1),
+                    D - 1 - np.argmax(real[:, ::-1], axis=1), -1)
+    out = []
+    for c in _range_bounds(D, ranges)[:-1]:
+        rows = np.flatnonzero(last >= c)
+        reach = int(rows[-1]) + 1 if len(rows) else 0
+        out.append(min(max(-(-reach // step), 1) * step, n_main))
+    return tuple(out)
+
+
+def pull_reach(ell: "EllIndex", ranges: Optional[int] = None,
+               step: Optional[int] = None, tables=None) -> Tuple:
+    """EllIndex.reach as the slot arrays say it: (in-table, out-table),
+    each a tuple over the buckets of _bucket_reach's tuple over
+    ``ranges`` column ranges (PULL_COLUMN_RANGES, PULL_REACH_STEP
+    unless a test passes its own).  ``tables`` = the tables_host()
+    indices to read anew; the others keep ``ell.reach``'s entry (an
+    absorb rewrites a few buckets and shares the rest)."""
+    ranges = PULL_COLUMN_RANGES if ranges is None else ranges
+    step = PULL_REACH_STEP if step is None else step
+    nb = len(ell.bucket_nbr)
+    mains = _main_rows(ell.n, ell.bucket_nbr)
+    return tuple(
+        tuple(_bucket_reach(nbr, mains[b], ell.n_rows, ranges, step)
+              if tables is None or side * nb + b in tables
+              else ell.reach[side][b]
+              for b, nbr in enumerate(nbrs))
+        for side, nbrs in enumerate((ell.bucket_nbr, ell.out_nbr)))
+
+
 class EllIndex:
     """Degree-bucketed slot tables, one per stored direction, over one
     relabeling of dense vertex ids."""
 
     __slots__ = ("n", "m", "perm", "inv", "bucket_D", "bucket_nbr",
                  "bucket_et", "out_nbr", "out_et", "extra_owner",
-                 "n_rows", "_device", "_n_hubs")
+                 "n_rows", "reach", "_device", "_n_hubs")
 
     def __init__(self):
         self.n = 0                     # real vertices
@@ -102,6 +201,10 @@ class EllIndex:
         self.out_et: List[np.ndarray] = []
         self.extra_owner = np.zeros(0, np.int32)  # hub extra row -> new id
         self.n_rows = 0                # n + len(extra_owner)
+        # (in-table, out-table) x bucket x column range: the leading
+        # main rows a pull has to gather there (pull_reach); None = a
+        # pull sweeps every row at every column
+        self.reach: Optional[Tuple] = None
         self._device = None            # lazy jnp copies of bucket arrays
         self._n_hubs = None            # lazy count of distinct hub owners
 
@@ -146,7 +249,7 @@ class EllIndex:
         ell.m = m
         if n == 0:
             ell.n_rows = 0
-            return ell
+            return _append_growth_spares(ell, 0)
 
         # rows are grouped by DST, one stable sort for both tables: a
         # +etype row is the owner's in-slot, a -etype row its out-slot
@@ -165,7 +268,8 @@ class EllIndex:
         cap = max(cap, min_d)
         per_row = np.minimum(deg, cap)
         D_v = np.clip(_next_pow2(per_row), min_d, cap)
-        vorder = np.lexsort((np.arange(n), D_v))         # stable by bucket
+        # by bucket, inside it the fullest in-row first, ties by old id
+        vorder = np.lexsort((np.arange(n), -sides[0][3], D_v))
         perm = np.empty(n, np.int32)
         perm[vorder] = np.arange(n, dtype=np.int32)
         ell.perm = perm
@@ -334,7 +438,7 @@ class EllIndex:
         return (self.n, self.n_rows, len(self.extra_owner), self.n_hubs,
                 tuple((nbr.shape[0], nbr.shape[1])
                       for nbr in self.bucket_nbr),
-                self.et_dtype.name)
+                self.reach, self.et_dtype.name)
 
     @property
     def et_dtype(self) -> np.dtype:
@@ -569,47 +673,137 @@ def _scatter_or_rows(jnp, nxt, vals, slot, rows):
     return nxt.at[rows].set(upd, mode="drop")
 
 
-def _bucket_expand_packed(jnp, jax, fp, nbr, et, mags):
+def _pull_segments(D: int, n_main: int, reach):
+    """What a bucket's pull loops over its main rows, as
+    [(rows, c0, c1)]: the leading ``rows`` rows over the columns
+    [c0, c1), the LAST columns first, so the rows only grow down the
+    list.  Neighbouring ranges of one reach are one entry, a range no
+    row reaches is none.  None where there is nothing to save: no reach
+    was given, or every range reaches every main row, and the bucket
+    takes one loop over all its rows."""
+    if reach is None or all(r >= n_main for r in reach):
+        return None
+    bounds = _range_bounds(D, len(reach))
+    segs: List[Tuple[int, int, int]] = []
+    for r in reversed(range(len(reach))):
+        # a reach never grows with the column by construction; hold it
+        # to that whatever was handed in
+        rows = max(min(reach[r], n_main), segs[-1][0] if segs else 0)
+        if rows == 0:
+            continue
+        if segs and segs[-1][0] == rows:
+            segs[-1] = (rows, bounds[r], segs[-1][2])
+        else:
+            segs.append((rows, bounds[r], bounds[r + 1]))
+    return segs
+
+
+def _bucket_swept(nb: int, D: int, n_main: int, reach) -> int:
+    """Slots _bucket_expand_packed gathers in a bucket of ``nb`` rows:
+    its segments' rows x columns and the rows past ``n_main`` whole."""
+    segs = _pull_segments(D, n_main, reach)
+    if segs is None:
+        return nb * D
+    return sum(rows * (c1 - c0) for rows, c0, c1 in segs) \
+        + (nb - n_main) * D
+
+
+def _bucket_expand_packed(jnp, jax, fp, nbr, et, mags, reach=None,
+                          n_main: int = 0):
     """Expand one bucket of one table: OR over D slot word gathers, the
     OVER mask a 0/1 uint8 multiply per word.  THE hop inner loop —
     shared by the single-chip and sharded kernels so their semantics
-    cannot skew."""
+    cannot skew.
+
+    ``reach`` (EllIndex.reach's entry for this table and bucket, with
+    ``n_main`` the bucket's rows < n) cuts the loop by column range:
+    a range gathers the leading rows that hold a real slot there and
+    no others, since every slot it skips names the pad row, which is
+    zero.  The ranges run from the last to the first over ONE
+    accumulator that grows with the reach (_pull_segments), so they
+    share the bucket's D turns and no buffer spans the bucket before
+    the first range needs it.  The rows past ``n_main`` (hub extra rows
+    and growth spares, which stand in no order) take every column in a
+    loop of their own.  ``None``, or a reach that saves nothing, is one
+    loop over every row and every column."""
     nb, D = nbr.shape
     nbr_T = nbr.T
     ok_T = _etype_ok(jnp, et, mags).T.astype(jnp.uint8)
+    W = fp.shape[1]
+    segs = _pull_segments(D, n_main, reach)
 
-    def body(j, acc):
-        g = fp[nbr_T[j]]                   # [nb, W] word-gather
-        return acc | (g * ok_T[j][:, None])
+    if segs is None:
+        def body(j, acc):
+            g = fp[nbr_T[j]]                   # [nb, W] word-gather
+            return acc | (g * ok_T[j][:, None])
 
-    acc0 = jnp.zeros((nb, fp.shape[1]), dtype=jnp.uint8)
-    return jax.lax.fori_loop(0, D, body, acc0)
+        acc0 = jnp.zeros((nb, W), dtype=jnp.uint8)
+        return jax.lax.fori_loop(0, D, body, acc0)
+
+    def sweep(acc, lo, c0, c1):
+        """OR into ``acc`` the columns [c0, c1) of the rows
+        [lo, lo + acc's rows)."""
+        rows = acc.shape[0]
+
+        def body(j, acc):
+            idx = jax.lax.dynamic_slice(nbr_T, (j, lo), (1, rows))[0]
+            ok = jax.lax.dynamic_slice(ok_T, (j, lo), (1, rows))[0]
+            return acc | (fp[idx] * ok[:, None])
+
+        return jax.lax.fori_loop(c0, c1, body, acc)
+
+    acc = jnp.zeros((0, W), dtype=jnp.uint8)
+    for rows, c0, c1 in segs:
+        acc = sweep(jnp.pad(acc, ((0, rows - acc.shape[0]), (0, 0))),
+                    0, c0, c1)
+    acc = jnp.pad(acc, ((0, n_main - acc.shape[0]), (0, 0)))
+    if nb == n_main:
+        return acc
+    tail = sweep(jnp.zeros((nb - n_main, W), dtype=jnp.uint8), n_main,
+                 0, D)
+    return jnp.concatenate([acc, tail], axis=0)
 
 
-def _buckets_expand_packed(jnp, jax, fp, sides):
+def _buckets_expand_packed(jnp, jax, fp, sides, n: int = 0,
+                           reaches=None):
     """Per bucket, the OR of its expansion over each table in ``sides``
-    (_read_sides: one table for a one-signed OVER set).  Each bucket
+    (_read_sides: one table for a one-signed OVER set), each cut by
+    its own entry of ``reaches`` (_side_reaches, ``n`` the real
+    vertices; None: every row at every column).  Each bucket
     sits in a named scope, so a device trace's op names say which
     bucket a loop or fusion is (``hop/bucket_w<D>`` in the HLO
     op_name)."""
     outs = []
-    for b in range(len(sides[0][0]) if sides else 0):
+    mains = _main_rows(n, sides[0][0]) if sides else []
+    for b in range(len(mains)):
         with jax.named_scope(f"hop/bucket_w{sides[0][0][b].shape[1]}"):
             acc = None
-            for nbrs, ets, mags in sides:
-                o = _bucket_expand_packed(jnp, jax, fp, nbrs[b], ets[b],
-                                          mags)
+            for k, (nbrs, ets, mags) in enumerate(sides):
+                o = _bucket_expand_packed(
+                    jnp, jax, fp, nbrs[b], ets[b], mags,
+                    reaches[k][b] if reaches is not None else None,
+                    mains[b])
                 acc = o if acc is None else acc | o
             outs.append(acc)
     return outs
 
 
+def _side_reaches(ell, etypes: Tuple[int, ...]):
+    """``ell.reach`` for the tables a PULL over ``etypes`` reads, in
+    _read_sides' order; None where the index carries none."""
+    if ell.reach is None:
+        return None
+    return [reach for reach, mags in zip(ell.reach, _split_signs(etypes))
+            if mags]
+
+
 def _hop_body_packed(jnp, jax, n: int, n_extras: int, sides,
-                     eslot, hrows, fp):
+                     eslot, hrows, fp, reaches=None):
     """One packed frontier advance by PULL: fp [n_rows+1, W] uint8 ->
-    same, over the tables in ``sides`` (_read_sides).  The hub merge
-    sits in a named scope of its own (``hop/hub_merge``)."""
-    outs = _buckets_expand_packed(jnp, jax, fp, sides)
+    same, over the tables in ``sides`` (_read_sides), each bucket's
+    loop cut by ``reaches`` (_side_reaches; None sweeps whole).  The
+    hub merge sits in a named scope of its own (``hop/hub_merge``)."""
+    outs = _buckets_expand_packed(jnp, jax, fp, sides, n, reaches)
     if not outs:
         return jnp.zeros_like(fp)
     nxt = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
@@ -649,13 +843,14 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
     import jax
     import jax.numpy as jnp
     n, n_extras, nb = ell.n, len(ell.extra_owner), len(ell.bucket_nbr)
+    reaches = _side_reaches(ell, etypes)
 
     def advance(f0p, eslot, hrows, tables):
         sides = _read_sides(etypes, tables, nb)
 
         def one(_, f):
             return _hop_body_packed(jnp, jax, n, n_extras, sides,
-                                    eslot, hrows, f)
+                                    eslot, hrows, f, reaches)
 
         def one_acc(_, carry):
             f, acc = carry
@@ -761,11 +956,14 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 # which the TPU runs one index after the other: 48.5 ns a slot on the
 # v5e (24.8 us a 512-wide row; 1.5 us an 8-wide one, where the row's
 # own turn of the loop is most of it) beside 2.2 ms fixed, against the
-# pull's 2.76 ns a slot over EVERY slot of the one table it reads
-# (68.5 ms at 24.8 M).  The budget is where the WORST push, every
-# live row 512 wide, still undercuts the sweep: 2.2 ms + R x 24.8 us
-# < 68.5 ms holds to R = 2,670, and 2,048 rows (1.05 M slots, 53 ms)
-# is the power of two under it.  While a row held both directions the
+# pull's 2.8 ns a slot over every slot its reach leaves of the one
+# table it reads (57.3 ms at 20.18 M of 24.84 M since PR 39; 69.6 ms
+# for the whole table).  The budget is where the WORST push, every
+# live row 512 wide, still undercuts the sweep: when it was set (PR
+# 35) 2.2 ms + R x 24.8 us < 68.5 ms held to R = 2,670, and 2,048 rows
+# (1.05 M slots, 53 ms) is the power of two under it; against PR 39's
+# 57.3 ms the same rule gives R = 2,220, so 2,048 stands, with 4 ms of
+# room where it had 15.  While a row held both directions the
 # same rule gave 4,096: a sweep of 113.4 ms at 42.2 M slots, a push of
 # 2.5 ms + 49.7 ns a slot, R < 4,360 (PERF.md §6, PR 35's chip runs;
 # PR 25 first read 114 ms and 47 ns).  A speed choice only: both
@@ -777,10 +975,27 @@ HOP_INFO_SPARSE, HOP_INFO_ROWS, HOP_INFO_SLOTS = 0, 1, 2
 
 
 def table_slots(ell: EllIndex, etypes: Tuple[int, ...]) -> int:
-    """Slots one pull over ``etypes`` visits: every slot of each table
-    it reads (the two tables share their shapes)."""
+    """Slots of the table(s) one pull over ``etypes`` reads (the two
+    tables share their shapes): what a pull REPORTS
+    (info[HOP_INFO_SLOTS], the bytes models of benchmark/ count the
+    table), and what it gathers where the index carries no reach."""
     return sides_read(etypes) * int(
         sum(nbr.shape[0] * nbr.shape[1] for nbr in ell.bucket_nbr))
+
+
+def swept_slots(ell: EllIndex, etypes: Tuple[int, ...]) -> int:
+    """Slots one pull over ``etypes`` GATHERS: per table it reads and
+    bucket, the reach prefixes times their column ranges and the rows
+    past n whole (_bucket_swept) — table_slots less the padding the
+    reach lets the loops skip.  Static, as the loops are."""
+    reaches = _side_reaches(ell, etypes)
+    if reaches is None:
+        return table_slots(ell, etypes)
+    mains = _main_rows(ell.n, ell.bucket_nbr)
+    return int(sum(
+        _bucket_swept(nbr.shape[0], nbr.shape[1], n_main, reach)
+        for side in reaches
+        for nbr, n_main, reach in zip(ell.bucket_nbr, mains, side)))
 
 
 def _set_positions(jnp, mask, cap: int, group: int = 128):
@@ -900,6 +1115,7 @@ def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
     n, n_rows = ell.n, ell.n_rows
     n_extras, nb = len(ell.extra_owner), len(ell.bucket_nbr)
     n_sides = sides_read(etypes)
+    reaches = _side_reaches(ell, etypes)
     if push_rows is None:
         push_rows = HOP_PUSH_ROWS
 
@@ -907,7 +1123,7 @@ def _make_frontier_step(ell: EllIndex, etypes: Tuple[int, ...],
         def pull(fp):
             return _hop_body_packed(jnp, jax, n, n_extras,
                                     _read_sides(etypes, tables, nb),
-                                    eslot, hrows, fp)
+                                    eslot, hrows, fp, reaches)
 
         if not nb or not n_sides:      # empty graph or OVER set
             return pull(fp), jnp.bool_(False), jnp.int32(0), jnp.int32(0)
@@ -1181,24 +1397,25 @@ def _append_growth_spares(ell: EllIndex, slack: int) -> EllIndex:
     Every pre-spare sentinel slot is re-pointed at the NEW pad row
     (the slot sentinel is n_rows by contract, and n_rows just grew);
     the tables are freshly built and unshared, so the rewrite is
-    safe in place."""
-    if slack <= 0 or ell.n == 0 or not ell.bucket_nbr:
-        return ell
-    old_sent = np.int32(ell.n_rows)
-    new_sent = np.int32(ell.n_rows + int(slack))
-    D = int(ell.bucket_nbr[-1].shape[1])
-    for nbrs, ets in ((ell.bucket_nbr, ell.bucket_et),
-                      (ell.out_nbr, ell.out_et)):
-        for nbr in nbrs:
-            nbr[nbr == old_sent] = new_sent
-        nbrs[-1] = np.vstack(
-            [nbrs[-1], np.full((int(slack), D), new_sent, np.int32)])
-        ets[-1] = np.vstack(
-            [ets[-1], np.zeros((int(slack), D), ets[-1].dtype)])
-    ell.extra_owner = np.concatenate(
-        [ell.extra_owner,
-         np.full(int(slack), new_sent, np.int32)]).astype(np.int32)
-    ell.n_rows = int(new_sent)
+    safe in place.  The index's reach is read here, off the finished
+    tables: the sentinel it tells padding by moves with the spares."""
+    if slack > 0 and ell.n and ell.bucket_nbr:
+        old_sent = np.int32(ell.n_rows)
+        new_sent = np.int32(ell.n_rows + int(slack))
+        D = int(ell.bucket_nbr[-1].shape[1])
+        for nbrs, ets in ((ell.bucket_nbr, ell.bucket_et),
+                          (ell.out_nbr, ell.out_et)):
+            for nbr in nbrs:
+                nbr[nbr == old_sent] = new_sent
+            nbrs[-1] = np.vstack(
+                [nbrs[-1], np.full((int(slack), D), new_sent, np.int32)])
+            ets[-1] = np.vstack(
+                [ets[-1], np.zeros((int(slack), D), ets[-1].dtype)])
+        ell.extra_owner = np.concatenate(
+            [ell.extra_owner,
+             np.full(int(slack), new_sent, np.int32)]).astype(np.int32)
+        ell.n_rows = int(new_sent)
+    ell.reach = pull_reach(ell)
     return ell
 
 
@@ -1381,6 +1598,11 @@ def apply_ell_absorb_host(ell: EllIndex, plan, m_new: int,
     out.bucket_et = [et for _, et in tables[:nb]]
     out.out_nbr = [nbr for nbr, _ in tables[nb:]]
     out.out_et = [et for _, et in tables[nb:]]
+    # the rewritten buckets' reach read anew off their slots (a row
+    # past a prefix may have gained an in-edge), the others' kept
+    out.reach = ell.reach
+    if ell.reach is not None:
+        out.reach = pull_reach(out, tables=set(plan))
     return out
 
 
@@ -1516,13 +1738,24 @@ def make_sharded_ell_absorb_kernel(mesh, axis: str, ell: EllIndex,
 BFS_INFO_LEVELS, BFS_INFO_PUSHED, BFS_INFO_PUSH_SLOTS = 0, 1, 2
 
 
+def _bfs_levels_slots(info, pull_slots: int) -> int:
+    """The pushed levels' slots plus ``pull_slots`` a pulled level."""
+    pulled = int(info[BFS_INFO_LEVELS]) - int(info[BFS_INFO_PUSHED])
+    return int(info[BFS_INFO_PUSH_SLOTS]) + pulled * pull_slots
+
+
 def bfs_slots(ell: EllIndex, etypes: Tuple[int, ...], info) -> int:
     """ELL slots the levels of one BFS dispatch visited: a pushed
     level its live slot rows' widths, a pulled one every slot of the
     table(s) ``etypes`` reads."""
-    pulled = int(info[BFS_INFO_LEVELS]) - int(info[BFS_INFO_PUSHED])
-    return int(info[BFS_INFO_PUSH_SLOTS]) \
-        + pulled * table_slots(ell, etypes)
+    return _bfs_levels_slots(info, table_slots(ell, etypes))
+
+
+def bfs_swept(ell: EllIndex, etypes: Tuple[int, ...], info) -> int:
+    """ELL slots the levels of one BFS dispatch GATHERED: bfs_slots
+    with a pulled level at swept_slots(ell, etypes), what its loops
+    gather, and not at the table's slots, what it reports."""
+    return _bfs_levels_slots(info, swept_slots(ell, etypes))
 
 
 def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,

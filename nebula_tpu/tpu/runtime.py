@@ -706,9 +706,11 @@ class TpuQueryRuntime:
                       # every slot of the table(s) read; and the hops
                       # (BFS levels too) that read one direction's
                       # table only: all of them unless an OVER set has
-                      # both signs
+                      # both signs; and the ELL slots hops and BFS
+                      # levels gathered (a pull's are ell.swept_slots)
                       "hop_sparse": 0, "hop_dense": 0,
                       "hop_onesided": 0,
+                      "hop_swept_slots": 0,
                       "prewarm_compiled": 0, "prewarm_hits": 0,
                       "prewarm_misses": 0, "prewarm_failed": 0,
                       "t_launch_s": 0.0, "t_fetch_s": 0.0,
@@ -1057,7 +1059,8 @@ class TpuQueryRuntime:
         self.breaker.reset_space(space_id)
         # NOTE: cached kernels are keyed by TABLE SHAPES and take the
         # tables as arguments (ell.py), so they survive rebuilds AND
-        # absorptions (shape_sig is generation-invariant); only the
+        # absorptions (shape_sig moves with a generation only where a
+        # spare is claimed or a pull's reach crosses its step); only the
         # fused-filter kernels bake mirror-specific constants and
         # carry build_version in their keys.
         self._kernels = {k: v for k, v in self._kernels.items()
@@ -3682,7 +3685,7 @@ class TpuQueryRuntime:
         out of their live rows, the slots they visited in all, and the
         lanes used."""
         from .ell import (BFS_INFO_LEVELS, BFS_INFO_PUSHED, INT16_INF,
-                          bfs_slots, dense_hop_bytes, lanes_width,
+                          bfs_slots, bfs_swept, dense_hop_bytes, lanes_width,
                           make_batched_bfs_lanes_kernel,
                           make_sharded_batched_bfs_kernel, sides_read)
         import time
@@ -3771,10 +3774,12 @@ class TpuQueryRuntime:
             # them, or (a mixed-sign OVER set) none
             onesided = levels if sides_read(et_tuple) == 1 else 0
             self._bump("hop_onesided", onesided)
+            swept = bfs_swept(ix, et_tuple, info)
+            self._bump("hop_swept_slots", swept)
             _flight.recorder.note_dispatch(
                 "ell_bfs", rung=B, steps=max_steps, levels=levels,
                 levels_push=levels_push, hop_onesided=onesided,
-                slots=bfs_slots(ix, et_tuple, info),
+                slots=bfs_slots(ix, et_tuple, info), swept=swept,
                 queries=nq, **stages)
         if host.dtype == np.int8:        # in-kernel compression (-1=INF)
             d = np.where(host < 0, INT16_INF, host).astype(np.int16)
@@ -4042,7 +4047,7 @@ class _ContinuousGoSession:
     def __init__(self, rt, space_id: int, m: CsrMirror, ix: EllIndex,
                  et_tuple: Tuple[int, ...], B: int):
         import jax.numpy as jnp
-        from .ell import lanes_width, sides_read
+        from .ell import lanes_width, sides_read, swept_slots
         self.rt = rt
         self.space_id = space_id
         self.m = m
@@ -4050,6 +4055,9 @@ class _ContinuousGoSession:
         self.et_tuple = et_tuple
         # every hop of this stream reads one direction's table only
         self._onesided = sides_read(et_tuple) == 1
+        # the slots a pull of this stream gathers (a pull REPORTS the
+        # table's; the index's reach lets its loops skip padding)
+        self._pull_swept = swept_slots(ix, et_tuple)
         self.B = B                          # lane count (width rung)
         self.W = lanes_width(B)
         self._tables = ix.kernel_args()[1:]  # mirror-resident buckets
@@ -4063,7 +4071,7 @@ class _ContinuousGoSession:
         # first (hop_reads); bounded, so a caller that never reads
         # them forgets the oldest
         self._hop_info: collections.deque = collections.deque(maxlen=64)
-        self._hop_read = [0, 0, 0, 0]   # read, not yet in a tick record
+        self._hop_read = [0, 0, 0, 0, 0]  # read, not yet in a tick record
         # perf_counter marks of the last join: its map loop's end and
         # its pack's end (join)
         self.join_marks = None
@@ -4144,29 +4152,35 @@ class _ContinuousGoSession:
         Never waits.  _LaneFetch calls this where the pump has just
         waited on an extract queued behind those hops."""
         from .ell import HOP_INFO_SLOTS, HOP_INFO_SPARSE
-        reads = sparse = 0
+        reads = sparse = swept = 0
         while self._hop_info and self._hop_info[0].is_ready():
             info = np.asarray(self._hop_info.popleft())
             reads += 1
-            sparse += int(info[HOP_INFO_SPARSE])
-            self._hop_read[2] += int(info[HOP_INFO_SLOTS])
+            pushed, slots = int(info[HOP_INFO_SPARSE]), \
+                int(info[HOP_INFO_SLOTS])
+            sparse += pushed
+            self._hop_read[2] += slots
+            # a push gathered the slots it reports, a pull its reach
+            swept += slots if pushed else self._pull_swept
         if reads:
             onesided = reads if self._onesided else 0
             self._hop_read[0] += reads
             self._hop_read[1] += sparse
             self._hop_read[3] += onesided
+            self._hop_read[4] += swept
             self.rt._bump("hop_sparse", sparse)
             self.rt._bump("hop_dense", reads - sparse)
             self.rt._bump("hop_onesided", onesided)
+            self.rt._bump("hop_swept_slots", swept)
 
-    def hop_reads(self) -> Tuple[int, int, int, int]:
+    def hop_reads(self) -> Tuple[int, int, int, int, int]:
         """(hops read, of them pushed, ELL slots they visited, hops
-        that read one direction's table only) since the last call —
-        the tick record's hop_reads / hop_sparse / hop_slots /
-        hop_onesided."""
+        that read one direction's table only, ELL slots they gathered)
+        since the last call — the tick record's hop_reads / hop_sparse
+        / hop_slots / hop_onesided / hop_swept."""
         self.read_hop_info()
         out = tuple(self._hop_read)
-        self._hop_read = [0, 0, 0, 0]
+        self._hop_read = [0, 0, 0, 0, 0]
         return out
 
     def _extract_kernel(self):

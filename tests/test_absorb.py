@@ -397,3 +397,96 @@ class TestOverflowObservability:
         finally:
             flags.set("mirror_absorb", saved)
             c.stop()
+
+
+class TestReachAfterAbsorb:
+    """PR 39: a pull gathers, per column range, only the leading rows
+    of a bucket that hold a real slot there (EllIndex.reach).  An
+    absorb that hands a row PAST such a prefix a new in-edge hands out
+    an index whose reach covers it: read anew off the rewritten
+    buckets' slots, never patched."""
+
+    @pytest.mark.parametrize("kind", ["in_place", "claimed_spare"])
+    def test_reach_covers_a_row_past_a_prefix(self, kind, monkeypatch):
+        import jax.numpy as jnp
+        from nebula_tpu.tpu import ell as E
+        monkeypatch.setattr(E, "PULL_COLUMN_RANGES", 4)
+        monkeypatch.setattr(E, "PULL_REACH_STEP", 4)
+        rng = np.random.default_rng(39)
+        n, m = 300, 5000
+        dst = (rng.zipf(1.5, m) % n).astype(np.int32)
+        src = rng.integers(0, n, m).astype(np.int32)
+        _, first = np.unique(src.astype(np.int64) * n + dst,
+                             return_index=True)
+        src, dst = src[first], dst[first]
+
+        def build(s, d):
+            one = np.ones(len(s), np.int32)
+            return E.EllIndex.build(
+                np.concatenate([s, d]), np.concatenate([d, s]),
+                np.concatenate([one, -one]), n, cap=16, min_d=2,
+                growth_slack=3)
+
+        ix = build(src, dst)
+        # the last main row of the widest bucket: the fewest in-edges
+        # of its bucket, past the prefix of every range but the first
+        nbr = ix.bucket_nbr[-1]
+        n_main = E._main_rows(ix.n, ix.bucket_nbr)[-1]
+        D = nbr.shape[1]
+        b0 = ix.n - n_main
+        row = n_main - 1
+        fill = int((nbr[row] != ix.n_rows).sum())
+        reach = ix.reach[0][-1]
+        assert fill <= D // 4 and all(r <= row for r in reach[1:])
+        v = int(ix.inv[b0 + row])
+        have = set(src[dst == v].tolist()) | {v}
+        k = (D - fill - 1) if kind == "in_place" else (D - fill + 5)
+        new_src = np.asarray([u for u in range(n) if u not in have][:k],
+                             np.int32)
+        new_dst = np.full(k, v, np.int32)
+        # the mirror's form: (dst, src, etype) rows, both directions
+        ins = (np.concatenate([new_dst, new_src]),
+               np.concatenate([new_src, new_dst]),
+               np.concatenate([np.ones(k, np.int32),
+                               -np.ones(k, np.int32)]))
+        none = np.zeros(0, np.int32)
+        claims = []
+        plan = E.plan_ell_absorb(ix, *ins, none, none, none,
+                                 claims_out=claims)
+        assert plan is not None
+        assert bool(claims) == (kind == "claimed_spare")
+        ix2 = E.apply_ell_absorb_host(ix, plan, ix.m + 2 * k,
+                                      claims=claims)
+        # the row now holds a real slot in the last range (in place) or
+        # in every column (its overflow went to the claimed spare)
+        got = ix2.reach[0][-1]
+        assert got != reach and all(r > row for r in got)
+        assert ix2.reach == E.pull_reach(ix2)
+        assert ix2.shape_sig() != ix.shape_sig()
+        nb = len(ix.bucket_nbr)
+        for side in (0, 1):
+            for b in range(nb):
+                if side * nb + b not in plan:
+                    assert ix2.reach[side][b] is ix.reach[side][b]
+        mains = E._main_rows(ix2.n, ix2.bucket_nbr)
+        for nbrs, side in zip((ix2.bucket_nbr, ix2.out_nbr), ix2.reach):
+            for t, nm, rb in zip(nbrs, mains, side):
+                for c, r in zip(E._range_bounds(t.shape[1], 4), rb):
+                    assert (t[r:nm, c:] == ix2.n_rows).all()
+        # a hop over the absorbed index is the rebuilt one's
+        ix3 = build(np.concatenate([src, new_src]),
+                    np.concatenate([dst, new_dst]))
+        starts = [np.asarray(s) for s in
+                  (new_src[:2], [v], rng.choice(n, 5), new_src[-1:])]
+        outs = []
+        for index in (ix2, ix3):
+            assert E.swept_slots(index, (1,)) < E.table_slots(index, (1,))
+            eslot, hrows = index.hub_merge()
+            f0 = index.start_frontier(starts, B=8)
+            kern = E.make_batched_go_lanes_kernel(index, 3, (1,))
+            out = kern(jnp.asarray(E.pack_lanes_host(f0)),
+                       jnp.asarray(eslot), jnp.asarray(hrows),
+                       *index.kernel_args()[1:])
+            outs.append(index.to_old(
+                E.unpack_lanes_host(np.asarray(out), 8)))
+        assert outs[0].any() and np.array_equal(outs[0], outs[1])

@@ -1299,3 +1299,252 @@ def test_absorb_declines_an_etype_the_column_cannot_hold():
     none = np.zeros(0, np.int32)
     assert E.plan_ell_absorb(ix, one(4), one(5), one(200),
                              none, none, none) is None
+
+
+# ============================================================
+# PR 39: a bucket's rows stand in descending order of in-degree, the
+# index carries per table, bucket and column range how many leading
+# main rows hold a real slot there (EllIndex.reach), and a pull's loop
+# over a column range gathers that prefix only.  A slot it skips names
+# the pad row, which is zero: the pull with the reach is the pull
+# without it, bit for bit, on every row a reader looks at.
+# ============================================================
+@pytest.fixture
+def fine_reach(monkeypatch):
+    """Set the module's ranges and step for one test: the shipped step
+    of 1,024 rows makes every bucket of a test graph sweep whole."""
+    def set_(ranges, step=4):
+        monkeypatch.setattr(E, "PULL_COLUMN_RANGES", ranges)
+        monkeypatch.setattr(E, "PULL_REACH_STEP", step)
+    return set_
+
+
+def _random_mirror(seed, n=400, m=6000):
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, m).astype(np.int32)
+    dst = (rng.integers(0, n, m) * rng.random(m) ** 2).astype(np.int32)
+    et = rng.choice(np.asarray([1, 2], np.int32), m)
+    return n, _mirror_edges(src, dst, et)
+
+
+def _skewed_mirror(seed=11):
+    _ix, edges = _hop_graph(seed, with_edges=True)
+    return 300, edges
+
+
+REACH_GRAPHS = {"random_a": lambda: _random_mirror(3, m=1800),
+                "random_b": lambda: _random_mirror(4, n=150, m=900),
+                "skewed": _skewed_mirror}
+
+
+def _brute_reach(nbr, n_main, sentinel, bounds):
+    """Leading main rows with a real slot at a column >= c, per range
+    start c, one row at a time; 0 where there is none."""
+    out = []
+    for c in bounds[:-1]:
+        reach = 0
+        for r in range(n_main):
+            if (nbr[r, c:] != sentinel).any():
+                reach = r + 1
+        out.append(reach)
+    return out
+
+
+@pytest.mark.parametrize("slack", [0, 3], ids=["no_spares", "spares"])
+@pytest.mark.parametrize("ranges", [1, 4, 8])
+@pytest.mark.parametrize("native", [False, True], ids=["numpy", "native"])
+@pytest.mark.parametrize("graph", sorted(REACH_GRAPHS))
+def test_every_slot_outside_a_reach_prefix_is_padding(graph, native, ranges,
+                                                      slack, fine_reach):
+    fine_reach(ranges, step=4)
+    n, edges = REACH_GRAPHS[graph]()
+    ix = E.EllIndex.build(*edges, n, cap=16, min_d=2, use_native=native,
+                          growth_slack=slack)
+    assert len(ix.bucket_nbr) >= 3 and len(ix.extra_owner) > slack
+    mains = E._main_rows(ix.n, ix.bucket_nbr)
+    assert sum(mains) == ix.n and mains[-1] < ix.bucket_nbr[-1].shape[0]
+    # inside a bucket the rows stand in descending order of in-degree,
+    # ties by old id
+    in_deg = np.bincount(edges[1][edges[2] > 0], minlength=n)[ix.inv]
+    b0 = 0
+    for n_main in mains:
+        d = in_deg[b0:b0 + n_main]
+        assert (np.diff(d) <= 0).all()
+        same = np.flatnonzero(np.diff(d) == 0)
+        assert (ix.inv[b0 + same] < ix.inv[b0 + same + 1]).all()
+        b0 += n_main
+    assert len(ix.reach) == 2
+    saved = 0
+    for nbrs, reach in zip((ix.bucket_nbr, ix.out_nbr), ix.reach):
+        assert len(reach) == len(nbrs)
+        for nbr, n_main, rb in zip(nbrs, mains, reach):
+            D = nbr.shape[1]
+            bounds = E._range_bounds(D, ranges)
+            assert len(rb) == len(bounds) - 1 == min(ranges, D)
+            assert list(rb) == sorted(rb, reverse=True)
+            want = _brute_reach(nbr, n_main, ix.n_rows, bounds)
+            for c, r, w in zip(bounds, rb, want):
+                assert (nbr[r:n_main, c:] == ix.n_rows).all()
+                # the least whole steps that hold it, one at the least
+                assert r == min(max(-(-w // 4), 1) * 4, n_main)
+            saved += sum(n_main - r for r in rb)
+    assert ranges == 1 or saved > 0
+    # read off the slots anew, the field is what the build left
+    assert E.pull_reach(ix) == ix.reach
+
+
+class _EagerLax:
+    """lax for a loop run in Python, one turn at a time."""
+
+    @staticmethod
+    def fori_loop(lo, hi, body, acc):
+        for j in range(lo, hi):
+            acc = body(j, acc)
+        return acc
+
+    @staticmethod
+    def dynamic_slice(a, start, size):
+        return a[start[0]:start[0] + size[0], start[1]:start[1] + size[1]]
+
+
+class _EagerJax:
+    lax = _EagerLax
+
+    @staticmethod
+    def named_scope(_name):
+        import contextlib
+        return contextlib.nullcontext()
+
+
+class _CountedFrontier:
+    """A frontier that counts the rows gathered from it."""
+
+    def __init__(self, fp):
+        self.fp, self.shape, self.gathered = fp, fp.shape, 0
+
+    def __getitem__(self, idx):
+        self.gathered += len(idx)
+        return self.fp[idx]
+
+
+def _reach_variants(ix, ranges):
+    """The index's own reach and one with the zeros a rounded reach
+    never holds: the exact prefixes, 0 for a range nothing reaches."""
+    mains = E._main_rows(ix.n, ix.bucket_nbr)
+    exact = tuple(
+        tuple(tuple(_brute_reach(nbr, n_main, ix.n_rows,
+                                 E._range_bounds(nbr.shape[1], ranges)))
+              for nbr, n_main in zip(nbrs, mains))
+        for nbrs in (ix.bucket_nbr, ix.out_nbr))
+    return {"rounded": ix.reach, "exact": exact}
+
+
+REACH_OVER = {"over_a": (1,), "over_a_b": (1, 2), "reversely": (-1,),
+              "reversely_both": (-1, -2), "mixed_sign": (2, -1)}
+
+
+@pytest.mark.parametrize("lanes", [8, 128])
+@pytest.mark.parametrize("ranges", [1, 4, 8])
+@pytest.mark.parametrize("over", sorted(REACH_OVER))
+def test_pull_with_the_reach_is_the_pull_without(over, ranges, lanes,
+                                                 fine_reach):
+    """Rows < n and the pad row of a pull cut by the reach equal the
+    whole sweep's and the push's; swept_slots is the gathers its loops
+    make, counted one turn at a time."""
+    fine_reach(ranges, step=4)
+    etypes = REACH_OVER[over]
+    ix = _hop_graph(11)
+    W = E.lanes_width(lanes)
+    rng = np.random.default_rng(ranges * 131 + lanes)
+    fp, _rows = _hop_frontier(ix, rng, 120, W)
+    accp = np.zeros_like(fp)
+    sel = np.r_[0:ix.n, ix.n_rows]
+    whole, _ = _pull_reference(ix, etypes, fp, accp)
+    pushed, _acc, info = _run_hop(ix, etypes, fp, accp, 1 << 20)
+    assert info[E.HOP_INFO_SPARSE] == 1
+    assert np.array_equal(pushed[sel], whole[sel])
+    nb = len(ix.bucket_nbr)
+    tables = ix.kernel_args()[1:]
+    eslot, hrows = ix.hub_merge()
+    for name, reach in _reach_variants(ix, ranges).items():
+        ix.reach = reach
+        # the rows stand in order of IN-degree: the in-table's padding
+        # is what the reach finds, the out-table's as it happens
+        assert E.swept_slots(ix, etypes) <= E.table_slots(ix, etypes)
+        assert E.swept_slots(ix, etypes) < E.table_slots(ix, etypes) \
+            or max(etypes) < 0
+        cut = np.asarray(E._hop_body_packed(
+            jnp, jax, ix.n, len(ix.extra_owner),
+            E._read_sides(etypes, tables, nb), jnp.asarray(eslot),
+            jnp.asarray(hrows), jnp.asarray(fp),
+            E._side_reaches(ix, etypes)))
+        assert np.array_equal(cut[sel], whole[sel]), name
+        # the program a stream runs: the pull branch of the hop, which
+        # reports the TABLE's slots whatever it gathers
+        nxt, _acc, info = _run_hop(ix, etypes, fp, accp, 8)
+        assert info[E.HOP_INFO_SPARSE] == 0
+        assert info[E.HOP_INFO_SLOTS] == E.table_slots(ix, etypes)
+        assert np.array_equal(nxt[sel], whole[sel]), name
+        # the same loops in numpy, a turn at a time, over a frontier
+        # that counts what is gathered from it
+        counted = _CountedFrontier(fp)
+        outs = E._buckets_expand_packed(
+            np, _EagerJax, counted,
+            E._read_sides(etypes, (*ix.bucket_nbr, *ix.bucket_et,
+                                   *ix.out_nbr, *ix.out_et), nb), ix.n,
+            E._side_reaches(ix, etypes))
+        assert counted.gathered == E.swept_slots(ix, etypes), name
+        # (before the hub merge: a hub's own row lacks its extra rows)
+        plain = np.flatnonzero(ix.hub_expansion()[0][:ix.n] == 0)
+        assert np.array_equal(np.concatenate(outs)[plain], whole[plain])
+    ix.reach = None
+    assert E.swept_slots(ix, etypes) == E.table_slots(ix, etypes)
+
+
+def test_shape_sig_differs_where_the_reach_does(fine_reach):
+    n, edges = _skewed_mirror()
+    sigs = {}
+    for ranges in (4, 8):
+        fine_reach(ranges, step=4)
+        ix = E.EllIndex.build(*edges, n, cap=16, min_d=2)
+        sigs[ranges] = ix.shape_sig()
+        assert ix.reach in sigs[ranges]
+    # the same tables, another cut of their columns: other programs
+    assert sigs[4] != sigs[8]
+    assert [s for s in sigs[4] if s != ix.reach][:5] \
+        == [s for s in sigs[8] if s != ix.reach][:5]
+    same = E.EllIndex.build(*edges, n, cap=16, min_d=2)
+    assert same.shape_sig() == sigs[8]
+    same.reach = None
+    assert same.shape_sig() != sigs[8]
+
+
+@pytest.mark.parametrize("ranges", [4, 8])
+def test_windowed_go_and_bfs_pull_by_the_reach(ranges, fine_reach):
+    """Every program that pulls takes the index's reach: the windowed
+    GO and the BFS whose levels all pull give what they give over an
+    index that carries none."""
+    fine_reach(ranges, step=4)
+    ix = _hop_graph(11)
+    assert E.swept_slots(ix, (1,)) < E.table_slots(ix, (1,))
+    rng = np.random.default_rng(ranges)
+    starts = [rng.choice(ix.n, 3, replace=False) for _ in range(8)]
+    f0 = ix.start_frontier(starts, B=8)
+    t0 = ix.start_frontier([rng.choice(ix.n, 2) for _ in range(8)], B=8)
+    reach = ix.reach
+
+    def answers():
+        go = [run_go(ix, 3, et, f0, upto=upto)[:ix.n]
+              for et in ((1, 2), (-1,), (2, -1)) for upto in (False, True)]
+        d, info = run_bfs_info(ix, 6, (1, 2), f0, t0, push_rows=1)
+        assert info[E.BFS_INFO_PUSHED] == 0 < info[E.BFS_INFO_LEVELS]
+        return go + [d[:ix.n]], info
+
+    cut, info = answers()
+    assert E.bfs_swept(ix, (1, 2), info) < E.bfs_slots(ix, (1, 2), info)
+    ix.reach = None
+    whole, info = answers()
+    assert E.bfs_swept(ix, (1, 2), info) == E.bfs_slots(ix, (1, 2), info)
+    ix.reach = reach
+    for a, b in zip(cut, whole):
+        assert np.array_equal(a, b)

@@ -1,5 +1,7 @@
 """The continuous hop program, the batched BFS program whose levels
-take the same step (PR 30), the per-lane count of the resident
+take the same step (PR 30), the windowed GO (PR 39: the three programs
+that pull, each cut by the cell's reach at 4 and at 8 column ranges
+and held against the whole sweep), the per-lane count of the resident
 frontier (PR 33) and the leavers' extract at every rung of leavers
 (PR 37), compiled for the v5e at the
 benchmark cell's real table shapes — no chip needed: the TPU's
@@ -27,6 +29,41 @@ S20_BUCKETS = [(452588, 8), (56666, 16), (74253, 32), (6223, 64),
 S20_N, S20_ROWS, S20_EXTRAS, S20_HUBS = 646081, 657674, 11593, 6197
 LANES = 128
 
+# The cell's reach (EllIndex.reach, PR 39) at 4 and at 8 column ranges,
+# (in-table, out-table) x bucket x range: the leading main rows of a
+# bucket that hold a real slot from the range's first column on,
+# rounded up to 1,024 rows.  Made by a replay of the configuration's
+# graph on the sandbox's host, numpy only: benchmark/generators/
+# kronecker.py at structure_seed 50020, self-loops and duplicates
+# dropped (16,084,349 edges, 646,081 vertices with one), both
+# directions handed to EllIndex.build(cap=512, min_d=8, growth_slack=8),
+# which gives S20_BUCKETS to the row; the reach depends on the degrees
+# alone, so every --seed's relabelling has this one.  The cap bucket's
+# 6,278 main rows reach every range (6,196 of them are full), so it
+# keeps one loop over its 17,871 rows.
+S20_REACH = {
+    4: (((353280, 135168, 64512, 27648), (56666, 51200, 33792, 4096),
+         (74253, 74253, 66560, 19456), (6223, 6144, 6144, 4096),
+         (34651, 34651, 26624, 1024), (15422, 15422, 15422, 15360),
+         (6278,) * 4),
+        ((452588, 452588, 452588, 451584), (56666,) * 4, (74253,) * 4,
+         (6223, 6223, 6223, 5120), (34651, 34651, 34651, 33792),
+         (15422,) * 4, (6278,) * 4)),
+    8: (((353280, 207872, 135168, 92160, 64512, 45056, 27648, 12288),
+         (56666, 56320, 51200, 41984, 33792, 12288, 4096, 2048),
+         (74253, 74253, 74253, 73728, 66560, 44032, 19456, 5120),
+         (6223, 6223, 6144, 6144, 6144, 5120, 4096, 4096),
+         (34651, 34651, 34651, 34651, 26624, 3072, 1024, 1024),
+         (15422, 15422, 15422, 15422, 15422, 15422, 15360, 4096),
+         (6278,) * 8),
+        ((452588,) * 5 + (451584,) * 3, (56666,) * 8, (74253,) * 8,
+         (6223,) * 6 + (5120,) * 2,
+         (34651,) * 6 + (33792, 1024), (15422,) * 8, (6278,) * 8)),
+}
+# slots a forward pull gathers under each (ell.swept_slots), of the
+# in-table's 24,835,040
+S20_SWEPT = {4: 20178536, 8: 18994048}
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -46,15 +83,17 @@ class _Shapes:
     """What a kernel builder reads of an EllIndex: sizes only (the
     zero tables are never touched)."""
 
-    def __init__(self):
+    def __init__(self, ranges=None):
         self.n, self.n_rows = S20_N, S20_ROWS
+        # None: every row at every column, the program until PR 39
+        self.reach = S20_REACH.get(ranges)
         self.extra_owner = np.zeros(S20_EXTRAS, np.int32)
         self.bucket_nbr = [np.zeros(s, np.int32) for s in S20_BUCKETS]
         self.bucket_et = [np.zeros(s, np.int8) for s in S20_BUCKETS]
         self.out_nbr, self.out_et = self.bucket_nbr, self.bucket_et
 
 
-def _compile(fn, one_chip):
+def _compile(fn, one_chip, carriers=2):
     import jax
     from nebula_tpu.tpu import ell as E
 
@@ -64,7 +103,8 @@ def _compile(fn, one_chip):
     fp = sd((S20_ROWS + 1, E.lanes_width(LANES)), np.uint8)
     # tables as EllIndex.kernel_args orders them: (*in_nbr, *in_et,
     # *out_nbr, *out_et)
-    args = (fp, fp, sd((S20_EXTRAS,), np.int32), sd((S20_HUBS,), np.int32)) \
+    args = (fp,) * carriers \
+        + (sd((S20_EXTRAS,), np.int32), sd((S20_HUBS,), np.int32)) \
         + (tuple(sd(s, np.int32) for s in S20_BUCKETS)
            + tuple(sd(s, np.int8) for s in S20_BUCKETS)) * 2
     t0 = time.perf_counter()
@@ -72,11 +112,56 @@ def _compile(fn, one_chip):
     return compiled, time.perf_counter() - t0
 
 
-def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip):
+@pytest.fixture(scope="module")
+def whole(one_chip):
+    """The programs over an index that carries no reach — every row at
+    every column, what the parent of PR 39 compiled — as (scratch
+    bytes, code bytes), each compiled once for the module."""
+    from nebula_tpu.tpu import ell as E
+    made = {}
+
+    def get(name):
+        if name not in made:
+            fn = {"hop": lambda: E.make_continuous_hop_kernel(
+                      _Shapes(), (1,), donate=True),
+                  "bfs": lambda: E.make_batched_bfs_lanes_kernel(
+                      _Shapes(), 5, (1,), stop_when_found=True,
+                      donate=True),
+                  "go": lambda: E.make_batched_go_lanes_kernel(
+                      _Shapes(), 3, (1,), donate=True)}[name]()
+            made[name] = _sizes(_compile(
+                fn, one_chip, carriers=1 if name == "go" else 2)[0])
+        return made[name]
+
+    return get
+
+
+def _sizes(compiled):
+    mem = compiled.memory_analysis()
+    return mem.temp_size_in_bytes, mem.generated_code_size_in_bytes
+
+
+# What a program of PR 39 may take on the device over its parent's, in
+# generated code: every column range of a bucket is a loop with a
+# gather of its own, 0.3-0.4 MB each, and code is device memory while
+# the program is loaded (PERF.md section 7, "Left by PR 37" (a)).
+# Measured +4.0 MB (jit_hop) and +4.1 MB (jit_bfs) at 4 ranges, +8.1
+# and +7.9 MB at 8; device_bytes_per_edge's bound of 0.01 is 8 MB at
+# this graph's 16.08 M edges.  The accumulators no longer span their
+# buckets, so scratch gives back more than the code takes: scratch +
+# code falls by 33 / 55 MB at 4 ranges, by 22 / 43 MB at 8.
+CODE_MARGIN = 5 * 10**6
+
+
+@pytest.mark.parametrize("ranges", [4, 8])
+def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip, whole,
+                                                       ranges):
     import jax
     import jax.numpy as jnp
     from nebula_tpu.tpu import ell as E
-    ix = _Shapes()
+    ix = _Shapes(ranges)
+    assert E.table_slots(ix, (1,)) == 24835040
+    assert E.swept_slots(ix, (1,)) == S20_SWEPT[ranges]
     nb = len(S20_BUCKETS)
     hop, hop_s = _compile(
         E.make_continuous_hop_kernel(ix, (1,), donate=True), one_chip)
@@ -84,43 +169,87 @@ def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip):
     def pull_only(fp, accp, eslot, hrows, *tables):
         nxt = E._hop_body_packed(jnp, jax, ix.n, S20_EXTRAS,
                                  E._read_sides((1,), tables, nb),
-                                 eslot, hrows, fp)
+                                 eslot, hrows, fp,
+                                 E._side_reaches(ix, (1,)))
         return nxt, accp | nxt
 
     pull, _s = _compile(jax.jit(pull_only, donate_argnums=(0, 1)),
                         one_chip)
     text = hop.as_text()
     assert "conditional" in text                  # both branches, one program
-    scratch = hop.memory_analysis().temp_size_in_bytes
+    scratch, code = _sizes(hop)
     scratch_pull = pull.memory_analysis().temp_size_in_bytes
     # the two branches share their scratch; what the program adds over
     # the pull alone is the conditional's own frontier-sized result
-    # (measured 142 MB against 118 MB).  A table laid out anew for a
-    # row read shows as +395 MB
+    # (measured 141.3 MB against 118 MB at 4 ranges).  A table laid out
+    # anew for a row read, or for a column prefix, shows as +395 MB
     assert scratch <= scratch_pull + 48 * 2**20, (scratch, scratch_pull)
+    # against the whole sweep (178.3 MB of scratch, 10.55 MB of code):
+    # 141.3 + 14.55 at 4 ranges, 148.2 + 18.66 at 8
+    scratch_whole, code_whole = whole("hop")
+    assert scratch + code <= scratch_whole + code_whole, \
+        (scratch, code, scratch_whole, code_whole)
+    if ranges == E.PULL_COLUMN_RANGES:
+        assert code <= code_whole + CODE_MARGIN, (code, code_whole)
     # measured 5-6 s; the flat running sum alone was 33 s
     assert hop_s < 25.0, hop_s
 
 
-def test_bfs_program_compiles_for_the_v5e_at_cell_size(one_chip):
+@pytest.mark.parametrize("ranges", [4, 8])
+def test_bfs_program_compiles_for_the_v5e_at_cell_size(one_chip, whole,
+                                                       ranges):
     """jit_bfs as graph500-s20-path.closed16 dispatches it (the
     128-lane rung, UPTO 5 STEPS, shortest): every level is the hop's
     step, so the conditional sits inside the level loop, beside a
     171 MB depth matrix that is live across it."""
     from nebula_tpu.tpu import ell as E
     bfs, bfs_s = _compile(
-        E.make_batched_bfs_lanes_kernel(_Shapes(), 5, (1,),
+        E.make_batched_bfs_lanes_kernel(_Shapes(ranges), 5, (1,),
                                         stop_when_found=True, donate=True),
         one_chip)
     text = bfs.as_text()
     assert "conditional" in text and "while" in text
-    # measured 578.5 MB (PR 29's sweep-only program: 516.0); a slot
+    # measured 511.1 MB at 4 ranges, 519.1 at 8 (the whole sweep's
+    # program: 570.3; PR 29's sweep-only program: 516.0); a slot
     # table laid out anew for a row read inside the loop shows as
-    # +390 MB (a budget of ONE row reads 908.5 MB)
-    scratch = bfs.memory_analysis().temp_size_in_bytes
+    # +390 MB (a budget of ONE row reads 908.5 MB), and one
+    # accumulator a range ORed together afterwards as +19 MB
+    scratch, code = _sizes(bfs)
     assert scratch <= 640e6, scratch
-    # measured 4-8 s, as the sweep-only program
+    # against the whole sweep (570.3 MB of scratch, 13.17 MB of code):
+    # 511.1 + 17.26 at 4 ranges, 519.1 + 21.04 at 8
+    scratch_whole, code_whole = whole("bfs")
+    assert scratch + code <= scratch_whole + code_whole, \
+        (scratch, code, scratch_whole, code_whole)
+    if ranges == E.PULL_COLUMN_RANGES:
+        assert code <= code_whole + CODE_MARGIN, (code, code_whole)
+    # measured 6-9 s, as the sweep-only program
     assert bfs_s < 30.0, bfs_s
+
+
+@pytest.mark.parametrize("ranges", [4, 8])
+def test_windowed_go_compiles_for_the_v5e_at_cell_size(one_chip, whole,
+                                                       ranges):
+    """jit_go as the warm-up and every ContinuousUnavailable bounce
+    load it (go_dispatch_mode=windowed: make_batched_go_lanes_kernel,
+    3 steps at the 128-lane rung): the pull alone, in a loop over the
+    hops."""
+    from nebula_tpu.tpu import ell as E
+    go, go_s = _compile(
+        E.make_batched_go_lanes_kernel(_Shapes(ranges), 3, (1,),
+                                       donate=True), one_chip, carriers=1)
+    scratch, code = _sizes(go)
+    # against the whole sweep (229.1 MB of scratch, 6.55 MB of code):
+    # 187.9 + 10.59 at 4 ranges; 234.0 + 14.52 at 8, which takes more
+    # of the device than it gives back here
+    scratch_whole, code_whole = whole("go")
+    assert scratch + code <= scratch_whole + code_whole + 16 * 10**6, \
+        (scratch, code, scratch_whole, code_whole)
+    if ranges == E.PULL_COLUMN_RANGES:
+        assert scratch + code <= scratch_whole + code_whole
+        assert code <= code_whole + CODE_MARGIN, (code, code_whole)
+    # measured 2-4 s
+    assert go_s < 25.0, go_s
 
 
 def test_count_program_compiles_for_the_v5e_at_cell_size(one_chip):

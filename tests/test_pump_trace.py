@@ -538,7 +538,7 @@ class TestHopBranchFields:
         _burst(c, _mixed(6))
         ticks = _ticks()
         for field in ("hop_reads", "hop_sparse", "hop_slots",
-                      "hop_onesided"):
+                      "hop_onesided", "hop_swept"):
             assert all(field in t for t in ticks), field
         reads = sum(t["hop_reads"] for t in ticks)
         # OVER one edge type forwards: every hop read one direction's
@@ -555,6 +555,12 @@ class TestHopBranchFields:
         pushed = [t for t in ticks if t["hop_sparse"]]
         assert pushed and all(
             0 < t["hop_slots"] < 42 * 512 * t["hop_reads"] for t in pushed)
+        # a push gathers the slots it reports (PR 39): every hop here
+        # pushed, so the two counts are one, in the records and in
+        # rt.stats
+        assert all(t["hop_swept"] == t["hop_slots"] for t in ticks)
+        assert rt.stats["hop_swept_slots"] - before["hop_swept_slots"] \
+            == sum(t["hop_slots"] for t in ticks)
 
     def test_show_timeline_renders_the_branch(self, graph):
         c, g, ok = graph
@@ -593,7 +599,7 @@ class TestHopBranchFields:
         sess.hop_reads()                    # drain what is there
         sess._hop_info.append(InFlight())
         try:
-            assert sess.hop_reads() == (0, 0, 0, 0)
+            assert sess.hop_reads() == (0, 0, 0, 0, 0)
             assert InFlight.asked >= 1 and len(sess._hop_info) == 1
         finally:
             sess._hop_info.clear()
