@@ -148,7 +148,9 @@ class FlightRecorder:
         pull the whole of the table it read; hop_swept the slots they
         gathered, a pull what its reach leaves of the table:
         ell.swept_slots; hop_onesided those that read one direction's
-        table only),
+        table only: a stream is one OVER set, so all of hop_reads, or 0
+        for the two-signed set of a GO ... BIDIRECT, whose pulls read
+        both tables),
         idle gap since the previous tick, mirror generation, tick wall
         micros."""
         rec = {"kind": "tick", "stream": int(stream)}

@@ -18,7 +18,8 @@ from ..clients.graph_client import ExecutionResponse, GraphClient
 from ..interface.common import HostAddr
 
 KEYWORDS = [
-    "GO", "FROM", "OVER", "REVERSELY", "WHERE", "YIELD", "AS", "STEPS",
+    "GO", "FROM", "OVER", "REVERSELY", "BIDIRECT", "WHERE", "YIELD", "AS",
+    "STEPS",
     "UPTO", "USE", "CREATE", "SPACE", "TAG", "EDGE", "DROP", "ALTER",
     "DESCRIBE", "DESC", "SHOW", "SPACES", "TAGS", "EDGES", "HOSTS",
     "INSERT", "VERTEX", "VALUES", "UPDATE", "DELETE", "FETCH", "PROP",

@@ -82,7 +82,7 @@ def default_col_name(expr: Expression) -> str:
     return str(expr)
 
 
-def _flat_yield_specs(yield_cols, over_aliases: Dict[str, int],
+def _flat_yield_specs(yield_cols, over_aliases: Dict[str, Tuple],
                       etypes: List[int]):
     """Map each YIELD column onto a flat-response column, or None when
     any column needs per-row evaluation (composite expressions, and
@@ -226,20 +226,14 @@ class GoExecutor(Executor):
         steps = s.step.steps
 
         # ---- OVER resolution ----------------------------------------
-        over_aliases: Dict[str, int] = {}  # alias/name -> etype (signed)
-        if s.over.is_all:
-            for et in sm.all_edge_types(space):
-                name = sm.edge_name(space, et)
-                over_aliases[name] = -et if s.over.reversely else et
-        else:
-            for oe in s.over.edges:
-                r = sm.to_edge_type(space, oe.edge)
-                if not r.ok():
-                    raise ExecError(f"unknown edge `{oe.edge}'")
-                et = -r.value() if s.over.reversely else r.value()
-                over_aliases[oe.alias or oe.edge] = et
-        etypes = sorted(set(over_aliases.values()))
-        etype_to_alias = {et: a for a, et in over_aliases.items()}
+        # alias/name -> its signed etypes: one, or under BIDIRECT both
+        try:
+            over_aliases = s.over.resolve(sm, space)
+        except KeyError as e:
+            raise ExecError(f"unknown edge `{e.args[0]}'")
+        etypes = sorted({et for ets in over_aliases.values() for et in ets})
+        etype_to_alias = {et: a for a, ets in over_aliases.items()
+                          for et in ets}
 
         # ---- YIELD defaults -----------------------------------------
         if s.yield_ is not None:
@@ -272,7 +266,8 @@ class GoExecutor(Executor):
 
         edge_props: Dict[int, List[str]] = {}
         for alias, prop in sorted(edge_refs):
-            edge_props.setdefault(over_aliases[alias], []).append(prop)
+            for et in over_aliases[alias]:
+                edge_props.setdefault(et, []).append(prop)
 
         # ---- filter pushdown decision -------------------------------
         pushed: Optional[bytes] = None
@@ -980,16 +975,17 @@ def _go_plain(go) -> bool:
 
 def _go_distinct_dst(go) -> bool:
     """The one DISTINCT the device answers: ``YIELD DISTINCT <e>._dst``
-    as a plain GO's only column, over one edge type, forwards.  Its
-    rows are the next frontier: on their own the k-hop neighbourhood
+    as a plain GO's only column, over one edge name: forwards,
+    REVERSELY or BIDIRECT, the k-th frontier is the k-th frontier
+    whatever tables the hops read.  Its rows are the next frontier:
+    on their own the k-hop neighbourhood
     (GoExecutor: reduce "distinct"), piped into a bare COUNT(*) its
     size (_go_reduce_shape: "count_distinct")."""
     return go.yield_ is not None and go.yield_.distinct \
         and _go_plain(go) \
         and len(go.yield_.columns) == 1 \
         and isinstance(go.yield_.columns[0].expr, EdgeDstIdExpr) \
-        and not go.over.is_all and not go.over.reversely \
-        and len(go.over.edges) == 1
+        and not go.over.is_all and len(go.over.edges) == 1
 
 
 def _go_reduce_shape(left, right):

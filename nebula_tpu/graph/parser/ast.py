@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 from ...filter.expressions import Expression
 
@@ -95,7 +95,42 @@ class OverEdge:
 class OverClause:
     edges: List[OverEdge] = field(default_factory=list)
     is_all: bool = False        # OVER *
-    reversely: bool = False
+    reversely: bool = False     # every edge from its far end
+    bidirect: bool = False      # every edge from either end
+
+    def signs(self) -> Tuple[int, ...]:
+        """The signs of each named edge type a step reads: the stored
+        edge (+t), its reverse key (-t, mutate.py writes both), or both
+        in the mirror's sort order."""
+        if self.bidirect:
+            return (-1, 1)
+        return (-1,) if self.reversely else (1,)
+
+    def resolve(self, sm, space: int) -> Dict[str, Tuple[int, ...]]:
+        """alias (or edge name) -> the signed edge types it names, one
+        but under BIDIRECT, where one name stands on both signs.
+        KeyError(edge name) where the schema has no such edge."""
+        if self.is_all:
+            named = [(sm.edge_name(space, et), et)
+                     for et in sm.all_edge_types(space)]
+        else:
+            named = []
+            for oe in self.edges:
+                r = sm.to_edge_type(space, oe.edge)
+                if not r.ok():
+                    raise KeyError(oe.edge)
+                named.append((oe.alias or oe.edge, r.value()))
+        signs = self.signs()
+        return {name: tuple(sg * et for sg in signs)
+                for name, et in named}
+
+    def __str__(self) -> str:
+        names = "*" if self.is_all else ", ".join(
+            e.edge + (f" AS {e.alias}" if e.alias else "")
+            for e in self.edges)
+        word = " BIDIRECT" if self.bidirect else \
+            " REVERSELY" if self.reversely else ""
+        return f"OVER {names}{word}"
 
 
 @dataclass

@@ -28,7 +28,8 @@ class Token(NamedTuple):
 
 
 KEYWORDS = {
-    "go", "steps", "step", "from", "over", "reversely", "where", "yield",
+    "go", "steps", "step", "from", "over", "reversely", "bidirect", "where",
+    "yield",
     "distinct", "as", "to", "upto", "match", "find", "path", "shortest",
     "all", "fetch", "prop", "on", "union", "intersect", "minus", "use",
     "show", "spaces", "tags", "edges", "hosts", "parts", "users", "configs",

@@ -296,6 +296,12 @@ class _Parser:
                     break
         if self.accept_kw("reversely"):
             oc.reversely = True
+            if self.at_kw("bidirect"):
+                self.fail("REVERSELY and BIDIRECT exclude each other")
+        elif self.accept_kw("bidirect"):
+            oc.bidirect = True
+            if self.at_kw("reversely"):
+                self.fail("REVERSELY and BIDIRECT exclude each other")
         return oc
 
     def p_where(self) -> Expression:
@@ -495,6 +501,9 @@ class _Parser:
             s.to = self.p_vid_list_or_ref()
             if self.at_kw("over"):
                 s.over = self.p_over_clause()
+                if s.over.bidirect:
+                    self.fail("FIND PATH walks OVER forwards: "
+                              "BIDIRECT is a GO clause")
             if self.accept_kw("upto"):
                 n = self.next()
                 if n.type != "INT":

@@ -121,13 +121,13 @@ class TpuStorageBackend:
             aliases = sorted({n.alias for n in _walk(filter_expr)
                               if isinstance(n, AliasPropExpr)}) or ["_"]
             for et in edge_types:
-                comp = ExprCompiler(m, space_id, sm,
-                                    {a: et for a in aliases})
+                alias_map = {a: (et,) for a in aliases}
+                comp = ExprCompiler(m, space_id, sm, alias_map)
                 try:
                     cval = comp.compile(filter_expr)
                 except CompileError:
                     self._decline("filter uncompilable against mirror")
-                plans[et] = _GoPlan(m, {a: et for a in aliases}, cval,
+                plans[et] = _GoPlan(m, alias_map, cval,
                                     dict(comp.used), True, comp, None,
                                     sc_or=_filter_has_or(filter_expr))
 

@@ -211,6 +211,37 @@ class Column:
         return int(v)
 
 
+def edge_column(m: "CsrMirror", et, prop: str) -> Optional[Column]:
+    """The edge column ``(et, prop)``.  ``et`` is one signed edge type,
+    or the tuple of both signs that one name stands on under ``GO ...
+    BIDIRECT`` (ExprCompiler._edge_col hands a lone sign over as the
+    int): the columns of +t and of -t are valid on disjoint rows
+    (a row is one sign's), so the merged column holds each row's own
+    value, built once per mirror generation (edge events publish a new
+    generation; only vertex writes commit in place).  None where either
+    column is missing or holds strings (two dictionaries, two codes for
+    one string): the per-row evaluator answers those."""
+    if not isinstance(et, tuple):
+        return m.edge_cols.get((et, prop))
+    cache = m.__dict__.setdefault("_merged_edge_cols", {})
+    got = cache.get((et, prop))
+    if got is None:
+        parts = [m.edge_cols.get((e, prop)) for e in et]
+        if any(c is None or c.values is None or c.raw is not None
+               or c.stype != parts[0].stype for c in parts):
+            return None
+        got = Column(prop, parts[0].stype, 0)
+        got.values = parts[0].values.copy()
+        got.valid = parts[0].valid.copy()
+        for c in parts[1:]:
+            got.values[c.valid] = c.values[c.valid]
+            got.valid |= c.valid
+        got.device_ok = all(c.device_ok for c in parts) \
+            and Column.numeric_device_ok(got.values)
+        cache[(et, prop)] = got
+    return got
+
+
 class CsrMirror:
     """Per-space CSR + columnar property store.
 

@@ -533,8 +533,9 @@ def _split_signs(etypes: Tuple[int, ...]):
 
 def sides_read(etypes: Tuple[int, ...]) -> int:
     """How many of the two tables a step over ``etypes`` reads: one
-    for a one-signed OVER set (every statement the grammar makes:
-    REVERSELY flips the whole set), two for a mixed-sign one."""
+    for a one-signed OVER set (forwards, or REVERSELY, which flips the
+    whole set), two for a mixed-sign one (GO ... BIDIRECT names every
+    edge type on both signs)."""
     return sum(1 for mags in _split_signs(etypes) if mags)
 
 

@@ -31,7 +31,7 @@ from ..filter.expressions import (AliasPropExpr, ArithmeticExpr, DestPropExpr,
                                   TypeCastingExpr, UnaryExpr,
                                   VariablePropExpr)
 from ..interface.common import SupportedType
-from .csr import Column, CsrMirror
+from .csr import Column, CsrMirror, edge_column
 
 
 class CompileError(Exception):
@@ -90,7 +90,7 @@ class ExprCompiler:
     """
 
     def __init__(self, mirror: CsrMirror, space_id: int, schema_man,
-                 alias_to_etype: Dict[str, int], host_only: bool = False):
+                 alias_to_etype: Dict[str, Tuple], host_only: bool = False):
         self.mirror = mirror
         # the compiled value will only ever run over the host's numpy
         # columns (int64 / float64, the CPU executor's precision), so a
@@ -112,10 +112,13 @@ class ExprCompiler:
 
     # ---- column registration ----------------------------------------
     def _edge_col(self, alias: str, prop: str) -> Tuple[str, Column]:
-        et = self.alias_to_etype.get(alias)
-        if et is None:
+        ets = self.alias_to_etype.get(alias)
+        if ets is None:
             raise CompileError(f"unknown edge alias `{alias}'")
-        col = self.mirror.edge_cols.get((et, prop))
+        # one signed type, or the name's two signs (BIDIRECT): each
+        # row then reads its own sign's column (csr.edge_column)
+        et = ets[0] if len(ets) == 1 else ets
+        col = edge_column(self.mirror, et, prop)
         if col is None:
             # edge type exists but column doesn't -> always-missing prop:
             # the CPU path errors per-row; decline so it handles it.
@@ -494,7 +497,7 @@ def audit_filter_entry():
                                       LogicalExpr, PrimaryExpr,
                                       RelationalExpr)
 
-    comp = ExprCompiler(None, 0, None, {"e": 1})
+    comp = ExprCompiler(None, 0, None, {"e": (1,)})
     tree = LogicalExpr(
         "&&",
         RelationalExpr("!=",

@@ -64,13 +64,13 @@ from ..common.status import ErrorCode
 from ..filter.expressions import ExprContext, ExprError, Expression
 from ..graph.interim import InterimResult
 from ..storage.device import DeviceExecError
-from .csr import CsrMirror, build_mirror
+from .csr import CsrMirror, build_mirror, edge_column
 from .expr_compile import (CompileError, CVal, Env, ExprCompiler, K_BOOL,
                            K_FLOAT, K_INT, K_STR, K_STRCODE, K_VIDRANK)
 from .jax_setup import device_info, ensure_jax_configured
 from . import kernels
 from .ell import (EllIndex, lane_bitmap_rows, lane_extract_rung,
-                  lane_extract_rungs)
+                  lane_extract_rungs, sides_read)
 
 
 class MeshUnavailable(DeviceExecError):
@@ -106,6 +106,15 @@ class _GoPlan:
         # hop program of the statement's own; False: compiled for the
         # host's float64 columns alone, whatever the device could hold
         self.fuse = fuse
+
+
+def _aliases_of(etype_to_alias: Dict[int, str]) -> Dict[str, Tuple]:
+    """alias -> its signed etypes in ascending order, from the map the
+    executor ships: one type a name, or under BIDIRECT both signs."""
+    out: Dict[str, Tuple] = {}
+    for et in sorted(etype_to_alias):
+        out[etype_to_alias[et]] = out.get(etype_to_alias[et], ()) + (et,)
+    return out
 
 
 def _filter_has_or(expr) -> bool:
@@ -735,7 +744,11 @@ class TpuQueryRuntime:
                       # frontier itself, either tier: statements, hops
                       # ridden, rows returned (distinct_results)
                       "go_distinct": 0, "distinct_hops": 0,
-                      "distinct_vertices": 0}
+                      "distinct_vertices": 0,
+                      # statements planned over a two-signed OVER set
+                      # (GO ... BIDIRECT): every hop of theirs reads
+                      # both direction tables
+                      "go_bidirect": 0}
         self._timing_seq = 0
         # shapes the AOT pre-warm compiled / shapes live dispatch used
         # (prewarm_hits/misses make the pre-warm's p99 effect auditable:
@@ -1440,7 +1453,7 @@ class TpuQueryRuntime:
             return dev
 
     # ================================================== GO planning
-    def _plan_go(self, space_id: int, alias_to_etype: Dict[str, int],
+    def _plan_go(self, space_id: int, alias_to_etype: Dict[str, Tuple],
                  where_expr: Optional[Expression],
                  pushed_mode: bool) -> Optional[_GoPlan]:
         """Compile a GO plan against the space's current mirror, or None
@@ -1524,20 +1537,12 @@ class TpuQueryRuntime:
             # (single-device sparse + dense); the frontier-sharded
             # mesh kernels have no union accumulator — CPU loop there
             return False        # nebulint: carveout=upto-mesh
-        # alias map (same resolution GoExecutor did)
-        alias_to_etype: Dict[str, int] = {}
+        # alias map (the resolution GoExecutor did)
         s = sentence
-        if s.over.is_all:
-            for et in self.sm.all_edge_types(space_id):
-                name = self.sm.edge_name(space_id, et)
-                alias_to_etype[name] = -et if s.over.reversely else et
-        else:
-            for oe in s.over.edges:
-                r = self.sm.to_edge_type(space_id, oe.edge)
-                if not r.ok():
-                    return False        # nebulint: carveout=schema-miss
-                alias_to_etype[oe.alias or oe.edge] = \
-                    -r.value() if s.over.reversely else r.value()
+        try:
+            alias_to_etype = s.over.resolve(self.sm, space_id)
+        except KeyError:
+            return False        # nebulint: carveout=schema-miss
 
         where_expr = s.where.filter if s.where else None
         plan = self._plan_go(space_id, alias_to_etype, where_expr,
@@ -1595,7 +1600,7 @@ class TpuQueryRuntime:
         except Exception as e:      # noqa: BLE001 — undecodable tree
             # nebulint: carveout=expr-undecodable
             raise TpuDecline(f"undecodable expression: {e}")
-        alias_to_etype = {a: et for et, a in etype_to_alias.items()}
+        alias_to_etype = _aliases_of(etype_to_alias)
         if upto and int(flags.get("tpu_mesh_devices") or 0) > 1:
             # the frontier-sharded mesh kernels have no UPTO union
             # accumulator; the graphd side can't see this flag, so the
@@ -1641,6 +1646,8 @@ class TpuQueryRuntime:
             raise TpuDecline(why, degraded=True)
         et_tuple = tuple(sorted(set(etypes)))
         self._bump("go_device")
+        if sides_read(et_tuple) == 2:
+            self._bump("go_bidirect")
         # what _plan_go declined went to the CPU executor before we
         # ever got here; whether a WHERE fuses was decided there too
         # (_where_fuses), with the precision it was compiled for
@@ -2198,6 +2205,7 @@ class TpuQueryRuntime:
         self._bump("go_sparse")
         _flight.recorder.note_dispatch(
             "sparse_go", rung=c0, steps=steps,
+            sides=sides_read(et_tuple),
             h2d_bytes=int(ids.nbytes + qid.nbytes))
         self._maybe_time_device(
             out_dev, sum(c * (d_max + 12) * 4 for c in caps[1:]),
@@ -2399,7 +2407,8 @@ class TpuQueryRuntime:
             # sharded dispatches already logged a (richer) row above
             _flight.recorder.note_dispatch(
                 "ell_go_count" if count_mode else "ell_go",
-                rung=B, steps=steps, hop_bytes=int(hop_bytes))
+                rung=B, steps=steps, sides=sides_read(et_tuple),
+                hop_bytes=int(hop_bytes))
         self._maybe_time_device(out_dev, hop_bytes, kind="ell_go")
 
         if count_mode:
@@ -2847,7 +2856,7 @@ class TpuQueryRuntime:
         inv = np.zeros(len(cand), dtype=bool)
         for k, desc in used.items():
             if desc[0] == "edge":
-                col = m.edge_cols[(desc[1], desc[2])]
+                col = edge_column(m, desc[1], desc[2])
                 inv |= ~col.valid[cand]
             elif desc[0] == "vertex":
                 col = m.vertex_cols[(desc[1], desc[2])]
@@ -2878,7 +2887,7 @@ class TpuQueryRuntime:
             # (see _assemble_group — same rule, fused flavor)
             for k, desc in plan.filter_used.items():
                 if desc[0] == "edge":
-                    col = m.edge_cols[(desc[1], desc[2])]
+                    col = edge_column(m, desc[1], desc[2])
                 elif desc[0] == "vertex":
                     col = m.vertex_cols[(desc[1], desc[2])]
                 else:
@@ -2931,7 +2940,7 @@ class TpuQueryRuntime:
         return columns, rows
 
     # -------------------------------------------------- host columns
-    def _gather_cols(self, m: CsrMirror, alias_to_etype: Dict[str, int],
+    def _gather_cols(self, m: CsrMirror, alias_to_etype: Dict[str, Tuple],
                      used: Dict[str, Tuple],
                      idx: np.ndarray) -> Dict[str, np.ndarray]:
         """numpy columns for compiled-expression eval over edge rows
@@ -2942,7 +2951,7 @@ class TpuQueryRuntime:
         for k, desc in used.items():
             if desc[0] == "edge":
                 cols[k] = _take(
-                    m.edge_cols[(desc[1], desc[2])].values, idx)
+                    edge_column(m, desc[1], desc[2]).values, idx)
             elif desc[0] == "vertex":
                 col = m.vertex_cols[(desc[1], desc[2])]
                 gather = _take(m.edge_src if desc[3] == "src"
@@ -2984,7 +2993,7 @@ class TpuQueryRuntime:
                       "the pump's thread in the continuous tier")
             return None
         key, op, c = cmp_
-        col = m.edge_cols.get(plan.filter_used[key][1:])
+        col = edge_column(m, *plan.filter_used[key][1:])
         if col is None or col.values.dtype != np.float64 \
                 or col.valid.dtype != np.bool_ or col.values.ndim != 1 \
                 or col.valid.shape != col.values.shape \
@@ -3044,7 +3053,7 @@ class TpuQueryRuntime:
             for k, desc in plan.filter_used.items():
                 if desc[0] == "edge":
                     valid_snap[k] = _take(
-                        m.edge_cols[(desc[1], desc[2])].valid, idx)
+                        edge_column(m, desc[1], desc[2]).valid, idx)
                 elif desc[0] == "vertex":
                     gather = _take(m.edge_src if desc[3] == "src"
                                    else m.edge_dst, idx)
@@ -3159,14 +3168,14 @@ class TpuQueryRuntime:
         return kern(dev["edge_src"], dev["edge_dst"], dev["edge_etype"],
                     jnp.asarray(start_idx), env_cols)
 
-    def _env_cols(self, m: CsrMirror, alias_to_etype: Dict[str, int],
+    def _env_cols(self, m: CsrMirror, alias_to_etype: Dict[str, Tuple],
                   used: Dict[str, Tuple], with_valid: bool) -> Dict:
         """Device env for a compiled filter: {key: array} (+"valid:key")."""
         import jax.numpy as jnp
         env: Dict[str, object] = {}
         for k, desc in used.items():
             if desc[0] in ("edge", "vertex"):
-                col = m.edge_cols[(desc[1], desc[2])] \
+                col = edge_column(m, desc[1], desc[2]) \
                     if desc[0] == "edge" \
                     else m.vertex_cols[(desc[1], desc[2])]
                 # valid is snapshotted BEFORE the values are read:
@@ -3185,7 +3194,7 @@ class TpuQueryRuntime:
 
     @staticmethod
     def _etype_alias_codes(m: CsrMirror,
-                           alias_to_etype: Dict[str, int]) -> np.ndarray:
+                           alias_to_etype: Dict[str, Tuple]) -> np.ndarray:
         """int32[m]: per-edge code into the sorted alias dictionary
         (cached per mirror+alias map — O(m) to build, reused across
         queries)."""
@@ -3193,8 +3202,8 @@ class TpuQueryRuntime:
             alias_pos = {a: i
                          for i, a in enumerate(sorted(alias_to_etype))}
             codes = np.zeros(m.m, dtype=np.int32)
-            for a, et in alias_to_etype.items():
-                codes[m.edge_etype == et] = alias_pos[a]
+            for a, ets in alias_to_etype.items():
+                codes[np.isin(m.edge_etype, ets)] = alias_pos[a]
             return codes
         return _mirror_table(m, "_alias_code_cache",
                              tuple(sorted(alias_to_etype.items())), fill)
@@ -3307,7 +3316,7 @@ class TpuQueryRuntime:
 
     # -------------------------------------------------- materialization
     def _materialize_group(self, m: CsrMirror, space_id: int,
-                           alias_to_etype: Dict[str, int],
+                           alias_to_etype: Dict[str, Tuple],
                            etype_to_alias: Dict[int, str], yield_cols,
                            idx: np.ndarray, qseg: np.ndarray,
                            qbounds: np.ndarray, nq: int,
@@ -3386,7 +3395,7 @@ class TpuQueryRuntime:
         return results
 
     def _materialize(self, m: CsrMirror, space_id: int,
-                     alias_to_etype: Dict[str, int],
+                     alias_to_etype: Dict[str, Tuple],
                      etype_to_alias: Dict[int, str], yield_cols,
                      idx: np.ndarray, exc_type) -> List[List[object]]:
         """Evaluate YIELD columns for the selected edges.
@@ -3456,7 +3465,7 @@ class TpuQueryRuntime:
         return a.astype(np.int64)
 
     def _materialize_per_row(self, m: CsrMirror, space_id: int,
-                             alias_to_etype: Dict[str, int],
+                             alias_to_etype: Dict[str, Tuple],
                              etype_to_alias: Dict[int, str], yield_cols,
                              idx: np.ndarray, exc_type) -> List[List[object]]:
         """Row-at-a-time eval with _RowCtx-equivalent getter semantics —
@@ -3687,7 +3696,7 @@ class TpuQueryRuntime:
         from .ell import (BFS_INFO_LEVELS, BFS_INFO_PUSHED, INT16_INF,
                           bfs_slots, bfs_swept, dense_hop_bytes, lanes_width,
                           make_batched_bfs_lanes_kernel,
-                          make_sharded_batched_bfs_kernel, sides_read)
+                          make_sharded_batched_bfs_kernel)
         import time
         stamps = [time.perf_counter()]
         ix = self.ell(m)
@@ -3779,6 +3788,7 @@ class TpuQueryRuntime:
             _flight.recorder.note_dispatch(
                 "ell_bfs", rung=B, steps=max_steps, levels=levels,
                 levels_push=levels_push, hop_onesided=onesided,
+                sides=sides_read(et_tuple),
                 slots=bfs_slots(ix, et_tuple, info), swept=swept,
                 queries=nq, **stages)
         if host.dtype == np.int8:        # in-kernel compression (-1=INF)
@@ -4047,14 +4057,15 @@ class _ContinuousGoSession:
     def __init__(self, rt, space_id: int, m: CsrMirror, ix: EllIndex,
                  et_tuple: Tuple[int, ...], B: int):
         import jax.numpy as jnp
-        from .ell import lanes_width, sides_read, swept_slots
+        from .ell import lanes_width, swept_slots
         self.rt = rt
         self.space_id = space_id
         self.m = m
         self.ix = ix
         self.et_tuple = et_tuple
-        # every hop of this stream reads one direction's table only
-        self._onesided = sides_read(et_tuple) == 1
+        # the direction tables every hop of this stream reads: one, or
+        # both for a two-signed OVER set (GO ... BIDIRECT)
+        self.sides = sides_read(et_tuple)
         # the slots a pull of this stream gathers (a pull REPORTS the
         # table's; the index's reach lets its loops skip padding)
         self._pull_swept = swept_slots(ix, et_tuple)
@@ -4130,7 +4141,7 @@ class _ContinuousGoSession:
             lambda: make_continuous_hop_kernel(self.ix, self.et_tuple,
                                                donate=True))
         with tracing.span("tpu.kernel", kind="ell_go_hop",
-                          width=self.B, packed=True):
+                          width=self.B, packed=True, sides=self.sides):
             self.fp, self.accp, info = kern(self.fp, self.accp,
                                             self.eslot, self.hrows,
                                             *self._tables)
@@ -4163,7 +4174,7 @@ class _ContinuousGoSession:
             # a push gathered the slots it reports, a pull its reach
             swept += slots if pushed else self._pull_swept
         if reads:
-            onesided = reads if self._onesided else 0
+            onesided = reads if self.sides == 1 else 0
             self._hop_read[0] += reads
             self._hop_read[1] += sparse
             self._hop_read[3] += onesided

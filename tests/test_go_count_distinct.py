@@ -252,12 +252,18 @@ def test_the_shape_gate_names_one_shape():
                  " | YIELD COUNT(*) AS n") == ("count_distinct", "n")
     assert shape("GO 3 STEPS FROM 1 OVER knows YIELD knows._dst" + tail) \
         == ("count", "COUNT()")
+    # one edge name from its far end, or from either: the same shape
+    for word in ("REVERSELY", "BIDIRECT"):
+        assert shape(f"GO 3 STEPS FROM 1 OVER knows {word} "
+                     f"YIELD DISTINCT knows._dst" + tail) \
+            == ("count_distinct", "COUNT()")
     for left in (
             "GO 3 STEPS FROM 1 OVER knows YIELD DISTINCT knows._src",
             "GO 3 STEPS FROM 1 OVER knows YIELD DISTINCT knows._dst, "
             "knows._rank",
-            "GO 3 STEPS FROM 1 OVER knows REVERSELY YIELD DISTINCT "
+            "GO 3 STEPS FROM 1 OVER knows, likes REVERSELY YIELD DISTINCT "
             "knows._dst",
+            "GO 3 STEPS FROM 1 OVER * BIDIRECT YIELD DISTINCT knows._dst",
             "GO 3 STEPS FROM 1 OVER * YIELD DISTINCT knows._dst",
             "GO 3 STEPS FROM 1 OVER knows, likes YIELD DISTINCT knows._dst",
             "GO UPTO 3 STEPS FROM 1 OVER knows YIELD DISTINCT knows._dst",
@@ -266,6 +272,34 @@ def test_the_shape_gate_names_one_shape():
         assert shape(left + tail) is None, left
     assert shape("GO 3 STEPS FROM 1 OVER knows YIELD DISTINCT knows._dst"
                  " | LIMIT 3") is None
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_reversely_counts_the_k_th_frontier_against_the_edges(served, k,
+                                                              mode):
+    """``REVERSELY`` reads the other table and rides the same count."""
+    c, g, graph, named = served
+    rt = c.tpu_runtime
+    src = np.repeat(np.arange(len(graph.deg)), graph.deg)
+    starts = [named["hub"]] + named["others"][:3]
+    some = 0
+    with flags_set({"go_dispatch_mode": mode, "tpu_sparse_go": k <= 2}):
+        before = rt.stats["go_count_distinct"]
+        for start in starts:
+            frontier = {int(start)}
+            for _ in range(k):
+                frontier = {int(s) for s in src[np.isin(
+                    graph.dst, sorted(frontier))]}
+            want = [(len(frontier),)] if frontier else []
+            stmt = (f"GO {k} STEPS FROM {start} OVER knows REVERSELY "
+                    f"YIELD DISTINCT knows._dst | YIELD COUNT(*)")
+            assert _rows(g, stmt) == want, (k, mode, start)
+            with flags_set({"storage_backend": "cpu"}):
+                assert _rows(g, stmt) == want
+            some += bool(want)
+        assert rt.stats["go_count_distinct"] - before == len(starts)
+    assert some
 
 
 def _burst(c, statements):

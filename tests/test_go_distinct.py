@@ -286,6 +286,42 @@ def test_several_starts_are_one_neighbourhood(served):
         assert _cpu_rows(g, stmt) == _brute(graph, starts, k)
 
 
+def _brute_reversely(graph, start: int, k: int) -> list:
+    """The walk against the edges: the sources of the in-edges."""
+    src = np.repeat(np.arange(len(graph.deg)), graph.deg)
+    frontier = {int(start)}
+    for _ in range(k):
+        frontier = {int(s) for s in src[np.isin(graph.dst,
+                                                 sorted(frontier))]}
+    return sorted((v,) for v in frontier)
+
+
+@pytest.mark.parametrize("mode", ["continuous", "windowed"])
+@pytest.mark.parametrize("k", [1, 2, 3])
+def test_reversely_is_reduced_like_the_forward_walk(served, k, mode):
+    """The sign flip reads the other table; the lanes, the leaver's
+    bitmap and the counters are the forward statement's."""
+    c, g, graph, named = served
+    rt = c.tpu_runtime
+    starts = [named["hub"]] + named["others"][:3]
+    with flags_set({"go_dispatch_mode": mode, "tpu_sparse_go": k <= 2}):
+        before = {key: rt.stats[key] for key in COUNTERS}
+        total = 0
+        for start in starts:
+            stmt = (f"GO {k} STEPS FROM {start} OVER knows REVERSELY "
+                    f"YIELD DISTINCT knows._dst")
+            want = _brute_reversely(graph, start, k)
+            assert _rows(g, stmt) == want, (k, mode, start)
+            assert _cpu_rows(g, stmt) == want
+            total += len(want)
+        grew = {key: rt.stats[key] - before[key] for key in COUNTERS}
+    assert total
+    assert grew == {"go_device": len(starts), "go_distinct": len(starts),
+                    "distinct_hops": k * len(starts),
+                    "distinct_vertices": total,
+                    "go_reduced": len(starts), "go_count_distinct": 0}
+
+
 def _first_seen(rows) -> list:
     seen, out = set(), []
     for r in rows:
@@ -303,8 +339,6 @@ NOT_REDUCED = {
             "YIELD DISTINCT knows._dst, knows._rank",
     "where": "GO 2 STEPS FROM {v} OVER knows WHERE knows.w > 0.5 "
              "YIELD DISTINCT knows._dst",
-    "reversely": "GO 2 STEPS FROM {v} OVER knows REVERSELY "
-                 "YIELD DISTINCT knows._dst",
     "upto": "GO UPTO 2 STEPS FROM {v} OVER knows "
             "YIELD DISTINCT knows._dst",
     "over_all": "GO 1 STEPS FROM {v} OVER * YIELD DISTINCT knows._dst",
@@ -390,6 +424,17 @@ def test_the_shape_gate_names_one_shape():
     assert _go_distinct_dst(go(_statement(3, 1)))
     assert _go_distinct_dst(go(
         "GO FROM 1 OVER knows AS k YIELD DISTINCT k._dst AS d"))
+    # the k-th frontier is the k-th frontier whatever tables the hops
+    # read: one edge name forwards, from its far end or from either
+    for word in ("REVERSELY", "BIDIRECT"):
+        assert _go_distinct_dst(go(
+            f"GO 2 STEPS FROM 1 OVER knows {word} "
+            f"YIELD DISTINCT knows._dst"))
+        assert not _go_distinct_dst(go(
+            f"GO 2 STEPS FROM 1 OVER knows, likes {word} "
+            f"YIELD DISTINCT knows._dst"))
+        assert not _go_distinct_dst(go(
+            f"GO 2 STEPS FROM 1 OVER * {word} YIELD DISTINCT knows._dst"))
     for stmt in (v.format(v=1) for k, v in NOT_REDUCED.items()
                  if k not in ("piped_input", "limit_behind")):
         assert not _go_distinct_dst(go(stmt)), stmt

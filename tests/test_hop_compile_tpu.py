@@ -1,4 +1,5 @@
-"""The continuous hop program, the batched BFS program whose levels
+"""The continuous hop program (PR 40: and its two-sided form, the
+OVER set of a GO ... BIDIRECT), the batched BFS program whose levels
 take the same step (PR 30), the windowed GO (PR 39: the three programs
 that pull, each cut by the cell's reach at 4 and at 8 column ranges
 and held against the whole sweep), the per-lane count of the resident
@@ -193,6 +194,45 @@ def test_hop_program_compiles_for_the_v5e_at_cell_size(one_chip, whole,
         assert code <= code_whole + CODE_MARGIN, (code, code_whole)
     # measured 5-6 s; the flat running sum alone was 33 s
     assert hop_s < 25.0, hop_s
+
+
+def test_two_sided_hop_program_compiles_for_the_v5e_at_cell_size(
+        one_chip):
+    """jit_hop as graph500-s20-bidir.bicount16 runs it (PR 40): the
+    OVER set (-t, +t) of ``GO ... OVER knows BIDIRECT``, so the pull
+    carries every bucket's loops twice, once a direction table, and the
+    push scatters a live row into its slots of both.  Held beside the
+    forward program and the REVERSELY one compiled with it: the
+    out-table's rows stand by IN-degree (PR 39), so its reach cuts
+    next to nothing (24,787,896 of 24,835,040 slots gathered, where
+    the in-table's pull gathers 20,178,536)."""
+    from nebula_tpu.tpu import ell as E
+    ix = _Shapes(E.PULL_COLUMN_RANGES)
+    assert E.sides_read((-1, 1)) == 2
+    assert E.table_slots(ix, (-1, 1)) == 2 * 24835040
+    assert E.swept_slots(ix, (-1,)) == 24787896
+    assert E.swept_slots(ix, (-1, 1)) \
+        == S20_SWEPT[E.PULL_COLUMN_RANGES] + 24787896
+    sizes = {}
+    for etypes in ((1,), (-1,), (-1, 1)):
+        hop, hop_s = _compile(
+            E.make_continuous_hop_kernel(ix, etypes, donate=True),
+            one_chip)
+        assert "conditional" in hop.as_text()
+        sizes[etypes] = _sizes(hop)
+        # measured 4-9 s each
+        assert hop_s < 30.0, (etypes, hop_s)
+    (s_fwd, c_fwd), (s_rev, c_rev), (s_two, c_two) = (
+        sizes[(1,)], sizes[(-1,)], sizes[(-1, 1)])
+    # measured: forwards 141.3 MB of scratch + 14.55 MB of code,
+    # REVERSELY 182.0 + 11.98 (its accumulators span their buckets:
+    # nearly every range reaches every row), two-sided 206.2 + 22.96
+    assert c_two <= c_fwd + c_rev, (c_two, c_fwd, c_rev)
+    assert c_two >= max(c_fwd, c_rev)       # it does hold both
+    # the sides run one after the other into one accumulator, so the
+    # scratch is NOT the sum (323 MB); a table laid out anew for a row
+    # read shows as +395 MB (the forward test)
+    assert s_two <= max(s_fwd, s_rev) + 48 * 2**20, (s_two, s_fwd, s_rev)
 
 
 @pytest.mark.parametrize("ranges", [4, 8])

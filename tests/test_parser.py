@@ -48,6 +48,52 @@ class TestGo:
         s2 = parse1("GO FROM 1 OVER *")
         assert s2.over.is_all
 
+    @pytest.mark.parametrize("over, edges, is_all", [
+        ("OVER follow BIDIRECT", [("follow", None)], False),
+        ("OVER follow, serve BIDIRECT",
+         [("follow", None), ("serve", None)], False),
+        ("OVER * BIDIRECT", [], True),
+        ("OVER follow AS f, serve AS s BIDIRECT",
+         [("follow", "f"), ("serve", "s")], False),
+        ("OVER follow bidirect", [("follow", None)], False),
+    ])
+    def test_over_bidirect(self, over, edges, is_all):
+        s = parse1(f"GO 2 STEPS FROM 1 {over} WHERE 1 > 0 "
+                   f"YIELD DISTINCT follow._dst")
+        assert s.over.bidirect and not s.over.reversely
+        assert s.over.is_all == is_all
+        assert [(e.edge, e.alias) for e in s.over.edges] == edges
+        assert s.over.signs() == (-1, 1)
+        assert s.where is not None and s.yield_.distinct
+        # the clause's own rendering parses to the same clause
+        again = parse1(f"GO FROM 1 {s.over}")
+        assert again.over == s.over and str(again.over) == str(s.over)
+        assert str(s.over).endswith(" BIDIRECT")
+
+    @pytest.mark.parametrize("word, signs", [
+        ("", (1,)), (" REVERSELY", (-1,)), (" BIDIRECT", (-1, 1))])
+    def test_over_clause_round_trips(self, word, signs):
+        s = parse1(f"GO FROM 1 OVER follow AS f, serve{word}")
+        assert str(s.over) == f"OVER follow AS f, serve{word}"
+        assert s.over.signs() == signs
+        assert parse1(f"GO FROM 1 {s.over}").over == s.over
+
+    @pytest.mark.parametrize("text, why", [
+        ("GO FROM 1 OVER follow REVERSELY BIDIRECT", "exclude each other"),
+        ("GO FROM 1 OVER follow BIDIRECT REVERSELY", "exclude each other"),
+        ("GO FROM 1 OVER * REVERSELY BIDIRECT", "exclude each other"),
+        ("GO FROM 1 OVER BIDIRECT", "edge name"),
+        ("FIND SHORTEST PATH FROM 1 TO 2 OVER follow BIDIRECT",
+         "BIDIRECT is a GO clause"),
+    ])
+    def test_bidirect_refused(self, text, why):
+        assert why in parse_err(text).msg
+
+    def test_bidirect_is_a_keyword_of_the_console_too(self):
+        from nebula_tpu.console.repl import KEYWORDS
+        from nebula_tpu.graph.parser.lexer import KEYWORDS as LEXED
+        assert "BIDIRECT" in KEYWORDS and "bidirect" in LEXED
+
     def test_from_ref(self):
         s = parse1("GO FROM $-.id OVER follow")
         assert isinstance(s.from_.ref, InputPropExpr)

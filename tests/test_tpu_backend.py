@@ -636,7 +636,7 @@ class TestFrontierEdges:
                          (7, "n"): Col(SupportedType.INT)}
 
         def compiled(expr):
-            return ExprCompiler(Mirror(), 1, None, {"e": 7}).compile(expr)
+            return ExprCompiler(Mirror(), 1, None, {"e": (7,)}).compile(expr)
 
         w, n = AliasPropExpr("e", "w"), AliasPropExpr("e", "n")
         assert compiled(RelationalExpr(">", w, PrimaryExpr(0.5))).cmp \
@@ -1069,10 +1069,16 @@ class TestSparseSplit:
                 base_dense = rt.stats["go_dense"]
                 results = {}
                 lock = threading.Lock()
+                # the burst has to land inside ONE pooling window to
+                # be oversized: connecting and USE take a loaded host
+                # longer than the window, so the statements start
+                # together, after them
+                together = threading.Barrier(len(queries))
 
                 def worker(i):
                     g2 = c.client()
                     g2.execute("USE nba")
+                    together.wait(timeout=60)
                     r = g2.execute(queries[i])
                     assert r.ok(), r.error_msg
                     with lock:
