@@ -124,8 +124,13 @@ class FlightRecorder:
 
     def note_tick(self, stream: int, **fields) -> int:
         """One continuous-pump tick of the per-(space, OVER set)
-        stream keyed ``stream``: seat churn counts, per-phase micros
-        in pump order (seat, then the join/hop/extract/clear ENQUEUES,
+        stream keyed ``stream``: seat churn counts (hold_joins: of the
+        joins, the riders that arrived while the tick held its door),
+        per-phase micros
+        in pump order (hold: the door held open while the device was
+        busy with the hop in flight, 0 on a tick that did not wait —
+        graph/batch_dispatch.py _hold; seat, then the
+        join/hop/extract/clear ENQUEUES,
         then the leave cohort's fetch_wait/d2h/unpack/rows/handover,
         whose sum is assemble_us; rows is what the pump answers
         itself: the COUNT riders' fold, a WHERE that filters in
@@ -302,9 +307,10 @@ def _span_events(node: dict, tid: int, out: List[dict]) -> None:
 
 # per-tick phases, in pump execution order — rendered as nested
 # slices inside the tick so the "where do the busy-ms go" question is
-# answered visually (batch_dispatch._tick records the micros; the last
-# five are the parts of assemble_us)
-_TICK_PHASES = ("seat_us", "join_us", "hop_us", "extract_us",
+# answered visually (batch_dispatch._tick records the micros; the first
+# is the door held open behind a busy device, the last five are the
+# parts of assemble_us)
+_TICK_PHASES = ("hold_us", "seat_us", "join_us", "hop_us", "extract_us",
                 "clear_us", "fetch_wait_us", "d2h_us", "unpack_us",
                 "rows_us", "handover_us")
 

@@ -139,6 +139,10 @@ SPAN_NAMES = (
     # the previous tick ended
     "pump.tick",              # root: one traced tick (tags: stream,
                               # tick, seats, joins, leaves, riders)
+    "pump.hold",              # the door held open behind a busy
+                              # device (batch_dispatch _hold; tag
+                              # joins: riders seated that arrived
+                              # meanwhile); only on a tick that held
     "pump.seat",              # anchor + seat-map bookkeeping
     "pump.enqueue",           # join + hop + extract + clear enqueues
     "pump.count",             # host blocked on the leave cohort's

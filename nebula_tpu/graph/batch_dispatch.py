@@ -185,6 +185,11 @@ stats.register_histogram("graph.admission.wait_us")
 stats.register_stats("graph.continuous.joins")
 stats.register_stats("graph.continuous.leaves")
 stats.register_stats("graph.continuous.evictions")
+# the pump's hold (_ContinuousStream._hold): the micros it held the
+# door of a tick open behind a busy device, and the riders it seated
+# that arrived meanwhile
+stats.register_stats("graph.continuous.hold_us")
+stats.register_stats("graph.continuous.held_joins")
 stats.register_histogram("graph.continuous.lane_occupancy",
                          buckets=(1.0, 2.0, 4.0, 8.0, 16.0, 32.0,
                                   64.0, 128.0, 256.0, 512.0, 1024.0))
@@ -431,12 +436,40 @@ PUMP_IDLE_SPAN_MIN_US = 1000
 PUMP_TICK_RIDER_TAGS = 8
 
 # the phases of a tick that have a ``<p>_us`` in its flight record, in
-# pump order (common/flight.py note_tick): the seating block, the four
-# enqueues, and the five parts of a leave cohort's assembly.  With
-# ``other_us`` they tile ``dur_us``
+# pump order (common/flight.py note_tick): the hold, the seating block,
+# the four enqueues, and the five parts of a leave cohort's assembly.
+# With ``other_us`` they tile ``dur_us``
 _ENQUEUE_PARTS = ("join", "hop", "extract", "clear")
 _ASSEMBLE_PARTS = ("fetch_wait", "d2h", "unpack", "rows", "handover")
-PUMP_PHASES = ("seat",) + _ENQUEUE_PARTS + _ASSEMBLE_PARTS
+PUMP_PHASES = ("hold", "seat") + _ENQUEUE_PARTS + _ASSEMBLE_PARTS
+
+# The hold (_ContinuousStream._hold, docs/admission.md "Continuous
+# dispatch"): before a tick takes its joiners the pump waits, for a
+# share of the time the hop in flight is expected to keep the device
+# busy beyond what the pump needs to enqueue the next hop behind it.
+# A caller handed its answer at the end of the tick before needs its
+# wake, its client's turn-around and its parse to be back in the queue
+# (3-12 ms on the v5e's host), and one that misses the door sits out a
+# whole pull.  HOLD_SHARE is the share of that slack the pump spends
+# waiting and HOLD_FLOOR_S the slack under which it does not wait at
+# all: PERF.md sections 5-6, PR 41 have the readings (a pull of 54 ms
+# holds 26 ms, and from a share of 0.5 to 0.9 the cell's qps is flat;
+# a push of 1-5 ms, which is every hop of the host-paced cells, never
+# passes the floor).  HOLD_POLL_S bounds one sleep of the hold,
+# so that a KILL QUERY, which sets a flag and wakes nobody, ends it
+# within that long; HOLD_BLOCKED_S is the least wait of a fetch that
+# says the device, and not the link's floor of 0.5 ms, was waited for,
+# and HOLD_SEEN_SHARE the least share of the time since its hop began
+# (a pump in step with the device waits 0.42-0.49 of it behind a hold
+# and 0.9 without one; one that waits for the interpreter 0.04-0.08).
+# HOP_EMA_KEEP is the weight of the old estimate in the two device
+# estimates, as in hop_ema_s.
+HOLD_SHARE = 0.5
+HOLD_FLOOR_S = 0.010
+HOLD_POLL_S = 0.005
+HOLD_BLOCKED_S = 0.001
+HOLD_SEEN_SHARE = 0.25
+HOP_EMA_KEEP = 0.7
 
 
 class _PhaseClocks:
@@ -470,6 +503,116 @@ class _PhaseClocks:
             out[p + "_us"] = wall
             out.update(hostclock.host_fields(p + "_", cpu, runq))
         return out
+
+
+def _ema(old: float, new: float) -> float:
+    return new if old == 0.0 else HOP_EMA_KEEP * old \
+        + (1.0 - HOP_EMA_KEEP) * new
+
+
+class _HopInFlight:
+    """What the pump knows of the hop the device is running while the
+    next tick starts, from stamps it takes anyway (pump thread only):
+
+      * ``began``: when that hop began on the device — the later of
+        its enqueue and the moment the fetch of the cohort before it
+        came back from a wait that blocked (the device finishing the
+        hop before it); 0 where no hop is in flight.  ``seen`` says
+        the start was observed and not assumed (a hop enqueued behind
+        one the pump never waited for began later than its enqueue:
+        the estimate then ends early, and the hold is short);
+      * ``hop_s``: how long a hop has taken from an observed start to
+        the blocked fetch behind it, an EMA apart for the hops that
+        pushed and those that pulled (``pushed``: the branch of the
+        last hops read, None where they differed or none was read);
+      * ``turn_s``: an EMA of the pump's own way from the door to the
+        next hop enqueued (seat + join + enqueue).
+
+    ``slack`` is what the hold may spend a share of."""
+
+    __slots__ = ("began", "seen", "before", "hop_s", "pushed", "turn_s")
+
+    def __init__(self):
+        self.began = 0.0
+        self.seen = False
+        self.before = (0.0, False)      # (began, seen) of the hop before
+        self.hop_s = {False: 0.0, True: 0.0}
+        self.pushed = None
+        self.turn_s = 0.0
+
+    def slack(self, now: float, carried: bool) -> float:
+        """Seconds the device is expected to stay busy with the hop in
+        flight after the pump, starting now, has enqueued the next one
+        behind it.  A pull is followed by a pull while any of its
+        riders ride on (``carried``); a hop of first hops alone is
+        taken for a push."""
+        if not self.began or self.pushed is None:
+            return 0.0
+        est = self.hop_s[self.pushed or not carried]
+        if est <= 0.0:
+            return 0.0
+        return self.began + est - now - self.turn_s
+
+    def enqueued(self, t_door: float, t_enq: float) -> None:
+        """A hop was enqueued at ``t_enq``; the pump had shut the door
+        at ``t_door``.  The whole stretch is the pump's turn-around,
+        the session's call too: in every untraced window on the chip
+        it returns in half a millisecond, and where it took 22-152 ms
+        (a traced window while the profiler wrote its trace) the join
+        beside it took 30-84: the interpreter, not the device's queue,
+        and a pump that slow has nothing to hold for."""
+        self.turn_s = _ema(self.turn_s, t_enq - t_door)
+        self.before = (self.began, self.seen)
+        # with nothing in flight the hop starts where it is enqueued
+        self.seen = not self.began
+        self.began = t_enq
+
+    def read(self, reads: int, sparse: int) -> None:
+        """The branches of the hops whose report arrived this tick."""
+        if reads:
+            self.pushed = True if sparse == reads \
+                else False if sparse == 0 else None
+
+    def fetched(self, t_asked: float, t_wait: float, enqueued: bool,
+                known: bool) -> None:
+        """The cohort of the hop before the newest was fetched: asked
+        for at ``t_asked``, there at ``t_wait``; by then that hop had
+        ended.  The fetch **blocked on the device** where the pump
+        waited HOLD_BLOCKED_S or more and HOLD_SEEN_SHARE or more of
+        the time since the hop began: then the hop ended where the
+        wait did, which is a sample if its start was observed (one
+        that a stall of the device or the runtime stretched counts for
+        twice the estimate at most: a fetch of 1.4 s behind a pull of
+        0.12 once made the next holds outlast their hops) and the
+        start of the hop behind it.  A pump that came late and waited
+        a sliver saw its own lateness (a traced window's fetches wait
+        10-20 ms for the interpreter while the profiler writes, and
+        the time since the hop began is then the pump's period, not
+        the hop): no sample; the hop took no longer than to the wait's
+        end, and an estimate above that comes down to it.  That bound
+        is what ends a hold that outlasts its hop, behind which no
+        fetch blocks and no sample comes.  ``enqueued``: this tick put
+        a hop behind it.  ``known``: its branch was read this tick."""
+        began, seen = self.before if enqueued else (self.began, self.seen)
+        took = t_wait - began
+        waited = t_wait - t_asked
+        blocked = waited >= HOLD_BLOCKED_S and bool(began) \
+            and waited >= HOLD_SEEN_SHARE * took
+        if began and known and self.pushed is not None:
+            est = self.hop_s[self.pushed]
+            if blocked and seen:
+                self.hop_s[self.pushed] = _ema(
+                    est, min(took, 2.0 * est) if est else took)
+            elif not blocked and est:
+                self.hop_s[self.pushed] = min(est, took)
+        if not enqueued:
+            self.idle()
+        elif blocked:
+            self.began, self.seen = max(self.began, t_wait), True
+
+    def idle(self) -> None:
+        """Everything enqueued was waited for."""
+        self.began, self.seen = 0.0, False
 
 
 class ContinuousUnavailable(Exception):
@@ -559,6 +702,8 @@ class _ContinuousStream:
     thread owns the device session (tpu/runtime.py
     _ContinuousGoSession) and runs the hop-tick loop —
 
+        hold the door while the device is busy with hop k-1 (_hold:
+        arrivals queue up; not at all when it is not) ->
         seat joiners -> scatter-merge their start frontiers ->
         dispatch hop k -> mark leavers/evictions -> enqueue their
         lane extraction (a counting leaver's per-lane count) + clear ->
@@ -570,7 +715,11 @@ class _ContinuousStream:
     filter and rows are its own thread's work (submit()), not the
     pump's, wherever that pass leaves the interpreter and the
     allocator alone (_finish): the next tick does not wait for the
-    slowest answer.  Mirror
+    slowest answer.  A hop costs the device the same for one lane as
+    for all of them, so where the device is the pace the pump spends
+    half of the time it would wait for the fetch anyway at the door
+    instead: a caller handed its answer rides the next hop enqueued,
+    and hop k is still enqueued before hop k-1 ends.  Mirror
     generation changes drain the stream: seated riders finish on the
     generation they captured (the published-generation contract,
     docs/durability.md), new arrivals wait for the re-anchor —
@@ -610,6 +759,9 @@ class _ContinuousStream:
         # pump-only: the pump slept for want of work since the last
         # tick — the ``why`` of that tick's pump.idle span
         self._saw_no_work = False       # nebulint: guarded-by=none
+        # pump-only: the hop the device is running and how long such
+        # hops take — what the hold's length is read off (_hold)
+        self._flight = _HopInFlight()   # nebulint: guarded-by=none
         self._pump_thread = threading.Thread(
             target=self._pump, daemon=True,
             name=f"continuous-go-{space_id}")
@@ -705,6 +857,7 @@ class _ContinuousStream:
                 sess.fp.block_until_ready()
             except Exception:       # noqa: BLE001 — a dead session
                 pass                # still ends the busy interval
+        self._flight.idle()
         self.sched.meter.end()
         # pump-thread-only state, like self.session
         self._meter_open = False  # nebulint: disable=lock-discipline
@@ -728,6 +881,7 @@ class _ContinuousStream:
         seat map."""
         # pump-thread-only state (see __init__)
         self.session = None  # nebulint: disable=lock-discipline
+        self._flight.idle()
         with self.cond:
             riders = list(self.queue) + list(self.seated.values())
             self.queue.clear()
@@ -788,11 +942,82 @@ class _ContinuousStream:
             self.draining = False
             self.ledger = _LaneLedger(new_sess.B)
 
+    def _hold(self, t0, pending):
+        """Hold the door: wait on the stream's condition, collecting
+        arrivals, for as long as the device is expected to have more
+        of the hop in flight left than the pump needs to get the next
+        hop enqueued behind it — HOLD_SHARE of that slack
+        (_HopInFlight.slack), nothing under HOLD_FLOOR_S — and return
+        the stamp the wait ended at, or ``t0`` itself where it did not
+        wait.  A pull costs the same for one lane as for 128, so a
+        caller handed its answer when the tick before ended, and back
+        in the queue a few milliseconds after this one began, rides
+        the hop this tick enqueues instead of sitting out a whole one.
+
+        No wait where no session is anchored, no hop is in flight,
+        the stream is draining, stopping or about to widen, nobody
+        stays seated (the next hop would carry the joiners alone) or
+        every free lane has a taker; the wait ends where one of those
+        comes true, where a rider was killed (its lane leaves at this
+        tick's boundary), where the runtime has published a mirror
+        that is not the session's (this tick's generation check will
+        drain), or where the time is up.  ``pending`` is the cohort of
+        the hop in flight: with the seated riders it says whether the
+        hop before it carried on into this one.  Pump thread only."""
+        sess, flight_now = self.session, self._flight
+        if sess is None or self._widen or not flight_now.began:
+            return t0
+        # nothing to hold for whoever rides: leave the condition alone
+        # (a tick of a stream the host paces is what it was: one more
+        # turn at the condition is one more chance to hand the
+        # interpreter to sixty-four callers, 2.5 ms a tick in closed64)
+        now = time.perf_counter()
+        if max(flight_now.slack(now, True),
+               flight_now.slack(now, False)) < HOLD_FLOOR_S:
+            return t0
+        riding = pending[1] if pending is not None else ()
+        end = None
+        with self.cond:
+            while not self._door_shut(sess):
+                now = time.perf_counter()
+                if end is None:
+                    # a rider of the hop in flight that rode the one
+                    # before it too: joined two ticks ago or earlier
+                    carried = any(
+                        r.joined_tick <= self.tick_no - 2
+                        for r in (*self.seated.values(), *riding))
+                    slack = flight_now.slack(now, carried)
+                    if slack < HOLD_FLOOR_S:
+                        break
+                    end = now + HOLD_SHARE * slack
+                if now >= end:
+                    break
+                self.cond.wait(min(end - now, HOLD_POLL_S))
+        return t0 if end is None else hostclock.stamp()
+
+    def _door_shut(self, sess) -> bool:
+        """Whether a hold has nothing (more) to wait for, whatever the
+        time: see _hold.  Caller holds the lock (the stream
+        condition)."""
+        if self.stopping or self.draining or not self.seated \
+                or self.ledger is None \
+                or len(self.queue) >= self.ledger.free_count():
+            return True
+        # published, not built: the dict the runtime serves from
+        # (a build or an absorb is the generation check's to pay)
+        mirrors = getattr(self.sched.runtime, "mirrors", None) or {}
+        if mirrors.get(self.space_id, sess.m) is not sess.m:
+            return True
+        return any(query_registry.is_killed(r.qid)
+                   for r in (*self.seated.values(), *self.queue))
+
     def _tick(self, pending):
         """One hop tick; returns the next tick's pending leave cohort
         (or None).  ``pending`` is the PREVIOUS tick's cohort — its
         fetch+assembly runs here, after this tick's hop is enqueued,
-        which is the overlap the idle-frac gauge measures."""
+        which is the overlap the idle-frac gauge measures.  The tick
+        begins by holding its door while the device is busy with the
+        hop in flight (_hold), then seats who is queued."""
         # every stamp that bounds a phase of the tick is the pump
         # thread's three clocks (common/hostclock.py): wall, run time,
         # time runnable without a core
@@ -804,6 +1029,16 @@ class _ContinuousStream:
         saw_no_work = self._saw_no_work
         # pump-thread-only state (see __init__)
         self._saw_no_work = False  # nebulint: disable=lock-discipline
+        # the door stays open while the device is busy with the hop in
+        # flight (_hold).  BEFORE the generation check below: whoever
+        # arrives meanwhile is in the queue when it is counted, so the
+        # check still follows the last arrival this tick seats
+        t_door = self._hold(t0, pending)
+        held = t_door is not t0
+        host = _PhaseClocks(t0)
+        if held:
+            host.add("hold", t0, t_door)
+            stats.add_value("graph.continuous.hold_us", host.wall("hold"))
         with self.cond:
             was_draining = self.draining
             # riders present BEFORE this tick's generation check are
@@ -916,13 +1151,13 @@ class _ContinuousStream:
         # end of the seating block (anchor + seat-map bookkeeping):
         # the tick record's seat_us, the trace's pump.seat
         t_seat = hostclock.stamp()
-        host = _PhaseClocks(t0)
-        host.add("seat", t0, t_seat)
+        host.add("seat", t_door, t_seat)
 
         new_pending = None
         leavers: List[_Rider] = []
         occupancy = 0
-        join_map_us = join_pack_us = 0
+        join_map_us = join_pack_us = hold_joins = 0
+        hop_enqueued = False
         busy = sess is not None and bool(joiners or evicted
                                          or seated_now)
         if busy:
@@ -970,6 +1205,8 @@ class _ContinuousStream:
                             t_hop = hostclock.stamp()
                             host.add("hop", th, t_hop)
                             t_left = t_hop[0]
+                            hop_enqueued = True
+                            self._flight.enqueued(t_door[0], t_left)
                             with self.cond:
                                 self.tick_no += 1
                                 for lane, r in \
@@ -1026,6 +1263,13 @@ class _ContinuousStream:
             if joiners:
                 stats.add_value("graph.continuous.joins",
                                 len(joiners))
+                if held:
+                    # riders this tick seated that came while it held
+                    # the door: a tick without the hold had left them
+                    # to the next
+                    hold_joins = sum(r.enq_t > t0[0] for r in joiners)
+                    stats.add_value("graph.continuous.held_joins",
+                                    hold_joins)
                 for r in joiners:
                     if r.midflight:
                         journal.record(
@@ -1076,12 +1320,14 @@ class _ContinuousStream:
         # nothing left in flight: the cohort just produced has no hop
         # to hide behind — flush it immediately rather than letting it
         # age one idle-poll interval
+        flushed = False
         if new_pending is not None:
             with self.cond:
                 empty = not self.seated and not self.queue
             if empty:
                 finishes.append(self._finish(new_pending))
                 new_pending = None
+                flushed = True
         t_end = hostclock.stamp()
         dur = t_end[0] - t0[0]
         # a handover runs to where the pump stamps next (the flush's
@@ -1093,19 +1339,29 @@ class _ContinuousStream:
                     for (stamps, n, met), t_hand
                     in zip(finishes, hand_ends)]
         with self.cond:
-            self.hop_ema_s = dur if self.hop_ema_s == 0.0 \
-                else 0.7 * self.hop_ema_s + 0.3 * dur
+            self.hop_ema_s = _ema(self.hop_ema_s, dur)
             tick_done = self.tick_no
             seated_riders = list(self.seated.values())
         # pump-thread-only state (see __init__)
         self._last_tick_end = time.perf_counter()  # nebulint: disable=lock-discipline
+        # the branch the device took, for the hops whose info the
+        # session has read since the last record: the fetch reads
+        # it where it has just waited (tpu/runtime.py _LaneFetch),
+        # this call takes in what else is ready.  Never a wait
+        hop_reads, hop_sparse, hop_slots, hop_onesided, hop_swept = \
+            sess.hop_reads() if busy else (0, 0, 0, 0, 0)
+        # what the next tick's hold reads (_hold): which branch ran,
+        # and where the fetch of the cohort before this tick's hop
+        # came back from the device
+        flight_now = self._flight
+        flight_now.read(hop_reads, hop_sparse)
+        if pending is not None:
+            ta, _t_count, t_wait = finishes[0][0][:3]
+            flight_now.fetched(ta[0], t_wait[0], hop_enqueued,
+                               hop_reads > 0)
+        if flushed:
+            flight_now.idle()
         if busy:
-            # the branch the device took, for the hops whose info the
-            # session has read since the last record: the fetch reads
-            # it where it has just waited (tpu/runtime.py _LaneFetch),
-            # this call takes in what else is ready.  Never a wait
-            hop_reads, hop_sparse, hop_slots, hop_onesided, hop_swept = \
-                sess.hop_reads()
             # per cohort: start, (count end,) fetch_wait end, d2h end,
             # unpack end, rows end, handover end
             for (ta, _t_count, *ends), _n, _met in finishes:
@@ -1122,6 +1378,7 @@ class _ContinuousStream:
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
+                hold_joins=hold_joins,
                 leaves=len(leavers), evictions=len(evicted),
                 **host.fields(),
                 join_map_us=join_map_us, join_pack_us=join_pack_us,
@@ -1129,8 +1386,8 @@ class _ContinuousStream:
                 - join_pack_us,
                 assemble_us=sum(host.wall(p) for p in _ASSEMBLE_PARTS),
                 # the bookkeeping between the stamps (ledger release,
-                # stats.observe, the journal): with it the ten parts
-                # tile dur_us
+                # stats.observe, the journal): with it the eleven
+                # parts tile dur_us
                 other_us=dur_us - sum(host.wall(p)
                                       for p in PUMP_PHASES),
                 **hostclock.host_fields("", tick_cpu_us, tick_runq_us),
@@ -1160,15 +1417,18 @@ class _ContinuousStream:
                        "tick_delay" if self.tick_delay_s > 0 else
                        "loop")
                 self._emit_pump_trace(
-                    riders, (t0, t_seat, t_end), finishes, idle_us, why,
+                    riders, (t0, t_door, t_seat, t_end), finishes,
+                    idle_us, why,
                     {p + "_us": host.wall(p) for p in _ENQUEUE_PARTS},
+                    hold_joins,
                     tick=tick_done, rec=rec_id, seats=occupancy,
                     joins=len(joiners), leaves=len(leavers))
         return new_pending
 
     def _emit_pump_trace(self, riders: List[int], tick_stamps,
                          finishes, idle_us: float, why: str,
-                         enqueues: Dict[str, int], **tags) -> None:
+                         enqueues: Dict[str, int], hold_joins: int,
+                         **tags) -> None:
         """The tick just ended, as a trace of its own, post hoc from
         the stamps the tick took anyway: root pump.tick,
         children that tile it in pump order (they lie inside it and do
@@ -1182,12 +1442,15 @@ class _ContinuousStream:
         did in its stretch beside the wall: ``cpu_us`` it ran,
         ``runq_us`` it was runnable without a core (left off where the
         machine has no such clock); pump.enqueue also the walls of the
-        four enqueues it is made of (``enqueues``).  ``finishes``
+        four enqueues it is made of (``enqueues``); pump.hold, which
+        heads a tick that held its door (_hold) and no other, the
+        riders seated that arrived meanwhile (``hold_joins``).
+        ``finishes``
         holds, per finished cohort, _finish's stamps plus the one its
         handover ran to, the leavers handed their frontier and what
         its unpack met.  Only called for a tick that touched a traced
         rider."""
-        t0, t_seat, t_end = tick_stamps
+        t0, t_door, t_seat, t_end = tick_stamps
         # ONE wall-minus-perf offset for the whole tick: the spans land
         # on the now_micros() clock every other span uses
         off = now_micros() - time.perf_counter() * 1e6
@@ -1209,7 +1472,11 @@ class _ContinuousStream:
         def ran(a, b) -> Dict[str, int]:
             return hostclock.span_fields("", a, b)
 
-        tracing.emit("pump.seat", *at(t0, t_seat), **ran(t0, t_seat))
+        if t_door is not t0:
+            tracing.emit("pump.hold", *at(t0, t_door), **ran(t0, t_door),
+                         joins=hold_joins)
+        tracing.emit("pump.seat", *at(t_door, t_seat),
+                     **ran(t_door, t_seat))
         t_enq = finishes[0][0][0] if finishes else t_end   # first ta
         tracing.emit("pump.enqueue", *at(t_seat, t_enq),
                      **ran(t_seat, t_enq), **enqueues)

@@ -52,7 +52,7 @@ def _flip32(v: np.ndarray) -> np.ndarray:
 
 
 def _flip64(v: np.ndarray) -> np.ndarray:
-    return (v.astype(np.uint64) + _S64) & np.uint64(0xFFFFFFFFFFFFFFFF)
+    return v.astype(np.uint64, copy=False) + _S64   # wraps mod 2^64
 
 
 def _parts_of(vids: np.ndarray, nparts: int) -> np.ndarray:
@@ -72,6 +72,9 @@ def _frames_varlen(keys: np.ndarray, blobs: List[bytes],
     klen = keys.dtype.itemsize
     blob_len = np.asarray([len(b) for b in blobs], np.int64)
     val_idx = np.asarray(val_idx, np.int64)
+    if m and _one_length(blob_len):
+        return _frames_one_length(
+            keys, blobs, val_idx, int(blob_len[0]) if len(blobs) else 0)
     vlen = blob_len[val_idx] if len(blobs) else np.zeros(m, np.int64)
     off = np.zeros(m + 1, np.int64)
     np.cumsum(8 + klen + vlen, out=off[1:])
@@ -104,6 +107,34 @@ def _frames_varlen(keys: np.ndarray, blobs: List[bytes],
         for i in range(L):
             buf[rb + i] = rv[:, i]
     return buf, off
+
+
+def _one_length(blob_len: np.ndarray) -> bool:
+    return not len(blob_len) or blob_len.min() == blob_len.max()
+
+
+def _frames_one_length(keys: np.ndarray, blobs: List[bytes],
+                       val_idx: np.ndarray, vlen: int
+                       ) -> Tuple[np.ndarray, np.ndarray]:
+    """_frames_varlen where every value has ``vlen`` bytes (a schema
+    of fixed-width columns: every bulk load the benchmark makes): the
+    frames are the rows of ONE [m, 8 + klen + vlen] byte matrix, filled
+    a field at a time by four strided copies where the mixed-length
+    form scatters a byte column at a time, 8 + klen + vlen passes over
+    an index array (13 of the 44 s of a 16 M-edge load's frames on the
+    v5e's host, PERF.md section 6, PR 41).  The same bytes in the same
+    order."""
+    m = len(keys)
+    klen = keys.dtype.itemsize
+    head = np.frombuffer(np.array([klen, vlen], ">u4").tobytes(), np.uint8)
+    frames = np.empty((m, 8 + klen + vlen), np.uint8)
+    frames[:, :8] = head
+    frames[:, 8:8 + klen] = keys.view(np.uint8).reshape(m, klen)
+    if vlen:
+        frames[:, 8 + klen:] = np.frombuffer(
+            b"".join(blobs), np.uint8).reshape(len(blobs), vlen)[val_idx]
+    return frames.reshape(-1), \
+        np.arange(m + 1, dtype=np.int64) * frames.shape[1]
 
 
 def _split_by_part(parts: np.ndarray, nparts: int, buf: np.ndarray,
@@ -149,38 +180,29 @@ def edge_frames(nparts: int, etype: int, src: np.ndarray, dst: np.ndarray,
     ver = inverted_version() if version is None else version
     owner = np.concatenate([src, dst])
     other = np.concatenate([dst, src])
-    ets = np.concatenate([np.full(m, etype, np.int64),
-                          np.full(m, -etype, np.int64)])
-    rank2 = np.concatenate([rank, rank])
     vidx2 = np.concatenate([np.asarray(val_idx, np.int64)] * 2)
-    parts = _parts_of(owner, nparts)
+    n2 = 2 * m
     # storage-key order == tuple order of the sign-flipped fields.
     # Common case (non-negative vids fitting 28 bits, tiny etype ids,
     # constant rank): one packed-u64 argsort instead of a 5-key
     # lexsort — the lexsort's per-key passes dominated frame build at
-    # 10^8 rows
-    order = None
-    if m and (rank2 == rank2[0]).all():
-        et_vals = np.unique(ets)
-        vmax = max(int(owner.max()), int(other.max())) if m else 0
-        vmin = min(int(owner.min()), int(other.min())) if m else 0
-        bw = max(vmax.bit_length(), 1)
-        be = max(len(et_vals).bit_length(), 1)
-        bp = max(int(nparts).bit_length() + 1, 1)
-        if vmin >= 0 and bp + bw + be + bw <= 64:
-            et_idx = np.searchsorted(et_vals, ets).astype(np.uint64)
-            key = ((parts.astype(np.uint64) << np.uint64(bw + be + bw))
-                   | (owner.astype(np.uint64) << np.uint64(be + bw))
-                   | (et_idx << np.uint64(bw))
-                   | other.astype(np.uint64))
-            order = np.argsort(key, kind="stable")
-    if order is None:
+    # 10^8 rows — and the sorted fields read back off the sorted key
+    # by shifts, where each was a gather of its own
+    packed = _packed_order(nparts, etype, owner, other, m) \
+        if m and (rank == rank[0]).all() else None
+    if packed is not None:
+        order, parts, owner, ets, other = packed
+        rank2 = np.full(n2, rank[0], np.int64)
+    else:
+        ets = np.concatenate([np.full(m, etype, np.int64),
+                              np.full(m, -etype, np.int64)])
+        rank2 = np.concatenate([rank, rank])
+        parts = _parts_of(owner, nparts)
         order = np.lexsort((_flip64(other), _flip64(rank2),
                             _flip32(ets), _flip64(owner), parts))
-    owner, other = owner[order], other[order]
-    ets, rank2, vidx2 = ets[order], rank2[order], vidx2[order]
-    parts = parts[order]
-    n2 = len(owner)
+        owner, other = owner[order], other[order]
+        ets, rank2, parts = ets[order], rank2[order], parts[order]
+    vidx2 = vidx2[order]
     keys = np.zeros(n2, dtype=_EDGE_KEY)
     keys["part"] = _flip32(parts)
     keys["src"] = _flip64(owner)
@@ -190,6 +212,42 @@ def edge_frames(nparts: int, etype: int, src: np.ndarray, dst: np.ndarray,
     keys["ver"] = _flip64(np.full(n2, ver, np.int64))
     buf, off = _frames_varlen(keys, blobs, vidx2)
     return _split_by_part(parts, nparts, buf, off)
+
+
+def _packed_order(nparts: int, etype: int, owner: np.ndarray,
+                  other: np.ndarray, m: int):
+    """edge_frames' order where (part, owner, etype, other) pack into
+    64 bits: (the stable argsort of the packed key; part, owner, etype
+    and other in that order, int64, read off the sorted key), or None
+    where a vid is negative or the fields do not fit."""
+    et_vals = np.unique(np.array([etype, -etype], np.int64))
+    vmax = max(int(owner.max()), int(other.max()))
+    vmin = min(int(owner.min()), int(other.min()))
+    bw = max(vmax.bit_length(), 1)
+    be = max(len(et_vals).bit_length(), 1)
+    bp = max(int(nparts).bit_length() + 1, 1)
+    if vmin < 0 or bp + bw + be + bw > 64:
+        return None
+    u = np.uint64
+    # id_hash is the unsigned modulo: in 32 bits where the vids fit
+    # them, which is four times as fast
+    small = np.uint32 if vmax < (1 << 32) else np.uint64
+    owner_u = owner.astype(u)
+    key = (owner.astype(small) % small(nparts)).astype(u) + u(1)
+    key <<= u(bw + be + bw)
+    key |= owner_u << u(be + bw)
+    et_idx = np.searchsorted(et_vals, [etype, -etype]).astype(u)
+    key[:m] |= et_idx[0] << u(bw)
+    key[m:] |= et_idx[1] << u(bw)
+    key |= other.astype(u)
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    mask = u((1 << bw) - 1)
+    return (order,
+            (key >> u(bw + be + bw)).astype(np.int64),
+            ((key >> u(be + bw)) & mask).astype(np.int64),
+            et_vals[((key >> u(bw)) & u((1 << be) - 1)).astype(np.int64)],
+            (key & mask).astype(np.int64))
 
 
 def vertex_frames(nparts: int, tag_id: int, vids: np.ndarray,

@@ -161,3 +161,68 @@ def test_bulk_load_stages_bounded_files(cluster, tmp_path, monkeypatch):
         assert r.ok()
         assert sorted(x[0] for x in r.rows) == \
             sorted(d for s, d in pairs if s == v)
+
+
+@pytest.mark.parametrize("vlen", [0, 9, 13])
+def test_frames_of_one_length_are_the_scattered_frames(vlen, monkeypatch):
+    """Values of one length take the row-matrix path
+    (_frames_one_length); the byte-column scatters of the mixed-length
+    path, forced here by hiding the short cut, build the same buffers,
+    cut at the same rows."""
+    rng = np.random.default_rng(11)
+    m = 500
+    src = rng.integers(1, 90, m)
+    dst = rng.integers(1, 90, m)
+    blobs = [bytes(rng.integers(0, 256, vlen, dtype=np.uint8))
+             for _ in range(5)] if vlen else []
+    idx = rng.integers(0, 5, m) if vlen else np.zeros(m, np.int64)
+    fast = BL.edge_frames(4, 7, src, dst, blobs, idx, version=123)
+    monkeypatch.setattr(BL, "_one_length", lambda blob_len: False)
+    slow = BL.edge_frames(4, 7, src, dst, blobs, idx, version=123)
+    assert fast.keys() == slow.keys()
+    for part in fast:
+        assert len(fast[part]) == len(slow[part])
+        for a, b in zip(fast[part], slow[part]):
+            assert a.dtype == b.dtype == np.uint8
+            assert np.array_equal(a, b)
+
+
+def test_frames_of_mixed_lengths_keep_the_scatter_path():
+    rng = np.random.default_rng(12)
+    m = 300
+    src = rng.integers(1, 60, m)
+    dst = rng.integers(1, 60, m)
+    blobs = [b"ab", b"cdefg", b"", b"hij"]
+    idx = rng.integers(0, 4, m)
+    frames = BL.edge_frames(3, 5, src, dst, blobs, idx, version=9)
+    total = sum(v.nbytes for vs in frames.values() for v in vs)
+    klen = BL._EDGE_KEY.itemsize
+    lens = np.array([len(b) for b in blobs])[idx]
+    assert total == 2 * int((8 + klen + lens).sum())
+
+
+@pytest.mark.parametrize("lo,hi,nparts", [(0, 60, 4), (1, 1 << 20, 3),
+                                          (1 << 33, 1 << 34, 16)])
+def test_the_packed_order_is_the_lexsort_order(lo, hi, nparts,
+                                               monkeypatch):
+    """Where part, owner, etype and other pack into 64 bits the frames
+    are sorted by one argsort and the sorted fields read back off the
+    key (_packed_order); the five-key lexsort and a gather a field,
+    which negative vids and ranks that differ still take, give the
+    same buffers: duplicates of one edge keep their order (both sorts
+    are stable), vids over 32 bits take the 64-bit modulo."""
+    rng = np.random.default_rng(13)
+    m = 3000
+    src = rng.integers(lo, hi, m)
+    dst = rng.integers(lo, hi, m)
+    src[:4], dst[:4] = src[4], dst[4]           # one edge five times
+    blobs = [b"123456789", b"abcdefghi", b"ABCDEFGHI"]
+    idx = rng.integers(0, 3, m)
+    packed = BL.edge_frames(nparts, 7, src, dst, blobs, idx, version=5)
+    monkeypatch.setattr(BL, "_packed_order", lambda *a: None)
+    plain = BL.edge_frames(nparts, 7, src, dst, blobs, idx, version=5)
+    assert packed.keys() == plain.keys()
+    for part in packed:
+        assert len(packed[part]) == len(plain[part])
+        for a, b in zip(packed[part], plain[part]):
+            assert np.array_equal(a, b)

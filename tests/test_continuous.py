@@ -330,26 +330,64 @@ class TestServingSemantics:
         assert joins > 0
         assert joins == leaves + evics, (joins, leaves, evics)
 
-    def test_write_fresh_generation_reanchors(self, nba):
+    @pytest.mark.parametrize("holding", [False, True],
+                             ids=["plain", "hold-in-progress"])
+    def test_write_fresh_generation_reanchors(self, nba, monkeypatch,
+                                              holding):
         """Read-your-writes across the stream: a write that publishes
         a new mirror generation must be visible to the next continuous
         query (the pump re-anchors instead of serving the stale
-        resident tables)."""
+        resident tables).  With a hold in progress too (the pump
+        holds its door 50 ms a tick while other statements ride): the
+        generation check comes after the hold, so whoever the hold
+        let in is checked like anybody else."""
+        from nebula_tpu.common import flight
+        from nebula_tpu.graph import batch_dispatch
         c, g, ok = nba
         before = sorted(map(tuple,
                             ok("GO 2 STEPS FROM 1 OVER e "
                                "YIELD e._dst").rows))
-        ok("INSERT EDGE e(w) VALUES 1 -> 39:(1), 39 -> 38:(2)")
-        deadline = time.monotonic() + 10.0
-        after = None
-        while time.monotonic() < deadline:
-            after = sorted(map(tuple,
-                               ok("GO 2 STEPS FROM 1 OVER e "
-                                  "YIELD e._dst").rows))
-            if (38,) in after:
-                break
-            time.sleep(0.1)
-        assert after is not None and (38,) in after, (before, after)
+        stop = threading.Event()
+        riders = []
+        if holding:
+            # CPU jax's hops take no time: say the device has 100 ms
+            # of its hop left whenever one is in flight
+            monkeypatch.setattr(batch_dispatch._HopInFlight, "slack",
+                                lambda self, now, carried: 0.1)
+            flight.recorder.clear_for_tests()
+
+            def ride_on():
+                g2 = c.client()
+                g2.execute("USE s")
+                while not stop.is_set():
+                    g2.execute("GO 4 STEPS FROM 2 OVER e YIELD e._dst")
+
+            riders = [threading.Thread(target=ride_on) for _ in range(2)]
+            [t.start() for t in riders]
+            time.sleep(0.2)
+        try:
+            # vertices of its own for each case: the graph has 1..40
+            via, end = (139, 138) if holding else (39, 38)
+            assert (end,) not in before
+            ok(f"INSERT EDGE e(w) VALUES 1 -> {via}:(1), "
+               f"{via} -> {end}:(2)")
+            deadline = time.monotonic() + 10.0
+            after = None
+            while time.monotonic() < deadline:
+                after = sorted(map(tuple,
+                                   ok("GO 2 STEPS FROM 1 OVER e "
+                                      "YIELD e._dst").rows))
+                if (end,) in after:
+                    break
+                time.sleep(0.1)
+        finally:
+            stop.set()
+            [t.join() for t in riders]
+        assert after is not None and (end,) in after, (before, after)
+        if holding:
+            held = [r for r in flight.recorder.dump(limit=4096)
+                    if r["kind"] == "tick" and r["hold_us"] > 0]
+            assert held, "no tick held its door"
 
     def test_metrics_surface(self, nba):
         """graph.continuous.* and the idle-frac gauges render in the
@@ -565,3 +603,519 @@ class TestServingSemantics:
         r = ok("GO 2 STEPS FROM 1 OVER e2 YIELD e2._dst")
         assert r.rows == [] or list(r.rows) == []
         ok("USE s")
+
+
+# ===================================================== the hold
+# (graph/batch_dispatch.py _ContinuousStream._hold): a stream driven
+# over a session stub whose "device" runs one hop after the other,
+# each for a fixed time, and whose count blocks until the hop it sits
+# behind has ended — the pace of a cell in which the device, not the
+# host, is what a tick waits for.  No jax, no cluster.
+from nebula_tpu.graph import batch_dispatch as bd  # noqa: E402
+from nebula_tpu.graph.query_registry import (  # noqa: E402
+    KilledError, bind as bind_qid, registry as query_registry)
+
+STUB_SPACE = 1
+STUB_ET = (1,)
+
+
+class _StubCount:
+    """The per-lane count's resolver: waits for the hop it was
+    enqueued behind (tpu/runtime.py _LaneCount)."""
+
+    def __init__(self, sess, ends_at, lanes):
+        self.sess, self.ends_at, self.lanes = sess, ends_at, lanes
+        self.t_done = None
+
+    def __call__(self):
+        left = self.ends_at - time.perf_counter()
+        if left > 0:
+            time.sleep(left)
+        self.sess.read_hop_info()
+        self.t_done = bd.hostclock.stamp()
+        return [7] * len(self.lanes)
+
+
+class _StubSession:
+    """_ContinuousGoSession's surface over a simulated device queue:
+    a hop starts when it is enqueued or when the one before it ends,
+    whichever is later, and takes ``hop_s`` (a pull) or ``push_s``
+    where ``rt.pushes`` says so."""
+    B = 16
+
+    def __init__(self, rt):
+        self.rt, self.m = rt, rt.mirrors[STUB_SPACE]
+        self.free_at = 0.0
+        self.fp = self
+        self.join_marks = None
+        self._info = []                 # (ready_at, pushed)
+        self._ends = []                 # when the last two hops end
+        self._read = [0, 0]
+        self.hops = 0
+
+    def block_until_ready(self):
+        left = self.free_at - time.perf_counter()
+        if left > 0:
+            time.sleep(left)
+
+    def join(self, joiners):
+        t = time.perf_counter()
+        self.join_marks = (t, t)
+
+    def hop(self):
+        pushed = self.rt.pushes(self.hops)
+        self.rt.pushed.append(pushed)
+        self.hops += 1
+        # the device's queue pushes back: one hop waits behind the
+        # one that runs, the enqueue of a third blocks
+        if len(self._ends) >= 2:
+            left = self._ends[-2] - time.perf_counter()
+            if left > 0:
+                time.sleep(left)
+        start = max(time.perf_counter(), self.free_at)
+        self.free_at = start + (self.rt.push_s if pushed
+                                else self.rt.hop_s)
+        self._info.append((self.free_at, pushed))
+        self._ends = self._ends[-1:] + [self.free_at]
+
+    def read_hop_info(self):
+        now = time.perf_counter()
+        while self._info and self._info[0][0] <= now:
+            self._read[0] += 1
+            self._read[1] += int(self._info.pop(0)[1])
+
+    def hop_reads(self):
+        self.read_hop_info()
+        reads, sparse = self._read
+        self._read = [0, 0]
+        return reads, sparse, 0, reads, 0
+
+    def count(self, lanes):
+        return _StubCount(self, self.free_at, list(lanes))
+
+    def clear(self, lanes):
+        pass
+
+
+class _StubMirror:
+    def __init__(self, generation):
+        self.generation = generation
+
+
+class _StubRuntime:
+    def __init__(self, hop_s, push_s=0.0, pushes=lambda i: False):
+        self.hop_s, self.push_s, self.pushes = hop_s, push_s, pushes
+        self.mirrors = {STUB_SPACE: _StubMirror(1)}
+        self.sessions = []
+        self.pushed = []                # hop by hop, what it did
+
+    def mirror(self, space_id):
+        return self.mirrors[space_id]
+
+    def continuous_session(self, space_id, et_tuple, min_lanes=1):
+        self.sessions.append(_StubSession(self))
+        return self.sessions[-1]
+
+    def rider_assembles(self, m, payload, et_tuple):
+        return True
+
+    def count_distinct_results(self, counts, hops):
+        return [(["__count__"], [[int(c)]]) for c in counts]
+
+
+class _StubPayload:
+    start_vids = (1,)
+
+
+class _StubStream:
+    """One stream of a real dispatcher over the stub, and the callers
+    that ride it: each statement a k-hop neighbourhood count (the one
+    reduction whose leaver needs no frontier).  ``ride`` returns the
+    tags of the statement's graph.continuous marker, which its own
+    thread annotates (joined_tick, left_tick, the waits)."""
+
+    def __init__(self, monkeypatch, hop_s, **kw):
+        self.rt = _StubRuntime(hop_s, **kw)
+        self.disp = bd.GoBatchDispatcher(self.rt)
+        self.st = self.disp.continuous._stream(STUB_SPACE, STUB_ET)
+        self.threads = []
+        self.errors = []
+        self._tags = threading.local()
+        real = bd.tracing.annotate
+
+        def note(name, **tags):
+            if name == "graph.continuous":
+                self._tags.last = tags
+            return real(name, **tags)
+
+        monkeypatch.setattr(bd.tracing, "annotate", note)
+
+    def ride(self, hops, qid=None, traced=False):
+        key = ("go_batch_execute", STUB_SPACE, STUB_ET, hops, False,
+               ("count_distinct",))
+        self._tags.last = None
+        with bind_qid(qid), bd.tracing.start_trace("graph.query",
+                                                   forced=traced):
+            try:
+                self.disp.continuous.submit(key, _StubPayload())
+            finally:
+                tags = self._tags.last
+        return tags
+
+    def start(self, fn, *args):
+        def run():
+            try:
+                fn(*args)
+            except Exception as ex:     # noqa: BLE001 — reported
+                self.errors.append(ex)
+        t = threading.Thread(target=run, daemon=True)
+        self.threads.append(t)
+        t.start()
+        return t
+
+    def caller(self, hops, n, think_s, out, traced=False):
+        """A closed-loop caller: ``n`` statements, ``think_s`` between
+        an answer and the next statement."""
+        def loop():
+            for _ in range(n):
+                out.append(self.ride(hops, traced=traced))
+                time.sleep(think_s)
+        return self.start(loop)
+
+    def outlasts(self, hops):
+        """A statement the test does not wait for: the stream's stop
+        ends it."""
+        def loop():
+            try:
+                self.ride(hops)
+            except RuntimeError as ex:
+                assert "stopped" in str(ex), ex
+        return self.start(loop)
+
+    def seated_long(self):
+        """A rider that outlasts the test: somebody stays seated, and
+        the hop in flight carries on into the next."""
+        self.outlasts(10_000)
+        self.until(lambda: self.st.seated)
+
+    def until(self, cond, timeout_s=10.0):
+        end = time.monotonic() + timeout_s
+        while not cond():
+            assert time.monotonic() < end, "stub stream stood still"
+            time.sleep(0.001)
+
+    def ticks(self):
+        """The stream's tick records, oldest first."""
+        from nebula_tpu.common import flight
+        return [r for r in reversed(flight.recorder.dump(limit=4096))
+                if r["kind"] == "tick" and r["stream"] == STUB_SPACE]
+
+    def close(self):
+        self.disp.continuous.shutdown(timeout_s=5.0)
+        for t in self.threads:
+            t.join(5.0)
+
+
+@pytest.fixture
+def stub(monkeypatch):
+    from nebula_tpu.common import flight
+    flight.recorder.clear_for_tests()
+    made = []
+
+    def make(hop_s, **kw):
+        made.append(_StubStream(monkeypatch, hop_s, **kw))
+        return made[-1]
+
+    yield make
+    for s in made:
+        s.close()
+        assert not s.errors, s.errors
+
+
+def _paced(s, hop_s):
+    """Ride short statements until the stream has timed a pull from an
+    observed start to the blocked fetch behind it: the estimate the
+    hold is read off."""
+    s.seated_long()
+    # four statements that leave at four ticks in a row: the fetch of
+    # each cohort blocks until its hop has ended
+    warm = [s.start(s.ride, hops) for hops in (1, 2, 3, 4)]
+    for t in warm:
+        t.join(30.0)
+    assert not s.errors, s.errors
+    est = s.st._flight.hop_s[False]
+    assert 0.8 * hop_s <= est <= 1.5 * hop_s, est
+
+
+class TestHold:
+    HOP_S = 0.08
+
+    @pytest.mark.parametrize("hold", [True, False],
+                             ids=["hold", "no-hold"])
+    def test_a_caller_back_in_time_rides_the_next_hop(
+            self, stub, monkeypatch, hold):
+        """(a) a device-paced stream (a hop takes 80 ms, the count
+        behind it blocks that long): two callers, each back 10 ms
+        after its answer.  The hop enqueued before the answer existed
+        is lost to the caller either way; with the hold it rides the
+        one after (seated in the tick after the one that handed it its
+        answer: joined_tick = left_tick + 1, its first hop is
+        left_tick + 2), and without, the door of that tick shuts
+        before it is back and it sits out a whole hop more."""
+        if not hold:
+            monkeypatch.setattr(bd, "HOLD_FLOOR_S", float("inf"))
+        s = stub(self.HOP_S)
+        if hold:
+            _paced(s, self.HOP_S)
+        else:
+            s.seated_long()
+        a, b = [], []
+        ta = s.caller(1, 7, 0.010, a)
+        # the second caller a tick behind the first
+        s.until(lambda: a or s.st.tick_no >= 2)
+        tb = s.caller(1, 7, 0.010, b)
+        ta.join(30.0)
+        tb.join(30.0)
+        assert len(a) == len(b) == 7 and not s.errors, s.errors
+        gaps = [nxt["joined_tick"] - prev["left_tick"]
+                for ms in (a, b) for prev, nxt in zip(ms[2:], ms[3:])]
+        ticks = s.ticks()
+        if hold:
+            assert gaps and all(g == 1 for g in gaps), (gaps, a, b)
+            held = [t for t in ticks if t["hold_us"] > 0]
+            assert len(held) >= 8
+            # never past the hop in flight: the device did not run dry
+            assert all(t["hold_us"] < self.HOP_S * 1e6 * 0.75
+                       for t in held), held
+            assert sum(t["hold_joins"] for t in ticks) >= len(gaps)
+        else:
+            assert gaps and all(g >= 2 for g in gaps), (gaps, a, b)
+            assert all(t["hold_us"] == 0 and t["hold_joins"] == 0
+                       for t in ticks)
+
+    @pytest.mark.parametrize("how", ["stop", "generation", "kill",
+                                     "lanes-full"])
+    def test_what_ends_a_hold_ends_it_at_once(self, stub, how):
+        """(c) a hold of ~200 ms in progress (a hop of 400 ms): the
+        stream's stop, a mirror generation the runtime published, a
+        KILL QUERY of a seated rider and a taker for every free lane
+        each end it well before its time; the generation check that
+        follows seats nobody on the old session, the killed rider
+        leaves at this tick's boundary."""
+        hop_s = 0.4
+        s = stub(hop_s)
+        st = s.st
+        if how == "kill":
+            qid = query_registry.register("GO 10000 STEPS")
+            ended = []
+
+            def victim():
+                try:
+                    s.ride(10_000, qid=qid)
+                except KilledError as ex:
+                    ended.append(ex)
+            s.start(victim)
+            s.until(lambda: st.seated)
+        _paced(s, hop_s)
+        # a statement whose answer starts a tick: that tick holds
+        s.ride(1)
+        t_handed = time.perf_counter()
+        s.until(lambda: st._flight.began > 0)
+        time.sleep(0.03)                # the hold is in progress
+        n_ticks = len(s.ticks())
+        if how == "stop":
+            s.disp.continuous.shutdown(timeout_s=5.0)
+        elif how == "generation":
+            s.rt.mirrors[STUB_SPACE] = _StubMirror(2)
+            s.outlasts(1)               # the writer's next read
+        elif how == "kill":
+            assert query_registry.kill(qid)
+        else:
+            free = st.ledger.free_count()
+            for _ in range(free):
+                s.start(s.ride, 1)
+        s.until(lambda: len(s.ticks()) > n_ticks or st.stopping)
+        if how == "stop":
+            assert time.perf_counter() - t_handed < 0.15
+            return
+        rec = s.ticks()[n_ticks]
+        # it held, and gave up long before half of the 400 ms
+        assert 20_000 <= rec["hold_us"] <= 120_000, rec
+        if how == "generation":
+            s.until(lambda: st.draining)
+            assert rec["joins"] == 0
+        elif how == "kill":
+            assert rec["evictions"] == 1
+            s.until(lambda: ended)
+            query_registry.unregister(qid)
+        else:
+            assert rec["joins"] == rec["hold_joins"] == free
+
+    def test_a_pull_predicted_where_the_device_pushes_costs_one_hold(
+            self, stub):
+        """(e) the branch is the device's choice: after pulls of 80 ms
+        the hops push, 2 ms each.  The first tick after a pull still
+        expects a pull and holds; the report of the push reaches the
+        next tick and the estimate follows: no hold while the hops
+        push (a push never passes the floor), and the pull's estimate
+        is kept for when the pulls are back."""
+        flips = []
+        s = stub(self.HOP_S, push_s=0.002,
+                 pushes=lambda i: bool(flips) and i >= flips[0])
+        _paced(s, self.HOP_S)
+        out = []
+        flips.append(s.rt.sessions[-1].hops + 1)
+        n0 = len(s.ticks())
+        s.caller(1, 12, 0.003, out).join(30.0)
+        assert len(out) == 12
+        ticks = s.ticks()[n0:]
+        pushed = [i for i, t in enumerate(ticks) if t["hop_sparse"]]
+        assert pushed, ticks
+        # from the tick after the first push's report on, no hold
+        late = ticks[pushed[0] + 1:]
+        assert len(late) >= 6
+        assert all(t["hold_us"] == 0 for t in late), late
+        # the mistake: a hold while the hop in flight (the one before
+        # the hop this tick enqueued, record ``tick``) pushed
+        wrong = [t for t in ticks if t["hold_us"] > 0
+                 and s.rt.pushed[t["tick"] - 2]]
+        assert len(wrong) == 1, wrong
+        fl = s.st._flight
+        assert fl.pushed is True
+        assert fl.hop_s[True] < 0.02
+        # pushes moved the pulls' estimate by nothing
+        assert 0.8 * self.HOP_S <= fl.hop_s[False] <= 1.5 * self.HOP_S
+
+
+class TestHopInFlight:
+    """The estimate the hold is read off, by hand (no thread, no
+    clock): times in seconds on an invented perf_counter."""
+
+    def _paced(self, fl, t=100.0, hop=0.05):
+        """Three hops back to back, each enqueued 5 ms after the fetch
+        before it came back, each fetch blocked until its hop ended."""
+        fl.enqueued(t, t + 0.002)        # device idle
+        fl.read(0, 0)
+        end = t + 0.002 + hop
+        for _ in range(3):
+            fl.enqueued(end - hop + 0.004, end - hop + 0.006)
+            fl.read(1, 0)                           # the one that ended pulled
+            fl.fetched(end - 0.04, end, True, True)
+            end += hop
+        return end - hop                            # the last fetch's end
+
+    def test_a_pull_timed_from_its_observed_start(self):
+        fl = bd._HopInFlight()
+        t_w = self._paced(fl)
+        assert fl.pushed is False and fl.seen
+        assert fl.began == t_w
+        assert abs(fl.hop_s[False] - 0.05) < 1e-6
+        assert fl.hop_s[True] == 0.0
+        assert 0.0015 < fl.turn_s < 0.0025
+        # 4 ms into the hop in flight: 50 - 4 - 2 of turn-around
+        assert abs(fl.slack(t_w + 0.004, True) - 0.044) < 1e-3
+        # first hops alone are taken for a push: nothing known of one
+        assert fl.slack(t_w + 0.004, False) == 0.0
+
+    @pytest.mark.parametrize("why", ["assumed-start", "not-blocked",
+                                     "branch-unread", "branches-mixed"])
+    def test_what_gives_no_sample(self, why):
+        fl = bd._HopInFlight()
+        t_w = self._paced(fl)
+        before = dict(fl.hop_s)
+        if why == "assumed-start":
+            # a tick without a cohort: the next hop is enqueued behind
+            # one nobody waited for, and began later than its enqueue
+            fl.enqueued(t_w + 0.004, t_w + 0.006)
+            fl.read(0, 0)
+            assert not fl.seen
+            fl.enqueued(t_w + 0.007, t_w + 0.009)
+            fl.read(1, 0)
+            fl.fetched(t_w + 0.01, t_w + 0.1, True, True)
+            assert fl.seen and fl.began == t_w + 0.1    # synced again
+        elif why == "not-blocked":
+            fl.enqueued(t_w + 0.06, t_w + 0.062)
+            fl.read(1, 0)
+            fl.fetched(t_w + 0.0621, t_w + 0.0622, True, True)
+            assert not fl.seen and fl.began == t_w + 0.062
+        elif why == "branch-unread":
+            fl.enqueued(t_w + 0.004, t_w + 0.006)
+            fl.read(0, 0)
+            fl.fetched(t_w + 0.01, t_w + 0.05, True, False)
+        else:
+            fl.enqueued(t_w + 0.004, t_w + 0.006)
+            fl.read(2, 1)
+            fl.fetched(t_w + 0.01, t_w + 0.05, True, True)
+            assert fl.pushed is None
+            assert fl.slack(t_w + 0.051, True) == 0.0
+        assert fl.hop_s == before
+
+    def test_a_stalled_fetch_counts_for_twice_the_estimate_at_most(self):
+        """The device (or the runtime) stands for 1.4 s behind a pull
+        of 50 ms: the estimate moves as for a sample of 100 ms, and
+        the next hold is still shorter than the hop."""
+        fl = bd._HopInFlight()
+        t_w = self._paced(fl)
+        fl.enqueued(t_w + 0.004, t_w + 0.006)
+        fl.read(1, 0)
+        fl.fetched(t_w + 0.01, t_w + 1.4, True, True)
+        assert abs(fl.hop_s[False] - (0.7 * 0.05 + 0.3 * 0.1)) < 1e-6
+        assert bd.HOLD_SHARE * fl.slack(t_w + 1.404, True) < 0.05
+
+    def test_a_hold_that_outlasted_its_hop_brings_the_estimate_down(self):
+        """An estimate far over the hop (however it came about): the
+        pump holds past the hop's end, so the fetch behind it does not
+        block and no sample comes; that the hop had ended by then is
+        itself a reading, and the estimate comes down to it, tick by
+        tick, until a fetch blocks again."""
+        fl = bd._HopInFlight()
+        t = self._paced(fl)                 # hop k began at t (seen)
+        fl.hop_s[False] = 0.5               # ten times the hop
+        holds = []
+        for _ in range(6):
+            hold = bd.HOLD_SHARE * max(fl.slack(t + 0.002, True), 0.0)
+            holds.append(hold)
+            e = t + 0.002 + hold + 0.002    # hop k+1 enqueued here
+            fl.enqueued(e - 0.002, e)
+            fl.read(1, 0)
+            end = fl.before[0] + 0.05       # when hop k really ended
+            if end <= e + 0.0005:           # before the pump asked
+                fl.fetched(e + 0.0005, e + 0.0006, True, True)
+                t = e                       # k+1 starts on an idle device
+            else:
+                fl.fetched(e + 0.0005, end, True, True)
+                t = end
+        assert holds[0] > 0.2               # the first outlasts its hop
+        assert holds[-1] < 0.05, holds      # and the last no longer
+        assert 0.04 < fl.hop_s[False] < 0.08, fl.hop_s
+
+    def test_a_pump_that_came_late_saw_its_own_lateness(self):
+        """A traced window while the profiler writes its trace: the
+        join and the enqueue take 100 ms of waiting for the
+        interpreter, the fetch another 15, and the time since the hop
+        began is the pump's period (260 ms), not the hop (50).  No
+        sample (the wait is a sliver of it), the hop behind it starts
+        where it was enqueued, the turn-around says how slow the pump
+        is, and there is nothing to hold for."""
+        fl = bd._HopInFlight()
+        t = self._paced(fl)
+        for _ in range(8):
+            e = t + 0.030 + 0.100           # door + join + enqueue
+            fl.enqueued(t + 0.030, e)
+            fl.read(1, 0)
+            fl.fetched(e + 0.001, e + 0.016, True, True)
+            assert not fl.seen and fl.began == e
+            t = e + 0.016
+        assert abs(fl.hop_s[False] - 0.05) < 1e-6
+        assert fl.turn_s > 0.08
+        assert fl.slack(t + 0.002, True) < 0.0
+
+    def test_a_flush_or_an_idle_stream_has_no_hop_in_flight(self):
+        fl = bd._HopInFlight()
+        t_w = self._paced(fl)
+        fl.fetched(t_w + 0.01, t_w + 0.05, False, True)  # its cohort, no new hop
+        assert fl.began == 0.0 and fl.slack(t_w + 0.051, True) == 0.0
+        assert abs(fl.hop_s[False] - 0.05) < 1e-6   # and it was a sample
+        self._paced(fl, t=200.0)
+        fl.idle()
+        assert fl.began == 0.0 and not fl.seen

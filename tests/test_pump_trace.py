@@ -27,12 +27,12 @@ from nebula_tpu.common.flags import flags
 from nebula_tpu.common.tracing import slow_log, trace_store
 from nebula_tpu.graph import batch_dispatch as bd
 
-from test_continuous import _boot_graph
+from test_continuous import _boot_graph, _paced, stub  # noqa: F401
 
 PARTS = ("fetch_wait_us", "d2h_us", "unpack_us", "rows_us",
          "handover_us")
 WAITS = tracing.RIDER_WAITS
-CHILDREN = {"pump.seat", "pump.enqueue", "pump.count", "pump.fetch_wait",
+CHILDREN = {"pump.hold", "pump.seat", "pump.enqueue", "pump.count", "pump.fetch_wait",
             "pump.d2h", "pump.unpack", "pump.rows", "pump.handover"}
 
 
@@ -118,6 +118,21 @@ def _ticks():
             if r["kind"] == "tick"]
 
 
+def _held_stream(stub, traced=False):
+    """A stream over a stub device that takes 60 ms a hop
+    (tests/test_continuous.py TestHold), two closed-loop callers on
+    it: most ticks hold their door.  Returns it once they are done."""
+    s = stub(0.06)
+    _paced(s, 0.06)
+    a, b = [], []
+    ta = s.caller(1, 5, 0.005, a, traced=traced)
+    tb = s.caller(2, 5, 0.005, b, traced=traced)
+    ta.join(30.0)
+    tb.join(30.0)
+    assert len(a) == len(b) == 5
+    return s
+
+
 # ===================================================== (a) the tick
 class TestTickTrace:
     def test_every_flight_tick_has_one_tiled_tree(self, graph):
@@ -175,6 +190,80 @@ class TestTickTrace:
             == sum(t["leaves"] for t in ticks) == 8
         assert all(t["handed"] == 0 for t in ticks
                    if t["assemble_us"] == 0)
+
+
+class TestHoldTrace:
+    """The hold in the pump's own records (graph/batch_dispatch.py
+    _hold): ``hold_us`` / ``hold_joins`` of the tick record, the
+    ``pump.hold`` child that heads a tick that held."""
+
+    @pytest.mark.parametrize("on", ["cluster", "stub"])
+    def test_a_fast_device_is_never_held_for(self, graph, stub, on):
+        """(b) a fetch that returns at once (CPU jax's hops; a stub
+        whose hops take no time): no tick holds, no span says so."""
+        c, g, ok = graph
+        flags.set("trace_sample_rate", 1.0)
+        if on == "cluster":
+            _burst(c, _mixed(10))
+            ticks = _ticks()
+        else:
+            s = stub(0.0)
+            s.seated_long()
+            out = []
+            ts = [s.caller(k, 6, 0.001, out, traced=True)
+                  for k in (1, 2, 3)]
+            [t.join(30.0) for t in ts]
+            assert len(out) == 18
+            ticks = s.ticks()
+        assert len(ticks) >= 3
+        assert all(t["hold_us"] == 0 and t["hold_joins"] == 0
+                   and t["hold_cpu_us"] == 0 for t in ticks)
+        roots = _pump_roots("pump.tick")
+        assert roots
+        assert not [k for r in roots for k in r["children"]
+                    if k["name"] == "pump.hold"]
+
+    def test_a_held_tick_has_one_pump_hold_at_its_head(self, graph,
+                                                       stub):
+        s = _held_stream(stub, traced=True)
+        s.until(lambda: len(s.st.seated) == 1 and not s.st.queue)
+        time.sleep(0.1)
+        ticks = {t["id"]: t for t in s.ticks()}
+        roots = [r for r in _pump_roots("pump.tick")
+                 if r["tags"]["rec"] in ticks]
+        assert roots
+        n_held = 0
+        for root in roots:
+            rec = ticks[root["tags"]["rec"]]
+            kids = root["children"]
+            assert {k["name"] for k in kids} <= CHILDREN
+            holds = [k for k in kids if k["name"] == "pump.hold"]
+            seat = [k for k in kids if k["name"] == "pump.seat"][0]
+            assert abs(seat["duration_us"] - rec["seat_us"]) <= 2
+            if rec["hold_us"] == 0:
+                assert not holds
+                assert abs(seat["start_us"] - root["start_us"]) <= 2
+                continue
+            n_held += 1
+            assert len(holds) == 1 and kids[0] is holds[0]
+            hold = holds[0]
+            assert abs(hold["duration_us"] - rec["hold_us"]) <= 2
+            assert hold["start_us"] == root["start_us"]
+            assert hold["tags"]["joins"] == rec["hold_joins"]
+            assert hold["tags"]["cpu_us"] == rec["hold_cpu_us"]
+            # the seat starts where the door shut
+            assert abs(seat["start_us"] - hold["start_us"]
+                       - hold["duration_us"]) <= 2
+            # the children still tile the tick
+            covered = sum(k["duration_us"] for k in kids)
+            assert covered >= 0.95 * root["duration_us"], (root, rec)
+        assert n_held >= 3
+        # the two stats count what the records count
+        from nebula_tpu.common.stats import stats
+        assert stats.read_stats("graph.continuous.hold_us.sum.600") \
+            >= sum(t["hold_us"] for t in ticks.values())
+        assert stats.read_stats("graph.continuous.held_joins.sum.600") \
+            >= sum(t["hold_joins"] for t in ticks.values())
 
 
 class TestUnpackCounters:
@@ -623,14 +712,30 @@ class TestHostClocks:
     the rider's marker say how long the thread ran and how long it was
     runnable without a core beside the wall."""
 
-    def test_the_parts_tile_the_tick_and_the_join(self, graph):
+    @pytest.mark.parametrize("held", [False, True],
+                             ids=["cluster", "held-stub"])
+    def test_the_parts_tile_the_tick_and_the_join(self, graph, stub,
+                                                  held):
+        """The eleven parts and ``other_us`` tile ``dur_us``: on the
+        cluster, where CPU jax's hops leave nothing to hold for, and
+        on a stream whose stub device takes 60 ms a hop, where most
+        ticks hold their door (tests/test_continuous.py TestHold)."""
         c, g, ok = graph
         flags.set("trace_sample_rate", 0.0)     # always on
-        _burst(c, _mixed(9))
-        ticks = _ticks()
+        assert len(PHASES) == 11 and PHASES[0] == "hold"
+        if held:
+            ticks = _held_stream(stub).ticks()
+            assert sum(t["hold_us"] > 0 for t in ticks) >= 5
+            assert sum(t["hold_joins"] for t in ticks) >= 5
+        else:
+            _burst(c, _mixed(9))
+            ticks = _ticks()
+            assert all(t["hold_us"] == 0 for t in ticks)
         assert len(ticks) >= 3
         has_runq = hostclock.stamp()[2] is not None
         for t in ticks:
+            assert 0 <= t["hold_joins"] <= t["joins"], t
+            assert t["hold_us"] > 0 or t["hold_joins"] == 0, t
             assert sum(t[p + "_us"] for p in PHASES) + t["other_us"] \
                 == t["dur_us"], t
             assert t["other_us"] >= 0
@@ -645,8 +750,9 @@ class TestHostClocks:
             assert t["cpu_us"] <= t["dur_us"] + 50, t
             if t["joins"] == 0:
                 assert t["join_us"] == 0 and t["join_cpu_us"] == 0
-        assert any(t["join_map_us"] > 0 for t in ticks)
-        assert any(t["join_enqueue_us"] > 0 for t in ticks)
+        if not held:                    # the stub's join is no work
+            assert any(t["join_map_us"] > 0 for t in ticks)
+            assert any(t["join_enqueue_us"] > 0 for t in ticks)
 
     def test_every_pump_child_says_what_the_thread_did(self, graph):
         c, g, ok = graph
