@@ -125,7 +125,10 @@ class FlightRecorder:
     def note_tick(self, stream: int, **fields) -> int:
         """One continuous-pump tick of the per-(space, OVER set)
         stream keyed ``stream``: seat churn counts (hold_joins: of the
-        joins, the riders that arrived while the tick held its door),
+        joins, the riders that arrived while the tick held its door;
+        seat_hops: of the joins, the riders whose seat took their first
+        hop, and join_rows, the rows the join scattered —
+        tpu/runtime.py _ContinuousGoSession.join),
         per-phase micros
         in pump order (hold: the door held open while the device was
         busy with the hop in flight, 0 on a tick that did not wait —

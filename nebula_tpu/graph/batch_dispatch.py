@@ -185,6 +185,9 @@ stats.register_histogram("graph.admission.wait_us")
 stats.register_stats("graph.continuous.joins")
 stats.register_stats("graph.continuous.leaves")
 stats.register_stats("graph.continuous.evictions")
+# of the joins, the riders whose seat took their first hop: the session
+# scattered their first frontier (_ContinuousGoSession.join)
+stats.register_stats("graph.continuous.seat_hops")
 # the pump's hold (_ContinuousStream._hold): the micros it held the
 # door of a tick open behind a busy device, and the riders it seated
 # that arrived meanwhile
@@ -632,8 +635,14 @@ class ContinuousUnavailable(Exception):
 
 class _Rider:
     """One query riding the continuous batch: queued until a lane
-    frees, seated for ``hops`` hop ticks, extracted at its last hop (or
-    evicted at its deadline).  ``hops`` is steps-1 — the last step's
+    frees, seated for ``hops`` hops, extracted at its last hop (or
+    evicted at its deadline).  The seat itself takes the first of them
+    where it can (``seat_hops`` 1: the session scattered the rider's
+    first frontier, its starts' neighbours, which the host holds —
+    _ContinuousGoSession.join), and the rider then rides ``hops`` - 1
+    hop ticks; ``may_advance`` is the part of that rule the stream
+    sees: it keeps a hop on the lanes afterwards, and its lane is no
+    UPTO one.  ``hops`` is steps-1 — the last step's
     edges are the host's to assemble from the frontier — but for a
     k-hop neighbourhood count (reduce "count_distinct": ``counts``),
     which rides all of its steps and leaves with the size of its last
@@ -650,7 +659,8 @@ class _Rider:
     _finish)."""
 
     __slots__ = ("payload", "steps", "upto", "reduce", "counts",
-                 "distinct", "hops", "deadline",
+                 "distinct", "hops", "may_advance", "seat_hops",
+                 "deadline",
                  "tctx", "enq", "enq_t", "seated_t", "left_t", "done_t",
                  "lane", "remaining", "joined_tick", "left_tick",
                  "midflight", "done", "result", "frontier", "mirror",
@@ -668,6 +678,8 @@ class _Rider:
             and self.reduce[0] == "distinct"
         self.hops = self.steps if self.counts or self.distinct \
             else self.steps - 1
+        self.may_advance = self.hops >= 2 and not self.upto
+        self.seat_hops = 0
         self.deadline = deadline
         # the submitter's trace snapshot: the pump attaches it around
         # the device phases this rider participates in, so a PROFILE
@@ -704,7 +716,8 @@ class _ContinuousStream:
 
         hold the door while the device is busy with hop k-1 (_hold:
         arrivals queue up; not at all when it is not) ->
-        seat joiners -> scatter-merge their start frontiers ->
+        seat joiners -> scatter-merge their FIRST frontiers (a joiner
+        whose seat cannot take its first hop: its starts) ->
         dispatch hop k -> mark leavers/evictions -> enqueue their
         lane extraction (a counting leaver's per-lane count) + clear ->
         fetch + unpack hop k-1's leavers while hop k computes -> hand
@@ -1157,6 +1170,7 @@ class _ContinuousStream:
         leavers: List[_Rider] = []
         occupancy = 0
         join_map_us = join_pack_us = hold_joins = 0
+        seat_hops = join_rows = 0
         hop_enqueued = False
         busy = sess is not None and bool(joiners or evicted
                                          or seated_now)
@@ -1186,8 +1200,19 @@ class _ContinuousStream:
                                       joiners=len(joiners), steps=1):
                         if joiners:
                             tj = hostclock.stamp()
-                            sess.join([(r.lane, r.payload.start_vids)
-                                       for r in joiners])
+                            took = sess.join(
+                                [(r.lane, r.payload.start_vids,
+                                  r.may_advance) for r in joiners])
+                            # the stream counts what the session did:
+                            # a rider whose seat took its first hop
+                            # has one fewer to ride
+                            with self.cond:
+                                for r, hop_taken in zip(joiners, took):
+                                    if hop_taken:
+                                        r.seat_hops = 1
+                                        r.remaining -= 1
+                            seat_hops = sum(took)
+                            join_rows = getattr(sess, "join_rows", 0)
                             host.add("join", tj, hostclock.stamp())
                             # where the session's own marks split it
                             # (tpu/runtime.py join): the joiners
@@ -1263,6 +1288,7 @@ class _ContinuousStream:
             if joiners:
                 stats.add_value("graph.continuous.joins",
                                 len(joiners))
+                stats.add_value("graph.continuous.seat_hops", seat_hops)
                 if held:
                     # riders this tick seated that came while it held
                     # the door: a tick without the hold had left them
@@ -1275,6 +1301,7 @@ class _ContinuousStream:
                         journal.record(
                             "query.joined_midflight",
                             detail=f"lane={r.lane} hops={r.hops} "
+                                   f"seat_hops={r.seat_hops} "
                                    f"tick={r.joined_tick}",
                             space=self.space_id)
             if leavers or evicted:
@@ -1378,7 +1405,8 @@ class _ContinuousStream:
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
-                hold_joins=hold_joins,
+                hold_joins=hold_joins, seat_hops=seat_hops,
+                join_rows=join_rows,
                 leaves=len(leavers), evictions=len(evicted),
                 **host.fields(),
                 join_map_us=join_map_us, join_pack_us=join_pack_us,
@@ -1637,15 +1665,17 @@ class _ContinuousStream:
                     elif self.hop_ema_s > 0.0:
                         # seats free at hop boundaries: if every free
                         # lane seats someone ahead of us we wait >= 1
-                        # tick for churn, then ride our hops — a
+                        # tick for churn, then ride our hops (one
+                        # fewer where the seat may take the first) — a
                         # conservative LOWER bound, so a shed is
                         # provably unmeetable
                         free = self.ledger.free_count() \
                             if self.ledger is not None else None
                         wait_ticks = 0 if (free is None
                                            or free > depth) else 1
-                        est_s = self.hop_ema_s \
-                            * (wait_ticks + max(1, rider.hops))
+                        est_s = self.hop_ema_s * (
+                            wait_ticks
+                            + max(1, rider.hops - rider.may_advance))
                         if rem < est_s:
                             if depth > 0:
                                 disp._shed(
@@ -1729,7 +1759,7 @@ class _ContinuousStream:
         tracing.annotate("graph.continuous", lane=rider.lane,
                          joined_tick=rider.joined_tick,
                          left_tick=rider.left_tick,
-                         hops=rider.hops,
+                         hops=rider.hops, seat_hops=rider.seat_hops,
                          reduce=rider.reduce[0] if rider.reduce else "",
                          midflight=rider.midflight,
                          ending=protocol.END_LEFT, **waits, **host)

@@ -928,7 +928,10 @@ def make_batched_go_lanes_kernel(ell: EllIndex, steps: int,
 #                                the batched BFS lanes program takes
 #                                the same step under the same budget
 #   make_lane_join_kernel        scatter-ADD of single lane bits into
-#                                FREE lanes.  Exact by the clear
+#                                FREE lanes: a joiner's start rows, or
+#                                its first frontier's where the seat
+#                                takes its first hop (the host holds a
+#                                start's neighbours).  Exact by the clear
 #                                contract: a freed lane's bit is zero
 #                                in every word it touches, and the host
 #                                dedups (row, lane) pairs, so each add
@@ -1197,13 +1200,17 @@ def make_continuous_hop_kernel(ell: EllIndex,
 
 
 def make_lane_join_kernel(ell: EllIndex, donate: bool = True):
-    """Merge queued arrivals' start frontiers into their assigned free
+    """Merge queued arrivals' frontiers (a joiner's starts, or its
+    first frontier where the seat takes its first hop:
+    runtime._ContinuousGoSession.join) into their assigned free
     lanes: fn(fp, accp, rows int32[Sp], words int32[Sp], vals uint8[Sp])
-    -> (fp', accp').  ``vals[i]`` is the single lane bit 1 << (lane & 7)
+    -> (fp', accp'), Sp a rung of LANE_JOIN_RUNGS.  ``vals[i]`` is the
+    single lane bit 1 << (lane & 7)
     for row ``rows[i]`` / word ``words[i]``; padding scatters target the
     pad row, which is re-zeroed (it is every sentinel slot's gather
     source and must stay all-zero).  The accumulator gets the same bits:
-    an UPTO union includes depth 0."""
+    an UPTO union includes depth 0 (and an UPTO lane is always seated
+    with its starts)."""
     import jax
     import jax.numpy as jnp
     pad_row = ell.n_rows
@@ -1231,6 +1238,27 @@ def make_lane_clear_kernel(donate: bool = True):
             return fp & keep[None, :], accp & keep[None, :]
 
     return jax.jit(clear, donate_argnums=(0, 1) if donate else ())
+
+
+# The row counts the join program is compiled for: a scatter table is
+# padded to the least rung that holds it, and one of more rows than the
+# top rung goes in as several programs of that rung.  A factor of four
+# apart: a pad row costs the device what a real one does (the TPU
+# scatters one index after the other, ~50 ns each into each carrier),
+# so the worst padding, 3/4 of 512 rows, is 40 us.  The ladder ends at
+# 512 because of what a program weighs on the device while it is
+# loaded (PERF.md section 7, "Left by PR 37" (a)): compiled for the
+# v5e at the cells' table, 0.14 MB of code at 8 rows and 0.24-0.26 MB
+# at 16 to 512, but 2.3 MB from 1,024 on and 5.5 MB at 8,192, where
+# the compiler sorts the indices first (tests/test_hop_compile_tpu.py).
+LANE_JOIN_RUNGS = (8, 32, 128, 512)
+
+
+def lane_join_rung(rows: int) -> int:
+    """The least of LANE_JOIN_RUNGS that holds ``rows`` scatter rows,
+    the top rung for more (the caller splits its table there)."""
+    return next((r for r in LANE_JOIN_RUNGS if rows <= r),
+                LANE_JOIN_RUNGS[-1])
 
 
 def lane_bitmap_bytes(n: int) -> int:
@@ -2890,7 +2918,7 @@ def _ell_lane_join_buckets(fx):
     out = []
     for B in fx.widths:
         pk = _packed_frontier_avals(fx, B)
-        for Sp in (8, 64):          # pow-2 scatter-pad ladder ends
+        for Sp in LANE_JOIN_RUNGS:  # every scatter-pad rung
             out.append((("ell_lane_join", fx.ell.shape_sig()), kern,
                         (pk[0], pk[0],
                          fx.aval((Sp,), np.int32),
@@ -3064,8 +3092,9 @@ register_kernel(KernelSpec(
     frontier=(0, 1), packed=(0, 1)))
 register_kernel(KernelSpec(
     "ell_lane_join", make_lane_join_kernel, phase_kind="ell_lane_join",
-    # one retrace per (width rung, pow-2 scatter-pad rung) pair — the
-    # same Sp ladder _upload_frontier_packed rides
+    # one retrace per (width rung, scatter-pad rung) pair:
+    # LANE_JOIN_RUNGS, which the session runs itself before its first
+    # join (runtime._ContinuousGoSession._join_kernel)
     budget=48, instantiate=_ell_lane_join_buckets, donate=(0, 1),
     dispatch=(2, 3, 4), frontier=(0, 1), packed=(0, 1)))
 register_kernel(KernelSpec(

@@ -45,6 +45,7 @@ return identical result sets (tests/test_tpu_backend.py asserts this).
 from __future__ import annotations
 
 import collections
+import itertools
 import sys
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -69,8 +70,9 @@ from .expr_compile import (CompileError, CVal, Env, ExprCompiler, K_BOOL,
                            K_FLOAT, K_INT, K_STR, K_STRCODE, K_VIDRANK)
 from .jax_setup import device_info, ensure_jax_configured
 from . import kernels
-from .ell import (EllIndex, lane_bitmap_rows, lane_extract_rung,
-                  lane_extract_rungs, sides_read)
+from .ell import (LANE_JOIN_RUNGS, EllIndex, lane_bitmap_rows,
+                  lane_extract_rung, lane_extract_rungs, lane_join_rung,
+                  sides_read)
 
 
 class MeshUnavailable(DeviceExecError):
@@ -673,6 +675,8 @@ class TpuQueryRuntime:
         # (table shapes, width rung) whose extract has run at every
         # rung of leavers (_ContinuousGoSession._extract_kernel)
         self.extract_rungs_run: set = set()
+        # the same for the join's scatter-pad rungs (_join_kernel)
+        self.join_rungs_run: set = set()
         self._lock = threading.Lock()
         self._build_locks: Dict[int, threading.Lock] = {}
         self._rebuilding: set = set()           # spaces rebuilding now
@@ -720,6 +724,10 @@ class TpuQueryRuntime:
                       "hop_sparse": 0, "hop_dense": 0,
                       "hop_onesided": 0,
                       "hop_swept_slots": 0,
+                      # continuous joiners, and those of them whose
+                      # seat took their first hop
+                      # (_ContinuousGoSession.join)
+                      "seat_joins": 0, "seat_hops": 0,
                       "prewarm_compiled": 0, "prewarm_hits": 0,
                       "prewarm_misses": 0, "prewarm_failed": 0,
                       "t_launch_s": 0.0, "t_fetch_s": 0.0,
@@ -1856,7 +1864,8 @@ class TpuQueryRuntime:
     # ------------------------------------- continuous dispatch seam
     def continuous_session(self, space_id: int,
                            et_tuple: Tuple[int, ...],
-                           min_lanes: int = 1):
+                           min_lanes: int = 1,
+                           seat_rows: Optional[int] = None):
         """Anchor one continuous-dispatch device session for a
         (space, OVER set) stream (graph/batch_dispatch.py
         ContinuousGoScheduler): the resident packed frontier pair plus
@@ -1865,7 +1874,8 @@ class TpuQueryRuntime:
         seat-map path — mesh-sharded tables (the replicated-frontier
         mesh kernels have no resident-pair protocol yet) or an
         empty/unbuildable mirror — and the caller falls back to the
-        windowed pipeline."""
+        windowed pipeline.  ``seat_rows`` is a test's own row budget
+        for the seat's first hop (_ContinuousGoSession.join)."""
         # flag check, not _mesh_only(): the mesh cache is request-path
         # state and the pump must not warm it from its own thread
         if int(flags.get("tpu_mesh_devices") or 0) > 1:
@@ -1888,7 +1898,8 @@ class TpuQueryRuntime:
             if min_lanes <= w:
                 B = w
                 break
-        return _ContinuousGoSession(self, space_id, m, ix, et_tuple, B)
+        return _ContinuousGoSession(self, space_id, m, ix, et_tuple, B,
+                                    seat_rows=seat_rows)
 
     def continuous_results(self, space_id: int, m: CsrMirror,
                            queries: List[_GoQuery], reduces,
@@ -4037,7 +4048,10 @@ class _ContinuousGoSession:
     """Resident device state of ONE continuous-dispatch stream: the
     packed frontier pair (exact-depth frontier + UPTO union
     accumulator) for a (space, OVER set) lane batch, advanced one hop
-    per tick (docs/admission.md "Continuous dispatch").
+    per tick (docs/admission.md "Continuous dispatch").  A joiner's
+    FIRST hop is the seat's wherever it can be: the host holds a
+    start's neighbours, so ``join`` scatters the first frontier and
+    the lane rides one sweep fewer.
 
     Owned by the stream's single pump thread (graph/batch_dispatch.py
     _ContinuousStream) — every method here runs on that one thread, so
@@ -4055,9 +4069,10 @@ class _ContinuousGoSession:
     donate, its output is a fresh fetch-sized buffer."""
 
     def __init__(self, rt, space_id: int, m: CsrMirror, ix: EllIndex,
-                 et_tuple: Tuple[int, ...], B: int):
+                 et_tuple: Tuple[int, ...], B: int,
+                 seat_rows: Optional[int] = None):
         import jax.numpy as jnp
-        from .ell import lanes_width, swept_slots
+        from .ell import HOP_PUSH_ROWS, lanes_width, swept_slots
         self.rt = rt
         self.space_id = space_id
         self.m = m
@@ -4073,6 +4088,19 @@ class _ContinuousGoSession:
         self.W = lanes_width(B)
         self._tables = ix.kernel_args()[1:]  # mirror-resident buckets
         self.eslot, self.hrows = rt._hub_merge_dev(m, ix)
+        # the seat takes a joiner's first hop (join) out of the
+        # mirror's per-(generation, OVER set) host tables, O(edges) at
+        # their first use: filled here, where a generation's other
+        # first-use costs land, never under a seated lane
+        rt._over_ranges(m, et_tuple)
+        # the most distinct rows a first frontier may have for the
+        # seat to take the hop: the budget the device's own branch
+        # uses (HOP_PUSH_ROWS unless a test passes its own; negative:
+        # no seat takes a hop), and the first-hop edges one cohort's
+        # seat expands, eight programs of the join ladder's top rung
+        self.seat_rows = HOP_PUSH_ROWS if seat_rows is None \
+            else seat_rows
+        self.seat_cohort_edges = SEAT_COHORT_EDGES
         fp = jnp.zeros((ix.n_rows + 1, self.W), jnp.uint8)
         # .copy(): the pair is donated together every hop — two
         # argument slots must never alias one device buffer
@@ -4083,54 +4111,135 @@ class _ContinuousGoSession:
         # them forgets the oldest
         self._hop_info: collections.deque = collections.deque(maxlen=64)
         self._hop_read = [0, 0, 0, 0, 0]  # read, not yet in a tick record
-        # perf_counter marks of the last join: its map loop's end and
-        # its pack's end (join)
+        # perf_counter marks of the last join: its map's end and its
+        # pack's end, and the rows it scattered (join)
         self.join_marks = None
+        self.join_rows = 0
 
-    def join(self, joiners) -> None:
-        """Scatter the arrivals' start frontiers into their assigned
-        lanes: ``joiners`` is [(lane, start_vids)].  Unmappable vids
-        drop exactly like the windowed upload; the (row, lane-bit)
-        scatter coordinates are deduped per lane so the add lands on
-        zero bits only (the clear contract)."""
-        import time
+    def _join_kernel(self):
+        """The join program.  The first join over tables of these
+        shapes at this width runs it once at every rung
+        (ell.LANE_JOIN_RUNGS) with all-pad rows and zero values, a
+        no-op on the carriers, so that no later cohort meets a shape
+        for the first time: which rung a tick needs is the rows its
+        joiners' first frontiers number, and no warm-up can promise to
+        have met them all (_extract_kernel's rule; a session
+        re-anchored over tables of the same shapes finds them run,
+        rt.join_rungs_run)."""
         from .ell import make_lane_join_kernel
-        rows_l: List[np.ndarray] = []
-        words_l: List[np.ndarray] = []
-        vals_l: List[np.ndarray] = []
-        for lane, start_vids in joiners:
-            d = self.m.to_dense(np.asarray(list(start_vids), np.int64))
-            d = np.unique(d[d >= 0]).astype(np.int64)
-            if not len(d):
-                continue                    # empty start: stays zero
-            r = self.ix.perm[d].astype(np.int32)
-            rows_l.append(r)
-            words_l.append(np.full(len(r), lane >> 3, np.int32))
-            vals_l.append(np.full(len(r), np.uint8(1) << (lane & 7),
-                                  np.uint8))
-        S = sum(len(r) for r in rows_l)
+        sig = self.ix.shape_sig()
+        kern = self.rt._kernel(
+            ("ell_lane_join", sig),
+            lambda: make_lane_join_kernel(self.ix, donate=True))
+        if (sig, self.B) not in self.rt.join_rungs_run:
+            for Sp in LANE_JOIN_RUNGS:
+                self.fp, self.accp = kern(
+                    self.fp, self.accp,
+                    np.full(Sp, self.ix.n_rows, np.int32),
+                    np.zeros(Sp, np.int32), np.zeros(Sp, np.uint8))
+            self.rt.join_rungs_run.add((sig, self.B))
+        return kern
+
+    def join(self, joiners) -> List[bool]:
+        """Seat the arrivals: ``joiners`` is [(lane, start_vids,
+        may_advance)], the answer per joiner whether THE SEAT TOOK ITS
+        FIRST HOP: its lane then holds its first frontier, the far ends
+        of its starts' edges of the OVER set, read from the mirror
+        generation the session is anchored on by the pass that answers
+        every GO's last hop (rt._frontier_edges_multi: signs,
+        REVERSELY and BIDIRECT are the mirror's rows, no case here),
+        and the stream counts it one hop fewer to ride.  A walk's
+        first step IS N(start), so every later frontier, count and row
+        is what it was, one sweep sooner.
+
+        A joiner advances iff the stream allows it (``may_advance``:
+        it keeps a hop on the lanes afterwards and its lane is no UPTO
+        one, whose accumulator needs depth 0 apart), its first
+        frontier has at most ``seat_rows`` distinct rows (HOP_PUSH_ROWS,
+        the device's own push budget) and the first-hop edges of the
+        cohort's joiners, taken cheapest first, up to and including its
+        own stay within ``seat_cohort_edges`` (a joiner of more edges
+        than that alone is not expanded and stands in nobody's way).  Every other joiner is seated with its
+        start rows, as ever, in the same scatter.  A start with no edge
+        of the OVER set advances to an empty lane.  Unmappable vids
+        drop exactly like the windowed upload.
+
+        ONE pass over the cohort, whatever its size: one to_dense, one
+        expansion, one np.unique over (joiner, row) keys, so that the
+        scatter-add lands on zero bits only (the clear contract;
+        multi-edges, several starts a statement, a neighbour reached
+        twice), ``perm`` for the rows, one pack.  The table goes in as
+        one program of the least rung that holds it
+        (ell.lane_join_rung), or several of the top rung."""
+        import time
+        n, nj = self.m.n, len(joiners)
+        lanes = np.fromiter((j[0] for j in joiners), np.int64, count=nj)
+        lens = np.fromiter((len(j[1]) for j in joiners), np.int64,
+                           count=nj)
+        d = self.m.to_dense(np.fromiter(
+            itertools.chain.from_iterable(j[1] for j in joiners),
+            np.int64, count=int(lens.sum()))).astype(np.int64)
+        who = np.repeat(np.arange(nj), lens)     # a start's joiner
+        d, who = d[d >= 0], who[d >= 0]
+        # keys (2 * joiner + kind) * n + row: kind 0 a start, kind 1 a
+        # first-frontier row
+        keys = 2 * who * n + d
+        may = np.fromiter((j[2] and self.seat_rows >= 0 for j in joiners),
+                          np.bool_, count=nj)
+        if may.any():
+            # who is expanded: read off the degrees, before any edge is
+            edges = np.bincount(
+                who, self.rt._deg_host(self.m, self.et_tuple)[d],
+                minlength=nj).astype(np.int64)
+            may &= edges <= self.seat_cohort_edges
+            # cheapest first: a heavy joiner, which may yet fail the
+            # row budget, takes the cohort's budget from nobody lighter
+            order = np.argsort(np.where(may, edges, 0), kind="stable")
+            may[order] &= np.cumsum(np.where(may, edges, 0)[order]) \
+                <= self.seat_cohort_edges
+            qs = np.flatnonzero(may)
+            sel = may[who]
+            idx, qseg, _ = self.rt._frontier_edges_multi(
+                self.m, np.split(d[sel], np.searchsorted(
+                    who[sel], qs[1:])), self.et_tuple)
+            keys = np.concatenate(
+                [keys, (2 * qs[qseg] + 1) * n + self.m.edge_dst[idx]])
+        keys = np.unique(keys)
+        kind, who = (keys // n) & 1, keys // (2 * n)
+        adv = may & (np.bincount(who[kind == 1], minlength=nj)
+                     <= self.seat_rows)
+        # an advancing joiner's first frontier, everyone else's starts
+        take = adv[who] == (kind == 1)
+        keys, who = keys[take], who[take]
+        S = len(keys)
+        rows = self.ix.perm[keys % n]
         t_map = time.perf_counter()
         # where the pump splits join_us (graph/batch_dispatch.py
         # _tick): the joiners mapped, the arrays packed, and what is
         # left, the enqueue
-        self.join_marks = (t_map, t_map)
+        self.join_marks, self.join_rows = (t_map, t_map), S
+        self.rt._bump("seat_joins", nj)
+        self.rt._bump("seat_hops", int(adv.sum()))
         if S == 0:
-            return
-        Sp = max(8, 1 << (S - 1).bit_length())   # stable shapes
+            return adv.tolist()
+        # full programs of the top rung, then the rest at its rung
+        top = LANE_JOIN_RUNGS[-1]
+        Sp = S - S % top + (lane_join_rung(S % top) if S % top else 0)
         rows_p = np.full(Sp, self.ix.n_rows, np.int32)   # pad row
         words_p = np.zeros(Sp, np.int32)
         vals_p = np.zeros(Sp, np.uint8)          # zero add: no-op
-        rows_p[:S] = np.concatenate(rows_l)
-        words_p[:S] = np.concatenate(words_l)
-        vals_p[:S] = np.concatenate(vals_l)
+        rows_p[:S] = rows
+        words_p[:S] = lanes[who] >> 3
+        vals_p[:S] = np.uint8(1) << (lanes[who] & 7).astype(np.uint8)
         self.join_marks = (t_map, time.perf_counter())
-        kern = self.rt._kernel(
-            ("ell_lane_join", self.ix.shape_sig()),
-            lambda: make_lane_join_kernel(self.ix, donate=True))
+        kern = self._join_kernel()
         with tracing.span("tpu.kernel", kind="ell_lane_join",
                           width=self.B):
-            self.fp, self.accp = kern(self.fp, self.accp, rows_p,
-                                      words_p, vals_p)
+            for lo in range(0, Sp, top):
+                self.fp, self.accp = kern(
+                    self.fp, self.accp, rows_p[lo:lo + top],
+                    words_p[lo:lo + top], vals_p[lo:lo + top])
+        return adv.tolist()
 
     def hop(self) -> None:
         """Advance every seated lane one hop; the UPTO accumulator
@@ -4268,6 +4377,17 @@ class _ContinuousGoSession:
         with tracing.span("tpu.kernel", kind="ell_lane_clear",
                           width=self.B):
             self.fp, self.accp = kern(self.fp, self.accp, keep)
+
+
+# The first-hop edges one cohort's seat expands on the host (join): its
+# joiners are expanded cheapest first while their edges up to and
+# including a joiner's own stay within this.  Eight programs of the join ladder's top rung:
+# a lone joiner at the row budget (HOP_PUSH_ROWS = 2,048 distinct rows)
+# fits with room for its multi-edges, and the pump's worst tick maps
+# and scatters 4,096 rows (on the cells' graph a cohort of four
+# joiners numbers 37 rows at the median and 969 at the 99th percentile,
+# ISSUE 43).  A bound on the pump's work, not on the answer.
+SEAT_COHORT_EDGES = 8 * LANE_JOIN_RUNGS[-1]
 
 
 # A leaver whose set rows pass this share of the table's vertex rows

@@ -425,13 +425,20 @@ class TestServingSemantics:
             s2 = dict(rt.stats)
             assert s2["hop_dense"] - s1["hop_dense"] == 1
             assert s2["hop_sparse"] == s1["hop_sparse"]
+            took0 = stats.read_stats(
+                "graph.continuous.seat_hops.sum.600") or 0
             ok("GO 3 STEPS FROM 3 OVER e YIELD e._dst")
             s3 = dict(rt.stats)
-            # two hops; the second frontier (a vertex's out-neighbours)
-            # may or may not fit six rows, the first always does
-            assert s3["hop_sparse"] - s2["hop_sparse"] >= 1
+            took = (stats.read_stats(
+                "graph.continuous.seat_hops.sum.600") or 0) - took0
+            # two hops, of which the seat takes the first where the
+            # start's out-neighbours fit the budget's six rows (the
+            # session's is the program's); the start's own row always
+            # fits, a vertex's out-neighbours may or may not
+            assert took in (0, 1)
+            assert s3["hop_sparse"] - s2["hop_sparse"] >= 1 - took
             assert (s3["hop_sparse"] + s3["hop_dense"]
-                    - s2["hop_sparse"] - s2["hop_dense"]) == 2
+                    - s2["hop_sparse"] - s2["hop_dense"]) == 2 - took
             # the windowed tier is the oracle for the rows
             flags.set("go_dispatch_mode", "windowed")
             w2 = ok("GO 2 STEPS FROM 3 OVER e YIELD e._dst")
@@ -659,8 +666,11 @@ class _StubSession:
             time.sleep(left)
 
     def join(self, joiners):
+        """The seat takes the first hop of whoever the stream lets
+        (``rt.seat_takes`` False: of nobody, the tree before PR 43)."""
         t = time.perf_counter()
         self.join_marks = (t, t)
+        return [may and self.rt.seat_takes for _, _, may in joiners]
 
     def hop(self):
         pushed = self.rt.pushes(self.hops)
@@ -703,8 +713,10 @@ class _StubMirror:
 
 
 class _StubRuntime:
-    def __init__(self, hop_s, push_s=0.0, pushes=lambda i: False):
+    def __init__(self, hop_s, push_s=0.0, pushes=lambda i: False,
+                 seat_takes=False):
         self.hop_s, self.push_s, self.pushes = hop_s, push_s, pushes
+        self.seat_takes = seat_takes
         self.mirrors = {STUB_SPACE: _StubMirror(1)}
         self.sessions = []
         self.pushed = []                # hop by hop, what it did
@@ -845,6 +857,67 @@ def _paced(s, hop_s):
     assert not s.errors, s.errors
     est = s.st._flight.hop_s[False]
     assert 0.8 * hop_s <= est <= 1.5 * hop_s, est
+
+
+class TestSeatHop:
+    """The stream counts what the session did (PR 43): a rider whose
+    seat took its first hop (the session scattered its first frontier,
+    _ContinuousGoSession.join) rides one tick fewer; its ``hops`` stay
+    its statement's."""
+
+    @pytest.mark.parametrize("hops", [1, 2, 3, 6])
+    @pytest.mark.parametrize("seat_takes", [False, True],
+                             ids=["rides-all", "seat-takes-first"])
+    def test_a_rider_rides_its_hops_less_the_seats(self, stub,
+                                                   seat_takes, hops):
+        s = stub(0.0, seat_takes=seat_takes)
+        progress = []
+        real = query_registry.note_hop
+        s.st.sched.runtime.count_distinct_results = \
+            lambda counts, hs: [(["n"], [[h]]) for h in hs]
+        query_registry.note_hop = \
+            lambda qid, hop: progress.append(hop) or real(qid, hop)
+        try:
+            m = s.ride(hops)
+        finally:
+            query_registry.note_hop = real
+        # a rider of one hop keeps none on the lanes after the seat's:
+        # it rides as ever
+        took = int(seat_takes and hops >= 2)
+        assert m["hops"] == hops and m["seat_hops"] == took
+        assert m["left_tick"] - m["joined_tick"] == hops - took
+        # the registry hears of the hops done, the seat's among them
+        assert progress == list(range(1 + took, hops + 1))
+        ticks = s.ticks()
+        assert sum(t["seat_hops"] for t in ticks) == took
+        assert sum(t["joins"] for t in ticks) == 1
+        assert [t["seat_hops"] for t in ticks if not t["joins"]] \
+            == [0] * (len(ticks) - 1)
+
+    def test_admission_counts_the_ticks_a_rider_may_ride(self, stub):
+        """The feasibility estimate is a LOWER bound: a rider of three
+        hops whose seat may take the first rides two ticks."""
+        from nebula_tpu.common import deadline as deadlines
+        s = stub(0.0, seat_takes=True)
+        s.ride(1)                       # the stream is anchored
+        with s.st.cond:
+            s.st.hop_ema_s = 1.0
+        try:
+            for hops, budget_s, admitted in ((3, 2.5, True),
+                                             (3, 1.5, False),
+                                             (1, 1.5, True)):
+                with deadlines.bind(deadlines.Deadline.after_s(budget_s)):
+                    with s.st.cond:
+                        s.st.hop_ema_s = 1.0
+                    try:
+                        s.ride(hops)
+                        got = True
+                    except bd.DeadlineExceeded:
+                        got = False
+                assert got == admitted, (hops, budget_s)
+        finally:
+            with s.st.cond:
+                s.st.hop_ema_s = 0.0
 
 
 class TestHold:

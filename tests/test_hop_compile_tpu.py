@@ -1,5 +1,6 @@
 """The continuous hop program (PR 40: and its two-sided form, the
-OVER set of a GO ... BIDIRECT), the batched BFS program whose levels
+OVER set of a GO ... BIDIRECT), the join program beside it at every
+rung of scatter rows (PR 43), the batched BFS program whose levels
 take the same step (PR 30), the windowed GO (PR 39: the three programs
 that pull, each cut by the cell's reach at 4 and at 8 column ranges
 and held against the whole sweep), the per-lane count of the resident
@@ -359,3 +360,48 @@ def test_extract_program_compiles_for_the_v5e_at_every_rung(one_chip, lanes):
     # measured 0.50-0.51 MB a rung at 128 lanes (3.0 MB over the six),
     # 0.66-0.67 MB at 1,024
     assert code <= 0.75e6 * len(rungs), code
+
+
+def test_join_program_compiles_for_the_v5e_at_every_rung(one_chip):
+    """jit_join as the seat runs it (PR 43: a joiner's first frontier,
+    hundreds of rows a tick where a start was one): one program a rung
+    of ell.LANE_JOIN_RUNGS, all loaded from a session's first join on
+    (runtime._ContinuousGoSession._join_kernel), both carriers donated
+    and scattered in place.  What the ladder costs the device is its
+    programs' code, and that is why it ends at 512 rows: from 1,024 on
+    the compiler sorts the indices first and a program weighs ten
+    times as much."""
+    import jax
+    from nebula_tpu.tpu import ell as E
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fp = sd((S20_ROWS + 1, E.lanes_width(LANES)), np.uint8)
+    kern = E.make_lane_join_kernel(_Shapes(), donate=True)
+
+    def sizes(Sp):
+        t0 = time.perf_counter()
+        join = kern.lower(fp, fp, sd((Sp,), np.int32),
+                          sd((Sp,), np.int32),
+                          sd((Sp,), np.uint8)).compile()
+        # measured 0.1-1.5 s a rung
+        assert time.perf_counter() - t0 < 20.0, Sp
+        return _sizes(join)
+
+    assert E.LANE_JOIN_RUNGS[-1] == 512
+    code = 0
+    for Sp in E.LANE_JOIN_RUNGS:
+        scratch, rung_code = sizes(Sp)
+        # measured: no scratch at all (the scatter runs in place)
+        assert scratch <= 2**20, (Sp, scratch)
+        code += rung_code
+    # measured 0.14 MB at 8 rows and 0.25-0.26 MB at 32, 128 and 512:
+    # 0.91 MB over the four, where the 8 and 16 of a warm-up statement
+    # were 0.40; device_bytes_per_edge's 0.3 % is 2.4 MB at this graph
+    assert code <= 1.2e6, code
+    # measured 2.34 MB of code and 0.32 MB of scratch at 1,024 rows
+    # (5.5 MB at 8,192): a larger table goes in as several programs of
+    # the top rung instead
+    _scratch, above = sizes(2 * E.LANE_JOIN_RUNGS[-1])
+    assert above >= 4 * code / len(E.LANE_JOIN_RUNGS), (above, code)

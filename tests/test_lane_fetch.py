@@ -410,8 +410,10 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     c, ok = star
     sess = _session(c)
     n = sess.ix.n
-    # lane 9: word 1, so the pad columns (word 0) are another word
-    sess.join([(9, [start])])
+    # lane 9: word 1, so the pad columns (word 0) are another word;
+    # both carriers of the lane are read below, so the seat may not
+    # take its first hop (the accumulator needs depth 0 apart)
+    assert sess.join([(9, [start], False)]) == [False]
     sess.hop()
     sess.hop()
     before = sess.rt.stats["fetch_bytes"]
@@ -441,6 +443,21 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     assert resolver.unpack_live == 2 * live
 
 
+@pytest.mark.parametrize("start", [1, 10])
+def test_session_seat_takes_the_first_of_two_hops(star, start):
+    """The same exact-depth answer out of ONE device hop: the join
+    scattered the start's out-neighbours (PR 43)."""
+    c, ok = star
+    sess = _session(c)
+    assert sess.join([(9, [start], True)]) == [True]
+    sess.hop()
+    (exact,) = sess.extract([(9, False)])()
+    want = sorted({row[0] for row in _cpu(
+        ok, f"GO 2 STEPS FROM {start} OVER e YIELD e._dst").rows})
+    assert sess.m.vids[exact].tolist() == want
+    assert exact.dtype == np.int64 and np.all(np.diff(exact) > 0)
+
+
 def test_every_rung_runs_once_and_only_where_a_stream_fetches(star):
     """A session whose leavers only count runs no extract program, so
     loads none; the first fetching cohort over these table shapes at
@@ -457,7 +474,7 @@ def test_every_rung_runs_once_and_only_where_a_stream_fetches(star):
         lambda fp, accp, lanes: ran.append(lanes.shape[1]) or kern(
             fp, accp, lanes)
     try:
-        sess.join([(9, [1])])
+        sess.join([(9, [1], False)])
         sess.hop()
         assert list(sess.count([9])()) == [1]
         assert not ran and (sig, sess.B) not in rt.extract_rungs_run
@@ -465,7 +482,7 @@ def test_every_rung_runs_once_and_only_where_a_stream_fetches(star):
         assert ran == list(E.lane_extract_rungs(sess.B)) + [4]
         assert (sig, sess.B) in rt.extract_rungs_run
         again = _session(c)
-        again.join([(9, [1])])
+        again.join([(9, [1], False)])
         again.hop()
         assert np.array_equal(again.extract([(9, False)])()[0], first[0])
         assert ran[len(E.lane_extract_rungs(sess.B)):] == [4, 4]

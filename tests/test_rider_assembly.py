@@ -40,8 +40,9 @@ from nebula_tpu.graph import batch_dispatch as bd
 from nebula_tpu.storage.device import TpuDecline
 
 N = 40
-# one dispatcher stream (space, OVER e, f), one leave tick (3 hops in,
-# UPTO too): rows, a COUNT rider, a WHERE on a double column (one
+# one dispatcher stream (space, OVER e, f), one leave tick (two hops in,
+# of which the seat takes the first; the UPTO rider, whose seat cannot,
+# has one): rows, a COUNT rider, a WHERE on a double column (one
 # native pass: the rider's own), a LIMIT rider, DISTINCT, an UPTO
 # union, a WHERE whose || reads f.x on e's edges, where it is invalid
 # (the CPU loop's short-circuit decides that one, so the device path
@@ -53,7 +54,7 @@ COHORT = [
     "GO 3 STEPS FROM 3 OVER e, f WHERE e.d > 0.4 YIELD e._dst, e.w",
     "GO 3 STEPS FROM 4 OVER e, f YIELD e._dst AS d | LIMIT 5",
     "GO 3 STEPS FROM 5 OVER e, f YIELD DISTINCT e._dst",
-    "GO UPTO 3 STEPS FROM 6 OVER e, f YIELD e._dst, f._dst",
+    "GO UPTO 2 STEPS FROM 6 OVER e, f YIELD e._dst, f._dst",
     "GO 3 STEPS FROM 7 OVER e, f WHERE e.w > 40 || f.x > 5 "
     "YIELD e._dst, f._dst",
     "GO 3 STEPS FROM 8 OVER e, f YIELD e._dst | YIELD COUNT(*)",
@@ -292,7 +293,8 @@ def test_a_parked_rider_holds_neither_the_pump_nor_a_later_rider(served):
     rt = c.tpu_runtime
     st = _stream(c)
     slow = "GO 3 STEPS FROM 11 OVER e, f YIELD e._dst, f._dst"
-    later = "GO 3 STEPS FROM 12 OVER e, f WHERE e.d > 0.1 YIELD e._dst"
+    # three hops: the seat's, then two ticks on the lanes
+    later = "GO 4 STEPS FROM 12 OVER e, f WHERE e.d > 0.1 YIELD e._dst"
     want_slow, want_later = _cpu_rows(ok, slow), _cpu_rows(ok, later)
     entered, release = threading.Event(), threading.Event()
     assemblers = []             # the threads that ran _assemble_results
