@@ -7,7 +7,7 @@ Runs the system's main path once through the entry points a user calls:
   phase A  embedded deployment at real size — LocalCluster(tpu_backend)
            with the shipped conf defaults, the repo's seeded power-law
            generator + bulk ingest, then a handful of nGQL statements
-           (1..4-hop GO, 32-start GO, fused WHERE, COUNT / LIMIT
+           (1..4-hop GO, 32-start GO, WHERE, COUNT / LIMIT
            pushdown, UPTO, FIND SHORTEST PATH, a 64-thread burst, the
            multi-hop set again under go_dispatch_mode=windowed, INSERT
            + read-back), each compared with the same statement under

@@ -81,7 +81,6 @@ SPAN_NAMES = (
                               # that feed a remote store's events into
                               # the absorption above (storage/device.py
                               # RemoteStoreView.delta_since)
-    "tpu.transfer",           # host→device mirror upload
     "tpu.jit.compile",        # kernel cache miss → XLA build/compile
     "tpu.kernel",             # device kernel dispatch (async launch)
     "tpu.launch",             # batch leader: frontier launch half
@@ -564,7 +563,6 @@ _PHASE_OF = {
     "tpu.mirror.build": PHASE_MIRROR,
     "tpu.absorb": PHASE_MIRROR,
     "tpu.peer_absorb": PHASE_MIRROR,
-    "tpu.transfer": PHASE_MIRROR,
     "tpu.jit.compile": PHASE_KERNEL,
     "tpu.launch": PHASE_KERNEL,
     "tpu.kernel": PHASE_KERNEL,

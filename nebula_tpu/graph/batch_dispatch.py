@@ -61,8 +61,7 @@ double-buffered: while hop k computes, the host assembles hop k-1's
 leavers and uploads the next joiners (tpu.device_idle_frac proves the
 overlap).  The windowed path is kept verbatim as the bit-exact parity
 oracle and rollback (``go_dispatch_mode=windowed``); BFS, mesh-sharded
-spaces, fused-filter and single-hop queries stay on their existing
-paths.
+spaces and single-hop queries stay on their existing paths.
 
 The reference has no cross-query batching (each GO is its own RPC
 fan-out); this is TPU-native serving the same way the reference's
@@ -162,7 +161,7 @@ flags.define("go_dispatch_mode", "continuous",
              "packed frontier, the device never idles between windows "
              "— 'windowed' restores the discrete coalescing pipeline "
              "(the bit-exact parity oracle and rollback).  BFS, "
-             "single-hop GO, fused-filter and mesh-sharded dispatch "
+             "single-hop GO and mesh-sharded dispatch "
              "always use the windowed pipeline.  Managed: UPDATE "
              "CONFIGS graph:go_dispatch_mode=...")
 flags.define("autoscale_max_replicas", 8,
@@ -1844,7 +1843,7 @@ class ContinuousGoScheduler:
     """The continuous-dispatch tier: one _ContinuousStream per
     (space, OVER set), routed to from submit_batched when
     ``go_dispatch_mode=continuous`` and the key is eligible (multi-hop
-    GO; BFS/mesh/fused stay windowed).  Scrape-time gauges expose the
+    GO; BFS/mesh stay windowed).  Scrape-time gauges expose the
     live seat maps — the chaos suite's lane-leak assertion reads
     graph.continuous.seated from /metrics."""
 

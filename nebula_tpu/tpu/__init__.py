@@ -3,13 +3,14 @@ kernels (the project's north star, BASELINE.json).
 
 The reference executes multi-hop GO as one RPC round trip per hop with
 host-side set dedup (GoExecutor.cpp:377-431, QueryBaseProcessor.inl
-prefix scans).  Here the whole loop runs on-device: each graph space's
-edge partitions are folded into an HBM-resident CSR mirror (csr.py), the
-pushed filter expression tree is compiled to vectorized XLA ops
-(expr_compile.py), and frontier expansion is a jitted edge-parallel BFS
-(kernels.py) — optionally sharded over a jax.sharding.Mesh with psum
-frontier merges riding ICI.  TpuQueryRuntime (runtime.py) plugs into the
-graphd executor seam (graph/executors/traverse.py).
+prefix scans).  Here the hop loop runs on-device: each graph space's
+edge partitions are folded into a host CSR mirror (csr.py) and from it
+into HBM-resident ELL tables (ell.py), frontier expansion is a jitted
+batched program over those tables — optionally sharded over a
+jax.sharding.Mesh — and the pushed filter expression tree is compiled
+to vectorized numpy ops (expr_compile.py) that meet the final
+frontier's candidate edges on the host.  TpuQueryRuntime (runtime.py)
+plugs into the graphd executor seam (graph/executors/traverse.py).
 """
 from .runtime import TpuQueryRuntime
 

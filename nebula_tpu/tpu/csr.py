@@ -1,4 +1,4 @@
-"""CSR mirror — fold a space's edge/vertex KV partitions into device arrays.
+"""CSR mirror — fold a space's edge/vertex KV partitions into host columns.
 
 The storage key encoding is order-preserving (common/keys.py), so a plain
 range scan over each partition already yields edges in
@@ -6,21 +6,20 @@ range scan over each partition already yields edges in
 merge pass with multi-version "first wins" dedup (the reference dedups the
 same way while scanning RocksDB — QueryBaseProcessor.inl:352-361).
 
-Everything the device needs is re-encoded into **order-preserving dense
-spaces** so the whole query runs in int32/float32:
+Ids and strings are re-encoded into **order-preserving dense
+spaces**, so the device tables (tpu/ell.py, built from these edge
+arrays) index vertices in int32 and every comparison is numeric:
 
   * vertex ids  → dense indices into the sorted ``vids`` array.  Sorted
     order means dense-index comparisons equal vid comparisons, so filter
     literals translate via searchsorted.
   * strings     → codes into a sorted per-column dictionary; the sort makes
     codes order-preserving too, so ==/!=/</> all compile.
-  * int columns → int32 when the value range fits, else float32 when
-    exactly representable, else the column is marked uncompilable and the
-    runtime falls back to the CPU path for filters touching it.
+  * numbers     → int64 / float64 as stored, the CPU executor's precision.
 
-Host numpy mirrors of every column are kept for result materialization
-(the device returns bool masks; the host gathers rows with fancy
-indexing — no per-row Python in the hot path).
+The columns stay on the host: the device advances frontiers, and a
+WHERE or a YIELD meets the candidate edges here in numpy (no per-row
+Python in the hot path).
 """
 from __future__ import annotations
 
@@ -77,18 +76,14 @@ class Column:
     schema version may miss appended columns).
     """
 
-    __slots__ = ("name", "stype", "values", "valid", "dictionary",
-                 "device_ok", "raw", "_int32_ok")
+    __slots__ = ("name", "stype", "values", "valid", "dictionary", "raw")
 
     def __init__(self, name: str, stype: SupportedType, size: int):
         self.name = name
         self.stype = stype
         self.valid = np.zeros(size, dtype=bool)
         self.dictionary: Optional[np.ndarray] = None  # sorted unique strings
-        self.device_ok = True
         self.raw: Optional[list] = None
-        self._int32_ok: Optional[bool] = None   # lazily cached: int64
-        # values all int32-representable (device uses int32, else f32)
         if stype == SupportedType.STRING:
             self.raw = [""] * size          # filled then dict-encoded
             self.values = None
@@ -99,105 +94,32 @@ class Column:
         else:  # INT / VID / TIMESTAMP
             self.values = np.zeros(size, dtype=np.int64)
 
-    @staticmethod
-    def numeric_device_ok(values: np.ndarray) -> bool:
-        """THE device-representability decision for a numeric column's
-        values — finalize() and the absorb-merge re-finalize
-        (_refinalize_numeric) both defer here so merged columns can
-        never earn a different device_ok than freshly built ones:
-        int64 must fit int32 or round-trip float32 exactly (the device
-        compares in float32, and CPU-float64 vs device-float32
-        comparisons could otherwise disagree at the boundary); float64
-        must round-trip float32 exactly.  absorb_form() applies the
-        same rules per scalar."""
-        if values.dtype == np.int64 and len(values):
-            lo, hi = int(values.min()), int(values.max())
-            if not (-2**31 < lo and hi < 2**31):
-                as32 = values.astype(np.float32)
-                return bool(np.array_equal(as32.astype(np.int64),
-                                           values))
-        elif values.dtype == np.float64 and len(values):
-            as32 = values.astype(np.float32)
-            return bool(np.array_equal(as32.astype(np.float64), values,
-                                       equal_nan=True))
-        return True
-
     def finalize(self) -> None:
-        """Dictionary-encode strings; decide device representability."""
+        """Dictionary-encode strings."""
         if self.stype == SupportedType.STRING:
             arr = np.asarray(self.raw, dtype=object)
             self.dictionary, codes = np.unique(
                 arr.astype(str), return_inverse=True)
             self.values = codes.astype(np.int32)
             self.raw = arr
-            return
-        if not Column.numeric_device_ok(self.values):
-            self.device_ok = False
-
-    def device_values(self):
-        """int32/float32/bool view for the device (codes for strings)."""
-        if self.stype == SupportedType.STRING:
-            return self.values                      # int32 codes
-        if self.values.dtype == np.int64:
-            if self._is_int32_representable():
-                return self.values.astype(np.int32)
-            return self.values.astype(np.float32)
-        if self.values.dtype == np.float64:
-            return self.values.astype(np.float32)
-        return self.values
-
-    def _is_int32_representable(self) -> bool:
-        """Does the device serve this int64 column as int32 (vs the
-        float32-exact fallback)?  Cached; in-place absorption keeps the
-        invariant because absorb_form refuses representation-changing
-        writes."""
-        if self._int32_ok is None:
-            if len(self.values):
-                lo, hi = int(self.values.min()), int(self.values.max())
-                self._int32_ok = -2**31 < lo and hi < 2**31
-            else:
-                self._int32_ok = True
-        return self._int32_ok
 
     def absorb_form(self, v):
         """The storable form of an in-place write of ``v`` to this
-        column, or _NO_ABSORB when the write would change how the
-        device represents the column (the single source of the same
-        int32/float32 rules device_values serves by — keep in sync):
-
-          * strings: only values already in the dictionary (growing it
-            re-encodes every row's code, torn for racing readers) —
-            returns (raw, code);
-          * int64 on the int32 path: v must fit int32;
-          * int64 on the float32-exact path / float64: v must
-            round-trip through float32, or device and CPU comparisons
-            diverge at the boundary."""
-        if self.stype == SupportedType.STRING:
-            if self.dictionary is None:
-                return _NO_ABSORB
-            s = v if isinstance(v, str) else str(v)
-            pos = int(np.searchsorted(self.dictionary, s))
-            if pos >= len(self.dictionary) \
-                    or str(self.dictionary[pos]) != s:
-                return _NO_ABSORB       # new string: dictionary grows
-            return (s, pos)
-        try:
-            if self.values.dtype == np.int64 and self.device_ok:
-                if self._is_int32_representable():
-                    if not (-2**31 < int(v) < 2**31):
-                        return _NO_ABSORB
-                elif int(np.int64(np.float32(v))) != int(v):
-                    return _NO_ABSORB
-            if self.values.dtype == np.float64 and self.device_ok:
-                if float(np.float64(np.float32(v))) != float(v):
-                    return _NO_ABSORB
-        except (OverflowError, ValueError):
-            # e.g. int64-max values where np.float32 rounds UP to 2^63
-            # and the int64() round-trip overflows (raises on NumPy 2):
-            # any conversion failure means "can't absorb", never an
-            # exception escaping into the live query's mirror() call
+        column, or _NO_ABSORB when the write needs a rebuild.  A
+        number absorbs as it is: the host column holds any int64 or
+        float64 the store does.  A string absorbs only when it is
+        already in the dictionary (growing it re-encodes every row's
+        code, torn for racing readers) — returns (raw, code)."""
+        if self.stype != SupportedType.STRING:
+            return v
+        if self.dictionary is None:
             return _NO_ABSORB
-        return v
+        s = v if isinstance(v, str) else str(v)
+        pos = int(np.searchsorted(self.dictionary, s))
+        if pos >= len(self.dictionary) \
+                or str(self.dictionary[pos]) != s:
+            return _NO_ABSORB       # new string: dictionary grows
+        return (s, pos)
 
     def host_value(self, i: int):
         """Python value at row i (for result rows)."""
@@ -236,8 +158,6 @@ def edge_column(m: "CsrMirror", et, prop: str) -> Optional[Column]:
         for c in parts[1:]:
             got.values[c.valid] = c.values[c.valid]
             got.valid |= c.valid
-        got.device_ok = all(c.device_ok for c in parts) \
-            and Column.numeric_device_ok(got.values)
         cache[(et, prop)] = got
     return got
 
@@ -270,7 +190,6 @@ class CsrMirror:
         # tag presence: tag_id -> bool[n]
         self.has_tag: Dict[int, np.ndarray] = {}
         self.build_version = -1
-        self._device = None   # populated lazily by runtime/kernels
         # FIND PATH's in-edge order by OVER set, built by the runtime
         # at this generation's first path statement under this lock
         # (runtime._path_index); host memory, goes with the generation
@@ -417,8 +336,8 @@ def build_delta_mirror(base: CsrMirror, events, schema_man,
     else:
         # re-seat the shared vertex side in the grown dense space.
         # Vectorized scatters only (no per-row Python, no re-encode:
-        # dictionaries and device_ok carry over — added rows are
-        # invalid, never read), and cached on the base keyed by the
+        # dictionaries carry over — added rows are invalid, never
+        # read), and cached on the base keyed by the
         # extra set: absorptions repeat over the accumulated event
         # list, and this runs under the runtime lock
         ext_key = extra.tobytes()
@@ -435,7 +354,6 @@ def build_delta_mirror(base: CsrMirror, events, schema_man,
             for key, c in base.vertex_cols.items():
                 nc = Column(c.name, c.stype, d.n)
                 nc.valid[remap] = c.valid
-                nc.device_ok = c.device_ok
                 if c.raw is not None:
                     raw = np.empty(d.n, dtype=object)
                     raw[:] = ""
@@ -537,18 +455,6 @@ def build_delta_mirror(base: CsrMirror, events, schema_man,
     return d
 
 
-def _refinalize_numeric(c: Column) -> None:
-    """Re-run finalize()'s device-representability decision on a
-    MERGED numeric column: two individually device_ok sides can mix
-    representation classes (base on the float32-exact path, overlay
-    int32-representable but not float32-exact), and the merged column
-    must re-earn its device_ok on the union of values — through the
-    same Column.numeric_device_ok decision a fresh build uses."""
-    c._int32_ok = None
-    if c.device_ok and c.values is not None and len(c.values):
-        c.device_ok = Column.numeric_device_ok(c.values)
-
-
 def _merge_edge_cols(base: CsrMirror, d: CsrMirror, keep: np.ndarray,
                      order: np.ndarray,
                      m_new: int) -> Dict[Tuple[int, str], Column]:
@@ -568,9 +474,6 @@ def _merge_edge_cols(base: CsrMirror, d: CsrMirror, keep: np.ndarray,
         c.name, c.stype = ref.name, ref.stype
         c.dictionary = None
         c.raw = None
-        c._int32_ok = None
-        c.device_ok = (b is None or b.device_ok) \
-            and (o is None or o.device_ok)
         valid = np.zeros(m_new, dtype=bool)
         if b is not None:
             valid[:kept] = b.valid[keep]
@@ -611,7 +514,6 @@ def _merge_edge_cols(base: CsrMirror, d: CsrMirror, keep: np.ndarray,
             if o is not None:
                 vals[kept:] = o.values
             c.values = vals[order]
-            _refinalize_numeric(c)
         cols[key] = c
     return cols
 
@@ -678,9 +580,7 @@ def plan_vertex_events(base: CsrMirror, events, schema_man,
         EXISTING value is a single-element code store, safe like the
         numeric case — this covers the common re-insert-row-to-update-
         one-field pattern);
-      * TTL'd schemas (need expiry tracking);
-      * values that break a column's device representability (the
-        compiled plans assume the checked range).
+      * TTL'd schemas (need expiry tracking).
 
     Numeric single-element stores are effectively atomic on the host;
     queries racing an absorption see either the old or the new value —

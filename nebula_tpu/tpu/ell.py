@@ -1792,7 +1792,7 @@ def make_batched_bfs_lanes_kernel(ell: EllIndex, max_steps: int,
                                   stop_when_found: bool = True,
                                   donate: bool = False,
                                   push_rows: Optional[int] = None):
-    """Batched BFS (the analogue of kernels.make_bfs_kernel): the
+    """Batched BFS: the
     frontier rides the hop gathers 1-bit packed (the gather traffic is
     the level loop's cost center); the depth matrix stays per-lane (its
     updates are streaming elementwise, and it IS the result).  The loop

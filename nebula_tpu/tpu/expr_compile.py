@@ -4,15 +4,16 @@ The reference evaluates pushed filters per edge row inside the storaged
 scan loop (QueryBaseProcessor.inl:369-396) and remnant WHERE + YIELD per
 row on graphd (GoExecutor.cpp:700-752).  Here the SAME expression tree
 (filter/expressions.py) compiles once into a function over the CSR
-mirror's columns and evaluates for every candidate edge at once — on
-device (jnp) for the filter mask fused into the traversal jit, on host
-(numpy) for YIELD materialization.
+mirror's columns and evaluates for every candidate edge at once, in
+numpy on the host at the CPU executor's precision (int64 / float64):
+a WHERE over the final frontier's candidate edges, a YIELD over the
+rows kept.
 
-Literal translation keeps everything in int32/float32 device space:
-vertex-id literals become dense ranks (csr.vids is sorted), string
-literals become dictionary ranks (dictionaries are sorted) — both
-order-preserving, so every relational op compiles, even when the literal
-itself is absent from the data.
+Literal translation keeps every comparison numeric: vertex-id literals
+become dense ranks (csr.vids is sorted), string literals become
+dictionary ranks (dictionaries are sorted) — both order-preserving, so
+every relational op compiles, even when the literal itself is absent
+from the data.
 
 Unsupported constructs raise CompileError; the runtime then declines the
 query and graphd's CPU path runs it (can_run_go → False).
@@ -35,7 +36,7 @@ from .csr import Column, CsrMirror, edge_column
 
 
 class CompileError(Exception):
-    """Expression not device-compilable → CPU fallback."""
+    """Expression not compilable to columnar ops → CPU fallback."""
 
 
 # value kinds flowing through the compiled graph
@@ -46,8 +47,8 @@ _NUMERIC = (K_INT, K_FLOAT)
 class CVal:
     """A compiled sub-expression: lazily evaluated columnar value.
 
-    ``fn(env) -> array`` where env carries the backend module (np/jnp) and
-    the gathered column arrays.  ``kind`` drives type checking at compile
+    ``fn(env) -> array`` where env carries the gathered column
+    arrays.  ``kind`` drives type checking at compile
     time (schemas make value types static — unlike the reference's per-row
     dynamic checks, mismatches surface before the query runs).
     """
@@ -69,14 +70,13 @@ class CVal:
 class Env:
     """Evaluation environment handed to compiled fns.
 
-    cols: name -> array (backend-native) for every column the compiler
-    registered during compilation; xp: numpy or jax.numpy.
+    cols: name -> numpy array for every column the compiler registered
+    during compilation.
     """
 
-    __slots__ = ("xp", "cols")
+    __slots__ = ("cols",)
 
-    def __init__(self, xp, cols: Dict[str, object]):
-        self.xp = xp
+    def __init__(self, cols: Dict[str, object]):
         self.cols = cols
 
 
@@ -84,19 +84,14 @@ class ExprCompiler:
     """Compiles expressions against one CsrMirror + alias/tag bindings.
 
     Column accesses are recorded in ``self.used`` so the runtime knows
-    exactly which device arrays each compiled filter needs:
+    exactly which columns each compiled value needs:
       ("edge", etype, prop) / ("vertex", tag_id, prop, which="src"|"dst") /
       ("rank",) / ("etype",) / ("src_idx",) / ("dst_idx",)
     """
 
     def __init__(self, mirror: CsrMirror, space_id: int, schema_man,
-                 alias_to_etype: Dict[str, Tuple], host_only: bool = False):
+                 alias_to_etype: Dict[str, Tuple]):
         self.mirror = mirror
-        # the compiled value will only ever run over the host's numpy
-        # columns (int64 / float64, the CPU executor's precision), so a
-        # column the device cannot hold exactly (Column.device_ok) is
-        # no reason to decline
-        self.host_only = host_only
         self.sm = schema_man
         self.space_id = space_id
         self.alias_to_etype = alias_to_etype
@@ -123,8 +118,6 @@ class ExprCompiler:
             # edge type exists but column doesn't -> always-missing prop:
             # the CPU path errors per-row; decline so it handles it.
             raise CompileError(f"no column {alias}.{prop}")
-        if not col.device_ok and not self.host_only:
-            raise CompileError(f"column {alias}.{prop} not device-representable")
         key = f"e:{et}:{prop}"
         self.used[key] = ("edge", et, prop)
         return key, col
@@ -137,8 +130,6 @@ class ExprCompiler:
         col = self.mirror.vertex_cols.get((tag_id, prop))
         if col is None:
             raise CompileError(f"no column {tag}.{prop}")
-        if not col.device_ok and not self.host_only:
-            raise CompileError(f"column {tag}.{prop} not device-representable")
         key = f"v:{which}:{tag_id}:{prop}"
         self.used[key] = ("vertex", tag_id, prop, which)
         return key, col
@@ -222,7 +213,7 @@ class ExprCompiler:
         o = self.compile(expr.operand)
         if expr.op == "!":
             b = _to_bool(o)
-            return CVal(K_BOOL, lambda env: env.xp.logical_not(b.fn(env)))
+            return CVal(K_BOOL, lambda env: np.logical_not(b.fn(env)))
         if expr.op == "-":
             if o.kind not in _NUMERIC:
                 raise CompileError("unary - on non-number")
@@ -236,19 +227,14 @@ class ExprCompiler:
     def _cast(self, expr: TypeCastingExpr) -> CVal:
         o = self.compile(expr.operand)
         t = expr.type_name.lower()
+        if o.kind not in _NUMERIC and o.kind != K_BOOL:
+            raise CompileError(f"cast to {t}")
         if t in ("int", "int64"):
-            if o.kind == K_BOOL:
-                return CVal(K_INT, lambda env: o.fn(env).astype("int32")
-                            if hasattr(o.fn(env), "astype") else int(o.fn(env)))
-            if o.kind in _NUMERIC:
-                return CVal(K_INT, lambda env: env.xp.asarray(
-                    o.fn(env)).astype("int32"))
-            raise CompileError("cast to int")
+            return CVal(K_INT, lambda env: np.asarray(
+                o.fn(env)).astype(np.int64))
         if t in ("double", "float"):
-            if o.kind in _NUMERIC or o.kind == K_BOOL:
-                return CVal(K_FLOAT, lambda env: env.xp.asarray(
-                    o.fn(env)).astype("float32"))
-            raise CompileError("cast to double")
+            return CVal(K_FLOAT, lambda env: np.asarray(
+                o.fn(env)).astype(np.float64))
         raise CompileError(f"cast to {t}")
 
     def _arith(self, expr: ArithmeticExpr) -> CVal:
@@ -270,23 +256,19 @@ class ExprCompiler:
                 # clamp |y| to 1 so guarded-out lanes don't fault
                 def idiv(env):
                     x, y = a.fn(env), b.fn(env)
-                    return env.xp.asarray(
-                        env.xp.sign(x) * env.xp.sign(y) *
-                        (abs(x) // env.xp.maximum(abs(y), 1))
-                    ).astype("int32")
+                    return np.sign(x) * np.sign(y) * (
+                        abs(x) // np.maximum(abs(y), 1))
                 return CVal(K_INT, idiv)
             return CVal(K_FLOAT, lambda env: a.fn(env) / b.fn(env))
         if op == "%":
             self._guard_zero(b)
             if kind != K_INT:
-                return CVal(K_FLOAT, lambda env: env.xp.fmod(
+                return CVal(K_FLOAT, lambda env: np.fmod(
                     a.fn(env), b.fn(env)))
 
             def imod(env):
                 x, y = a.fn(env), b.fn(env)
-                return env.xp.asarray(
-                    env.xp.sign(x) *
-                    (abs(x) % env.xp.maximum(abs(y), 1))).astype("int32")
+                return np.sign(x) * (abs(x) % np.maximum(abs(y), 1))
             return CVal(K_INT, imod)
         if op == "^":
             if a.kind != K_INT or b.kind != K_INT:
@@ -417,9 +399,9 @@ class ExprCompiler:
         b = _to_bool(self.compile(expr.right))
         if expr.op == "&&":
             return CVal(K_BOOL,
-                        lambda env: env.xp.logical_and(a.fn(env), b.fn(env)))
+                        lambda env: np.logical_and(a.fn(env), b.fn(env)))
         return CVal(K_BOOL,
-                    lambda env: env.xp.logical_or(a.fn(env), b.fn(env)))
+                    lambda env: np.logical_or(a.fn(env), b.fn(env)))
 
     _FN1 = {"abs": "abs", "floor": "floor", "ceil": "ceil",
             "round": "round", "sqrt": "sqrt", "cbrt": "cbrt",
@@ -437,7 +419,7 @@ class ExprCompiler:
             attr = self._FN1[name]
             kind = a.kind if name in self._INT_RESULT else K_FLOAT
             return CVal(kind,
-                        lambda env: getattr(env.xp, attr)(a.fn(env)))
+                        lambda env: getattr(np, attr)(a.fn(env)))
         if name in ("pow", "hypot", "atan2") and len(expr.args) == 2:
             a, b = self.compile(expr.args[0]), self.compile(expr.args[1])
             if a.kind not in _NUMERIC or b.kind not in _NUMERIC:
@@ -445,8 +427,8 @@ class ExprCompiler:
             attr = {"pow": "power", "hypot": "hypot",
                     "atan2": "arctan2"}[name]
             return CVal(K_FLOAT,
-                        lambda env: getattr(env.xp, attr)(a.fn(env), b.fn(env)))
-        raise CompileError(f"function {name} not device-compilable")
+                        lambda env: getattr(np, attr)(a.fn(env), b.fn(env)))
+        raise CompileError(f"function {name} not compilable")
 
 
 def _to_bool(v: CVal) -> CVal:
@@ -477,62 +459,3 @@ def _cmp_fn(a: CVal, b: CVal, op: str):
 def _py_cmp(a, b, op: str) -> bool:
     return {"<": a < b, "<=": a <= b, ">": a > b, ">=": a >= b,
             "==": a == b, "!=": a != b}[op]
-
-
-# ====================================================================
-# Kernel-registry entry (tpu/kernels.py KernelSpec): the CVal/Env
-# device-filter machinery as jaxaudit traces it.  A representative
-# compiled WHERE — integer modulo compare AND a division with a LIVE
-# div guard over a non-constant denominator — built by the REAL
-# ExprCompiler (EdgeRankExpr needs no mirror), then evaluated the way
-# runtime._run_go_kernel's fused filter closures evaluate cvals.
-# ====================================================================
-def audit_filter_entry():
-    """(jitted fn(env_cols) -> bool mask, env aval builder) for the
-    registry; the traced graph covers _arith's guarded idiv/imod
-    lowering, _cmp_fn, _to_bool and a div-guard mask merge."""
-    import jax
-    import jax.numpy as jnp
-    from ..filter.expressions import (ArithmeticExpr, EdgeRankExpr,
-                                      LogicalExpr, PrimaryExpr,
-                                      RelationalExpr)
-
-    comp = ExprCompiler(None, 0, None, {"e": (1,)})
-    tree = LogicalExpr(
-        "&&",
-        RelationalExpr("!=",
-                       ArithmeticExpr("%", EdgeRankExpr("e"),
-                                      PrimaryExpr(7)),
-                       PrimaryExpr(0)),
-        RelationalExpr(">=",
-                       ArithmeticExpr("/", PrimaryExpr(10),
-                                      EdgeRankExpr("e")),
-                       PrimaryExpr(0)))
-    cval = comp.compile(tree)
-    guards = list(comp.div_guards)
-
-    def filt(env_cols):
-        env = Env(jnp, env_cols)
-        mask = jnp.asarray(cval.fn(env))
-        if mask.dtype != jnp.bool_:
-            mask = mask != 0
-        for g in guards:
-            mask = mask & jnp.logical_not(g(env))
-        return mask
-
-    return jax.jit(filt)
-
-
-def _expr_filter_buckets(fx):
-    kern = audit_filter_entry()
-    return [(("expr_filter",), kern,
-             ({"rank": fx.aval((fx.m,), np.int32)},))]
-
-
-from .kernels import KernelSpec, register_kernel  # noqa: E402
-
-register_kernel(KernelSpec(
-    "expr_filter", audit_filter_entry, phase_kind="expr_filter",
-    # one compiled program per (space, build, expr) by design; the
-    # audit proves the machinery's IR, not a shape ladder
-    budget=1, instantiate=_expr_filter_buckets, dispatch=(0,)))

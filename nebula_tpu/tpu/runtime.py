@@ -4,10 +4,11 @@ executor seam (BASELINE.json north star).
 The reference runs a multi-hop GO as one storaged RPC fan-out per hop
 plus graphd-side set dedup, and an extra RPC wave for $$-props
 (GoExecutor.cpp:334-431, 531-569).  This runtime answers the same
-executor calls from an HBM-resident CSR mirror instead: the full hop
-loop, the WHERE filter (including $$ refs — no second wave), and the
-frontier dedup all run inside one jitted XLA program; the host only
-materializes the selected result rows from numpy column mirrors.
+executor calls from HBM-resident ELL tables instead: the full hop loop
+and the frontier dedup run as jitted XLA programs; the WHERE filter
+(including $$ refs — no second wave) and the YIELD meet the final
+frontier's candidate edges on the host, in numpy over the mirror's
+columns at the CPU executor's precision.
 
 Serving architecture:
 
@@ -37,10 +38,10 @@ Serving architecture:
 
 Fallback contract: ``can_run_go``/``can_run_path`` decline anything the
 device can't reproduce bit-for-bit (per-root $-/$var inputs, expressions
-the compiler rejects, columns too wide for int32/float32) — graphd's CPU
-path then executes the query, exactly like the reference's
-CPU-storaged path.  One flagship rule: whatever both paths can run must
-return identical result sets (tests/test_tpu_backend.py asserts this).
+the compiler rejects) — graphd's CPU path then executes the query,
+exactly like the reference's CPU-storaged path.  One flagship rule:
+whatever both paths can run must return identical result sets
+(tests/test_tpu_backend.py asserts this).
 """
 from __future__ import annotations
 
@@ -67,7 +68,7 @@ from ..graph.interim import InterimResult
 from ..storage.device import DeviceExecError
 from .csr import CsrMirror, build_mirror, edge_column
 from .expr_compile import (CompileError, CVal, Env, ExprCompiler, K_BOOL,
-                           K_FLOAT, K_INT, K_STR, K_STRCODE, K_VIDRANK)
+                           K_FLOAT, K_STR, K_STRCODE, K_VIDRANK)
 from .jax_setup import device_info, ensure_jax_configured
 from . import kernels
 from .ell import (LANE_JOIN_RUNGS, EllIndex, lane_bitmap_rows,
@@ -86,10 +87,10 @@ class _GoPlan:
     """Prepared per-query state handed from can_run_go to run_go."""
 
     __slots__ = ("mirror", "alias_to_etype", "filter_cval", "filter_used",
-                 "pushed_mode", "compiler", "expr_str", "sc_or", "fuse")
+                 "pushed_mode", "compiler", "expr_str", "sc_or")
 
     def __init__(self, mirror, alias_to_etype, filter_cval, filter_used,
-                 pushed_mode, compiler, expr_str, sc_or=False, fuse=False):
+                 pushed_mode, compiler, expr_str, sc_or=False):
         self.mirror = mirror
         self.alias_to_etype = alias_to_etype
         self.filter_cval = filter_cval
@@ -103,11 +104,6 @@ class _GoPlan:
         # invalid used props must decline to the CPU loop then
         # (pure-conjunction masks match skip-on-error exactly)
         self.sc_or = sc_or
-        # the WHERE was compiled for the device (float32 / int32
-        # columns, TpuQueryRuntime._where_fuses) and may fuse into a
-        # hop program of the statement's own; False: compiled for the
-        # host's float64 columns alone, whatever the device could hold
-        self.fuse = fuse
 
 
 def _aliases_of(etype_to_alias: Dict[int, str]) -> Dict[str, Tuple]:
@@ -338,26 +334,11 @@ def _take(arr: np.ndarray, idx) -> np.ndarray:
     return idx.take(arr) if isinstance(idx, _EdgeRuns) else arr[idx]
 
 
-def _pad_pow2(arr: np.ndarray, fill=-1, min_size: int = 8) -> np.ndarray:
-    size = max(min_size, 1 << (max(len(arr), 1) - 1).bit_length())
-    return kernels.pad_to(arr, size, fill)
-
-
+# nebulint: disable=flag-registry
 flags.define(
     "tpu_filter_mode", "auto",
-    "where a GO's WHERE filter evaluates on the device path: 'auto' "
-    "(default — on one device a filtered GO rides the dispatcher and "
-    "the ELL hop program like any other GO, lanes included, and the "
-    "compiled predicate runs in float64 numpy over the final "
-    "frontier's candidate edges at assembly, as under 'host'; on a "
-    "mesh it fuses as under 'device'), 'host' (always that float64 "
-    "pass, bit-identical to the CPU executor path, and every GO "
-    "shape batches through the dispatcher) or 'device' (the mask "
-    "fuses into a CSR hop program of the statement's own: float32, "
-    "O(edges) a statement, no cross-query batching; a column float32 "
-    "does not hold exactly declines there to the CPU executor, where "
-    "the float64 pass serves it).  Managed: UPDATE CONFIGS "
-    "graph:tpu_filter_mode=...")
+    "read by nothing; defined and managed only because benchmark/"
+    "configs/graph500-s20-where.json sets it in set-up (ROADMAP S7 g)")
 flags.define(
     "tpu_device_timing_every", 16,
     "sample every Nth dense/sparse device dispatch with a "
@@ -631,11 +612,6 @@ DEVICE_PHASES = {
                        "h2d": 2, "d2h": 1},
     "mesh_sparse_bfs": {"phases": ("tpu.kernel", "tpu.fetch"),
                         "h2d": 4, "d2h": 2},
-    "go_fused": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 2},
-    "go_filtered": {"phases": ("tpu.kernel",), "h2d": 3, "d2h": 2},
-    "bfs_fused": {"phases": ("tpu.kernel",), "h2d": 2, "d2h": 1},
-    "go_sharded": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 2},
-    "expr_filter": {"phases": ("tpu.kernel",), "h2d": 1, "d2h": 1},
 }
 
 
@@ -1021,7 +997,6 @@ class TpuQueryRuntime:
                 built = build_mirror(space_id, stores, self.sm)
                 if bs is not None:
                     bs.tag(edges=built.m, vertices=built.n)
-            built._device = self._to_device(built)
             with self._lock:
                 return self._publish(space_id, built, ver, stores, vers)
 
@@ -1081,11 +1056,7 @@ class TpuQueryRuntime:
         # NOTE: cached kernels are keyed by TABLE SHAPES and take the
         # tables as arguments (ell.py), so they survive rebuilds AND
         # absorptions (shape_sig moves with a generation only where a
-        # spare is claimed or a pull's reach crosses its step); only the
-        # fused-filter kernels bake mirror-specific constants and
-        # carry build_version in their keys.
-        self._kernels = {k: v for k, v in self._kernels.items()
-                         if not (k[0] == "fused" and k[1] == space_id)}
+        # spare is claimed or a pull's reach crosses its step).
         return m
 
     # ============================================== delta absorption
@@ -1401,7 +1372,6 @@ class TpuQueryRuntime:
             stores = self._stores_for(space_id)
             vers = self._store_versions(space_id, stores)  # pre-build
             m = build_mirror(space_id, stores, self.sm)
-            m._device = self._to_device(m)
             with self._lock:
                 # publish only if the mirror we set out to replace is
                 # still the installed one — anything else means a sync
@@ -1413,52 +1383,6 @@ class TpuQueryRuntime:
         finally:
             with self._lock:
                 self._rebuilding.discard(space_id)
-
-    def _device_csr(self, m: CsrMirror) -> Dict[str, object]:
-        """Device CSR copies (edge arrays + rank) for the fused-filter
-        kernels, built LAZILY per generation: a full build uploads them
-        eagerly as part of its cost, but an absorbed generation defers
-        the O(m) re-upload until a fused/rank query actually needs it —
-        absorption itself stays O(delta) on the link.  The build runs
-        under the per-space build lock with a double-check: N
-        concurrent fused queries hitting a fresh generation must pay
-        ONE upload, not N duplicate multi-GB transfers (the global
-        runtime lock must NOT be held across a device transfer — same
-        stance as the sync mirror build)."""
-        dev = getattr(m, "_device", None)
-        if dev is not None:
-            return dev
-        with self._build_lock(m.space_id):
-            dev = getattr(m, "_device", None)
-            if dev is None:
-                dev = m._device = self._to_device(m)
-            return dev
-
-    @staticmethod
-    def _rank_device_ok(m: CsrMirror) -> bool:
-        """int32-representability of the rank column — a HOST check
-        (min/max over edge_rank), deliberately free of any device
-        transfer: the GO plan gate asks this question per query and an
-        absorbed generation defers its O(m) CSR upload until a fused
-        query pays for it."""
-        return m.m == 0 or bool(m.edge_rank.min() > -2**31
-                                and m.edge_rank.max() < 2**31)
-
-    @staticmethod
-    def _to_device(m: CsrMirror) -> Dict[str, object]:
-        import jax.numpy as jnp
-        with tracing.span("tpu.transfer", edges=int(m.m)):
-            dev = {
-                "edge_src": jnp.asarray(m.edge_src),
-                "edge_dst": jnp.asarray(m.edge_dst),
-                "edge_etype": jnp.asarray(m.edge_etype),
-            }
-            # rank device copy when int32-representable
-            if TpuQueryRuntime._rank_device_ok(m):
-                dev["rank"] = jnp.asarray(m.edge_rank.astype(np.int32))
-            else:
-                dev["rank"] = None
-            return dev
 
     # ================================================== GO planning
     def _plan_go(self, space_id: int, alias_to_etype: Dict[str, Tuple],
@@ -1484,47 +1408,23 @@ class TpuQueryRuntime:
             return None
         filter_cval = None
         filter_used: Dict[str, Tuple] = {}
-        # a WHERE that will meet the candidates on the host in float64
-        # (_host_filter) needs no column the device can hold exactly:
-        # only the fused program compares in float32
-        fuse = where_expr is not None and self._where_fuses()
-        compiler = ExprCompiler(m, space_id, self.sm, alias_to_etype,
-                                host_only=not fuse)
+        compiler = ExprCompiler(m, space_id, self.sm, alias_to_etype)
         if where_expr is not None:
             try:
                 filter_cval = compiler.compile(where_expr)
             except CompileError:
                 return None
             filter_used = dict(compiler.used)
-            if fuse and "rank" in filter_used \
-                    and not self._rank_device_ok(m):
-                # host-side representability check: forcing the lazy
-                # _device_csr upload here would cost an O(m) transfer
-                # per absorbed generation just to answer a plan gate
-                return None
             if compiler.div_guards and not pushed_mode:
-                # graphd-side WHERE raises ExprError on a real x/0; the
-                # device can't raise mid-jit — let the CPU path run it
+                # graphd-side WHERE raises ExprError on a real x/0; a
+                # vectorized mask can't raise for one row — let the
+                # CPU path run it
                 return None
         return _GoPlan(
             m, alias_to_etype, filter_cval, filter_used,
             pushed_mode=pushed_mode, compiler=compiler,
             expr_str=(str(where_expr) if where_expr is not None else None),
-            sc_or=_filter_has_or(where_expr), fuse=fuse)
-
-    @staticmethod
-    def _where_fuses() -> bool:
-        """tpu_filter_mode: 'device' fuses a compiled WHERE into a CSR
-        hop program of the statement's own (float32, no batching,
-        O(edges) a statement); 'auto' (the shipped default) does that
-        only on a mesh, where no benchmark cell has judged the other
-        route yet — on one device a filtered GO takes the dispatcher
-        like any other and its predicate runs in float64 over the final
-        frontier's candidate edges, as under 'host'."""
-        fmode = flags.get("tpu_filter_mode")
-        return fmode == "device" or (
-            fmode == "auto"
-            and int(flags.get("tpu_mesh_devices") or 0) > 1)
+            sc_or=_filter_has_or(where_expr))
 
     def can_run_go(self, space_id: int, etypes: List[int], sentence,
                    pushed: Optional[bytes], remnant: Optional[Expression],
@@ -1637,10 +1537,7 @@ class TpuQueryRuntime:
         pipeline for every rider (go_batch_execute), a WHERE included:
         its hops ride beside unfiltered statements of the same key and
         the predicate meets the final frontier's candidate edges at
-        assembly (_assemble_group).  Only the fused device-filter mode
-        bypasses the dispatcher (its kernel bakes the query's filter;
-        UPTO and reductions keep the dispatcher there too — the fused
-        kernels have no union accumulator)."""
+        assembly (_assemble_group)."""
         from ..storage.device import TpuDecline, classify_device_failure
         bkey = (space_id, "go")
         why = self.breaker.admit(bkey)
@@ -1657,24 +1554,15 @@ class TpuQueryRuntime:
         if sides_read(et_tuple) == 2:
             self._bump("go_bidirect")
         # what _plan_go declined went to the CPU executor before we
-        # ever got here; whether a WHERE fuses was decided there too
-        # (_where_fuses), with the precision it was compiled for
+        # ever got here
         try:
-            if plan.filter_cval is not None and not upto \
-                    and reduce is None and plan.fuse:
-                result = self._execute_fused(space_id, plan, start_vids,
-                                             et_tuple, steps,
-                                             etype_to_alias, yield_cols,
-                                             distinct, where_expr,
-                                             ExcType)
-            else:
-                q = _GoQuery(start_vids, plan, yield_cols, distinct,
-                             where_expr, etype_to_alias, ExcType,
-                             deadline=deadlines.current())
-                result, _m = self.dispatcher.submit_batched(
-                    ("go_batch_execute", space_id, et_tuple, steps, upto,
-                     tuple(reduce) if reduce is not None else None),
-                    q)
+            q = _GoQuery(start_vids, plan, yield_cols, distinct,
+                         where_expr, etype_to_alias, ExcType,
+                         deadline=deadlines.current())
+            result, _m = self.dispatcher.submit_batched(
+                ("go_batch_execute", space_id, et_tuple, steps, upto,
+                 tuple(reduce) if reduce is not None else None),
+                q)
         except Exception as e:      # noqa: BLE001 — classify, then rethrow
             reason = classify_device_failure(e)
             if reason is None:
@@ -2747,7 +2635,7 @@ class TpuQueryRuntime:
         # (dictionary codes, vid ranks)
         if plan.mirror is not m and plan.filter_cval is not None:
             compiler = ExprCompiler(m, space_id, self.sm,
-                                    plan.alias_to_etype, host_only=True)
+                                    plan.alias_to_etype)
             try:
                 cval = compiler.compile(rep.where_expr)
             except CompileError:
@@ -2876,80 +2764,6 @@ class TpuQueryRuntime:
                 inv |= ~col.valid[gather]
         return inv
 
-    # ------------------------------------------------ fused-filter mode
-    def _execute_fused(self, space_id: int, plan: _GoPlan,
-                       start_vids: List[int], et_tuple: Tuple[int, ...],
-                       steps: int, etype_to_alias: Dict[int, str],
-                       yield_cols, distinct: bool, where_expr, ExcType):
-        """tpu_filter_mode=device: the WHERE mask compiles into the same
-        XLA program as the hop loop (expression pushdown -> device,
-        SURVEY.md §7 hard part (c)); no cross-query batching.  The
-        kernel bakes mirror-specific constants, so its cache key keeps
-        build_version."""
-        m = plan.mirror
-        columns = [c.alias or _default_col_name(c.expr) for c in yield_cols]
-        if steps < 1 or not start_vids or m.m == 0:
-            return columns, []
-        from ..storage.device import TpuDecline
-        if plan.pushed_mode and plan.sc_or:
-            # the fused kernel ANDs validity into the mask; a
-            # disjunction short-circuits past missing props on the CPU
-            # path, so any invalid used column declines pre-dispatch
-            # (see _assemble_group — same rule, fused flavor)
-            for k, desc in plan.filter_used.items():
-                if desc[0] == "edge":
-                    col = edge_column(m, desc[1], desc[2])
-                elif desc[0] == "vertex":
-                    col = m.vertex_cols[(desc[1], desc[2])]
-                else:
-                    continue
-                if not col.valid.all():
-                    # nebulint: carveout=invalid-prop-shortcircuit
-                    raise TpuDecline(
-                        "fused WHERE with || reads a partially-invalid "
-                        "column; CPU short-circuit semantics decide")
-        start_idx = _pad_pow2(m.to_dense(start_vids))
-        # the fused dispatch must be phase-attributable like every
-        # other kernel kind (DEVICE_PHASES) — PROFILE otherwise showed
-        # device-filter queries as unattributed wall time
-        with tracing.span("tpu.kernel", kind="go_fused",
-                          starts=len(start_vids)):
-            final_mask, frontier = self._run_go_kernel(
-                m, space_id, steps, et_tuple, plan, start_idx)
-        final_mask = np.asarray(final_mask)
-        frontier = np.asarray(frontier)
-        vs = np.nonzero(frontier[:m.n])[0]
-        cand_idx = (self._frontier_edges(m, vs, et_tuple)
-                    if not plan.pushed_mode else None)
-        idx = np.nonzero(final_mask)[0]
-        if not plan.pushed_mode:
-            inv = self._invalid_candidates(m, plan.filter_used, cand_idx)
-            if inv is not None and inv.any():
-                # graphd-mode WHERE may or may not raise depending on
-                # the row-level evaluation order — the CPU loop decides
-                # nebulint: carveout=invalid-prop-shortcircuit
-                raise TpuDecline(
-                    "WHERE reads a prop invalid on candidate rows; "
-                    "CPU short-circuit semantics decide")
-        # columnar like the dispatcher's rows (one group of one query),
-        # so a client takes both routes' answers in one form
-        rows = self._materialize_group(
-            m, space_id, plan.alias_to_etype, etype_to_alias, yield_cols,
-            idx, np.zeros(len(idx), np.int64),
-            np.asarray([0, len(idx)], np.int64), 1, [ExcType])[0]
-        if isinstance(rows, Exception):
-            raise rows
-        if distinct:
-            seen = set()
-            out = []
-            for r in rows:
-                key = tuple(r)
-                if key not in seen:
-                    seen.add(key)
-                    out.append(r)
-            rows = out
-        return columns, rows
-
     # -------------------------------------------------- host columns
     def _gather_cols(self, m: CsrMirror, alias_to_etype: Dict[str, Tuple],
                      used: Dict[str, Tuple],
@@ -3047,10 +2861,8 @@ class TpuQueryRuntime:
     def _host_filter(self, m: CsrMirror, plan: _GoPlan,
                      idx) -> np.ndarray:
         """Evaluate the compiled WHERE over candidate edges ``idx`` (a
-        row index array, or _EdgeRuns) in numpy float64 — the same
-        cval the device path would run, with the same pushed-mode
-        validity/div-guard semantics, but with the CPU executor's exact
-        precision."""
+        row index array, or _EdgeRuns) in numpy at the CPU executor's
+        precision, with pushed-mode validity/div-guard semantics."""
         if len(idx) == 0:
             return np.zeros(0, dtype=bool)
         # pushed-mode validity is snapshotted BEFORE the value gather:
@@ -3082,7 +2894,7 @@ class TpuQueryRuntime:
                 raise TpuDecline(
                     "pushed WHERE with || over a partially-valid "
                     "prop; per-row short-circuit semantics decide")
-        env = Env(np, self._gather_cols(m, plan.alias_to_etype,
+        env = Env(self._gather_cols(m, plan.alias_to_etype,
                                         plan.filter_used, idx))
         with np.errstate(divide="ignore", invalid="ignore"):
             mask = np.broadcast_to(np.asarray(plan.filter_cval.fn(env)),
@@ -3103,105 +2915,6 @@ class TpuQueryRuntime:
             for k in valid_snap:
                 mask &= valid_snap[k]
         return mask
-
-    # -------------------------------------------------- kernel dispatch
-    def _run_go_kernel(self, m: CsrMirror, space_id: int, steps: int,
-                       et_tuple: Tuple[int, ...], plan: _GoPlan,
-                       start_idx: np.ndarray):
-        import jax.numpy as jnp
-        dev = self._device_csr(m)
-        filt = plan.filter_cval
-        key = ("fused", space_id, m.build_version, steps, et_tuple,
-               plan.pushed_mode, plan.expr_str, len(start_idx))
-        with self._lock:
-            kern = self._kernels.get(key)
-
-        if filt is None:
-            if kern is None:
-                kern = kernels.make_go_kernel(m.n, steps, et_tuple)
-                with self._lock:
-                    self._kernels[key] = kern
-            return kern(dev["edge_src"], dev["edge_dst"], dev["edge_etype"],
-                        jnp.asarray(start_idx))
-
-        # device filter: assemble env columns (full-length, edge- or
-        # vertex-aligned) + validity arrays for pushed (skip) semantics
-        env_cols = self._env_cols(m, plan.alias_to_etype, plan.filter_used,
-                                  with_valid=plan.pushed_mode)
-
-        if kern is None:
-            used = dict(plan.filter_used)
-            cval = filt
-            pushed = plan.pushed_mode
-            guards = list(plan.compiler.div_guards)
-
-            def filter_fn(edge_src, edge_dst, raw):
-                cols = {}
-                for k2, desc2 in used.items():
-                    if desc2[0] == "vertex":
-                        arr = raw[k2]
-                        cols[k2] = arr[edge_src] if desc2[3] == "src" \
-                            else arr[edge_dst]
-                    elif desc2[0] in ("edge", "rank", "etype_alias"):
-                        cols[k2] = raw[k2]
-                    elif desc2[0] == "src_idx":
-                        cols[k2] = edge_src
-                    elif desc2[0] == "dst_idx":
-                        cols[k2] = edge_dst
-                env = Env(jnp, cols)
-                mask = jnp.asarray(cval.fn(env))
-                if mask.dtype != jnp.bool_:
-                    mask = mask != 0   # numeric WHERE: nonzero = truthy
-                mask = jnp.broadcast_to(mask, edge_src.shape)
-                # x/0 raises ExprError on the CPU path; in pushed mode
-                # that drops the row (can_run_go declines remnant mode)
-                for g in guards:
-                    mask = mask & jnp.logical_not(
-                        jnp.broadcast_to(g(env), edge_src.shape))
-                if pushed:
-                    for vk, varr in raw.items():
-                        if not vk.startswith("valid:"):
-                            continue
-                        k2 = vk[6:]
-                        desc2 = used[k2]
-                        if desc2[0] == "edge":
-                            mask = mask & varr
-                        elif desc2[0] == "vertex":
-                            mask = mask & (varr[edge_src]
-                                           if desc2[3] == "src"
-                                           else varr[edge_dst])
-                return mask
-
-            kern = kernels.make_go_filtered_kernel(
-                m.n, steps, et_tuple, filter_fn)
-            with self._lock:
-                self._kernels[key] = kern
-        return kern(dev["edge_src"], dev["edge_dst"], dev["edge_etype"],
-                    jnp.asarray(start_idx), env_cols)
-
-    def _env_cols(self, m: CsrMirror, alias_to_etype: Dict[str, Tuple],
-                  used: Dict[str, Tuple], with_valid: bool) -> Dict:
-        """Device env for a compiled filter: {key: array} (+"valid:key")."""
-        import jax.numpy as jnp
-        env: Dict[str, object] = {}
-        for k, desc in used.items():
-            if desc[0] in ("edge", "vertex"):
-                col = edge_column(m, desc[1], desc[2]) \
-                    if desc[0] == "edge" \
-                    else m.vertex_cols[(desc[1], desc[2])]
-                # valid is snapshotted BEFORE the values are read:
-                # in-place absorption commits values-first/valid-last
-                # (csr.commit_vertex_plan), so validity read here must
-                # never be fresher than the value it gates
-                if with_valid:
-                    env["valid:" + k] = jnp.asarray(col.valid.copy())
-                env[k] = jnp.asarray(col.device_values())
-            elif desc[0] == "rank":
-                env["rank"] = self._device_csr(m)["rank"]
-            elif desc[0] == "etype_alias":
-                env["etype_alias"] = jnp.asarray(
-                    self._etype_alias_codes(m, alias_to_etype))
-        return env
 
     @staticmethod
     def _etype_alias_codes(m: CsrMirror,
@@ -3230,19 +2943,6 @@ class TpuQueryRuntime:
             m, "_etype_mask_cache", et_tuple,
             lambda: np.isin(m.edge_etype,
                             np.asarray(et_tuple, dtype=np.int32)))
-
-    def _frontier_edges(self, m: CsrMirror, vs: np.ndarray,
-                        et_tuple: Tuple[int, ...]) -> np.ndarray:
-        """Final-hop candidate edges (src in the frontier vertex list
-        ``vs``, etype in the OVER set) as an ascending index array.
-
-        Walks CSR row slices of only the frontier vertices —
-        O(|frontier| + candidates) instead of an O(m) gather over every
-        edge (the reference's analogue is the per-vertex prefix scan,
-        QueryBaseProcessor.inl:336-405: it also only touches the
-        frontier's own edges)."""
-        idx, _, _ = self._frontier_edges_multi(m, [vs], et_tuple)
-        return idx
 
     def _over_ranges(self, m: CsrMirror, et_tuple: Tuple[int, ...]):
         """(lo, cnt) int64[n]: vertex v's edges of the OVER set lie at
@@ -3371,7 +3071,7 @@ class TpuQueryRuntime:
         if not clean.any():
             return per_query_fallback()
 
-        env = Env(np, self._gather_cols(m, alias_to_etype, compiler.used,
+        env = Env(self._gather_cols(m, alias_to_etype, compiler.used,
                                         idx))
         if compiler.div_guards:
             g_any = np.zeros(len(idx), dtype=bool)
@@ -3432,7 +3132,7 @@ class TpuQueryRuntime:
                 m, space_id, alias_to_etype, etype_to_alias,
                 yield_cols, idx, exc_type)
 
-        env = Env(np, self._gather_cols(m, alias_to_etype, compiler.used,
+        env = Env(self._gather_cols(m, alias_to_etype, compiler.used,
                                         idx))
 
         # a real x/0 in a YIELD raises on the CPU path — per-row eval

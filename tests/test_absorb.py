@@ -399,6 +399,42 @@ class TestOverflowObservability:
             c.stop()
 
 
+class TestVertexDoubleAbsorbsInPlace:
+    """A numeric vertex write absorbs in place whatever the value: the
+    host column holds any double the store does, and nothing compares
+    it in float32 any more."""
+
+    def test_a_double_float32_does_not_hold_absorbs_and_filters(self):
+        c, _cl, ok = _boot("vd", n=12)
+        try:
+            ok("CREATE TAG acct(bal double)")
+            c.refresh_all()
+            # every stored balance is a float32 too
+            ok("INSERT VERTEX acct(bal) VALUES " + ", ".join(
+                f"{100 + i}:({i / 8})" for i in range(12)))
+            q = ("GO FROM " + ", ".join(str(100 + i) for i in range(12))
+                 + " OVER follow WHERE $$.acct.bal > 0.1 "
+                 "YIELD follow._dst, $$.acct.bal")
+            before_rows = _cpu_parity(ok, q)
+            rt = c.tpu_runtime
+            before = dict(rt.stats)
+            # float32(0.1) > 0.1: neither new balance is a float32,
+            # and only one is over 0.1
+            ok("INSERT VERTEX acct(bal) VALUES 103:(0.1), "
+               "104:(0.10000000000000002)")
+            rows = _cpu_parity(ok, q)
+            grew = {k: rt.stats[k] - before[k] for k in
+                    ("mirror_deltas", "mirror_absorb_failed",
+                     "mirror_builds")}
+            assert grew == {"mirror_deltas": 1, "mirror_absorb_failed": 0,
+                            "mirror_builds": 0}
+            assert (104, 0.10000000000000002) in rows
+            assert 103 not in [r[0] for r in rows]
+            assert 103 in [r[0] for r in before_rows]      # it was 0.375
+        finally:
+            c.stop()
+
+
 class TestReachAfterAbsorb:
     """PR 39: a pull gathers, per column range, only the leading rows
     of a bucket that hold a real slot there (EllIndex.reach).  An

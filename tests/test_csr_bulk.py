@@ -32,7 +32,6 @@ def _assert_mirrors_equal(a, b):
         np.testing.assert_array_equal(ca.valid, cb.valid, err_msg=str(k))
         np.testing.assert_array_equal(ca.values, cb.values,
                                       err_msg=str(k))
-        assert ca.device_ok == cb.device_ok, k
         if ca.raw is not None or cb.raw is not None:
             assert [str(x) for x in ca.raw] == [str(x) for x in cb.raw], k
     assert set(a.vertex_cols) == set(b.vertex_cols)

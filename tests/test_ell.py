@@ -332,28 +332,48 @@ def test_runtime_mesh_sharded_parity():
         "CREATE SPACE sm(partition_num=3, replica_factor=1)").ok()
     c.refresh_all()
     assert g.execute("USE sm").ok()
-    assert g.execute("CREATE EDGE e(w int)").ok()
+    assert g.execute("CREATE EDGE e(w int, f double)").ok()
     c.refresh_all()
     rng = np.random.default_rng(13)
-    vals = ", ".join(f"{a}->{b}:({i})" for i, (a, b) in
+    # f: doubles float32 does not hold, 0.7 among them
+    vals = ", ".join(f"{a}->{b}:({i}, {(i % 10) / 10!r})" for i, (a, b) in
                      enumerate(zip(rng.integers(1, 60, 300),
                                    rng.integers(1, 60, 300))))
-    assert g.execute(f"INSERT EDGE e(w) VALUES {vals}").ok()
+    assert g.execute(f"INSERT EDGE e(w, f) VALUES {vals}").ok()
 
     queries = [
         "GO 3 STEPS FROM 1 OVER e YIELD e._dst",
         "GO 2 STEPS FROM 5 OVER e WHERE e.w > 100 YIELD e._dst, e.w",
+        "GO 3 STEPS FROM 2 OVER e WHERE e.f > 0.7 YIELD e._dst, e.f",
+        "GO FROM 7, 9 OVER e WHERE e.f <= 0.7 && e.w % 2 == 1 "
+        "YIELD e._dst, e.f",
         "FIND SHORTEST PATH FROM 1 TO 59 OVER e",
     ]
+    n_where = sum("WHERE" in q for q in queries)
     single = [sorted(map(tuple, g.execute(q).rows)) for q in queries]
+    assert all(single)
+    # ... and the CPU executor answers the same
+    flags.set("storage_backend", "cpu")
+    try:
+        for q, exp in zip(queries, single):
+            assert sorted(map(tuple, g.execute(q).rows)) == exp, q
+    finally:
+        flags.set("storage_backend", "tpu")
     flags.set("tpu_mesh_devices", 8)
     try:
         for mode in ("sparse", "dense"):
             flags.set("tpu_mesh_mode", mode)
+            before = dict(c.tpu_runtime.stats)
             for q, exp in zip(queries, single):
                 r = g.execute(q)
                 assert r.ok(), f"[{mode}] {q}: {r.error_msg}"
                 assert sorted(map(tuple, r.rows)) == exp, (mode, q)
+            # a WHERE on a mesh rides the dispatcher and filters at
+            # assembly like any other: no route of its own
+            grew = {k: c.tpu_runtime.stats[k] - before[k]
+                    for k in ("go_where", "go_device")}
+            assert grew == {"go_where": n_where,
+                            "go_device": n_where + 1}, (mode, grew)
         # the frontier-sharded paths must have actually served, and
         # mesh-served FIND PATH must count in path_device like every
         # other device BFS (the serving accounting the benches report)

@@ -1,11 +1,11 @@
 """A filtered GO through the system's normal entry (LocalCluster,
 tpu_backend=True, the shipped flags) against the benchmark's plain
-reference (benchmark/semantics/go_where.py): under the shipped
-tpu_filter_mode=auto on one device it rides the dispatcher and the
-lanes like any other GO, its predicate meets the final frontier's
-candidate edges at assembly in float64, and the counters and the
-tpu.where span say what was filtered.  CPU jax: no number here is a
-device number."""
+reference (benchmark/semantics/go_where.py): it rides the dispatcher
+and the lanes like any other GO, its predicate meets the final
+frontier's candidate edges at assembly in float64, and the counters and
+the tpu.where span say what was filtered, whatever tpu_filter_mode
+holds (a name nothing reads).  CPU jax: no number here is a device
+number."""
 from __future__ import annotations
 
 import threading
@@ -191,8 +191,6 @@ def test_a_traced_filtered_go_shows_the_dispatcher_and_tpu_where(
     # continuous tier), the windowed batch for 1
     assert ("graph.continuous" if steps > 1 else "graph.batched") in names
     assert {"tpu.launch", "tpu.fetch", "tpu.assemble"} <= names
-    assert not [s for s in spans if s["name"] == "tpu.kernel"
-                and s["tags"].get("kind") == "go_fused"]
     where = [s for s in spans if s["name"] == "tpu.where"]
     assert len(where) == 1
     tags = where[0]["tags"]
@@ -297,11 +295,10 @@ def _walk(node):
         yield from _walk(child)
 
 
-@pytest.mark.parametrize("mode,fused", [("auto", False), ("host", False),
-                                        ("device", True)])
-def test_which_filter_modes_still_fuse_on_one_device(served, mode, fused):
-    """'device' keeps the first-generation program of the statement's
-    own; 'auto' and 'host' go through the dispatcher."""
+@pytest.mark.parametrize("mode", ["auto", "host", "device"])
+def test_no_filter_mode_fuses(served, mode):
+    """Whatever the flag holds, a WHERE goes through the dispatcher:
+    no statement has a hop program of its own."""
     c, g, graph = served
     rt = c.tpu_runtime
     want = graph.answer(_semantics(2, ">", 0.5), 4)
@@ -312,14 +309,11 @@ def test_which_filter_modes_still_fuse_on_one_device(served, mode, fused):
     finally:
         flags.set("tpu_filter_mode", "auto")
     assert resp.ok(), resp.error_msg
-    # both routes hand a client int64 columns, so a harness compares
-    # them by content
     assert reference.same_rows(columns_of(resp), want)
     assert reference.digest(columns_of(resp)) == reference.digest(want)
-    kinds = [s["tags"].get("kind") for s in _spans(resp.raw["profile"])
-             if s["name"] == "tpu.kernel"]
-    assert ("go_fused" in kinds) == fused
-    assert rt.stats["go_where"] - before == (0 if fused else 1)
+    names = {s["name"] for s in _spans(resp.raw["profile"])}
+    assert {"graph.continuous", "tpu.where"} <= names
+    assert rt.stats["go_where"] - before == 1
 
 
 # ---- weights float32 does not hold (the cell's split levels) --------
@@ -330,8 +324,7 @@ SPLIT_LEVELS = 64
 def served_split():
     """(cluster, client, reference graph) over the benchmark's split
     weight table at 64 levels: beside 0.9 two stored doubles that are
-    one float32, so the column is not device-representable
-    (Column.device_ok) and a float32 evaluation answers one wrong."""
+    one float32, so a float32 evaluation answers one wrong."""
     from benchmark.generators.kronecker_split import split_levels
     rng = np.random.default_rng(313)
     key = np.unique(rng.integers(0, N, 1500) * N + rng.integers(0, N, 1500))
@@ -363,21 +356,15 @@ def served_split():
 
 
 @pytest.mark.parametrize("steps", [1, 2, 3])
-@pytest.mark.parametrize("mode,device_served", [("auto", True),
-                                                ("host", True),
-                                                ("device", False)])
+@pytest.mark.parametrize("mode", ["auto", "host", "device"])
 def test_doubles_float32_does_not_hold_are_filtered_in_float64(
-        served_split, steps, mode, device_served):
-    """The dispatcher's route filters on the host in float64, so it
-    serves a column the device could not hold exactly; the fused
-    float32 program still declines it, to the CPU executor.  Either
-    way the rows are the float64 reference's, and not what a float32
-    evaluation of the reference keeps."""
+        served_split, steps, mode):
+    """The WHERE filters on the host in float64 under every value the
+    flag accepts, so every statement is device-served and the rows are
+    the float64 reference's, not what a float32 evaluation of the
+    reference keeps."""
     c, g, graph = served_split
     rt = c.tpu_runtime
-    mirror = rt.mirror(c.graph_meta_client.get_space_id_by_name("ws")
-                       .value())
-    assert not any(col.device_ok for col in mirror.edge_cols.values())
     before = dict(rt.stats)
     told_apart = 0
     flags.set("tpu_filter_mode", mode)
@@ -387,13 +374,102 @@ def test_doubles_float32_does_not_hold_are_filtered_in_float64(
             want = graph.answer(sem, start)
             in32 = graph.answer({**sem, "precision": "float32"}, start)
             got = _served_rows(g, _statement(steps, ">", 0.9, start))
-            if not device_served:   # the CPU executor hands row tuples
-                got = (np.asarray([r[0] for r in got], np.int64),)
             assert reference.same_rows(got, want), (steps, mode, start)
             told_apart += reference.digest(in32) != reference.digest(want)
     finally:
         flags.set("tpu_filter_mode", "auto")
     assert told_apart >= (3 if steps == 1 else 10)
-    served = 12 if device_served else 0
-    assert rt.stats["go_device"] - before["go_device"] == served
-    assert rt.stats["go_where"] - before["go_where"] == served
+    assert rt.stats["go_device"] - before["go_device"] == 12
+    assert rt.stats["go_where"] - before["go_where"] == 12
+
+
+def _vectorised_and_cpu_rows(c, g, monkeypatch, stmt):
+    """(rows, the CPU executor's rows) of a device-served statement
+    that may not fall back to the per-row evaluator."""
+    rt = c.tpu_runtime
+
+    def per_row(*a, **kw):
+        raise AssertionError("fell back to the per-row evaluator")
+    monkeypatch.setattr(rt, "_materialize_per_row", per_row)
+    before = rt.stats["go_device"]
+    got = g.execute(stmt)
+    assert got.ok() and not got.warnings, got.error_msg
+    assert rt.stats["go_device"] == before + 1
+    with flags_set({"storage_backend": "cpu"}):
+        want = g.execute(stmt)
+    assert want.ok(), want.error_msg
+    return sorted(map(tuple, got.rows)), sorted(map(tuple, want.rows))
+
+
+def test_a_yield_of_such_doubles_is_vectorised(served_split, monkeypatch):
+    """A YIELD compiles against the host's float64 column like the
+    WHERE does: no statement falls to the per-row evaluator because
+    float32 would not hold a value it is never given."""
+    c, g, graph = served_split
+    rows, want = _vectorised_and_cpu_rows(
+        c, g, monkeypatch, "GO 2 STEPS FROM 3 OVER knows "
+        "WHERE knows.w > 0.5 YIELD knows._dst, knows.w")
+    assert rows == want and len(rows) > 20
+    assert any(np.float64(np.float32(w)) != w for _, w in rows)
+
+
+def test_no_csr_column_is_on_the_device_after_a_full_build(served):
+    """The device holds the ELL tables; the mirror's edge arrays (2 x
+    edges rows each) stay on the host, where the WHERE and the YIELD
+    read them."""
+    import jax
+    c, g, graph = served
+    rt = c.tpu_runtime
+    _served_rows(g, _statement(2, ">", 0.5, 1))
+    mirror = rt.mirror(c.graph_meta_client.get_space_id_by_name("w")
+                       .value())
+    assert rt.stats["mirror_builds"] >= 1 and mirror.m > 1000
+    assert not hasattr(mirror, "_device")
+    assert not [a.shape for a in jax.live_arrays()
+                if a.shape == (mirror.m,)]
+
+
+# ---- casts and integer arithmetic at the CPU executor's width -------
+BIG = 1 << 40
+
+
+@pytest.fixture(scope="module")
+def served_wide():
+    """(cluster, client): 40 edges out of vertex 1 whose double is no
+    float32 and whose int is no int32."""
+    with flags_set({**shipped_defaults(), "go_backend_router": False}):
+        c = LocalCluster(num_storage=1, tpu_backend=True)
+        g = c.client()
+        for stmt in ("CREATE SPACE wide(partition_num=2, replica_factor=1)",
+                     "USE wide", "CREATE EDGE e(w double, big int)"):
+            r = g.execute(stmt)
+            assert r.ok(), f"{stmt}: {r.error_msg}"
+            c.refresh_all()
+        r = g.execute("INSERT EDGE e(w, big) VALUES " + ", ".join(
+            f"1->{10 + k}:({(k - 20) / 10!r}, {(k - 20) * BIG + k})"
+            for k in range(40)))
+        assert r.ok(), r.error_msg
+        try:
+            yield c, g
+        finally:
+            c.stop()
+
+
+@pytest.mark.parametrize("where,yields", [
+    ("", "(double)e.w, (int)e.big"),
+    ("", "(int)e.w, (double)e.big, (int)(e.w * 1000000000000.0)"),
+    ("", "e.big / 7, e.big % 1000003, -e.big / 3"),
+    ("WHERE (double)e.w >= 0.30000001", "e.w"),
+    (f"WHERE (int)e.big >= {3 * BIG + 23}", "e.big"),
+    (f"WHERE e.big / 3 > {BIG} && e.big % 1000003 < 500000", "e.big"),
+])
+def test_casts_and_integer_arithmetic_keep_the_executor_s_width(
+        served_wide, monkeypatch, where, yields):
+    """``(double)``, ``(int)``, ``/`` and ``%`` evaluate in float64 /
+    int64 on the device path's host pass, as the CPU executor does."""
+    c, g = served_wide
+    rows, want = _vectorised_and_cpu_rows(
+        c, g, monkeypatch, f"GO FROM 1 OVER e {where} YIELD e._dst, {yields}")
+    assert rows == want
+    assert 0 < len(rows) <= 40 and (where == "") == (len(rows) == 40)
+    assert [type(v) for v in rows[0]] == [type(v) for v in want[0]]

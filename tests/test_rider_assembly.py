@@ -33,7 +33,7 @@ import pytest
 
 import nebula_tpu.graph.backend_router    # noqa: F401 — define the flags
 from nebula_tpu.cluster import LocalCluster
-from nebula_tpu.common import flight, tracing
+from nebula_tpu.common import flight, hostclock, tracing
 from nebula_tpu.common.flags import flags
 from nebula_tpu.common.tracing import trace_store
 from nebula_tpu.graph import batch_dispatch as bd
@@ -395,17 +395,18 @@ def test_spans_counters_handed_and_the_five_waits(served, monkeypatch):
     c, ok = served
     rt = c.tpu_runtime
     flags.set("trace_sample_rate", 1.0)
-    walls = {}                  # COHORT's index -> (trace id, wall us)
+    walls = {}          # COHORT's index -> (trace id, wall us, cpu us)
     real_submit = bd._ContinuousStream.submit
 
     def timed(self, key, payload, steps, upto, reduce):
-        t0 = time.perf_counter()
+        h0 = hostclock.stamp()
         try:
             return real_submit(self, key, payload, steps, upto, reduce)
         finally:
             (start,) = payload.start_vids
+            wall, cpu, _runq = hostclock.split(h0, hostclock.stamp())
             walls[int(start) - 1] = (tracing.current_context()[0],
-                                     (time.perf_counter() - t0) * 1e6)
+                                     wall, cpu)
 
     monkeypatch.setattr(bd._ContinuousStream, "submit", timed)
     spy, finishes = _Spy(rt), []
@@ -464,7 +465,7 @@ def test_spans_counters_handed_and_the_five_waits(served, monkeypatch):
     all_wheres = []
     outside = []
     for i, stmt in enumerate(COHORT):
-        trace_id, wall = walls[i]
+        trace_id, wall, cpu = walls[i]
         nodes = _rider_nodes(trace_id)
         names = [n["name"] for n in nodes]
         mark = [n["tags"] for n in nodes
@@ -499,10 +500,11 @@ def test_spans_counters_handed_and_the_five_waits(served, monkeypatch):
         assert all(mark[w] >= 0 for w in tracing.RIDER_WAITS), mark
         total = sum(mark[w] for w in tracing.RIDER_WAITS)
         # the stamps tile enq_t -> its rows; submit() adds admission
-        # before and the marker after: a few us each, unless this
-        # thread loses the interpreter there (nine run at once)
+        # before and the marker after.  What they cost is read on this
+        # thread's own CPU clock: its wall also holds every wait for
+        # the interpreter (nine run at once, beside other workers)
         assert total <= wall
-        outside.append(wall - total)
+        outside.append(cpu - mark["wait_cpu_us"] - mark["assemble_cpu_us"])
     # three tpu.where spans (the declined statement's group opens one
     # over the candidates it has left: none): the rider's own pass is
     # native, the pump's two are numpy

@@ -242,13 +242,13 @@ class TestParity:
 
 
 class TestGenerativeWhereDifferential:
-    """Generative CPU-vs-device WHERE differential (VERDICT r5 ask #5,
-    the tpu_filter_mode=auto default's safety net): seeded-random
-    predicates composed from atoms covering int/float/string columns,
-    src/dst vertex props MISSING on some vertices, TTL-expired rows,
-    modulo and division with a zero divisor present — executed under
-    every filter mode (host float64 / fused device / auto) and
-    compared against the CPU backend: same rows, or the same error."""
+    """Generative CPU-vs-device WHERE differential (VERDICT r5 ask #5):
+    seeded-random predicates composed from atoms covering
+    int/float/string columns, src/dst vertex props MISSING on some
+    vertices, TTL-expired rows, modulo and division with a zero divisor
+    present — executed under every value tpu_filter_mode accepts (all
+    of them the host's float64 pass at assembly) and compared against
+    the CPU backend: same rows, or the same error."""
 
     ATOMS = [
         "rel.i > {a}",
@@ -415,68 +415,9 @@ class TestKernels:
         assert np.asarray(frontier).tolist() == [False, True, True, False,
                                                  False]
 
-    def test_bfs_depth(self):
-        import jax.numpy as jnp
-        es, ed, ee = self._arrays()
-        kern = kernels.make_bfs_kernel(5, 5, (1,), stop_when_found=False)
-        d = kern(es, ed, ee, jnp.asarray(np.array([0], dtype=np.int32)),
-                 jnp.asarray(np.array([4], dtype=np.int32)))
-        assert np.asarray(d).tolist() == [0, 1, 1, 2, 3]
-
-    def test_sharded_go_matches_single_device(self):
-        import jax
-        from jax.sharding import Mesh
-        rng = np.random.RandomState(7)
-        n, m = 64, 400
-        es = rng.randint(0, n, m).astype(np.int32)
-        ed = rng.randint(0, n, m).astype(np.int32)
-        ee = rng.choice([1, 2], m).astype(np.int32)
-        start = np.array([3, 11, -1, -1], dtype=np.int32)
-
-        import jax.numpy as jnp
-        single = kernels.make_go_kernel(n, 3, (1,))
-        mask1, f1 = single(jnp.asarray(es), jnp.asarray(ed), jnp.asarray(ee),
-                           jnp.asarray(start))
-
-        devs = np.array(jax.devices())
-        mesh = Mesh(devs, ("parts",))
-        sharded = kernels.make_sharded_go_kernel(mesh, "parts", n, 3, (1,))
-        s_es, s_ed, s_ee, padded = kernels.shard_edges(mesh, "parts", es, ed,
-                                                       ee)
-        f0 = kernels.bitmap_from_idx(jnp.asarray(start), n)
-        mask8, f8 = sharded(s_es, s_ed, s_ee, f0)
-        assert np.array_equal(np.asarray(f1), np.asarray(f8))
-        assert np.array_equal(np.asarray(mask1),
-                              np.asarray(mask8)[:m])
-
-
-class TestFilterModeParity:
-    """tpu_filter_mode=host (dispatcher + float64 host filter) and
-    =device (WHERE fused into the XLA hop program) must produce
-    identical rows for every WHERE-carrying parity query."""
-
-    def test_same_rows_both_filter_modes(self, clusters):
-        from nebula_tpu.common.flags import flags
-        _, _, tpu_c, tpu = clusters
-        where_queries = [q for q in PARITY_QUERIES if "WHERE" in q]
-        assert where_queries
-        host_rows = {}
-        for q in where_queries:
-            r = tpu.execute(q)
-            assert r.ok(), f"{q}: {r.error_msg}"
-            host_rows[q] = sorted(map(tuple, r.rows))
-        flags.set("tpu_filter_mode", "device")
-        try:
-            for q in where_queries:
-                r = tpu.execute(q)
-                assert r.ok(), f"{q}: {r.error_msg}"
-                assert sorted(map(tuple, r.rows)) == host_rows[q], q
-        finally:
-            flags.set("tpu_filter_mode", "host")
-
 
 class TestFrontierEdges:
-    """_frontier_edges (CSR row-slice final-hop candidate assembly) must
+    """_frontier_edges_multi (CSR row-slice final-hop candidate assembly) must
     equal the flat frontier[edge_src] gather in both density regimes —
     it replaces round 1's per-query O(m) host pass."""
 
@@ -509,9 +450,9 @@ class TestFrontierEdges:
         flat = np.nonzero(
             frontier[mir.edge_src]
             & np.isin(mir.edge_etype, np.asarray(et_tuple, np.int32)))[0]
-        got = TpuQueryRuntime._frontier_edges(
+        got, _, _ = TpuQueryRuntime._frontier_edges_multi(
             TpuQueryRuntime.__new__(TpuQueryRuntime), mir,
-            np.nonzero(frontier)[0], et_tuple)
+            [np.nonzero(frontier)[0]], et_tuple)
         assert np.array_equal(got, flat)
 
 
@@ -626,7 +567,6 @@ class TestFrontierEdges:
 
         class Col:
             dictionary = None
-            device_ok = True
 
             def __init__(self, stype):
                 self.stype = stype

@@ -571,7 +571,7 @@ class TestProfileTpuPhases:
             names = set()
             _walk(prof["roots"][0], names)
             assert {"graph.parse", "graph.executor", "rpc.client",
-                    "rpc.server", "tpu.mirror.build", "tpu.transfer",
+                    "rpc.server", "tpu.mirror.build",
                     "tpu.launch", "tpu.kernel", "tpu.fetch",
                     "tpu.assemble"} <= names, names
             # the trace is fetchable over /traces on both daemons' web
