@@ -135,21 +135,18 @@ class Deployment:
             raise RuntimeError(f"set-up statement failed: {stmt!r}: "
                                f"{resp.error_msg}")
 
-    def load(self, data: dict) -> None:
-        """Bulk-ingest the labelled arrays, fold the CSR mirror, build
-        and upload the ELL tables — each stage timed."""
-        import jax
+    def start(self) -> None:
+        """The cluster up, the space made and the schema in it: the
+        empty deployment, before anything is generated or loaded."""
         from nebula_tpu.cluster import LocalCluster
-        from nebula_tpu.codec.rows import encode_row
         from nebula_tpu.native import lib
-        from nebula_tpu.tools import bulk_load as BL
 
         cfg = self.config
         if lib() is None:
             raise RuntimeError("native library not loaded (Python engine)")
         space = cfg["space"]
         self.cluster = c = LocalCluster(num_storage=1, tpu_backend=True)
-        self.rt = rt = c.tpu_runtime
+        self.rt = c.tpu_runtime
         g = self.client()
         self._must(g, f"CREATE SPACE {space}(partition_num="
                       f"{int(cfg['partition_num'])}, replica_factor="
@@ -159,6 +156,46 @@ class Deployment:
         for stmt in cfg["schema"]:
             self._must(g, stmt)
         c.refresh_all()
+
+    def missing(self, requires: dict) -> List[str]:
+        """What the configuration's ``requires`` names and this program
+        lacks, on the empty space: a flag that is not in the program's
+        registry, a statement its ``EXPLAIN`` refuses (the grammar or
+        the planner lacks the shape), a counter that neither the
+        runtime's served counters (``counters()``) nor the stats
+        registry holds.  A declaration, not a setting: nothing is set
+        and nothing runs."""
+        from nebula_tpu.common.flags import flags
+        from nebula_tpu.common.stats import stats
+        out = [f"flag {name!r} is not in the program's registry"
+               for name in requires.get("flags", ())
+               if flags.info(name) is None]
+        statements = requires.get("statements", ())
+        if statements:
+            g = self.client()
+            self._must(g, f"USE {self.config['space']}")
+            out += [f"statement {stmt!r} is refused: {resp.error_msg}"
+                    for stmt in statements
+                    for resp in [g.execute(f"EXPLAIN {stmt}")]
+                    if not resp.ok()]
+        known = set(self.counters()) | set(stats.names())
+        out += [f"counter {name!r} is not registered"
+                for name in requires.get("counters", ())
+                if name not in known]
+        return out
+
+    def load(self, data: dict) -> None:
+        """Bulk-ingest the labelled arrays into the started deployment
+        (started here where the caller has not), fold the CSR mirror,
+        build and upload the ELL tables — each stage timed."""
+        import jax
+        from nebula_tpu.codec.rows import encode_row
+        from nebula_tpu.tools import bulk_load as BL
+
+        if self.cluster is None:
+            self.start()
+        cfg, c, rt = self.config, self.cluster, self.rt
+        space = cfg["space"]
         sid = c.graph_meta_client.get_space_id_by_name(space).value()
         store = c.storage_nodes[0].kv
         nparts = len(store.part_ids(sid))

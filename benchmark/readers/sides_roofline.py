@@ -1,17 +1,19 @@
 """A hop program's share of its bytes-bound roofline over the traced
-interval where a hop may read both direction tables (a two-signed OVER
-set, ``GO ... BIDIRECT``): slots_roofline's arithmetic with the bytes
-of sides_bytes.visited_bytes, which takes from each tick record how
-many tables its hops read (2 where the record's one-sided hops are 0,
-else 1) and charges a pull every table it swept at a pull's rate and
-the carriers once.  On a one-sided record it is slots_roofline's
-number; on a record without its hop fields (a program from before
-them) it reads nothing.  The rest as there: the program's device time
-in the traced interval, the chip's published HBM rate, the lane width
-the program's own kernel span states.
+interval: what the hops of the tick records that fall in the interval
+had to move (bytes_model.visited_bytes: from the slots each record
+says its hops visited, how many direction tables they read (2 where
+the record's one-sided hops are 0, a two-signed OVER set, ``GO ...
+BIDIRECT``; else 1), the loaded table shapes and the lane width the
+program's own kernel span states) over the device time the trace shows
+for the program in the same interval, against the chip's published HBM
+rate.  A hop's report is read a tick after the hop ran, so the
+interval's edges may hold or miss one hop's slots; hops whose report
+was never read count no bytes, so the share errs low, not high.  On a
+record without its hop fields (a program from before them) it reads
+nothing.
 select: {program: regex, width_span, width_kind, width_tag,
          kind, hops, pushes, slots, onesided}"""
-from ..sides_bytes import visited_bytes
+from ..bytes_model import visited_bytes
 from ..spans import walk
 from .trace_program import matched
 

@@ -3,8 +3,10 @@
 
     python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process: it holds the chip, builds and loads the embedded
-deployment the cell's configuration file describes, warms the shapes
+One process: it holds the chip, builds the embedded deployment the
+cell's configuration file describes, asks it for what the configuration
+``requires`` of the program (a miss ends the run there, exit code 1, no
+result, before anything is generated), loads it, warms the shapes
 the cell's traffic uses, measures for ``--seconds``, holds every
 response of the window to the plain reference, and prints one JSON
 object as its last line.  Without a TPU it exits non-zero and prints no
@@ -165,6 +167,17 @@ def measure(parts: dict, seed: int, seconds: float, trace: bool,
 
     try:
         with flags_set(run_flags):
+            # what the configuration requires of the program is asked
+            # of the empty deployment, before anything is generated: a
+            # program that lacks it ends here in seconds, with no result
+            dep.start()
+            missing = dep.missing(config.get("requires", {}))
+            if missing:
+                for what in missing:
+                    print(f"configuration {config['name']!r} requires "
+                          f"what this program lacks: {what}",
+                          file=sys.stderr)
+                raise SystemExit(1)
             t = time.perf_counter()
             gen = importlib.import_module(
                 f"benchmark.generators.{config['generator']}").generate(
@@ -412,6 +425,20 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
                                  r["sent"] - ev["t0"], r["done"] - ev["t0"],
                                  r.get("rows"), r["failed"]]) + "\n")
     before, after = ev["counters"]["before"], ev["counters"]["after"]
+    # what a retired per-layer guard watched, on the notes line of a
+    # run of its ``cells`` (harness.json "notes": a reader and its
+    # select a key; a reader with nothing to read leaves its key out):
+    # a share that every run of those cells read as 1.0; never
+    # compared, and marked on stderr where it reads anything else
+    seen = {"flight": ev["flight"], "counters": ev["counters"],
+            "statements_done": sum(1 for r in records
+                                   if r["done"] <= ev["t_end"])}
+    watched = {name: g for name, g in
+               parts["harness"].get("notes", {}).items()
+               if parts["cell"]["name"] in g["cells"]}
+    guards = {name: importlib.import_module(
+        f"benchmark.readers.{g['reader']}").read(g["select"], seen)
+        for name, g in watched.items()}
     notes = {"stages": ev["stages"], "reference_s": ev["reference_s"],
              "reference_ms_per_stmt":
              1e3 * ev["reference_s"] / max(len(records), 1),
@@ -420,6 +447,9 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool,
              "compiled_in_window": [
                  {"program": name, "at_s": at - ev["t0"], "seconds": secs}
                  for at, name, secs in ev["compiled"] if at >= ev["t0"]],
+             **{k: v for k, v in guards.items() if v is not None},
+             "guards_off": {k: v for k, v in guards.items()
+                            if v not in (None, 1.0)},
              "memory": ev["memory"], "edges": ev["data"]["edges"],
              "end_to_end": end_to_end,
              "late_max_s": max((r["sent"] - r["due"] for r in records),
@@ -489,6 +519,9 @@ def finish(result: dict, trace: bool) -> int:
         print(f"MARKED: {notes['compiles_in_window']} program(s) compiled "
               f"inside the window: {notes['compiled_in_window']}",
               file=sys.stderr)
+    for name, value in notes.get("guards_off", {}).items():
+        print(f"MARKED: {name} reads {value} where every run of this "
+              f"cell read 1.0 (harness.json, notes)", file=sys.stderr)
     if notes.get("missing_per_layer"):
         # a reader of spans or counters that a later PR adds to the
         # program finds nothing on that PR's parent

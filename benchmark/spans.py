@@ -1,9 +1,10 @@
 """Per-phase self times of a statement's span tree.
 
 A copy of the arithmetic of ``nebula_tpu/common/tracing.py``
-``critical_path`` (PR 21 tree), kept here so that no later PR can move
-the yardstick: each span's self time (its duration minus the merged
-stretch its children cover) is charged to its phase.  ``hop-kernel``
+``critical_path`` (PR 21 tree; the span names of PR 44's), kept here so
+that no later PR can move the yardstick: each span's self time (its
+duration minus the merged stretch its children cover) is charged to
+its phase.  ``hop-kernel``
 there is the ENQUEUE of the device work (``tpu.kernel`` is an async
 launch), not device time, so it is named ``enqueue`` here; a carrier's
 self time is the wait for a window or a lane seat, ``queue``.
@@ -14,10 +15,11 @@ from typing import Dict, Iterator, List, Optional
 
 PHASE_OF = {
     "tpu.mirror.build": "mirror", "tpu.absorb": "mirror",
-    "tpu.peer_absorb": "mirror", "tpu.transfer": "mirror",
+    "tpu.peer_absorb": "mirror",
     "tpu.jit.compile": "enqueue", "tpu.launch": "enqueue",
     "tpu.kernel": "enqueue",
-    "tpu.fetch": "fetch", "tpu.assemble": "assemble",
+    "tpu.fetch": "fetch", "tpu.count": "fetch",
+    "tpu.assemble": "assemble", "tpu.where": "assemble",
 }
 PHASES = ("queue", "mirror", "enqueue", "fetch", "assemble", "other")
 

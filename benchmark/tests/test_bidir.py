@@ -7,9 +7,10 @@ comparison (the count off by one, and the DIRECTED count in its place:
 each ``correct: false`` by ``digest_mismatches`` and
 ``exact_mismatches`` and no other limit); the kind found by name, the
 cell resolved and its configuration held to ``graph500-s20-khop``'s;
-one traced rehearsal through the harness; and the two-table bytes
-model and its reader on hand-made records.  CPU only: no number here is
-a device number."""
+one traced rehearsal through the harness; and the bytes model that
+counts the tables a hop read (one module and one reader since PR 45:
+``hop_roofline.*`` lists this cell too) on hand-made one-sided and
+two-sided records.  CPU only: no number here is a device number."""
 from __future__ import annotations
 
 import os
@@ -23,8 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import bytes_model, reference, run, sides_bytes  # noqa: E402
-from benchmark.readers import sides_roofline, slots_roofline  # noqa: E402
+from benchmark import bytes_model, reference, run  # noqa: E402
+from benchmark.readers import sides_roofline  # noqa: E402
 from benchmark.semantics import (go_count_distinct,  # noqa: E402
                                  go_count_distinct_bidirect as bidir)
 
@@ -238,10 +239,14 @@ def test_the_bidir_kind_is_found_by_name_and_its_cell_resolves():
                 "partition_num", "replica_factor", "flags", "layout",
                 "edge", "space", "selfcheck", "reduced"):
         assert config[key] == khop[key], key
-    # ... its tier pinned the same way, and then the statement that a
-    # program whose grammar lacks the word refuses
-    assert config["schema"] == khop["schema"] + [
-        "EXPLAIN GO FROM 1 OVER knows BIDIRECT"]
+    # ... its schema and its tier's flag, and then the statement that
+    # a program whose grammar lacks the word refuses: declared under
+    # ``requires`` (PR 45), no set-up statement but CREATE EDGE
+    assert config["schema"] == khop["schema"] == [
+        "CREATE EDGE knows(w double)"]
+    assert config["requires"] == {
+        **khop["requires"],
+        "statements": ["GO FROM 1 OVER knows BIDIRECT"]}
     assert config["guarantees"][:3] == khop["guarantees"][:3]
     assert len(config["guarantees"]) == 4
     assert "from either end" in config["guarantees"][-1]
@@ -254,29 +259,28 @@ def test_the_bidir_kind_is_found_by_name_and_its_cell_resolves():
                      "source": config["source"],
                      "file": "benchmark/configs/graph500-s20-bidir.json",
                      "reduced": ["scale"], "why": entry["why"]}
-    # every .qps family count16 lists but the one-table roofline, in
-    # count16's order (since PR 42 PR 36's host families too: both
-    # cells list the seventeen that need no unpack), then its own
+    # every .qps family count16 lists, in count16's order, and nothing
+    # else: since PR 45 the roofline that counts the tables a hop read
+    # is hop_roofline.qps itself
     listed = [m["name"] for m in parts["per_layer"]]
-    theirs = [m["name"] for m in count16["per_layer"]]
-    assert listed == [n for n in theirs if n != "hop_roofline.qps"] \
-        + ["hop_sides_roofline.qps"]
+    assert listed == [m["name"] for m in count16["per_layer"]]
     assert "pump_unpack_cpu_share.qps" not in listed    # nothing unpacked
-    for name in ("khop_count_roofline.qps", "hop_onesided_share.qps",
+    for name in ("khop_count_roofline.qps", "hop_roofline.qps",
                  "hop_swept_share.qps", "hop_kernel_ms.qps",
                  "hop_sparse_share.qps", "tick_ms.qps",
-                 "device_idle_pct.qps", "compiles_in_window.qps",
+                 "device_idle_pct.qps", "seat_hop_share.qps",
+                 "pump_hold_ms.qps", "held_join_share.qps",
                  "fetch_bytes_per_stmt.qps", "pump_join_ms.qps",
                  "pump_hop_enqueue_ms.qps", "gil_late_ms.qps",
                  "gen_s", "load_s", "fold_s",
                  "ell_s", "compile_s", "warmup_s", "parse_us.qps"):
         assert name in listed, name
-    # ... and the new entry is the benchmark's last, its 128th
+    assert "no hop_roofline.qps" not in parts["cell"]["why"]
+    # the contract's size; the room PR 45 made below it is for PRs
+    # that only add, and is held by no test
     assert len(spec["per_layer"]) <= 128
-    assert spec["per_layer"][-1] == {
-        "name": "hop_sides_roofline.qps", "unit": "%", "better": "higher",
-        "source": "device_trace", "layer": "kernels (tpu/ell.py)",
-        "moves": "qps", "workloads": [BIDIR_CELL]}
+    assert not [m for m in spec["per_layer"]
+                if m["name"].startswith("hop_sides_roofline")]
     assert BIDIR_CELL in next(m for m in spec["end_to_end"]
                               if m["name"] == "qps")["workloads"]
 
@@ -297,17 +301,23 @@ def test_a_rehearsal_of_the_cell_is_correct_and_reads_both_tables(
         assert number["value"] == number.get("limit", number["value"]), \
             name
     metrics = {k: v["value"] for k, v in out["metrics"].items()}
-    assert metrics["hop_onesided_share.qps"] == 0.0
-    assert metrics["khop_counted_share.qps"] == 1.0
+    # what the retired guards watched is on the notes line; that no
+    # hop was one-sided is the statement's text (BIDIRECT) and the
+    # tick records' (the two-table bytes below read it)
+    assert out["notes"]["khop_counted_share"] == 1.0
+    assert "neigh_ridden_share" not in out["notes"]     # rows16's guard
+    assert out["notes"]["guards_off"] == {}
+    assert out["notes"]["counter_growth"].get("rt.hop_onesided", 0) == 0
+    assert 0.5 < metrics["seat_hop_share.qps"] <= 1.0
     assert 3.0 < metrics["khop_hops_per_stmt.qps"] < 4.4   # (2+3+6)/3
     assert metrics["khop_vertices_per_stmt.qps"] > 1
     assert metrics["fetch_bytes_per_stmt.qps"] <= 4 * 128
     assert 0.0 < metrics["hop_swept_share.qps"] <= 1.0
-    assert metrics["compiles_in_window.qps"] == 0
+    assert out["notes"]["compiles_in_window"] == 0
     assert set(out["notes"]["missing_per_layer"]) <= {
         m["name"] for m in parts["per_layer"]
         if m["source"] == "device_trace"}
-    assert "hop_sides_roofline.qps" in out["notes"]["missing_per_layer"]
+    assert "hop_roofline.qps" in out["notes"]["missing_per_layer"]
     grown = out["notes"]["counter_growth"]
     assert grown["rt.go_count_distinct"] == grown["rt.go_device"] \
         == grown["rt.go_reduced"] == grown["rt.go_bidirect"]
@@ -316,50 +326,51 @@ def test_a_rehearsal_of_the_cell_is_correct_and_reads_both_tables(
 # ------------------------------------------------ the two-table bytes
 SHAPES = [[600, 8], [400, 512]]         # 209,600 slots a table, 1,000 rows
 SIZES = (4, 1, 16)                      # index, etype, lane bytes (128 lanes)
+TABLE = 209_600
+CARRIERS = 1_000 * 4 * 16
 
 
-def test_one_sided_records_read_as_bytes_model_reads_them():
-    table = bytes_model.table_slots(SHAPES)
-    assert table == 209_600
-    assert sides_bytes.pull_bytes(SHAPES, 1, *SIZES) \
-        == bytes_model.hop_bytes(SHAPES, *SIZES)
-    for hops, pushes, slots in ((1, 0, table), (3, 1, 2 * table + 520),
-                                (2, 2, 1_040), (0, 0, 0)):
-        for onesided in (hops, None):   # None: a program before the field
-            if hops == 0 and onesided == 0:
-                continue
-            assert sides_bytes.visited_bytes(
-                hops, pushes, slots, onesided, SHAPES, *SIZES) \
-                == bytes_model.visited_bytes(hops, pushes, slots, SHAPES,
-                                             *SIZES)
-    assert sides_bytes.sides_of(3, 3) == 1 and sides_bytes.sides_of(3, 0) == 2
-    assert sides_bytes.sides_of(0, 0) == 1      # no hop read no table
+@pytest.mark.parametrize("hops,pushes,slots,moved", [
+    # what the one-table model read until PR 45, to the byte: a pull is
+    # the table at 21 B a slot and the carriers, a push 37 B a slot
+    (1, 0, TABLE, TABLE * 21 + CARRIERS),
+    (3, 1, 2 * TABLE + 520, 2 * (TABLE * 21 + CARRIERS) + 520 * 37),
+    (2, 2, 1_040, 1_040 * 37),
+    (0, 0, 0, 0),
+])
+def test_a_one_sided_record_moves_what_one_table_holds(hops, pushes,
+                                                       slots, moved):
+    assert bytes_model.table_slots(SHAPES) == TABLE
+    for onesided in (hops, None):   # None: a program before the field
+        assert bytes_model.visited_bytes(
+            hops, pushes, slots, onesided, SHAPES, *SIZES) == moved
+    assert bytes_model.sides_of(3, 3) == 1 and bytes_model.sides_of(3, 0) == 2
+    assert bytes_model.sides_of(0, 0) == 1      # no hop read no table
 
 
 def test_a_two_sided_pull_is_the_pull_twice_less_one_carrier_term():
-    table = bytes_model.table_slots(SHAPES)
-    one = bytes_model.hop_bytes(SHAPES, *SIZES)
-    carriers = 1_000 * 4 * 16
-    assert one == table * 21 + carriers
-    two = sides_bytes.pull_bytes(SHAPES, 2, *SIZES)
-    assert two == 2 * one - carriers == 2 * table * 21 + carriers
+    one = bytes_model.pull_bytes(SHAPES, 1, *SIZES)
+    assert one == TABLE * 21 + CARRIERS
+    two = bytes_model.pull_bytes(SHAPES, 2, *SIZES)
+    assert two == 2 * one - CARRIERS == 2 * TABLE * 21 + CARRIERS
     # a record of one two-sided pull reports both tables' slots
-    assert sides_bytes.visited_bytes(1, 0, 2 * table, 0, SHAPES, *SIZES) \
+    assert bytes_model.visited_bytes(1, 0, 2 * TABLE, 0, SHAPES, *SIZES) \
         == two
     # two pulls and a push that visited 96 slots (3 rows of width 16,
     # both tables)
-    assert sides_bytes.visited_bytes(3, 1, 4 * table + 96, 0, SHAPES,
+    assert bytes_model.visited_bytes(3, 1, 4 * TABLE + 96, 0, SHAPES,
                                      *SIZES) \
         == 2 * two + bytes_model.push_bytes(96, *SIZES)
-    # bytes_model reads the second table as pushed slots, at 37 B where
-    # a pull moves 21: what hop_roofline.qps would read too high by
-    wrong = bytes_model.visited_bytes(1, 0, 2 * table, SHAPES, *SIZES)
-    assert wrong == one + table * 37 and wrong > two
+    # the same record read as one-sided (what hop_roofline.qps would
+    # have read here until PR 45): the second table lands among the
+    # pushed slots, at 37 B where a pull moves 21
+    wrong = bytes_model.visited_bytes(1, 0, 2 * TABLE, 1, SHAPES, *SIZES)
+    assert wrong == one + TABLE * 37 and wrong > two
     # pulls that report fewer slots than the tables they swept hold,
     # more pushes than hops, a record that says nothing: no bytes
-    assert sides_bytes.visited_bytes(1, 0, table, 0, SHAPES, *SIZES) is None
-    assert sides_bytes.visited_bytes(1, 2, table, 0, SHAPES, *SIZES) is None
-    assert sides_bytes.visited_bytes(None, None, None, None, SHAPES,
+    assert bytes_model.visited_bytes(1, 0, TABLE, 0, SHAPES, *SIZES) is None
+    assert bytes_model.visited_bytes(1, 2, TABLE, 0, SHAPES, *SIZES) is None
+    assert bytes_model.visited_bytes(None, None, None, None, SHAPES,
                                      *SIZES) is None
 
 
@@ -391,45 +402,46 @@ def _record(ticks, **over) -> dict:
     return record
 
 
-def test_the_sides_roofline_on_hand_made_records():
-    layer = _layer("hop_sides_roofline")
+def test_the_hop_roofline_on_hand_made_two_sided_and_one_sided_records():
+    layer = _layer("hop_roofline")
     assert layer["reader"] == "sides_roofline"
-    assert layer["select"] == {**_layer("hop_roofline")["select"],
-                               "onesided": "hop_onesided"}
-    table = 209_600
-    two_sided = [{"hop_reads": 1, "hop_sparse": 0, "hop_slots": 2 * table,
+    assert layer["select"]["onesided"] == "hop_onesided"
+    two_sided = [{"hop_reads": 1, "hop_sparse": 0, "hop_slots": 2 * TABLE,
                   "hop_onesided": 0, "hop_swept": 380_000},
                  {"hop_reads": 2, "hop_sparse": 1,
-                  "hop_slots": 2 * table + 96, "hop_onesided": 0,
+                  "hop_slots": 2 * TABLE + 96, "hop_onesided": 0,
                   "hop_swept": 380_096},
                  {"hop_reads": 0, "hop_sparse": 0, "hop_slots": 0,
                   "hop_onesided": 0, "hop_swept": 0}]
-    pull = 2 * table * 21 + 1_000 * 64
+    pull = 2 * TABLE * 21 + CARRIERS
     moved = 2 * pull + 96 * 37      # the tick outside the interval: none
     got = sides_roofline.read(layer["select"], _record(two_sided))
     assert got == pytest.approx(100 * moved / 819e9 / 0.004)
-    # the one-table reader reads the same records 1.4 x too high ...
-    high = slots_roofline.read(_layer("hop_roofline")["select"],
-                               _record(two_sided))
-    assert high > 1.3 * got
-    # ... and one-sided records alike
+    # a reader that does not ask the record for its sides (the select
+    # of hop_roofline.* until PR 45) reads the same records 1.4 x too
+    # high: the second table at a push's rate
+    blind = {**layer["select"], "onesided": "no_such_field"}
+    assert sides_roofline.read(blind, _record(two_sided)) > 1.3 * got
+    # ... and one-sided records alike, with the field or without it
     one_sided = [{"hop_reads": 2, "hop_sparse": 1,
-                  "hop_slots": table + 48, "hop_onesided": 2}]
+                  "hop_slots": TABLE + 48, "hop_onesided": 2}]
+    want = 100 * (TABLE * 21 + CARRIERS + 48 * 37) / 819e9 / 0.004
     assert sides_roofline.read(layer["select"], _record(one_sided)) \
-        == pytest.approx(slots_roofline.read(
-            _layer("hop_roofline")["select"], _record(one_sided)))
+        == pytest.approx(want)
+    assert sides_roofline.read(blind, _record(one_sided)) \
+        == pytest.approx(want)
     # the share counts the table, not the reach: hop_swept moves nothing
     for t in two_sided:
         t["hop_swept"] = t["hop_slots"]
     assert sides_roofline.read(layer["select"], _record(two_sided)) == got
 
 
-def test_the_sides_roofline_reads_nothing_where_there_is_nothing():
+def test_the_hop_roofline_reads_nothing_where_there_is_nothing():
     """A CPU rehearsal (no peaks), no trace, no jit_hop in the trace,
     no kernel span, a program whose ticks carry no hop fields (before
     PR 28), pulls that report one table for two: None each time (left
     out of the line, named on stderr), and no raise."""
-    select = _layer("hop_sides_roofline")["select"]
+    select = _layer("hop_roofline")["select"]
     tick = [{"hop_reads": 1, "hop_sparse": 0, "hop_slots": 419_200,
              "hop_onesided": 0}]
     assert sides_roofline.read(select, _record(tick)) is not None
@@ -452,6 +464,6 @@ def test_the_cell_size_two_sided_pull_cannot_pass_its_roofline():
     shapes = [[452588, 8], [56666, 16], [74253, 32], [6223, 64],
               [34651, 128], [15422, 256], [17871, 512]]     # PR 39's tables
     assert bytes_model.table_slots(shapes) == 24_835_040
-    moved = sides_bytes.pull_bytes(shapes, 2, 4, 1, 16)
+    moved = bytes_model.pull_bytes(shapes, 2, 4, 1, 16)
     assert moved == 2 * 24_835_040 * 21 + 657_674 * 64
     assert moved / 819e9 == pytest.approx(1.325e-3, rel=1e-3)

@@ -21,8 +21,8 @@ if ROOT not in sys.path:
 
 from benchmark import reference, run  # noqa: E402
 from benchmark.generators import kronecker, kronecker_split  # noqa: E402
-from benchmark.readers import (counter_delta, span_duration, span_tag,  # noqa: E402
-                               span_tag_ratio)
+from benchmark.readers import (counter_delta, span_duration,  # noqa: E402
+                               span_tag)
 from benchmark.semantics import go_where  # noqa: E402
 from benchmark.semantics.go import go  # noqa: E402
 
@@ -252,12 +252,22 @@ def test_the_where_readers_on_hand_made_records():
         == pytest.approx(1.4)
     assert counter_delta.read(
         _layer("where_candidates_per_stmt")["select"], record) == 2_000.0
-    assert counter_delta.read(_layer("where_keep_share")["select"],
-                              record) == pytest.approx(0.011)
-    # thread time for each candidate edge: 4,200 us over 300 edges
-    assert _layer("where_cpu_ns_per_edge")["reader"] == "span_tag_ratio"
-    assert span_tag_ratio.read(_layer("where_cpu_ns_per_edge")["select"],
-                               record) == pytest.approx(14_000.0)
+    # what where_cpu_ns_per_edge.qps read until PR 45 (thread time for
+    # each candidate edge) is the quotient of two listed metrics: 1.4 ms
+    # a span over 2,000 candidates a statement
+    assert _layer("where_filter_cpu_ms")["select"]["tag"] == "cpu_us"
+    assert _layer("where_candidates_per_stmt")["select"]["counter"] \
+        == "rt.where_candidates"
+    # what where_keep_share.qps read (a property of the traffic) is the
+    # quotient of two counters every run's notes print under
+    # counter_growth
+    assert (record["counters"]["after"]["rt.where_rows"]
+            - record["counters"]["before"]["rt.where_rows"]) / 8_000 \
+        == pytest.approx(0.011)
+    for gone in ("where_keep_share", "where_cpu_ns_per_edge",
+                 "where_native_share"):
+        assert not os.path.exists(os.path.join(
+            ROOT, "benchmark", "layer_metrics", gone + ".json")), gone
 
 
 def test_the_where_readers_read_nothing_on_a_program_without_them():
@@ -269,7 +279,5 @@ def test_the_where_readers_read_nothing_on_a_program_without_them():
                            "after": {"rt.go_device": 5}}}
     for name, reader in (("where_filter_ms", span_duration),
                          ("where_filter_cpu_ms", span_tag),
-                         ("where_candidates_per_stmt", counter_delta),
-                         ("where_keep_share", counter_delta),
-                         ("where_cpu_ns_per_edge", span_tag_ratio)):
+                         ("where_candidates_per_stmt", counter_delta)):
         assert reader.read(_layer(name)["select"], record) is None

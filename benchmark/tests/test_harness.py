@@ -19,9 +19,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import bytes_model, reference, run, semantics  # noqa: E402
-from benchmark.deploy import label_data  # noqa: E402
-from benchmark.readers import (flight_ratio, slots_roofline,  # noqa: E402
+from benchmark import (bytes_model, reference, run, selfcheck,  # noqa: E402
+                       semantics, spans)
+from benchmark.deploy import (Deployment, flags_set, label_data,  # noqa: E402
+                              shipped_defaults)
+from benchmark.readers import (flight_ratio, sides_roofline,  # noqa: E402
                                span_tag)
 from benchmark.workload import Mix  # noqa: E402
 
@@ -291,11 +293,32 @@ def test_a_traced_run_in_which_no_reader_read_prints_no_result(capsys):
     assert "no per-layer metric" in capsys.readouterr().err
 
 
-# ------------------------------------------------- (e) the slots reader
+@pytest.mark.parametrize("off,marked", [
+    ({}, []),
+    ({"khop_counted_share": 0.98}, ["khop_counted_share"]),
+    ({"neigh_ridden_share": 0.0, "where_native_share": 0.5},
+     ["neigh_ridden_share", "where_native_share"])])
+def test_a_guard_that_reads_off_is_marked_on_stderr(capsys, off, marked):
+    """What a retired per-layer guard watched is compared with nothing,
+    and a run says so itself where it is not what every run read: one
+    MARKED line a guard, as for a compile in the window."""
+    out = {"correct": True, "attempted": 1, "failed": 0,
+           "metrics": {"qps": {"value": 1.0, "unit": "stmt/s"}},
+           "device": dict(CPU), "compared": {},
+           "notes": {"compiles_in_window": 0, "guards_off": off}}
+    assert run.finish(out, trace=False) == 0
+    said = capsys.readouterr()
+    lines = [ln for ln in said.err.splitlines() if ln.startswith("MARKED")]
+    assert [ln.split()[1] for ln in lines] == marked
+    assert json.loads(said.out.strip().splitlines()[-1])["correct"] is True
+
+
+# ------------------------------------ (e) the hop roofline's reader
 SHAPES = [[1000, 8], [10, 512]]         # 13,120 slots, 1,010 rows
 SELECT = run.load_json(run.HERE, "layer_metrics", "hop_roofline.json")[
     "select"]
-PULL = bytes_model.hop_bytes(SHAPES, 4, 4, 16)
+PULL = bytes_model.pull_bytes(SHAPES, 1, 4, 4, 16)
+PULL2 = bytes_model.pull_bytes(SHAPES, 2, 4, 4, 16)
 
 
 def _record(ticks, interval=(1_000, 2_000), seconds=0.5):
@@ -326,39 +349,84 @@ def _share(moved_bytes, seconds=0.5):
     # one of each in one tick
     ([{"hop_reads": 2, "hop_sparse": 1, "hop_slots": 13_120 + 64}],
      PULL + 64 * 40),
-    # a record from before the program reported its hops: one sweep
-    ([{"dur_us": 5}], PULL),
+    # a stream whose OVER set has both signs (no hop one-sided): a
+    # pull sweeps both tables and moves the carriers once
+    ([{"hop_reads": 1, "hop_sparse": 0, "hop_slots": 2 * 13_120,
+       "hop_onesided": 0}], PULL2),
     # a tick that learned of no hop moves nothing, the next one does
     ([{"hop_reads": 0, "hop_sparse": 0, "hop_slots": 0},
       {"hop_reads": 1, "hop_sparse": 1, "hop_slots": 8}], 8 * 40),
 ])
-def test_slots_roofline_counts_what_the_hops_visited(ticks, moved):
+def test_hop_roofline_counts_what_the_hops_visited(ticks, moved):
     assert PULL == 13_120 * 24 + 1_010 * 64
-    assert slots_roofline.read(SELECT, _record(ticks)) == \
+    assert PULL2 == 2 * 13_120 * 24 + 1_010 * 64
+    assert SELECT["onesided"] == "hop_onesided"
+    assert sides_roofline.read(SELECT, _record(ticks)) == \
+        pytest.approx(_share(moved))
+    # a record that says every hop was one-sided reads as one that does
+    # not say: the five cells whose statements name one sign
+    for t in ticks:
+        t.setdefault("hop_onesided", t.get("hop_reads", 0))
+    assert sides_roofline.read(SELECT, _record(ticks)) == \
         pytest.approx(_share(moved))
 
 
-def test_slots_roofline_reads_nothing_where_there_is_nothing():
+def test_hop_roofline_reads_nothing_where_there_is_nothing():
     tick = {"hop_reads": 1, "hop_sparse": 1, "hop_slots": 100}
-    assert slots_roofline.read(SELECT, _record([tick])) is not None
+    assert sides_roofline.read(SELECT, _record([tick])) is not None
+    # a record from before the program reported its hops: the reader
+    # cannot know what moved (until PR 45 it guessed one whole sweep)
+    assert sides_roofline.read(SELECT, _record([tick, {"dur_us": 5}])) \
+        is None
     # no tick inside the traced interval; no interval; no hop program in
     # the trace; hops that moved nothing: never 0 for a roofline share
-    assert slots_roofline.read(SELECT, _record([tick], (5_000, 6_000))) \
+    assert sides_roofline.read(SELECT, _record([tick], (5_000, 6_000))) \
         is None
-    assert slots_roofline.read(SELECT, _record([tick], None)) is None
-    assert slots_roofline.read(SELECT, _record([])) is None
+    assert sides_roofline.read(SELECT, _record([tick], None)) is None
+    assert sides_roofline.read(SELECT, _record([])) is None
     no_hop = _record([tick])
     no_hop["trace"] = {"program_s": {"jit_other": 1.0},
                        "program_runs": {"jit_other": 1}}
-    assert slots_roofline.read(SELECT, no_hop) is None
-    assert slots_roofline.read(SELECT, _record(
+    assert sides_roofline.read(SELECT, no_hop) is None
+    assert sides_roofline.read(SELECT, _record(
         [{"hop_reads": 0, "hop_sparse": 0, "hop_slots": 0}])) is None
-    assert slots_roofline.read(SELECT, {**_record([tick]),
+    assert sides_roofline.read(SELECT, {**_record([tick]),
                                         "trace": None}) is None
     # a pull that reports fewer slots than the loaded table has counts
     # another table than the harness: no share of the wrong bytes
     short = {"hop_reads": 1, "hop_sparse": 0, "hop_slots": 13_119}
-    assert slots_roofline.read(SELECT, _record([tick, short])) is None
+    assert sides_roofline.read(SELECT, _record([tick, short])) is None
+
+
+def test_on_the_recorded_trace_one_sided_hops_read_what_the_parent_read():
+    """``hop_roofline.*`` changed reader in PR 45 and must not have
+    changed number where every hop reads one table (seven of the eight
+    cells): the recorded v5e trace's ``jit_hop`` seconds, three
+    one-sided tick records, and the parent's arithmetic
+    (``bytes_model.visited_bytes`` as PR 44 had it, written out here)
+    give the same float, to the digit."""
+    from benchmark import reduce_trace
+    reduced = reduce_trace.reduce(reduce_trace.read_planes(os.path.join(
+        run.HERE, "recorded", "v5e_small.xplane.pb")))
+    assert reduced["program_runs"]["jit_hop"] == 3.0
+    ticks = [{"hop_reads": 1, "hop_sparse": 0, "hop_slots": 13_120},
+             {"hop_reads": 1, "hop_sparse": 1, "hop_slots": 72},
+             {"hop_reads": 1, "hop_sparse": 0, "hop_slots": 13_120}]
+    for t in ticks:
+        t["hop_onesided"] = t["hop_reads"]
+    record = {**_record(ticks), "trace": reduced,
+              "peaks": run.load_json(run.HERE, "peaks.json")["TPU v5 lite"]}
+    table, rows = 13_120, 1_010
+    pull = table * (4 + 4 + 16) + rows * 4 * 16
+    parents = 0
+    for t in ticks:
+        pulls = t["hop_reads"] - t["hop_sparse"]
+        parents += pulls * pull \
+            + (t["hop_slots"] - pulls * table) * (4 + 4 + 2 * 16)
+    want = 100.0 * parents / record["peaks"]["hbm_bytes_per_s"] \
+        / reduced["program_s"]["jit_hop"]
+    assert sides_roofline.read(SELECT, record) == want
+    assert 0 < want < 105
 
 
 def test_flight_ratio_divides_two_summed_fields():
@@ -416,6 +484,122 @@ def test_latency_is_read_end_to_end_and_as_a_traced_runs_own():
         pytest.approx(96)
     assert window_latency.read(select, {"window": {
         "deadline_s": 30.0, "records": []}}) is None
+
+
+# ------------------------------------------- (g) the phases of a tree
+def test_the_phase_map_charges_a_span_as_the_program_does():
+    """``tpu.where`` is assembly and ``tpu.count`` is fetch, as the
+    program's own ``critical_path`` has them (until PR 45 both fell to
+    ``other``); ``tpu.transfer`` went with the span (PR 44)."""
+    from nebula_tpu.common import tracing
+    assert spans.PHASE_OF == {
+        name: "enqueue" if phase == tracing.PHASE_KERNEL else phase
+        for name, phase in tracing._PHASE_OF.items()}
+    assert "tpu.transfer" not in spans.PHASE_OF
+    leaf = lambda name, start, dur: {  # noqa: E731
+        "name": name, "start_us": start, "duration_us": dur, "tags": {},
+        "children": []}
+    tree = {"roots": [{"name": "graph.query", "start_us": 0,
+                       "duration_us": 1_000, "tags": {}, "children": [
+                           leaf("tpu.fetch", 100, 200),
+                           leaf("tpu.count", 300, 50),
+                           {"name": "tpu.assemble", "start_us": 400,
+                            "duration_us": 500, "tags": {},
+                            "children": [leaf("tpu.where", 450, 300)]}]}]}
+    assert spans.phases(tree) == {
+        "queue": 250, "mirror": 0, "enqueue": 0, "fetch": 250,
+        "assemble": 500, "other": 0}
+
+
+# ------------------------------------ (h) a configuration's requires
+@pytest.mark.parametrize("requires,lacks", [
+    ({"flags": ["go_dispatch_mode", "no_such_flag_of_a_later_pr"]},
+     "flag 'no_such_flag_of_a_later_pr'"),
+    ({"statements": ["GO FROM 1 OVER knows",
+                     "GO FROM 1 OVER knows SIDEWAYS"]},
+     "statement 'GO FROM 1 OVER knows SIDEWAYS'"),
+    ({"counters": ["rt.go_device", "graph.continuous.seat_hops",
+                   "rt.no_such_counter"]},
+     "counter 'rt.no_such_counter'"),
+], ids=["flag", "statement", "counter"])
+def test_a_configuration_that_requires_what_the_program_lacks_ends_early(
+        requires, lacks, monkeypatch, capsys):
+    """Exit code 1 and no result line, before a vertex is generated or
+    labelled; what the program HAS beside the missing name is not
+    named."""
+    from benchmark import deploy
+    from benchmark.generators import kronecker
+
+    def never(*a, **k):
+        raise AssertionError("data was made before requires was asked")
+    monkeypatch.setattr(deploy, "label_data", never)
+    monkeypatch.setattr(kronecker, "generate", never)
+    parts = run.resolve(SPEC, CELL)
+    assert parts["config"]["generator"] == "kronecker"
+    parts["config"] = {**parts["config"], "requires": requires}
+    with pytest.raises(SystemExit) as ended:
+        run.run_cell(parts, seed=4_500_000_011, seconds=2.0, trace=False,
+                     device=CPU, tiny=True)
+    assert ended.value.code == 1
+    said = capsys.readouterr()
+    missing = [line for line in said.err.splitlines()
+               if "requires what this program lacks" in line]
+    assert len(missing) == 1 and lacks in missing[0]
+    for line in said.out.strip().splitlines():      # no result line
+        assert "metrics" not in json.loads(line)
+
+
+@pytest.mark.parametrize("entry", SPEC["configs"],
+                         ids=[c["name"] for c in SPEC["configs"]])
+def test_the_shipped_configurations_declare_and_this_program_has_it(entry):
+    """Each configuration's ``requires`` is met on the empty space, and
+    no set-up statement stands for a declaration any more: a schema is
+    ``CREATE EDGE`` alone, but for the two pins that tests outside
+    ``benchmark/`` still hold (PERF.md section 7, Left by PR 45)."""
+    config = run.load_json(ROOT, entry["file"])
+    requires = config.get("requires", {})
+    assert set(requires) <= {"flags", "statements", "counters"}
+    assert not [s for s in config["schema"] if s.startswith("EXPLAIN")]
+    pins = [s for s in config["schema"] if not s.startswith("CREATE ")]
+    held_outside = {
+        "graph500-s20-path": ["find_path_max_paths"],
+        "graph500-s20-neigh": ["go_dispatch_mode", "query_deadline_ms"]}
+    assert [p.split(":")[1].split("=")[0] for p in pins] \
+        == held_outside.get(entry["name"], [])
+    assert set(held_outside.get(entry["name"], [])) \
+        <= set(requires.get("flags", []))           # declared as well
+    dep = Deployment(config, str(os.path.join(run.OUT_DIR, "requires")))
+    try:
+        with flags_set({**shipped_defaults(), **config["flags"]}):
+            dep.start()
+            assert dep.missing(requires) == []
+            assert len(dep.missing({"flags": ["no_such_flag"],
+                                    "counters": ["rt.no_such"]})) == 2
+    finally:
+        dep.stop()
+
+
+# ----------------------- (i) the rehearsal compares rows before forms
+def test_an_answer_is_its_rows_whatever_form_it_came_in():
+    cols = (np.array([5, 3, 3, 9]), np.array([1, 2, 2, 4]))
+    rows = [(3, 2), (9, 4), (5, 1), (3, 2)]
+    assert selfcheck.row_multiset(cols) == selfcheck.row_multiset(rows)
+    assert not reference.same_rows(cols, rows)      # the form differs
+    assert selfcheck.row_multiset(cols) != selfcheck.row_multiset(rows[:-1])
+    assert selfcheck.row_multiset((np.array([], np.int64),)) \
+        == selfcheck.row_multiset([]) == []
+    assert selfcheck.check_comparator() == []
+
+
+@pytest.mark.parametrize("cell", ["graph500-s20-where.filtered16",
+                                  "graph500-s20-neigh.rows16"])
+def test_the_rehearsal_holds_the_cpu_executor_to_rows_not_forms(cell):
+    """The CPU executor hands a filtered GO's rows, and a DISTINCT's,
+    as tuples; until PR 45 ``python3 -m benchmark.selfcheck`` exited 1
+    on these two cells with every row equal."""
+    only = {**SPEC, "workloads": [w for w in SPEC["workloads"]
+                                  if w["name"] == cell]}
+    assert selfcheck.check_reference_against_cpu_executor(only) == []
 
 
 def test_no_kind_is_named_in_the_harness():

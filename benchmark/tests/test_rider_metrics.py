@@ -5,11 +5,13 @@ over the tick record's ``handed`` / ``leaves``, ``span_tag`` over the
 ``assemble_us`` tag of a rider's ``graph.continuous`` marker).  On a
 program whose records lack them (PR 32's parent: the pump assembles,
 the marker has four waits) both read nothing: left out of the line,
-named on stderr, exit 0.  ``where_native_share.qps`` is the same kind
-of thing: the ``native`` tag of the ``tpu.where`` spans (statements the
-one native pass filtered) over their ``queries`` tag, through
-``span_tag_ratio``; the parent's spans have no such tag.  CPU only: no
-number here is a device number."""
+named on stderr, exit 0.  ``rider_share.lat`` went with PR 45 (half the
+latency cells' statements are counts: it read 0.5 in every run), and
+so did ``where_native_share.qps`` (1.0 in every run): "the native
+filter ran" is a key of every run's notes line now, the growth of
+``rt.where_native`` over that of ``rt.go_where`` through
+``counter_delta`` (``harness.json`` "notes"), and is compared with
+nothing.  CPU only: no number here is a device number."""
 from __future__ import annotations
 
 import os
@@ -24,7 +26,7 @@ if ROOT not in sys.path:
 
 from benchmark import run  # noqa: E402
 from benchmark.readers import (  # noqa: E402
-    flight_ratio, span_tag, span_tag_ratio)
+    counter_delta, flight_ratio, span_tag)
 
 SPEC = run.load_json(ROOT, "BENCHMARK.json")
 RIDER_CELLS = {"graph500-s20.lone8": ("lat", "trav_p50_ms"),
@@ -103,14 +105,16 @@ def test_the_rider_metrics_are_listed_where_the_continuous_tier_serves(
     listed = {m["name"]: m for m in run.resolve(SPEC, cell)["per_layer"]
               if m["name"].split(".")[0] in ("rider_share",
                                              "rider_assemble_ms")}
-    assert sorted(listed) == [f"rider_assemble_ms.{suffix}",
-                              f"rider_share.{suffix}"]
+    # the share reads the statement mix in the latency cells (half are
+    # counts: 0.5 in every run), so only the .qps entry stays (PR 45)
+    assert sorted(listed) == [f"rider_assemble_ms.{suffix}"] + (
+        [f"rider_share.{suffix}"] if suffix == "qps" else [])
     for m in listed.values():
         assert m["moves"] == moves
         assert m["layer"] == \
             "fetch + host assembly (tpu/runtime.py _assemble_*)"
-    assert listed[f"rider_share.{suffix}"]["unit"] == "ratio"
-    assert listed[f"rider_assemble_ms.{suffix}"]["unit"] == "ms"
+        assert m["unit"] == ("ratio" if m["name"].startswith("rider_share")
+                             else "ms")
 
 
 def test_the_path_cell_lists_neither_rider_metric():
@@ -121,42 +125,45 @@ def test_the_path_cell_lists_neither_rider_metric():
                 if m["name"].startswith("rider_")]
 
 
-def _where_tree(**tags) -> dict:
-    return {"roots": [{"name": "graph.query", "start_us": 0,
-                       "duration_us": 30_000, "tags": {},
-                       "children": [
-                           {"name": "tpu.where", "start_us": 100,
-                            "duration_us": 900, "children": [],
-                            "tags": {"site": "assembly",
-                                     "candidates": 5000, "kept": 50,
-                                     "cpu_us": 800, **tags}}]}]}
+def _note(name: str) -> dict:
+    """A guard of the notes line (``harness.json`` "notes")."""
+    return run.load_json(ROOT, "benchmark", "harness.json")["notes"][name]
 
 
-def test_where_native_share_on_hand_made_records():
-    layer = _rider_layer("where_native_share")
-    assert layer["reader"] == "span_tag_ratio"
-    assert layer["select"] == {"span": "tpu.where", "top": "native",
-                               "bottom": "queries", "scale": 1}
-    # three riders' own native passes, one group of two the pump
-    # filtered in numpy
-    record = {"trees": [_where_tree(queries=1, native=1),
-                        _where_tree(queries=1, native=1),
-                        _where_tree(queries=2, native=0),
-                        _where_tree(queries=1, native=1)]}
-    assert span_tag_ratio.read(layer["select"], record) \
+def test_the_native_filter_guard_on_hand_made_counters():
+    note = _note("where_native_share")
+    assert note["reader"] == "counter_delta"
+    assert note["select"] == {"counter": "rt.where_native",
+                              "per": "rt.go_where", "scale": 1}
+    # five filtered statements in the window, three of them by the one
+    # native pass, two by numpy on the pump
+    record = {"counters": {
+        "before": {"rt.go_where": 10, "rt.where_native": 10},
+        "after": {"rt.go_where": 15, "rt.where_native": 13}}}
+    assert counter_delta.read(note["select"], record) \
         == pytest.approx(0.6)
-    # the parent's spans carry no ``native``: nothing to read
-    parent = {"trees": [_where_tree(queries=2), _where_tree(queries=1)]}
-    assert span_tag_ratio.read(layer["select"], parent) is None
-    assert span_tag_ratio.read(layer["select"], {"trees": []}) is None
+    # a window without a filtered statement, and a program without the
+    # counter: nothing to read, the key is left off the line
+    idle = {"counters": {"before": {"rt.go_where": 10,
+                                    "rt.where_native": 10},
+                         "after": {"rt.go_where": 10,
+                                   "rt.where_native": 10}}}
+    assert counter_delta.read(note["select"], idle) is None
+    parent = {"counters": {"before": {"rt.go_where": 1},
+                           "after": {"rt.go_where": 4}}}
+    assert counter_delta.read(note["select"], parent) is None
 
 
-def test_where_native_share_is_listed_in_the_filtered_cell_alone():
-    for cell in [w["name"] for w in SPEC["workloads"]]:
-        listed = [m for m in run.resolve(SPEC, cell)["per_layer"]
-                  if m["name"] == "where_native_share.qps"]
-        if cell == "graph500-s20-where.filtered16":
-            assert len(listed) == 1 and listed[0]["moves"] == "qps"
-            assert listed[0]["unit"] == "ratio"
-        else:
-            assert not listed
+def test_the_retired_guards_are_notes_and_listed_in_no_cell():
+    notes = run.load_json(ROOT, "benchmark", "harness.json")["notes"]
+    assert sorted(notes) == ["khop_counted_share", "neigh_ridden_share",
+                             "where_native_share"]
+    families = {m["name"].split(".")[0] for m in SPEC["per_layer"]}
+    assert not families & (set(notes) | {"compiles_in_window"})
+    cells = {w["name"] for w in SPEC["workloads"]}
+    for name, note in notes.items():    # each by a reader that is there
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmark", "readers", note["reader"] + ".py")), name
+        # watched in the cells that listed it, where it read 1.0 in
+        # every run: any other reading is MARKED on stderr
+        assert note["cells"] and set(note["cells"]) <= cells, name
