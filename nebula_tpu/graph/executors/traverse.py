@@ -1115,8 +1115,11 @@ class FindPathExecutor(Executor):
     jitted batched BFS over the ELL tables).  At most
     ``find_path_max_paths`` rows (the flag), the first under the order
     tpu/runtime.py states above its path walk: targets by ascending
-    id, each vertex's parent edges by ascending (source id, edge type,
-    rank), depth first."""
+    id, each vertex's parent edges by ascending (the vertex before,
+    SIGNED edge type, rank), depth first.  The OVER set is walked by
+    its signs: forwards (+t), REVERSELY (-t: every stored edge from its
+    far end) or BIDIRECT (both, -t before +t), the same signed types
+    GO asks getNeighbors for."""
 
     NAME = "FindPathExecutor"
 
@@ -1127,18 +1130,20 @@ class FindPathExecutor(Executor):
         sm = self.ectx.schema_man
         srcs = self.resolve_vids(s.from_)
         dsts = self.resolve_vids(s.to)
-        if s.over.is_all:
-            etypes = sm.all_edge_types(space)
-        else:
-            etypes = []
-            for oe in s.over.edges:
-                r = sm.to_edge_type(space, oe.edge)
-                if not r.ok():
-                    raise ExecError(f"unknown edge `{oe.edge}'")
-                etypes.append(r.value())
+        # the OVER set's SIGNED edge types, as GO resolves them: +t
+        # walks a stored edge along it, -t (REVERSELY) against it,
+        # BIDIRECT names both; a step crossed against its direction is
+        # printed under the signed name (`<-knows,0>`)
+        try:
+            over = s.over.resolve(sm, space)
+        except KeyError as e:
+            raise ExecError(f"unknown edge `{e.args[0]}'")
+        etypes = sorted({et for ets in over.values() for et in ets})
         max_steps = s.upto.steps if s.upto else 5
-        etype_names = {et: sm.edge_name(space, et) or str(et)
-                       for et in etypes}
+        etype_names = {
+            et: ("-" if et < 0 else "")
+            + (sm.edge_name(space, abs(et)) or str(abs(et)))
+            for et in etypes}
 
         rt = self.ectx.tpu_runtime
         if rt is not None and rt.can_run_path(space, etypes):

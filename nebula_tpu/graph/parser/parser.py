@@ -501,9 +501,6 @@ class _Parser:
             s.to = self.p_vid_list_or_ref()
             if self.at_kw("over"):
                 s.over = self.p_over_clause()
-                if s.over.bidirect:
-                    self.fail("FIND PATH walks OVER forwards: "
-                              "BIDIRECT is a GO clause")
             if self.accept_kw("upto"):
                 n = self.next()
                 if n.type != "INT":

@@ -662,11 +662,12 @@ class TpuQueryRuntime:
         self.stats = {"go_device": 0, "path_device": 0,
                       # FIND PATH: BFS levels the device loops ran,
                       # rows answered, statements cut at find_path_max_paths,
-                      # in-edge orders built (one a mirror generation
-                      # and OVER set, at its first path statement)
+                      # predecessor orders built (one a mirror
+                      # generation and signed OVER set, at its first
+                      # path statement) and the microseconds they took
                       "path_levels": 0, "path_levels_push": 0,
                       "path_rows": 0, "path_capped": 0,
-                      "path_index_builds": 0,
+                      "path_index_builds": 0, "path_index_us": 0,
                       # filtered GO served through the dispatcher:
                       # statements, candidate edges their predicates
                       # were evaluated over, rows kept, and how many
@@ -3700,15 +3701,22 @@ class TpuQueryRuntime:
         return InterimResult(["path"], [[p] for p in sorted(paths)])
 
     def _path_index(self, m: CsrMirror, et_tuple: Tuple[int, ...]):
-        """The in-edge order of one mirror generation for one OVER set:
-        (ptr int64 [n+1], edge ids) with vertex v's in-edges of those
-        types at ``[ptr[v]:ptr[v+1]]`` by ascending (source vid, etype,
-        rank) — the order _reconstruct_paths cuts by.  Built at the
-        generation's first path statement and kept on the mirror (host
-        memory only: 4 bytes an edge of those types), so it goes when
-        the generation does."""
+        """The predecessor order of one mirror generation for one
+        SIGNED OVER set: (ptr int64 [n+1], edge ids) with the mirror
+        rows (u, etype, rank, v) of those types that end in vertex v at
+        ``[ptr[v]:ptr[v+1]]`` by ascending (u's vid, signed etype,
+        rank) — the order _reconstruct_paths cuts by.  A row of +t is a
+        stored edge u -> v, a row of -t the reverse key of a stored
+        v -> u: a forward set indexes v's in-edges, REVERSELY its
+        out-edges, BIDIRECT both, -t before +t.  Built at the
+        generation's first path statement over that set and kept on
+        the mirror (host memory only: 4 bytes a row of those types),
+        so it goes when the generation does; ``path_index_us`` counts
+        what the builds took, traced or not."""
+        import time
         with m._path_index_lock:
             if et_tuple not in m._path_index:
+                t0 = time.perf_counter()
                 with tracing.span("tpu.path_index", edges=int(m.m)):
                     of_type = np.nonzero(np.isin(
                         m.edge_etype, np.asarray(et_tuple, np.int32)))[0]
@@ -3725,6 +3733,8 @@ class TpuQueryRuntime:
                         edge = edge.astype(np.int32)
                 m._path_index[et_tuple] = (ptr, edge)
                 self._bump("path_index_builds")
+                self._bump("path_index_us",
+                           int((time.perf_counter() - t0) * 1e6))
             return m._path_index[et_tuple]
 
     def serve_find_path(self, space_id: int, srcs: List[int],
@@ -4234,10 +4244,17 @@ class _LaneCount:
 # A FIND PATH answers at most ``find_path_max_paths`` rows (the flag,
 # common/flags.py), chosen by a rule over vertex ids (docs/STATUS.md
 # "FIND PATH"): a path is read from its target backwards, one step an
-# edge (source vid, then edge type, then rank), the smaller step first
-# and a path before its extensions; the first that many under that
-# order are the answer.  FindPathExecutor's CPU walk
-# (graph/executors/traverse.py) cuts by the same order.
+# edge (the vertex before, then the SIGNED edge type, then rank), the
+# smaller step first and a path before its extensions; the first that
+# many under that order are the answer.  The signed type is how the
+# step crosses its stored edge: +t along it (u -> v stored, walked
+# u to v), -t against it (v -> u stored, walked u to v: the mirror
+# holds that as the reverse key (u, -t, rank, v)), so -t comes before
+# +t.  A forward OVER set has +t alone, REVERSELY -t alone, BIDIRECT
+# both: a path is a sequence of EDGES, so where u -> v and v -> u are
+# both stored the step between them is two steps and gives two paths.
+# FindPathExecutor's CPU walk (graph/executors/traverse.py) cuts by
+# the same order.
 
 
 def _reconstruct_paths(m: CsrMirror, index, depth: np.ndarray, srcs, dsts,
@@ -4250,20 +4267,26 @@ def _reconstruct_paths(m: CsrMirror, index, depth: np.ndarray, srcs, dsts,
     the in-edges of the vertices on answered paths, never the edge
     table.  Returns (path strings, the span's tags: ``capped`` whether
     more than the cap existed, ``on_path_vertices`` the distinct
-    vertices visited, ``depth`` the longest answered path's edges)."""
+    vertices visited, ``depth`` the longest answered path's edges,
+    ``steps`` the edges of the answered paths and ``rev_steps`` those
+    of them crossed against their direction, a -t step)."""
     from .ell import INT16_INF
     targets = np.unique(m.to_dense(dsts))
     targets = targets[targets >= 0]
     src_set = {int(i) for i in m.to_dense(srcs) if i >= 0}
     paths: List[str] = []
     max_paths = int(flags.get("find_path_max_paths"))
-    found = {"capped": False, "on_path_vertices": 0, "depth": 0}
+    found = {"capped": False, "on_path_vertices": 0, "depth": 0,
+             "steps": 0, "rev_steps": 0}
 
     def rows(verts: np.ndarray, eids: np.ndarray) -> List[str]:
         """Path strings of chains of one length: ``verts`` [k, D+1]
         dense ids target first, ``eids`` [k, D] the edges between."""
         vids = m.vids[verts[:, ::-1]].tolist()
-        ets = m.edge_etype[eids[:, ::-1]].tolist()
+        signed = m.edge_etype[eids[:, ::-1]]
+        found["steps"] += signed.size
+        found["rev_steps"] += int((signed < 0).sum())
+        ets = signed.tolist()
         ranks = m.edge_rank[eids[:, ::-1]].tolist()
         return [" ".join([str(vs[0])] + [
             f"<{etype_names.get(et, et)},{rank}> {v}"

@@ -875,12 +875,19 @@ class TestSeatHop:
         real = query_registry.note_hop
         s.st.sched.runtime.count_distinct_results = \
             lambda counts, hs: [(["n"], [[h]]) for h in hs]
-        query_registry.note_hop = \
-            lambda qid, hop: progress.append(hop) or real(qid, hop)
+        # the registry is the process's: a stream another test left
+        # pumping in this worker reports its riders' hops through the
+        # same door, so the rider has a qid of its own and only its
+        # calls are counted
+        mine = query_registry.register(f"GO {hops} STEPS")
+        query_registry.note_hop = lambda qid, hop: (
+            progress.append(hop) if qid == mine else None) \
+            or real(qid, hop)
         try:
-            m = s.ride(hops)
+            m = s.ride(hops, qid=mine)
         finally:
             query_registry.note_hop = real
+            query_registry.unregister(mine)
         # a rider of one hop keeps none on the lanes after the seat's:
         # it rides as ever
         took = int(seat_takes and hops >= 2)

@@ -143,6 +143,25 @@ def _sizes(compiled):
     return mem.temp_size_in_bytes, mem.generated_code_size_in_bytes
 
 
+@pytest.fixture(scope="module")
+def bfs_at(one_chip):
+    """jit_bfs at the cell's shapes (5 steps, shortest) by (column
+    ranges, OVER set) as (compiled, compile seconds), each compiled
+    once for the module."""
+    from nebula_tpu.tpu import ell as E
+    made = {}
+
+    def get(ranges, etypes):
+        if (ranges, etypes) not in made:
+            made[ranges, etypes] = _compile(
+                E.make_batched_bfs_lanes_kernel(
+                    _Shapes(ranges), 5, etypes, stop_when_found=True,
+                    donate=True), one_chip)
+        return made[ranges, etypes]
+
+    return get
+
+
 # What a program of PR 39 may take on the device over its parent's, in
 # generated code: every column range of a bucket is a loop with a
 # gather of its own, 0.3-0.4 MB each, and code is device memory while
@@ -236,20 +255,38 @@ def test_two_sided_hop_program_compiles_for_the_v5e_at_cell_size(
     assert s_two <= max(s_fwd, s_rev) + 48 * 2**20, (s_two, s_fwd, s_rev)
 
 
-@pytest.mark.parametrize("ranges", [4, 8])
-def test_bfs_program_compiles_for_the_v5e_at_cell_size(one_chip, whole,
-                                                       ranges):
+@pytest.mark.parametrize("ranges, etypes", [
+    (4, (1,)), (8, (1,)), (4, (-1, 1))],
+    ids=["4", "8", "4-two-signed"])
+def test_bfs_program_compiles_for_the_v5e_at_cell_size(bfs_at, whole,
+                                                       ranges, etypes):
     """jit_bfs as graph500-s20-path.closed16 dispatches it (the
     128-lane rung, UPTO 5 STEPS, shortest): every level is the hop's
     step, so the conditional sits inside the level loop, beside a
-    171 MB depth matrix that is live across it."""
+    171 MB depth matrix that is live across it.  The two-signed case
+    is graph500-s20-bipath.bipath16's (PR 46: ``FIND SHORTEST PATH ...
+    OVER knows BIDIRECT``, the OVER set (-t, +t)): a pulled level
+    carries every bucket's loops twice, once a direction table."""
     from nebula_tpu.tpu import ell as E
-    bfs, bfs_s = _compile(
-        E.make_batched_bfs_lanes_kernel(_Shapes(ranges), 5, (1,),
-                                        stop_when_found=True, donate=True),
-        one_chip)
+    bfs, bfs_s = bfs_at(ranges, etypes)
     text = bfs.as_text()
     assert "conditional" in text and "while" in text
+    if len(etypes) == 2:
+        # measured 625.6 MB of scratch + 26.66 MB of code against the
+        # forward program's 511.1 + 17.26 (PR 46): the sides run one
+        # after the other into one accumulator, so the scratch is not
+        # the sum (+114 MB, where the two-sided hop's is +65: 206.2 +
+        # 22.96 against 141.3 + 14.55), and the code holds both
+        # tables' loops.  It is the one BFS program bipath16 loads:
+        # +124 MB on the device over closed16's, 7.7 B an edge
+        scratch, code = _sizes(bfs)
+        fwd_scratch, fwd_code = _sizes(bfs_at(ranges, (1,))[0])
+        assert fwd_code <= code <= 2 * fwd_code + CODE_MARGIN, \
+            (code, fwd_code)
+        assert scratch <= fwd_scratch + 160 * 2**20, (scratch, fwd_scratch)
+        # measured 14.8 s (the forward program 6-9 s)
+        assert bfs_s < 45.0, bfs_s
+        return
     # measured 511.1 MB at 4 ranges, 519.1 at 8 (the whole sweep's
     # program: 570.3; PR 29's sweep-only program: 516.0); a slot
     # table laid out anew for a row read inside the loop shows as

@@ -70,21 +70,33 @@ class TestGo:
         assert again.over == s.over and str(again.over) == str(s.over)
         assert str(s.over).endswith(" BIDIRECT")
 
+    @pytest.mark.parametrize("head", [
+        "GO FROM 1", "FIND SHORTEST PATH FROM 1 TO 2",
+        "FIND ALL PATH FROM 1 TO 2"])
     @pytest.mark.parametrize("word, signs", [
         ("", (1,)), (" REVERSELY", (-1,)), (" BIDIRECT", (-1, 1))])
-    def test_over_clause_round_trips(self, word, signs):
-        s = parse1(f"GO FROM 1 OVER follow AS f, serve{word}")
+    def test_over_clause_round_trips(self, word, signs, head):
+        s = parse1(f"{head} OVER follow AS f, serve{word}")
         assert str(s.over) == f"OVER follow AS f, serve{word}"
         assert s.over.signs() == signs
-        assert parse1(f"GO FROM 1 {s.over}").over == s.over
+        assert parse1(f"{head} {s.over}").over == s.over
+
+    @pytest.mark.parametrize("word, signs", [
+        (" REVERSELY", (-1,)), (" BIDIRECT", (-1, 1))])
+    def test_find_path_takes_the_word_before_upto(self, word, signs):
+        s = parse1(f"FIND SHORTEST PATH FROM 1 TO 2, 3 OVER *{word} "
+                   f"UPTO 4 STEPS")
+        assert s.kind == ast.Kind.FIND_PATH and s.shortest
+        assert s.over.is_all and s.over.signs() == signs
+        assert s.upto.steps == 4
 
     @pytest.mark.parametrize("text, why", [
         ("GO FROM 1 OVER follow REVERSELY BIDIRECT", "exclude each other"),
         ("GO FROM 1 OVER follow BIDIRECT REVERSELY", "exclude each other"),
         ("GO FROM 1 OVER * REVERSELY BIDIRECT", "exclude each other"),
         ("GO FROM 1 OVER BIDIRECT", "edge name"),
-        ("FIND SHORTEST PATH FROM 1 TO 2 OVER follow BIDIRECT",
-         "BIDIRECT is a GO clause"),
+        ("FIND SHORTEST PATH FROM 1 TO 2 OVER follow BIDIRECT REVERSELY",
+         "exclude each other"),
     ])
     def test_bidirect_refused(self, text, why):
         assert why in parse_err(text).msg
