@@ -138,9 +138,12 @@ class FlightRecorder:
         whose sum is assemble_us; rows is what the pump answers
         itself: the COUNT riders' fold, a WHERE that filters in
         numpy), what unpack_us met (unpack_leavers unpacked in the tick's cohorts,
-        of them unpack_live out of the non-zero bytes of their fetched
-        bitmap and not out of the whole of it, unpack_rows the set rows
-        of all of them, summed over the cohorts — tpu/runtime.py
+        of them unpack_live whose set rows were at most
+        LANE_UNPACK_LIVE_SHARE of the table, unpack_rows the set rows
+        of all of them, unpack_native the leavers that went through
+        the native pass — one call for the cohort, native/unpack.cc:
+        all of unpack_leavers, or 0 where the library lacks the entry
+        and numpy unpacks — summed over the cohorts — tpu/runtime.py
         _unpack_lanes), handed
         (the leavers whose frontier went to their own thread, which
         filters and makes the rows: every leaver but a COUNT rider and

@@ -152,8 +152,9 @@ SPAN_NAMES = (
                               # extract buffer (the device, seen from
                               # the pump)
     "pump.d2h",               # the copy after that wait
-    "pump.unpack",            # the cohort's live rows found once,
-                              # per leaver its bit + inv + sort
+    "pump.unpack",            # the cohort's bitmaps to its leavers'
+                              # id arrays: one native call (tag
+                              # native: the leavers it took)
     "pump.rows",              # what the pump answers itself: the
                               # cohort's COUNT fold, a WHERE that
                               # filters in numpy (tag handed: the

@@ -1399,8 +1399,8 @@ class _ContinuousStream:
             # field names (_finish)
             met = {name: sum(f[2][name] for f in finishes)
                    for name in ("unpack_leavers", "unpack_live",
-                                "unpack_rows", "counted", "count_us",
-                                "distinct")}
+                                "unpack_rows", "unpack_native",
+                                "counted", "count_us", "distinct")}
             rec_id = flight.recorder.note_tick(
                 stream=self.space_id, tick=tick_done,
                 seats=occupancy, joins=len(joiners),
@@ -1521,7 +1521,8 @@ class _ContinuousStream:
                          **ran(t_d2h, t_unpack),
                          leavers=met["unpack_leavers"],
                          live=met["unpack_live"],
-                         rows=met["unpack_rows"])
+                         rows=met["unpack_rows"],
+                         native=met["unpack_native"])
             tracing.emit("pump.rows", *at(t_unpack, t_rows),
                          **ran(t_unpack, t_rows), handed=n)
             tracing.emit("pump.handover", *at(t_rows, t_hand),
@@ -1552,7 +1553,8 @@ class _ContinuousStream:
         unpack end, rows end —
         "rows" being what the pump answered itself; the leavers handed
         their frontier; what the fetches met, under the tick record's
-        field names: unpack_leavers, unpack_live, unpack_rows —
+        field names: unpack_leavers, unpack_live, unpack_rows,
+        unpack_native —
         tpu/runtime.py _unpack_lanes — and counted, count_us: the
         counting leavers and the wait for and read of their counts,
         which is the head of the fetch wait — and distinct: the
@@ -1615,7 +1617,8 @@ class _ContinuousStream:
         # a wait this long with both beats on time is the device's
         hostclock.note_wait("fetch_wait", ta, t_wait)
         met = {name: int(getattr(resolver, name, 0)) for name in
-               ("unpack_leavers", "unpack_live", "unpack_rows")}
+               ("unpack_leavers", "unpack_live", "unpack_rows",
+                "unpack_native")}
         met["counted"] = len(counting)
         met["count_us"] = hostclock.split(ta, t_count)[0]
         met["distinct"] = sum(r.distinct for r in fetching)

@@ -165,6 +165,19 @@ def lib() -> Optional[ctypes.CDLL]:
              [vp, vp, vp, vp, ctypes.c_int64, ctypes.c_int32,
               ctypes.c_double, vp])
 
+    # a leave cohort's bitmaps to its leavers' id arrays (tpu/runtime.py
+    # _unpack_lanes). Guarded like ell_build. The count sizes the pass's
+    # output and is microseconds of work: it is called WITH the
+    # interpreter lock (PYFUNCTYPE), because getting the lock back
+    # beside working riders costs more than the count does
+    if hasattr(L, "neb_unpack_lanes"):
+        L.neb_count_lanes = ctypes.PYFUNCTYPE(
+            ctypes.c_int64, vp, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, vp)(("neb_count_lanes", L))
+        _sig(L.neb_unpack_lanes, ctypes.c_int64,
+             [vp, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+              ctypes.c_int64, vp, vp, vp])
+
     # the native beat (common/hostclock.py). Guarded like ell_build
     if hasattr(L, "neb_beat_start"):
         _sig(L.neb_beat_start, ctypes.c_int, [ctypes.c_int64])

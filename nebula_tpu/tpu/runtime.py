@@ -705,6 +705,10 @@ class TpuQueryRuntime:
                       # seat took their first hop
                       # (_ContinuousGoSession.join)
                       "seat_joins": 0, "seat_hops": 0,
+                      # continuous leavers whose bitmap the native
+                      # pass unpacked (_unpack_lanes): all that fetch
+                      # one, or none where the library lacks the entry
+                      "unpack_native": 0,
                       "prewarm_compiled": 0, "prewarm_hits": 0,
                       "prewarm_misses": 0, "prewarm_failed": 0,
                       "t_launch_s": 0.0, "t_fetch_s": 0.0,
@@ -4100,16 +4104,20 @@ class _ContinuousGoSession:
 SEAT_COHORT_EDGES = 8 * LANE_JOIN_RUNGS[-1]
 
 
-# A leaver whose set rows pass this share of the table's vertex rows
-# takes its whole bitmap through ``perm`` (_unpack_lanes); under it, the
-# bits of its non-zero bytes through ``inv`` and a sort.  On the v5e's
-# host at 646,081 rows (PERF.md section 6, PR 37) a leaver costs 0.03
-# ms out of 30 set rows, 0.06 out of 1,000, 0.5 out of 18,000, 2.1 out
-# of 100,000, 2.6 out of 130,000 and 3.9 out of 200,000 that way, and
-# 1.6-1.9 ms at the low counts, 2.5 at 130,000 and 2.9 at 200,000
-# through ``perm``: the two cross at 0.19-0.20 of the table, where
-# the column block's routes crossed too (PR 28).  A speed choice only:
-# both ways give the same array.
+# A leaver whose set rows are at most this share of the table's vertex
+# rows counts as ``unpack_live`` (the tick record's field; the traffic's
+# share of sparse leavers).  The native pass (_unpack_lanes) takes every
+# leaver one way and the share chooses nothing there.  In the numpy form
+# it is the turn between two routes: a leaver over it takes its whole
+# bitmap through ``perm``; under it, the bits of its non-zero bytes
+# through ``inv`` and a sort.  On the v5e's host at 646,081 rows
+# (PERF.md section 6, PR 37) a leaver costs 0.03 ms out of 30 set rows,
+# 0.06 out of 1,000, 0.5 out of 18,000, 2.1 out of 100,000, 2.6 out of
+# 130,000 and 3.9 out of 200,000 that way, and 1.6-1.9 ms at the low
+# counts, 2.5 at 130,000 and 2.9 at 200,000 through ``perm``: the two
+# cross at 0.19-0.20 of the table, where the column block's routes
+# crossed too (PR 28).  A speed choice only: both ways give the same
+# array.
 LANE_UNPACK_LIVE_SHARE = 0.2
 
 
@@ -4121,15 +4129,58 @@ def _unpack_lanes(packed: np.ndarray, n: int, perm: np.ndarray,
     uint8 [L, nb], nb a multiple of eight; row i < ``n_leavers`` is
     leaver i's bitmap, bit k of byte j vertex row k * nb + j
     (ell.lane_bitmap_rows), the bits of rows from n on zero; the rows
-    past the leavers are the rung's padding, never read.  However the
-    device hands the buffer over, the leavers' rows are made one
-    contiguous run each before they are read (on the TPU and on CPU
-    jax they already are).  Returns (per leaver the ascending old
-    dense ids of its set rows, int64: element for element what
-    ``np.nonzero(column_bit[perm])[0]`` gives over the lane's word
-    column; how many leavers were unpacked out of their non-zero bytes
-    and not out of the whole bitmap; the set rows found, summed over
-    the leavers).
+    past the leavers are the rung's padding, never read.  Returns (per
+    leaver the ascending old dense ids of its set rows, int64: element
+    for element what ``np.nonzero(column_bit[perm])[0]`` gives over the
+    lane's word column; how many leavers' set rows were at most
+    LANE_UNPACK_LIVE_SHARE of the table; the set rows found, summed
+    over the leavers; how many leavers the native pass unpacked: all of
+    them or none).
+
+    One call into the native library (native/unpack.cc
+    neb_unpack_lanes, made through ctypes: the interpreter lock is the
+    riders' for its whole length) reads each leaver's own row of the
+    buffer where it lies, marks ``inv`` of every set row in a bitmap of
+    n id bits and reads the marks off in order, so the ids are
+    ascending without a sort and a leaver of few set rows goes the way
+    one of many does.  The ids of all leavers land in one array sized
+    by their counted bits (neb_count_lanes: a pass over the words, made
+    WITH the lock: it costs less than getting the lock back would) and
+    leave as slices of it.  A library that lacks the entry leaves the
+    numpy form (_unpack_lanes_numpy), said once on stderr."""
+    from ..native import lib
+    L = lib()
+    if L is None or not hasattr(L, "neb_unpack_lanes"):
+        _say_once("[tpu] native lane unpack missing: a leave cohort's "
+                  "bitmaps are unpacked in numpy (three to four times "
+                  "slower, and the lock changes hands at every call)")
+        return _unpack_lanes_numpy(packed, n, perm, inv, n_leavers) + (0,)
+    nb = packed.shape[1]
+    own = packed[:n_leavers]
+    if own.strides[1] != 1:
+        # however the device hands the buffer over, a leaver's row is
+        # one run of bytes before it is read (on the TPU and on CPU
+        # jax it already is)
+        own = np.ascontiguousarray(own)
+    inv = np.ascontiguousarray(inv, np.int32)
+    if len(inv) < n:
+        raise IndexError("vertex row outside the index")
+    found = np.empty(n_leavers, np.int64)
+    ids = np.empty(L.neb_count_lanes(
+        own.ctypes.data, own.strides[0], nb, n_leavers,
+        found.ctypes.data), np.int64)
+    L.neb_unpack_lanes(own.ctypes.data, own.strides[0], nb, n_leavers, n,
+                       inv.ctypes.data, found.ctypes.data, ids.ctypes.data)
+    ends = np.cumsum(found).tolist()
+    outs = [ids[a:b] for a, b in zip([0] + ends, ends)]
+    return (outs, int((found <= LANE_UNPACK_LIVE_SHARE * n).sum()),
+            int(found.sum()), n_leavers)
+
+
+def _unpack_lanes_numpy(packed: np.ndarray, n: int, perm: np.ndarray,
+                        inv: np.ndarray, n_leavers: int):
+    """_unpack_lanes without the native library: the same arrays and
+    the same two counts.
 
     The set bits are counted first, a leaver at a time in one pass
     over the 64-bit words, and each leaver's route follows from its
@@ -4178,19 +4229,21 @@ class _LaneFetch:
     and its trace (graph/batch_dispatch.py _finish); ``tpu.fetch``
     still wraps wait + copy, as the windowed resolvers' does.  What
     the unpack met is left beside them for the same reader:
-    ``unpack_leavers``, of them ``unpack_live`` out of their non-zero
-    bytes, ``unpack_rows`` the set rows of all of them
-    (_unpack_lanes)."""
+    ``unpack_leavers``, of them ``unpack_live`` of few set rows and
+    ``unpack_native`` through the native pass, ``unpack_rows`` the set
+    rows of all of them (_unpack_lanes)."""
 
     __slots__ = ("session", "out_dev", "n_leavers", "t_wait", "t_d2h",
-                 "unpack_leavers", "unpack_live", "unpack_rows")
+                 "unpack_leavers", "unpack_live", "unpack_rows",
+                 "unpack_native")
 
     def __init__(self, session, out_dev, n_leavers):
         self.session = session
         self.out_dev = out_dev
         self.n_leavers = n_leavers
         self.t_wait = self.t_d2h = None
-        self.unpack_leavers = self.unpack_live = self.unpack_rows = 0
+        self.unpack_leavers = self.unpack_live = 0
+        self.unpack_rows = self.unpack_native = 0
 
     def __call__(self):
         with tracing.span("tpu.fetch"):
@@ -4206,9 +4259,10 @@ class _LaneFetch:
         # the buffer that crossed the link, the rung's padding too
         self.session.rt._note_fetch(packed)
         ix = self.session.ix
-        outs, self.unpack_live, self.unpack_rows = _unpack_lanes(
-            packed, ix.n, ix.perm, ix.inv, self.n_leavers)
+        outs, self.unpack_live, self.unpack_rows, self.unpack_native = \
+            _unpack_lanes(packed, ix.n, ix.perm, ix.inv, self.n_leavers)
         self.unpack_leavers = self.n_leavers
+        self.session.rt._bump("unpack_native", self.unpack_native)
         return outs
 
 

@@ -5,8 +5,8 @@ instrumented process (LD_PRELOAD=libasan, NEBULA_NATIVE_SO pointing at
 the `make asan` artifact).  Deliberately avoids pytest and jax device
 work — the instrumented interpreter makes those minutes-slow — while
 still driving every native entry point: engine CRUD/scans/snapshot
-ingest (fuzzed against MemEngine), the batch column decoder, and the
-C++ ELL builder.
+ingest (fuzzed against MemEngine), the batch column decoder, the
+C++ ELL builder and the lane unpack.
 """
 import os
 import random
@@ -113,6 +113,41 @@ def main(tmp_dir: str) -> None:
     ee = np.ones(600, np.int32)
     ix = EllIndex.build(es, ed, ee, 64)
     assert ix.n == 64
+
+    # the lane unpack (neb_count_lanes / neb_unpack_lanes), called as
+    # tpu/runtime.py _unpack_lanes calls it: strided rows that start
+    # off a word, n not a multiple of 64, an empty and a full leaver,
+    # the rung's padding and the bits of rows from n on all ones, an
+    # inv with ids outside the table
+    from nebula_tpu.native import lib
+    from nebula_tpu.tpu.ell import lane_bitmap_bytes
+    from nebula_tpu.tpu.runtime import _unpack_lanes, _unpack_lanes_numpy
+    assert hasattr(lib(), "neb_unpack_lanes")
+    nrng = np.random.default_rng(3)
+    for n in (1, 61, 1003, 4099):
+        nb = lane_bitmap_bytes(n)
+        inv = nrng.permutation(n).astype(np.int32)
+        perm = np.empty(n, np.int32)
+        perm[inv] = np.arange(n, dtype=np.int32)
+        wide = np.full(8 * (nb + 5) + 3, 0xFF, np.uint8)
+        packed = wide[3:].reshape(8, nb + 5)[:, :nb]
+        packed[:5] = 0
+        for i, k in enumerate((0, n, n // 2, min(n, 7), n // 5)):
+            rows = nrng.choice(n, k, replace=False)
+            np.bitwise_or.at(packed[i], rows % nb,
+                             (1 << (rows // nb)).astype(np.uint8))
+        got = _unpack_lanes(packed, n, perm, inv, 5)
+        ref = _unpack_lanes_numpy(packed, n, perm, inv, 5)
+        assert got[3] == 5 and got[1:3] == ref[1:]
+        assert all(np.array_equal(a, b) for a, b in zip(got[0], ref[0]))
+        # a padding row: every bit set, those of rows >= n too
+        full = _unpack_lanes(packed[5:], n, perm, inv, 1)
+        assert full[0][0].tolist() == list(range(n))
+        bad = inv.copy()
+        bad[0] = n
+        bad[n - 1] = -1
+        assert len(_unpack_lanes(packed[5:], n, perm, bad, 1)[0][0]) \
+            == max(n - 2, 0)
     print("ASAN DRIVER OK")
 
 

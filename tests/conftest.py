@@ -74,3 +74,23 @@ def _lock_order_watchdog(request):
         # session-wide enable must survive past the first fixture use
         if not was_enabled:
             watchdog.disable()
+
+
+@pytest.fixture
+def stale_native(monkeypatch):
+    """``stale_native("neb_x", ...)``: until the test ends, the native
+    library reads as a build from before those entries were written
+    (every other entry stays) — how each entry's Python fallback is
+    reached anywhere."""
+    def hide(*entries):
+        from nebula_tpu import native
+        real = native.lib()
+
+        class Stale:
+            def __getattr__(self, name):
+                if name in entries:
+                    raise AttributeError(name)
+                return getattr(real, name)
+
+        monkeypatch.setattr(native, "lib", lambda: Stale())
+    return hide

@@ -1,10 +1,14 @@
 """The lane's way out (tpu/ell.py make_lane_extract_kernel, tpu/runtime.py
 _LaneFetch / _unpack_lanes): the extract packs each leaving lane down
 the vertex rows on the device, eight rows a byte, plane by plane, and
-a leaver's frontier is unpacked out of the non-zero bytes of its own
-bitmap (one pass for all such leavers of a cohort), or out of the
-whole bitmap through ``perm`` where its set rows pass
-LANE_UNPACK_LIVE_SHARE of the table.  Either way the arrays are,
+the cohort's bitmaps become its leavers' id arrays in one native call
+(native/unpack.cc, PR 47: a bitmap of id bits read off in order) or,
+where the library lacks the entry, in numpy: a leaver's frontier out
+of the non-zero bytes of its own bitmap (one pass for all such leavers
+of a cohort), or out of the whole bitmap through ``perm`` where its
+set rows pass LANE_UNPACK_LIVE_SHARE of the table.  Every case runs
+against both forms (the ``form`` fixture; the numpy one is reached by
+hiding the symbol).  Whichever way, the arrays are,
 element for element and dtype for dtype, what the formula the resolver
 used before PR 28 gives over the lane's word column — kept here as the
 reference, and every hand-made cohort it was held to is kept with it.
@@ -18,6 +22,19 @@ from nebula_tpu.common.flags import flags
 from nebula_tpu.tpu import ell as E
 from nebula_tpu.tpu.runtime import (LANE_UNPACK_LIVE_SHARE, _LaneFetch,
                                     _unpack_lanes)
+
+
+@pytest.fixture(params=["native", "numpy"])
+def form(request, stale_native):
+    """Which form of _unpack_lanes the test meets: the library's one
+    call, or numpy behind a library that lacks the entry (a stale
+    build), which is how the fallback is reached anywhere."""
+    from nebula_tpu import native
+    if request.param == "numpy":
+        stale_native("neb_unpack_lanes")
+    elif not hasattr(native.lib(), "neb_unpack_lanes"):
+        pytest.skip("native lib unavailable")
+    return request.param
 
 
 def _reference(cols, perm, leavers, cols_of):
@@ -183,7 +200,7 @@ CASES = {
 
 @pytest.mark.parametrize("order", ["rows_contiguous", "columns_contiguous"])
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_unpack_equals_the_whole_column_formula(name, order):
+def test_unpack_equals_the_whole_column_formula(name, order, form):
     cols, n, perm, inv, pairs, leavers, cols_of = _case(name)
     # a leaver's bitmap contiguous (what np.asarray gives on the TPU
     # and on CPU jax) or strided (a buffer handed over the other way
@@ -193,7 +210,9 @@ def test_unpack_equals_the_whole_column_formula(name, order):
                      order="C" if order == "rows_contiguous" else "F")
     packed.setflags(write=False)                # as np.asarray's is
     want = _reference(cols, perm, leavers, cols_of)
-    outs, live, rows = _unpack_lanes(packed, n, perm, inv, len(leavers))
+    outs, live, rows, native = _unpack_lanes(packed, n, perm, inv,
+                                             len(leavers))
+    assert native == (len(leavers) if form == "native" else 0)
     assert len(outs) == len(leavers)
     for got, ref in zip(outs, want):
         assert got.dtype == np.int64 and got.ndim == 1
@@ -214,7 +233,7 @@ def test_inv_of_ascending_rows_is_not_ascending():
     assert len(rows) > 3 and np.any(np.diff(inv[rows]) < 0)
 
 
-def test_junk_in_the_hub_rows_reaches_no_answer():
+def test_junk_in_the_hub_rows_reaches_no_answer(form):
     """The hub extra rows' junk never reaches the packed form, so
     both blocks pack to the same buffer and unpack to one answer."""
     cols, n, perm, inv, pairs, leavers, cols_of = _case("one_leaver")
@@ -230,9 +249,11 @@ def test_junk_in_the_hub_rows_reaches_no_answer():
 
 @pytest.mark.parametrize("k", [0, 1, 79, 80, 81, 200, 399, 400])
 def test_both_routes_give_one_array_on_either_side_of_the_share(
-        k, monkeypatch):
+        k, monkeypatch, form):
     """A leaver of k set rows of 400, unpacked with the share put
-    under and over k: the route is a speed choice only."""
+    under and over k: the route is a speed choice only (numpy), and
+    no choice at all (native: the share only says which side of it
+    the leaver is counted on)."""
     from nebula_tpu.tpu import runtime
     n = 400
     perm, inv = _index(n, 5)
@@ -244,16 +265,169 @@ def test_both_routes_give_one_array_on_either_side_of_the_share(
     got = {}
     for share, sparse in ((1.0, 1), (-1.0, 0)):
         monkeypatch.setattr(runtime, "LANE_UNPACK_LIVE_SHARE", share)
-        (ids,), was_sparse, found = _unpack_lanes(packed, n, perm, inv, 1)
+        (ids,), was_sparse, found, _native = _unpack_lanes(
+            packed, n, perm, inv, 1)
         assert was_sparse == sparse and found == k
         assert ids.dtype == np.int64
         got[sparse] = ids
     assert np.array_equal(got[1], got[0])
     assert np.array_equal(got[1], old_ids)
-    monkeypatch.undo()
     # the shipped share puts the turn at 80 of 400
+    monkeypatch.setattr(runtime, "LANE_UNPACK_LIVE_SHARE",
+                        LANE_UNPACK_LIVE_SHARE)
     assert _unpack_lanes(packed, n, perm, inv, 1)[1] \
         == (k <= LANE_UNPACK_LIVE_SHARE * n)
+
+
+# ===================================== cohorts of every size, both forms
+def _cohort(n, sizes, seed, rung=None, poison=0xFF):
+    """(packed, perm, inv, want) of a cohort whose leaver i has
+    sizes[i] set rows: each leaver's 0/1 column over the vertex rows,
+    packed as the extract packs it, and ``np.nonzero(column_bit[perm])
+    [0]`` as what the unpack owes.  The rung's padding rows, past the
+    leavers, are all ones (or another byte): whoever read one would
+    count its rows."""
+    perm, inv = _index(n, 5, seed)
+    rng = np.random.default_rng(seed)
+    L = rung or E.lane_extract_rung(len(sizes), 128)
+    packed = np.full((L, E.lane_bitmap_bytes(n)), poison, np.uint8)
+    want = []
+    for i, k in enumerate(sizes):
+        column_bit = np.zeros(n, np.uint8)
+        column_bit[rng.choice(n, k, replace=False)] = 1
+        packed[i] = _pack_rows(column_bit, n)
+        want.append(np.nonzero(column_bit[perm])[0])
+    return packed, perm, inv, want
+
+
+def _same(got, want):
+    outs, live, rows, _native = got
+    assert len(outs) == len(want)
+    for ids, ref in zip(outs, want):
+        assert ids.dtype == np.int64 and ids.ndim == 1
+        assert np.array_equal(ids, ref)
+    assert rows == sum(len(ref) for ref in want)
+    return live
+
+
+@pytest.mark.parametrize("n", [1, 61, 64, 65, 400, 1003, 4099])
+@pytest.mark.parametrize("leavers", [1, 4, 25, 128])
+def test_cohorts_of_1_4_25_and_128_leavers_at_any_n(n, leavers, form):
+    """n under 64, a multiple of 64 and not; a cohort that fills its
+    rung (4, 128) and one that leaves padding (1, 25), poisoned with
+    0xFF and never read; among the leavers one of no set rows and one
+    of all n; ``inv`` int32, as the index holds it."""
+    rng = np.random.default_rng(n + leavers)
+    sizes = [0, n] + rng.integers(0, n + 1, leavers).tolist()
+    sizes = sizes[2:] if leavers == 1 else sizes[:leavers]
+    packed, perm, inv, want = _cohort(n, sizes, seed=n * leavers)
+    assert inv.dtype == np.int32
+    assert packed[len(sizes):].all() or leavers in (4, 128)
+    live = _same(_unpack_lanes(packed, n, perm, inv, len(sizes)), want)
+    assert live == sum(k <= LANE_UNPACK_LIVE_SHARE * n for k in sizes)
+
+
+@pytest.mark.parametrize("sizes", [[0], [400], [0, 400, 0, 400]])
+def test_a_leaver_of_no_set_rows_and_one_of_all_n(sizes, form):
+    packed, perm, inv, want = _cohort(400, sizes, seed=3)
+    outs = _unpack_lanes(packed, 400, perm, inv, len(sizes))[0]
+    _same((outs, None, sum(sizes), None), want)
+    for ids, k in zip(outs, sizes):
+        assert ids.tolist() == list(range(k))
+
+
+@pytest.mark.parametrize("layout", [
+    "every_other_row", "inside_a_wider_buffer", "rows_backwards",
+    "off_the_word"])
+def test_a_strided_packed_is_read_where_it_lies(layout, form):
+    """The leavers' rows at a stride that is not their length, a
+    negative one, and a start that is no multiple of eight bytes: the
+    native pass takes the stride and loads a word from any address;
+    the numpy form copies first.  What lies between the rows is all
+    ones and reaches no answer."""
+    n, sizes = 1003, [0, 7, 300, 1003, 64]
+    tight, perm, inv, want = _cohort(n, sizes, seed=11, rung=8)
+    L, nb = tight.shape
+    if layout == "every_other_row":
+        wide = np.full((2 * L, nb), 0xFF, np.uint8)
+        packed = wide[::2]
+    elif layout == "inside_a_wider_buffer":
+        wide = np.full((L, nb + 40), 0xFF, np.uint8)
+        packed = wide[:, 16:16 + nb]
+    elif layout == "rows_backwards":
+        wide = np.full((L, nb), 0xFF, np.uint8)
+        packed = wide[::-1]
+    else:
+        wide = np.full(L * nb + 3, 0xFF, np.uint8)
+        packed = wide[3:].reshape(L, nb)
+        assert packed.ctypes.data % 8 != wide.ctypes.data % 8
+    packed[:] = tight
+    assert layout == "off_the_word" or not packed.flags.c_contiguous
+    assert packed.strides[1] == 1
+    packed.setflags(write=False)
+    _same(_unpack_lanes(packed, n, perm, inv, len(sizes)), want)
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int64])
+@pytest.mark.parametrize("strided", [False, True])
+def test_inv_is_read_as_int32_however_it_comes(dtype, strided, form):
+    """The index holds ``inv`` as contiguous int32 and the native pass
+    reads that in place; any other array is made one first."""
+    n, sizes = 400, [5, 0, 390]
+    packed, perm, inv, want = _cohort(n, sizes, seed=5)
+    inv = inv.astype(dtype)
+    if strided:
+        inv = np.stack([inv, inv], axis=1)[:, 0]
+        assert not inv.flags.c_contiguous
+    _same(_unpack_lanes(packed, n, perm, inv, len(sizes)), want)
+
+
+def test_both_forms_give_equal_arrays_and_counts():
+    """One cohort through whatever form the library gives and through
+    numpy: the same arrays, the same ``live`` (the share of 4,099 is
+    819.8 rows) and ``rows``; only ``native`` differs."""
+    from nebula_tpu.tpu.runtime import _unpack_lanes_numpy
+    n, sizes = 4099, [0, 1, 63, 64, 65, 819, 820, 2000, 4099]
+    packed, perm, inv, want = _cohort(n, sizes, seed=9)
+    got = _unpack_lanes(packed, n, perm, inv, len(sizes))
+    ref = _unpack_lanes_numpy(packed, n, perm, inv, len(sizes))
+    assert _same(got, want) == _same(ref + (0,), want) == 6
+    assert got[1:3] == ref[1:]
+
+
+def test_the_native_pass_skips_what_the_contract_rules_out():
+    """Not the extract's output, but nothing the pass may follow out
+    of its arrays: bits of rows from n on (the bitmap has room for
+    them up to the next 64) are passed over, and so is a row whose
+    ``inv`` lies outside the table."""
+    from nebula_tpu import native
+    if not hasattr(native.lib(), "neb_unpack_lanes"):
+        pytest.skip("native lib unavailable")
+    n = 61
+    perm, inv = _index(n, 5)
+    packed = np.full((4, E.lane_bitmap_bytes(n)), 0xFF, np.uint8)
+    (ids,), live, rows, native_n = _unpack_lanes(packed, n, perm, inv, 1)
+    assert ids.tolist() == list(range(n)) and rows == n
+    assert (live, native_n) == (0, 1)
+    bad = inv.copy()
+    bad[perm[7]], bad[perm[9]] = -1, n
+    (ids,), _live, rows, _n = _unpack_lanes(packed, n, perm, bad, 1)
+    assert ids.tolist() == [i for i in range(n) if i not in (7, 9)]
+    assert rows == n - 2
+    with pytest.raises(IndexError):
+        _unpack_lanes(packed, n, perm, inv[:n - 1], 1)
+
+
+def test_the_fallback_is_said_once_on_stderr(form, capfd, monkeypatch):
+    from nebula_tpu.tpu import runtime
+    monkeypatch.setattr(runtime, "_said", set())
+    packed, perm, inv, want = _cohort(400, [3, 0], seed=1)
+    for _ in range(3):
+        native = _unpack_lanes(packed, 400, perm, inv, 2)[3]
+    said = capfd.readouterr().err
+    assert said.count("native lane unpack missing") \
+        == (0 if form == "native" else 1)
+    assert native == (2 if form == "native" else 0)
 
 
 # ============================== the device's pack, on CPU jax
@@ -406,7 +580,8 @@ def _session(c):
 
 
 @pytest.mark.parametrize("start,live", [(1, 1), (10, 0)])
-def test_session_join_two_hops_extract_equals_cpu(star, start, live):
+def test_session_join_two_hops_extract_equals_cpu(star, start, live,
+                                                  form):
     c, ok = star
     sess = _session(c)
     n = sess.ix.n
@@ -417,6 +592,7 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     sess.hop()
     sess.hop()
     before = sess.rt.stats["fetch_bytes"]
+    native_before = sess.rt.stats["unpack_native"]
     resolver = sess.extract([(9, False), (9, True)])
     assert isinstance(resolver, _LaneFetch)
     exact, upto = resolver()
@@ -441,6 +617,10 @@ def test_session_join_two_hops_extract_equals_cpu(star, start, live):
     for rows in (want, want_upto):
         assert (len(rows) <= LANE_UNPACK_LIVE_SHARE * n) == bool(live)
     assert resolver.unpack_live == 2 * live
+    # which form unpacked them, on the resolver and in rt.stats
+    assert resolver.unpack_native == (2 if form == "native" else 0)
+    assert sess.rt.stats["unpack_native"] - native_before \
+        == resolver.unpack_native
 
 
 @pytest.mark.parametrize("start", [1, 10])

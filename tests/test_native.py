@@ -277,6 +277,34 @@ def test_native_suite_under_asan(tmp_path):
     assert "AddressSanitizer" not in r.stderr, r.stderr
 
 
+def test_the_lane_unpack_is_built_into_both_artifacts():
+    """neb_unpack_lanes (native/unpack.cc, PR 47) is there after
+    ensure_built(), with the count that sizes its output, and the
+    Makefile builds its source into the ASAN artifact too (one SRCS
+    for both targets; test_native_suite_under_asan builds and drives
+    it)."""
+    from nebula_tpu.native import ensure_built, lib
+    assert ensure_built()
+    for entry in ("neb_unpack_lanes", "neb_count_lanes"):
+        assert hasattr(lib(), entry), entry
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "native", "Makefile")) as f:
+        make = f.read()
+    srcs = next(line for line in make.splitlines()
+                if line.startswith("SRCS :="))
+    assert "unpack.cc" in srcs.split()
+    assert make.count("$(SRCS)") >= 4       # both targets: deps + inputs
+    # the count holds the interpreter lock, the pass gives it up
+    FUNCFLAG_PYTHONAPI = 0x4                # ctypes' own, not exported
+    assert lib().neb_count_lanes._flags_ & FUNCFLAG_PYTHONAPI
+    assert not lib().neb_unpack_lanes._flags_ & FUNCFLAG_PYTHONAPI
+    packed = np.array([[0b101, 0, 0, 0, 0, 0, 0, 0x80]] * 2, np.uint8)
+    counts = np.zeros(2, np.int64)
+    assert lib().neb_count_lanes(packed.ctypes.data, 8, 8, 2,
+                                 counts.ctypes.data) == 6
+    assert counts.tolist() == [3, 3]
+
+
 def test_split_rowset_rejects_overflowing_varint():
     """A corrupt row-length varint near 2^64 must fail the split, not
     wrap the bounds check into an out-of-bounds row (review finding)."""

@@ -276,13 +276,34 @@ class TestUnpackCounters:
     """What ``unpack_us`` met rides the same way the stamps do: from
     the resolver (tpu/runtime.py _LaneFetch) through _finish to the
     tick record and the ``pump.unpack`` span."""
-    FIELDS = ("unpack_leavers", "unpack_live", "unpack_rows")
+    FIELDS = ("unpack_leavers", "unpack_live", "unpack_rows",
+              "unpack_native")
 
-    def test_tick_record_says_what_the_unpack_met(self, graph):
+    @pytest.mark.parametrize("library", ["with_the_native_pass", "without"])
+    def test_tick_record_says_what_the_unpack_met(
+            self, graph, library, stale_native, monkeypatch, capfd):
         c, g, ok = graph
+        rt = c.tpu_runtime
+        if library == "without":
+            # a build from before native/unpack.cc: numpy unpacks, and
+            # says so once
+            from nebula_tpu.tpu import runtime
+            stale_native("neb_unpack_lanes")
+            monkeypatch.setattr(runtime, "_said", set())
         flags.set("trace_sample_rate", 1.0)
+        before = rt.stats["unpack_native"]
+        capfd.readouterr()
         _burst(c, _mixed(9))
         ticks = _ticks()
+        # the native pass takes every leaver of every cohort, or none
+        took = 9 if library == "with_the_native_pass" else 0
+        for t in ticks:
+            assert t["unpack_native"] == (t["unpack_leavers"] if took
+                                          else 0)
+        assert sum(t["unpack_native"] for t in ticks) == took \
+            == rt.stats["unpack_native"] - before
+        assert capfd.readouterr().err.count(
+            "native lane unpack missing") == (0 if took else 1)
         for t in ticks:
             for f in self.FIELDS:
                 assert isinstance(t[f], int) and t[f] >= 0, (f, t)
@@ -299,7 +320,8 @@ class TestUnpackCounters:
         # them, a cohort's live rows per leaver need not
         assert 0 <= sum(t["unpack_live"] for t in ticks) <= 9
         # the span carries the same numbers, cohort by cohort
-        for f, tag in zip(self.FIELDS, ("leavers", "live", "rows")):
+        for f, tag in zip(self.FIELDS,
+                          ("leavers", "live", "rows", "native")):
             spans = [k for r in _pump_roots("pump.tick")
                      for k in r["children"] if k["name"] == "pump.unpack"]
             assert sum(k["tags"][tag] for k in spans) \

@@ -514,7 +514,7 @@ def test_spans_counters_handed_and_the_five_waits(served, monkeypatch):
 
 # ================================ (d) a numpy WHERE stays on the pump
 @pytest.mark.parametrize("library", ["with_the_native_pass", "without"])
-def test_a_where_that_filters_in_numpy_is_the_pump_s(served, monkeypatch,
+def test_a_where_that_filters_in_numpy_is_the_pump_s(served, stale_native,
                                                      library):
     """Sixteen numpy passes at once are slower than one thread running
     them in turn (PERF.md section 6, PR 32), so the pump keeps a WHERE
@@ -524,16 +524,7 @@ def test_a_where_that_filters_in_numpy_is_the_pump_s(served, monkeypatch,
     c, ok = served
     rt = c.tpu_runtime
     if library == "without":
-        from nebula_tpu import native
-        real = native.lib()
-
-        class Stale:
-            def __getattr__(self, name):
-                if name == "neb_filter_runs_f64":
-                    raise AttributeError(name)
-                return getattr(real, name)
-
-        monkeypatch.setattr(native, "lib", lambda: Stale())
+        stale_native("neb_filter_runs_f64")
     statements = [
         "GO 3 STEPS FROM 21 OVER e, f WHERE e.d > 0.4 YIELD e._dst, e.w",
         "GO 3 STEPS FROM 22 OVER e, f WHERE e.w > 40 YIELD e._dst, e.w",
